@@ -188,7 +188,8 @@ class RolloutSection:
 @dataclass
 class ParallelSection:
     """Mesh axes for the trainer's GSPMD sharding (parallel/mesh.py). With
-    every axis 1 and a single process, no mesh is built (single-chip path).
+    every axis 1, a single process and no device list, no mesh is built
+    (single-chip path).
     Multi-host runs (jax.distributed via JAX_COORDINATOR_ADDRESS et al.)
     always build the mesh over the global device set."""
     dp: int = 1
@@ -201,6 +202,11 @@ class ParallelSection:
     # sequence-parallel attention flavor when sp > 1 (parallel/sequence.py):
     # ulysses (head all-to-all) | ring (KV ppermute) | dense (GSPMD decides)
     sp_mode: str = "ulysses"
+    # indices into jax.devices() the trainer's mesh is built over; () =
+    # all of them. One process can hold the trainer and rollout engines on
+    # separate chips (rollout.serve's device list is the other half); a
+    # list here builds the mesh even when every axis is 1.
+    devices: tuple = ()
 
 
 @dataclass

@@ -54,17 +54,19 @@ class ControlPlaneDown(ManagerError):
 
 
 def build_manager(force: bool = False) -> str:
-    """(Re)build the C++ manager; returns the binary path. Always runs
-    ``make`` — its dependency check is a no-op when the binary is fresh,
-    and a checked-in binary must not shadow newer sources."""
+    """Build the C++ manager from the sources beside this file; returns
+    the binary path. Always runs ``make`` — its dependency check is a
+    no-op when the binary is fresh — and a failed build is an error: the
+    binary is git-ignored, so one found lying in the tree says nothing
+    about the sources in it. ``force`` rebuilds unconditionally
+    (``make -B``)."""
+    cmd = ["make", "-C", _CPP_DIR] + (["-B"] if force else [])
     try:
-        subprocess.run(["make", "-C", _CPP_DIR], check=True,
-                       capture_output=True)
-    except (OSError, subprocess.CalledProcessError):
-        # no toolchain on this box: fall back to a prebuilt binary
-        if not force and os.path.exists(_BINARY):
-            return _BINARY
-        raise
+        subprocess.run(cmd, check=True, capture_output=True, text=True)
+    except subprocess.CalledProcessError as exc:
+        raise RuntimeError(
+            f"building the rollout manager failed ({' '.join(cmd)}):\n"
+            f"{exc.stderr[-2000:]}") from exc
     return _BINARY
 
 

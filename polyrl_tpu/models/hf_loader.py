@@ -150,7 +150,7 @@ def load_hf_params(ckpt_dir: str, cfg: decoder.ModelConfig | None = None,
     ``quantize="int8"``: matmul weights are quantized ON HOST (numpy) and
     only the int8 tensors + scales are transferred — the full-precision
     tree never exists on device, so an 8B checkpoint loads onto a 16 GiB
-    chip (models/quant.py; 8B_FEASIBILITY.md).
+    chip (models/quant.py).
 
     ``to_device=False`` keeps every leaf host-side (numpy): callers that
     shard over a mesh device_put leaf-by-leaf straight into the sharded

@@ -18,8 +18,7 @@ Why this design on TPU:
   mantissa covers ±256), so the cast itself is lossless; the only error is
   the quantization rounding, bounded by scale/2 per weight.
 - It makes the 8B north-star model (Llama-3.1-8B, 16.06 GiB bf16) fit a
-  16 GiB-HBM chip: int8 matmul weights + bf16 embeddings ≈ 8.6 GiB
-  (8B_FEASIBILITY.md).
+  16 GiB-HBM chip: int8 matmul weights + bf16 embeddings ≈ 8.6 GiB.
 
 ``QuantWeight`` is a registered pytree node, so quantized param trees flow
 through ``jax.jit``, ``tree_map`` layer slicing, ``lax.scan``, device_put

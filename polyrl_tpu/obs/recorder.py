@@ -1,7 +1,7 @@
 """Anomaly flight recorder (ARCHITECTURE.md "Goodput & health plane").
 
-BENCH_r01–r05 all died rc=124 with nobody noticing mid-run: nothing was
-watching the live trajectory. The recorder watches the per-step record
+Earlier benchmark rounds died at their time limit with nobody noticing
+mid-run: nothing was watching the live trajectory. The recorder watches the per-step record
 stream with an EWMA/z-score detector over step time and decode throughput
 and, on anomaly, crash, or SIGTERM, dumps a self-contained post-mortem
 bundle into the run directory:

@@ -25,6 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from polyrl_tpu.ops import dispatch
 from polyrl_tpu.ops.attention import attention, causal_mask
 
 _BLOCKS = (1024, 512, 256, 128)
@@ -65,7 +66,9 @@ def flash_attention_train(q, k, v, attn_mask, *, causal: bool = True,
     b, t, hq, d = q.shape
     hkv = k.shape[2]
     if not supports_flash(t, d):
+        dispatch.note("train_attention", "dense")
         return _dense(q, k, v, attn_mask, causal, segment_ids)
+    dispatch.note("train_attention", "flash")
 
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         BlockSizes, SegmentIds, flash_attention)

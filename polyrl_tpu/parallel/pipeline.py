@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from polyrl_tpu.parallel.compat import shard_map
 from polyrl_tpu.parallel.mesh import PP, SP
 
 
@@ -182,7 +181,7 @@ def make_pipeline_layers_fn(mesh: Mesh, cfg, num_microbatches: int,
             in_specs = (specs, P(), P(), P(), P(), P())
             out_spec = P()
             manual = {PP}
-        fn = shard_map(
+        fn = jax.shard_map(
             inner, mesh=mesh, in_specs=in_specs,
             out_specs=out_spec, axis_names=manual, check_vma=False)
         outs = fn(staged, xs, coss, sins, valids, segs)

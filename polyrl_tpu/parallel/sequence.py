@@ -48,7 +48,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from polyrl_tpu.ops.attention import repeat_kv
-from polyrl_tpu.parallel.compat import shard_map
 from polyrl_tpu.parallel.mesh import DP, FSDP, SP, TP
 
 _NEG = -0.7 * float(jnp.finfo(jnp.float32).max)  # finite -inf (no exp NaNs)
@@ -114,11 +113,11 @@ def make_ulysses_attention(mesh: Mesh, axis: str = SP,
     qkv_spec = P(batch_axes, axis, TP, None)  # heads stay tp-sharded
     mask_spec = P(batch_axes, axis)
     if packed:
-        return shard_map(
+        return jax.shard_map(
             inner, mesh=mesh,
             in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec, mask_spec),
             out_specs=qkv_spec, check_vma=False)
-    return shard_map(
+    return jax.shard_map(
         lambda q, k, v, tm: inner(q, k, v, tm), mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec),
         out_specs=qkv_spec, check_vma=False)
@@ -204,11 +203,40 @@ def make_ring_attention(mesh: Mesh, axis: str = SP, batch_axes=(DP, FSDP),
     qkv_spec = P(batch_axes, axis, TP, None)  # heads stay tp-sharded
     mask_spec = P(batch_axes, axis)
     if packed:
-        return shard_map(
+        return jax.shard_map(
             inner, mesh=mesh,
             in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec, mask_spec),
             out_specs=qkv_spec, check_vma=False)
-    return shard_map(
+    return jax.shard_map(
+        lambda q, k, v, tm: inner(q, k, v, tm), mesh=mesh,
+        in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec),
+        out_specs=qkv_spec, check_vma=False)
+
+
+def make_sharded_flash_attention(mesh: Mesh, batch_axes=(DP, FSDP),
+                                 packed: bool = False):
+    """The training attention under a mesh WITHOUT sequence parallelism:
+    ``flash_attention_train`` shard_mapped with the batch over
+    ``batch_axes`` and the heads over tp; every chip sees whole sequences.
+    The Pallas flash kernel is a Mosaic custom call and GSPMD refuses to
+    partition one ("Mosaic kernels cannot be automatically partitioned.
+    Please wrap the call in a shard_map") — so a sharded trainer's default
+    attention has to say how it splits. Same signatures as the SP
+    variants: (q, k, v, token_mask[, segment_ids])."""
+    from polyrl_tpu.ops import flash
+
+    def inner(q, k, v, token_mask, segment_ids=None):
+        return flash.flash_attention_train(q, k, v, token_mask, causal=True,
+                                           segment_ids=segment_ids)
+
+    qkv_spec = P(batch_axes, None, TP, None)
+    mask_spec = P(batch_axes, None)
+    if packed:
+        return jax.shard_map(
+            inner, mesh=mesh,
+            in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec, mask_spec),
+            out_specs=qkv_spec, check_vma=False)
+    return jax.shard_map(
         lambda q, k, v, tm: inner(q, k, v, tm), mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec),
         out_specs=qkv_spec, check_vma=False)

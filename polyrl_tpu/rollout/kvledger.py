@@ -77,16 +77,19 @@ FREE_CAUSES = ("finalize", "abort", "salvage", "cache_pressure", "flush",
 _GB = 1e9
 
 
-def hbm_truth(accounted_bytes: float) -> dict:
+def hbm_truth(accounted_bytes: float, devices=None) -> dict:
     """Best-effort device-memory reconciliation: ``jax`` per-device
     ``memory_stats()`` vs the bytes the ledger can account for (KV pools +
-    weights). Returns ``{}`` when no device reports stats (CPU test runs)
+    weights), over ``devices`` — the chips the engine's pools live on
+    (default: every local device; an engine that shares a process with a
+    trainer or other engines must not report the fullest chip's memory as
+    its own). Returns ``{}`` when no device reports stats (CPU test runs)
     — callers treat the keys as optional, like every per-field fleet
     aggregate."""
     try:
         import jax
 
-        devs = jax.local_devices()
+        devs = devices if devices is not None else jax.local_devices()
     except Exception:  # noqa: BLE001 — absent/uninitialized backend
         return {}
     used_max = 0.0
@@ -134,8 +137,10 @@ class PageLedger:
         self.page_size = int(page_size)
         self.cold_after = max(1, int(cold_after_dispatches))
         self.warm_after = max(1, self.cold_after // 4)
-        # per-page KV bytes; set by the engine once pools materialize
+        # per-page KV bytes, and the chips the pools live on (None = all
+        # local devices); both set by the engine once pools materialize
         self.page_bytes = 0
+        self.devices = None
         self._lock = threading.Lock()
         self._role = np.zeros((self.num_pages,), np.uint8)
         self._role[0] = ROLE_RESERVED
@@ -389,7 +394,7 @@ class PageLedger:
             }
             for cause, count in self.freed_by_cause.items():
                 fields[f"memory/freed_{cause}"] = float(count)
-        fields.update(hbm_truth(accounted_bytes))
+        fields.update(hbm_truth(accounted_bytes, self.devices))
         return fields
 
     def snapshot(self, pool_free: int, cache_pages: int,
@@ -451,5 +456,5 @@ class PageLedger:
                 "page_bytes": int(self.page_bytes),
                 "accounted_bytes": float(accounted_bytes),
             }
-        out["hbm"] = hbm_truth(accounted_bytes)
+        out["hbm"] = hbm_truth(accounted_bytes, self.devices)
         return out

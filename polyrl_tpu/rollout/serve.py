@@ -59,7 +59,8 @@ def create_server(model: str, manager_endpoint: str | None = None,
                   kv_spill_high_watermark: float = 0.92,
                   kv_spill_low_watermark: float = 0.80,
                   loop_profile: bool = True,
-                  fault_injector=None):
+                  fault_injector=None,
+                  devices: tuple[int, ...] | None = None):
     """Build engine + server, register with the manager, attach receiver.
 
     ``backend="cb"`` (default) serves with the paged continuous-batching
@@ -67,7 +68,13 @@ def create_server(model: str, manager_endpoint: str | None = None,
     ``weight_quant="int8"`` serves with int8 weight-only quantized matmuls
     (models/quant.py) — halves weight HBM and fits 8B-class models on a
     16 GiB chip; weight pushes from the trainer stay bf16 on the wire and
-    are re-quantized on arrival (server.weight_preprocess)."""
+    are re-quantized on arrival (server.weight_preprocess).
+
+    ``devices``: indices into ``jax.devices()`` this engine may use (its
+    ``tp`` chips are the first ``tp`` of them). None = the process's first
+    ``tp`` devices. One process can hold several engines and a trainer,
+    each on its own chips; naming the chips is how they are kept apart —
+    parameters and KV pools are then created on exactly those chips."""
     import jax
     import jax.numpy as jnp
 
@@ -79,19 +86,23 @@ def create_server(model: str, manager_endpoint: str | None = None,
     if weight_quant not in ("", "int8"):
         raise ValueError(f"unknown weight_quant {weight_quant!r}")
     mesh = None
-    if tp > 1:
+    if tp > 1 or devices is not None:
         # tensor-parallel serving (the reference's --tp-size role,
         # launch_sglang.sh:13): params/KV shard over tp chips of this host.
         # Built BEFORE param materialization so weights never stage
         # unsharded through one chip's HBM (the models tp exists for don't
-        # fit one chip).
+        # fit one chip). A named device list builds the mesh even at tp=1:
+        # the mesh is what pins params and pools to those chips.
         if backend != "cb":
-            raise NotImplementedError("tp > 1 requires backend='cb'")
+            raise NotImplementedError(
+                "tp > 1 or a device list requires backend='cb'")
         from polyrl_tpu.parallel import mesh as meshlib
 
-        devs = jax.devices()
-        if len(devs) % tp != 0:
-            raise ValueError(f"tp={tp} does not divide {len(devs)} devices")
+        all_devs = jax.devices()
+        devs = (all_devs if devices is None
+                else [all_devs[i] for i in devices])
+        if len(devs) < tp or (devices is None and len(devs) % tp):
+            raise ValueError(f"tp={tp} does not fit {len(devs)} devices")
         mesh = meshlib.make_mesh(meshlib.MeshConfig(fsdp=1, tp=tp),
                                  devs[:tp])
     if os.path.isdir(model):
@@ -281,6 +292,9 @@ def main() -> None:
                         "128 256 512 1024 2048 4096)")
     p.add_argument("--tp", type=int, default=1,
                    help="tensor-parallel serving over this many chips")
+    p.add_argument("--devices", type=int, nargs="+", default=None,
+                   help="indices into jax.devices() this engine may use "
+                        "(default: the first --tp devices)")
     p.add_argument("--prefill-chunk", type=int, default=0,
                    help="chunked prefill: prompts longer than this prefill "
                         "one page-aligned chunk per engine iteration, "
@@ -339,6 +353,9 @@ def main() -> None:
     args = p.parse_args()
 
     logging.basicConfig(level=logging.INFO)
+    from polyrl_tpu.utils.xla_cache import configure_compile_cache
+
+    configure_compile_cache()
     server = create_server(args.model, args.manager_endpoint, host=args.host,
                            port=args.port, advertise_host=args.advertise_host,
                            dtype=args.dtype, is_local=args.is_local,
@@ -353,6 +370,7 @@ def main() -> None:
                            warmup=args.warmup,
                            prompt_buckets=args.prompt_buckets,
                            tp=args.tp,
+                           devices=args.devices,
                            prefill_chunk=args.prefill_chunk,
                            spec_tokens=args.spec_tokens,
                            spec_rounds=args.spec_rounds,

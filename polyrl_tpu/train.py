@@ -55,7 +55,7 @@ def load_custom_score(path: str):
     return mod.compute_score
 
 
-def _build_model(cfg: RunConfig):
+def _build_model(cfg: RunConfig, mesh=None):
     import jax
     import jax.numpy as jnp
 
@@ -71,9 +71,24 @@ def _build_model(cfg: RunConfig):
         return mcfg, params
     mcfg = decoder.get_config(cfg.model.preset, dtype=getattr(jnp, cfg.model.dtype),
                               **cfg.model.overrides)
-    params = jax.jit(lambda: decoder.init_params(
-        jax.random.PRNGKey(cfg.trainer.seed), mcfg))()
-    return mcfg, params
+
+    def init():
+        return decoder.init_params(jax.random.PRNGKey(cfg.trainer.seed), mcfg)
+
+    if mesh is None or jax.process_count() > 1:
+        # multi-process: every process inits the same seeded tree locally
+        # (the reference policy keeps a process-local copy of it) and the
+        # actor shards it
+        return mcfg, jax.jit(init)()
+    # born sharded: each leaf is created in its mesh layout, so the full
+    # tree never stages through the default device (which the mesh may
+    # not even contain)
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    shardings = jax.tree_util.tree_map(
+        lambda s: NamedSharding(mesh, s), decoder.param_specs(mcfg),
+        is_leaf=lambda x: isinstance(x, P))
+    return mcfg, jax.jit(init, out_shardings=shardings)()
 
 
 def _build_rollout(cfg: RunConfig, mcfg, params, tokenizer, cleanup: list):
@@ -268,9 +283,9 @@ def _build_rollout(cfg: RunConfig, mcfg, params, tokenizer, cleanup: list):
 
 
 def _build_mesh(cfg: RunConfig):
-    """Build the global GSPMD mesh when parallelism is configured or the run
-    is multi-process (jax.distributed). Returns None single-chip — the
-    actor then skips sharding entirely."""
+    """Build the global GSPMD mesh when parallelism is configured, a device
+    list is given, or the run is multi-process (jax.distributed). Returns
+    None single-chip — the actor then skips sharding entirely."""
     import jax
 
     from polyrl_tpu.parallel import distributed
@@ -278,20 +293,25 @@ def _build_mesh(cfg: RunConfig):
 
     p = cfg.parallel
     axes = (p.dp, p.fsdp, p.tp, p.sp, p.ep, p.pp)
-    if jax.process_count() == 1 and all(a == 1 for a in axes):
+    if (jax.process_count() == 1 and all(a == 1 for a in axes)
+            and not p.devices):
         return None
     fsdp = p.fsdp
-    if all(a == 1 for a in axes):
+    if all(a == 1 for a in axes) and not p.devices:
         # multi-process with no axes configured: absorb the global device
         # count into fsdp (MeshConfig's own default) so a plain multi-host
         # launch works without hand-set parallel: overrides
         fsdp = -1
     mcfg = meshlib.MeshConfig(dp=p.dp, fsdp=fsdp, tp=p.tp, sp=p.sp,
                               pp=p.pp, ep=p.ep)
-    mesh = distributed.make_hybrid_mesh(config=mcfg)
-    log.info("mesh: %s over %d devices (%d processes)",
+    if p.devices:
+        all_devs = jax.devices()
+        mesh = meshlib.make_mesh(mcfg, [all_devs[i] for i in p.devices])
+    else:
+        mesh = distributed.make_hybrid_mesh(config=mcfg)
+    log.info("mesh: %s over %d of %d devices (%d processes)",
              dict(zip(mesh.axis_names, mesh.devices.shape)),
-             jax.device_count(), jax.process_count())
+             mesh.devices.size, jax.device_count(), jax.process_count())
     return mesh
 
 
@@ -321,7 +341,7 @@ def build_trainer(cfg: RunConfig, cleanup: list | None = None):
                   jax_annotations=cfg.obs.jax_annotations)
     tokenizer = build_tokenizer(cfg)
     mesh = _build_mesh(cfg)
-    mcfg, params = _build_model(cfg)
+    mcfg, params = _build_model(cfg, mesh)
 
     # SP attention setup + config validation FIRST: a bad combination must
     # fail before the manager/fabric/reward workers are spawned and torn
@@ -561,8 +581,10 @@ def main(argv: list[str] | None = None) -> int:
     # multi-host bring-up first (no-op single-process): jax.distributed from
     # the standard env vars, before any backend use (parallel/distributed.py)
     from polyrl_tpu.parallel import distributed
+    from polyrl_tpu.utils.xla_cache import configure_compile_cache
 
     distributed.initialize()
+    configure_compile_cache()
     cfg = load_config(args.config, args.overrides)
     if args.print_config:
         import yaml

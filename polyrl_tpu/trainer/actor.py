@@ -82,9 +82,20 @@ def make_optimizer(cfg: ActorConfig, total_steps: int = 0) -> optax.GradientTran
     return opt
 
 
-def default_train_attention():
+def default_train_attention(mesh=None, packed: bool = False):
     """Default training attention: Pallas flash on TPU (O(T) memory — the
-    reference's flash-attn varlen role), dense masked attention elsewhere."""
+    reference's flash-attn varlen role), dense masked attention elsewhere.
+    Under a mesh the call is shard_mapped over batch and heads
+    (parallel/sequence.make_sharded_flash_attention): GSPMD cannot
+    partition the Pallas kernel by itself. ``packed``: the segment-id
+    variant for ``bind_packed_attention`` (None without a mesh — its own
+    default is the single-logical-device segment-id flash kernel)."""
+    if mesh is not None:
+        from polyrl_tpu.parallel.sequence import make_sharded_flash_attention
+
+        return make_sharded_flash_attention(mesh, packed=packed)
+    if packed:
+        return None
     from polyrl_tpu.ops import flash
 
     return flash.auto_train_attention()
@@ -208,10 +219,15 @@ class StreamActor:
         self.model_cfg = model_cfg
         self.cfg = cfg
         self.mesh = mesh
-        self.attn_fn = attn_fn if attn_fn is not None else default_train_attention()
+        self.attn_fn = (attn_fn if attn_fn is not None
+                        else default_train_attention(mesh))
         self.layers_fn = layers_fn  # pipeline-parallel layer stack (pp > 1)
-        # segment-aware SP attention for the packed (remove-padding) passes;
+        # segment-aware attention for the packed (remove-padding) passes:
+        # the SP variant when given, the mesh-sharded flash under a mesh
+        # (the pipeline computes its own stage attention instead), else
         # None → the single-logical-device segment-id flash kernel
+        if packed_attn_fn is None and layers_fn is None:
+            packed_attn_fn = default_train_attention(mesh, packed=True)
         self.packed_attn_fn = packed_attn_fn
         self._lora = cfg.lora_rank > 0
         if self._lora:

@@ -104,9 +104,12 @@ class StreamCritic:
         self.model_cfg = model_cfg
         self.cfg = cfg
         self.mesh = mesh
-        self.attn_fn = attn_fn if attn_fn is not None else default_train_attention()
+        self.attn_fn = (attn_fn if attn_fn is not None
+                        else default_train_attention(mesh))
         self.layers_fn = layers_fn  # pipeline-parallel layer stack (pp > 1)
-        self.packed_attn_fn = packed_attn_fn  # see StreamActor
+        if packed_attn_fn is None and layers_fn is None:  # see StreamActor
+            packed_attn_fn = default_train_attention(mesh, packed=True)
+        self.packed_attn_fn = packed_attn_fn
         if mesh is not None:
             # backbone leaves follow decoder.param_specs; critic-only leaves
             # (the [D, 1] value head) fall back to replicated

@@ -41,7 +41,7 @@ from polyrl_tpu.rollout.sampling import SamplingParams
 from polyrl_tpu.trainer.actor import ActorConfig, ReferencePolicy, StreamActor
 from polyrl_tpu.trainer.critic import CriticConfig, StreamCritic
 from polyrl_tpu.utils import checkpoint as ckpt_lib
-from polyrl_tpu.utils.flops import FlopsCounter
+from polyrl_tpu.utils.flops import FlopsCounter, peak_tflops
 from polyrl_tpu.utils.metrics import MetricsTracker, marked_timer
 
 log = logging.getLogger(__name__)
@@ -262,7 +262,17 @@ class StreamRLTrainer:
             else None
         )
         self._esi_expiry = ckpt_lib.esi_expiry_from_env()
-        self._flops = FlopsCounter(actor.model_cfg, n_chips=jax.device_count())
+        # the chips the step's rate and utilization are taken over: the
+        # devices the actor's parameters live on, not every device this
+        # process can see (rollout engines may own the others)
+        actor_devices = set().union(*(
+            x.sharding.device_set
+            for x in jax.tree_util.tree_leaves(actor.params)))
+        self._n_chips = len(actor_devices)
+        self._flops = FlopsCounter(
+            actor.model_cfg,
+            peak_tflops=peak_tflops(next(iter(actor_devices)).device_kind),
+            n_chips=self._n_chips)
         self._tracing = False
         # goodput accounting (obs/goodput.py): every step's wall time is
         # decomposed into non-overlapping phases; /statusz reads the
@@ -1321,7 +1331,7 @@ class StreamRLTrainer:
                     "perf/trainer_bubble_s": state["bubble"],
                     "perf/throughput_tokens_per_s": throughput,
                     "perf/throughput_tok_s_per_chip":
-                        throughput / max(jax.device_count(), 1),
+                        throughput / self._n_chips,
                     "perf/rollout_throughput_tok_s":
                         self.rollout.last_gen_throughput,
                 })
@@ -1421,7 +1431,7 @@ class StreamRLTrainer:
                     histograms=hists,
                     n_tokens=state["n_tokens"],
                     mean_context_len=state["n_tokens"] / n_traj,
-                    n_chips=jax.device_count())
+                    n_chips=self._n_chips)
                 metrics.update(gp)
                 metrics.merge_histograms(hists)
                 tracer = obs.get_tracer()

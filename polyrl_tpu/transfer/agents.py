@@ -620,6 +620,13 @@ class SenderAgent:
             # wake any injected stall so teardown never waits it out
             self.fault.stop()
         try:
+            # shutdown before close: closing a listening socket does not
+            # wake a thread blocked in accept(), and the join below would
+            # wait out its whole timeout on every stop
+            self._server.shutdown(socket.SHUT_RDWR)
+        except OSError:
+            pass
+        try:
             self._server.close()
         except OSError:
             pass

@@ -5,23 +5,34 @@ style metrics, stream_ray_trainer.py:656-663).
 Per-token transformer FLOPs use the standard decomposition: ~6·P for the
 dense path (fwd 2·P, bwd 4·P) plus the attention quadratic term
 12·L·H·s per token at context length s (fwd+bwd; halve both for
-inference-only). Peak chip FLOP/s defaults to TPU v5e bf16 and can be
-overridden (env ``POLYRL_PEAK_TFLOPS`` or argument) for other parts.
+inference-only). The peak a utilization is taken against comes from
+``CHIP_PEAKS``, keyed by the device's ``device_kind``; a device that is not
+in the table (the CPU backend included) has no peak, and no ``mfu`` key is
+emitted for it.
 """
 
 from __future__ import annotations
 
-import os
 from typing import Any
 
-# bf16 peak TFLOP/s per chip (v5e: 197, v4: 275, v5p: 459, v6e/trillium: 918)
-PEAK_TFLOPS = {
-    "v4": 275.0,
-    "v5e": 197.0,
-    "v5p": 459.0,
-    "v6e": 918.0,
+# Published per-chip peaks by ``jax.Device.device_kind``: (bf16 TFLOP/s,
+# HBM GB/s). Source: Google Cloud TPU documentation, the "System
+# architecture" page of each generation (v4, v5e, v5p, v6e).
+CHIP_PEAKS: dict[str, tuple[float, float]] = {
+    "TPU v4": (275.0, 1228.0),
+    "TPU v5 lite": (197.0, 819.0),    # v5e
+    "TPU v5e": (197.0, 819.0),
+    "TPU v5": (459.0, 2765.0),        # v5p
+    "TPU v5p": (459.0, 2765.0),
+    "TPU v6 lite": (918.0, 1640.0),   # v6e
+    "TPU v6e": (918.0, 1640.0),
 }
-DEFAULT_PEAK_TFLOPS = 197.0
+
+
+def peak_tflops(device_kind: str) -> float | None:
+    """bf16 peak of one chip of this kind; None when the kind is unknown."""
+    peaks = CHIP_PEAKS.get(device_kind)
+    return peaks[0] if peaks else None
 
 
 def param_count(cfg: Any) -> int:
@@ -78,14 +89,13 @@ def flops_per_token(cfg: Any, context_len: int, *, training: bool = True,
 
 
 class FlopsCounter:
-    """Achieved TFLOP/s and MFU from token counts + wall time."""
+    """Achieved TFLOP/s from token counts + wall time, and MFU where the
+    chip's peak is known (``peak_tflops=None``: no ``mfu`` key)."""
 
     def __init__(self, model_cfg: Any, peak_tflops: float | None = None,
                  n_chips: int = 1):
         self.cfg = model_cfg
-        env = os.environ.get("POLYRL_PEAK_TFLOPS", "")
-        self.peak_tflops = (peak_tflops if peak_tflops is not None
-                            else float(env) if env else DEFAULT_PEAK_TFLOPS)
+        self.peak_tflops = peak_tflops
         self.n_chips = max(n_chips, 1)
         self.params = param_count(model_cfg)
 
@@ -103,8 +113,10 @@ class FlopsCounter:
                                     training=training)
         achieved_tflops = flops / step_time_s / 1e12
         per_chip = achieved_tflops / self.n_chips
-        return {
+        out = {
             f"{prefix}/tflops_all_chips": achieved_tflops,
             f"{prefix}/tflops_per_chip": per_chip,
-            f"{prefix}/mfu": per_chip / self.peak_tflops,
         }
+        if self.peak_tflops:
+            out[f"{prefix}/mfu"] = per_chip / self.peak_tflops
+        return out

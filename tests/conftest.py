@@ -7,32 +7,13 @@ and collectives are exercised host-side on a virtual device mesh
 
 import os
 
-
-def _xla_flag_supported(flag: str) -> bool:
-    """An UNKNOWN flag in XLA_FLAGS is a hard process abort (SIGABRT in
-    parse_flags_from_env) at first backend init — worse than the problem
-    any optional flag solves. The image's jaxlib can predate a flag (this
-    VM image migrates), so probe the binary for the flag-registry string
-    before adding it."""
-    try:
-        import jaxlib
-
-        so = os.path.join(os.path.dirname(jaxlib.__file__), "xla_extension.so")
-        with open(so, "rb") as f:
-            return flag.encode() in f.read()
-    except Exception:  # noqa: BLE001 — unknown layout: assume supported
-        return True
-
-
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = " --xla_force_host_platform_device_count=8"
-# 8 virtual devices share ONE core: a loaded box can miss XLA:CPU's
-# default 40 s collective-rendezvous termination window, which ABORTS
-# the whole pytest process. Slow is fine; aborted is not. (Skipped on
-# jaxlibs that predate the flags — see _xla_flag_supported.)
-if _xla_flag_supported("xla_cpu_collective_call_warn_stuck_timeout_seconds"):
-    _flags += (" --xla_cpu_collective_call_warn_stuck_timeout_seconds=120"
-               " --xla_cpu_collective_call_terminate_timeout_seconds=1200")
+# a loaded box can miss XLA:CPU's default 40 s collective-rendezvous
+# termination window, which ABORTS the whole pytest process. Slow is
+# fine; aborted is not.
+_flags += (" --xla_cpu_collective_call_warn_stuck_timeout_seconds=120"
+           " --xla_cpu_collective_call_terminate_timeout_seconds=1200")
 os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + _flags
 
 import jax  # noqa: E402
@@ -41,13 +22,12 @@ jax.config.update("jax_threefry_partitionable", True)
 # Numerical tests assume exact f32 matmuls (TPU bf16-MXU defaults would add
 # ~1e-3 noise); production code paths keep the fast default.
 jax.config.update("jax_default_matmul_precision", "highest")
-# Single-core machine: persist compiled executables across test runs. The
-# cache dir is keyed by the host's CPU feature set (a migrated VM must
-# start a fresh cache, not SIGABRT loading foreign AOT executables —
-# see polyrl_tpu/utils/xla_cache.py).
-from polyrl_tpu.utils.xla_cache import cpu_feature_cache_dir  # noqa: E402
+# Persist compiled executables across test runs, where
+# JAX_COMPILATION_CACHE_DIR says or in <checkout>/.jax_cache
+# (polyrl_tpu/utils/xla_cache.py).
+from polyrl_tpu.utils.xla_cache import configure_compile_cache  # noqa: E402
 
-jax.config.update("jax_compilation_cache_dir", cpu_feature_cache_dir())
+configure_compile_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 import threading  # noqa: E402

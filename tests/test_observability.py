@@ -40,11 +40,18 @@ def test_step_metrics_and_mfu():
     assert fc.step_metrics(0, 0, 0.0) == {}
 
 
-def test_peak_tflops_env_override(monkeypatch):
+def test_peak_comes_from_the_device_kind_table(monkeypatch):
+    """One table keyed by device_kind; an unknown kind (CPU included) has
+    no peak and emits no mfu key; POLYRL_PEAK_TFLOPS is not read."""
     cfg = decoder.get_config("tiny", dtype=jnp.float32)
+    assert flops_lib.peak_tflops("TPU v5 lite") == 197.0
+    assert flops_lib.peak_tflops("cpu") is None
+    assert flops_lib.peak_tflops("TPU v99") is None
     monkeypatch.setenv("POLYRL_PEAK_TFLOPS", "918")
-    fc = flops_lib.FlopsCounter(cfg)
-    assert fc.peak_tflops == 918.0
+    fc = flops_lib.FlopsCounter(cfg, peak_tflops=flops_lib.peak_tflops("cpu"))
+    assert fc.peak_tflops is None
+    m = fc.step_metrics(n_tokens=1000, mean_context_len=64, step_time_s=1.0)
+    assert set(m) == {"perf/tflops_all_chips", "perf/tflops_per_chip"}
 
 
 def test_profiler_step_gating(tmp_path):

@@ -13,6 +13,7 @@ from polyrl_tpu.ops.attention import attention, causal_mask
 from polyrl_tpu.parallel import mesh as meshlib
 from polyrl_tpu.parallel.sequence import (
     make_ring_attention,
+    make_sharded_flash_attention,
     make_sp_attention,
     make_ulysses_attention,
 )
@@ -345,3 +346,41 @@ def test_ring_never_expands_kv(sp_mesh, rng):
     want = dense_reference(q, k, v, tmask)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+# -- no SP: the default attention under a mesh ------------------------------
+
+
+@pytest.mark.parametrize("packed", [False, True])
+def test_mesh_sharded_flash_matches_unsharded(devices8, rng, packed):
+    """The trainer's default attention under a mesh (batch over dp×fsdp,
+    heads over tp, sequence whole): same values and gradients as the
+    unsharded call, GQA and left padding included."""
+    from polyrl_tpu.ops import flash
+
+    mesh = meshlib.make_mesh(meshlib.MeshConfig(dp=1, fsdp=2, tp=2),
+                             devices8[:4])
+    q, k, v, tmask = make_qkv(rng, hkv=4, left_pad=3)
+    seg = jnp.asarray(np.where(np.arange(32)[None] < 3, 0,
+                               1 + (np.arange(32)[None] >= 17))
+                      .repeat(4, 0), jnp.int32)
+    extra = (seg,) if packed else ()
+    fn = make_sharded_flash_attention(mesh, packed=packed)
+
+    def unsharded(q, k, v):
+        return flash.flash_attention_train(
+            q, k, v, tmask, causal=True, segment_ids=seg if packed else None)
+
+    valid = tmask[:, :, None, None]
+
+    def loss(f):
+        return lambda q, k, v: jnp.sum((f(q, k, v) * valid) ** 2)
+
+    got, g_got = jax.jit(jax.value_and_grad(
+        loss(lambda q, k, v: fn(q, k, v, tmask, *extra)), argnums=(0, 1, 2))
+    )(q, k, v)
+    want, g_want = jax.value_and_grad(loss(unsharded), argnums=(0, 1, 2))(
+        q, k, v)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for a, b in zip(g_got, g_want):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)

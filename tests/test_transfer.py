@@ -106,6 +106,20 @@ def test_tcp_engine_transfer():
 # -- sender/receiver agents (no manager) ------------------------------------
 
 
+def test_sender_stop_wakes_its_accept_thread():
+    """stop() must not wait out the join timeout: closing a listening
+    socket does not wake a thread blocked in accept(), shutting it down
+    does (every agent teardown used to cost a flat 5 s)."""
+    sender = SenderAgent(np.zeros(64, np.uint8), advertise_host="127.0.0.1")
+    sender.start()
+    time.sleep(0.1)  # let the accept thread block
+    threads = list(sender._threads)
+    t0 = time.monotonic()
+    sender.stop()
+    assert time.monotonic() - t0 < 2.0
+    assert not any(t.is_alive() for t in threads)
+
+
 def test_agents_direct_push():
     params = small_params(1)
     layout = build_layout(params)

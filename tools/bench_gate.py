@@ -3,7 +3,7 @@
 round trajectory and FAIL on regressions (ARCHITECTURE.md "Goodput &
 health plane").
 
-BENCH_r01–r05 drifted into rc=124 deaths with nobody noticing between
+Earlier rounds drifted into rc=124 deaths with nobody noticing between
 rounds — the trajectory was recorded but never read. This gate reads it:
 
 - **rc**: the newest round must have exited 0 (a rc=124/SIGTERM round is
@@ -254,7 +254,7 @@ def gate(rounds: list[dict], threshold: float = DEFAULT_THRESHOLD) -> dict:
     if newest["rc"] == 0:
         base = _median([r["value"] for r in prior])
         if newest["value"] <= 0:
-            # rc=0 with no headline number (BENCH_r03's failure mode):
+            # rc=0 with no headline number (a failed round that still exited 0):
             # the run "succeeded" but measured nothing — a regression
             failures.append(
                 f"newest round (n={newest['n']}) recorded no headline "

@@ -28,9 +28,7 @@ def main() -> None:
     import numpy as np
 
     from polyrl_tpu.models.decoder import _scatter_token_kv
-    from polyrl_tpu.ops.paged_attention import (
-        _pallas_kv_write_supported, paged_kv_write_pallas,
-    )
+    from polyrl_tpu.ops.paged_attention import paged_kv_write_pallas
 
     slots = int(os.environ.get("POLYRL_KVW_SLOTS", "65"))   # S+1 w/ sink
     hkv = int(os.environ.get("POLYRL_KVW_HKV", "8"))
@@ -57,13 +55,8 @@ def main() -> None:
     def pallas_impl(kp, vp):
         return paged_kv_write_pallas(kp, vp, pages, offs, upd, upd)
 
-    impls = {"scatter": jax.jit(scatter_impl, donate_argnums=(0, 1))}
-    if _pallas_kv_write_supported(hkv, page, d, kp.dtype, upd.dtype):
-        impls["pallas_dma"] = jax.jit(pallas_impl, donate_argnums=(0, 1))
-    else:
-        print(json.dumps({"impl": "pallas_dma",
-                          "error": "probe rejected on this backend"}),
-              flush=True)
+    impls = {"scatter": jax.jit(scatter_impl, donate_argnums=(0, 1)),
+             "pallas_dma": jax.jit(pallas_impl, donate_argnums=(0, 1))}
 
     for name, fn in impls.items():
         a, b = kp, vp
