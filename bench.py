@@ -138,7 +138,7 @@ def _http_generate(endpoint: str, rid: str, input_ids,
 
 
 def make_cb_engine(cfg, params, prompt_len, new_tokens, *, max_slots=64,
-                   page_size=64, steps_per_dispatch=8, trace=False,
+                   page_size=64, steps_per_dispatch=8,
                    spec_tokens=0, prompt_buckets=None):
     """Shared CB-engine construction for bench phases AND the knob-sweep
     tool (tools/bench_cb_sweep.py) — one code path so sweep findings
@@ -160,8 +160,17 @@ def make_cb_engine(cfg, params, prompt_len, new_tokens, *, max_slots=64,
         cfg, params, pad_token_id=0, kv_cache_dtype=jnp.bfloat16,
         max_slots=max_slots, page_size=page_size, max_seq_len=max_seq,
         prompt_buckets=buckets, steps_per_dispatch=steps_per_dispatch,
-        num_pages=max_slots * pages_per * 2 + 8, trace=trace,
+        num_pages=max_slots * pages_per * 2 + 8,
         spec_tokens=spec_tokens)
+
+
+def engine_phase_trace(engine) -> dict:
+    """Cumulative seconds and ``n_<phase>`` counts per engine-loop phase,
+    from the loop profiler (the one seam that times them)."""
+    snap = engine.loop_profile_snapshot()
+    out = {k: round(v, 3) for k, v in snap.get("phase_s", {}).items()}
+    out.update({"n_" + k: n for k, n in snap.get("phase_n", {}).items()})
+    return dict(sorted(out.items()))
 
 
 def warmup_cb(engine, cfg, rng, prompt_len):
@@ -345,9 +354,7 @@ def bench_cb(cfg, params, batch, prompt_len, new_tokens, max_slots=64,
              page_size=64, steps_per_dispatch=8):
     """CB engine: direct in-process batch, then concurrent HTTP serving
     (FRESH prompts per phase so the serve number isn't inflated by
-    prefix-cache hits on the direct phase's pages). trace=True adds ~4
-    clock reads per multi-token dispatch — negligible next to a dispatch,
-    and scoped to this engine only (the 8b phase runs untraced)."""
+    prefix-cache hits on the direct phase's pages)."""
     import numpy as np
 
     from polyrl_tpu.rollout.sampling import SamplingParams
@@ -355,7 +362,7 @@ def bench_cb(cfg, params, batch, prompt_len, new_tokens, max_slots=64,
 
     engine = make_cb_engine(cfg, params, prompt_len, new_tokens,
                             max_slots=max_slots, page_size=page_size,
-                            steps_per_dispatch=steps_per_dispatch, trace=True)
+                            steps_per_dispatch=steps_per_dispatch)
     rng = np.random.default_rng(1)
     prompts = [rng.integers(1, cfg.vocab_size, prompt_len).tolist()
                for _ in range(batch)]
@@ -459,7 +466,7 @@ def bench_cb(cfg, params, batch, prompt_len, new_tokens, max_slots=64,
     # shard-to-shard streams over the production fabric) — promoted by
     # assemble_result as extra.transfer_push_streams/push_shard_wall_s
     push_shard = _cb_push_shard_drill(params)
-    trace = {k: round(v, 3) for k, v in sorted(engine.trace_report().items())}
+    trace = engine_phase_trace(engine)
     del engine
     gc.collect()
     return {

@@ -237,9 +237,6 @@ class ObsSection:
     trace: bool = False                   # span tracer on/off
     trace_dir: str = ""                   # spans.jsonl + trace.json dump dir
     trace_buffer: int = 4096              # ring-buffer span capacity
-    # wrap trainer phases in jax.profiler.TraceAnnotation so device traces
-    # (trainer.profile_steps) line up with host spans
-    jax_annotations: bool = False
     # live health plane: the trainer serves GET /statusz (shared schema
     # with the rollout server's route — obs/statusz.py). port 0 = ephemeral
     statusz: bool = False

@@ -445,6 +445,61 @@ def _mlp_block(cfg: ModelConfig, h: jnp.ndarray, lp: dict,
 # -- forward ----------------------------------------------------------------
 
 
+# Scopes of the forward pass (``jax.named_scope``: metadata only, the
+# compiled program is the same with and without them). They are the path
+# by which a device trace tells one layer's parts apart, so every forward
+# path names the same five: ``attn_qkv`` (norm, q/k/v projections, rope),
+# ``attn_core`` (the KV write, the attention, and every gather, convert or
+# reshape between them), ``attn_out``, ``mlp``, ``head`` (final norm and
+# the output matmul). The engine adds ``sample``.
+
+
+def _attn_qkv(cfg, x, lp, cos, sin, lead: tuple):
+    """Pre-attention of one layer: norm, q/k/v projections (bias, qk-norm)
+    and rope. ``x`` [..., d] -> q [*lead, Hq, D], k and v [*lead, Hkv, D]."""
+    hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+    with jax.named_scope("attn_qkv"):
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        q, k, v = mm(h, lp["wq"]), mm(h, lp["wk"]), mm(h, lp["wv"])
+        if cfg.attention_bias:  # Qwen2/2.5 family
+            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+        q = q.reshape(*lead, hq, hd)
+        k = k.reshape(*lead, hkv, hd)
+        v = v.reshape(*lead, hkv, hd)
+        if cfg.use_qk_norm:
+            q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
+            k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
+        return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+
+
+def _attn_out_mlp(cfg, x, attn_out, lp, token_valid):
+    """Post-attention of one layer: output projection and the MLP block,
+    each with its residual. ``attn_out`` [..., Hq·D] -> x [..., d]."""
+    with jax.named_scope("attn_out"):
+        x = x + mm(attn_out, lp["wo"])
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+        return x + _mlp_block(cfg, h, lp, token_valid)
+
+
+def _head(cfg, params, x, logits_for=None):
+    """Final norm and the output matmul; ``logits_for`` [B] unembeds one
+    position a row of ``x`` [B, T, d]."""
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        head = (params["embed"].T if cfg.tie_word_embeddings
+                else params["lm_head"])
+        if logits_for is not None:
+            # unembed only one position per row: prefill needs just the
+            # last real token's logits, and [B, T, V] f32 for a long chunk
+            # is the dominant HBM transient (e.g. 4k x 152k f32 = 2.5 GB
+            # per prompt)
+            x = jnp.take_along_axis(x, logits_for[:, None, None], axis=1)[:, 0]
+            return unembed(x, head, "bd,dv->bv")
+        eq = "btd,dv->btv" if x.ndim == 3 else "sd,dv->sv"
+        return unembed(x, head, eq)
+
+
 def _layer_forward(cfg, x, lp, cos, sin, mask, layer_cache, attn_fn=None,
                    token_valid=None):
     """One decoder layer. layer_cache: None or (k_cache, v_cache) [B, S, Hkv, D]
@@ -452,40 +507,24 @@ def _layer_forward(cfg, x, lp, cos, sin, mask, layer_cache, attn_fn=None,
     ``attn_fn``: optional sequence-parallel attention (Ulysses/ring,
     polyrl_tpu.parallel.sequence) used on the no-cache (training) path."""
     b, t, d = x.shape
-    hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+    q, k, v = _attn_qkv(cfg, x, lp, cos, sin, (b, t))
 
-    h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-    q, k, v = mm(h, lp["wq"]), mm(h, lp["wk"]), mm(h, lp["wv"])
-    if cfg.attention_bias:  # Qwen2/2.5 family
-        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-    q = q.reshape(b, t, hq, hd)
-    k = k.reshape(b, t, hkv, hd)
-    v = v.reshape(b, t, hkv, hd)
-    if cfg.use_qk_norm:
-        q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
-        k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    with jax.named_scope("attn_core"):
+        if layer_cache is not None:
+            k_cache, v_cache, write_idx = layer_cache
+            k_full = jax.lax.dynamic_update_slice(k_cache, k.astype(k_cache.dtype), (0, write_idx, 0, 0))
+            v_full = jax.lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype), (0, write_idx, 0, 0))
+            attn_out = attention(q, k_full, v_full, mask=mask)
+            new_cache = (k_full, v_full)
+        elif attn_fn is not None:
+            attn_out = attn_fn(q, k, v)  # SP impl applies causal+pad internally
+            new_cache = None
+        else:
+            attn_out = attention(q, k, v, mask=mask)
+            new_cache = None
+        attn_out = attn_out.reshape(b, t, -1)
 
-    if layer_cache is not None:
-        k_cache, v_cache, write_idx = layer_cache
-        k_full = jax.lax.dynamic_update_slice(k_cache, k.astype(k_cache.dtype), (0, write_idx, 0, 0))
-        v_full = jax.lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype), (0, write_idx, 0, 0))
-        attn_out = attention(q, k_full, v_full, mask=mask)
-        new_cache = (k_full, v_full)
-    elif attn_fn is not None:
-        attn_out = attn_fn(q, k, v)  # SP impl applies causal+pad internally
-        new_cache = None
-    else:
-        attn_out = attention(q, k, v, mask=mask)
-        new_cache = None
-
-    attn_out = mm(attn_out.reshape(b, t, hq * hd), lp["wo"])
-    x = x + attn_out
-
-    h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-    x = x + _mlp_block(cfg, h, lp, token_valid)
-    return x, new_cache
+    return _attn_out_mlp(cfg, x, attn_out, lp, token_valid), new_cache
 
 
 def forward(
@@ -566,44 +605,24 @@ def forward(
         n_layers = k_cache.shape[0]
         b = x.shape[0]
         t_chunk = x.shape[1]
-        hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
         # chunk validity from the cache-slot mask (the chunk occupies slots
         # [write_idx, write_idx+t)): keeps MoE routing off padded tokens
         chunk_valid = jax.lax.dynamic_slice_in_dim(
             attn_mask, write_idx, t_chunk, axis=1) > 0
         for l in range(n_layers):
             lp = jax.tree_util.tree_map(lambda a: a[l], layers)
-            h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-            q, k, v = mm(h, lp["wq"]), mm(h, lp["wk"]), mm(h, lp["wv"])
-            if cfg.attention_bias:
-                q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-            q = q.reshape(b, t_chunk, hq, hd)
-            k = k.reshape(b, t_chunk, hkv, hd)
-            v = v.reshape(b, t_chunk, hkv, hd)
-            if cfg.use_qk_norm:
-                q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
-                k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-            k_cache = jax.lax.dynamic_update_slice(
-                k_cache, k[None].astype(k_cache.dtype), (l, 0, write_idx, 0, 0))
-            v_cache = jax.lax.dynamic_update_slice(
-                v_cache, v[None].astype(v_cache.dtype), (l, 0, write_idx, 0, 0))
-            attn_out = attention(q, k_cache[l], v_cache[l], mask=mask)
-            x = x + mm(attn_out.reshape(b, t_chunk, hq * hd), lp["wo"])
-            h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-            x = x + _mlp_block(cfg, h, lp, chunk_valid)
+            q, k, v = _attn_qkv(cfg, x, lp, cos, sin, (b, t_chunk))
+            with jax.named_scope("attn_core"):
+                k_cache = jax.lax.dynamic_update_slice(
+                    k_cache, k[None].astype(k_cache.dtype), (l, 0, write_idx, 0, 0))
+                v_cache = jax.lax.dynamic_update_slice(
+                    v_cache, v[None].astype(v_cache.dtype), (l, 0, write_idx, 0, 0))
+                attn_out = attention(q, k_cache[l], v_cache[l], mask=mask)
+                attn_out = attn_out.reshape(b, t_chunk, -1)
+            x = _attn_out_mlp(cfg, x, attn_out, lp, chunk_valid)
         new_cache = (k_cache, v_cache)
 
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    head = params["embed"].T if cfg.tie_word_embeddings else params["lm_head"]
-    if logits_for is not None:
-        # unembed only one position per row: prefill needs just the last
-        # real token's logits, and [B, T, V] f32 for a long chunk is the
-        # dominant HBM transient (e.g. 4k x 152k f32 = 2.5 GB per prompt)
-        x = jnp.take_along_axis(x, logits_for[:, None, None], axis=1)[:, 0]
-        return unembed(x, head, "bd,dv->bv"), new_cache
-    return unembed(x, head, "btd,dv->btv"), new_cache
+    return _head(cfg, params, x, logits_for), new_cache
 
 
 # -- paged KV (continuous batching) -----------------------------------------
@@ -699,7 +718,6 @@ def forward_paged_decode(
     attn_fn = attn_fn or paged_attention
     kv_write_fn = kv_write_fn or paged_kv_write
     s = tokens.shape[0]
-    hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
     page_size = pools[0][0].shape[2]
 
     x = params["embed"][tokens]  # [S, d]
@@ -721,34 +739,22 @@ def forward_paged_decode(
     n_layers = len(k_pools)
     for l in range(n_layers):
         lp = jax.tree_util.tree_map(lambda a: a[l], layers)
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-        q, k, v = mm(h, lp["wq"]), mm(h, lp["wk"]), mm(h, lp["wv"])
-        if cfg.attention_bias:
-            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-        q = q.reshape(s, 1, hq, hd)
-        k = k.reshape(s, 1, hkv, hd)
-        v = v.reshape(s, 1, hkv, hd)
-        if cfg.use_qk_norm:
-            q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
-            k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        # fused K+V Pallas write on TPU (XLA row-scatter elsewhere): the
-        # scatter lowers to a serialized per-row loop on TPU and was the
-        # dominant cost of the whole decode step (2 x n_layers x k fused
-        # steps of S*Hkv-row scatters per dispatch)
-        k_pools[l], v_pools[l] = kv_write_fn(
-            k_pools[l], v_pools[l], write_page, write_off, k[:, 0], v[:, 0])
-        attn_out = attn_fn(q[:, 0], k_pools[l], v_pools[l], page_table,
-                           attn_lens)  # [S, Hq, D]
-        x = x + mm(attn_out.reshape(s, hq * hd), lp["wo"])
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+        q, k, v = _attn_qkv(cfg, x, lp, cos, sin, (s, 1))
+        with jax.named_scope("attn_core"):
+            # fused K+V Pallas write on TPU (XLA row-scatter elsewhere):
+            # the scatter lowers to a serialized per-row loop on TPU and
+            # was the dominant cost of the whole decode step (2 x n_layers
+            # x k fused steps of S*Hkv-row scatters per dispatch)
+            k_pools[l], v_pools[l] = kv_write_fn(
+                k_pools[l], v_pools[l], write_page, write_off, k[:, 0],
+                v[:, 0])
+            attn_out = attn_fn(q[:, 0], k_pools[l], v_pools[l], page_table,
+                               attn_lens)  # [S, Hq, D]
+            attn_out = attn_out.reshape(s, -1)
         # inactive slots route nowhere (their pad rows would otherwise fill
         # the experts real slots route to)
-        x = x + _mlp_block(cfg, h, lp, active)
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    head = params["embed"].T if cfg.tie_word_embeddings else params["lm_head"]
-    return unembed(x, head, "sd,dv->sv"), (tuple(k_pools), tuple(v_pools))
+        x = _attn_out_mlp(cfg, x, attn_out, lp, active)
+    return _head(cfg, params, x), (tuple(k_pools), tuple(v_pools))
 
 
 def prefill_into_pages(
@@ -776,13 +782,14 @@ def prefill_into_pages(
         params, cfg, ids[None], positions, mask, cache=cache, write_idx=0,
         logits_for=jnp.maximum(prompt_len - 1, 0)[None])
 
-    # [L, pb, hkv, hd] → per layer [hkv, n_pg, page, hd] (head-major pools)
-    k_r = k_new[:, 0].reshape(layers, n_pg, page_size, hkv, hd).transpose(0, 3, 1, 2, 4)
-    v_r = v_new[:, 0].reshape(layers, n_pg, page_size, hkv, hd).transpose(0, 3, 1, 2, 4)
-    k_pools = tuple(_scatter_pages_kv(pools[0][l], page_ids, k_r[l])
-                    for l in range(layers))
-    v_pools = tuple(_scatter_pages_kv(pools[1][l], page_ids, v_r[l])
-                    for l in range(layers))
+    with jax.named_scope("attn_core"):
+        # [L, pb, hkv, hd] → per layer [hkv, n_pg, page, hd] (head-major pools)
+        k_r = k_new[:, 0].reshape(layers, n_pg, page_size, hkv, hd).transpose(0, 3, 1, 2, 4)
+        v_r = v_new[:, 0].reshape(layers, n_pg, page_size, hkv, hd).transpose(0, 3, 1, 2, 4)
+        k_pools = tuple(_scatter_pages_kv(pools[0][l], page_ids, k_r[l])
+                        for l in range(layers))
+        v_pools = tuple(_scatter_pages_kv(pools[1][l], page_ids, v_r[l])
+                        for l in range(layers))
     return (k_pools, v_pools), last_logits[0]
 
 
@@ -813,14 +820,15 @@ def prefill_batch_into_pages(
         params, cfg, ids, positions, mask, cache=cache, write_idx=0,
         logits_for=jnp.maximum(prompt_lens - 1, 0))
 
-    # [L, B, pb, hkv, hd] → per layer [hkv, B·n_pg, page, hd]
-    k_r = k_new.reshape(layers, b * n_pg, page_size, hkv, hd).transpose(0, 3, 1, 2, 4)
-    v_r = v_new.reshape(layers, b * n_pg, page_size, hkv, hd).transpose(0, 3, 1, 2, 4)
-    flat_pages = page_ids.reshape(-1)
-    k_pools = tuple(_scatter_pages_kv(pools[0][l], flat_pages, k_r[l])
-                    for l in range(layers))
-    v_pools = tuple(_scatter_pages_kv(pools[1][l], flat_pages, v_r[l])
-                    for l in range(layers))
+    with jax.named_scope("attn_core"):
+        # [L, B, pb, hkv, hd] → per layer [hkv, B·n_pg, page, hd]
+        k_r = k_new.reshape(layers, b * n_pg, page_size, hkv, hd).transpose(0, 3, 1, 2, 4)
+        v_r = v_new.reshape(layers, b * n_pg, page_size, hkv, hd).transpose(0, 3, 1, 2, 4)
+        flat_pages = page_ids.reshape(-1)
+        k_pools = tuple(_scatter_pages_kv(pools[0][l], flat_pages, k_r[l])
+                        for l in range(layers))
+        v_pools = tuple(_scatter_pages_kv(pools[1][l], flat_pages, v_r[l])
+                        for l in range(layers))
     return (k_pools, v_pools), last_logits
 
 
@@ -854,17 +862,18 @@ def prefill_suffix_into_pages(
     # dense scratch cache: [prefix_cap | suffix chunk]
     s_total = prefix_cap + pb
     cache = make_cache(cfg, 1, s_total, dtype=pools[0][0].dtype)
-    # per layer [hkv, n_pre, page, hd] → dense [L, prefix_cap, hkv, hd]
-    k_pre = jnp.stack([pools[0][l][:, prefix_page_ids] for l in range(layers)])
-    v_pre = jnp.stack([pools[1][l][:, prefix_page_ids] for l in range(layers)])
-    k_pre = k_pre.transpose(0, 2, 3, 1, 4)
-    v_pre = v_pre.transpose(0, 2, 3, 1, 4)
-    cache = (
-        cache[0].at[:, 0, :prefix_cap].set(
-            k_pre.reshape(layers, prefix_cap, hkv, hd)),
-        cache[1].at[:, 0, :prefix_cap].set(
-            v_pre.reshape(layers, prefix_cap, hkv, hd)),
-    )
+    with jax.named_scope("attn_core"):
+        # per layer [hkv, n_pre, page, hd] → dense [L, prefix_cap, hkv, hd]
+        k_pre = jnp.stack([pools[0][l][:, prefix_page_ids] for l in range(layers)])
+        v_pre = jnp.stack([pools[1][l][:, prefix_page_ids] for l in range(layers)])
+        k_pre = k_pre.transpose(0, 2, 3, 1, 4)
+        v_pre = v_pre.transpose(0, 2, 3, 1, 4)
+        cache = (
+            cache[0].at[:, 0, :prefix_cap].set(
+                k_pre.reshape(layers, prefix_cap, hkv, hd)),
+            cache[1].at[:, 0, :prefix_cap].set(
+                v_pre.reshape(layers, prefix_cap, hkv, hd)),
+        )
     # slot layout: prefix occupies [0, prefix_len); the chunk writes at
     # write_idx=prefix_len so slot order stays temporal (padded prefix tail
     # slots get overwritten by the chunk — they were masked anyway)
@@ -877,14 +886,15 @@ def prefill_suffix_into_pages(
         cache=cache, write_idx=prefix_len,
         logits_for=jnp.maximum(suffix_len - 1, 0)[None])
 
-    k_sfx = jax.lax.dynamic_slice_in_dim(k_all[:, 0], prefix_len, pb, axis=1)
-    v_sfx = jax.lax.dynamic_slice_in_dim(v_all[:, 0], prefix_len, pb, axis=1)
-    k_r = k_sfx.reshape(layers, n_pg, page_size, hkv, hd).transpose(0, 3, 1, 2, 4)
-    v_r = v_sfx.reshape(layers, n_pg, page_size, hkv, hd).transpose(0, 3, 1, 2, 4)
-    k_pools = tuple(_scatter_pages_kv(pools[0][l], page_ids, k_r[l])
-                    for l in range(layers))
-    v_pools = tuple(_scatter_pages_kv(pools[1][l], page_ids, v_r[l])
-                    for l in range(layers))
+    with jax.named_scope("attn_core"):
+        k_sfx = jax.lax.dynamic_slice_in_dim(k_all[:, 0], prefix_len, pb, axis=1)
+        v_sfx = jax.lax.dynamic_slice_in_dim(v_all[:, 0], prefix_len, pb, axis=1)
+        k_r = k_sfx.reshape(layers, n_pg, page_size, hkv, hd).transpose(0, 3, 1, 2, 4)
+        v_r = v_sfx.reshape(layers, n_pg, page_size, hkv, hd).transpose(0, 3, 1, 2, 4)
+        k_pools = tuple(_scatter_pages_kv(pools[0][l], page_ids, k_r[l])
+                        for l in range(layers))
+        v_pools = tuple(_scatter_pages_kv(pools[1][l], page_ids, v_r[l])
+                        for l in range(layers))
     return (k_pools, v_pools), last_logits[0]
 
 
@@ -919,17 +929,18 @@ def prefill_suffix_batch_into_pages(
     # dense scratch cache per row: [prefix_cap | suffix chunk]
     s_total = prefix_cap + pb
     cache = make_cache(cfg, b, s_total, dtype=pools[0][0].dtype)
-    # per layer [hkv, B, n_pre, page, hd] → dense [L, B, prefix_cap, hkv, hd]
-    k_pre = jnp.stack([pools[0][l][:, prefix_page_ids] for l in range(layers)])
-    v_pre = jnp.stack([pools[1][l][:, prefix_page_ids] for l in range(layers)])
-    k_pre = k_pre.transpose(0, 2, 3, 4, 1, 5)
-    v_pre = v_pre.transpose(0, 2, 3, 4, 1, 5)
-    cache = (
-        cache[0].at[:, :, :prefix_cap].set(
-            k_pre.reshape(layers, b, prefix_cap, hkv, hd)),
-        cache[1].at[:, :, :prefix_cap].set(
-            v_pre.reshape(layers, b, prefix_cap, hkv, hd)),
-    )
+    with jax.named_scope("attn_core"):
+        # per layer [hkv, B, n_pre, page, hd] → dense [L, B, prefix_cap, hkv, hd]
+        k_pre = jnp.stack([pools[0][l][:, prefix_page_ids] for l in range(layers)])
+        v_pre = jnp.stack([pools[1][l][:, prefix_page_ids] for l in range(layers)])
+        k_pre = k_pre.transpose(0, 2, 3, 4, 1, 5)
+        v_pre = v_pre.transpose(0, 2, 3, 4, 1, 5)
+        cache = (
+            cache[0].at[:, :, :prefix_cap].set(
+                k_pre.reshape(layers, b, prefix_cap, hkv, hd)),
+            cache[1].at[:, :, :prefix_cap].set(
+                v_pre.reshape(layers, b, prefix_cap, hkv, hd)),
+        )
     positions = jnp.broadcast_to(
         prefix_len + jnp.arange(pb, dtype=jnp.int32), (b, pb))
     slot_idx = jnp.arange(s_total)
@@ -941,16 +952,17 @@ def prefill_suffix_batch_into_pages(
         cache=cache, write_idx=prefix_len,
         logits_for=jnp.maximum(suffix_lens - 1, 0))
 
-    k_sfx = jax.lax.dynamic_slice_in_dim(k_all, prefix_len, pb, axis=2)
-    v_sfx = jax.lax.dynamic_slice_in_dim(v_all, prefix_len, pb, axis=2)
-    # [L, B, pb, hkv, hd] → per layer [hkv, B·n_pg, page, hd]
-    k_r = k_sfx.reshape(layers, b * n_pg, page_size, hkv, hd).transpose(0, 3, 1, 2, 4)
-    v_r = v_sfx.reshape(layers, b * n_pg, page_size, hkv, hd).transpose(0, 3, 1, 2, 4)
-    flat_pages = page_ids.reshape(-1)
-    k_pools = tuple(_scatter_pages_kv(pools[0][l], flat_pages, k_r[l])
-                    for l in range(layers))
-    v_pools = tuple(_scatter_pages_kv(pools[1][l], flat_pages, v_r[l])
-                    for l in range(layers))
+    with jax.named_scope("attn_core"):
+        k_sfx = jax.lax.dynamic_slice_in_dim(k_all, prefix_len, pb, axis=2)
+        v_sfx = jax.lax.dynamic_slice_in_dim(v_all, prefix_len, pb, axis=2)
+        # [L, B, pb, hkv, hd] → per layer [hkv, B·n_pg, page, hd]
+        k_r = k_sfx.reshape(layers, b * n_pg, page_size, hkv, hd).transpose(0, 3, 1, 2, 4)
+        v_r = v_sfx.reshape(layers, b * n_pg, page_size, hkv, hd).transpose(0, 3, 1, 2, 4)
+        flat_pages = page_ids.reshape(-1)
+        k_pools = tuple(_scatter_pages_kv(pools[0][l], flat_pages, k_r[l])
+                        for l in range(layers))
+        v_pools = tuple(_scatter_pages_kv(pools[1][l], flat_pages, v_r[l])
+                        for l in range(layers))
     return (k_pools, v_pools), last_logits
 
 

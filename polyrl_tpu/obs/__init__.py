@@ -36,8 +36,6 @@ when tracing is disabled, so hot paths can call into it unconditionally.
 
 from __future__ import annotations
 
-import contextlib
-
 from polyrl_tpu.obs.critical_path import (SEGMENTS,  # noqa: F401
                                           CriticalPath,
                                           extract_critical_path)
@@ -56,17 +54,12 @@ from polyrl_tpu.obs.timeseries import (TimeSeriesStore,  # noqa: F401
                                        least_squares_slope)
 from polyrl_tpu.obs.trace import Tracer, get_tracer  # noqa: F401
 
-_jax_annotations = False
-
-
 def configure(trace: bool | None = None, max_spans: int | None = None,
               out_dir: str | None = None,
-              jax_annotations: bool | None = None,
               reset: bool = False) -> Tracer:
-    """Configure the process-global tracer (and the jax-annotation toggle).
-    ``None`` leaves a setting unchanged; ``reset`` clears the span ring
-    buffer and the histogram registry (test isolation / fresh runs)."""
-    global _jax_annotations
+    """Configure the process-global tracer. ``None`` leaves a setting
+    unchanged; ``reset`` clears the span ring buffer and the histogram
+    registry (test isolation / fresh runs)."""
     tracer = get_tracer()
     if trace is not None:
         tracer.enabled = trace
@@ -74,8 +67,6 @@ def configure(trace: bool | None = None, max_spans: int | None = None,
         tracer.set_capacity(max_spans)
     if out_dir is not None:
         tracer.out_dir = out_dir or None
-    if jax_annotations is not None:
-        _jax_annotations = jax_annotations
     if reset:
         tracer.clear()
         drain_histograms()
@@ -92,15 +83,24 @@ def trace_headers() -> dict[str, str]:
     return get_tracer().headers()
 
 
-def phase_annotation(name: str):
-    """Optional ``jax.profiler.TraceAnnotation`` so device traces line up
-    with host spans (configure(jax_annotations=True)); nullcontext
-    otherwise — jax is only imported when the feature is on."""
-    if not _jax_annotations:
-        return contextlib.nullcontext()
-    try:
-        import jax
+def named_program(name: str, fn):
+    """``fn`` under the function name ``name``. ``jax.jit`` names a program
+    after its function (``jit_<name>``, the name on a device trace's
+    ``XLA Modules`` line); a ``partial`` or a lambda has none of its own,
+    and two sites that jit one function would share one."""
 
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001 — annotation is best-effort
-        return contextlib.nullcontext()
+    def call(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    call.__name__ = call.__qualname__ = name
+    return call
+
+
+def phase_annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation``: the phase as a host span of a
+    device trace, on the device's clock. No knob selects it: outside a
+    profiler session a TraceMe is a flag test. jax is imported here and
+    not with the package, which stays import-light."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(name)

@@ -39,27 +39,47 @@ Stacks are thread-local, so the fetcher thread (or a unit test driving
 engine internals directly) can enter phases without corrupting the loop
 thread's iteration; cumulative totals fold under one lock.
 
-The windowed device-vs-host split (``device_frac`` /
-``host_overhead_frac`` / ``accounting_frac`` / ``idle_frac``) is computed
-over a two-bucket flip window (~``window_s`` of recent loop wall) so a
-long-lived engine reports CURRENT behaviour, not a run-lifetime average:
+The windowed split (``device_frac`` / ``host_overhead_frac`` /
+``accounting_frac`` / ``idle_frac``) is computed over a two-bucket flip
+window (~``window_s`` of recent loop wall) so a long-lived engine reports
+CURRENT behaviour, not a run-lifetime average:
 
-- ``device_frac``          = (prefill_dispatch + decode_dispatch_device +
-  sample_fetch) / wall — host wall spent dispatching to or waiting on the
-  device (the utilization ceiling the disaggregation work steers on);
+- ``device_frac``          = seconds with device work outstanding / wall,
+  from the completion stamps below (NOT host wall spent in dispatch and
+  fetch: a loop blocked on a fetch says nothing about the device);
 - ``accounting_frac``      = (accounting + spill_sweep) / wall;
-- ``host_overhead_frac``   = 1 − device_frac − idle_frac — ALL host-side
-  work including the residual, so the three fracs + idle partition 1.
+- ``host_overhead_frac``   = 1 − (sample_fetch + idle) / wall — what the
+  loop thread did between its waits, the residual included;
+- ``idle_frac``            = idle / wall.
 
-Per-dispatch spans for the dispatch phases are emitted into the process
-tracer ring (obs/trace.py) when tracing is enabled, trace_id-joined with
-whatever context the serving layer adopted — ``tools/trace2perfetto.py``
-renders the engine-loop track beside the trainer's spans.
+One seam, one clock. :meth:`phase` is the only place an engine phase is
+timed, and every phase also opens a ``jax.profiler.TraceAnnotation``
+(``engine/<phase>``), always: outside a profiler session a TraceMe is a
+flag test, inside one the phase lies on the device trace's clock beside
+the device's own events. The fetcher's blocking ``device_get`` is
+annotated the same way on its own thread (:meth:`fetch`, ``engine/fetch``)
+and counted outside the loop's partition. The dispatch phases also emit
+spans into the process tracer ring (obs/trace.py) when that is enabled;
+the ring is for cross-process request traces (its wall/monotonic anchor
+joins trainer, manager and engine), the device trace is where host phases
+meet device time.
 
-The legacy ``_trace``/``_tmark`` seam (POLYRL_CB_TRACE) is absorbed here:
-:meth:`mark_legacy` keeps the cumulative ``{key: seconds, n_<key>}``
-counters ``/metrics`` has always rendered, owned by the profiler instead
-of a parallel dict.
+Completion-stamp counters (cumulative, monotone, flat in ``server_info``;
+two samples give a rate over any window, with no profiler session):
+
+- ``decode_dispatches`` / ``decode_steps_done`` — fused decode dispatches
+  enqueued, and fused steps whose results have landed on the host;
+- ``device_busy_s`` — seconds with device work outstanding: an interval
+  opens when a dispatch is enqueued with nothing outstanding and closes
+  when a landed result leaves nothing newer outstanding. Seconds are added
+  only at a landing (``device_busy_at_s`` is that landing's clock), so
+  ``delta(device_busy_s) / delta(decode_steps_done)`` between any two
+  samples carries no dispatch quantum;
+- ``loop_wall_s`` / ``loop_host_s`` — loop wall, and loop wall less
+  ``idle`` and ``sample_fetch`` self-time;
+- ``programs_built`` / ``last_program_built`` — jit-cache misses of the
+  engine's program tables (:meth:`on_build`), the last 32 in
+  ``snapshot()``.
 """
 
 from __future__ import annotations
@@ -75,15 +95,17 @@ from polyrl_tpu.obs.trace import get_tracer
 PHASES = ("collect_wave", "restore", "prefill_dispatch",
           "decode_dispatch_device", "sample_fetch", "emit", "accounting",
           "spill_sweep", "idle", "other")
-# host wall spent dispatching to / waiting on the device
-DEVICE_PHASES = frozenset(
-    ("prefill_dispatch", "decode_dispatch_device", "sample_fetch"))
+# the loop thread's waits: loop wall less these is what the host did
+WAIT_PHASES = frozenset(("sample_fetch", "idle"))
 # the bookkeeping overhead the regression budget pins
 ACCOUNTING_PHASES = frozenset(("accounting", "spill_sweep"))
 # phases worth a tracer span each occurrence (dispatch-scale, not µs-scale)
 SPAN_PHASES = frozenset(
     ("prefill_dispatch", "decode_dispatch_device", "sample_fetch",
      "restore"))
+# dispatch kinds whose results are fused decode steps
+DECODE_KINDS = frozenset(("step", "spec"))
+MAX_BUILDS_KEPT = 32
 
 
 class EngineLoopProfiler:
@@ -94,23 +116,40 @@ class EngineLoopProfiler:
 
     def __init__(self, window_s: float = 20.0, clock=time.monotonic,
                  tracer=None):
+        import jax
+
         self._clock = clock
         self._tracer = tracer  # None → resolve the process tracer lazily
+        self._annotate = jax.profiler.TraceAnnotation
         self._lock = threading.Lock()
         self._tls = threading.local()
         self.window_s = float(window_s)
         self.iters = 0
         self.wall_s = 0.0
+        self.loop_host_s = 0.0
         self.totals = {p: 0.0 for p in PHASES}
         self.counts = {p: 0 for p in PHASES}
         self.hists = {p: Histogram() for p in PHASES if p != "other"}
-        # two-bucket flip window: [wall, device, accounting, idle] each;
-        # readers sum both buckets → ~window_s/2..window_s of loop wall
-        self._win_cur = [0.0, 0.0, 0.0, 0.0]
-        self._win_prev = [0.0, 0.0, 0.0, 0.0]
-        # legacy POLYRL_CB_TRACE counters (cumulative seconds + n_ counts);
-        # the fetcher thread marks "fetch" concurrently with loop marks
-        self._legacy: dict[str, float] = collections.defaultdict(float)
+        # two-bucket flip window: [wall, device busy, accounting, idle,
+        # waits] each; readers sum both buckets → ~window_s/2..window_s of
+        # loop wall
+        self._win_cur = [0.0] * 5
+        self._win_prev = [0.0] * 5
+        self._win_busy_mark = 0.0  # device_busy_s at the last iteration close
+        # completion stamps: dispatches whose results will land, oldest
+        # first, as their fused decode steps (0 for a prefill)
+        self._landing: collections.deque = collections.deque()
+        self._tail_unlanded = False  # dispatched after them, lands nothing
+        self._busy_from: float | None = None  # busy not yet counted, since
+        self.device_busy_s = 0.0
+        self.device_busy_at_s = 0.0
+        self.decode_dispatches = 0
+        self.decode_steps_done = 0
+        self.fetch_s = 0.0
+        self.fetch_n = 0
+        self.programs_built = 0
+        self.builds: collections.deque = collections.deque(
+            maxlen=MAX_BUILDS_KEPT)
 
     # -- thread-local attribution state --------------------------------------
 
@@ -147,7 +186,8 @@ class EngineLoopProfiler:
                 span_cm = tracer.span("engine/" + name)
                 span_cm.__enter__()
         try:
-            yield
+            with self._annotate("engine/" + name):
+                yield
         finally:
             if span_cm is not None:
                 span_cm.__exit__(None, None, None)
@@ -160,6 +200,21 @@ class EngineLoopProfiler:
                 self.totals[name] += self_s
                 self.counts[name] += 1
                 self.hists[name].observe(self_s)
+
+    @contextlib.contextmanager
+    def fetch(self):
+        """The fetcher thread's blocking ``device_get``: on the device
+        trace as ``engine/fetch``, counted beside the loop's partition and
+        not in it (the loop's own wait for it is ``sample_fetch``)."""
+        t0 = self._clock()
+        try:
+            with self._annotate("engine/fetch"):
+                yield
+        finally:
+            dt = self._clock() - t0
+            with self._lock:
+                self.fetch_s += dt
+                self.fetch_n += 1
 
     @contextlib.contextmanager
     def iteration(self):
@@ -182,32 +237,81 @@ class EngineLoopProfiler:
             wall = now - t0
             attributed = sum(phases.values())
             other = max(0.0, wall - attributed)
-            device = sum(phases.get(p, 0.0) for p in DEVICE_PHASES)
+            waits = sum(phases.get(p, 0.0) for p in WAIT_PHASES)
             acct = sum(phases.get(p, 0.0) for p in ACCOUNTING_PHASES)
-            idle = phases.get("idle", 0.0)
             with self._lock:
                 self.iters += 1
                 self.wall_s += wall
+                self.loop_host_s += max(0.0, wall - waits)
                 self.totals["other"] += other
                 cur = self._win_cur
                 cur[0] += wall
-                cur[1] += device
+                cur[1] += self.device_busy_s - self._win_busy_mark
                 cur[2] += acct
-                cur[3] += idle
+                cur[3] += phases.get("idle", 0.0)
+                cur[4] += waits
+                self._win_busy_mark = self.device_busy_s
                 if cur[0] >= self.window_s / 2.0:
                     self._win_prev = cur
-                    self._win_cur = [0.0, 0.0, 0.0, 0.0]
+                    self._win_cur = [0.0] * 5
 
-    # -- legacy POLYRL_CB_TRACE counters -------------------------------------
+    # -- completion stamps ----------------------------------------------------
 
-    def mark_legacy(self, key: str, dt: float) -> None:
+    def on_dispatch(self, kind: str, steps: int = 0,
+                    lands: bool = True) -> None:
+        """A dispatch was just enqueued on the device; ``steps``: the
+        decode steps it fuses. ``lands`` False: it returns nothing the
+        host fetches (a chunked prefill's mid-chunk),
+        so a later dispatch's landing stands for it."""
+        now = self._clock()
         with self._lock:
-            self._legacy[key] += dt
-            self._legacy["n_" + key] += 1
+            if self._busy_from is None:
+                self._busy_from = now
+            if lands:
+                self._landing.append(steps)
+                self._tail_unlanded = False
+            else:
+                self._tail_unlanded = True
+            if kind in DECODE_KINDS:
+                self.decode_dispatches += 1
 
-    def legacy_report(self) -> dict:
+    def on_landed(self, n: int) -> None:
+        """The oldest ``n`` dispatches' results are on the host: the
+        device has finished them and everything enqueued before them."""
+        now = self._clock()
         with self._lock:
-            return dict(self._legacy)
+            for _ in range(min(n, len(self._landing))):
+                self.decode_steps_done += self._landing.popleft()
+            self._count_busy(now, bool(self._landing)
+                             or self._tail_unlanded)
+
+    def drop_outstanding(self, tail_only: bool = False) -> None:
+        """Dispatches were abandoned and will never land (an engine reset
+        or stop; ``tail_only``: an aborted chunked prefill's mid-chunks):
+        count them done as of now."""
+        now = self._clock()
+        with self._lock:
+            self._tail_unlanded = False
+            if not tail_only:
+                self._landing.clear()
+            if not self._landing:
+                self._count_busy(now, False)
+
+    def _count_busy(self, now: float, still_busy: bool) -> None:
+        if self._busy_from is not None:
+            self.device_busy_s += max(0.0, now - self._busy_from)
+            self.device_busy_at_s = now
+        self._busy_from = now if still_busy else None
+
+    # -- program builds -------------------------------------------------------
+
+    def on_build(self, kind: str, key, seconds: float) -> None:
+        """A miss of the engine's program tables, after the program's first
+        call returned (trace, lower, compile or cache read, enqueue)."""
+        with self._lock:
+            self.programs_built += 1
+            self.builds.append({"kind": kind, "key": str(key),
+                                "seconds": round(seconds, 4)})
 
     # -- export ---------------------------------------------------------------
 
@@ -221,30 +325,42 @@ class EngineLoopProfiler:
                 return 1.0
             return (self.wall_s - self.totals["other"]) / self.wall_s
 
-    def _window(self) -> tuple[float, float, float, float]:
-        cur, prev = self._win_cur, self._win_prev
-        return tuple(cur[i] + prev[i] for i in range(4))
-
     def window_fracs(self) -> dict:
-        """The windowed device-vs-host split over ~window_s of recent
-        loop wall; zeros before the first iteration closes."""
+        """The windowed split over ~window_s of recent loop wall; zeros
+        before the first iteration closes."""
         with self._lock:
-            wall, device, acct, idle = self._window()
+            wall, busy, acct, idle, waits = (
+                c + p for c, p in zip(self._win_cur, self._win_prev))
         if wall <= 0.0:
             return {"wall_s": 0.0, "device_frac": 0.0,
                     "host_overhead_frac": 0.0, "accounting_frac": 0.0,
                     "idle_frac": 0.0}
-        device_f = device / wall
-        idle_f = idle / wall
         return {
             "wall_s": wall,
-            "device_frac": device_f,
-            # everything host-side that is neither device wait nor idle —
-            # includes the unattributed residual, so the three partition 1
-            "host_overhead_frac": max(0.0, 1.0 - device_f - idle_f),
+            # busy seconds are booked at landings, so a window can be
+            # credited a sliver that ran before it opened
+            "device_frac": min(1.0, busy / wall),
+            "host_overhead_frac": max(0.0, 1.0 - waits / wall),
             "accounting_frac": acct / wall,
-            "idle_frac": idle_f,
+            "idle_frac": idle / wall,
         }
+
+    def counters(self) -> dict:
+        """The cumulative counters, one consistent copy."""
+        with self._lock:
+            out = {
+                "decode_dispatches": self.decode_dispatches,
+                "decode_steps_done": self.decode_steps_done,
+                "device_busy_s": round(self.device_busy_s, 6),
+                "device_busy_at_s": round(self.device_busy_at_s, 6),
+                "loop_wall_s": round(self.wall_s, 6),
+                "loop_host_s": round(self.loop_host_s, 6),
+                "programs_built": self.programs_built,
+            }
+            if self.builds:
+                last = self.builds[-1]
+                out["last_program_built"] = f"{last['kind']} {last['key']}"
+        return out
 
     def server_info_fields(self) -> dict:
         """Flat keys merged into ``server_info`` (no ``/`` — the C++
@@ -256,6 +372,7 @@ class EngineLoopProfiler:
             "host_overhead_frac": round(w["host_overhead_frac"], 6),
             "accounting_frac": round(w["accounting_frac"], 6),
             "loop_attributed_frac": round(self.attributed_frac(), 6),
+            **self.counters(),
         }
 
     def snapshot(self) -> dict:
@@ -266,6 +383,8 @@ class EngineLoopProfiler:
             counts = dict(self.counts)
             iters = self.iters
             wall = self.wall_s
+            fetch = {"seconds": round(self.fetch_s, 4), "n": self.fetch_n}
+            builds = list(self.builds)
             hists = {p: {
                 "p50": h.percentile(50.0), "p95": h.percentile(95.0),
                 "p99": h.percentile(99.0),
@@ -284,6 +403,9 @@ class EngineLoopProfiler:
             "phase_n": {p: counts[p] for p in PHASES if counts[p]},
             "window": {k: round(v, 4)
                        for k, v in self.window_fracs().items()},
+            "counters": self.counters(),
+            "fetch": fetch,
+            "builds": builds,
         }
         if hists:
             out["latency"] = hists

@@ -35,8 +35,11 @@ version-history table in ARCHITECTURE.md "Observability"):
   (obs/engine_profile.py): exhaustive per-iteration phase attribution of
   the engine loop's wall (``attributed_frac`` pinned to 1.0,
   goodput-ledger style), per-phase log2 latency summaries, and the
-  windowed device-vs-host split (``device_frac`` /
-  ``host_overhead_frac`` / ``accounting_frac`` / ``idle_frac``).
+  windowed device-vs-host split (``device_frac``, the share of wall
+  with device work outstanding by completion stamps /
+  ``host_overhead_frac`` / ``accounting_frac`` / ``idle_frac``), the
+  cumulative ``counters`` (``CUMULATIVE_INFO_KEYS`` below) and the last
+  32 ``builds`` (programs the engine's jit tables missed).
   ``{"enabled": false}`` when ``rollout.loop_profile`` is off or the
   engine has no loop profiler; the trainer's is the fleet view keyed by
   instance.
@@ -95,6 +98,14 @@ _HIST_SUFFIXES = ("p50", "p95", "p99", "max", "mean", "count")
 
 # every key the schema guarantees on EVERY snapshot, both planes — the
 # conformance contract consumers (and the conformance test) rely on
+# server_info keys that only ever grow: the engine profiler's
+# completion-stamp counters and the server's stream counters. They are
+# counters (not gauges) in the rollout plane's snapshot and at /metrics,
+# and tools/check_statusz_docs.py holds ARCHITECTURE.md to naming each.
+CUMULATIVE_INFO_KEYS = frozenset((
+    "decode_dispatches", "decode_steps_done", "device_busy_s", "loop_wall_s",
+    "loop_host_s", "programs_built", "stream_chunks", "stream_lag_s"))
+
 REQUIRED_SECTIONS = ("schema", "role", "pid", "time_unix_s", "uptime_s",
                      "step", "goodput", "histograms", "counters", "gauges",
                      "queues", "weights", "pool", "engine", "training",

@@ -86,11 +86,14 @@ def flash_attention_train(q, k, v, attn_mask, *, causal: bool = True,
         block_k_dkv=blk, block_q_dkv=blk,
         block_k_major_dq=blk, block_k_dq=blk, block_q_dq=blk,
     )
-    out = flash_attention(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3),
-        segment_ids=SegmentIds(q=ids, kv=ids),
-        causal=causal, sm_scale=d ** -0.5, block_sizes=bs)
+    # the library owns the pallas_calls, forward and backward (no ``name=``
+    # to give): the scope is their stable name on a device trace
+    with jax.named_scope("flash_attention"):
+        out = flash_attention(
+            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3),
+            segment_ids=SegmentIds(q=ids, kv=ids),
+            causal=causal, sm_scale=d ** -0.5, block_sizes=bs)
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 

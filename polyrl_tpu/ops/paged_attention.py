@@ -201,6 +201,7 @@ def paged_attention_pallas(
         out_shape=jax.ShapeDtypeStruct((s, hkv, rep, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="paged_attention",
     )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32), qr, k_pool, v_pool)
     return out.reshape(s, hq, d)
 
@@ -222,11 +223,14 @@ def paged_attention_lib(q, k_pool, v_pool, page_table, seq_lens, scale=None):
     ppcb = min(8, p)
     while p % ppcb:
         ppcb -= 1
-    return _pa(
-        (q * scale).astype(q.dtype), k_pool, v_pool,
-        jnp.maximum(seq_lens.astype(jnp.int32), 1),
-        page_table.astype(jnp.int32),
-        pages_per_compute_block=ppcb)
+    # the library owns the pallas_call (no ``name=`` to give): the scope
+    # is this kernel's stable name on a device trace
+    with jax.named_scope("paged_attention_lib"):
+        return _pa(
+            (q * scale).astype(q.dtype), k_pool, v_pool,
+            jnp.maximum(seq_lens.astype(jnp.int32), 1),
+            page_table.astype(jnp.int32),
+            pages_per_compute_block=ppcb)
 
 
 # -- shared-prefix grouped decode attention ---------------------------------
@@ -536,6 +540,7 @@ def grouped_paged_attention_pallas(
                    jax.ShapeDtypeStruct((ng, hkv, gr_pad, 128), jnp.float32)],
         grid_spec=grid1,
         interpret=interpret,
+        name="grouped_prefix",
     )(group_prefix_pages.astype(jnp.int32),
       group_prefix_lens.astype(jnp.int32), qg, k_pool, v_pool)
 
@@ -595,6 +600,7 @@ def grouped_paged_attention_pallas(
         out_shape=jax.ShapeDtypeStruct((s, hkv, rep, d), q.dtype),
         grid_spec=grid2,
         interpret=interpret,
+        name="grouped_suffix",
     )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32), slot_npre,
       qr, m1s, l1s, acc1s, k_pool, v_pool)
     return out.reshape(s, hq, d)
@@ -767,6 +773,7 @@ def paged_kv_write_pallas(k_pool, v_pool, write_page, write_off, k_upd,
         # 2=owner 3=k_pool 4=v_pool (aliased onto outputs 0/1) 5/6=updates
         input_output_aliases={3: 0, 4: 1},
         interpret=interpret,
+        name="paged_kv_write",
         # chunks run in order: a window shared across two chunks must be
         # written back by the first before the second reads it
         compiler_params=pltpu.CompilerParams(

@@ -44,6 +44,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import functools
 import logging
 import os
 import queue
@@ -74,6 +75,20 @@ log = logging.getLogger(__name__)
 STREAM_END = object()  # terminal marker on every request's output queue
 
 MAX_STOP_TOKENS = 8
+
+
+class StreamLine(dict):
+    """One line of a request's stream as the engine queues it. ``t_put``,
+    the engine's monotonic clock at the put, rides the object and not the
+    mapping: the server reads it for ``stream_lag_s``, and what it
+    serializes to the wire is the mapping alone."""
+
+    __slots__ = ("t_put",)
+
+    def __init__(self, fields: dict):
+        super().__init__(fields)
+        self.t_put = time.monotonic()
+
 
 # reusable no-op phase context (contextlib.nullcontext is reentrant):
 # _phase() hands this out when the loop profiler is off so the hot path
@@ -209,7 +224,6 @@ class CBEngine:
         pipeline_depth: int | None = None,
         mesh=None,
         prefill_chunk: int = 0,
-        trace: bool | None = None,
         spec_tokens: int = 0,
         spec_rounds: int = 2,
         salvage_partials: bool = True,
@@ -494,38 +508,28 @@ class CBEngine:
         # have emitted (active_slots * rounds * (spec_tokens+1) each) —
         # spec_emitted / this ratio is the acceptance-rate gauge
         self.spec_token_ceiling = 0
-        # POLYRL_CB_TRACE=1: cumulative wall per engine phase (dispatch vs
-        # fetch vs prefill vs host bookkeeping) — the serving-path analogue
-        # of the trainer's marked_timer spans (SURVEY.md §5.1)
-        if trace is None:  # explicit arg wins; env is the ops-facing toggle
-            trace = bool(os.environ.get("POLYRL_CB_TRACE"))
-        self._trace_enabled = bool(trace)
-        # engine-loop profiler (obs/engine_profile.py): exhaustive phase
-        # attribution of every loop iteration, the windowed device-vs-host
-        # split, and the accounting-overhead gauge. When on it ABSORBS the
-        # legacy trace seam (one accounting path: _tmark feeds the
-        # profiler's legacy counters). rollout.loop_profile=False restores
-        # the pre-profiler loop bit for bit — the profiler never touches
-        # RNG, device state or scheduling, only clocks around them.
+        # engine-loop profiler (obs/engine_profile.py): the one seam that
+        # times an engine phase (each also a TraceAnnotation on the device
+        # trace's clock), the completion-stamp counters, the windowed
+        # device/host gauges. rollout.loop_profile=False leaves the loop
+        # without it: the profiler never touches RNG, device state or
+        # scheduling, only clocks around them.
         self.profiler = EngineLoopProfiler() if loop_profile else None
-        self._trace: dict | None = (
-            collections.defaultdict(float)
-            if trace and self.profiler is None else None)
-        # the fetcher thread marks "fetch"; += on a shared dict is a
-        # non-atomic read-modify-write against the loop thread's marks
-        self._trace_lock = threading.Lock()
-
-    def trace_report(self) -> dict:
-        """Cumulative seconds per phase (POLYRL_CB_TRACE=1), else empty."""
-        if self.profiler is not None:
-            return self.profiler.legacy_report() if self._trace_enabled \
-                else {}
-        return dict(self._trace or {})
 
     def _phase(self, name: str):
         """Profiler phase context for ``name`` (no-op when off)."""
         prof = self.profiler
         return prof.phase(name) if prof is not None else _NULL_PHASE
+
+    def _fetch_scope(self):
+        """The fetcher thread's blocking transfer (``engine/fetch``)."""
+        prof = self.profiler
+        return prof.fetch() if prof is not None else _NULL_PHASE
+
+    def _landed(self, n: int) -> None:
+        """The oldest ``n`` queued dispatch outputs reached the host."""
+        if self.profiler is not None:
+            self.profiler.on_landed(n)
 
     def loop_profile_info(self) -> dict:
         """Flat server_info fields for the loop profiler ({} when off).
@@ -796,17 +800,30 @@ class CBEngine:
         return tuple(tuple(jax.device_put(a, sh) for a in side)
                      for side in pools)
 
-    def _tmark(self, key: str, t0: float) -> None:
-        if self.profiler is not None:
-            # one accounting path: the profiler owns the legacy counters
-            if self._trace_enabled:
-                self.profiler.mark_legacy(key, time.monotonic() - t0)
-        elif self._trace is not None:
-            with self._trace_lock:
-                self._trace[key] += time.monotonic() - t0
-                self._trace["n_" + key] += 1
-
     # -- compiled pieces ----------------------------------------------------
+
+    def _program(self, table: dict, kind: str, key, jitted) -> None:
+        """Enter a newly jitted program into ``table``. Its first call
+        (trace, lower, compile or cache read, enqueue) is timed, logged,
+        annotated on the device trace and counted as a build: a shape the
+        warm-up missed shows by name, not as a stall. Each program's
+        function has a name of its own, which is its module's name on the
+        device trace (``jit_<name>``)."""
+
+        @functools.wraps(jitted)
+        def first_call(*args, **kwargs):
+            table[key] = jitted
+            t0 = time.monotonic()
+            with jax.profiler.TraceAnnotation(f"engine/build {kind} {key}"):
+                out = jitted(*args, **kwargs)
+            dt = time.monotonic() - t0
+            log.info("built program %s %s: %.2fs to first return",
+                     kind, key, dt)
+            if self.profiler is not None:
+                self.profiler.on_build(kind, key, dt)
+            return out
+
+        table[key] = first_call
 
     def _get_step(self, use_filters: bool, k: int = 1, gshape=None):
         """``k`` fused decode steps per dispatch, state advanced on device.
@@ -862,18 +879,20 @@ class CBEngine:
                         params, cfg, last_tokens, seq_lens, (kp, vp),
                         page_table, seq_lens, active=active,
                         attn_fn=attn, kv_write_fn=kv_write)
-                    rng, sub = jax.random.split(rng)
-                    token, logp = sample_token_vec(
-                        logits, sub, temps, top_ps, top_ks,
-                        use_filters=use_filters)
-                    n_gen = n_generated + active.astype(jnp.int32)
-                    hit_stop = jnp.any(token[:, None] == stop_table, axis=-1)
-                    done = active & (hit_stop | (n_gen >= budgets))
-                    token = jnp.where(active, token, pad)
-                    logp = jnp.where(active, logp, 0.0)
-                    new_active = active & ~done
-                    new_seq = seq_lens + active.astype(jnp.int32)
-                    new_last = jnp.where(active, token, last_tokens)
+                    with jax.named_scope("sample"):
+                        rng, sub = jax.random.split(rng)
+                        token, logp = sample_token_vec(
+                            logits, sub, temps, top_ps, top_ks,
+                            use_filters=use_filters)
+                        n_gen = n_generated + active.astype(jnp.int32)
+                        hit_stop = jnp.any(token[:, None] == stop_table,
+                                           axis=-1)
+                        done = active & (hit_stop | (n_gen >= budgets))
+                        token = jnp.where(active, token, pad)
+                        logp = jnp.where(active, logp, 0.0)
+                        new_active = active & ~done
+                        new_seq = seq_lens + active.astype(jnp.int32)
+                        new_last = jnp.where(active, token, last_tokens)
                     return ((kp, vp, rng, new_seq, new_last, n_gen, new_active),
                             (token, logp, done))
 
@@ -885,8 +904,8 @@ class CBEngine:
                 return (kp, vp, rng, token, logp, done,
                         seq_lens, last_tokens, n_generated, active)
 
-            self._step_fns[key] = jax.jit(
-                step, donate_argnums=(1, 2, 5, 6, 7, 9), static_argnames=())
+            self._program(self._step_fns, "step", key, jax.jit(
+                step, donate_argnums=(1, 2, 5, 6, 7, 9), static_argnames=()))
         return self._step_fns[key]
 
     def _get_spec_step(self, use_filters: bool, m: int, rounds: int):
@@ -910,9 +929,9 @@ class CBEngine:
             kv_write = self._tp_kv_write()
             page_size = self.page_size
 
-            def spec(params, kp, vp, rng, tok_buf, page_table, seq_lens,
-                     last_tokens, n_generated, budgets, active, temps,
-                     top_ps, top_ks, stop_table):
+            def spec_step(params, kp, vp, rng, tok_buf, page_table,
+                          seq_lens, last_tokens, n_generated, budgets,
+                          active, temps, top_ps, top_ks, stop_table):
                 s = seq_lens.shape[0]
                 buf_len = tok_buf.shape[1]
                 rows = jnp.arange(s)
@@ -943,10 +962,11 @@ class CBEngine:
                         pos.reshape(s * m), active=okf.reshape(s * m),
                         attn_fn=paged_attn, kv_write_fn=kv_write)
                     logits = logits.reshape(s, m, -1)
-                    rng, sub = jax.random.split(rng)
-                    toks, logps, n_acc = spec_verify_sample_vec(
-                        logits, draft, sub, temps, top_ps, top_ks,
-                        use_filters)
+                    with jax.named_scope("sample"):
+                        rng, sub = jax.random.split(rng)
+                        toks, logps, n_acc = spec_verify_sample_vec(
+                            logits, draft, sub, temps, top_ps, top_ks,
+                            use_filters)
                     # sequential stop/budget semantics over the prefix
                     stopped = jnp.zeros_like(active)
                     n_gen = n_generated
@@ -993,8 +1013,8 @@ class CBEngine:
                         d.reshape(rounds * m, s), e.reshape(rounds * m, s),
                         seq_lens, last_tokens, n_generated, active)
 
-            self._step_fns[key] = jax.jit(
-                spec, donate_argnums=(1, 2, 4, 6, 7, 8, 10))
+            self._program(self._step_fns, "spec_step", key, jax.jit(
+                spec_step, donate_argnums=(1, 2, 4, 6, 7, 8, 10)))
         return self._step_fns[key]
 
     def _tp_paged_attn(self):
@@ -1096,16 +1116,17 @@ class CBEngine:
             cfg = self.cfg
             n_pg, pps = pb // self.page_size, self.pages_per_slot
 
-            def prefill(params, kp, vp, packed, rng, **state):
+            def prefill_one(params, kp, vp, packed, rng, **state):
                 (ids, page_ids, row, stop_row, _pre, prompt_len, _b, slot,
                  budget, top_k, temp, top_p) = self._unpack_prefill(
                     packed, pb, n_pg, pps, 0)
                 (kp, vp), last_logits = decoder.prefill_into_pages(
                     params, cfg, ids, prompt_len, (kp, vp), page_ids)
-                rng, sub = jax.random.split(rng)
-                token, logp = sample_token_vec(
-                    last_logits[None], sub, temp[None], top_p[None],
-                    top_k[None], use_filters=use_filters)
+                with jax.named_scope("sample"):
+                    rng, sub = jax.random.split(rng)
+                    token, logp = sample_token_vec(
+                        last_logits[None], sub, temp[None], top_p[None],
+                        top_k[None], use_filters=use_filters)
                 token, logp = token[0], logp[0]
                 done = jnp.any(token == stop_row) | (budget <= 1)
                 st = self._insert_slot_state(
@@ -1113,7 +1134,8 @@ class CBEngine:
                     temp, top_p, top_k, stop_row, row)
                 return kp, vp, rng, token, logp, done, st
 
-            self._prefill_fns[key] = jax.jit(prefill, donate_argnums=(1, 2))
+            self._program(self._prefill_fns, "prefill_one", key, jax.jit(
+                prefill_one, donate_argnums=(1, 2)))
         return self._prefill_fns[key]
 
     def _get_prefill_batch(self, pb: int, nb: int, use_filters: bool):
@@ -1128,7 +1150,7 @@ class CBEngine:
             cfg = self.cfg
             n_pg, pps = pb // self.page_size, self.pages_per_slot
 
-            def prefill(params, kp, vp, packed, rng, **state):
+            def prefill_batch(params, kp, vp, packed, rng, **state):
                 o = 0
                 ids = packed[:, o:o + pb]; o += pb
                 page_ids = packed[:, o:o + n_pg]; o += n_pg
@@ -1141,10 +1163,11 @@ class CBEngine:
                 top_ps = jax.lax.bitcast_convert_type(sc[:, 6], jnp.float32)
                 (kp, vp), last_logits = decoder.prefill_batch_into_pages(
                     params, cfg, ids, prompt_lens, (kp, vp), page_ids)
-                rng, sub = jax.random.split(rng)
-                token, logp = sample_token_vec(
-                    last_logits, sub, temps, top_ps, top_ks,
-                    use_filters=use_filters)
+                with jax.named_scope("sample"):
+                    rng, sub = jax.random.split(rng)
+                    token, logp = sample_token_vec(
+                        last_logits, sub, temps, top_ps, top_ks,
+                        use_filters=use_filters)
                 done = (jnp.any(token[:, None] == stop_rows, axis=-1)
                         | (budgets <= 1))
                 st = dict(state)
@@ -1160,7 +1183,8 @@ class CBEngine:
                 st["page_table"] = st["page_table"].at[slots].set(rows)
                 return kp, vp, rng, token, logp, done, st
 
-            self._prefill_fns[key] = jax.jit(prefill, donate_argnums=(1, 2))
+            self._program(self._prefill_fns, "prefill_batch", key, jax.jit(
+                prefill_batch, donate_argnums=(1, 2)))
         return self._prefill_fns[key]
 
     def _get_prefill_extend(self, pb: int, n_prefix_pg: int):
@@ -1173,7 +1197,7 @@ class CBEngine:
             cfg = self.cfg
             n_pg, pps = pb // self.page_size, self.pages_per_slot
 
-            def extend(params, kp, vp, packed, rng):
+            def prefill_extend(params, kp, vp, packed, rng):
                 (ids, page_ids, _row, _stop, prefix_ids, suffix_len,
                  prefix_len, *_rest) = self._unpack_prefill(
                     packed, pb, n_pg, pps, n_prefix_pg)
@@ -1182,7 +1206,8 @@ class CBEngine:
                     prefix_ids, page_ids)
                 return kp, vp, rng
 
-            self._prefill_fns[key] = jax.jit(extend, donate_argnums=(1, 2))
+            self._program(self._prefill_fns, "prefill_extend", key, jax.jit(
+                prefill_extend, donate_argnums=(1, 2)))
         return self._prefill_fns[key]
 
     def _pack_suffix(self, tokens, suffix_len: int, prefix_len: int,
@@ -1216,19 +1241,13 @@ class CBEngine:
         iteration, so decode steps interleave with long-prompt admission."""
         job = self._chunk_jobs[0]
         req = job["req"]
-        if req.abort is not None and req.abort.is_set():
-            self._chunk_jobs.popleft()
-            self._emit_abort(req)
-            self._finalize(job["slot"], cause="abort")
-            return
-        if self.weight_version != job["version"]:
-            # a weight swap landed mid-job: the filled chunks' KV belongs
-            # to the OLD weights — finishing (and publishing) would mix
-            # weight versions into the freshly flushed prefix cache. Abort;
-            # the manager's continuation layer re-dispatches.
-            self._chunk_jobs.popleft()
-            self._emit_abort(req)
-            self._finalize(job["slot"], cause="abort")
+        if ((req.abort is not None and req.abort.is_set())
+                or self.weight_version != job["version"]):
+            # aborted, or a weight swap landed mid-job: the filled chunks'
+            # KV belongs to the OLD weights — finishing (and publishing)
+            # would mix weight versions into the freshly flushed prefix
+            # cache. Abort; the manager's continuation layer re-dispatches.
+            self._abort_chunk_job(self._chunk_jobs.popleft())
             return
         n_prompt = len(req.input_ids)
         remaining = n_prompt - job["pos"]
@@ -1273,6 +1292,8 @@ class CBEngine:
                                jnp.asarray(packed), self._rng)
         self._pools = (kp, vp)
         self.chunk_dispatches += 1
+        if self.profiler is not None:
+            self.profiler.on_dispatch("prefill_extend", lands=False)
         job["pos"] = pos + chunk
         job["own_filled"] += n_chunk_pg
 
@@ -1285,17 +1306,18 @@ class CBEngine:
             cfg = self.cfg
             n_pg, pps = pb // self.page_size, self.pages_per_slot
 
-            def prefill(params, kp, vp, packed, rng, **state):
+            def prefill_suffix(params, kp, vp, packed, rng, **state):
                 (ids, page_ids, row, stop_row, prefix_page_ids, suffix_len,
                  prefix_len, slot, budget, top_k, temp, top_p) = \
                     self._unpack_prefill(packed, pb, n_pg, pps, n_prefix_pg)
                 (kp, vp), last_logits = decoder.prefill_suffix_into_pages(
                     params, cfg, ids, suffix_len, prefix_len, (kp, vp),
                     prefix_page_ids, page_ids)
-                rng, sub = jax.random.split(rng)
-                token, logp = sample_token_vec(
-                    last_logits[None], sub, temp[None], top_p[None],
-                    top_k[None], use_filters=use_filters)
+                with jax.named_scope("sample"):
+                    rng, sub = jax.random.split(rng)
+                    token, logp = sample_token_vec(
+                        last_logits[None], sub, temp[None], top_p[None],
+                        top_k[None], use_filters=use_filters)
                 token, logp = token[0], logp[0]
                 done = jnp.any(token == stop_row) | (budget <= 1)
                 st = self._insert_slot_state(
@@ -1303,7 +1325,8 @@ class CBEngine:
                     temp, top_p, top_k, stop_row, row)
                 return kp, vp, rng, token, logp, done, st
 
-            self._prefill_fns[key] = jax.jit(prefill, donate_argnums=(1, 2))
+            self._program(self._prefill_fns, "prefill_suffix", key, jax.jit(
+                prefill_suffix, donate_argnums=(1, 2)))
         return self._prefill_fns[key]
 
     def _get_prefill_suffix_batch(self, pb: int, nb: int, n_prefix_pg: int,
@@ -1320,7 +1343,7 @@ class CBEngine:
             cfg = self.cfg
             n_pg, pps = pb // self.page_size, self.pages_per_slot
 
-            def prefill(params, kp, vp, packed, rng, **state):
+            def prefill_suffix_batch(params, kp, vp, packed, rng, **state):
                 o = 0
                 ids = packed[:, o:o + pb]; o += pb
                 page_ids = packed[:, o:o + n_pg]; o += n_pg
@@ -1338,10 +1361,11 @@ class CBEngine:
                 (kp, vp), last_logits = decoder.prefill_suffix_batch_into_pages(
                     params, cfg, ids, suffix_lens, prefix_len, (kp, vp),
                     prefix_ids, page_ids)
-                rng, sub = jax.random.split(rng)
-                token, logp = sample_token_vec(
-                    last_logits, sub, temps, top_ps, top_ks,
-                    use_filters=use_filters)
+                with jax.named_scope("sample"):
+                    rng, sub = jax.random.split(rng)
+                    token, logp = sample_token_vec(
+                        last_logits, sub, temps, top_ps, top_ks,
+                        use_filters=use_filters)
                 done = (jnp.any(token[:, None] == stop_rows, axis=-1)
                         | (budgets <= 1))
                 st = dict(state)
@@ -1358,7 +1382,9 @@ class CBEngine:
                 st["page_table"] = st["page_table"].at[slots].set(rows)
                 return kp, vp, rng, token, logp, done, st
 
-            self._prefill_fns[key] = jax.jit(prefill, donate_argnums=(1, 2))
+            self._program(
+                self._prefill_fns, "prefill_suffix_batch", key,
+                jax.jit(prefill_suffix_batch, donate_argnums=(1, 2)))
         return self._prefill_fns[key]
 
     def _sink_pad_row(self, pb: int, n_pre: int = 0) -> np.ndarray:
@@ -1436,7 +1462,6 @@ class CBEngine:
                             n_pre *= 2
             for uf in filter_variants:
                 st = self._dev_state
-                t0 = time.monotonic()
                 if self.spec_tokens > 0:
                     # speculative engines route EVERY decode dispatch
                     # through the spec step — precompile it (the k-step
@@ -1462,18 +1487,15 @@ class CBEngine:
                         st["active"], st["temps"], st["top_ps"],
                         st["top_ks"], st["stop_table"])
                 self._pools = (kp, vp)
-                self._tmark("warmup_step", t0)
             jax.block_until_ready(self._pools[0][0])
 
     def _warm_call(self, fn, packed_dev) -> None:
         """One discarded dispatch of a prefill variant against the sink row
         (pools donated in, updated pools threaded back)."""
         state_kwargs = {k: self._dev_state[k] for k in self._STATE_KEYS}
-        t0 = time.monotonic()
         kp, vp, self._rng, _t, _l, _d, new_st = fn(
             self.params, self._pools[0], self._pools[1], packed_dev,
             self._rng, **state_kwargs)
-        self._tmark("warmup_prefill", t0)
         self._pools = (kp, vp)
         self._carry_spec_state(new_st, [])
         self._dev_state = new_st
@@ -1523,6 +1545,8 @@ class CBEngine:
             self._emit_q.clear()
             self._fetched_q.clear()
             self._fetch_exc = None
+        if self.profiler is not None:
+            self.profiler.drop_outstanding()
         self._inflight_tok[:] = 0
         self._invalidate_dev_state()
         # every in-flight and queued request must still see a terminal line +
@@ -1673,21 +1697,24 @@ class CBEngine:
                 # one chunk per iteration: long-prompt admission interleaves
                 # with the decode step below instead of monopolizing the
                 # device for the whole prefill
-                t0 = time.monotonic()
                 with self._phase("prefill_dispatch"):
                     self._advance_chunk_job()
-                self._tmark("chunk_prefill", t0)
             if self._active.any():
                 self._step_once()
             elif self._pending and not self._chunk_jobs:
                 with self._phase("idle"):
                     time.sleep(0.005)  # pending but blocked on pages/slots
 
+    def _abort_chunk_job(self, job: dict) -> None:
+        self._emit_abort(job["req"])
+        self._finalize(job["slot"], cause="abort")
+        if self.profiler is not None:
+            # its mid-chunk dispatches return nothing that will ever land
+            self.profiler.drop_outstanding(tail_only=True)
+
     def _abort_chunk_jobs(self) -> None:
         while self._chunk_jobs:
-            job = self._chunk_jobs.popleft()
-            self._emit_abort(job["req"])
-            self._finalize(job["slot"], cause="abort")
+            self._abort_chunk_job(self._chunk_jobs.popleft())
 
     def _recover(self) -> None:
         """After any jit failure the pools may have been donated to the dead
@@ -1701,6 +1728,8 @@ class CBEngine:
             self._emit_q.clear()
             self._fetched_q.clear()
             self._fetch_exc = None
+        if self.profiler is not None:
+            self.profiler.drop_outstanding()
         self._inflight_tok[:] = 0
         self._invalidate_dev_state()
         self._fail_all("engine error")
@@ -1735,7 +1764,6 @@ class CBEngine:
             if not wave:
                 break
             try:
-                t0 = time.monotonic()
                 with self._phase("prefill_dispatch"):
                     if len(wave) == 1:
                         req, slot, pages, budget, mp, me = wave[0]
@@ -1746,7 +1774,6 @@ class CBEngine:
                     else:
                         self._prefill_wave(wave)
                 self.prefill_dispatches += 1
-                self._tmark("prefill_dispatch", t0)
                 self.deck.on_admit_wave(len(wave))
             except Exception:
                 for req, _slot, pages, _b, _mp, me in wave:
@@ -2455,6 +2482,11 @@ class CBEngine:
 
     def _enqueue_output(self, entry) -> None:
         """Queue a dispatch output for the fetcher thread (wakes it)."""
+        if self.profiler is not None:
+            # before the fetcher can see the entry: it lands them in order
+            kind = entry[0]
+            self.profiler.on_dispatch(
+                kind, entry[3] if kind in ("step", "spec") else 0)
         with self._fetch_cv:
             self._emit_q.append(entry)
             self._fetch_cv.notify_all()
@@ -2487,11 +2519,11 @@ class CBEngine:
                          for _ in range(min(cap, len(self._emit_q)))]
                 self._fetch_inflight = len(batch)
                 epoch = self._fetch_epoch
-            t0 = time.monotonic()
             handed_off = False
             try:
                 try:
-                    fetched = jax.device_get([e[1] for e in batch])
+                    with self._fetch_scope():
+                        fetched = jax.device_get([e[1] for e in batch])
                 except Exception as exc:  # noqa: BLE001 — surface on the
                     # loop thread (next drain) where _recover can reset
                     # pools; true BaseExceptions (SystemExit et al) must
@@ -2503,7 +2535,7 @@ class CBEngine:
                         cv.notify_all()
                     handed_off = True
                     continue
-                self._tmark("fetch", t0)
+                self._landed(len(batch))
                 with cv:
                     self._fetched_q.extend(
                         (epoch, e, a) for e, a in zip(batch, fetched))
@@ -2580,6 +2612,7 @@ class CBEngine:
                 if batch:
                     with self._phase("sample_fetch"):
                         fetched = jax.device_get([e[1] for e in batch])
+                    self._landed(len(batch))
                     with cv:
                         self._fetched_q.extend(
                             (epoch, e, a) for e, a in zip(batch, fetched))
@@ -2599,10 +2632,9 @@ class CBEngine:
             epoch = self._fetch_epoch
         if not batch:
             return
-        t0 = time.monotonic()
         with self._phase("sample_fetch"):
             fetched = jax.device_get([e[1] for e in batch])
-        self._tmark("fetch", t0)
+        self._landed(len(batch))
         with self._fetch_cv:
             self._fetched_q.extend(
                 (epoch, e, a) for e, a in zip(batch, fetched))
@@ -2647,9 +2679,9 @@ class CBEngine:
         stop_hit = t in info.stop_set
         fin = device_done or stop_hit
         reason = "stop" if stop_hit else ("length" if fin else "")
-        info.req.out.put({"token_ids": [t], "logprobs": [lp],
-                          "finished": fin, "finish_reason": reason,
-                          "weight_version": wv})
+        info.req.out.put(StreamLine({
+            "token_ids": [t], "logprobs": [lp], "finished": fin,
+            "finish_reason": reason, "weight_version": wv}))
         self._last_tokens[slot] = t
         info.emitted.append(t)
         if self._hist is not None:
@@ -2702,10 +2734,10 @@ class CBEngine:
                 reason = ""
                 if fin:
                     reason = "stop" if t in info.stop_set else "length"
-                info.req.out.put({"token_ids": [t],
-                                  "logprobs": [float(logp[r, i])],
-                                  "finished": fin, "finish_reason": reason,
-                                  "weight_version": wv})
+                info.req.out.put(StreamLine({
+                    "token_ids": [t], "logprobs": [float(logp[r, i])],
+                    "finished": fin, "finish_reason": reason,
+                    "weight_version": wv}))
                 n_emitted += 1
                 self._seq_lens[i] += 1
                 self._last_tokens[i] = t
@@ -2780,17 +2812,14 @@ class CBEngine:
         if self.spec_tokens > 0:
             self._spec_step_once(use_filters)
             return
-        t0 = time.monotonic()
         with self._phase("decode_dispatch_device"):
             self._ensure_dev_state()
-        self._tmark("upload", t0)
         st = self._dev_state
         # shared-prefix grouped decode: pack the live group tables (one
         # small int32 upload riding the dispatch — membership churn changes
         # DATA, not the compiled step, as long as the bucketed shape holds)
         gpack, gshape, group_rows = self._decode_group_pack()
         fn = self._get_step(use_filters, self.steps_per_dispatch, gshape)
-        t0 = time.monotonic()
         args = (self.params, self._pools[0], self._pools[1], self._rng,
                 st["page_table"], st["seq_lens"], st["last_tokens"],
                 st["n_generated"], st["budgets"], st["active"], st["temps"],
@@ -2801,7 +2830,6 @@ class CBEngine:
         with self._phase("decode_dispatch_device"):
             (kp, vp, self._rng, token, logp, done, st["seq_lens"],
              st["last_tokens"], st["n_generated"], st["active"]) = fn(*args)
-        self._tmark("step_dispatch", t0)
         self._pools = (kp, vp)
         with self._phase("accounting"):
             self._account_kv_reads(group_rows, self.steps_per_dispatch)
@@ -2939,13 +2967,10 @@ class CBEngine:
         exactly like fused normal steps — outputs drain lazily while the
         device runs ahead."""
         m = self.spec_tokens + 1
-        t0 = time.monotonic()
         with self._phase("decode_dispatch_device"):
             self._ensure_dev_state()
-        self._tmark("upload", t0)
         st = self._dev_state
         fn = self._get_spec_step(use_filters, m, self.spec_rounds)
-        t0 = time.monotonic()
         with self._phase("decode_dispatch_device"):
             (kp, vp, self._rng, st["tok_buf"], token, logp, done, emitted,
              st["seq_lens"], st["last_tokens"], st["n_generated"],
@@ -2955,7 +2980,6 @@ class CBEngine:
                 st["last_tokens"], st["n_generated"], st["budgets"],
                 st["active"], st["temps"], st["top_ps"], st["top_ks"],
                 st["stop_table"])
-        self._tmark("spec_dispatch", t0)
         self._pools = (kp, vp)
         # spec verify attends m virtual rows per slot per round, all over
         # the slot's own pages (grouped decode is decode-path only);

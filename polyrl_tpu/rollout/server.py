@@ -38,6 +38,7 @@ import jax
 import numpy as np
 
 from polyrl_tpu import obs
+from polyrl_tpu.obs.statusz import CUMULATIVE_INFO_KEYS
 from polyrl_tpu.rollout.cb_engine import STREAM_END
 from polyrl_tpu.rollout.flightdeck import ThroughputEWMA
 from polyrl_tpu.rollout.sampling import SamplingParams
@@ -124,6 +125,11 @@ class RolloutServer:
         # aggregates + slopes as the "timeseries" section
         self._timeseries = obs.TimeSeriesStore()
         self._ts_samples = 0
+        # bursts written to clients, and the seconds each took from the
+        # engine's put of its first line to the return of the flush
+        self._stream_lock = threading.Lock()
+        self.stream_chunks = 0
+        self.stream_lag_s = 0.0
 
         outer = self
 
@@ -166,8 +172,7 @@ class RolloutServer:
                     self._json(200, outer.statusz_snapshot())
                 elif self.path == "/metrics":
                     # Prometheus text exposition of the same telemetry the
-                    # manager polls via /get_server_info (plus the engine's
-                    # POLYRL_CB_TRACE phase timers when enabled)
+                    # manager polls via /get_server_info
                     self._send(200, outer.metrics_text().encode(),
                                "text/plain; version=0.0.4")
                 else:
@@ -275,9 +280,11 @@ class RolloutServer:
                                 done = True
                                 break
                         if items:
-                            chunk("".join(outer._serialize_line(rid, i,
-                                                                abort_ev)
-                                          for i in items))
+                            with jax.profiler.TraceAnnotation(
+                                    "server/stream_write"):
+                                chunk("".join(outer._serialize_line(
+                                    rid, i, abort_ev) for i in items))
+                            outer._count_stream_chunk(items[0])
                     self.wfile.write(b"0\r\n\r\n")
                 except (BrokenPipeError, ConnectionResetError):
                     outer.abort_request(rid)
@@ -443,6 +450,17 @@ class RolloutServer:
             if replaced is not None:
                 return replaced
         return json.dumps(line) + "\n"
+
+    def _count_stream_chunk(self, first_line) -> None:
+        """One burst reached the socket: its lag runs from the engine's put
+        of its first line (``StreamLine.t_put``, never serialized)."""
+        t_put = getattr(first_line, "t_put", None)
+        if t_put is None:   # a terminal the server or an abort path wrote
+            return
+        lag = time.monotonic() - t_put
+        with self._stream_lock:
+            self.stream_chunks += 1
+            self.stream_lag_s += lag
 
     def _drop_abort(self, rid: str, ev: threading.Event | None = None) -> None:
         with self._aborts_lock:
@@ -614,6 +632,9 @@ class RolloutServer:
             # bench's cb phase promotes them, and the engine/* time-series
             # feed below picks them up ({} when rollout.loop_profile=false)
             info.update(loop_info())
+        with self._stream_lock:
+            info["stream_chunks"] = self.stream_chunks
+            info["stream_lag_s"] = round(self.stream_lag_s, 6)
         kv_info = getattr(self.engine, "kv_memory_info", None)
         if kv_info is not None:
             # KV memory plane (rollout/kvledger.py): residency tiers, the
@@ -654,7 +675,8 @@ class RolloutServer:
                              "spec_dispatches", "prefill_dispatches",
                              "sibling_attach_dispatches",
                              "group_forked_requests",
-                             "grouped_decode_dispatches")}
+                             "grouped_decode_dispatches")
+                    or k in CUMULATIVE_INFO_KEYS}
         counters["total_tokens_served"] = float(
             getattr(self.engine, "total_tokens_served", 0))
         if self.fault is not None:
@@ -727,9 +749,10 @@ class RolloutServer:
 
     def metrics_text(self) -> str:
         """Prometheus text format for /metrics: server_info fields as
-        gauges, cumulative values (tokens served, engine trace counts +
-        phase seconds) as counters. Full precision — %g-style rounding
-        makes rate() over large counters see flat-then-jump."""
+        gauges, cumulative values (tokens served, the engine's
+        completion-stamp counters, stream chunks) as counters. Full
+        precision — %g-style rounding makes rate() over large counters
+        see flat-then-jump."""
 
         def fmt(v):
             return str(int(v)) if float(v).is_integer() else repr(float(v))
@@ -742,15 +765,9 @@ class RolloutServer:
             if isinstance(v, bool) or not isinstance(v, (int, float)):
                 continue
             name = "polyrl_" + k.replace("#", "num_").replace("/", "_")
-            kind = "counter" if k == "total_tokens_served" else "gauge"
+            kind = ("counter" if k == "total_tokens_served"
+                    or k in CUMULATIVE_INFO_KEYS else "gauge")
             lines.append(f"# TYPE {name} {kind}")
-            lines.append(f"{name} {fmt(v)}")
-        trace = getattr(self.engine, "trace_report", lambda: {})()
-        for k, v in sorted(trace.items()):
-            # every trace entry is cumulative (call counts and phase
-            # seconds both only increase)
-            name = f"polyrl_engine_{k}"
-            lines.append(f"# TYPE {name} counter")
             lines.append(f"{name} {fmt(v)}")
         return "\n".join(lines) + "\n"
 

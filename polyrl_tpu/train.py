@@ -337,8 +337,7 @@ def build_trainer(cfg: RunConfig, cleanup: list | None = None):
     if not trace_dir and cfg.obs.trace and cfg.logging.path:
         trace_dir = os.path.dirname(os.path.abspath(cfg.logging.path))
     obs.configure(trace=cfg.obs.trace, max_spans=cfg.obs.trace_buffer,
-                  out_dir=trace_dir or None,
-                  jax_annotations=cfg.obs.jax_annotations)
+                  out_dir=trace_dir or None)
     tokenizer = build_tokenizer(cfg)
     mesh = _build_mesh(cfg)
     mcfg, params = _build_model(cfg, mesh)

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from polyrl_tpu import obs
 from polyrl_tpu.models import decoder
 from polyrl_tpu.ops import core_algos
 
@@ -381,7 +382,7 @@ class StreamActor:
         optimizer = self.optimizer
         labels = self._labels
 
-        def update(params, opt_state, accum_grads, batch, loss_scale):
+        def actor_update(params, opt_state, accum_grads, batch, loss_scale):
             (loss, metrics), grads = jax.value_and_grad(self._loss_fn, has_aux=True)(
                 params, batch, loss_scale
             )
@@ -404,7 +405,7 @@ class StreamActor:
                 accum_grads = jax.tree_util.tree_map(jnp.zeros_like, accum_grads)
             return params, opt_state, accum_grads, loss, metrics
 
-        return jax.jit(update, donate_argnums=(0, 1, 2))
+        return jax.jit(actor_update, donate_argnums=(0, 1, 2))
 
     def _shard_feed(self, batch: dict) -> dict:
         """Batch-shard a host-side feed over the mesh (no-op without one).
@@ -442,7 +443,7 @@ class StreamActor:
         if not hasattr(self, "_flush_fn"):
             optimizer = self.optimizer
 
-            def flush(params, opt_state, accum_grads, inv_scale):
+            def actor_flush(params, opt_state, accum_grads, inv_scale):
                 accum_grads = jax.tree_util.tree_map(
                     lambda g: g * inv_scale, accum_grads)
                 updates, opt_state = optimizer.update(accum_grads, opt_state, params)
@@ -451,7 +452,7 @@ class StreamActor:
                 accum_grads = jax.tree_util.tree_map(jnp.zeros_like, accum_grads)
                 return params, opt_state, accum_grads, gn
 
-            self._flush_fn = jax.jit(flush, donate_argnums=(0, 1, 2))
+            self._flush_fn = jax.jit(actor_flush, donate_argnums=(0, 1, 2))
         inv = 1.0 / self._accum_scale if self._accum_scale > 0 else 1.0
         self.params, self.opt_state, self.accum_grads, gn = self._flush_fn(
             self.params, self.opt_state, self.accum_grads,
@@ -464,9 +465,10 @@ class StreamActor:
         batch = self._shard_feed(batch)
         if compute_entropy not in self._logprob_fns:
             self._logprob_fns[compute_entropy] = jax.jit(
-                partial(_model_logprobs_entropy, remat=False,
-                        compute_entropy=compute_entropy,
-                        attn_fn=self.attn_fn, layers_fn=self.layers_fn),
+                obs.named_program("actor_logprob", partial(
+                    _model_logprobs_entropy, remat=False,
+                    compute_entropy=compute_entropy,
+                    attn_fn=self.attn_fn, layers_fn=self.layers_fn)),
                 static_argnums=(1,),
             )
         return self._logprob_fns[compute_entropy](
@@ -483,10 +485,11 @@ class StreamActor:
         key = ("packed", compute_entropy)
         if key not in self._logprob_fns:
             self._logprob_fns[key] = jax.jit(
-                partial(_packed_logprobs_entropy, remat=False,
-                        compute_entropy=compute_entropy,
-                        attn_fn=self.packed_attn_fn,
-                        layers_fn=self.layers_fn),
+                obs.named_program("actor_logprob_packed", partial(
+                    _packed_logprobs_entropy, remat=False,
+                    compute_entropy=compute_entropy,
+                    attn_fn=self.packed_attn_fn,
+                    layers_fn=self.layers_fn)),
                 static_argnums=(1,),
             )
         return self._logprob_fns[key](
@@ -510,13 +513,15 @@ class ReferencePolicy:
         if attn_fn is None:
             attn_fn = default_train_attention()
         self._fn = jax.jit(
-            partial(_model_logprobs_entropy, remat=False, compute_entropy=False,
-                    attn_fn=attn_fn),
+            obs.named_program("ref_logprob", partial(
+                _model_logprobs_entropy, remat=False, compute_entropy=False,
+                attn_fn=attn_fn)),
             static_argnums=(1,),
         )
         self._packed_fn = jax.jit(
-            partial(_packed_logprobs_entropy, remat=False,
-                    compute_entropy=False),
+            obs.named_program("ref_logprob_packed", partial(
+                _packed_logprobs_entropy, remat=False,
+                compute_entropy=False)),
             static_argnums=(1,),
         )
 

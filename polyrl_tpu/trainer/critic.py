@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from polyrl_tpu import obs
 from polyrl_tpu.models import decoder
 from polyrl_tpu.ops import core_algos
 
@@ -155,7 +156,7 @@ class StreamCritic:
     def _build_update(self, is_opt_step: bool):
         optimizer = self.optimizer
 
-        def update(params, opt_state, accum, batch, loss_scale):
+        def critic_update(params, opt_state, accum, batch, loss_scale):
             (loss, metrics), grads = jax.value_and_grad(self._loss, has_aux=True)(
                 params, batch, loss_scale
             )
@@ -168,7 +169,7 @@ class StreamCritic:
                 accum = jax.tree_util.tree_map(jnp.zeros_like, accum)
             return params, opt_state, accum, loss, metrics
 
-        return jax.jit(update, donate_argnums=(0, 1, 2))
+        return jax.jit(critic_update, donate_argnums=(0, 1, 2))
 
     def _shard_feed(self, batch: dict) -> dict:
         if self.mesh is None:
@@ -195,7 +196,7 @@ class StreamCritic:
         if not hasattr(self, "_flush_fn"):
             optimizer = self.optimizer
 
-            def flush(params, opt_state, accum, inv_scale):
+            def critic_flush(params, opt_state, accum, inv_scale):
                 accum = jax.tree_util.tree_map(lambda g: g * inv_scale, accum)
                 updates, opt_state = optimizer.update(accum, opt_state, params)
                 params = optax.apply_updates(params, updates)
@@ -203,7 +204,7 @@ class StreamCritic:
                 accum = jax.tree_util.tree_map(jnp.zeros_like, accum)
                 return params, opt_state, accum, gn
 
-            self._flush_fn = jax.jit(flush, donate_argnums=(0, 1, 2))
+            self._flush_fn = jax.jit(critic_flush, donate_argnums=(0, 1, 2))
         inv = 1.0 / self._accum_scale if self._accum_scale > 0 else 1.0
         self.params, self.opt_state, self.accum_grads, gn = self._flush_fn(
             self.params, self.opt_state, self.accum_grads,
@@ -214,26 +215,26 @@ class StreamCritic:
     def compute_values(self, batch: dict) -> jnp.ndarray:
         batch = self._shard_feed(batch)
         if self._value_fn is None:
-            self._value_fn = jax.jit(
+            self._value_fn = jax.jit(obs.named_program(
+                "critic_value",
                 lambda p, b: forward_values(
                     p, self.model_cfg, b["input_ids"], b["positions"],
                     b["attention_mask"], b["responses"], False,
                     attn_fn=self.attn_fn, layers_fn=self.layers_fn,
-                )
-            )
+                )))
         return self._value_fn(self.params, batch)
 
     def compute_values_packed(self, batch: dict) -> jnp.ndarray:
         """[R, L] per-column values on a packed feed (no grad)."""
         batch = self._shard_feed(batch)
         if not hasattr(self, "_value_fn_packed"):
-            self._value_fn_packed = jax.jit(
+            self._value_fn_packed = jax.jit(obs.named_program(
+                "critic_value_packed",
                 lambda p, b: forward_values_packed(
                     p, self.model_cfg, b["input_ids"], b["positions"],
                     b["attention_mask"], b["segment_ids"], False,
                     loss_mask=b.get("loss_mask"),
                     attn_fn=self.packed_attn_fn,
                     layers_fn=self.layers_fn,
-                )
-            )
+                )))
         return self._value_fn_packed(self.params, batch)

@@ -157,10 +157,11 @@ def marked_timer(name: str, tracker: MetricsTracker):
     """Phase timer: always emits ``timing_s/<name>`` (even when the phase
     raises — a phase that fails must not vanish from the step record, the
     failure adds a ``<name>/failed`` count instead), opens a tracer span
-    ``trainer/<name>``, and (opt-in) a jax.profiler annotation so device
-    traces line up with host spans."""
+    ``trainer/<name>`` and, under the same name, a jax.profiler annotation
+    so that a device trace holds the trainer's phases on its own clock."""
     t0 = time.monotonic()
-    with obs.span("trainer/" + name), obs.phase_annotation(name):
+    with obs.span("trainer/" + name), \
+            obs.phase_annotation("trainer/" + name):
         try:
             yield
         except BaseException:

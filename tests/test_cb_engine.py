@@ -414,66 +414,59 @@ def test_chunked_prefill_matches_unchunked():
 
 def test_chunked_prefill_interleaves_with_decode():
     """While a long prompt chunks in, an already-running stream keeps
-    emitting tokens — the trace must show chunk dispatches AND decode steps
-    interleaved (neither starves)."""
-    import os
+    emitting tokens — the engine's counters must show chunk dispatches AND
+    decode dispatches interleaved (neither starves)."""
     import time as _time
 
-    os.environ["POLYRL_CB_TRACE"] = "1"
-    try:
-        cfg, engine, kw, params = _mk_engines_for_chunking(prefill_chunk=8)
-        from polyrl_tpu.rollout.sampling import SamplingParams
+    cfg, engine, kw, params = _mk_engines_for_chunking(prefill_chunk=8)
+    from polyrl_tpu.rollout.sampling import SamplingParams
 
-        rng = np.random.default_rng(12)
-        engine.start()
-        sp_long = SamplingParams(temperature=0.0, max_new_tokens=24,
-                                 stop_token_ids=())
-        # request 1: short prompt, long generation → decoding while...
-        q1 = engine.submit("r1", rng.integers(1, cfg.vocab_size, 5).tolist(),
-                           sp_long)
-        _time.sleep(0.3)  # let it admit and start decoding
-        # ...request 2's 40-token prompt chunks in (5 chunks of 8)
-        q2 = engine.submit("r2", rng.integers(1, cfg.vocab_size, 40).tolist(),
-                           sp_long)
-        from polyrl_tpu.rollout.cb_engine import STREAM_END
+    rng = np.random.default_rng(12)
+    engine.start()
+    sp_long = SamplingParams(temperature=0.0, max_new_tokens=24,
+                             stop_token_ids=())
+    # request 1: short prompt, long generation → decoding while...
+    q1 = engine.submit("r1", rng.integers(1, cfg.vocab_size, 5).tolist(),
+                       sp_long)
+    _time.sleep(0.3)  # let it admit and start decoding
+    # ...request 2's 40-token prompt chunks in (5 chunks of 8)
+    q2 = engine.submit("r2", rng.integers(1, cfg.vocab_size, 40).tolist(),
+                       sp_long)
+    from polyrl_tpu.rollout.cb_engine import STREAM_END
 
-        done = 0
-        t0 = _time.monotonic()
-        toks = {"r1": 0, "r2": 0}
-        while done < 2 and _time.monotonic() - t0 < 180:
-            for name, q in (("r1", q1), ("r2", q2)):
-                try:
-                    item = q.get(timeout=0.05)
-                except Exception:  # noqa: BLE001 — queue.Empty
-                    continue
-                if item is STREAM_END:
-                    done += 1
-                elif isinstance(item, dict):
-                    toks[name] += len(item.get("token_ids", []))
-        rep = engine.trace_report()
-        engine.stop()
-        assert toks["r1"] == 24 and toks["r2"] == 24, toks
-        assert rep.get("n_chunk_prefill", 0) >= 5, rep
-        assert rep.get("n_step_dispatch", 0) >= 3, rep
-    finally:
-        os.environ.pop("POLYRL_CB_TRACE", None)
+    done = 0
+    t0 = _time.monotonic()
+    toks = {"r1": 0, "r2": 0}
+    while done < 2 and _time.monotonic() - t0 < 180:
+        for name, q in (("r1", q1), ("r2", q2)):
+            try:
+                item = q.get(timeout=0.05)
+            except Exception:  # noqa: BLE001 — queue.Empty
+                continue
+            if item is STREAM_END:
+                done += 1
+            elif isinstance(item, dict):
+                toks[name] += len(item.get("token_ids", []))
+    rep = engine.profiler.counters()
+    engine.stop()
+    assert toks["r1"] == 24 and toks["r2"] == 24, toks
+    # 40 tokens in chunks of 8: four mid-chunks, then the final chunk
+    # through the suffix path
+    assert engine.chunk_dispatches >= 4, engine.chunk_dispatches
+    assert rep["decode_dispatches"] >= 3, rep
+    assert rep["decode_steps_done"] >= 3, rep
 
 
 def test_chunked_prefill_abort_frees_pages():
     """Abort fires MID-JOB (after ≥1 chunk dispatched) so the chunk-job
     abort branch — not _collect_wave's pre-admission check — must free the
     slot, pages, and cache refs."""
-    import os
     import threading
     import time as _time
 
     from polyrl_tpu.rollout.sampling import SamplingParams
 
-    os.environ["POLYRL_CB_TRACE"] = "1"
-    try:
-        cfg, engine, kw, params = _mk_engines_for_chunking(prefill_chunk=8)
-    finally:
-        os.environ.pop("POLYRL_CB_TRACE", None)
+    cfg, engine, kw, params = _mk_engines_for_chunking(prefill_chunk=8)
     engine.start()
     rng = np.random.default_rng(13)
     free0 = engine.allocator.free_count
@@ -482,10 +475,9 @@ def test_chunked_prefill_abort_frees_pages():
                       SamplingParams(temperature=0.0, max_new_tokens=8,
                                      stop_token_ids=()), abort=abort)
     t0 = _time.monotonic()
-    while (engine.trace_report().get("n_chunk_prefill", 0) < 1
-           and _time.monotonic() - t0 < 120):
+    while engine.chunk_dispatches < 1 and _time.monotonic() - t0 < 120:
         _time.sleep(0.01)
-    assert engine.trace_report().get("n_chunk_prefill", 0) >= 1
+    assert engine.chunk_dispatches >= 1
     abort.set()
     from polyrl_tpu.rollout.cb_engine import STREAM_END
 
@@ -511,17 +503,12 @@ def test_chunked_prefill_aborts_on_weight_swap():
     """A weight update mid-chunk-job must abort the job (its filled KV
     belongs to the old weights; finishing would publish mixed-version KV
     into the freshly flushed prefix cache)."""
-    import os
     import time as _time
 
     from polyrl_tpu.rollout.cb_engine import STREAM_END
     from polyrl_tpu.rollout.sampling import SamplingParams
 
-    os.environ["POLYRL_CB_TRACE"] = "1"
-    try:
-        cfg, engine, kw, params = _mk_engines_for_chunking(prefill_chunk=8)
-    finally:
-        os.environ.pop("POLYRL_CB_TRACE", None)
+    cfg, engine, kw, params = _mk_engines_for_chunking(prefill_chunk=8)
     engine.start()
     rng = np.random.default_rng(14)
     free0 = engine.allocator.free_count
@@ -529,8 +516,7 @@ def test_chunked_prefill_aborts_on_weight_swap():
                       SamplingParams(temperature=0.0, max_new_tokens=8,
                                      stop_token_ids=()))
     t0 = _time.monotonic()
-    while (engine.trace_report().get("n_chunk_prefill", 0) < 1
-           and _time.monotonic() - t0 < 120):
+    while engine.chunk_dispatches < 1 and _time.monotonic() - t0 < 120:
         _time.sleep(0.01)
     engine.update_weights(engine.params, version=99)
     items = []

@@ -1,7 +1,9 @@
 """Engine-loop profiler (ARCHITECTURE.md "Engine-loop profiler"): the
 phase walls partition the loop wall exactly under a fake clock (nested
-phases charged exclusively, residual in ``other``), the flip window
-yields the device-vs-host split, a real CB engine under churn keeps
+phases charged exclusively, residual in ``other``), every phase is also a
+``TraceAnnotation``, the completion stamps give device-busy seconds that
+never exceed wall and a per-step cost without a dispatch quantum, the flip
+window yields the device-vs-host split, a real CB engine under churn keeps
 ``attributed_frac`` >= 0.95, the v8 ``engine.loop`` block rides BOTH
 statusz planes, the fleet gauges/bundle artifact/report tool work, the
 accounting overhead stays under budget with every plane ON, and
@@ -17,8 +19,8 @@ import pytest
 
 from polyrl_tpu.models import decoder
 from polyrl_tpu.obs import statusz
-from polyrl_tpu.obs.engine_profile import (ACCOUNTING_PHASES, DEVICE_PHASES,
-                                           PHASES, EngineLoopProfiler)
+from polyrl_tpu.obs.engine_profile import (ACCOUNTING_PHASES, PHASES,
+                                           WAIT_PHASES, EngineLoopProfiler)
 from polyrl_tpu.rollout.cb_engine import STREAM_END, CBEngine
 from polyrl_tpu.rollout.sampling import SamplingParams
 
@@ -117,20 +119,32 @@ def test_unattributed_residual_lands_in_other():
     assert sum(snap["phase_s"].values()) == pytest.approx(4.0, abs=1e-3)
 
 
+PROFILER_INFO_KEYS = {
+    "device_frac", "host_overhead_frac", "accounting_frac",
+    "loop_attributed_frac", "decode_dispatches", "decode_steps_done",
+    "device_busy_s", "device_busy_at_s", "loop_wall_s", "loop_host_s",
+    "programs_built"}
+
+
 def test_window_flip_and_device_host_split():
-    """The two-bucket flip window sums ~window_s of recent wall and
-    folds phases into device/accounting/idle/host-overhead fracs that
-    partition 1 (host overhead includes the residual)."""
+    """The two-bucket flip window sums ~window_s of recent wall.
+    ``device_frac`` is the share of it with device work outstanding, by
+    the completion stamps (a dispatch enqueued at 0 whose result lands
+    at 4 of 6 s), NOT the host wall spent in dispatch and fetch phases;
+    host overhead is the wall outside the loop's two waits, the residual
+    included."""
     clock = _FakeClock()
     prof = EngineLoopProfiler(window_s=8.0, clock=clock)  # flips at 4 s
     with prof.iteration():
         with prof.phase("decode_dispatch_device"):
+            prof.on_dispatch("step", steps=8)
             clock.advance(2.0)
         with prof.phase("idle"):
             clock.advance(1.0)
         with prof.phase("accounting"):
             clock.advance(1.0)
     # 4 s of wall reached -> that iteration flipped into the prev bucket
+    prof.on_landed(1)                        # busy 100.0 .. 104.0
     with prof.iteration():
         with prof.phase("sample_fetch"):
             clock.advance(2.0)
@@ -139,34 +153,37 @@ def test_window_flip_and_device_host_split():
     assert w["device_frac"] == pytest.approx(4.0 / 6.0)
     assert w["idle_frac"] == pytest.approx(1.0 / 6.0)
     assert w["accounting_frac"] == pytest.approx(1.0 / 6.0)
-    assert w["host_overhead_frac"] == pytest.approx(1.0 / 6.0)
-    assert w["device_frac"] + w["host_overhead_frac"] + w["idle_frac"] \
-        == pytest.approx(1.0)
+    # 6 s less the two waits (1 s idle, 2 s sample_fetch)
+    assert w["host_overhead_frac"] == pytest.approx(3.0 / 6.0)
     # flat server_info keys: no "/" (the C++ poller indexes them bare)
     fields = prof.server_info_fields()
-    assert set(fields) == {"device_frac", "host_overhead_frac",
-                           "accounting_frac", "loop_attributed_frac"}
+    assert set(fields) == PROFILER_INFO_KEYS
     assert all("/" not in k for k in fields)
     assert fields["device_frac"] == pytest.approx(4.0 / 6.0, abs=1e-5)
     assert fields["loop_attributed_frac"] == pytest.approx(1.0)
+    assert fields["loop_wall_s"] == pytest.approx(6.0)
+    assert fields["loop_host_s"] == pytest.approx(3.0)
+    assert fields["decode_dispatches"] == 1
+    assert fields["decode_steps_done"] == 8
 
 
-def test_phase_taxonomy_and_legacy_counters():
-    """The taxonomy is closed (device/accounting subsets of PHASES, other
-    last) and the absorbed POLYRL_CB_TRACE counters keep their
-    ``{key: seconds, n_<key>: count}`` shape."""
+def test_phase_taxonomy_and_fetch_counters():
+    """The taxonomy is closed (wait/accounting subsets of PHASES, other
+    last) and the fetcher's transfers are counted beside the partition:
+    seconds and a count in the snapshot, nothing in the loop's phases."""
     assert PHASES[-1] == "other"
-    assert DEVICE_PHASES < set(PHASES)
+    assert WAIT_PHASES < set(PHASES)
     assert ACCOUNTING_PHASES < set(PHASES)
-    assert not DEVICE_PHASES & ACCOUNTING_PHASES
-    prof = EngineLoopProfiler(clock=_FakeClock())
-    prof.mark_legacy("fetch", 0.5)
-    prof.mark_legacy("fetch", 0.25)
-    prof.mark_legacy("dispatch", 0.1)
-    rep = prof.legacy_report()
-    assert rep["fetch"] == pytest.approx(0.75)
-    assert rep["n_fetch"] == 2
-    assert rep["n_dispatch"] == 1
+    assert not WAIT_PHASES & ACCOUNTING_PHASES
+    clock = _FakeClock()
+    prof = EngineLoopProfiler(clock=clock)
+    for dt in (0.5, 0.25):
+        with prof.fetch():
+            clock.advance(dt)
+    snap = prof.snapshot()
+    assert snap["fetch"] == {"seconds": pytest.approx(0.75), "n": 2}
+    assert "fetch" not in snap["phase_s"] and not snap["phase_n"]
+    assert snap["wall_s"] == 0.0 and prof.attributed_frac() == 1.0
 
 
 def test_cross_thread_phase_does_not_corrupt_iteration():
@@ -232,12 +249,18 @@ def test_real_engine_attribution_under_churn(tiny):
     assert snap["phase_n"]["decode_dispatch_device"] > 0
     assert snap["latency"]["decode_dispatch_device"]["count"] > 0
     info = eng.loop_profile_info()
-    assert set(info) == {"device_frac", "host_overhead_frac",
-                         "accounting_frac", "loop_attributed_frac"}
-    assert info["device_frac"] > 0.0        # the dispatches dominate
+    assert set(info) == PROFILER_INFO_KEYS | {"last_program_built"}
+    assert info["device_frac"] > 0.0        # work was outstanding
     assert info["loop_attributed_frac"] >= 0.90
-    # the absorbed legacy counters still answer (POLYRL_CB_TRACE shape)
-    assert isinstance(eng.trace_report(), dict)
+    # the completion stamps: every dispatched step landed or was dropped
+    # at the abort, busy seconds never exceed the loop's wall
+    assert info["decode_dispatches"] >= 3
+    assert 0 < info["decode_steps_done"] <= (
+        info["decode_dispatches"] * eng.steps_per_dispatch)
+    assert 0.0 < info["device_busy_s"] <= info["loop_wall_s"] + 0.5
+    assert info["loop_host_s"] <= info["loop_wall_s"]
+    # the fetcher's transfers are counted outside the partition
+    assert snap["fetch"]["n"] > 0
 
 
 def test_statusz_v8_loop_block_both_planes(tiny):
@@ -479,3 +502,294 @@ def test_loop_profile_off_is_bitwise_identical(tiny):
         assert a["token_ids"] == b["token_ids"]
         assert a["logprobs"] == b["logprobs"]  # exact, not approx
         assert a["finish_reason"] == b["finish_reason"]
+
+
+# -- one seam, one clock: annotations and completion stamps -------------------
+
+
+class _AnnotationRecorder:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records the name
+    and the thread of every annotation opened."""
+
+    def __init__(self):
+        self.opened: list[tuple[str, int]] = []
+        self.depth = 0
+
+    def __call__(self, name, **_kw):
+        rec = self
+
+        class _Cm:
+            def __enter__(self):
+                rec.opened.append((name, threading.get_ident()))
+                rec.depth += 1
+
+            def __exit__(self, *exc):
+                rec.depth -= 1
+                return False
+
+        return _Cm()
+
+
+def test_phase_seam_opens_one_annotation_per_phase_on_both_threads(
+        monkeypatch):
+    """Every phase but ``other`` is also a TraceAnnotation ``engine/<phase>``
+    on the thread that entered it, always (no knob), and the fetcher's
+    transfer is ``engine/fetch`` on its own thread, outside the partition."""
+    rec = _AnnotationRecorder()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", rec)
+    clock = _FakeClock()
+    prof = EngineLoopProfiler(window_s=1e9, clock=clock)
+    named = [p for p in PHASES if p != "other"]
+    with prof.iteration():
+        for p in named:
+            with prof.phase(p):
+                clock.advance(0.5)
+        clock.advance(0.25)                  # -> other: never annotated
+    fetcher_ident = []
+
+    def fetcher():
+        fetcher_ident.append(threading.get_ident())
+        with prof.fetch():
+            pass
+        with prof.phase("sample_fetch"):     # the dead-fetcher fallback
+            pass
+
+    t = threading.Thread(target=fetcher)
+    t.start()
+    t.join(timeout=10)
+    assert not t.is_alive() and rec.depth == 0
+    me = threading.get_ident()
+    assert [n for n, tid in rec.opened if tid == me] == [
+        "engine/" + p for p in named]
+    assert [n for n, tid in rec.opened if tid == fetcher_ident[0]] == [
+        "engine/fetch", "engine/sample_fetch"]
+    assert "engine/other" not in {n for n, _ in rec.opened}
+    # the fetch is counted beside the loop's partition, not in it
+    assert prof.wall_s == pytest.approx(0.5 * len(named) + 0.25)
+    assert prof.attributed_frac() == pytest.approx(
+        0.5 * len(named) / prof.wall_s)
+
+
+def test_marked_timer_annotates_the_trainer_phase(monkeypatch):
+    """``marked_timer`` opens ``trainer/<name>`` on the device trace's
+    clock unconditionally: ``obs.jax_annotations`` is gone."""
+    from polyrl_tpu import obs
+    from polyrl_tpu.utils.metrics import MetricsTracker, marked_timer
+
+    rec = _AnnotationRecorder()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", rec)
+    tracker = MetricsTracker()
+    with marked_timer("old_log_prob", tracker):
+        assert rec.depth == 1
+    assert [n for n, _ in rec.opened] == ["trainer/old_log_prob"]
+    assert "timing_s/old_log_prob" in tracker.as_dict()
+    assert "jax_annotations" not in obs.configure.__code__.co_varnames
+
+
+# (dispatch time, landing time) of each dispatch, in order; expected busy
+_BUSY_CASES = {
+    # each enqueued before the last one landed: one interval, 0 .. 6
+    "back_to_back": ([(0.0, 2.0), (1.0, 4.0), (3.0, 6.0)], 6.0),
+    # the device idles 2..5 and 6..9: three intervals of 2, 1 and 1
+    "gapped": ([(0.0, 2.0), (5.0, 6.0), (9.0, 10.0)], 4.0),
+    # three in flight at once, landing together at 5, then one alone
+    "overlapping": ([(0.0, 5.0), (0.5, 5.0), (1.0, 5.0), (7.0, 8.0)], 6.0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BUSY_CASES))
+def test_device_busy_seconds_by_completion_stamps(case):
+    """``device_busy_s`` is the union of the intervals in which a dispatch
+    was outstanding: it opens at an enqueue with nothing outstanding,
+    closes when a landing leaves nothing newer outstanding, grows only AT
+    a landing, and never exceeds the wall."""
+    events, want = _BUSY_CASES[case]
+    clock = _FakeClock()
+    t0 = clock.t
+    prof = EngineLoopProfiler(clock=clock)
+    timeline = sorted([(d, 0, i) for i, (d, _l) in enumerate(events)]
+                      + [(l, 1, i) for i, (_d, l) in enumerate(events)])
+    seen = []
+    for t, is_landing, _i in timeline:
+        clock.t = t0 + t
+        before = prof.counters()["device_busy_s"]
+        if is_landing:
+            prof.on_landed(1)
+        else:
+            prof.on_dispatch("step", steps=4)
+            # an enqueue adds nothing: seconds are booked at landings only
+            assert prof.counters()["device_busy_s"] == before
+        c = prof.counters()
+        assert c["device_busy_s"] <= (clock.t - t0) + 1e-9
+        seen.append(c["device_busy_s"])
+    assert seen == sorted(seen)              # monotone
+    c = prof.counters()
+    assert c["device_busy_s"] == pytest.approx(want)
+    assert c["device_busy_at_s"] == pytest.approx(t0 + events[-1][1])
+    assert c["decode_dispatches"] == len(events)
+    assert c["decode_steps_done"] == 4 * len(events)
+
+
+def test_busy_per_step_has_no_dispatch_quantum():
+    """Samples taken BETWEEN landings (a dispatch enqueued and not yet
+    landed at each) still give delta busy / delta steps == the step's
+    cost exactly: both counters move only at a landing."""
+    clock = _FakeClock()
+    prof = EngineLoopProfiler(clock=clock)
+    k, step_s = 8, 0.0225
+    samples = []
+    prof.on_dispatch("step", steps=k)
+    for i in range(40):
+        prof.on_dispatch("step", steps=k)    # run-ahead: two in flight
+        clock.advance(k * step_s * 0.37)
+        samples.append(prof.counters())      # mid-dispatch sample
+        clock.advance(k * step_s * 0.63)
+        prof.on_landed(1)
+    for a, b in ((samples[3], samples[17]), (samples[10], samples[39]),
+                 (samples[1], samples[2])):
+        steps = b["decode_steps_done"] - a["decode_steps_done"]
+        busy = b["device_busy_s"] - a["device_busy_s"]
+        assert steps > 0 and steps % k == 0
+        assert busy / steps == pytest.approx(step_s, rel=1e-6)
+        # and the busy share over the counter's own clock is exactly 1
+        assert busy / (b["device_busy_at_s"] - a["device_busy_at_s"]) \
+            == pytest.approx(1.0, rel=1e-6)
+
+
+def test_unlanded_dispatches_are_settled_not_leaked():
+    """A chunked prefill's mid-chunks return nothing to land: a later
+    landing stands for them, an aborted job settles them
+    (``tail_only``), and a reset drops everything outstanding — busy
+    never stays open across an idle stretch."""
+    clock = _FakeClock()
+    prof = EngineLoopProfiler(clock=clock)
+    # two mid-chunks, then the final chunk whose first token lands at 3
+    prof.on_dispatch("prefill_extend", lands=False)
+    clock.advance(1.0)
+    prof.on_dispatch("prefill_extend", lands=False)
+    clock.advance(1.0)
+    prof.on_dispatch("prefill")
+    clock.advance(1.0)
+    prof.on_landed(1)
+    assert prof.counters()["device_busy_s"] == pytest.approx(3.0)
+    # a mid-chunk behind a decode step: the step's landing does not close
+    # the interval (the chunk is newer), the abort settles it
+    clock.advance(10.0)                      # idle: not counted
+    prof.on_dispatch("step", steps=8)
+    prof.on_dispatch("prefill_extend", lands=False)
+    clock.advance(1.0)
+    prof.on_landed(1)
+    clock.advance(0.5)
+    prof.drop_outstanding(tail_only=True)
+    assert prof.counters()["device_busy_s"] == pytest.approx(4.5)
+    # engine reset with two dispatches in flight: counted up to the reset
+    clock.advance(10.0)
+    prof.on_dispatch("step", steps=8)
+    prof.on_dispatch("step", steps=8)
+    clock.advance(0.25)
+    prof.drop_outstanding()
+    clock.advance(10.0)
+    prof.on_landed(2)                        # a stale fetch lands late
+    c = prof.counters()
+    assert c["device_busy_s"] == pytest.approx(4.75)
+    assert c["decode_dispatches"] == 3 and c["decode_steps_done"] == 8
+
+
+def test_jit_cache_miss_records_kind_and_key(tiny, caplog):
+    """Every miss of the engine's program tables logs one line with kind,
+    key and seconds, counts in ``programs_built`` and stands in the
+    /statusz ``engine.loop.builds``; a hit adds nothing."""
+    import logging
+
+    eng = _mk_engine(tiny, steps_per_dispatch=2)
+    sp = SamplingParams(temperature=0.0, max_new_tokens=4)
+    try:
+        with caplog.at_level(logging.INFO,
+                             logger="polyrl_tpu.rollout.cb_engine"):
+            eng.generate([[5] * 16], sp)
+            first = eng.loop_profile_info()
+            eng.generate([[9] * 16], sp)     # same shapes: all hits
+            again = eng.loop_profile_info()
+    finally:
+        eng.stop()
+    builds = eng.loop_profile_snapshot()["builds"]
+    assert {b["kind"] for b in builds} == {"prefill_one", "step"}
+    step = next(b for b in builds if b["kind"] == "step")
+    assert step["key"] == "(False, 2, None)" and step["seconds"] > 0
+    assert first["programs_built"] == again["programs_built"] == len(builds)
+    assert again["last_program_built"] == \
+        f"{builds[-1]['kind']} {builds[-1]['key']}"
+    lines = [r.getMessage() for r in caplog.records
+             if r.getMessage().startswith("built program")]
+    assert len(lines) == len(builds)
+    assert any("step (False, 2, None)" in ln for ln in lines)
+    # the table holds the jitted program itself after its first call
+    assert hasattr(eng._step_fns[(False, 2, None)], "lower")
+
+
+def test_stream_lag_is_counted_and_never_reaches_the_wire(tiny):
+    """The engine's put stamp rides the queue item as an attribute: the
+    serialized line is byte-for-byte the plain dict's, and the server
+    counts one lag per burst it flushed."""
+    from polyrl_tpu.rollout.cb_engine import StreamLine
+    from polyrl_tpu.rollout.server import RolloutServer
+
+    fields = {"token_ids": [7], "logprobs": [-0.5], "finished": False,
+              "finish_reason": "", "weight_version": 3}
+    line = StreamLine(fields)
+    eng = _mk_engine(tiny)
+    server = RolloutServer(eng, host="127.0.0.1", port=0).start()
+    try:
+        assert line.t_put > 0 and line == fields
+        assert server._serialize_line("r", line, None) == \
+            server._serialize_line("r", dict(fields), None) == \
+            json.dumps(fields) + "\n"
+        assert "t_put" not in server._serialize_line("r", line, None)
+        body = json.dumps({"rid": "lag", "input_ids": [3] * 12,
+                           "sampling_params": {"temperature": 0.0,
+                                               "max_new_tokens": 6}})
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{server.port}/generate",
+            data=body.encode(), method="POST",
+            headers={"Content-Type": "application/json"})
+        with urllib.request.urlopen(req, timeout=120) as r:
+            wire = r.read().decode()
+        rows = [json.loads(ln) for ln in wire.strip().splitlines()]
+        assert sum(len(x["token_ids"]) for x in rows) == 6
+        assert all(set(x) == set(fields) for x in rows)
+        info = server.server_info()
+        assert 1 <= info["stream_chunks"] <= 6
+        assert 0.0 < info["stream_lag_s"] < 60.0
+        text = server.metrics_text()
+        assert "# TYPE polyrl_stream_lag_s counter" in text
+        assert "# TYPE polyrl_device_busy_s counter" in text
+        assert "polyrl_engine_" not in text  # the legacy block is gone
+    finally:
+        server.stop()
+
+
+def test_server_info_keeps_its_readers_keys(tiny):
+    """``occupancy``, ``page_util`` and ``device_frac`` keep their keys
+    (benchmark readers, the C++ manager, pool.py) beside the new
+    counters; the cumulative ones are statusz counters, not gauges."""
+    from polyrl_tpu.rollout.server import (CUMULATIVE_INFO_KEYS,
+                                           RolloutServer)
+
+    eng = _mk_engine(tiny)
+    server = RolloutServer(eng, host="127.0.0.1", port=0).start()
+    try:
+        eng.generate([[5] * 16], SamplingParams(temperature=0.0,
+                                                max_new_tokens=4))
+        info = server.server_info()
+        assert {"occupancy", "page_util", "device_frac",
+                "accounting_frac"} <= set(info)
+        assert CUMULATIVE_INFO_KEYS <= set(info)
+        assert 0.0 <= info["device_frac"] <= 1.0
+        json.dumps(info)                     # the manager parses it
+        snap = server.statusz_snapshot()
+        assert CUMULATIVE_INFO_KEYS <= set(snap["counters"])
+        assert not CUMULATIVE_INFO_KEYS & set(snap["gauges"])
+        assert snap["engine"]["loop"]["counters"]["decode_steps_done"] \
+            == info["decode_steps_done"]
+    finally:
+        server.stop()

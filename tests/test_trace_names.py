@@ -1,0 +1,208 @@
+"""Stable names on the device trace: every jitted program has a function
+name of its own (its ``XLA Modules`` name, ``jit_<name>``), the forward
+pass carries the scopes a trace reduction reads device time by, each
+``pallas_call`` we own has a ``name=``, and the scopes are metadata only
+(the decode step compiles to the same HLO without them)."""
+
+import contextlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from polyrl_tpu.models import decoder
+from polyrl_tpu.rollout.cb_engine import CBEngine
+
+SCOPES = ("attn_qkv", "attn_core", "attn_out", "mlp", "head")
+ENGINE_PROGRAMS = {
+    "step": lambda e: e._get_step(False, 2),
+    "spec_step": lambda e: e._get_spec_step(False, 3, 2),
+    "prefill_one": lambda e: e._get_prefill(16, False),
+    "prefill_batch": lambda e: e._get_prefill_batch(16, 2, False),
+    "prefill_extend": lambda e: e._get_prefill_extend(16, 1),
+    "prefill_suffix": lambda e: e._get_prefill_suffix(16, 1, False),
+    "prefill_suffix_batch":
+        lambda e: e._get_prefill_suffix_batch(16, 2, 1, False),
+}
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    cfg = decoder.get_config("tiny")
+    return cfg, decoder.init_params(jax.random.PRNGKey(0), cfg)
+
+
+def _engine(tiny, **kw):
+    cfg, params = tiny
+    return CBEngine(cfg, params, max_slots=4, page_size=8, max_seq_len=64,
+                    prompt_buckets=(16,), num_pages=32,
+                    steps_per_dispatch=2, **kw)
+
+
+def _shapes(tree):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+
+def _lower_step(eng):
+    eng._ensure_dev_state()
+    st = eng._dev_state
+    args = (eng.params, eng._pools[0], eng._pools[1], eng._rng,
+            st["page_table"], st["seq_lens"], st["last_tokens"],
+            st["n_generated"], st["budgets"], st["active"], st["temps"],
+            st["top_ps"], st["top_ks"], st["stop_table"])
+    return eng._get_step(False, 2).__wrapped__.lower(*_shapes(args))
+
+
+def _lower_prefill(eng):
+    eng._ensure_dev_state()
+    state = {k: eng._dev_state[k] for k in eng._STATE_KEYS}
+    packed = jnp.asarray(eng._sink_pad_row(16))
+    return eng._get_prefill(16, False).__wrapped__.lower(
+        *_shapes((eng.params, eng._pools[0], eng._pools[1], packed,
+                  eng._rng)), **_shapes(state))
+
+
+def _lower_actor_update(tiny):
+    from polyrl_tpu.trainer.actor import ActorConfig, StreamActor
+
+    cfg, params = tiny
+    actor = StreamActor(cfg, ActorConfig(lr=1e-3, remat=False),
+                        jax.tree_util.tree_map(jnp.copy, params))
+    b, t, r = 2, 8, 4
+    batch = {
+        "input_ids": np.ones((b, t), np.int32),
+        "positions": np.tile(np.arange(t, dtype=np.int32), (b, 1)),
+        "attention_mask": np.ones((b, t), np.float32),
+        "responses": np.ones((b, r), np.int32),
+        "response_mask": np.ones((b, r), np.float32),
+        "advantages": np.ones((b, r), np.float32),
+        "old_log_probs": np.zeros((b, r), np.float32),
+    }
+    fn = actor._build_update(True)
+    return fn.lower(actor.params, actor.opt_state, actor.accum_grads,
+                    batch, jnp.asarray(1.0, jnp.float32))
+
+
+@pytest.mark.parametrize("program", ["step", "prefill_one", "actor_update"])
+def test_lowered_text_carries_the_program_name_and_every_scope(tiny, program):
+    if program == "actor_update":
+        lowered = _lower_actor_update(tiny)
+        scopes = SCOPES
+    else:
+        eng = _engine(tiny)
+        lowered = _lower_step(eng) if program == "step" \
+            else _lower_prefill(eng)
+        scopes = SCOPES + ("sample",)
+    text = lowered.as_text(debug_info=True)
+    assert f"module @jit_{program} " in text
+    for scope in scopes:
+        # a scope is a component of the operations' name path
+        # (inside a scan's body the path starts at the body; under a
+        # gradient the component reads ``jvp(<scope>)``)
+        assert re.search(rf'loc\("(?:[^"]*[/(])?{scope}\)*/', text), \
+            (program, scope)
+
+
+def test_every_engine_program_has_a_name_of_its_own(tiny):
+    """``decode_step_ms`` matches ``jit_step``: only the fused decode
+    program may start with ``step``, and no two programs share a name."""
+    eng = _engine(tiny, spec_tokens=2)
+    names = {kind: fn(eng).__wrapped__.__name__
+             for kind, fn in ENGINE_PROGRAMS.items()}
+    assert names == {k: k for k in ENGINE_PROGRAMS}
+    assert [n for n in names.values() if n.startswith("step")] == ["step"]
+
+
+def test_trainer_programs_are_named_by_role(tiny):
+    from polyrl_tpu.trainer.actor import (ActorConfig, ReferencePolicy,
+                                          StreamActor)
+    from polyrl_tpu.trainer.critic import (CriticConfig, StreamCritic,
+                                           init_critic_params)
+
+    cfg, params = tiny
+    actor = StreamActor(cfg, ActorConfig(lr=1e-3, remat=False),
+                        jax.tree_util.tree_map(jnp.copy, params))
+    assert actor._build_update(False).__wrapped__.__name__ == "actor_update"
+    ref = ReferencePolicy(cfg, params)
+    assert ref._fn.__wrapped__.__name__ == "ref_logprob"
+    critic = StreamCritic(cfg, CriticConfig(),
+                          init_critic_params(jax.random.PRNGKey(1), cfg))
+    assert critic._build_update(False).__wrapped__.__name__ == \
+        "critic_update"
+    b, t, r = 2, 8, 4
+    critic.compute_values({
+        "input_ids": np.ones((b, t), np.int32),
+        "positions": np.tile(np.arange(t, dtype=np.int32), (b, 1)),
+        "attention_mask": np.ones((b, t), np.float32),
+        "responses": np.ones((b, r), np.int32)})
+    assert critic._value_fn.__wrapped__.__name__ == "critic_value"
+    actor.compute_log_prob({
+        "input_ids": np.ones((b, t), np.int32),
+        "positions": np.tile(np.arange(t, dtype=np.int32), (b, 1)),
+        "attention_mask": np.ones((b, t), np.float32),
+        "responses": np.ones((b, r), np.int32),
+        "response_mask": np.ones((b, r), np.float32)})
+    assert actor._logprob_fns[True].__wrapped__.__name__ == "actor_logprob"
+
+
+def test_decode_step_hlo_is_the_same_without_the_scopes(tiny, monkeypatch):
+    """Scopes are metadata: with ``jax.named_scope`` made a no-op the
+    decode step lowers to the same StableHLO and compiles to the same HLO,
+    metadata aside — so no device number can move."""
+    with_scopes = _lower_step(_engine(tiny))
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    without = _lower_step(_engine(tiny))
+    assert "attn_core" in with_scopes.as_text(debug_info=True)
+    assert "attn_core" not in without.as_text(debug_info=True)
+    assert with_scopes.as_text() == without.as_text()
+
+    def hlo(lowered):
+        text = lowered.compile().as_text()
+        text = re.sub(r',?\s*metadata=\{[^{}]*\}', "", text)
+        return re.sub(r"\n\s*(FileNames|FunctionNames|FileLocations|"
+                      r"StackFrames)\n(\s*\d+ .*\n)*", "\n", text)
+
+    a, b = hlo(with_scopes), hlo(without)
+    assert "op_name" not in a
+    assert a == b
+
+
+def test_each_pallas_call_we_own_has_a_name():
+    from polyrl_tpu.ops import paged_attention as pa
+
+    def names(fn, *args):
+        found = []
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    found.append(eqn.params["name"])
+                for v in eqn.params.values():
+                    inner = getattr(v, "jaxpr", v)
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+
+        walk(jax.make_jaxpr(fn)(*args).jaxpr)
+        return found
+
+    hkv, n, ps, d, s = 2, 8, 8, 128, 2
+    pool = jnp.zeros((hkv, n, ps, d), jnp.float32)
+    q = jnp.zeros((s, 4, d), jnp.float32)
+    table = jnp.zeros((s, 4), jnp.int32)
+    lens = jnp.ones((s,), jnp.int32)
+    assert names(lambda *a: pa.paged_attention_pallas(*a, interpret=True),
+                 q, pool, pool, table, lens) == ["paged_attention"]
+    upd = jnp.zeros((s, hkv, d), jnp.float32)
+    assert names(lambda *a: pa.paged_kv_write_pallas(*a, interpret=True),
+                 pool, pool, lens, lens, upd, upd) == ["paged_kv_write"]
+    g_slots = jnp.asarray([[0, 1]], jnp.int32)
+    g_pages = jnp.asarray([[1]], jnp.int32)
+    g_lens = jnp.asarray([ps], jnp.int32)
+    assert names(
+        lambda *a: pa.grouped_paged_attention_pallas(*a, interpret=True),
+        q, pool, pool, table, lens + ps, g_slots, g_pages, g_lens) == [
+            "grouped_prefix", "grouped_suffix"]

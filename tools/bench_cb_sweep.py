@@ -44,12 +44,12 @@ def run_point(cfg, params, batch, prompt_len, new_tokens, *, max_slots,
     top)."""
     import numpy as np
 
-    from bench import make_cb_engine, warmup_cb
+    from bench import engine_phase_trace, make_cb_engine, warmup_cb
     from polyrl_tpu.rollout.sampling import SamplingParams
 
     engine = make_cb_engine(cfg, params, prompt_len, new_tokens,
                             max_slots=max_slots, page_size=page_size,
-                            steps_per_dispatch=steps_per_dispatch, trace=True)
+                            steps_per_dispatch=steps_per_dispatch)
     if pipeline_depth is not None:
         engine.pipeline_depth = pipeline_depth
     try:
@@ -63,9 +63,8 @@ def run_point(cfg, params, batch, prompt_len, new_tokens, *, max_slots,
         outs = engine.generate(prompts, sp, timeout=1800.0)
         dt = time.monotonic() - t0
         total = sum(len(o["token_ids"]) for o in outs)
-        trace = engine.trace_report()
         return {"tok_s": round(total / dt, 1), "wall_s": round(dt, 2),
-                "trace": {k: round(v, 3) for k, v in sorted(trace.items())
+                "trace": {k: v for k, v in engine_phase_trace(engine).items()
                           if isinstance(v, float)}}
     finally:
         engine.stop()

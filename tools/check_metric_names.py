@@ -55,13 +55,18 @@ fraction of logically-attended pages the grouped prefix phase
 deduplicated), fed per engine from ``EngineFlightDeck.on_kv_read`` via
 ``server_info`` and aggregated fleet-wide in ``rollout/pool.py``.
 The engine-loop profiler (obs/engine_profile.py) extends the same
-``engine/*`` namespace with the windowed device-vs-host loop-wall split —
-``engine/device_frac`` (fleet MIN: the engine whose loop thread feeds the
-chip least), ``engine/accounting_frac`` (fleet MAX: the worst
-deck/ledger/spill bookkeeping share), ``engine/host_overhead_frac`` and
-``engine/loop_attributed_frac`` — riding the flat ``server_info`` fields
-the manager forwards per instance, plus the balancer-side
-``pool/balance_device_frac`` windowed median.
+``engine/*`` namespace with the windowed device-vs-host split —
+``engine/device_frac`` (share of loop wall with device work outstanding,
+by the engine's completion stamps; fleet MIN: the engine whose chip
+waits most), ``engine/accounting_frac`` (fleet MAX: the worst
+deck/ledger/spill bookkeeping share), ``engine/host_overhead_frac``
+(loop wall outside its two waits) and ``engine/loop_attributed_frac`` —
+riding the flat ``server_info`` fields the manager forwards per
+instance, plus the balancer-side ``pool/balance_device_frac`` windowed
+median. The same fields carry the cumulative completion-stamp counters
+(``decode_steps_done``, ``device_busy_s``, ``loop_host_s``,
+``stream_lag_s``, ``programs_built``, ...: ``statusz.CUMULATIVE_INFO_KEYS``),
+which the server's time-series feed also lands as ``engine/<key>``.
 The training health
 plane (obs/rlhealth.py) emits ``training/*`` — distribution summaries
 (``training/adv_abs``, ``training/tis_weight``, ``training/staleness``,

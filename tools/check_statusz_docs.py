@@ -14,7 +14,10 @@ contract change nobody can discover — so this lint fails the quick tier
   ARCHITECTURE.md (the version-history table must cover the live
   version);
 - any ``check_metric_names.NAMESPACES`` entry is not mentioned
-  (backticked, bare or as an ``area/...`` key prefix) in ARCHITECTURE.md.
+  (backticked, bare or as an ``area/...`` key prefix) in ARCHITECTURE.md;
+- any ``statusz.CUMULATIVE_INFO_KEYS`` entry (the engine's
+  completion-stamp counters and the server's stream counters in
+  ``server_info``) is not mentioned (backticked) in ARCHITECTURE.md.
 
 Run: ``python tools/check_statusz_docs.py [ARCHITECTURE.md]`` — exits 1
 and lists violations.
@@ -65,6 +68,11 @@ def check_doc(doc_path: str) -> list[str]:
                 f"metric namespace {ns!r} (check_metric_names.NAMESPACES) "
                 f"is not documented in {os.path.basename(doc_path)} — the "
                 "namespace list there must stay in sync")
+    for key in sorted(statusz.CUMULATIVE_INFO_KEYS):
+        if not _mentioned(doc, key):
+            violations.append(
+                f"server_info counter {key!r} (statusz.CUMULATIVE_INFO_KEYS)"
+                f" is not documented in {os.path.basename(doc_path)}")
     return violations
 
 

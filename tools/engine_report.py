@@ -150,6 +150,19 @@ def _render_engine(loop: dict) -> list[str]:
                    f"{_fmt(win.get('host_overhead_frac'))}, accounting "
                    f"{_fmt(win.get('accounting_frac'))}, idle "
                    f"{_fmt(win.get('idle_frac'))}")
+    c = loop.get("counters", {})
+    steps = c.get("decode_steps_done", 0)
+    if steps:
+        out.append("")
+        out.append(
+            f"{steps} decode steps landed of {c.get('decode_dispatches', 0)} "
+            f"dispatches: device busy {_fmt(c.get('device_busy_s'))} s "
+            f"({_fmt(1e3 * c.get('device_busy_s', 0.0) / steps)} ms a step); "
+            f"loop host {_fmt(c.get('loop_host_s'))} s of "
+            f"{_fmt(c.get('loop_wall_s'))} s wall")
+    for b in loop.get("builds", []):
+        out.append(f"built {b.get('kind')} {b.get('key')}: "
+                   f"{_fmt(b.get('seconds'))} s")
     hists = loop.get("latency", {})
     if hists:
         out.append("")
