@@ -30,8 +30,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from polyrl_tpu.models.quant import mm, moe_mm, unembed
+from polyrl_tpu.models.quant import QuantWeight, mm, moe_mm, unembed
 from polyrl_tpu.ops.attention import attention, causal_mask
+from polyrl_tpu.ops.grouped_matmul import row_tile, tiled_layout
 from polyrl_tpu.parallel.mesh import DP, EP, FSDP, SP, TP
 
 
@@ -64,19 +65,12 @@ class ModelConfig:
     max_position_embeddings: int = 131072
     # MoE (Qwen3-MoE / Mixtral-class): num_experts > 0 replaces every
     # layer's dense MLP with a routed mixture (softmax-over-all-experts
-    # top-k routing, HF Qwen3MoeSparseMoeBlock semantics). Dispatch is
-    # GShard-style fixed-capacity einsum (static shapes for the MXU);
-    # moe_capacity_factor sizes the per-expert buffer — tokens routed past
-    # capacity drop that expert contribution (standard GShard behavior).
+    # top-k routing, HF Qwen3MoeSparseMoeBlock semantics), dropless: every
+    # (token, expert) choice is computed (``_moe_mlp``).
     num_experts: int = 0
     num_experts_per_tok: int = 8
     moe_intermediate_size: int = 0
     norm_topk_prob: bool = True
-    moe_capacity_factor: float = 2.0
-    # tokens per routing group (GShard-style): capacity is per-group, so
-    # dispatch/combine memory is O(N·E·k·cf/E·g)= linear in N instead of
-    # O(N²). 0 → min(N, 512).
-    moe_group_size: int = 0
     dtype: Any = jnp.bfloat16
 
     @property
@@ -122,13 +116,14 @@ PRESETS: dict[str, ModelConfig] = {
     "qwen3-1.7b": ModelConfig(
         vocab_size=151936, hidden_size=2048, intermediate_size=6144,
         num_layers=28, num_heads=16, num_kv_heads=8, head_dim=128,
-        rope_theta=1000000.0, use_qk_norm=True, tie_word_embeddings=True,
+        rope_theta=1000000.0, rms_norm_eps=1e-6, use_qk_norm=True,
+        tie_word_embeddings=True,
     ),
     # Qwen3-8B
     "qwen3-8b": ModelConfig(
         vocab_size=151936, hidden_size=4096, intermediate_size=12288,
         num_layers=36, num_heads=32, num_kv_heads=8, head_dim=128,
-        rope_theta=1000000.0, use_qk_norm=True,
+        rope_theta=1000000.0, rms_norm_eps=1e-6, use_qk_norm=True,
     ),
     # Qwen2.5-0.5B (BASELINE config 1: GRPO on GSM8K)
     "qwen2.5-0.5b": ModelConfig(
@@ -169,7 +164,7 @@ PRESETS: dict[str, ModelConfig] = {
     "qwen3-30b-a3b": ModelConfig(
         vocab_size=151936, hidden_size=2048, intermediate_size=6144,
         num_layers=48, num_heads=32, num_kv_heads=4, head_dim=128,
-        rope_theta=1000000.0, use_qk_norm=True,
+        rope_theta=1000000.0, rms_norm_eps=1e-6, use_qk_norm=True,
         num_experts=128, num_experts_per_tok=8, moe_intermediate_size=768,
     ),
     # Mixtral-8x7B (HF config: mistralai/Mixtral-8x7B-v0.1 — 8 experts,
@@ -264,8 +259,9 @@ def param_specs(cfg: ModelConfig) -> dict:
     if cfg.num_experts:
         # experts shard over ep (the REAL expert axis — beyond the
         # reference's stubbed EP config, SURVEY.md §2.3); within each
-        # expert the FFN shards like the dense MLP (fsdp × tp). GSPMD
-        # derives the token dispatch/combine all-to-alls from these specs.
+        # expert the FFN shards like the dense MLP (fsdp × tp). With the
+        # mesh set (``parallel.mesh.under``) each ep rank computes its own
+        # experts' rows (``_expert_mix_sharded``).
         layer.update({
             "router": P(None, FSDP, None),
             "we_gate": P(None, EP, FSDP, TP),
@@ -352,94 +348,184 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarra
 
 # -- MoE MLP ----------------------------------------------------------------
 
+EXPERT_KEYS = ("we_gate", "we_up", "we_down")
+
+
+def _unrolled_layer(cfg: ModelConfig, layers: dict, l: int) -> dict:
+    """Layer ``l``'s weights out of the stacked tree, for a loop unrolled
+    over static layer indices. A slice of a stack fuses into the matmul
+    that reads it; the experts' grouped matmul is a custom call and a
+    slice would be copied first, so the expert stacks stay whole and the
+    caller hands ``l`` on as ``_attn_out_mlp``'s ``layer``
+    (``quant.moe_mm``)."""
+    if not cfg.num_experts:
+        return jax.tree_util.tree_map(lambda a: a[l], layers)
+    return {k: v if k in EXPERT_KEYS
+            else jax.tree_util.tree_map(lambda a: a[l], v)
+            for k, v in layers.items()}
+
+
+# tables of at most this many rows are read by a one-hot product
+_ONE_HOT_ROWS = 1024
+
+
+def _take(table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """``table[idx]``: rows of a 2-D table, entries of a 1-D one. A TPU
+    gather moves one row or one scalar at a time (12 ns a 4 KB row, 25 ns a
+    scalar: a decode step's 2,560 tiled rows cost 45 us a layer, beside
+    1.6 ms of experts; PERF.md section 6, PR 27), so a small table is read
+    by a product with the one-hot of ``idx`` instead, which is exact (one
+    term a row) and runs on the MXU. A large table (the trainer's tokens)
+    is gathered."""
+    n = table.shape[0]
+    if n > _ONE_HOT_ROWS:
+        return table[idx]
+    hot = idx[:, None] == jnp.arange(n)[None, :]
+    if table.ndim == 1:
+        return jnp.sum(jnp.where(hot, table[None, :], 0), axis=1)
+    return jnp.einsum("pn,nd->pd", hot.astype(table.dtype), table,
+                      precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32).astype(table.dtype)
+
+
+def _context_mesh():
+    """The mesh set around this trace (``parallel.mesh.under``) when it
+    has more than one device, else None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty or mesh.size == 1 else mesh
+
+
+def _expert_mix(x, experts, layer, token_of, place, choice, top_p, sizes,
+                first=0):
+    """Each token's weighted sum over those of its k choices that fall on
+    the experts ``experts`` holds, which are ``first`` onwards of all of
+    them: [N, d] float32, zero for a token with no choice here.
+
+    ``token_of`` [N*k]: the token of each choice in expert-sorted order;
+    ``place`` [N*k]: each choice's place in that order; ``sizes`` [E]: all
+    experts' rows. The local experts' rows are contiguous there. They are
+    gathered into whole tiles an expert (``tiled_layout``; at most
+    N*k + E*tile rows), run through the grouped SwiGLU and the grouped
+    down projection, and each choice reads its row back."""
+    n, d = x.shape
+    m = token_of.shape[0]
+    e_here = experts["we_gate"].shape[-3]
+    mine = jax.lax.dynamic_slice_in_dim(sizes, first, e_here)
+    first_row = jnp.sum(jnp.where(jnp.arange(sizes.shape[0]) < first,
+                                  sizes, 0))
+    lay = tiled_layout(mine, m, row_tile(m, e_here))
+    # a pad row reads the zero row appended to x
+    rows = jnp.where(
+        lay.live, _take(token_of, jnp.clip(first_row + lay.src, 0, m - 1)), n)
+    xs = _take(jnp.concatenate([x, jnp.zeros((1, d), x.dtype)]), rows)
+    hidden = moe_mm(xs, (experts["we_gate"], experts["we_up"]), lay, layer)
+    ys = moe_mm(hidden, (experts["we_down"],), lay, layer)
+    here = choice - first            # invalid choices carry expert E
+    is_here = (here >= 0) & (here < e_here)
+    row = place - first_row + lay.shift[jnp.clip(here, 0, e_here - 1)]
+    # a gather, not ``_take``: the kernel leaves the rows of tiles without
+    # rows undefined, and a one-hot product would sum them in (0 x NaN)
+    y = jnp.where(is_here[:, None], ys[jnp.clip(row, 0, xs.shape[0] - 1)], 0)
+    return jnp.einsum("nkd,nk->nd", y.reshape(n, -1, d).astype(jnp.float32),
+                      top_p)
+
+
+def _expert_mix_sharded(mesh, x, experts, layer, *route):
+    """``_expert_mix`` on a mesh, manual over every axis (a Mosaic kernel
+    cannot be partitioned for it): each ``ep`` rank computes the rows of
+    its own experts, each ``tp`` rank its own columns of gate and up and
+    rows of down (SwiGLU is element-wise there), and the results, zero or
+    partial elsewhere, are summed over ``ep`` and ``tp``. The experts'
+    ``fsdp`` shards are gathered on the way in, as for any weight. The
+    routing is one sort over all the tokens, so every rank of the data
+    axes holds, and computes, them all."""
+    lead = () if layer is None else (None,)   # whole stacks [L, E, ..]
+
+    def spec(key, w):
+        s = (P(*lead, EP, TP, None) if key == "we_down"
+             else P(*lead, EP, None, TP))
+        # a QuantWeight's scale [.., E, out] follows the output columns
+        return s if not isinstance(w, QuantWeight) else QuantWeight(
+            q=s, scale=P(*s[:-2], s[-1]))
+
+    def local(x, experts, *route):
+        first = jax.lax.axis_index(EP) * experts["we_gate"].shape[-3]
+        return jax.lax.psum(
+            _expert_mix(x, experts, layer, *route, first=first), (EP, TP))
+
+    specs = {key: spec(key, w) for key, w in experts.items()}
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(P(), specs) + (P(),) * len(route),
+        out_specs=P(), check_vma=False)(x, experts, *route)
+
 
 def _moe_mlp(cfg: ModelConfig, x: jnp.ndarray, lp: dict,
-             valid: jnp.ndarray | None = None) -> jnp.ndarray:
-    """Routed mixture MLP on flattened tokens ``x`` [N, d] → [N, d].
+             valid: jnp.ndarray | None = None, layer: int | None = None
+             ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Routed mixture MLP on flattened tokens ``x`` [N, d] -> [N, d],
+    dropless, with static shapes.
 
-    Routing follows HF Qwen3MoeSparseMoeBlock: softmax over ALL experts,
-    top-k, optional renormalization of the k probabilities. Dispatch is
-    GShard-style fixed capacity with TOKEN GROUPS: tokens are split into
-    groups of ``moe_group_size`` and every expert processes
-    ``C = ceil(k·g·capacity_factor / E)`` slots PER GROUP (static shapes —
-    the TPU requirement). Grouping keeps dispatch/combine memory linear in
-    N (the ungrouped [N, E, ceil(k·N·cf/E)] tensor is quadratic — a 4k-long
-    MoE prefill would OOM), exactly GShard's motivation. Everything is
-    batched einsums over the stacked expert weights [E, d, f] so the MXU
-    sees large batched matmuls, not E small ones.
+    Routing follows HF Qwen3MoeSparseMoeBlock: router logits in the
+    model's dtype, softmax in float32 over ALL experts, top-k, optional
+    renormalisation of the k probabilities. The N*k (token, expert)
+    choices are sorted by expert, the three expert projections run as
+    grouped matmuls over the sorted rows (``_expert_mix``: whatever the
+    imbalance, no choice is dropped and no expert multiplies a row that
+    did not choose it), and each token sums its k results with the routing
+    weights in float32. One block serves decode, prefill and the trainer.
 
-    ``valid`` [N] masks tokens out of routing entirely (bucket padding,
-    inactive decode slots): without it, pad tokens — which all embed
-    identically and therefore all route to the SAME experts — fill those
-    experts' capacity ahead of later real tokens. Tokens routed to a full
-    expert lose that expert's contribution (standard GShard dropping;
-    capacity_factor ≥ E/k disables dropping exactly, which the HF-parity
-    test uses)."""
+    ``valid`` [N] (padding, decode rows without a request): an invalid
+    token routes nowhere and returns zero.
+
+    ``layer``: ``lp``'s experts are whole stacks, and that layer of them
+    is meant (``_unrolled_layer``).
+
+    Also returns the step's load, int32 [3]: (token, expert) pairs routed,
+    experts with at least one row, rows of the busiest expert."""
     n, d = x.shape
     e, k = cfg.num_experts, cfg.num_experts_per_tok
-    g = min(cfg.moe_group_size or 512, n)
-    n_pad = -(-n // g) * g
-    ng = n_pad // g
-
-    if valid is None:
-        valid = jnp.ones((n,), jnp.float32)
-    else:
-        valid = valid.astype(jnp.float32)
-    x_p = jnp.pad(x, ((0, n_pad - n), (0, 0))) if n_pad != n else x
-    valid = (jnp.pad(valid, (0, n_pad - n)) if n_pad != n else valid)
-
-    router_logits = mm(x_p, lp["router"]).astype(jnp.float32)     # [Np, E]
-    probs = jax.nn.softmax(router_logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, k)                        # [Np, k]
-    if cfg.norm_topk_prob:
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-
-    cap = int(np.ceil(k * g * cfg.moe_capacity_factor / e))
-    cap = max(1, min(cap, g))
-
-    # slot assignment per group, token-major order (earlier tokens win
-    # capacity; within a token its higher-probability choice wins — top_k
-    # returns descending, so flattening [g, k] row-major preserves both)
-    flat_e = top_i.reshape(ng, g * k)                             # [G, g·k]
-    vk = jnp.repeat(valid.reshape(ng, g), k, axis=1)              # [G, g·k]
-    e_onehot = jax.nn.one_hot(flat_e, e, dtype=jnp.float32) * vk[:, :, None]
-    pos_in_e = jnp.cumsum(e_onehot, axis=1) - e_onehot            # [G, g·k, E]
-    pos = jnp.take_along_axis(pos_in_e, flat_e[:, :, None], axis=2)[:, :, 0]
-    keep = (pos < cap) * vk                                       # [G, g·k]
-    cap_oh = jax.nn.one_hot(pos.astype(jnp.int32), cap,
-                            dtype=jnp.float32) * keep[:, :, None]
-
-    # dispatch/combine [G, g, E, cap]: contract the k choices inside the
-    # einsum — the [G, g, k, E, cap] product never materializes
-    eo = e_onehot.reshape(ng, g, k, e)
-    co = cap_oh.reshape(ng, g, k, cap)
-    dispatch = jnp.einsum("gtke,gtkc->gtec", eo, co).astype(x.dtype)
-    combine = jnp.einsum("gtke,gtkc,gtk->gtec", eo, co,
-                         top_p.reshape(ng, g, k)).astype(jnp.float32)
-
-    xg = x_p.reshape(ng, g, d)
-    xe = jnp.einsum("gtd,gtec->gecd", xg, dispatch)               # [G, E, cap, d]
-    gate = jax.nn.silu(moe_mm("gecd,edf->gecf", xe, lp["we_gate"]
-                              ).astype(jnp.float32)).astype(x.dtype)
-    up = moe_mm("gecd,edf->gecf", xe, lp["we_up"])
-    ye = moe_mm("gecf,efd->gecd", gate * up, lp["we_down"])       # [G, E, cap, d]
-    out = jnp.einsum("gecd,gtec->gtd", ye.astype(jnp.float32), combine)
-    return out.reshape(n_pad, d)[:n].astype(x.dtype)
+    with jax.named_scope("moe_route"):
+        probs = jax.nn.softmax(mm(x, lp["router"]).astype(jnp.float32), axis=-1)
+        top_p, top_i = jax.lax.top_k(probs, k)                    # [N, k]
+        if cfg.norm_topk_prob:
+            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        choice = top_i.reshape(n * k)
+        if valid is not None:
+            valid = valid.astype(bool)
+            top_p = jnp.where(valid[:, None], top_p, 0.0)
+            # expert ``e`` is none: it sorts last and counts nowhere
+            choice = jnp.where(jnp.repeat(valid, k), choice, e)
+        order = jnp.argsort(choice, stable=True)   # sorted row -> choice
+        place = jnp.argsort(order)                 # choice -> sorted row
+        sizes = jnp.sum(jax.nn.one_hot(choice, e, dtype=jnp.int32), axis=0)
+        load = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0),
+                          jnp.max(sizes)])
+    with jax.named_scope("moe_experts"):
+        experts = {key: lp[key] for key in EXPERT_KEYS}
+        route = (order // k, place, choice, top_p, sizes)
+        mesh = _context_mesh()
+        if mesh is None:
+            out = _expert_mix(x, experts, layer, *route)
+        else:
+            out = _expert_mix_sharded(mesh, x, experts, layer, *route)
+    return out.astype(x.dtype), load
 
 
 def _mlp_block(cfg: ModelConfig, h: jnp.ndarray, lp: dict,
-               valid: jnp.ndarray | None = None) -> jnp.ndarray:
+               valid: jnp.ndarray | None = None, layer: int | None = None):
     """Post-norm MLP: dense SwiGLU, or the routed mixture when the config
     is MoE. ``h`` is [..., d]; MoE flattens leading dims into one token
     axis (routing is per-token, layout-independent). ``valid`` matches
-    ``h``'s leading dims and keeps padding/inactive tokens from consuming
-    expert capacity."""
+    ``h``'s leading dims: padding and inactive tokens route nowhere.
+    ``layer`` as ``_moe_mlp`` takes it. Returns (output, the MoE block's
+    load or None)."""
     if cfg.num_experts:
         shape = h.shape
         v = valid.reshape(-1) if valid is not None else None
-        return _moe_mlp(cfg, h.reshape(-1, shape[-1]), lp, v).reshape(shape)
+        out, load = _moe_mlp(cfg, h.reshape(-1, shape[-1]), lp, v, layer)
+        return out.reshape(shape), load
     gate = jax.nn.silu(mm(h, lp["w_gate"]).astype(jnp.float32)).astype(h.dtype)
-    return mm(gate * mm(h, lp["w_up"]), lp["w_down"])
+    return mm(gate * mm(h, lp["w_up"]), lp["w_down"]), None
 
 
 # -- forward ----------------------------------------------------------------
@@ -472,14 +558,17 @@ def _attn_qkv(cfg, x, lp, cos, sin, lead: tuple):
         return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
 
-def _attn_out_mlp(cfg, x, attn_out, lp, token_valid):
+def _attn_out_mlp(cfg, x, attn_out, lp, token_valid, layer=None):
     """Post-attention of one layer: output projection and the MLP block,
-    each with its residual. ``attn_out`` [..., Hq·D] -> x [..., d]."""
+    each with its residual. ``attn_out`` [..., Hq·D] -> (x [..., d], the
+    MoE block's load or None). ``layer``: ``lp`` is ``_unrolled_layer``'s,
+    of that layer."""
     with jax.named_scope("attn_out"):
         x = x + mm(attn_out, lp["wo"])
     with jax.named_scope("mlp"):
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-        return x + _mlp_block(cfg, h, lp, token_valid)
+        out, load = _mlp_block(cfg, h, lp, token_valid, layer)
+        return x + out, load
 
 
 def _head(cfg, params, x, logits_for=None):
@@ -524,7 +613,8 @@ def _layer_forward(cfg, x, lp, cos, sin, mask, layer_cache, attn_fn=None,
             new_cache = None
         attn_out = attn_out.reshape(b, t, -1)
 
-    return _attn_out_mlp(cfg, x, attn_out, lp, token_valid), new_cache
+    x, _load = _attn_out_mlp(cfg, x, attn_out, lp, token_valid)
+    return x, new_cache
 
 
 def forward(
@@ -610,7 +700,7 @@ def forward(
         chunk_valid = jax.lax.dynamic_slice_in_dim(
             attn_mask, write_idx, t_chunk, axis=1) > 0
         for l in range(n_layers):
-            lp = jax.tree_util.tree_map(lambda a: a[l], layers)
+            lp = _unrolled_layer(cfg, layers, l)
             q, k, v = _attn_qkv(cfg, x, lp, cos, sin, (b, t_chunk))
             with jax.named_scope("attn_core"):
                 k_cache = jax.lax.dynamic_update_slice(
@@ -619,7 +709,7 @@ def forward(
                     v_cache, v[None].astype(v_cache.dtype), (l, 0, write_idx, 0, 0))
                 attn_out = attention(q, k_cache[l], v_cache[l], mask=mask)
                 attn_out = attn_out.reshape(b, t_chunk, -1)
-            x = _attn_out_mlp(cfg, x, attn_out, lp, chunk_valid)
+            x, _load = _attn_out_mlp(cfg, x, attn_out, lp, chunk_valid, l)
         new_cache = (k_cache, v_cache)
 
     return _head(cfg, params, x, logits_for), new_cache
@@ -695,11 +785,13 @@ def forward_paged_decode(
     attn_fn=None,
     active: jnp.ndarray | None = None,  # [S] bool — mask KV writes
     kv_write_fn=None,  # TP override (ops.paged_attention.make_tp_paged_kv_write)
-) -> tuple[jnp.ndarray, tuple]:
+) -> tuple[jnp.ndarray, tuple, jnp.ndarray | None]:
     """One decode step for every slot at once: write the new token's KV into
     each slot's current page, then paged-attend over [0, seq_len]. Returns
-    (logits [S, V] f32, updated pools). Static shapes regardless of the mix
-    of live requests — the continuous-batching hot loop.
+    (logits [S, V] f32, updated pools, the MoE blocks' load summed over the
+    layers as ``_moe_mlp`` counts it, None for a dense model). Static shapes
+    regardless of the mix of live requests — the continuous-batching hot
+    loop.
 
     ``active`` routes INACTIVE slots' writes to the null page 0: a finished
     slot's pages return to the allocator while its device page_table row is
@@ -740,8 +832,9 @@ def forward_paged_decode(
     # slicing) — catastrophic when the pool IS the whole KV memory.
     k_pools, v_pools = list(pools[0]), list(pools[1])
     n_layers = len(k_pools)
+    moe_load = None
     for l in range(n_layers):
-        lp = jax.tree_util.tree_map(lambda a: a[l], layers)
+        lp = _unrolled_layer(cfg, layers, l)
         q, k, v = _attn_qkv(cfg, x, lp, cos, sin, (s, 1))
         with jax.named_scope("attn_core"):
             # fused K+V Pallas write on TPU (XLA row-scatter elsewhere):
@@ -754,10 +847,11 @@ def forward_paged_decode(
             attn_out = attn_fn(q[:, 0], k_pools[l], v_pools[l], page_table,
                                attn_lens)  # [S, Hq, D]
             attn_out = attn_out.reshape(s, -1)
-        # inactive slots route nowhere (their pad rows would otherwise fill
-        # the experts real slots route to)
-        x = _attn_out_mlp(cfg, x, attn_out, lp, active)
-    return _head(cfg, params, x), (tuple(k_pools), tuple(v_pools))
+        # inactive slots route nowhere and count in no expert's load
+        x, load = _attn_out_mlp(cfg, x, attn_out, lp, active, l)
+        if load is not None:
+            moe_load = load if moe_load is None else moe_load + load
+    return _head(cfg, params, x), (tuple(k_pools), tuple(v_pools)), moe_load
 
 
 def prefill_into_pages(

@@ -35,6 +35,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from polyrl_tpu.ops.grouped_matmul import grouped_matmul
+
 # layer-stacked matmul weights that get quantized ([L, in, out]);
 # embed stays bf16 (it is a gather, not a matmul), norms/biases are tiny
 QUANTIZED_LAYER_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
@@ -136,15 +138,37 @@ def mm(x, w):
     return x @ w
 
 
-def moe_mm(eq: str, x, w):
-    """Expert-batched einsum (``..., out`` result, experts on result axis 1)
-    with QuantWeight dispatch — the MoE expert projections' analogue of
-    ``mm``. ``w.scale`` is [E, out] (contraction axis reduced away)."""
-    if isinstance(w, QuantWeight):
-        y = jnp.einsum(eq, x, w.q.astype(x.dtype))
-        return (y.astype(jnp.float32)
-                * w.scale[None, :, None, :]).astype(x.dtype)
-    return jnp.einsum(eq, x, w)
+def moe_mm(x, ws: tuple, lay, layer: int | None = None):
+    """Grouped matmul of the MoE expert projections, the analogue of
+    ``mm``: ``x`` [M, in] holds each expert's rows in whole tiles
+    (``ops.grouped_matmul.tiled_layout``: ``lay``), and every row is
+    multiplied with its own expert's matrix of ``ws[0]`` [E, in, out]
+    alone; with two weights, gate and up, the result is SwiGLU's
+    ``silu(x @ gate) * (x @ up)`` (``ops.grouped_matmul.grouped_matmul``:
+    a Pallas kernel on a TPU, ``jax.lax.ragged_dot`` elsewhere). A
+    QuantWeight's int8 experts are read as int8 and cast in the kernel
+    (exact); the per-expert, per-output-channel ``scale`` [E, out]
+    multiplies each row's product by its expert's.
+
+    With ``layer``, each weight is the whole stack [L, E, in, out] and the
+    experts are that layer's. The kernel is a custom call, whose operand
+    XLA cannot slice in place: ``w[layer]`` would copy the layer's experts
+    (1.2 GB at qwen3-30b-a3b's widths) every step. So the stack goes in
+    whole, as L*E groups of which only this layer's have rows; a group
+    without rows is not read."""
+    scales = None
+    if isinstance(ws[0], QuantWeight):
+        ws, scales = tuple(w.q for w in ws), tuple(w.scale for w in ws)
+    if layer is not None:
+        n_layers, e = ws[0].shape[:2]
+        ws = tuple(w.reshape(-1, *w.shape[2:]) for w in ws)
+        if scales is not None:
+            scales = tuple(s.reshape(-1, s.shape[-1]) for s in scales)
+        lay = lay._replace(
+            tile_group=lay.tile_group + layer * e,
+            padded_sizes=jnp.pad(lay.padded_sizes,
+                                 (layer * e, (n_layers - 1 - layer) * e)))
+    return grouped_matmul(x, ws, scales, lay)
 
 
 def unembed(x, head, eq: str):

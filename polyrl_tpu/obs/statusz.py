@@ -105,6 +105,9 @@ _HIST_SUFFIXES = ("p50", "p95", "p99", "max", "mean", "count")
 CUMULATIVE_INFO_KEYS = frozenset((
     "decode_dispatches", "decode_steps_done", "device_busy_s", "loop_wall_s",
     "loop_host_s", "programs_built", "stream_chunks", "stream_lag_s"))
+# cumulative too, and counters where present: a MoE model's engine alone
+# reports them (``CBEngine.moe_info``)
+MOE_INFO_KEYS = frozenset(("moe_routed", "moe_experts_hit", "moe_load_max"))
 
 REQUIRED_SECTIONS = ("schema", "role", "pid", "time_unix_s", "uptime_s",
                      "step", "goodput", "histograms", "counters", "gauges",

@@ -9,8 +9,8 @@ one ``jax.sharding.Mesh`` with five logical axes:
 - ``fsdp``  ZeRO-style parameter sharding (combines with dp for the batch)
 - ``tp``    tensor/model parallel (MXU-dim sharding, rides ICI)
 - ``sp``    sequence/context parallel (Ulysses all-to-all or ring attention)
-- ``ep``    expert parallel (MoE expert dim; GSPMD inserts the dispatch/
-            combine all-to-alls from the einsum shardings)
+- ``ep``    expert parallel (MoE expert dim; each rank computes its own
+            experts' rows and the results are summed over ep)
 - ``pp``    pipeline parallel (layer-stack stages; GPipe microbatch
             schedule via shard_map + ppermute, parallel/pipeline.py)
 
@@ -24,6 +24,7 @@ world disappear into the compiled program.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Sequence
 
 import jax
@@ -48,8 +49,8 @@ class MeshConfig:
     pp: int = 1
     # Expert parallelism: a REAL axis (beyond the reference, which stubs
     # expert knobs at workers/config/rollout.py:193-196) — MoE expert
-    # weights shard over it (models/decoder.py MoE param specs) and GSPMD
-    # derives the dispatch/combine all-to-alls from the einsum shardings.
+    # weights shard over it (models/decoder.py MoE param specs) and each
+    # rank computes its own experts' rows (decoder._expert_mix_sharded).
     ep: int = 1
 
     def resolve(self, n_devices: int) -> tuple[int, int, int, int, int, int]:
@@ -80,6 +81,24 @@ def make_mesh(config: MeshConfig | None = None, devices: Sequence[jax.Device] | 
     dims = config.resolve(len(devices))
     dev_array = np.array(devices).reshape(dims)
     return Mesh(dev_array, AXES)
+
+
+def under(mesh: Mesh | None, fn):
+    """``fn`` with every call (so its trace too) made under
+    ``jax.set_mesh(mesh)``; ``fn`` itself without a mesh. The trainer and
+    the engine put their jitted programs through this: code inside a trace
+    that has to go manual over an axis (the MoE block over ``ep``,
+    ``decoder._expert_mix_sharded``) finds the mesh there, and every call sets
+    it because the mesh is part of jit's cache key."""
+    if mesh is None:
+        return fn
+
+    @functools.wraps(fn)
+    def call(*args, **kwargs):
+        with jax.set_mesh(mesh):
+            return fn(*args, **kwargs)
+
+    return call
 
 
 def single_device_mesh(device: jax.Device | None = None) -> Mesh:

@@ -24,8 +24,8 @@ sglang_http_async_engine.py:286-298). Design:
 - Decode: the control state lives on device and the step ADVANCES it there;
   dispatches stay `pipeline_depth` ahead while a dedicated FETCHER THREAD
   owns the blocking device->host output transfer, batching every queued
-  dispatch output into one ``device_get`` — so the loop keeps the device
-  fed and result round trips overlap both compute and each other: a
+  dispatch output the device has finished into one ``device_get`` — so the
+  loop keeps the device fed and result round trips overlap compute: a
   blocking fetch per dispatch would leave the device idle for every
   device->host round trip. Host np mirrors (updated at drain) drive
   admission and are re-uploaded only after host-side events (abort,
@@ -94,6 +94,13 @@ class StreamLine(dict):
 # _phase() hands this out when the loop profiler is off so the hot path
 # pays one attribute read, not an allocation
 _NULL_PHASE = contextlib.nullcontext()
+
+
+def _finished_on_device(payload) -> bool:
+    """Whether the device has finished every array of a dispatch's output,
+    so that a ``device_get`` of it waits for the transfer alone."""
+    return all(a.is_ready() for a in jax.tree_util.tree_leaves(payload)
+               if isinstance(a, jax.Array))
 
 
 def device_ngram_propose(tok_buf: jnp.ndarray, hist_len: jnp.ndarray,
@@ -448,6 +455,12 @@ class CBEngine:
         self.prefill_dispatches = 0         # all admission dispatches
         self.sibling_attach_dispatches = 0  # batched suffix-attach dispatches
         self.group_forked_requests = 0      # requests admitted by attach wave
+        # MoE load of the decode steps landed so far (they move with
+        # ``decode_steps_done``), as the model counted it on the device
+        # (decoder._moe_mlp), summed over fused steps and layers: (row,
+        # expert) pairs of live rows, experts with a row, rows of the
+        # busiest expert. Stays zero for a dense model.
+        self._moe_load = np.zeros(3, np.int64)
         # group pre-ref registry: leader publish pre-takes group_size-1 refs
         # on the shared prefix entries so pool-pressure eviction can't race
         # the siblings' attach; consumed per attach, TTL-swept for groups
@@ -526,10 +539,15 @@ class CBEngine:
         prof = self.profiler
         return prof.fetch() if prof is not None else _NULL_PHASE
 
-    def _landed(self, n: int) -> None:
-        """The oldest ``n`` queued dispatch outputs reached the host."""
+    def _landed(self, batch: list, fetched: list) -> None:
+        """The oldest ``len(batch)`` queued dispatch outputs reached the
+        host as ``fetched``: the completion-stamp counters move, and with
+        them (the same steps) the MoE load the decode steps counted."""
+        for entry, arrs in zip(batch, fetched):
+            if entry[0] == "step" and arrs[3] is not None:
+                self._moe_load += arrs[3]
         if self.profiler is not None:
-            self.profiler.on_landed(n)
+            self.profiler.on_landed(len(batch))
 
     def loop_profile_info(self) -> dict:
         """Flat server_info fields for the loop profiler ({} when off).
@@ -583,6 +601,15 @@ class CBEngine:
     def _cache_pages(self) -> int:
         return (self.prefix_cache.num_entries
                 if self.prefix_cache is not None else 0)
+
+    def moe_info(self) -> dict:
+        """Flat cumulative server_info fields of the MoE blocks' load over
+        the decode steps landed so far ({} for a dense model)."""
+        if not self.cfg.num_experts:
+            return {}
+        routed, hit, load_max = (int(v) for v in self._moe_load)
+        return {"moe_routed": routed, "moe_experts_hit": hit,
+                "moe_load_max": load_max}
 
     def kv_memory_info(self) -> dict:
         """Flat server_info fields for the memory plane ({} when the
@@ -808,7 +835,11 @@ class CBEngine:
         annotated on the device trace and counted as a build: a shape the
         warm-up missed shows by name, not as a stall. Each program's
         function has a name of its own, which is its module's name on the
-        device trace (``jit_<name>``)."""
+        device trace (``jit_<name>``). Under a mesh every call is made with
+        the mesh set (``parallel.mesh.under``)."""
+        from polyrl_tpu.parallel.mesh import under
+
+        jitted = under(self.mesh, jitted)
 
         @functools.wraps(jitted)
         def first_call(*args, **kwargs):
@@ -875,7 +906,7 @@ class CBEngine:
 
                 def body(carry, _):
                     kp, vp, rng, seq_lens, last_tokens, n_generated, active = carry
-                    logits, (kp, vp) = decoder.forward_paged_decode(
+                    logits, (kp, vp), moe_load = decoder.forward_paged_decode(
                         params, cfg, last_tokens, seq_lens, (kp, vp),
                         page_table, seq_lens, active=active,
                         attn_fn=attn, kv_write_fn=kv_write)
@@ -894,15 +925,17 @@ class CBEngine:
                         new_seq = seq_lens + active.astype(jnp.int32)
                         new_last = jnp.where(active, token, last_tokens)
                     return ((kp, vp, rng, new_seq, new_last, n_gen, new_active),
-                            (token, logp, done))
+                            (token, logp, done, moe_load))
 
-                carry, (token, logp, done) = jax.lax.scan(
+                carry, (token, logp, done, moe_load) = jax.lax.scan(
                     body,
                     (kp, vp, rng, seq_lens, last_tokens, n_generated, active),
                     None, length=k)
                 kp, vp, rng, seq_lens, last_tokens, n_generated, active = carry
-                return (kp, vp, rng, token, logp, done,
-                        seq_lens, last_tokens, n_generated, active)
+                if moe_load is not None:   # a MoE model: [k, 3] -> [3]
+                    moe_load = jnp.sum(moe_load, axis=0)
+                return (kp, vp, rng, token, logp, done, seq_lens,
+                        last_tokens, n_generated, active, moe_load)
 
             self._program(self._step_fns, "step", key, jax.jit(
                 step, donate_argnums=(1, 2, 5, 6, 7, 9), static_argnames=()))
@@ -956,7 +989,7 @@ class CBEngine:
                     # rows past the slot's page capacity write to the null
                     # page (garbage logits; budgets stop emission first)
                     okf = (pos < max_pos) & active[:, None]
-                    logits, (kp, vp) = decoder.forward_paged_decode(
+                    logits, (kp, vp), _moe = decoder.forward_paged_decode(
                         params, cfg, tokens_in.reshape(s * m),
                         pos.reshape(s * m), (kp, vp), pt_rep,
                         pos.reshape(s * m), active=okf.reshape(s * m),
@@ -1017,11 +1050,18 @@ class CBEngine:
                 spec_step, donate_argnums=(1, 2, 4, 6, 7, 8, 10)))
         return self._step_fns[key]
 
+    def _many_chips(self) -> bool:
+        """A mesh of more than one device: no Mosaic kernel lowers in its
+        programs outside a shard_map over every axis, whatever ``tp`` is
+        (an ``ep``-only mesh too; PERF.md section 6, PR 27)."""
+        return self.mesh is not None and self.mesh.size > 1
+
     def _tp_paged_attn(self):
-        """Under a tp>1 mesh the Pallas paged-attention custom call must be
-        shard_mapped over the head dim (GSPMD cannot partition custom
-        calls); None otherwise → forward_paged_decode's default."""
-        if self.mesh is None or self.mesh.shape.get("tp", 1) <= 1:
+        """On a mesh of several chips the Pallas paged-attention custom
+        call must be shard_mapped, over the head dim where tp > 1 (GSPMD
+        cannot partition custom calls); None otherwise →
+        forward_paged_decode's default."""
+        if not self._many_chips():
             return None
         from polyrl_tpu.ops.paged_attention import make_tp_paged_attention
 
@@ -1029,8 +1069,8 @@ class CBEngine:
 
     def _grouped_attn_fn(self):
         """The grouped two-phase decode attention callable (built once):
-        shard_mapped over the head dim under a tp>1 mesh (same custom-call
-        constraint as ``_tp_paged_attn``), the plain dispatcher (Pallas on
+        shard_mapped over the head dim on a mesh of several chips (same
+        custom-call constraint as ``_tp_paged_attn``), the plain dispatcher (Pallas on
         TPU, jnp oracle elsewhere) otherwise. The group tables ride as
         replicated operands either way."""
         if self._grouped_attn is None:
@@ -1039,7 +1079,7 @@ class CBEngine:
                 make_tp_grouped_paged_attention,
             )
 
-            if self.mesh is not None and self.mesh.shape.get("tp", 1) > 1:
+            if self._many_chips():
                 self._grouped_attn = make_tp_grouped_paged_attention(self.mesh)
             else:
                 self._grouped_attn = grouped_paged_attention
@@ -1048,7 +1088,7 @@ class CBEngine:
     def _tp_kv_write(self):
         """Same constraint as _tp_paged_attn for the Pallas K/V write
         kernel; None under no mesh -> forward_paged_decode's default."""
-        if self.mesh is None or self.mesh.shape.get("tp", 1) <= 1:
+        if not self._many_chips():
             return None
         from polyrl_tpu.ops.paged_attention import make_tp_paged_kv_write
 
@@ -1480,7 +1520,8 @@ class CBEngine:
                 else:
                     fn = self._get_step(uf, self.steps_per_dispatch)
                     (kp, vp, self._rng, _t, _l, _d, st["seq_lens"],
-                     st["last_tokens"], st["n_generated"], st["active"]) = fn(
+                     st["last_tokens"], st["n_generated"], st["active"],
+                     _m) = fn(
                         self.params, self._pools[0], self._pools[1],
                         self._rng, st["page_table"], st["seq_lens"],
                         st["last_tokens"], st["n_generated"], st["budgets"],
@@ -2509,14 +2550,20 @@ class CBEngine:
                 if not self._emit_q:
                     cv.wait(timeout=0.05)
                     continue
-                # oldest half-window only: a get blocks until its NEWEST
-                # entry finishes on device, so grabbing everything would
-                # stall each round trip behind just-dispatched compute —
-                # the older half is already done and returns in one RTT
-                # while the newer half computes
+                # the oldest entry, and with it only what the device has
+                # finished already (at most half the window): a get blocks
+                # until its NEWEST entry finishes on device, so a batch
+                # that reached into work still computing would hold every
+                # older result back behind it. A saturated device then
+                # streams dispatch by dispatch as each one ends (a burst
+                # of steps_per_dispatch tokens a stream, not of half a
+                # window's); a slow host or a long round trip still gets
+                # everything that piled up in one get
                 cap = max(1, self.pipeline_depth // 2)
-                batch = [self._emit_q.popleft()
-                         for _ in range(min(cap, len(self._emit_q)))]
+                batch = [self._emit_q.popleft()]
+                while (self._emit_q and len(batch) < cap
+                       and _finished_on_device(self._emit_q[0][1])):
+                    batch.append(self._emit_q.popleft())
                 self._fetch_inflight = len(batch)
                 epoch = self._fetch_epoch
             handed_off = False
@@ -2535,7 +2582,7 @@ class CBEngine:
                         cv.notify_all()
                     handed_off = True
                     continue
-                self._landed(len(batch))
+                self._landed(batch, fetched)
                 with cv:
                     self._fetched_q.extend(
                         (epoch, e, a) for e, a in zip(batch, fetched))
@@ -2612,7 +2659,7 @@ class CBEngine:
                 if batch:
                     with self._phase("sample_fetch"):
                         fetched = jax.device_get([e[1] for e in batch])
-                    self._landed(len(batch))
+                    self._landed(batch, fetched)
                     with cv:
                         self._fetched_q.extend(
                             (epoch, e, a) for e, a in zip(batch, fetched))
@@ -2634,7 +2681,7 @@ class CBEngine:
             return
         with self._phase("sample_fetch"):
             fetched = jax.device_get([e[1] for e in batch])
-        self._landed(len(batch))
+        self._landed(batch, fetched)
         with self._fetch_cv:
             self._fetched_q.extend(
                 (epoch, e, a) for e, a in zip(batch, fetched))
@@ -2653,7 +2700,8 @@ class CBEngine:
         # version is live when the fetch lands steps later
         wv = entry[-1]
         if kind == "step":
-            self._emit_fetched(*arrs, tail, wv=wv)
+            token, logp, done, _moe_load = arrs
+            self._emit_fetched(token, logp, done, tail, wv=wv)
         elif kind == "spec":
             token, logp, done, emitted = arrs
             self._emit_fetched(token, logp, done, tail, emitted=emitted,
@@ -2829,12 +2877,13 @@ class CBEngine:
             self.grouped_decode_dispatches += 1
         with self._phase("decode_dispatch_device"):
             (kp, vp, self._rng, token, logp, done, st["seq_lens"],
-             st["last_tokens"], st["n_generated"], st["active"]) = fn(*args)
+             st["last_tokens"], st["n_generated"], st["active"],
+             moe_load) = fn(*args)
         self._pools = (kp, vp)
         with self._phase("accounting"):
             self._account_kv_reads(group_rows, self.steps_per_dispatch)
         self._inflight_tok[self._active] += self.steps_per_dispatch
-        self._enqueue_output(("step", (token, logp, done),
+        self._enqueue_output(("step", (token, logp, done, moe_load),
                              [(int(i), int(self._slot_gen[i]))
                               for i in np.flatnonzero(self._active)],
                              self.steps_per_dispatch, self.weight_version))

@@ -38,7 +38,7 @@ import jax
 import numpy as np
 
 from polyrl_tpu import obs
-from polyrl_tpu.obs.statusz import CUMULATIVE_INFO_KEYS
+from polyrl_tpu.obs.statusz import CUMULATIVE_INFO_KEYS, MOE_INFO_KEYS
 from polyrl_tpu.rollout.cb_engine import STREAM_END
 from polyrl_tpu.rollout.flightdeck import ThroughputEWMA
 from polyrl_tpu.rollout.sampling import SamplingParams
@@ -632,6 +632,11 @@ class RolloutServer:
             # bench's cb phase promotes them, and the engine/* time-series
             # feed below picks them up ({} when rollout.loop_profile=false)
             info.update(loop_info())
+        moe_info = getattr(self.engine, "moe_info", None)
+        if moe_info is not None:
+            # MoE load of the decode steps (cumulative; {} for a dense
+            # model): pairs routed, experts hit, the busiest expert's rows
+            info.update(moe_info())
         with self._stream_lock:
             info["stream_chunks"] = self.stream_chunks
             info["stream_lag_s"] = round(self.stream_lag_s, 6)
@@ -676,7 +681,7 @@ class RolloutServer:
                              "sibling_attach_dispatches",
                              "group_forked_requests",
                              "grouped_decode_dispatches")
-                    or k in CUMULATIVE_INFO_KEYS}
+                    or k in CUMULATIVE_INFO_KEYS | MOE_INFO_KEYS}
         counters["total_tokens_served"] = float(
             getattr(self.engine, "total_tokens_served", 0))
         if self.fault is not None:
@@ -766,7 +771,8 @@ class RolloutServer:
                 continue
             name = "polyrl_" + k.replace("#", "num_").replace("/", "_")
             kind = ("counter" if k == "total_tokens_served"
-                    or k in CUMULATIVE_INFO_KEYS else "gauge")
+                    or k in CUMULATIVE_INFO_KEYS | MOE_INFO_KEYS
+                    else "gauge")
             lines.append(f"# TYPE {name} {kind}")
             lines.append(f"{name} {fmt(v)}")
         return "\n".join(lines) + "\n"

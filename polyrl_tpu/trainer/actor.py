@@ -27,6 +27,7 @@ import optax
 from polyrl_tpu import obs
 from polyrl_tpu.models import decoder
 from polyrl_tpu.ops import core_algos
+from polyrl_tpu.parallel import mesh as meshlib
 
 
 @dataclasses.dataclass(frozen=True)
@@ -243,8 +244,6 @@ class StreamActor:
             # grads/opt state inherit the layout through jit propagation.
             # Works identically for single-host multi-chip and jax.distributed
             # multi-host (the mesh just spans more processes).
-            from polyrl_tpu.parallel import mesh as meshlib
-
             specs = decoder.param_specs(model_cfg)
             if self._lora:
                 from polyrl_tpu.models import lora as lora_mod
@@ -405,7 +404,8 @@ class StreamActor:
                 accum_grads = jax.tree_util.tree_map(jnp.zeros_like, accum_grads)
             return params, opt_state, accum_grads, loss, metrics
 
-        return jax.jit(actor_update, donate_argnums=(0, 1, 2))
+        return meshlib.under(
+            self.mesh, jax.jit(actor_update, donate_argnums=(0, 1, 2)))
 
     def _shard_feed(self, batch: dict) -> dict:
         """Batch-shard a host-side feed over the mesh (no-op without one).
@@ -413,8 +413,6 @@ class StreamActor:
         shards — the jax multi-host data path (per-host data sharding)."""
         if self.mesh is None:
             return batch
-        from polyrl_tpu.parallel import mesh as meshlib
-
         return meshlib.shard_batch(self.mesh, batch)
 
     def update_stream(self, batch: dict, is_opt_step: bool, loss_scale: float = 1.0) -> dict:
@@ -464,13 +462,13 @@ class StreamActor:
         """Old-logprob pass (no grad). Returns (logprobs, entropy|None)."""
         batch = self._shard_feed(batch)
         if compute_entropy not in self._logprob_fns:
-            self._logprob_fns[compute_entropy] = jax.jit(
-                obs.named_program("actor_logprob", partial(
-                    _model_logprobs_entropy, remat=False,
-                    compute_entropy=compute_entropy,
-                    attn_fn=self.attn_fn, layers_fn=self.layers_fn)),
-                static_argnums=(1,),
-            )
+            self._logprob_fns[compute_entropy] = meshlib.under(
+                self.mesh, jax.jit(
+                    obs.named_program("actor_logprob", partial(
+                        _model_logprobs_entropy, remat=False,
+                        compute_entropy=compute_entropy,
+                        attn_fn=self.attn_fn, layers_fn=self.layers_fn)),
+                    static_argnums=(1,)))
         return self._logprob_fns[compute_entropy](
             self.params, self.model_cfg,
             batch["input_ids"], batch["positions"], batch["attention_mask"],
@@ -484,14 +482,13 @@ class StreamActor:
         batch = self._shard_feed(batch)
         key = ("packed", compute_entropy)
         if key not in self._logprob_fns:
-            self._logprob_fns[key] = jax.jit(
+            self._logprob_fns[key] = meshlib.under(self.mesh, jax.jit(
                 obs.named_program("actor_logprob_packed", partial(
                     _packed_logprobs_entropy, remat=False,
                     compute_entropy=compute_entropy,
                     attn_fn=self.packed_attn_fn,
                     layers_fn=self.layers_fn)),
-                static_argnums=(1,),
-            )
+                static_argnums=(1,)))
         return self._logprob_fns[key](
             params if params is not None else self.params, self.model_cfg,
             batch["input_ids"], batch["positions"], batch["attention_mask"],

@@ -19,6 +19,7 @@ import optax
 from polyrl_tpu import obs
 from polyrl_tpu.models import decoder
 from polyrl_tpu.ops import core_algos
+from polyrl_tpu.parallel import mesh as meshlib
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,8 +115,6 @@ class StreamCritic:
         if mesh is not None:
             # backbone leaves follow decoder.param_specs; critic-only leaves
             # (the [D, 1] value head) fall back to replicated
-            from polyrl_tpu.parallel import mesh as meshlib
-
             params = meshlib.shard_params(mesh, params,
                                           decoder.param_specs(model_cfg))
         self.params = params
@@ -169,13 +168,12 @@ class StreamCritic:
                 accum = jax.tree_util.tree_map(jnp.zeros_like, accum)
             return params, opt_state, accum, loss, metrics
 
-        return jax.jit(critic_update, donate_argnums=(0, 1, 2))
+        return meshlib.under(
+            self.mesh, jax.jit(critic_update, donate_argnums=(0, 1, 2)))
 
     def _shard_feed(self, batch: dict) -> dict:
         if self.mesh is None:
             return batch
-        from polyrl_tpu.parallel import mesh as meshlib
-
         return meshlib.shard_batch(self.mesh, batch)
 
     def update_stream(self, batch: dict, is_opt_step: bool, loss_scale: float = 1.0) -> dict:
@@ -215,26 +213,28 @@ class StreamCritic:
     def compute_values(self, batch: dict) -> jnp.ndarray:
         batch = self._shard_feed(batch)
         if self._value_fn is None:
-            self._value_fn = jax.jit(obs.named_program(
-                "critic_value",
-                lambda p, b: forward_values(
-                    p, self.model_cfg, b["input_ids"], b["positions"],
-                    b["attention_mask"], b["responses"], False,
-                    attn_fn=self.attn_fn, layers_fn=self.layers_fn,
-                )))
+            self._value_fn = meshlib.under(
+                self.mesh, jax.jit(obs.named_program(
+                    "critic_value",
+                    lambda p, b: forward_values(
+                        p, self.model_cfg, b["input_ids"], b["positions"],
+                        b["attention_mask"], b["responses"], False,
+                        attn_fn=self.attn_fn, layers_fn=self.layers_fn,
+                    ))))
         return self._value_fn(self.params, batch)
 
     def compute_values_packed(self, batch: dict) -> jnp.ndarray:
         """[R, L] per-column values on a packed feed (no grad)."""
         batch = self._shard_feed(batch)
         if not hasattr(self, "_value_fn_packed"):
-            self._value_fn_packed = jax.jit(obs.named_program(
-                "critic_value_packed",
-                lambda p, b: forward_values_packed(
-                    p, self.model_cfg, b["input_ids"], b["positions"],
-                    b["attention_mask"], b["segment_ids"], False,
-                    loss_mask=b.get("loss_mask"),
-                    attn_fn=self.packed_attn_fn,
-                    layers_fn=self.layers_fn,
-                )))
+            self._value_fn_packed = meshlib.under(
+                self.mesh, jax.jit(obs.named_program(
+                    "critic_value_packed",
+                    lambda p, b: forward_values_packed(
+                        p, self.model_cfg, b["input_ids"], b["positions"],
+                        b["attention_mask"], b["segment_ids"], False,
+                        loss_mask=b.get("loss_mask"),
+                        attn_fn=self.packed_attn_fn,
+                        layers_fn=self.layers_fn,
+                    ))))
         return self._value_fn_packed(self.params, batch)

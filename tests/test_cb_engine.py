@@ -572,6 +572,37 @@ def test_fetcher_failure_recovers_and_serving_continues(tiny):
     assert all(s is None for s in cbe._slots)
 
 
+@pytest.mark.parametrize("finished", [False, True])
+def test_fetcher_takes_only_what_the_device_has_finished(tiny, monkeypatch,
+                                                         finished):
+    """The fetcher's batch is the oldest output and, behind it, only
+    outputs the device has finished (at most half the window): with none
+    finished every landing is one dispatch, so a saturated device streams
+    dispatch by dispatch; with all finished, what piled up lands in one
+    get. The tokens are the same either way."""
+    from polyrl_tpu.rollout import cb_engine
+
+    monkeypatch.setattr(cb_engine, "_finished_on_device",
+                        lambda payload: finished)
+    cbe = _mk_engine(tiny, pipeline_depth=4, steps_per_dispatch=2)
+    landed = []
+    landing = cbe._landed
+    monkeypatch.setattr(cbe, "_landed", lambda batch, fetched: (
+        landed.append(len(batch)), landing(batch, fetched)))
+    sp = SamplingParams(temperature=0.0, max_new_tokens=24,
+                        stop_token_ids=())
+    out = cbe.generate([[5, 3, 9, 2], [11, 4]], sp, timeout=120.0)
+    cbe.stop()
+    assert [len(o["token_ids"]) for o in out] == [24, 24]
+    assert landed and max(landed) <= 2          # half of pipeline_depth
+    if not finished:
+        assert set(landed) == {1}
+    plain = _mk_engine(tiny, pipeline_depth=0)
+    want = plain.generate([[5, 3, 9, 2], [11, 4]], sp, timeout=120.0)
+    plain.stop()
+    assert [o["token_ids"] for o in out] == [o["token_ids"] for o in want]
+
+
 def test_weight_swap_mid_generation_with_pipeline(tiny):
     """update_weights while a long stream is mid-generation with the deep
     run-ahead pipeline: the stream must complete cleanly (no device-state

@@ -2,10 +2,11 @@
 
 The reference stubs expert-parallel config without executing it
 (reference workers/config/rollout.py:193-196); here MoE is implemented:
-Qwen3-MoE architecture (softmax-over-all top-k routing), GShard-style
-fixed-capacity einsum dispatch (static shapes for the MXU), and a real
-``ep`` mesh axis the expert weights shard over. Correctness anchor: logits
-parity against transformers' Qwen3MoeForCausalLM.
+Qwen3-MoE architecture (softmax-over-all top-k routing), a dropless block
+(choices sorted by expert, grouped matmuls; static shapes), and a real
+``ep`` mesh axis the expert weights shard over. Correctness anchors: logits
+parity against transformers' Qwen3MoeForCausalLM here, and against the
+benchmark's float32 reference in ``test_moe_reference.py``.
 """
 
 import dataclasses
@@ -42,32 +43,12 @@ def test_moe_router_selects_forced_expert():
     w_g, w_u, w_d = lp["we_gate"][0], lp["we_up"][0], lp["we_down"][0]
     gate = jax.nn.silu(x @ w_g)
     want_e0 = (gate * (x @ w_u)) @ w_d
-    # k=1 isolates expert 0 (capacity E/k so all-to-one-expert doesn't drop)
-    cfg1 = dataclasses.replace(cfg, num_experts_per_tok=1,
-                               moe_capacity_factor=float(cfg.num_experts))
-    out1 = _moe_mlp(cfg1, x, lp)
+    # k=1 isolates expert 0 (all rows on one expert: nothing is dropped)
+    cfg1 = dataclasses.replace(cfg, num_experts_per_tok=1)
+    out1, load = _moe_mlp(cfg1, x, lp)
+    assert load.tolist() == [3, 1, 3]
     np.testing.assert_allclose(np.asarray(out1), np.asarray(want_e0),
                                rtol=1e-5, atol=1e-6)
-
-
-def test_moe_capacity_drops_overflow_tokens():
-    """Tokens routed past an expert's capacity lose that contribution
-    (GShard token dropping); earlier tokens win the slots."""
-    cfg, params = _mk()
-    lp = jax.tree_util.tree_map(lambda a: a[0], params["layers"])
-    d, e = cfg.hidden_size, cfg.num_experts
-    router = np.full((d, e), -1.0, np.float32)
-    router[:, 0] = 1.0  # every token → expert 0 (k=1)
-    lp = dict(lp)
-    lp["router"] = jnp.asarray(router)
-    cfg1 = dataclasses.replace(cfg, num_experts_per_tok=1,
-                               moe_capacity_factor=e / 8.0)  # cap = n/8
-    n = 8
-    x = jnp.ones((n, d), jnp.float32) * 0.1
-    out = _moe_mlp(cfg1, x, lp)
-    # cap = ceil(1·8·(4/8)/4) = 1 → only the first token gets expert 0
-    assert not np.allclose(np.asarray(out[0]), 0.0)
-    np.testing.assert_allclose(np.asarray(out[1:]), 0.0, atol=1e-7)
 
 
 def test_moe_forward_full_and_decode_paths():
@@ -112,8 +93,8 @@ def test_moe_grads_flow_including_router():
 @pytest.mark.parametrize("quant", [False, True])
 def test_moe_hf_logits_parity(tmp_path, quant):
     """Logits parity against transformers Qwen3MoeForCausalLM (the MoE
-    correctness anchor). capacity_factor = E/k makes fixed-capacity
-    dispatch exact (no drops), matching HF's dropless loop."""
+    correctness anchor), on the default path: the block is dropless as
+    HF's loop is."""
     torch = pytest.importorskip("torch")
     transformers = pytest.importorskip("transformers")
 
@@ -135,9 +116,6 @@ def test_moe_hf_logits_parity(tmp_path, quant):
     cfg = config_from_hf(str(out_dir), dtype=jnp.float32)
     assert cfg.num_experts == 4 and cfg.num_experts_per_tok == 2
     assert cfg.moe_intermediate_size == 48 and cfg.use_qk_norm
-    # exact dispatch: cap = ceil(k·N·(E/k)/E) = N
-    cfg = dataclasses.replace(cfg, moe_capacity_factor=cfg.num_experts
-                              / cfg.num_experts_per_tok)
     params = load_hf_params(str(out_dir), cfg,
                             quantize="int8" if quant else "")
 
@@ -160,9 +138,10 @@ def test_moe_hf_logits_parity(tmp_path, quant):
 
 
 def test_moe_expert_parallel_mesh(devices8):
-    """The ep axis is REAL: expert weights placed over a dp1·fsdp2·tp1·ep2
-    mesh, forward jitted with GSPMD-inserted dispatch/combine collectives,
-    output matches the single-device forward."""
+    """The ep axis is REAL: expert weights placed over a dp1·fsdp2·tp2·ep2
+    mesh; with the mesh set (``parallel.mesh.under``) each ep rank computes
+    its own experts' rows and the results are summed over ep
+    (``_expert_mix_sharded``); output matches the single-device forward."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from polyrl_tpu.parallel import mesh as meshlib
@@ -187,13 +166,28 @@ def test_moe_expert_parallel_mesh(devices8):
         logits, _ = decoder.forward(p, cfg, i, po, m)
         return logits
 
-    with mesh:
-        got = fwd(sharded,
-                  jax.device_put(ids, NamedSharding(mesh, P())),
-                  jax.device_put(pos, NamedSharding(mesh, P())),
-                  jax.device_put(mask, NamedSharding(mesh, P())))
+    fwd_ep = meshlib.under(mesh, fwd)   # as the trainer and the engine call
+    assert "psum" in str(meshlib.under(mesh, jax.make_jaxpr(fwd))(
+        sharded, ids, pos, mask))
+    got = fwd_ep(sharded,
+                 jax.device_put(ids, NamedSharding(mesh, P())),
+                 jax.device_put(pos, NamedSharding(mesh, P())),
+                 jax.device_put(mask, NamedSharding(mesh, P())))
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
+
+    # and the gradient through the manual region and its sums
+    def loss(p):
+        logits, _ = decoder.forward(p, cfg, ids, pos, mask)
+        return jnp.sum(jnp.tanh(logits))
+
+    want_g = jax.grad(loss)(params)["layers"]
+    got_g = meshlib.under(mesh, jax.jit(jax.grad(loss)))(sharded)["layers"]
+    for key in ("router", "we_gate", "we_up", "we_down", "wo"):
+        want_k = np.asarray(want_g[key])
+        np.testing.assert_allclose(
+            np.asarray(got_g[key]), want_k, rtol=2e-4,
+            atol=2e-5 * np.abs(want_k).max(), err_msg=key)
 
 
 def test_moe_cb_engine_decode():
@@ -237,45 +231,6 @@ def test_moe_quantize_params_covers_experts_not_router():
     ref, got = np.asarray(ref, np.float32), np.asarray(got, np.float32)
     nrmse = np.sqrt(np.mean((ref - got) ** 2)) / (np.std(ref) + 1e-9)
     assert nrmse < 0.05, nrmse
-
-
-def test_moe_padding_does_not_consume_capacity():
-    """Pad tokens are masked out of routing entirely, so real-token logits
-    cannot depend on pad CONTENT. Without validity masking, pads route by
-    their (identical) embeddings and fill those experts' capacity ahead of
-    later real tokens — then changing pad ids changes which experts fill
-    and which real tokens get dropped."""
-    cfg, params = _mk({"moe_capacity_factor": 1.0})  # tight capacity
-    ids_real = jax.random.randint(jax.random.PRNGKey(5), (2, 6), 1,
-                                  cfg.vocab_size)
-    pad_a = jnp.zeros((2, 10), jnp.int32)
-    pad_b = jax.random.randint(jax.random.PRNGKey(7), (2, 10), 1,
-                               cfg.vocab_size)
-    pos = jnp.broadcast_to(jnp.arange(16), (2, 16))
-    mask = jnp.concatenate([jnp.ones((2, 6)), jnp.zeros((2, 10))], axis=1)
-    a, _ = decoder.forward(params, cfg,
-                           jnp.concatenate([ids_real, pad_a], axis=1),
-                           pos, mask)
-    b, _ = decoder.forward(params, cfg,
-                           jnp.concatenate([ids_real, pad_b], axis=1),
-                           pos, mask)
-    np.testing.assert_allclose(np.asarray(a[:, :6]), np.asarray(b[:, :6]),
-                               rtol=1e-6, atol=1e-7)
-
-
-def test_moe_grouped_matches_ungrouped():
-    """Token grouping (linear-memory dispatch) is numerically identical to
-    one big group when capacity never binds."""
-    cfg_big, params = _mk({"moe_capacity_factor": 2.0, "moe_group_size": 512})
-    cfg_small = dataclasses.replace(cfg_big, moe_group_size=4)
-    ids = jax.random.randint(jax.random.PRNGKey(6), (2, 12), 1,
-                             cfg_big.vocab_size)
-    pos = jnp.broadcast_to(jnp.arange(12), (2, 12))
-    mask = jnp.ones((2, 12))
-    a, _ = decoder.forward(params, cfg_big, ids, pos, mask)
-    b, _ = decoder.forward(params, cfg_small, ids, pos, mask)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                               rtol=1e-5, atol=1e-6)
 
 
 def test_moe_grpo_e2e_fit_step():
@@ -341,8 +296,6 @@ def test_mixtral_hf_logits_parity(tmp_path):
     cfg = config_from_hf(str(out_dir), dtype=jnp.float32)
     assert cfg.num_experts == 4 and cfg.num_experts_per_tok == 2
     assert cfg.moe_intermediate_size == 48 and not cfg.use_qk_norm
-    cfg = dataclasses.replace(cfg, moe_capacity_factor=cfg.num_experts
-                              / cfg.num_experts_per_tok)  # dropless
     params = load_hf_params(str(out_dir), cfg)
 
     rng = np.random.default_rng(0)
@@ -356,17 +309,30 @@ def test_mixtral_hf_logits_parity(tmp_path):
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-4)
 
 
-def test_moe_packed_logprobs_under_ep_match_single(devices8):
-    """Packed (remove-padding) training on the MoE family under a real
-    expert-parallel mesh: the packed logprob pass with experts sharded over
-    ep must match the single-device segment-id pass (packed × ep cell —
-    ep needs no special attention, GSPMD inserts dispatch/combine from the
-    param specs; pack-pad columns are segment 0 and loss-masked, and MoE
-    capacity ignores them via token_valid)."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
+def _count_ep_traces(monkeypatch) -> list:
+    """Count the traces that enter ``decoder._expert_mix_sharded``."""
+    calls = []
+    real = decoder._expert_mix_sharded
 
+    def counted(*args):
+        calls.append(1)
+        return real(*args)
+
+    monkeypatch.setattr(decoder, "_expert_mix_sharded", counted)
+    return calls
+
+
+def test_moe_packed_logprobs_under_ep_match_single(devices8, monkeypatch):
+    """Packed (remove-padding) training on the MoE family under a real
+    expert-parallel mesh, entered as the trainer enters it
+    (``StreamActor(mesh=...)``): the packed logprob pass with experts
+    sharded over ep goes manual over ep (``_expert_mix_sharded``) and matches
+    the single-device segment-id pass (packed × ep cell — ep needs no
+    special attention; pack-pad columns are segment 0 and loss-masked, and
+    route nowhere via token_valid)."""
     from polyrl_tpu.parallel import mesh as meshlib
-    from polyrl_tpu.trainer.actor import _packed_logprobs_entropy
+    from polyrl_tpu.trainer.actor import (ActorConfig, StreamActor,
+                                          _packed_logprobs_entropy)
 
     cfg, params = _mk()
     b, t = 2, 16
@@ -387,13 +353,52 @@ def test_moe_packed_logprobs_under_ep_match_single(devices8):
 
     mesh = meshlib.make_mesh(meshlib.MeshConfig(dp=1, fsdp=2, tp=2, ep=2),
                              devices8)
-    sharded = meshlib.shard_params(mesh, params, decoder.param_specs(cfg))
-    rspec = NamedSharding(mesh, P())
-    with mesh:
-        got_lp, _ = jax.jit(
-            lambda p, i, po, a, s, l: _packed_logprobs_entropy(
-                p, cfg, i, po, a, s, False, False, loss_mask=l)
-        )(sharded, *(jax.device_put(x, rspec)
-                     for x in (ids, pos, am, seg, lm)))
+    calls = _count_ep_traces(monkeypatch)
+    actor = StreamActor(cfg, ActorConfig(lr=1e-4, remat=False), params,
+                        mesh=mesh)
+    assert actor.params["layers"]["we_gate"].sharding.spec[1] == meshlib.EP
+    got_lp, _ = actor.compute_log_prob_packed(
+        {"input_ids": ids, "positions": pos, "attention_mask": am,
+         "segment_ids": seg, "loss_mask": lm}, compute_entropy=False)
+    assert calls, "the trainer's program did not take the ep path"
     np.testing.assert_allclose(np.asarray(got_lp), np.asarray(want_lp),
                                rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("tp,ep", [(1, 2), (2, 2)])
+def test_moe_cb_engine_under_ep_matches_single(devices8, monkeypatch, tp, ep):
+    """The engine under an ep mesh (``CBEngine(mesh=...)``), alone and
+    with tp: prefill and decode programs go manual over the mesh with the
+    expert stacks left whole (and the attention kernels' wrappers are
+    taken without tp too, as a TPU needs on any mesh of several chips),
+    and greedy decoding gives the single-device engine's tokens."""
+    from polyrl_tpu.parallel import mesh as meshlib
+    from polyrl_tpu.rollout.cb_engine import CBEngine
+    from polyrl_tpu.rollout.sampling import SamplingParams
+
+    cfg, params = _mk()
+    kw = dict(pad_token_id=0, kv_cache_dtype=jnp.float32, max_slots=4,
+              page_size=8, max_seq_len=64, prompt_buckets=(8,), num_pages=64)
+    sp = SamplingParams(temperature=0.0, max_new_tokens=8, stop_token_ids=())
+    prompts = [[1, 2, 3, 4, 5], [9, 8, 7]]
+    single = CBEngine(cfg, params, **kw)
+    try:
+        want = [o["token_ids"] for o in
+                single.generate(prompts, sp, timeout=120.0)]
+    finally:
+        single.stop()
+    mesh = meshlib.make_mesh(meshlib.MeshConfig(fsdp=1, tp=tp, ep=ep),
+                             devices8[:tp * ep])
+    calls = _count_ep_traces(monkeypatch)
+    engine = CBEngine(cfg, params, mesh=mesh, **kw)
+    try:
+        assert engine._tp_kv_write() is not None
+        assert engine.params["layers"]["we_down"].sharding.spec[1] == \
+            meshlib.EP
+        got = [o["token_ids"] for o in
+               engine.generate(prompts, sp, timeout=120.0)]
+    finally:
+        engine.stop()
+    # every layer of a prefill and of a decode program, at the least
+    assert len(calls) >= 2 * cfg.num_layers, len(calls)
+    assert got == want, (got, want)

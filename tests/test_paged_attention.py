@@ -163,7 +163,7 @@ def test_decode_gives_rows_without_a_request_length_zero(with_active):
             return paged_attention_ref(
                 q, kp, vp, pt, seq_lens + 1 if before else lens)
 
-        logits, _ = decoder.forward_paged_decode(
+        logits, _, _ = decoder.forward_paged_decode(
             params, cfg, tokens, seq_lens, pools, table, seq_lens,
             attn_fn=attn, active=active)
         return np.asarray(logits)

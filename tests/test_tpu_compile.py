@@ -8,6 +8,7 @@ The topology is described inside a fixture (never at import: one process
 at a time may load the TPU's library, and every xdist worker imports every
 test file), and all such tests live in this one file."""
 
+import functools
 import os
 
 import jax
@@ -18,10 +19,10 @@ from polyrl_tpu.ops import paged_attention as pa
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
+    """The four devices of a described v5e 2x2 host."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs go to /tmp
     try:
@@ -35,9 +36,16 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_2x2[0])
 
 
 # (rows, hq, hkv, d, dtype, page_size, table width): the benchmark's cell,
@@ -60,3 +68,249 @@ def test_decode_kernel_compiles_for_v5e(one_chip, s, hq, hkv, d, dtype, page,
             arg((s, hq, d), dtype), pool, pool,
             arg((s, width), jnp.int32), arg((s,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- the MoE block at qwen3-30b-a3b's published widths ---------------------
+
+MOE_ROWS = [64, 512]     # the cell's decode rows; one prefill chunk
+
+
+@pytest.fixture
+def chip_precision():
+    """The matmul precision a chip run has (none set), not the float32
+    ``highest`` that tests/conftest.py sets for exact CPU parity: XLA's
+    grouped-matmul kernel refuses bf16 operands at float32 precision."""
+    with jax.default_matmul_precision("default"):
+        yield
+
+
+def _moe_cfg():
+    from polyrl_tpu.models import decoder
+
+    return decoder.get_config("qwen3-30b-a3b", num_layers=1)
+
+
+def _layer_shapes(cfg, one_chip):
+    """One layer's weights, as shapes on the described chip."""
+    from polyrl_tpu.models import decoder
+
+    tree = jax.eval_shape(
+        lambda: decoder.init_params(jax.random.PRNGKey(0), cfg))["layers"]
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype,
+                                       sharding=one_chip), tree)
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The dispatchers ask ``jax.default_backend()``, which is the CPU
+    here: steer them onto their TPU kernels for the described chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8])
+@pytest.mark.parametrize("rows", MOE_ROWS)
+def test_grouped_expert_matmul_compiles_for_v5e(one_chip, chip_precision,
+                                                rows, dtype):
+    """The owned grouped matmul over the rows' top-8 choices in whole
+    tiles an expert, 128 experts of 2048x768 (one layer of a stack of 7),
+    in bf16 and with int8 experts: a whole expert's block fits VMEM."""
+    from polyrl_tpu.ops import grouped_matmul as gm
+
+    cfg = _moe_cfg()
+    e, k = cfg.num_experts, cfg.num_experts_per_tok
+    d, f = cfg.hidden_size, cfg.moe_intermediate_size
+    tile = gm.row_tile(rows * k, e)
+    n_tiles = rows * k // tile + e
+    assert tile == (16 if rows == 64 else 64)
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    for n_w, shape in [(2, (d, f)), (1, (f, d))]:    # SwiGLU, then down
+        scales = ((arg((7 * e, shape[1]), jnp.float32),) * n_w
+                  if dtype == jnp.int8 else None)
+        compiled = jax.jit(functools.partial(
+            gm.grouped_matmul_pallas, tile=tile)).lower(
+                arg((n_tiles * tile, shape[0]), jnp.bfloat16),
+                (arg((7 * e, *shape), dtype),) * n_w,
+                arg((n_tiles,), jnp.int32), arg((1,), jnp.int32),
+                scales).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows", MOE_ROWS)
+def test_moe_block_compiles_for_v5e(one_chip, chip_precision, on_tpu, rows):
+    """One layer's whole routed MLP (route, sort, the gather into tiles,
+    the grouped SwiGLU and down projection, weighted sum) at the cell's decode rows and at
+    a prefill chunk's, the experts a layer of a whole stack."""
+    from polyrl_tpu.models import decoder
+
+    cfg = _moe_cfg()
+    lp = _layer_shapes(cfg, one_chip)
+    for key in decoder.EXPERT_KEYS:
+        lp[key] = jax.ShapeDtypeStruct((7, *lp[key].shape), lp[key].dtype,
+                                       sharding=one_chip)
+    x = jax.ShapeDtypeStruct((rows, cfg.hidden_size), jnp.bfloat16,
+                             sharding=one_chip)
+    valid = jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip)
+    compiled = jax.jit(
+        lambda x, lp, v: decoder._moe_mlp(cfg, x, lp, v, layer=3)
+    ).lower(x, lp, valid).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 and "ragged" not in text
+    # no copy of a layer's experts, nothing of size experts x rows
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+def test_moe_block_under_ep_compiles_for_four_v5e(v5e_2x2, chip_precision,
+                                                  on_tpu):
+    """The block with the experts of a stack of 7 sharded over ``ep`` 4,
+    traced with the mesh set as the engine and the trainer set it
+    (``parallel.mesh.under``): each chip runs the two kernels over its own
+    32 experts of the whole stacks, the results are summed, and no chip
+    gathers another's experts."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from polyrl_tpu.models import decoder
+    from polyrl_tpu.parallel import mesh as meshlib
+
+    cfg = _moe_cfg()
+    mesh = meshlib.make_mesh(meshlib.MeshConfig(dp=1, fsdp=1, ep=4),
+                             list(v5e_2x2))
+    specs = decoder.param_specs(cfg)["layers"]
+    tree = jax.eval_shape(
+        lambda: decoder.init_params(jax.random.PRNGKey(0), cfg))["layers"]
+    lp = {}
+    for key, a in tree.items():
+        stacked = key in decoder.EXPERT_KEYS
+        shape = (7, *a.shape[1:]) if stacked else a.shape[1:]
+        spec = specs[key] if stacked else P(*specs[key][1:])
+        lp[key] = jax.ShapeDtypeStruct(shape, a.dtype,
+                                       sharding=NamedSharding(mesh, spec))
+    rows = 64
+    everywhere = NamedSharding(mesh, P())
+    x = jax.ShapeDtypeStruct((rows, cfg.hidden_size), jnp.bfloat16,
+                             sharding=everywhere)
+    valid = jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=everywhere)
+    block = jax.jit(
+        lambda x, lp, v: decoder._moe_mlp(cfg, x, lp, v, layer=3))
+    compiled = meshlib.under(mesh, lambda *a: block.lower(*a).compile())(
+        x, lp, valid)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 and "all-reduce" in text
+    assert "all-gather" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+def test_moe_decode_layer_compiles_for_v5e(one_chip, chip_precision, on_tpu):
+    """One MoE decode layer at the cell's shapes: 64 rows (and the sink
+    row), 32/4 heads of 128, the owned paged-attention and KV-write
+    kernels, 128 experts top-8, head left out."""
+    from polyrl_tpu.models import decoder
+
+    cfg = _moe_cfg()
+    s, page, width, n_pages = 65, 64, 96, 4701
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    lp = _layer_shapes(cfg, one_chip)
+    pool = arg((cfg.num_kv_heads, n_pages, page, cfg.head_dim_), jnp.bfloat16)
+
+    def layer(x, lp, k_pool, v_pool, table, lens, active):
+        cos, sin = decoder.rope_cos_sin(cfg, lens[:, None])
+        q, k, v = decoder._attn_qkv(cfg, x, lp, cos, sin, (s, 1))
+        k_pool, v_pool = pa.paged_kv_write_pallas(
+            k_pool, v_pool, table[:, 0], lens % page, k[:, 0], v[:, 0])
+        attn = pa.paged_attention_pallas(q[:, 0], k_pool, v_pool, table,
+                                         lens + 1)
+        x, load = decoder._attn_out_mlp(cfg, x, attn.reshape(s, -1), lp,
+                                        active)
+        return x, load, k_pool, v_pool
+
+    compiled = jax.jit(layer, donate_argnums=(2, 3)).lower(
+        arg((s, cfg.hidden_size), jnp.bfloat16), lp, pool, pool,
+        arg((s, width), jnp.int32), arg((s,), jnp.int32),
+        arg((s,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 4   # attention, write, 2 matmuls
+
+
+def test_moe_decode_layer_under_ep_compiles_for_four_v5e(v5e_2x2,
+                                                         chip_precision,
+                                                         on_tpu):
+    """The decode layer as an engine on an ``ep`` 4 mesh runs it: no
+    Mosaic kernel lowers in a program of several chips outside a shard_map
+    over every axis, so the attention and the KV write go through the
+    engine's wrappers without any ``tp``, and the block goes manual
+    itself."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from polyrl_tpu.models import decoder
+    from polyrl_tpu.parallel import mesh as meshlib
+
+    cfg = _moe_cfg()
+    mesh = meshlib.make_mesh(meshlib.MeshConfig(dp=1, fsdp=1, ep=4),
+                             list(v5e_2x2))
+    s, page, width, n_pages = 65, 64, 96, 1201
+    specs = decoder.param_specs(cfg)["layers"]
+    tree = jax.eval_shape(
+        lambda: decoder.init_params(jax.random.PRNGKey(0), cfg))["layers"]
+    lp = {key: jax.ShapeDtypeStruct(
+        a.shape[1:], a.dtype,
+        sharding=NamedSharding(mesh, P(*specs[key][1:])))
+        for key, a in tree.items()}
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt,
+                                    sharding=NamedSharding(mesh, P()))
+
+    pool = arg((cfg.num_kv_heads, n_pages, page, cfg.head_dim_), jnp.bfloat16)
+    attn_fn = pa.make_tp_paged_attention(mesh)
+    write_fn = pa.make_tp_paged_kv_write(mesh)
+
+    def layer(x, lp, k_pool, v_pool, table, lens, active):
+        cos, sin = decoder.rope_cos_sin(cfg, lens[:, None])
+        q, k, v = decoder._attn_qkv(cfg, x, lp, cos, sin, (s, 1))
+        k_pool, v_pool = write_fn(
+            k_pool, v_pool, table[:, 0], lens % page, k[:, 0], v[:, 0])
+        attn = attn_fn(q[:, 0], k_pool, v_pool, table, lens + 1)
+        x, load = decoder._attn_out_mlp(cfg, x, attn.reshape(s, -1), lp,
+                                        active)
+        return x, load, k_pool, v_pool
+
+    jitted = jax.jit(layer, donate_argnums=(2, 3))
+    compiled = meshlib.under(mesh, lambda *a: jitted.lower(*a).compile())(
+        arg((s, cfg.hidden_size), jnp.bfloat16), lp, pool, pool,
+        arg((s, width), jnp.int32), arg((s,), jnp.int32),
+        arg((s,), jnp.bool_))
+    assert compiled.as_text().count("tpu_custom_call") >= 4
+
+
+def test_qwen3_30b_a3b_preset_equals_the_benchmark_file():
+    """The program's preset with the file's overrides is the file, on
+    every size: the four MoE keys reach the program through the preset
+    alone (``harness.MODEL_FIELDS`` does not carry them)."""
+    from benchmark.lib import harness
+    from polyrl_tpu.models import decoder
+
+    config = harness.load_config(os.path.join(
+        harness.BENCH_DIR, "configs", "qwen3-30b-a3b.json"))
+    raw, fields = config["config"], harness.MODEL_FIELDS
+    cfg = decoder.get_config(config["preset"],
+                             **harness.model_overrides(config))
+    moe = {"num_experts": "num_experts",
+           "num_experts_per_tok": "num_experts_per_tok",
+           "moe_intermediate_size": "moe_intermediate_size",
+           "norm_topk_prob": "norm_topk_prob"}
+    for key, field in {**fields, **moe}.items():
+        assert getattr(cfg, field) == raw[key], key
+    # what the preset alone says, beside the depth the file cuts
+    preset = decoder.get_config(config["preset"])
+    for key, field in moe.items():
+        assert getattr(preset, field) == raw[key], key
+    assert preset.rms_norm_eps == raw["rms_norm_eps"]
+    assert config["reduced"] == ["num_hidden_layers"]
+    assert preset.num_layers == 48 and cfg.num_layers == 7
+    assert raw["decoder_sparse_step"] == 1 and raw["mlp_only_layers"] == []
