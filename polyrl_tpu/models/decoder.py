@@ -30,7 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from polyrl_tpu.models.quant import QuantWeight, mm, moe_mm, unembed
+from polyrl_tpu.models.quant import (LoraWeight, QuantWeight, mm, moe_mm,
+                                     unembed)
 from polyrl_tpu.ops.attention import attention, causal_mask
 from polyrl_tpu.ops.grouped_matmul import row_tile, tiled_layout
 from polyrl_tpu.parallel.mesh import DP, EP, FSDP, SP, TP
@@ -589,6 +590,36 @@ def _head(cfg, params, x, logits_for=None):
         return unembed(x, head, eq)
 
 
+def samples_in_head(cfg, params, use_filters: bool, many_chips: bool) -> bool:
+    """Whether a decode step can draw its token inside the output matmul
+    (``head_and_sample``), from what its program is built on: no top-p or
+    top-k row (those need the sorted logits), a plain array for a head (a
+    ``QuantWeight`` or LoRA head keeps ``_head``), one chip (a head sharded
+    over the vocabulary would need the running values combined across
+    shards) and a TPU."""
+    head = params["embed" if cfg.tie_word_embeddings else "lm_head"]
+    return (not use_filters and not many_chips
+            and not isinstance(head, (QuantWeight, LoraWeight))
+            and jax.default_backend() == "tpu")
+
+
+def head_and_sample(cfg, params, x, rng, temps):
+    """Final norm, then output matmul and sampler as one kernel
+    (``ops/fused_sample.py``) for decode rows ``x`` [S, d]: ``(token [S],
+    logp [S])``, drawn and scored as ``sampling.sample_token_vec`` without
+    filters, the [S, V] logits never written. A tied head is read as the
+    embedding's [V, d] rows. The kernel runs interpreted off a TPU (tests
+    alone get here then: see ``samples_in_head``)."""
+    from polyrl_tpu.ops.fused_sample import head_sample_pallas
+
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        tied = cfg.tie_word_embeddings
+        return head_sample_pallas(
+            x, params["embed" if tied else "lm_head"], rng, temps, tied=tied,
+            interpret=jax.default_backend() != "tpu")
+
+
 def _layer_forward(cfg, x, lp, cos, sin, mask, layer_cache, attn_fn=None,
                    token_valid=None):
     """One decoder layer. layer_cache: None or (k_cache, v_cache) [B, S, Hkv, D]
@@ -785,13 +816,15 @@ def forward_paged_decode(
     attn_fn=None,
     active: jnp.ndarray | None = None,  # [S] bool — mask KV writes
     kv_write_fn=None,  # TP override (ops.paged_attention.make_tp_paged_kv_write)
+    head_fn=None,  # (cfg, params, x [S, d]) -> first result; default _head
 ) -> tuple[jnp.ndarray, tuple, jnp.ndarray | None]:
     """One decode step for every slot at once: write the new token's KV into
     each slot's current page, then paged-attend over [0, seq_len]. Returns
     (logits [S, V] f32, updated pools, the MoE blocks' load summed over the
     layers as ``_moe_mlp`` counts it, None for a dense model). Static shapes
     regardless of the mix of live requests — the continuous-batching hot
-    loop.
+    loop. ``head_fn`` takes ``_head``'s place on the last layer's output and
+    its result the logits' (the engine's step hands ``head_and_sample``).
 
     ``active`` routes INACTIVE slots' writes to the null page 0: a finished
     slot's pages return to the allocator while its device page_table row is
@@ -851,7 +884,8 @@ def forward_paged_decode(
         x, load = _attn_out_mlp(cfg, x, attn_out, lp, active, l)
         if load is not None:
             moe_load = load if moe_load is None else moe_load + load
-    return _head(cfg, params, x), (tuple(k_pools), tuple(v_pools)), moe_load
+    return ((head_fn or _head)(cfg, params, x),
+            (tuple(k_pools), tuple(v_pools)), moe_load)
 
 
 def prefill_into_pages(

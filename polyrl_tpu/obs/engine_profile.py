@@ -69,6 +69,8 @@ two samples give a rate over any window, with no profiler session):
 
 - ``decode_dispatches`` / ``decode_steps_done`` — fused decode dispatches
   enqueued, and fused steps whose results have landed on the host;
+  ``fused_sample_steps`` — those of them whose program drew its tokens
+  inside the output matmul (``decoder.head_and_sample``);
 - ``device_busy_s`` — seconds with device work outstanding: an interval
   opens when a dispatch is enqueued with nothing outstanding and closes
   when a landed result leaves nothing newer outstanding. Seconds are added
@@ -137,7 +139,8 @@ class EngineLoopProfiler:
         self._win_prev = [0.0] * 5
         self._win_busy_mark = 0.0  # device_busy_s at the last iteration close
         # completion stamps: dispatches whose results will land, oldest
-        # first, as their fused decode steps (0 for a prefill)
+        # first, as (their fused decode steps (0 for a prefill), whether
+        # those sampled inside the head)
         self._landing: collections.deque = collections.deque()
         self._tail_unlanded = False  # dispatched after them, lands nothing
         self._busy_from: float | None = None  # busy not yet counted, since
@@ -145,6 +148,7 @@ class EngineLoopProfiler:
         self.device_busy_at_s = 0.0
         self.decode_dispatches = 0
         self.decode_steps_done = 0
+        self.fused_sample_steps = 0
         self.fetch_s = 0.0
         self.fetch_n = 0
         self.programs_built = 0
@@ -257,10 +261,11 @@ class EngineLoopProfiler:
 
     # -- completion stamps ----------------------------------------------------
 
-    def on_dispatch(self, kind: str, steps: int = 0,
-                    lands: bool = True) -> None:
+    def on_dispatch(self, kind: str, steps: int = 0, lands: bool = True,
+                    fused_sample: bool = False) -> None:
         """A dispatch was just enqueued on the device; ``steps``: the
-        decode steps it fuses. ``lands`` False: it returns nothing the
+        decode steps it fuses, ``fused_sample``: they sample inside the
+        head. ``lands`` False: it returns nothing the
         host fetches (a chunked prefill's mid-chunk),
         so a later dispatch's landing stands for it."""
         now = self._clock()
@@ -268,7 +273,7 @@ class EngineLoopProfiler:
             if self._busy_from is None:
                 self._busy_from = now
             if lands:
-                self._landing.append(steps)
+                self._landing.append((steps, fused_sample))
                 self._tail_unlanded = False
             else:
                 self._tail_unlanded = True
@@ -281,7 +286,10 @@ class EngineLoopProfiler:
         now = self._clock()
         with self._lock:
             for _ in range(min(n, len(self._landing))):
-                self.decode_steps_done += self._landing.popleft()
+                steps, fused_sample = self._landing.popleft()
+                self.decode_steps_done += steps
+                if fused_sample:
+                    self.fused_sample_steps += steps
             self._count_busy(now, bool(self._landing)
                              or self._tail_unlanded)
 
@@ -351,6 +359,7 @@ class EngineLoopProfiler:
             out = {
                 "decode_dispatches": self.decode_dispatches,
                 "decode_steps_done": self.decode_steps_done,
+                "fused_sample_steps": self.fused_sample_steps,
                 "device_busy_s": round(self.device_busy_s, 6),
                 "device_busy_at_s": round(self.device_busy_at_s, 6),
                 "loop_wall_s": round(self.wall_s, 6),

@@ -884,6 +884,7 @@ class CBEngine:
         key = (use_filters, k, gshape)
         if key not in self._step_fns:
             cfg, pad = self.cfg, self.pad_token_id
+            fused = self._samples_in_head(use_filters)
             paged_attn = self._tp_paged_attn()
             kv_write = self._tp_kv_write()
             grouped_attn = self._grouped_attn_fn() if gshape else None
@@ -906,15 +907,24 @@ class CBEngine:
 
                 def body(carry, _):
                     kp, vp, rng, seq_lens, last_tokens, n_generated, active = carry
-                    logits, (kp, vp), moe_load = decoder.forward_paged_decode(
+                    head_fn = None
+                    if fused:  # token and log-prob come out of the head
+                        with jax.named_scope("sample"):
+                            rng, sub = jax.random.split(rng)
+                        head_fn = functools.partial(
+                            decoder.head_and_sample, rng=sub, temps=temps)
+                    out, (kp, vp), moe_load = decoder.forward_paged_decode(
                         params, cfg, last_tokens, seq_lens, (kp, vp),
                         page_table, seq_lens, active=active,
-                        attn_fn=attn, kv_write_fn=kv_write)
+                        attn_fn=attn, kv_write_fn=kv_write, head_fn=head_fn)
                     with jax.named_scope("sample"):
-                        rng, sub = jax.random.split(rng)
-                        token, logp = sample_token_vec(
-                            logits, sub, temps, top_ps, top_ks,
-                            use_filters=use_filters)
+                        if fused:
+                            token, logp = out
+                        else:
+                            rng, sub = jax.random.split(rng)
+                            token, logp = sample_token_vec(
+                                out, sub, temps, top_ps, top_ks,
+                                use_filters=use_filters)
                         n_gen = n_generated + active.astype(jnp.int32)
                         hit_stop = jnp.any(token[:, None] == stop_table,
                                            axis=-1)
@@ -1055,6 +1065,14 @@ class CBEngine:
         programs outside a shard_map over every axis, whatever ``tp`` is
         (an ``ep``-only mesh too; PERF.md section 6, PR 27)."""
         return self.mesh is not None and self.mesh.size > 1
+
+    def _samples_in_head(self, use_filters: bool) -> bool:
+        """Whether the decode step draws its tokens inside the output
+        matmul (``decoder.samples_in_head``). One answer a ``use_filters``
+        for the engine's life: a weight update keeps the tree's
+        structure."""
+        return decoder.samples_in_head(self.cfg, self.params, use_filters,
+                                       self._many_chips())
 
     def _tp_paged_attn(self):
         """On a mesh of several chips the Pallas paged-attention custom
@@ -2521,13 +2539,15 @@ class CBEngine:
             self._dev_state["tok_buf"] = jnp.asarray(buf)
 
 
-    def _enqueue_output(self, entry) -> None:
-        """Queue a dispatch output for the fetcher thread (wakes it)."""
+    def _enqueue_output(self, entry, fused_sample: bool = False) -> None:
+        """Queue a dispatch output for the fetcher thread (wakes it).
+        ``fused_sample``: its decode steps sampled inside the head."""
         if self.profiler is not None:
             # before the fetcher can see the entry: it lands them in order
             kind = entry[0]
             self.profiler.on_dispatch(
-                kind, entry[3] if kind in ("step", "spec") else 0)
+                kind, entry[3] if kind in ("step", "spec") else 0,
+                fused_sample=fused_sample)
         with self._fetch_cv:
             self._emit_q.append(entry)
             self._fetch_cv.notify_all()
@@ -2886,7 +2906,8 @@ class CBEngine:
         self._enqueue_output(("step", (token, logp, done, moe_load),
                              [(int(i), int(self._slot_gen[i]))
                               for i in np.flatnonzero(self._active)],
-                             self.steps_per_dispatch, self.weight_version))
+                             self.steps_per_dispatch, self.weight_version),
+                             fused_sample=self._samples_in_head(use_filters))
         with self._phase("accounting"):
             self._deck_dispatch()
         # run ahead up to pipeline_depth dispatches: older outputs stream

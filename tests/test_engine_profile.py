@@ -122,8 +122,8 @@ def test_unattributed_residual_lands_in_other():
 PROFILER_INFO_KEYS = {
     "device_frac", "host_overhead_frac", "accounting_frac",
     "loop_attributed_frac", "decode_dispatches", "decode_steps_done",
-    "device_busy_s", "device_busy_at_s", "loop_wall_s", "loop_host_s",
-    "programs_built"}
+    "fused_sample_steps", "device_busy_s", "device_busy_at_s", "loop_wall_s",
+    "loop_host_s", "programs_built"}
 
 
 def test_window_flip_and_device_host_split():
@@ -165,6 +165,13 @@ def test_window_flip_and_device_host_split():
     assert fields["loop_host_s"] == pytest.approx(3.0)
     assert fields["decode_dispatches"] == 1
     assert fields["decode_steps_done"] == 8
+    # steps sampled inside the head move at the landing with them
+    assert fields["fused_sample_steps"] == 0
+    prof.on_dispatch("step", steps=8, fused_sample=True)
+    assert prof.counters()["fused_sample_steps"] == 0
+    prof.on_landed(1)
+    assert prof.counters()["fused_sample_steps"] == 8
+    assert prof.counters()["decode_steps_done"] == 16
 
 
 def test_phase_taxonomy_and_fetch_counters():

@@ -70,6 +70,35 @@ def test_decode_kernel_compiles_for_v5e(one_chip, s, hq, hkv, d, dtype, page,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# -- the head that samples (ops/fused_sample.py) ---------------------------
+
+# (rows, hidden, vocabulary, tied): the two cells' heads (152064 is whole
+# tiles, 151936 = 128 x 1187 leaves a ragged last one), and qwen3-1.7b's
+# tied embedding read as [V, d]
+@pytest.mark.parametrize("s,d,v,tied", [
+    (65, 3584, 152064, False),
+    (65, 2048, 151936, False),
+    (65, 2048, 151936, True),
+])
+def test_head_sample_kernel_compiles_for_v5e(one_chip, s, d, v, tied):
+    """No ``chip_precision`` here: the kernel pins DEFAULT on its dot, so
+    it compiles under the ``highest`` that tests/conftest.py sets."""
+    from polyrl_tpu.ops import fused_sample as fs
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    head = arg((v, d) if tied else (d, v), jnp.bfloat16)
+    compiled = jax.jit(functools.partial(
+        fs.head_sample_pallas, tied=tied)).lower(
+            arg((s, d), jnp.bfloat16), head, arg((2,), jnp.uint32),
+            arg((s,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "head_sample" in text
+    # no [rows, vocabulary] array, whatever its dtype, beside the kernel
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**20
+
+
 # -- the MoE block at qwen3-30b-a3b's published widths ---------------------
 
 MOE_ROWS = [64, 512]     # the cell's decode rows; one prefill chunk

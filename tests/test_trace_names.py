@@ -106,6 +106,22 @@ def test_lowered_text_carries_the_program_name_and_every_scope(tiny, program):
             (program, scope)
 
 
+def test_the_step_that_samples_in_the_head_keeps_both_scopes(tiny,
+                                                            monkeypatch):
+    """``head_sample_ms`` reads scopes ``head`` and ``sample`` inside
+    ``jit_step``: the fused step has its kernel under ``head`` (with the
+    final norm) and the key split, the stop check and the ``where``s under
+    ``sample``."""
+    monkeypatch.setattr(decoder, "samples_in_head",
+                        lambda cfg, params, use_filters, many: True)
+    text = _lower_step(_engine(tiny)).as_text(debug_info=True)
+    assert "module @jit_step " in text
+    for scope in SCOPES + ("sample",):
+        assert re.search(rf'loc\("(?:[^"]*[/(])?{scope}\)*/', text), scope
+    assert re.search(r'loc\("[^"]*head/[^"]*head_sample', text)
+    assert not re.search(r'loc\("[^"]*sample/[^"]*head_sample', text)
+
+
 def test_every_engine_program_has_a_name_of_its_own(tiny):
     """``decode_step_ms`` matches ``jit_step``: only the fused decode
     program may start with ``step``, and no two programs share a name."""
@@ -189,6 +205,12 @@ def test_each_pallas_call_we_own_has_a_name():
         walk(jax.make_jaxpr(fn)(*args).jaxpr)
         return found
 
+    from polyrl_tpu.ops import fused_sample as fs
+
+    assert names(
+        lambda *a: fs.head_sample_pallas(*a, interpret=True),
+        jnp.zeros((2, 16)), jnp.zeros((16, 256)), jax.random.PRNGKey(0),
+        jnp.ones((2,))) == ["head_sample"]
     hkv, n, ps, d, s = 2, 8, 8, 128, 2
     pool = jnp.zeros((hkv, n, ps, d), jnp.float32)
     q = jnp.zeros((s, 4, d), jnp.float32)

@@ -64,8 +64,9 @@ deck/ledger/spill bookkeeping share), ``engine/host_overhead_frac``
 riding the flat ``server_info`` fields the manager forwards per
 instance, plus the balancer-side ``pool/balance_device_frac`` windowed
 median. The same fields carry the cumulative completion-stamp counters
-(``decode_steps_done``, ``device_busy_s``, ``loop_host_s``,
-``stream_lag_s``, ``programs_built``, ...: ``statusz.CUMULATIVE_INFO_KEYS``),
+(``decode_steps_done``, ``fused_sample_steps``, ``device_busy_s``,
+``loop_host_s``, ``stream_lag_s``, ``programs_built``, ...:
+``statusz.CUMULATIVE_INFO_KEYS``),
 which the server's time-series feed also lands as ``engine/<key>``.
 The training health
 plane (obs/rlhealth.py) emits ``training/*`` — distribution summaries
