@@ -302,6 +302,28 @@ def verdict(out: dict, rehearse: bool) -> bool:
     return bool(ok)
 
 
+def compared(out: dict, config: dict, rehearse: bool) -> dict:
+    """Each number that ``verdict`` holds to a limit, beside that limit
+    (all are "at most"): printed with every run, last on standard error
+    and last in the result's line, so that a run that is not ``correct``
+    says by how much."""
+    c, limits = out["checks"], config["correct"]
+    ref = c["reference"]
+    rows = {
+        "logprob_mean_abs_diff": (ref["logprob_mean_abs_diff"],
+                                  limits["logprob_mean_abs_diff_max"]),
+        "logprob_max_abs_diff": (ref["logprob_max_abs_diff"],
+                                 limits["logprob_max_abs_diff_max"]),
+        "requests_failed": (out["failed"], 0),
+        "engine_recoveries": (c["engine_recoveries"], 0),
+        "kernels_off_their_tpu_path": (0 if c["kernels_ok"] else 1, 0),
+    }
+    if not rehearse:
+        rows["compile_seconds_in_window"] = (
+            c["compile_seconds_in_window"], MAX_COMPILE_S_IN_WINDOW)
+    return {k: {"value": v, "limit": lim} for k, (v, lim) in rows.items()}
+
+
 def start_watchdog(limit_s: float) -> None:
     """A run that hangs must end itself and its manager, inside the first
     run's allowance: dump every thread's stack and exit 4, printing no

@@ -41,26 +41,20 @@ def rng_for(seed: int, stream: int = 0) -> np.random.Generator:
     return np.random.default_rng(np.random.SeedSequence([int(seed), stream]))
 
 
-def steady_plan(mix: dict, seed: int, vocab: int, free_pages: int,
-                page_size: int, max_requests: int):
-    """The long requests of a ``steady_decode`` mix: the largest number n
-    (at most ``max_requests``) whose n prompt-length quantiles, each with
-    its answer budget, fit ``free_pages`` when admission reserves prompt
-    plus budget in whole pages. The same n lengths in the same shuffled
-    order for every seed; the seed draws the token ids. (The order is work:
-    a request prefilled early decodes while the others prefill, so the
-    order sets the contexts the window starts from. With the order drawn
+def steady_plan(mix: dict, seed: int, vocab: int):
+    """The requests of a ``steady_decode`` mix: as many as the mix's
+    ``offered_requests`` says, whatever the engine can hold of them (how
+    many it admits is the engine's, and the number a change to it moves).
+    Their lengths are that many prompt-length quantiles, the same in the
+    same shuffled order for every seed; the seed draws the token ids. (The
+    order is work: a request prefilled early decodes while the others
+    prefill, so the order sets the contexts the window starts from, and
+    which requests an engine short of pages admits. With the order drawn
     from the seed, runs spread by 0.6%; PERF.md section 6.) Returns, in the
     order of submission, {"prompt": [ids], "budget": max_new_tokens,
     "rank": place of this length among the n, shortest first}."""
+    n = int(mix["offered_requests"])
     budget = quantile(mix["answer_tokens"], 0.5)
-
-    def pages(lengths):
-        return sum(-(-(n + budget) // page_size) for n in lengths)
-
-    n = max_requests
-    while n > 1 and pages(size_set(mix["prompt_tokens"], n)) > free_pages:
-        n -= 1
     lengths = size_set(mix["prompt_tokens"], n)
     rng = rng_for(seed)
     # ids in [1, vocab): 0 is the engine's pad token

@@ -258,6 +258,21 @@ def _clip(s, d, window):
     return max(s, window[0]), min(s + d, window[1])
 
 
+def whole_programs(trace: dict | None, program_prefix: str) -> list:
+    """(start_ns, end_ns), in order, of the programs on the first device
+    plane whose ``XLA Modules`` name starts with ``program_prefix`` and
+    that lie wholly inside the window: one that an edge of the window (or
+    of the profiler's session, which ends just outside it) cuts is a
+    program with part of its time."""
+    if not trace or not trace["device"]:
+        return []
+    plane = trace["device"][sorted(trace["device"])[0]]
+    win = trace["window"]
+    return sorted((s, s + d) for n, s, d in plane["modules"]
+                  if n.startswith(program_prefix)
+                  and (win is None or (s >= win[0] and s + d <= win[1])))
+
+
 def scope_seconds(trace: dict | None, scope: str,
                   program_prefix: str) -> tuple[float, int] | None:
     """(device seconds of the operations under ``scope`` inside programs
@@ -265,15 +280,10 @@ def scope_seconds(trace: dict | None, scope: str,
     those programs) on the first device plane, inside the window; only
     programs that lie wholly inside it count. None when there is no such
     program or no operation carries the scope."""
-    if not trace or not trace["device"]:
-        return None
-    plane = trace["device"][sorted(trace["device"])[0]]
-    win = trace["window"]
-    progs = sorted((s, s + d) for n, s, d in plane["modules"]
-                   if n.startswith(program_prefix)
-                   and (win is None or (s >= win[0] and s + d <= win[1])))
+    progs = whole_programs(trace, program_prefix)
     if not progs:
         return None
+    plane = trace["device"][sorted(trace["device"])[0]]
     starts = [a for a, _b in progs]
     total, hits = 0.0, 0
     for name, path, s, d in plane["ops"]:

@@ -144,14 +144,20 @@ class RolloutPlane:
         for t in self.clients:
             t.join(timeout=30.0)
 
-    def sample_server_info(self) -> None:
+    def server_info(self) -> dict | None:
+        """``GET /get_server_info`` now; None when the server did not
+        answer."""
         url = f"http://{self.srv.endpoint}/get_server_info"
         try:
             with urllib.request.urlopen(url, timeout=5.0) as r:
-                self.info_samples.append(
-                    (time.monotonic(), json.loads(r.read())))
+                return json.loads(r.read())
         except OSError:
-            pass
+            return None
+
+    def sample_server_info(self) -> None:
+        info = self.server_info()
+        if info is not None:
+            self.info_samples.append((time.monotonic(), info))
 
     def poll_server_info(self) -> None:
         """Sample ``GET /get_server_info`` twice a second until stopped."""

@@ -71,10 +71,22 @@ def _attention(q, k, v):
     return out.reshape(n_blk * Q_BLOCK, hq, d)[:t]
 
 
-@functools.partial(jax.jit, static_argnames=("sizes", "n_score"))
-def _score(params, tokens, sizes, n_score):
+MATMULS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+
+
+def _int8(w):
+    """A matmul weight [in, out] as weight-only int8 would hold it: one
+    scale an output channel, 127 steps either side of zero."""
+    scale = jnp.max(jnp.abs(w), axis=0, keepdims=True) / 127.0
+    return jnp.round(w / jnp.maximum(scale, 1e-30)) * scale
+
+
+@functools.partial(jax.jit, static_argnames=("sizes", "n_score", "weights"))
+def _score(params, tokens, sizes, n_score, weights=""):
     """Log-probability and entropy of tokens[-n_score:] given what comes
-    before each, for one sequence ``tokens`` [T]."""
+    before each, for one sequence ``tokens`` [T]. ``weights="int8"`` is the
+    control of ``correct`` (``benchmark/tests/control_on_chip.py``): the
+    same forward with every matmul weight rounded to int8."""
     (hq, hkv, hd, theta, eps, qk_norm, bias, tied) = sizes
     f32 = jnp.float32
     t = tokens.shape[0]
@@ -83,6 +95,8 @@ def _score(params, tokens, sizes, n_score):
 
     def layer(x, lp):
         lp = jax.tree_util.tree_map(lambda a: a.astype(f32), lp)
+        if weights == "int8":
+            lp = {k: _int8(v) if k in MATMULS else v for k, v in lp.items()}
         h = _rms(x, lp["attn_norm"], eps)
         q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
         if bias:
@@ -105,6 +119,8 @@ def _score(params, tokens, sizes, n_score):
     # position i predicts token i + 1
     pred = jax.lax.dynamic_slice_in_dim(x, t - n_score - 1, n_score, 0)
     head = (params["embed"].T if tied else params["lm_head"]).astype(f32)
+    if weights == "int8":
+        head = _int8(head)
     logp = jax.nn.log_softmax(pred @ head, axis=-1)
     tgt = tokens[t - n_score:]
     lp_tok = jnp.take_along_axis(logp, tgt[:, None], axis=-1)[:, 0]
@@ -112,7 +128,7 @@ def _score(params, tokens, sizes, n_score):
     return lp_tok, ent
 
 
-def score(params, c: dict, tokens, n_score: int):
+def score(params, c: dict, tokens, n_score: int, weights: str = ""):
     """(log-probabilities, entropies), each [n_score] float32 on the host,
     of the last ``n_score`` tokens of ``tokens``. ``c`` is the
     configuration's ``config`` dict (published key names)."""
@@ -126,5 +142,5 @@ def score(params, c: dict, tokens, n_score: int):
              bool(c.get("tie_word_embeddings", False)))
     with jax.default_matmul_precision("highest"):
         lp, ent = _score(params, jnp.asarray(tokens, jnp.int32), sizes,
-                         int(n_score))
+                         int(n_score), weights)
     return np.asarray(lp), np.asarray(ent)

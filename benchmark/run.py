@@ -10,7 +10,8 @@ with the plane and pattern it names (``benchmark/planes``,
 are files found by name, so a new cell is new files and one new entry,
 and this file is not edited. The last line of
 standard output is one JSON object: ``correct``, ``attempted``,
-``failed``, ``metrics``, ``device`` and, traced, ``breakdown``.
+``failed``, ``metrics``, ``device``, traced ``breakdown``, and last
+``compared``: each number ``correct`` holds to a limit, beside it.
 
 Exits non-zero and prints no result without a TPU, with fewer chips than
 the cell asks for, or with a device kind whose peaks are not in
@@ -68,6 +69,10 @@ def main(argv=None) -> int:
                     bool(args.trace), counter, T_PROC0)
 
     out["correct"] = harness.verdict(out, args.rehearse_cpu)
+    compared = harness.compared(out, config, args.rehearse_cpu)
+    for name, row in compared.items():
+        print(f"compared {name}: {row['value']:g} (limit {row['limit']:g})",
+              file=sys.stderr, flush=True)
     observed = out.pop("observed")
     observed.update(config=config, mix=mix, peaks=device.peaks,
                     end_to_end=out["end_to_end"], checks=out["checks"])
@@ -81,7 +86,8 @@ def main(argv=None) -> int:
                           "attempted": out["attempted"],
                           "failed": out["failed"],
                           "failures": out.get("failures", []),
-                          "device": {"platform": device.platform}}))
+                          "device": {"platform": device.platform},
+                          "compared": compared}))
         return 0 if out["correct"] else 1
 
     metrics = {}
@@ -105,6 +111,7 @@ def main(argv=None) -> int:
         line["device"]["window_s"] = reduced["window_s"]
         line["breakdown"] = {"device_ops": reduced["device_ops"],
                              "idle_gaps": reduced["idle_gaps"]}
+    line["compared"] = compared     # last: the end of the line is kept
     print(json.dumps(line))
     return 0
 

@@ -23,10 +23,9 @@ def test_percentile_interpolates_between_order_statistics():
 
 def test_every_seed_gets_the_same_sizes_in_the_same_order():
     mix = traffic.load_mix("rollout-long")
-    args = (1000, 2193, 64, 64)
-    a = traffic.steady_plan(mix, 7, *args)
-    b = traffic.steady_plan(mix, 7, *args)
-    c = traffic.steady_plan(mix, 2**31 + 12345, *args)  # over 32 signed bits
+    a = traffic.steady_plan(mix, 7, 1000)
+    b = traffic.steady_plan(mix, 7, 1000)
+    c = traffic.steady_plan(mix, 2**31 + 12345, 1000)  # over 32 signed bits
     assert a == b
     assert a != c
 
@@ -35,15 +34,17 @@ def test_every_seed_gets_the_same_sizes_in_the_same_order():
                                    for p in plan)
     assert sizes(a) == sizes(c)
     # the order of submission is work (what decodes while the rest
-    # prefills), so it is the same for every seed, and shuffled
+    # prefills, and what an engine short of pages admits), so it is the
+    # same for every seed, and shuffled
     ranks = [p["rank"] for p in a]
     assert ranks == [p["rank"] for p in c] and ranks != sorted(ranks)
     lo, hi = mix["prompt_tokens"]["lo"], mix["prompt_tokens"]["hi"]
     assert all(lo <= len(p["prompt"]) <= hi for p in a)
     assert all(0 < t < 1000 for p in a for t in p["prompt"])
-    # what the pool admits: prompt plus budget, in whole pages
-    assert len(a) == 18
-    assert sum(-(-(len(p["prompt"]) + p["budget"]) // 64) for p in a) <= 2193
+    # what the client offers is the mix's number, not the engine's
+    assert len(a) == mix["offered_requests"] == 18
+    assert len(traffic.steady_plan(dict(mix, offered_requests=40), 7,
+                                   1000)) == 40
     ranked = sorted(a, key=lambda p: p["rank"])
     assert [len(p["prompt"]) for p in ranked] == sorted(
         len(p["prompt"]) for p in a)

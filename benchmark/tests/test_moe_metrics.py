@@ -53,9 +53,7 @@ def _obs(samples, **over):
     obs = {"config": {"config": config}, "peaks": {"bytes": 1e9},
            "mix": {"engine": {"steps_per_dispatch": 2}},
            "window": (0.0, 10.0),
-           "trace": {"window_s": 4.0,
-                     "modules": [("/device:TPU:0", "jit_step(1)", 0.0, 4e-6),
-                                 ("/device:TPU:0", "jit_step(1)", 1.0, 4e-6)]},
+           "trace": {"window_s": 4.0},
            "kv_tokens_at_end": 1000.0, "tokens_in_window": 100.0,
            "server_info": samples}
     obs.update(over)
@@ -105,7 +103,8 @@ def test_readers_on_a_decoded_trace_with_fabricated_counters(monkeypatch):
     assert read("moe_experts_roofline")(obs) == pytest.approx(
         100.0 * (hit * 192 / 1e9) / 100e-9)
     kv_mid = 1000.0 - 100.0 * (1.0 - 0.4 / 2.0)
-    step_s = 4e-6 / 2                      # decode_step_ms of obs["trace"]
+    step_s = 1000e-9 / 2                   # decode_step_ms: whole programs
+    assert read("decode_step_ms")(obs) == pytest.approx(1e3 * step_s)
     assert read("decode_step_roofline.moe")(obs) == pytest.approx(
         100.0 * costs_moe.decode_step_bytes(c, hit, kv_mid) / 1e9 / step_s)
     # the busiest expert's 2400 rows of 9600, 6 experts: 1.5 times the mean

@@ -43,3 +43,19 @@ def test_rehearsal_walks_the_cell_and_prints_no_metric(cell, trace):
     assert line["rehearsal"] == "passed"
     assert "metrics" not in line and line["device"] == {"platform": "cpu"}
     assert line["checks"]["reference"]["ok"]
+    # each number compared beside its limit: last in the line, and the
+    # last lines of standard error
+    assert list(line)[-1] == "compared"
+    for name, row in line["compared"].items():
+        assert row["value"] <= row["limit"], name
+    assert p.stderr.strip().splitlines()[-1].startswith(
+        "compared kernels_off_their_tpu_path: 0 (limit 0)")
+    # the two mixes that offer what the engine holds have it all admitted;
+    # ``rollout-short`` offers 8 where the tiny pool admits 5, and the run
+    # goes on with 3 waiting in the engine's queue
+    c = line["checks"]
+    assert c["offered"] == line["attempted"] and line["failed"] == 0
+    if cell.endswith(".rollout-short"):
+        assert (c["offered"], c["admitted"], c["queued"]) == (8, 5, 3)
+    else:
+        assert (c["admitted"], c["queued"]) == (c["offered"], 0)
