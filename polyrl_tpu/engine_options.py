@@ -49,7 +49,9 @@ class EngineOptions:
     pipeline_depth: int = _opt(
         16, "run-ahead dispatch window of the fetcher-thread pipeline "
             "(0 = drain every dispatch); lower it for tighter abort "
-            "latency on colocated time-sliced workers")
+            "latency on colocated time-sliced workers. While a request "
+            "waits for pages or a slot the engine runs one program "
+            "ahead of the device instead, whatever this says")
     prefill_chunk: int = _opt(
         0, "chunked prefill: prompts longer than this prefill one "
            "page-aligned chunk per engine iteration, interleaved with "

@@ -71,6 +71,13 @@ two samples give a rate over any window, with no profiler session):
   enqueued, and fused steps whose results have landed on the host;
   ``fused_sample_steps`` — those of them whose program drew its tokens
   inside the output matmul (``decoder.head_and_sample``);
+  ``decode_dispatches_cold`` — those of the dispatches enqueued with
+  NOTHING outstanding (the device had run dry: an engine that keeps its
+  run-ahead does it once a burst, one that drains before every dispatch
+  does it every time);
+- ``admission_deferrals`` — loop iterations in which admission left a
+  request pending for want of pages or a slot and went on without
+  waiting (``CBEngine._admit``);
 - ``device_busy_s`` — seconds with device work outstanding: an interval
   opens when a dispatch is enqueued with nothing outstanding and closes
   when a landed result leaves nothing newer outstanding. Seconds are added
@@ -147,6 +154,8 @@ class EngineLoopProfiler:
         self.device_busy_s = 0.0
         self.device_busy_at_s = 0.0
         self.decode_dispatches = 0
+        self.decode_dispatches_cold = 0
+        self.admission_deferrals = 0
         self.decode_steps_done = 0
         self.fused_sample_steps = 0
         self.fetch_s = 0.0
@@ -270,6 +279,10 @@ class EngineLoopProfiler:
         so a later dispatch's landing stands for it."""
         now = self._clock()
         with self._lock:
+            if kind in DECODE_KINDS:
+                self.decode_dispatches += 1
+                if self._busy_from is None:
+                    self.decode_dispatches_cold += 1
             if self._busy_from is None:
                 self._busy_from = now
             if lands:
@@ -277,8 +290,12 @@ class EngineLoopProfiler:
                 self._tail_unlanded = False
             else:
                 self._tail_unlanded = True
-            if kind in DECODE_KINDS:
-                self.decode_dispatches += 1
+
+    def on_admission_deferred(self) -> None:
+        """Admission left a request pending (no pages, no slot) and the
+        loop went on to dispatch without waiting for either."""
+        with self._lock:
+            self.admission_deferrals += 1
 
     def on_landed(self, n: int) -> None:
         """The oldest ``n`` dispatches' results are on the host: the
@@ -358,6 +375,8 @@ class EngineLoopProfiler:
         with self._lock:
             out = {
                 "decode_dispatches": self.decode_dispatches,
+                "decode_dispatches_cold": self.decode_dispatches_cold,
+                "admission_deferrals": self.admission_deferrals,
                 "decode_steps_done": self.decode_steps_done,
                 "fused_sample_steps": self.fused_sample_steps,
                 "device_busy_s": round(self.device_busy_s, 6),

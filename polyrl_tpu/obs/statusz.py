@@ -103,9 +103,10 @@ _HIST_SUFFIXES = ("p50", "p95", "p99", "max", "mean", "count")
 # counters (not gauges) in the rollout plane's snapshot and at /metrics,
 # and tools/check_statusz_docs.py holds ARCHITECTURE.md to naming each.
 CUMULATIVE_INFO_KEYS = frozenset((
-    "decode_dispatches", "decode_steps_done", "fused_sample_steps",
-    "device_busy_s", "loop_wall_s", "loop_host_s", "programs_built",
-    "stream_chunks", "stream_lag_s"))
+    "decode_dispatches", "decode_dispatches_cold", "admission_deferrals",
+    "decode_steps_done", "fused_sample_steps", "device_busy_s",
+    "loop_wall_s", "loop_host_s", "programs_built", "stream_chunks",
+    "stream_lag_s"))
 # cumulative too, and counters where present: a MoE model's engine alone
 # reports them (``CBEngine.moe_info``)
 MOE_INFO_KEYS = frozenset(("moe_routed", "moe_experts_hit", "moe_load_max"))

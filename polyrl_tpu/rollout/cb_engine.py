@@ -31,6 +31,14 @@ sglang_http_async_engine.py:286-298). Design:
   admission and are re-uploaded only after host-side events (abort,
   overflow stop); a full drain (``keep=0``) barriers on the fetcher
   first, so re-uploads never rewind slots past results still in flight.
+- Admission never waits for the device. A request that finds no pages or
+  no slot takes what the fetcher has ALREADY landed (``_retry_landed``),
+  tries once more, and stays in ``_pending``; the finisher it needs is
+  landed by the throttle like every other output. While a request waits
+  the throttle (``_throttle``) is one program, not `pipeline_depth`: the
+  next dispatch goes out when fewer than two are unfinished on the
+  device, so the device never runs dry and a freed slot is refilled a
+  dispatch later, not a window later.
 
 Weight hot-swap = atomic ``self.params`` swap between steps (buffer shapes
 and shardings unchanged → no recompilation), mirroring the reference's
@@ -361,6 +369,14 @@ class CBEngine:
         # emission. A negative one would make the drain's `outstanding <=
         # keep` exit unreachable and spin the loop thread forever
         self.pipeline_depth = max(0, int(o.pipeline_depth))
+        # admission left a request pending for want of pages or a slot
+        # (set by _collect_wave, cleared at the top of every _admit): what
+        # _throttle reads. Loop thread only.
+        self._admission_waiting = False
+        # outputs of the newest two dispatches, oldest first (loop thread
+        # only): the device runs its programs in order, so fewer than two
+        # are unfinished on it exactly when the older of these is ready
+        self._last_two: collections.deque = collections.deque(maxlen=2)
         self.steps_per_dispatch = max(1, int(o.steps_per_dispatch))
         self.prefill_chunk = int(o.prefill_chunk)
         self._chunk_jobs: collections.deque = collections.deque()
@@ -679,10 +695,7 @@ class CBEngine:
 
     def _restore_entries_inner(self, entries: list) -> bool:
         need = len(entries)
-        pages = self.allocator.alloc(need)
-        while pages is None and self._outstanding():
-            self._drain_emit_q(keep=self._outstanding() - 1)
-            pages = self.allocator.alloc(need)
+        pages = self._retry_landed(lambda: self.allocator.alloc(need))
         if pages is None:
             # colder spillable pages can make room without losing KV;
             # the entries being restored are already spilled, so they are
@@ -1531,15 +1544,7 @@ class CBEngine:
                 self._drain_emit_q()
             except Exception:  # noqa: BLE001
                 log.exception("shutdown salvage drain failed")
-        with self._fetch_cv:
-            self._fetch_epoch += 1  # orphan anything a hung get still holds
-            self._emit_q.clear()
-            self._fetched_q.clear()
-            self._fetch_exc = None
-        if self.profiler is not None:
-            self.profiler.drop_outstanding()
-        self._inflight_tok[:] = 0
-        self._invalidate_dev_state()
+        self._abandon_pipeline()
         # every in-flight and queued request must still see a terminal line +
         # STREAM_END or its HTTP handler thread blocks forever. With salvage
         # on, in-flight requests end in a PARTIAL (abort) — the manager's
@@ -1704,18 +1709,7 @@ class CBEngine:
         """After any jit failure the pools may have been donated to the dead
         call; fail everything and reallocate so serving can continue."""
         self.recoveries += 1
-        with self._fetch_cv:
-            # bump the epoch FIRST: results a still-running device_get lands
-            # after this point are dropped at emission (slot generations
-            # would drop most anyway; the epoch also covers mirrors)
-            self._fetch_epoch += 1
-            self._emit_q.clear()
-            self._fetched_q.clear()
-            self._fetch_exc = None
-        if self.profiler is not None:
-            self.profiler.drop_outstanding()
-        self._inflight_tok[:] = 0
-        self._invalidate_dev_state()
+        self._abandon_pipeline()
         self._fail_all("engine error")
         self._decode_groups.clear()
         self._slot_decode_gid.clear()
@@ -1725,6 +1719,24 @@ class CBEngine:
                 self._disband_group_prerefs()
                 self.prefix_cache.flush()
             self._pools = self._make_pools()
+
+    def _abandon_pipeline(self) -> None:
+        """Forget every dispatch output not yet emitted (an engine reset
+        or stop): nothing of it will be streamed."""
+        with self._fetch_cv:
+            # bump the epoch FIRST: results a still-running (or hung)
+            # device_get lands after this point are dropped at emission
+            # (slot generations would drop most anyway; the epoch also
+            # covers mirrors)
+            self._fetch_epoch += 1
+            self._emit_q.clear()
+            self._fetched_q.clear()
+            self._fetch_exc = None
+        self._last_two.clear()
+        if self.profiler is not None:
+            self.profiler.drop_outstanding()
+        self._inflight_tok[:] = 0
+        self._invalidate_dev_state()
 
     def _drain_queue(self) -> None:
         while True:
@@ -1737,6 +1749,7 @@ class CBEngine:
     def _admit(self) -> None:
         with self._phase("accounting"):
             self._sweep_group_prerefs()
+        self._admission_waiting = False
         while self._pending:
             with self._phase("collect_wave"):
                 wave, kind = self._collect_wave()
@@ -1763,7 +1776,21 @@ class CBEngine:
                         self.prefix_cache.release(me)
                     self._emit_error(req, "prefill failed")
                 raise  # pools may be donation-poisoned: let _recover reset
+        if self._admission_waiting:
+            # deferred, not stalled: the loop goes on to dispatch, and a
+            # request aborted while it waits (behind a starved head the
+            # scan never reaches it) is released now, not when pages come
+            self._drop_aborted_pending()
+            if self.profiler is not None:
+                self.profiler.on_admission_deferred()
         self.num_queued = len(self._pending)
+
+    def _drop_aborted_pending(self) -> None:
+        for req in [r for r in self._pending
+                    if r.abort is not None and r.abort.is_set()]:
+            self._pending.remove(req)
+            self._emit_abort(req)
+            self._consume_group_preref(req)  # sibling that never attaches
 
     def _collect_wave(self) -> tuple[list, str]:
         """Collect up to ``admit_wave`` admissible requests, reserving a
@@ -1795,20 +1822,20 @@ class CBEngine:
         chunk_keys.discard(None)
         skipped = 0
         scan = 0
-        while len(wave) < self.admit_wave and scan < len(self._pending):
-            free = [int(i) for i in np.flatnonzero(
+
+        def free_slots() -> list[int]:
+            return [int(i) for i in np.flatnonzero(
                         ~self._active & np.asarray(
                             [s is None for s in self._slots]))
                     if int(i) not in assigned]
+
+        while len(wave) < self.admit_wave and scan < len(self._pending):
+            # a finished slot may stand among the outputs the fetcher has
+            # landed since the last throttle: emit those and look again,
+            # once, and never wait for the device (holding _pool_lock)
+            free = free_slots() if wave else self._retry_landed(free_slots)
             if not free:
-                out = self._outstanding()
-                if not wave and out:
-                    # finished slots may be hiding behind undrained
-                    # outputs: land ONE more fetch batch and re-check —
-                    # a full barrier here would stall admission (holding
-                    # _pool_lock) for the whole run-ahead pipeline
-                    self._drain_emit_q(keep=out - 1)
-                    continue
+                self._admission_waiting = True
                 break
             req = self._pending[scan]
             if req.abort is not None and req.abort.is_set():
@@ -1873,7 +1900,9 @@ class CBEngine:
             need = n_pages - len(matched_pages)
             pages = self._try_alloc(need, matched_entries)
             if pages is None:
-                break  # pages exhausted: wait (no skip — alloc fairness)
+                # pages exhausted: wait (no skip — alloc fairness)
+                self._admission_waiting = True
+                break
             del self._pending[scan]
             slot = free[0]
             assigned.add(slot)
@@ -1920,14 +1949,12 @@ class CBEngine:
         return wave, kind
 
     def _try_alloc(self, need: int, matched_entries: list):
-        """Page allocation with the drain + cache-evict fallbacks; releases
-        the caller's matched cache entries on failure."""
-        pages = self.allocator.alloc(need)
-        while pages is None and self._outstanding():
-            # drain incrementally: finished slots return their pages, and
-            # often the oldest fetch batch already holds the finisher
-            self._drain_emit_q(keep=self._outstanding() - 1)
-            pages = self.allocator.alloc(need)
+        """Page allocation with the landed-output, spill and cache-evict
+        fallbacks, in that order; releases the caller's matched cache
+        entries on failure. Never waits for the device: a finisher still
+        in the pipeline returns its pages when the loop's throttle lands
+        it, and the request is placed on the iteration after."""
+        pages = self._retry_landed(lambda: self.allocator.alloc(need))
         if pages is None and self.kvspill is not None:
             # allocation pressure: page unreferenced published KV out to
             # host BEFORE evicting it — spilling preserves what eviction
@@ -2468,6 +2495,7 @@ class CBEngine:
             self.profiler.on_dispatch(
                 kind, entry[3] if kind in ("step", "spec") else 0,
                 fused_sample=fused_sample)
+        self._last_two.append(entry[1])
         with self._fetch_cv:
             self._emit_q.append(entry)
             self._fetch_cv.notify_all()
@@ -2543,30 +2571,78 @@ class CBEngine:
                         self._fetch_inflight = 0
                         cv.notify_all()
 
+    def _emit_landed(self) -> int:
+        """Stream out the dispatch outputs the fetcher has ALREADY landed
+        (``_fetched_q``), bringing the host mirrors up to date, and say how
+        many there were. Never waits: not for ``_emit_q``, not for a
+        ``device_get`` in flight. A failure of the fetcher's surfaces here,
+        on the loop thread."""
+        with self._fetch_cv:
+            ready = list(self._fetched_q)
+            self._fetched_q.clear()
+            exc, self._fetch_exc = self._fetch_exc, None
+            epoch = self._fetch_epoch
+        if ready:
+            with self._phase("emit"):
+                for ep, entry, arrs in ready:
+                    if ep == epoch:
+                        self._emit_entry(entry, arrs)
+        if exc is not None:
+            raise exc
+        return len(ready)
+
+    def _retry_landed(self, attempt):
+        """How admission waits: not at all. ``attempt()`` (take pages, find
+        a free slot) and, where it comes back empty, once more after
+        emitting what the fetcher has landed meanwhile: a finisher's pages
+        and slot return at its emission. What is still on the device or in
+        a transfer is left to the loop's throttle (``_throttle``), and the
+        caller leaves its request pending for the next iteration."""
+        got = attempt()
+        if not got and self._emit_landed():
+            got = attempt()
+        return got
+
+    def _throttle(self) -> None:
+        """After a decode dispatch: how far the loop may run ahead of the
+        device. With nobody waiting, ``pipeline_depth`` outputs un-emitted
+        (older ones stream out of the fetcher while the device computes,
+        hiding the fetch round trips entirely). While admission has a
+        request it could not place, one program: the next dispatch goes
+        out when fewer than two are unfinished ON THE DEVICE (one running,
+        one queued behind it), so the device never runs dry, outputs that
+        are finished and in transit do not hold the loop, and a finisher's
+        slot is refilled a dispatch after the device freed it."""
+        if self._admission_waiting and len(self._last_two) == 2:
+            older = self._last_two[0]
+            cv = self._fetch_cv
+            while not (_finished_on_device(older) or self._stop.is_set()):
+                self._emit_landed()
+                with cv:
+                    if not self._fetched_q:
+                        # woken by the landing; the timeout covers a
+                        # transfer that outlasts the program behind it
+                        with self._phase("sample_fetch"):
+                            cv.wait(timeout=0.005)
+        self._drain_emit_q(keep=self.pipeline_depth)
+
     def _drain_emit_q(self, keep: int = 0) -> None:
         """Stream out every dispatch output the fetcher has landed, bringing
         the host mirrors up to date; block until at most ``keep`` outputs
         remain un-emitted. ``keep=0`` is the full barrier every dev-state
-        re-upload needs; ``keep=pipeline_depth`` is the steady-state call
-        that only throttles the loop when the device runs too far ahead."""
+        re-upload and abort needs; ``keep=pipeline_depth`` is the
+        steady-state call (``_throttle``) that only holds the loop when it
+        runs too far ahead. Admission never calls it: a blocked request
+        takes what has landed (``_retry_landed``) and waits in
+        ``_pending``, and while one does the throttle holds the loop one
+        program ahead of the device instead of ``pipeline_depth``."""
         if self._fetch_thread is None:
             # engine not started (unit tests drive internals directly):
             # fetch the oldest beyond ``keep`` synchronously on this thread
             self._fetch_sync(keep)
         cv = self._fetch_cv
         while True:
-            with cv:
-                ready = list(self._fetched_q)
-                self._fetched_q.clear()
-                exc, self._fetch_exc = self._fetch_exc, None
-                epoch = self._fetch_epoch
-            if ready:
-                with self._phase("emit"):
-                    for ep, entry, arrs in ready:
-                        if ep == epoch:
-                            self._emit_entry(entry, arrs)
-            if exc is not None:
-                raise exc
+            self._emit_landed()
             with cv:
                 if (len(self._emit_q) + self._fetch_inflight
                         + len(self._fetched_q) <= keep):
@@ -2830,10 +2906,7 @@ class CBEngine:
                              fused_sample=self._samples_in_head(use_filters))
         with self._phase("accounting"):
             self._deck_dispatch()
-        # run ahead up to pipeline_depth dispatches: older outputs stream
-        # out of the fetcher while the device computes, hiding the fetch
-        # round trips entirely
-        self._drain_emit_q(keep=self.pipeline_depth)
+        self._throttle()
 
     def _abort_fast(self) -> None:
         # emit the abort terminal FIRST and bump the slot generation so
@@ -2990,7 +3063,7 @@ class CBEngine:
                              self.spec_rounds, self.weight_version))
         with self._phase("accounting"):
             self._deck_dispatch()
-        self._drain_emit_q(keep=self.pipeline_depth)
+        self._throttle()
 
     def _deck_dispatch(self) -> None:
         """Scheduler step-ledger sample at decode-dispatch time: occupancy,

@@ -4,6 +4,7 @@ pallas interpret) pinned against the ungrouped full-table oracle, and the
 engine-level group-table lifecycle — parity with the off-switch engine,
 abort/salvage mid-group, KV-read accounting, and the knob echoes."""
 
+import queue
 import threading
 
 import jax
@@ -176,6 +177,19 @@ def _mk_engine(tiny, **kw):
     return CBEngine(cfg, params, **defaults)
 
 
+class _AbortAtFirstChunk(queue.Queue):
+    """A request's stream that sets its abort event as the engine puts the
+    first chunk, on the engine's own thread (as tests/test_kv_ledger.py)."""
+
+    def __init__(self):
+        super().__init__()
+        self.abort = threading.Event()
+
+    def put(self, item, block=True, timeout=None):
+        super().put(item, block, timeout)
+        self.abort.set()
+
+
 def _collect(q, timeout=120):
     toks, lps, reason = [], [], ""
     while True:
@@ -276,21 +290,22 @@ def test_engine_abort_mid_group_survivors_keep_decoding(tiny):
     ref = ref_eng.generate([prompt] * 4, sp)
     ref_eng.stop()
 
-    eng = _mk_engine(tiny, decode_group_share=True)
-    evs = [threading.Event() for _ in range(4)]
-    outs = [eng.submit(f"a-{i}", prompt, sp, abort=evs[i],
-                       group_id="gA", group_size=4)
+    # two dispatches (16 tokens) at most are out when a first chunk is
+    # put, and the abort is set there, on the engine's own thread: the
+    # aborted members cannot have reached their 24 tokens, however late a
+    # loaded box schedules the thread that reads the streams
+    eng = _mk_engine(tiny, decode_group_share=True, pipeline_depth=1)
+    outs = [_AbortAtFirstChunk() if i in (1, 2) else queue.Queue()
             for i in range(4)]
-    eng.start()
-    # wait until decode is underway, then abort two members
-    firsts = [q.get(timeout=120) for q in outs]
-    assert all(f["token_ids"] for f in firsts)
-    evs[1].set()
-    evs[2].set()
-    res = []
     for i, q in enumerate(outs):
+        eng.submit(f"a-{i}", prompt, sp, out=q,
+                   abort=getattr(q, "abort", None),
+                   group_id="gA", group_size=4)
+    eng.start()
+    res = []
+    for q in outs:
         toks, lps, reason = _collect(q)
-        res.append((firsts[i]["token_ids"] + toks, reason))
+        res.append((toks, reason))
     assert res[1][1] == "abort" and res[2][1] == "abort"
     for i in (0, 3):  # survivors: full budget, greedy-identical to ref
         assert res[i][1] == "length"

@@ -156,8 +156,10 @@ def _render_engine(loop: dict) -> list[str]:
         out.append("")
         out.append(
             f"{steps} decode steps landed of {c.get('decode_dispatches', 0)} "
-            f"dispatches ({c.get('fused_sample_steps', 0)} sampled inside "
-            f"the head): device busy {_fmt(c.get('device_busy_s'))} s "
+            f"dispatches ({c.get('decode_dispatches_cold', 0)} onto a dry "
+            f"device, {c.get('admission_deferrals', 0)} admissions "
+            f"deferred; {c.get('fused_sample_steps', 0)} steps sampled "
+            f"inside the head): device busy {_fmt(c.get('device_busy_s'))} s "
             f"({_fmt(1e3 * c.get('device_busy_s', 0.0) / steps)} ms a step); "
             f"loop host {_fmt(c.get('loop_host_s'))} s of "
             f"{_fmt(c.get('loop_wall_s'))} s wall")
