@@ -57,6 +57,11 @@ class EngineOptions:
            "page-aligned chunk per engine iteration, interleaved with "
            "decode, so a long admission cannot stall every running "
            "stream (0 = off, whole-prompt dispatches)")
+    prefill_first: bool = _opt(
+        False, "while a chunked prefill is under way, hold the decode "
+               "dispatches: the admitted batch is whole soonest and its "
+               "streams stall meanwhile (off = one chunk, then one decode "
+               "dispatch, in turn)")
     # Proposals, token history and acceptance stay on the device, so spec
     # dispatches pipeline like normal steps. Wins when outputs are locally
     # repetitive (math/code CoT); costs m x attention reads per verify, so

@@ -1,0 +1,190 @@
+"""The one place that says, per layer, which mixer and which MLP a model
+runs and what a sequence keeps for that layer between steps.
+
+A mixer is ``gqa`` (grouped-query softmax attention with RoPE), ``kda``
+(Kimi Delta Attention: a recurrent float32 state a head plus the tails of
+three short convolutions) or ``mla`` (multi-head latent attention: one
+normed latent row and one shared rope key a token). An MLP is ``dense``
+(SwiGLU) or ``moe`` (routed experts, ``decoder._moe_mlp``). Every preset
+from before the hybrid family is the uniform pattern: ``gqa`` in every
+layer, with the same MLP in every layer.
+
+What a sequence keeps is either PAGED (so many values a token, in pages
+that the engine's allocator hands out: a K/V pair of ``[Hkv, N, page, D]``
+for ``gqa``, one latent pool ``[1, N, page, row]`` for ``mla``, ``row`` being ``rank +
+rope`` rounded up to whole lanes) or
+a SLOT (fixed-size arrays indexed by the engine's slot, for ``kda``). A
+state in a slot has no page boundary to snapshot at, so a model with one
+is STATEFUL: ``CBEngine`` turns off every feature that re-enters a
+sequence anywhere but at its last token (ARCHITECTURE.md, "Cache
+specification").
+
+Readers: ``decoder.make_paged_pools`` and ``CBEngine._make_pools`` (the
+arrays; the page ledger takes its bytes a page from the paged ones),
+``CBEngine`` (``is_stateful``: whether ``prefix_cache``, ``kvspill`` and
+speculation may run at all), ``models/hybrid.py`` (the layer loop);
+``benchmark/lib/costs_hybrid.py`` repeats the arithmetic on its own."""
+
+from __future__ import annotations
+
+import dataclasses
+
+import jax.numpy as jnp
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerPlan:
+    mixer: str        # "gqa" | "kda" | "mla"
+    mlp: str          # "dense" | "moe"
+    published: int    # the layer's index in the published model
+
+
+@dataclasses.dataclass(frozen=True)
+class Paged:
+    """``arrays`` pools of ``[heads, pages, page_size, width]`` a layer."""
+    arrays: int
+    heads: int
+    width: int
+
+    def values_per_token(self) -> int:
+        return self.arrays * self.heads * self.width
+
+
+@dataclasses.dataclass(frozen=True)
+class Slot:
+    """Arrays of ``(slots, *shape)`` a layer: (name, shape, dtype)."""
+    arrays: tuple
+
+    def bytes_per_slot(self) -> int:
+        total = 0
+        for _name, shape, dtype in self.arrays:
+            n = 1
+            for s in shape:
+                n *= s
+            total += n * jnp.dtype(dtype).itemsize
+        return total
+
+
+# the type the recurrent state is kept in (Ling's config: float32). A
+# module constant and no option: the benchmark's control of ``correct``
+# computes the reference with a bfloat16 state, not the program.
+STATE_DTYPE = jnp.float32
+
+
+def layer_plan(cfg) -> tuple[LayerPlan, ...]:
+    """The model's layers in order. ``layer_group_size`` > 0 is the hybrid
+    family: published layer ``i`` is ``mla`` where ``(i + 1) %
+    layer_group_size == 0`` and ``kda`` otherwise; ``first_k_dense_replace``
+    leading published layers keep the dense MLP. ``kept_layers`` names the
+    published layers that run here (a depth cut), all of them by default."""
+    kept = cfg.kept_layers or tuple(range(cfg.num_layers))
+    if len(kept) != cfg.num_layers:
+        raise ValueError(f"kept_layers {kept} names {len(kept)} layers, "
+                         f"num_layers is {cfg.num_layers}")
+    plan = []
+    for i in kept:
+        if cfg.layer_group_size:
+            mixer = "mla" if (i + 1) % cfg.layer_group_size == 0 else "kda"
+        else:
+            mixer = "gqa"
+        sparse = bool(cfg.num_experts) and i >= cfg.first_k_dense_replace
+        plan.append(LayerPlan(mixer, "moe" if sparse else "dense", i))
+    return tuple(plan)
+
+
+def is_uniform(cfg) -> bool:
+    """Every layer alike and ``gqa``: the stacked-scan decoder."""
+    return not cfg.layer_group_size and not (
+        cfg.num_experts and cfg.first_k_dense_replace)
+
+
+def experts_held(cfg) -> tuple[int, int]:
+    """(first, count) of the routed experts a layer holds here, of the
+    ``num_experts`` it routes over: all of them unless the configuration
+    names a share."""
+    return cfg.experts_held or (0, cfg.num_experts)
+
+
+def kda_dims(cfg) -> tuple[int, int, int]:
+    """(heads, key size, value size) of a KDA layer."""
+    return cfg.num_heads, cfg.head_dim_, cfg.head_dim_
+
+
+def latent_width(cfg) -> int:
+    """Values a token's latent row holds: the normed latent and the
+    shared rope key."""
+    return cfg.kv_lora_rank + cfg.qk_rope_head_dim
+
+
+LANES = 128
+
+
+def latent_row(cfg) -> int:
+    """Columns a latent row takes in its pool: ``latent_width`` rounded up
+    to whole lanes, the rest zero. The chip lays a minor dimension out in
+    tiles of 128 whatever its logical size, and a kernel's DMA can slice
+    a pool only at tile boundaries, so the pad is stated, not hidden."""
+    return -(-latent_width(cfg) // LANES) * LANES
+
+
+def layer_cache(cfg, plan: LayerPlan, dtype=None) -> Paged | Slot:
+    dtype = dtype or cfg.dtype
+    if plan.mixer == "gqa":
+        return Paged(2, cfg.num_kv_heads, cfg.head_dim_)
+    if plan.mixer == "mla":
+        return Paged(1, 1, latent_row(cfg))
+    h, dk, dv = kda_dims(cfg)
+    k = cfg.short_conv_kernel_size
+    return Slot((("state", (h, dk, dv), STATE_DTYPE),
+                 ("conv", (k - 1, h * (2 * dk + dv)), dtype)))
+
+
+def cache_spec(cfg, dtype=None) -> tuple:
+    return tuple(layer_cache(cfg, p, dtype) for p in layer_plan(cfg))
+
+
+def is_stateful(cfg) -> bool:
+    """Some layer keeps a state that is not paged."""
+    return any(isinstance(c, Slot) for c in cache_spec(cfg))
+
+
+def paged_bytes_per_token(cfg, dtype=None) -> int:
+    item = jnp.dtype(dtype or cfg.dtype).itemsize
+    return sum(c.values_per_token() * item for c in cache_spec(cfg, dtype)
+               if isinstance(c, Paged))
+
+
+def slot_bytes(cfg, dtype=None) -> int:
+    """Bytes one slot's state takes, all layers."""
+    return sum(c.bytes_per_slot() for c in cache_spec(cfg, dtype)
+               if isinstance(c, Slot))
+
+
+def make_pools(cfg, num_pages: int, page_size: int, slots: int = 0,
+               dtype=None) -> tuple:
+    """The arrays. For the uniform ``gqa`` pattern ``(k, v)``, each a
+    per-layer tuple of ``[Hkv, num_pages, page_size, D]`` (page 0 is the
+    null page). For any other pattern ``(paged, state)``: ``paged`` a tuple
+    with one ``[1, num_pages, page_size, width]`` latent pool for each
+    ``mla`` layer in order, ``state`` a tuple with one ``(state [slots, H,
+    Dk, Dv] float32, conv [slots, K-1, channels])`` pair for each ``kda``
+    layer in order. The engine hands ``slots = max_slots + 1``: the last
+    row is the sink that padding rows of an admission wave write to."""
+    dtype = dtype or cfg.dtype
+    spec = cache_spec(cfg, dtype)
+    if is_uniform(cfg):
+        shape = (spec[0].heads, num_pages, page_size, spec[0].width)
+        return (tuple(jnp.zeros(shape, dtype) for _ in spec),
+                tuple(jnp.zeros(shape, dtype) for _ in spec))
+    paged, state = [], []
+    for c in spec:
+        if isinstance(c, Paged):
+            if c.arrays != 1:
+                raise NotImplementedError(
+                    "a K/V pair beside a recurrent state: no such model yet")
+            paged.append(jnp.zeros((c.heads, num_pages, page_size, c.width),
+                                   dtype))
+        else:
+            state.append(tuple(jnp.zeros((slots, *shape), dt)
+                               for _name, shape, dt in c.arrays))
+    return tuple(paged), tuple(state)

@@ -14,6 +14,10 @@ Design choices (TPU rationale):
 - bf16 params/activations, f32 softmax/logits head.
 - GQA + RoPE (llama3 frequency scaling supported), RMSNorm, SwiGLU,
   optional per-head QK-norm (Qwen3).
+- One kind of layer, repeated, is this file's; a model whose layers
+  differ in kind (``models/cache_spec.py::layer_plan``: KDA, MLA, a routed
+  MLP behind dense layers) has its blocks and its layer loop in
+  ``models/hybrid.py``, and every entry point here hands over to it.
 - ``param_specs`` returns a matching PartitionSpec tree: params shard over
   (fsdp, tp) — GSPMD inserts the all-gathers the reference got from FSDP
   + NCCL (SURVEY.md §2.4 mapping).
@@ -30,10 +34,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from polyrl_tpu.models.quant import (LoraWeight, QuantWeight, mm, moe_mm,
-                                     unembed)
+from polyrl_tpu.models import cache_spec, hybrid
+from polyrl_tpu.models.blocks import (EXPERT_KEYS, _head, _moe_mlp,  # noqa: F401
+                                      _scatter_token_kv, rms_norm)
+from polyrl_tpu.models.quant import LoraWeight, QuantWeight, mm
 from polyrl_tpu.ops.attention import attention, causal_mask
-from polyrl_tpu.ops.grouped_matmul import row_tile, tiled_layout
 from polyrl_tpu.parallel.mesh import DP, EP, FSDP, SP, TP
 
 
@@ -72,6 +77,33 @@ class ModelConfig:
     num_experts_per_tok: int = 8
     moe_intermediate_size: int = 0
     norm_topk_prob: bool = True
+    # the router (DeepSeek-V3's ``noaux_tc`` when ``sigmoid``): scores,
+    # groups of consecutive experts of which the best ``topk_group`` are
+    # kept, a bias that enters the choice and not the weights, a factor
+    # on the k weights, one shared expert of this width beside the routed
+    scoring_func: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    moe_shared_expert_intermediate_size: int = 0
+    # (first, count) of the experts this chip holds out of ``num_experts``
+    # (a chip's share of an expert-parallel deployment: the router keeps
+    # its width, choices that fall elsewhere are left out); None: all
+    experts_held: tuple | None = None
+    # layers of several kinds (``models/cache_spec.py::layer_plan``):
+    # ``layer_group_size`` > 0 makes published layer i MLA where (i + 1) %
+    # size == 0 and KDA otherwise; the first ``first_k_dense_replace``
+    # published layers keep the dense MLP; ``kept_layers`` are the
+    # published layers run here (a depth cut; ``num_layers`` of them)
+    layer_group_size: int = 0
+    first_k_dense_replace: int = 0
+    kept_layers: tuple | None = None
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    short_conv_kernel_size: int = 0
+    kda_lower_bound: float = -5.0
     dtype: Any = jnp.bfloat16
 
     @property
@@ -189,6 +221,59 @@ PRESETS["deepseek-r1-distill-qwen-32b"] = PRESETS["qwen2.5-32b"]
 PRESETS["deepseek-r1-distill-llama-8b"] = PRESETS["llama3-8b"]
 
 
+
+
+def cut_to_share(cfg: ModelConfig, kept_layers: tuple, chips: int
+                 ) -> ModelConfig:
+    """One chip's view of a deployment in which ``chips`` chips share each
+    layer and the layers not in ``kept_layers`` (published indices) lie on
+    further chips as pipeline stages: this chip's experts (the first
+    ``num_experts / chips``; the router keeps its width) and its slice of
+    the vocabulary (the first ``vocab_size / chips`` rows). Attention and
+    a shared expert are whole on every chip."""
+    held = cfg.num_experts // chips
+    return dataclasses.replace(
+        cfg, num_layers=len(kept_layers), kept_layers=tuple(kept_layers),
+        experts_held=(0, held) if cfg.num_experts else None,
+        vocab_size=cfg.vocab_size // chips)
+
+
+# Ling-3.0-flash (HF config: inclusionAI/Ling-3.0-flash, model_type
+# bailing_hybrid): KDA layers with every sixth an MLA layer, two leading
+# dense layers, 512 routed experts of width 768 behind a sigmoid router
+# with 8 groups, one shared expert. The multi-token-prediction layer is
+# not part of the decoder. ``num_kv_heads`` is the published key (32) and
+# unused: no layer keeps a K/V pair.
+PRESETS["ling-3.0-flash"] = ModelConfig(
+    vocab_size=157184, hidden_size=2560, intermediate_size=6144,
+    num_layers=42, num_heads=32, num_kv_heads=32, head_dim=128,
+    rope_theta=6000000.0, rms_norm_eps=1e-6, max_position_embeddings=262144,
+    num_experts=512, num_experts_per_tok=8, moe_intermediate_size=768,
+    scoring_func="sigmoid", n_group=8, topk_group=4,
+    routed_scaling_factor=2.5, moe_shared_expert_intermediate_size=768,
+    layer_group_size=6, first_k_dense_replace=2, kv_lora_rank=512,
+    qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+    short_conv_kernel_size=4, kda_lower_bound=-5.0,
+)
+# one chip of four that share each layer, the leading dense layer once and
+# one whole period of six sparse layers (benchmark/configs/ling-3.0-flash.json)
+PRESETS["ling-3.0-flash-share4"] = cut_to_share(
+    PRESETS["ling-3.0-flash"], (0, 2, 3, 4, 5, 6, 7), 4)
+# test-size model of the same family: 2 KDA layers and 1 MLA layer, the
+# first dense, 16 experts in 4 groups of which 4 are held
+PRESETS["hybrid-tiny"] = ModelConfig(
+    vocab_size=512, hidden_size=64, intermediate_size=128, num_layers=3,
+    num_heads=4, num_kv_heads=4, head_dim=16, rope_theta=10000.0,
+    rms_norm_eps=1e-6, max_position_embeddings=512,
+    num_experts=16, num_experts_per_tok=4, moe_intermediate_size=32,
+    scoring_func="sigmoid", n_group=4, topk_group=2,
+    routed_scaling_factor=2.5, moe_shared_expert_intermediate_size=32,
+    experts_held=(0, 4), layer_group_size=3, first_k_dense_replace=1,
+    kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    short_conv_kernel_size=4, kda_lower_bound=-5.0,
+)
+
+
 def get_config(name: str, **overrides) -> ModelConfig:
     return dataclasses.replace(PRESETS[name], **overrides)
 
@@ -198,6 +283,8 @@ def get_config(name: str, **overrides) -> ModelConfig:
 
 def init_params(rng: jax.Array, cfg: ModelConfig) -> dict:
     """Initialise stacked-layer params. Normal(0.02) like the HF default."""
+    if not cache_spec.is_uniform(cfg):
+        return hybrid.init_params(rng, cfg)
     hd = cfg.head_dim_
     d, f, L = cfg.hidden_size, cfg.intermediate_size, cfg.num_layers
     hq, hkv = cfg.num_heads, cfg.num_kv_heads
@@ -249,6 +336,8 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> dict:
 
 def param_specs(cfg: ModelConfig) -> dict:
     """PartitionSpec tree matching ``init_params`` (fsdp × tp sharding)."""
+    if not cache_spec.is_uniform(cfg):
+        return hybrid.param_specs(cfg)
     layer = {
         "attn_norm": P(None, None),
         "mlp_norm": P(None, None),
@@ -293,14 +382,6 @@ def param_specs(cfg: ModelConfig) -> dict:
 
 
 # -- building blocks --------------------------------------------------------
-
-
-def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float) -> jnp.ndarray:
-    dtype = x.dtype
-    x = x.astype(jnp.float32)
-    var = jnp.mean(x * x, axis=-1, keepdims=True)
-    x = x * jax.lax.rsqrt(var + eps)
-    return (x * weight.astype(jnp.float32)).astype(dtype)
 
 
 def _rope_freqs(cfg: ModelConfig) -> np.ndarray:
@@ -349,9 +430,6 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarra
 
 # -- MoE MLP ----------------------------------------------------------------
 
-EXPERT_KEYS = ("we_gate", "we_up", "we_down")
-
-
 def _unrolled_layer(cfg: ModelConfig, layers: dict, l: int) -> dict:
     """Layer ``l``'s weights out of the stacked tree, for a loop unrolled
     over static layer indices. A slice of a stack fuses into the matmul
@@ -364,152 +442,6 @@ def _unrolled_layer(cfg: ModelConfig, layers: dict, l: int) -> dict:
     return {k: v if k in EXPERT_KEYS
             else jax.tree_util.tree_map(lambda a: a[l], v)
             for k, v in layers.items()}
-
-
-# tables of at most this many rows are read by a one-hot product
-_ONE_HOT_ROWS = 1024
-
-
-def _take(table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
-    """``table[idx]``: rows of a 2-D table, entries of a 1-D one. A TPU
-    gather moves one row or one scalar at a time (12 ns a 4 KB row, 25 ns a
-    scalar: a decode step's 2,560 tiled rows cost 45 us a layer, beside
-    1.6 ms of experts; PERF.md section 6, PR 27), so a small table is read
-    by a product with the one-hot of ``idx`` instead, which is exact (one
-    term a row) and runs on the MXU. A large table (the trainer's tokens)
-    is gathered."""
-    n = table.shape[0]
-    if n > _ONE_HOT_ROWS:
-        return table[idx]
-    hot = idx[:, None] == jnp.arange(n)[None, :]
-    if table.ndim == 1:
-        return jnp.sum(jnp.where(hot, table[None, :], 0), axis=1)
-    return jnp.einsum("pn,nd->pd", hot.astype(table.dtype), table,
-                      precision=jax.lax.Precision.HIGHEST,
-                      preferred_element_type=jnp.float32).astype(table.dtype)
-
-
-def _context_mesh():
-    """The mesh set around this trace (``parallel.mesh.under``) when it
-    has more than one device, else None."""
-    mesh = jax.sharding.get_abstract_mesh()
-    return None if mesh.empty or mesh.size == 1 else mesh
-
-
-def _expert_mix(x, experts, layer, token_of, place, choice, top_p, sizes,
-                first=0):
-    """Each token's weighted sum over those of its k choices that fall on
-    the experts ``experts`` holds, which are ``first`` onwards of all of
-    them: [N, d] float32, zero for a token with no choice here.
-
-    ``token_of`` [N*k]: the token of each choice in expert-sorted order;
-    ``place`` [N*k]: each choice's place in that order; ``sizes`` [E]: all
-    experts' rows. The local experts' rows are contiguous there. They are
-    gathered into whole tiles an expert (``tiled_layout``; at most
-    N*k + E*tile rows), run through the grouped SwiGLU and the grouped
-    down projection, and each choice reads its row back."""
-    n, d = x.shape
-    m = token_of.shape[0]
-    e_here = experts["we_gate"].shape[-3]
-    mine = jax.lax.dynamic_slice_in_dim(sizes, first, e_here)
-    first_row = jnp.sum(jnp.where(jnp.arange(sizes.shape[0]) < first,
-                                  sizes, 0))
-    lay = tiled_layout(mine, m, row_tile(m, e_here))
-    # a pad row reads the zero row appended to x
-    rows = jnp.where(
-        lay.live, _take(token_of, jnp.clip(first_row + lay.src, 0, m - 1)), n)
-    xs = _take(jnp.concatenate([x, jnp.zeros((1, d), x.dtype)]), rows)
-    hidden = moe_mm(xs, (experts["we_gate"], experts["we_up"]), lay, layer)
-    ys = moe_mm(hidden, (experts["we_down"],), lay, layer)
-    here = choice - first            # invalid choices carry expert E
-    is_here = (here >= 0) & (here < e_here)
-    row = place - first_row + lay.shift[jnp.clip(here, 0, e_here - 1)]
-    # a gather, not ``_take``: the kernel leaves the rows of tiles without
-    # rows undefined, and a one-hot product would sum them in (0 x NaN)
-    y = jnp.where(is_here[:, None], ys[jnp.clip(row, 0, xs.shape[0] - 1)], 0)
-    return jnp.einsum("nkd,nk->nd", y.reshape(n, -1, d).astype(jnp.float32),
-                      top_p)
-
-
-def _expert_mix_sharded(mesh, x, experts, layer, *route):
-    """``_expert_mix`` on a mesh, manual over every axis (a Mosaic kernel
-    cannot be partitioned for it): each ``ep`` rank computes the rows of
-    its own experts, each ``tp`` rank its own columns of gate and up and
-    rows of down (SwiGLU is element-wise there), and the results, zero or
-    partial elsewhere, are summed over ``ep`` and ``tp``. The experts'
-    ``fsdp`` shards are gathered on the way in, as for any weight. The
-    routing is one sort over all the tokens, so every rank of the data
-    axes holds, and computes, them all."""
-    lead = () if layer is None else (None,)   # whole stacks [L, E, ..]
-
-    def spec(key, w):
-        s = (P(*lead, EP, TP, None) if key == "we_down"
-             else P(*lead, EP, None, TP))
-        # a QuantWeight's scale [.., E, out] follows the output columns
-        return s if not isinstance(w, QuantWeight) else QuantWeight(
-            q=s, scale=P(*s[:-2], s[-1]))
-
-    def local(x, experts, *route):
-        first = jax.lax.axis_index(EP) * experts["we_gate"].shape[-3]
-        return jax.lax.psum(
-            _expert_mix(x, experts, layer, *route, first=first), (EP, TP))
-
-    specs = {key: spec(key, w) for key, w in experts.items()}
-    return jax.shard_map(
-        local, mesh=mesh, in_specs=(P(), specs) + (P(),) * len(route),
-        out_specs=P(), check_vma=False)(x, experts, *route)
-
-
-def _moe_mlp(cfg: ModelConfig, x: jnp.ndarray, lp: dict,
-             valid: jnp.ndarray | None = None, layer: int | None = None
-             ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Routed mixture MLP on flattened tokens ``x`` [N, d] -> [N, d],
-    dropless, with static shapes.
-
-    Routing follows HF Qwen3MoeSparseMoeBlock: router logits in the
-    model's dtype, softmax in float32 over ALL experts, top-k, optional
-    renormalisation of the k probabilities. The N*k (token, expert)
-    choices are sorted by expert, the three expert projections run as
-    grouped matmuls over the sorted rows (``_expert_mix``: whatever the
-    imbalance, no choice is dropped and no expert multiplies a row that
-    did not choose it), and each token sums its k results with the routing
-    weights in float32. One block serves decode, prefill and the trainer.
-
-    ``valid`` [N] (padding, decode rows without a request): an invalid
-    token routes nowhere and returns zero.
-
-    ``layer``: ``lp``'s experts are whole stacks, and that layer of them
-    is meant (``_unrolled_layer``).
-
-    Also returns the step's load, int32 [3]: (token, expert) pairs routed,
-    experts with at least one row, rows of the busiest expert."""
-    n, d = x.shape
-    e, k = cfg.num_experts, cfg.num_experts_per_tok
-    with jax.named_scope("moe_route"):
-        probs = jax.nn.softmax(mm(x, lp["router"]).astype(jnp.float32), axis=-1)
-        top_p, top_i = jax.lax.top_k(probs, k)                    # [N, k]
-        if cfg.norm_topk_prob:
-            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-        choice = top_i.reshape(n * k)
-        if valid is not None:
-            valid = valid.astype(bool)
-            top_p = jnp.where(valid[:, None], top_p, 0.0)
-            # expert ``e`` is none: it sorts last and counts nowhere
-            choice = jnp.where(jnp.repeat(valid, k), choice, e)
-        order = jnp.argsort(choice, stable=True)   # sorted row -> choice
-        place = jnp.argsort(order)                 # choice -> sorted row
-        sizes = jnp.sum(jax.nn.one_hot(choice, e, dtype=jnp.int32), axis=0)
-        load = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0),
-                          jnp.max(sizes)])
-    with jax.named_scope("moe_experts"):
-        experts = {key: lp[key] for key in EXPERT_KEYS}
-        route = (order // k, place, choice, top_p, sizes)
-        mesh = _context_mesh()
-        if mesh is None:
-            out = _expert_mix(x, experts, layer, *route)
-        else:
-            out = _expert_mix_sharded(mesh, x, experts, layer, *route)
-    return out.astype(x.dtype), load
 
 
 def _mlp_block(cfg: ModelConfig, h: jnp.ndarray, lp: dict,
@@ -570,24 +502,6 @@ def _attn_out_mlp(cfg, x, attn_out, lp, token_valid, layer=None):
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
         out, load = _mlp_block(cfg, h, lp, token_valid, layer)
         return x + out, load
-
-
-def _head(cfg, params, x, logits_for=None):
-    """Final norm and the output matmul; ``logits_for`` [B] unembeds one
-    position a row of ``x`` [B, T, d]."""
-    with jax.named_scope("head"):
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-        head = (params["embed"].T if cfg.tie_word_embeddings
-                else params["lm_head"])
-        if logits_for is not None:
-            # unembed only one position per row: prefill needs just the
-            # last real token's logits, and [B, T, V] f32 for a long chunk
-            # is the dominant HBM transient (e.g. 4k x 152k f32 = 2.5 GB
-            # per prompt)
-            x = jnp.take_along_axis(x, logits_for[:, None, None], axis=1)[:, 0]
-            return unembed(x, head, "bd,dv->bv")
-        eq = "btd,dv->btv" if x.ndim == 3 else "sd,dv->sv"
-        return unembed(x, head, eq)
 
 
 def samples_in_head(cfg, params, use_filters: bool, many_chips: bool) -> bool:
@@ -669,6 +583,14 @@ def forward(
     is written at ``write_idx``; ``attn_mask`` must be [B, S] marking valid
     cache slots INCLUDING the chunk being written.
     """
+    if not cache_spec.is_uniform(cfg):
+        if cache is not None or attn_fn is not None or layers_fn is not None:
+            raise NotImplementedError(
+                "a model of several kinds of layer runs without a dense "
+                "cache, sequence parallelism or a pipeline: CBEngine serves "
+                "it (models/hybrid.py)")
+        return hybrid.forward(params, cfg, input_ids, positions, attn_mask,
+                              remat=remat, logits_for=logits_for), None
     b, t = input_ids.shape
     x = params["embed"][input_ids]  # gather; sharded over tp on vocab dim
 
@@ -750,9 +672,12 @@ def forward(
 
 
 def make_paged_pools(cfg: ModelConfig, num_pages: int, page_size: int,
-                     dtype=None) -> tuple:
+                     dtype=None, slots: int = 0) -> tuple:
     """Paged KV pool: (k, v), each a PER-LAYER tuple of
-    [Hkv, num_pages, page_size, D] arrays.
+    [Hkv, num_pages, page_size, D] arrays. What a layer keeps comes from
+    ``models/cache_spec.py``; for a model of several kinds of layer the
+    pair is (latent pools, state rows of ``slots`` slots) instead
+    (``cache_spec.make_pools``).
 
     Head-major layout: each layer's pool is exactly the
     [num_kv_heads, total_pages, page_size, head_dim] shape the TPU paged
@@ -767,29 +692,7 @@ def make_paged_pools(cfg: ModelConfig, num_pages: int, page_size: int,
     Page 0 is reserved as the null page — inactive slots and padding scatter
     their garbage KV there so every decode step has uniform static shapes
     (the TPU answer to SGLang's paged allocator, SURVEY.md §2.2 row 1)."""
-    dtype = dtype or cfg.dtype
-    shape = (cfg.num_kv_heads, num_pages, page_size, cfg.head_dim_)
-    return (tuple(jnp.zeros(shape, dtype=dtype) for _ in range(cfg.num_layers)),
-            tuple(jnp.zeros(shape, dtype=dtype) for _ in range(cfg.num_layers)))
-
-
-def _scatter_token_kv(pool, write_page, write_off, upd):
-    """Scatter one token's KV per slot into ``pool`` [Hkv, N, ps, D];
-    ``upd`` is [S, Hkv, D]. Written as a ROW scatter in the flattened
-    [Hkv·N·ps, D] view: the update window is then the minor-most dim alone,
-    so XLA's layout assignment keeps the pool in standard layout — the
-    4-D form's split window (Hkv major + D minor) made layout assignment
-    pick a permuted physical layout, and the attention kernel's
-    standard-layout operand constraint then forced a full-pool copy every
-    decode iteration."""
-    hkv, n, ps, d = pool.shape
-    s = write_page.shape[0]
-    flat = pool.reshape(hkv * n * ps, d)
-    head_off = jnp.arange(hkv, dtype=jnp.int32)[:, None] * (n * ps)
-    idx = (head_off + (write_page * ps + write_off)[None, :]).reshape(-1)
-    flat = flat.at[idx].set(
-        upd.transpose(1, 0, 2).reshape(hkv * s, d).astype(pool.dtype))
-    return flat.reshape(hkv, n, ps, d)
+    return cache_spec.make_pools(cfg, num_pages, page_size, slots, dtype)
 
 
 def _scatter_pages_kv(pool, page_ids, upd):
@@ -840,6 +743,13 @@ def forward_paged_decode(
     unchanged (grouping only changes the kernel's HBM read pattern)."""
     from polyrl_tpu.ops.paged_attention import paged_attention, paged_kv_write
 
+    if not cache_spec.is_uniform(cfg):
+        if attn_fn is not None or kv_write_fn is not None:
+            raise NotImplementedError(
+                "grouped or mesh-sharded decode attention for a model of "
+                "several kinds of layer")
+        return hybrid.paged_decode(params, cfg, tokens, positions, pools,
+                                   page_table, seq_lens, active, head_fn)
     attn_fn = attn_fn or paged_attention
     kv_write_fn = kv_write_fn or paged_kv_write
     s = tokens.shape[0]
@@ -895,11 +805,17 @@ def prefill_into_pages(
     prompt_len: jnp.ndarray,  # scalar int32
     pools: tuple,
     page_ids: jnp.ndarray,    # [pb // page_size] int32 (0-padded past prompt)
+    slot: jnp.ndarray | None = None,  # the engine's slot (a recurrent state's row)
 ) -> tuple[tuple, jnp.ndarray]:
     """Prefill one prompt and scatter its KV into the slot's pages. Returns
     (updated pools, last-token logits [V] f32). Padding positions write into
     the null page / the tail of the last real page — never attended (masking
     is by seq_len everywhere)."""
+    if not cache_spec.is_uniform(cfg):
+        pools, logits = hybrid.prefill(
+            params, cfg, ids[None], prompt_len[None], jnp.int32(0), pools,
+            jnp.zeros((1, 0), jnp.int32), page_ids[None], slot[None])
+        return pools, logits[0]
     page_size = pools[0][0].shape[2]
     pb = ids.shape[0]
     n_pg = pb // page_size
@@ -931,6 +847,7 @@ def prefill_batch_into_pages(
     prompt_lens: jnp.ndarray,  # [B] int32
     pools: tuple,
     page_ids: jnp.ndarray,     # [B, pb // page_size] int32
+    slots: jnp.ndarray | None = None,  # [B] the engine's slots
 ) -> tuple[tuple, jnp.ndarray]:
     """Batched admission prefill: B prompts in ONE dispatch. Dispatch count
     is the admission bottleneck on dispatch-latency-bound links (and still
@@ -938,6 +855,10 @@ def prefill_batch_into_pages(
     forwards). Returns (updated pools, last-token logits [B, V] f32).
     Duplicate page rows (wave padding repeats a real request) write the
     same content twice — benign."""
+    if not cache_spec.is_uniform(cfg):
+        return hybrid.prefill(
+            params, cfg, ids, prompt_lens, jnp.int32(0), pools,
+            jnp.zeros((ids.shape[0], 0), jnp.int32), page_ids, slots)
     page_size = pools[0][0].shape[2]
     b, pb = ids.shape
     n_pg = pb // page_size
@@ -972,6 +893,7 @@ def prefill_suffix_into_pages(
     pools: tuple,
     prefix_page_ids: jnp.ndarray, # [n_prefix_pg] int32 (0/null-padded tail)
     page_ids: jnp.ndarray,        # [pb // page_size] int32 suffix pages
+    slot: jnp.ndarray | None = None,  # the engine's slot (a recurrent state's row)
 ) -> tuple[tuple, jnp.ndarray]:
     """Prefix-cache prefill: compute KV only for the suffix while attending
     over the cached prefix pages (the compute-skip that makes page-granular
@@ -981,7 +903,16 @@ def prefill_suffix_into_pages(
     The prefix occupies whole pages (``prefix_len`` ≤
     ``n_prefix_pg·page_size``, padded entries null); suffix KV is scattered
     into ``page_ids``. Returns (updated pools, last-token logits [V] f32).
+
+    For a model with a recurrent state the prefix is what chunked prefill
+    itself filled: the state row ``slot`` holds the state after it
+    (``hybrid.prefill``; there is no prefix-cache hit for such a model).
     """
+    if not cache_spec.is_uniform(cfg):
+        pools, logits = hybrid.prefill(
+            params, cfg, ids[None], suffix_len[None], prefix_len, pools,
+            prefix_page_ids[None], page_ids[None], slot[None])
+        return pools, logits[0]
     page_size = pools[0][0].shape[2]
     pb = ids.shape[0]
     n_pg = pb // page_size
@@ -1038,6 +969,7 @@ def prefill_suffix_batch_into_pages(
     pools: tuple,
     prefix_page_ids: jnp.ndarray, # [B, n_prefix_pg] int32 (null-padded tail)
     page_ids: jnp.ndarray,        # [B, pb // page_size] int32 suffix pages
+    slots: jnp.ndarray | None = None,  # [B] the engine's slots
 ) -> tuple[tuple, jnp.ndarray]:
     """Batched prefix-cache prefill: B suffixes in ONE dispatch, each
     attending over its own cached prefix pages — the group-shared-prefill
@@ -1049,6 +981,9 @@ def prefill_suffix_batch_into_pages(
     cache's write offset is one traced scalar); rows may differ in suffix
     content/length and prefix pages. Returns (updated pools, last-token
     logits [B, V] f32)."""
+    if not cache_spec.is_uniform(cfg):
+        return hybrid.prefill(params, cfg, ids, suffix_lens, prefix_len,
+                              pools, prefix_page_ids, page_ids, slots)
     page_size = pools[0][0].shape[2]
     b, pb = ids.shape
     n_pg = pb // page_size

@@ -50,7 +50,7 @@ class MeshConfig:
     # Expert parallelism: a REAL axis (beyond the reference, which stubs
     # expert knobs at workers/config/rollout.py:193-196) — MoE expert
     # weights shard over it (models/decoder.py MoE param specs) and each
-    # rank computes its own experts' rows (decoder._expert_mix_sharded).
+    # rank computes its own experts' rows (blocks._expert_mix_sharded).
     ep: int = 1
 
     def resolve(self, n_devices: int) -> tuple[int, int, int, int, int, int]:
@@ -88,7 +88,7 @@ def under(mesh: Mesh | None, fn):
     ``jax.set_mesh(mesh)``; ``fn`` itself without a mesh. The trainer and
     the engine put their jitted programs through this: code inside a trace
     that has to go manual over an axis (the MoE block over ``ep``,
-    ``decoder._expert_mix_sharded``) finds the mesh there, and every call sets
+    ``blocks._expert_mix_sharded``) finds the mesh there, and every call sets
     it because the mesh is part of jit's cache key."""
     if mesh is None:
         return fn

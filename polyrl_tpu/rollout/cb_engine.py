@@ -65,7 +65,7 @@ import numpy as np
 
 from polyrl_tpu import obs
 from polyrl_tpu.engine_options import EngineOptions
-from polyrl_tpu.models import decoder
+from polyrl_tpu.models import cache_spec, decoder, hybrid
 from polyrl_tpu.obs.engine_profile import EngineLoopProfiler
 from polyrl_tpu.rollout.engine import next_bucket
 from polyrl_tpu.rollout.flightdeck import EngineFlightDeck, ThroughputEWMA
@@ -256,6 +256,24 @@ class CBEngine:
                     "paged attention shard on the head dim")
             params = self._shard_params_for_mesh(params)
         self.params = params
+        # a model that keeps a recurrent state in its slot (decided from
+        # its layers, models/cache_spec.py, and by no option) has no
+        # snapshot to re-enter a sequence from: no prefix cache (so no hit,
+        # no publish, no spill, no salvage publish; a GRPO group's siblings
+        # and a resumed partial prefill from token 0), no shared-prefix
+        # decode groups, and no prompt-lookup speculation
+        self.stateful = cache_spec.is_stateful(cfg)
+        if self.stateful:
+            if int(o.spec_tokens) > 0:
+                raise ValueError(
+                    "spec_tokens > 0 needs a state to roll back to after a "
+                    "rejected draft; this model keeps a recurrent state "
+                    "with no snapshot (models/cache_spec.py)")
+            if mesh is not None and mesh.size > 1:
+                raise NotImplementedError(
+                    "a model with a recurrent state on a mesh of several "
+                    "chips")
+            enable_prefix_cache = False
         self.max_slots = max_slots
         self.page_size = page_size
         self.max_seq_len = o.max_seq_len
@@ -379,6 +397,7 @@ class CBEngine:
         self._last_two: collections.deque = collections.deque(maxlen=2)
         self.steps_per_dispatch = max(1, int(o.steps_per_dispatch))
         self.prefill_chunk = int(o.prefill_chunk)
+        self.prefill_first = bool(o.prefill_first)
         self._chunk_jobs: collections.deque = collections.deque()
         # prompt-lookup speculative decoding: each decode dispatch runs
         # spec_rounds fused speculation rounds; every round proposes
@@ -412,7 +431,7 @@ class CBEngine:
         # (decoder._moe_mlp), summed over fused steps and layers: (row,
         # expert) pairs of live rows, experts with a row, rows of the
         # busiest expert. Stays zero for a dense model.
-        self._moe_load = np.zeros(3, np.int64)
+        self._moe_load = np.zeros(hybrid.load_width(cfg), np.int64)
         # group pre-ref registry: leader publish pre-takes group_size-1 refs
         # on the shared prefix entries so pool-pressure eviction can't race
         # the siblings' attach; consumed per attach, TTL-swept for groups
@@ -429,7 +448,8 @@ class CBEngine:
         # prompt KV per group instead of one per sibling); singleton
         # leftovers and decode_group_share=False degrade to the ungrouped
         # kernel (bitwise the pre-PR decode path). Loop-thread only.
-        self.decode_group_share = bool(o.decode_group_share)
+        self.decode_group_share = (bool(o.decode_group_share)
+                                   and not self.stateful)
         self._decode_groups: dict[str, dict] = {}
         self._slot_decode_gid: dict[int, str] = {}
         self._grouped_attn = None  # built lazily (TP wrapper under a mesh)
@@ -543,7 +563,11 @@ class CBEngine:
                          for x in jax.tree_util.tree_leaves(pools)
                          if hasattr(x, "nbytes"))
         if self.kvledger is not None and pool_b:
-            self.kvledger.page_bytes = pool_b // max(1, self.num_pages)
+            # bytes a page: the paged arrays', not a recurrent state's
+            paged = pools if not self.stateful else pools[0]
+            self.kvledger.page_bytes = sum(
+                int(x.nbytes) for x in jax.tree_util.tree_leaves(paged)
+            ) // max(1, self.num_pages)
         return float(self._weight_bytes + pool_b)
 
     def _cache_pages(self) -> int:
@@ -555,9 +579,37 @@ class CBEngine:
         the decode steps landed so far ({} for a dense model)."""
         if not self.cfg.num_experts:
             return {}
-        routed, hit, load_max = (int(v) for v in self._moe_load)
-        return {"moe_routed": routed, "moe_experts_hit": hit,
+        routed, hit, load_max, *more = (int(v) for v in self._moe_load)
+        info = {"moe_routed": routed, "moe_experts_hit": hit,
                 "moe_load_max": load_max}
+        if more:
+            # a model of several kinds of layer (hybrid.load_width): every
+            # choice of a live row, held here or not (``moe_routed`` counts
+            # the held ones), and live rows times KDA layers
+            info["moe_choices"], info["kda_state_rows"] = more
+        return info
+
+    def recurrent_state(self, rid: str):
+        """What the slot of the running request ``rid`` holds of its
+        recurrent state now: (tokens it has consumed, prompt and fed-back
+        answer alike; one float32 array ``[H, Dk, Dv]`` a KDA layer in
+        order), on the host. None for a model without such a state or a
+        request that is not decoding. Waits for the programs in flight;
+        the read-only half of a state snapshot (ROADMAP M8)."""
+        if not self.stateful:
+            return None
+        with self._pool_lock:
+            for i, info in enumerate(self._slots):
+                if (info is not None and self._active[i]
+                        and info.req.rid == rid):
+                    break
+            else:
+                return None
+            self._ensure_dev_state()
+            consumed = int(np.asarray(self._dev_state["seq_lens"])[i])
+            rows = [np.asarray(state[i]).astype(np.float32)
+                    for state, _conv in self._pools[1]]
+        return consumed, rows
 
     def kv_memory_info(self) -> dict:
         """Flat server_info fields for the memory plane ({} when the
@@ -761,7 +813,7 @@ class CBEngine:
         params induce, decoder.cache_specs rationale)."""
         pools = decoder.make_paged_pools(
             self.cfg, self.num_pages, self.page_size,
-            dtype=self.kv_cache_dtype)
+            dtype=self.kv_cache_dtype, slots=self.max_slots + 1)
         if self.mesh is None:
             return pools
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1124,7 +1176,7 @@ class CBEngine:
                  budget, top_k, temp, top_p) = self._unpack_prefill(
                     packed, pb, n_pg, pps, 0)
                 (kp, vp), last_logits = decoder.prefill_into_pages(
-                    params, cfg, ids, prompt_len, (kp, vp), page_ids)
+                    params, cfg, ids, prompt_len, (kp, vp), page_ids, slot)
                 with jax.named_scope("sample"):
                     rng, sub = jax.random.split(rng)
                     token, logp = sample_token_vec(
@@ -1165,7 +1217,7 @@ class CBEngine:
                 temps = jax.lax.bitcast_convert_type(sc[:, 5], jnp.float32)
                 top_ps = jax.lax.bitcast_convert_type(sc[:, 6], jnp.float32)
                 (kp, vp), last_logits = decoder.prefill_batch_into_pages(
-                    params, cfg, ids, prompt_lens, (kp, vp), page_ids)
+                    params, cfg, ids, prompt_lens, (kp, vp), page_ids, slots)
                 with jax.named_scope("sample"):
                     rng, sub = jax.random.split(rng)
                     token, logp = sample_token_vec(
@@ -1202,11 +1254,11 @@ class CBEngine:
 
             def prefill_extend(params, kp, vp, packed, rng):
                 (ids, page_ids, _row, _stop, prefix_ids, suffix_len,
-                 prefix_len, *_rest) = self._unpack_prefill(
+                 prefix_len, slot, *_rest) = self._unpack_prefill(
                     packed, pb, n_pg, pps, n_prefix_pg)
                 (kp, vp), _ = decoder.prefill_suffix_into_pages(
                     params, cfg, ids, suffix_len, prefix_len, (kp, vp),
-                    prefix_ids, page_ids)
+                    prefix_ids, page_ids, slot)
                 return kp, vp, rng
 
             self._program(self._prefill_fns, "prefill_extend", key, jax.jit(
@@ -1315,7 +1367,7 @@ class CBEngine:
                     self._unpack_prefill(packed, pb, n_pg, pps, n_prefix_pg)
                 (kp, vp), last_logits = decoder.prefill_suffix_into_pages(
                     params, cfg, ids, suffix_len, prefix_len, (kp, vp),
-                    prefix_page_ids, page_ids)
+                    prefix_page_ids, page_ids, slot)
                 with jax.named_scope("sample"):
                     rng, sub = jax.random.split(rng)
                     token, logp = sample_token_vec(
@@ -1363,7 +1415,7 @@ class CBEngine:
                 top_ps = jax.lax.bitcast_convert_type(sc[:, 6], jnp.float32)
                 (kp, vp), last_logits = decoder.prefill_suffix_batch_into_pages(
                     params, cfg, ids, suffix_lens, prefix_len, (kp, vp),
-                    prefix_ids, page_ids)
+                    prefix_ids, page_ids, slots)
                 with jax.named_scope("sample"):
                     rng, sub = jax.random.split(rng)
                     token, logp = sample_token_vec(
@@ -1688,7 +1740,10 @@ class CBEngine:
                 # device for the whole prefill
                 with self._phase("prefill_dispatch"):
                     self._advance_chunk_job()
-            if self._active.any():
+            if self.prefill_first and self._chunk_jobs:
+                # decode waits for the prompts; what has landed streams out
+                self._emit_landed()
+            elif self._active.any():
                 self._step_once()
             elif self._pending and not self._chunk_jobs:
                 with self._phase("idle"):

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from polyrl_tpu.models import decoder
+from polyrl_tpu.models import blocks, decoder
 from polyrl_tpu.models.decoder import _moe_mlp
 
 
@@ -310,15 +310,15 @@ def test_mixtral_hf_logits_parity(tmp_path):
 
 
 def _count_ep_traces(monkeypatch) -> list:
-    """Count the traces that enter ``decoder._expert_mix_sharded``."""
+    """Count the traces that enter ``blocks._expert_mix_sharded``."""
     calls = []
-    real = decoder._expert_mix_sharded
+    real = blocks._expert_mix_sharded
 
     def counted(*args):
         calls.append(1)
         return real(*args)
 
-    monkeypatch.setattr(decoder, "_expert_mix_sharded", counted)
+    monkeypatch.setattr(blocks, "_expert_mix_sharded", counted)
     return calls
 
 

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from polyrl_tpu.models import decoder
+from polyrl_tpu.models import blocks, decoder
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 RTOL = 1e-4
@@ -301,7 +301,7 @@ def test_large_tables_are_gathered_to_the_same_result(monkeypatch):
     x = jax.random.normal(jax.random.PRNGKey(1), (40, cfg.hidden_size))
     valid = jnp.arange(40) % 5 != 2
     hot, load = decoder._moe_mlp(cfg, x, lp, valid)
-    monkeypatch.setattr(decoder, "_ONE_HOT_ROWS", 0)
+    monkeypatch.setattr(blocks, "_ONE_HOT_ROWS", 0)
     gathered, load2 = decoder._moe_mlp(cfg, x, lp, valid)
     np.testing.assert_array_equal(np.asarray(hot), np.asarray(gathered))
     assert load.tolist() == load2.tolist()

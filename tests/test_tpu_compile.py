@@ -343,3 +343,79 @@ def test_qwen3_30b_a3b_preset_equals_the_benchmark_file():
     assert config["reduced"] == ["num_hidden_layers"]
     assert preset.num_layers == 48 and cfg.num_layers == 7
     assert raw["decoder_sparse_step"] == 1 and raw["mlp_only_layers"] == []
+
+
+# -- the Ling-3.0 hybrid at its published widths ------------------------------
+
+
+def test_latent_attention_kernel_compiles_for_v5e(one_chip):
+    """The absorbed MLA decode kernel at the cell's shapes: 129 rows, 32
+    heads, latent rows of 576 values in 640 lanes, 15,617 pages of 64, a
+    table 192 pages wide. (A pool 576 wide is refused: a DMA slices a
+    pool at whole 128-lane tiles.)"""
+    from polyrl_tpu.ops.mla_attention import latent_paged_attention_pallas
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    fn = functools.partial(latent_paged_attention_pallas, rank=512,
+                           scale=192 ** -0.5)
+    compiled = jax.jit(fn).lower(
+        arg((129, 32, 640), jnp.bfloat16),
+        arg((1, 15617, 64, 640), jnp.bfloat16), arg((129, 192), jnp.int32),
+        arg((129,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**20
+
+
+def test_ling_decode_step_compiles_for_v5e_within_memory(one_chip,
+                                                         chip_precision,
+                                                         on_tpu):
+    """The cell's whole decode program: 8 fused steps of the 7-layer cut
+    at 129 rows, the state slots and the latent pool donated, the token
+    drawn inside the head. Everything it holds at once fits a 16 GB chip
+    with a gigabyte to spare, and the states are updated in place (no
+    copy of a 270 MB state array among the temporaries)."""
+    from polyrl_tpu.models import decoder
+
+    cfg = decoder.get_config("ling-3.0-flash-share4")
+    s, width, n_pages, page = 129, 192, 15617, 64
+
+    def shapes(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = shapes(jax.eval_shape(
+        lambda: decoder.init_params(jax.random.PRNGKey(0), cfg)))
+    pools = shapes(jax.eval_shape(
+        lambda: decoder.make_paged_pools(cfg, n_pages, page, slots=s)))
+
+    def step(params, paged, state, rng, table, lens, last, active, temps):
+        def body(carry, _):
+            paged, state, rng, lens, last = carry
+            rng, sub = jax.random.split(rng)
+            head = functools.partial(decoder.head_and_sample, rng=sub,
+                                     temps=temps)
+            (tok, logp), (paged, state), load = decoder.forward_paged_decode(
+                params, cfg, last, lens, (paged, state), table, lens,
+                active=active, head_fn=head)
+            return (paged, state, rng, lens + 1, tok), (tok, logp, load)
+        return jax.lax.scan(body, (paged, state, rng, lens, last), None,
+                            length=8)
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, pools[0], pools[1], arg((2,), jnp.uint32),
+        arg((s, width), jnp.int32), arg((s,), jnp.int32),
+        arg((s,), jnp.int32), arg((s,), jnp.bool_),
+        arg((s,), jnp.float32)).compile()
+    m = compiled.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert live < 15 * 10**9
+    assert m.temp_size_in_bytes < 200 * 2**20
+    # the latent attention, six grouped gate/up and six down matmuls, the head
+    assert compiled.as_text().count("tpu_custom_call") >= 14
