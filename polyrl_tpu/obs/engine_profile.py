@@ -78,6 +78,10 @@ two samples give a rate over any window, with no profiler session):
 - ``admission_deferrals`` — loop iterations in which admission left a
   request pending for want of pages or a slot and went on without
   waiting (``CBEngine._admit``);
+- ``pages_grown`` / ``slot_yields`` — KV pages handed to rows already
+  running, as they wrote their way into them (``CBEngine._grow_rows``),
+  and rows that gave up slot and pages because the pool had no more and
+  went back to the queue's head (``CBEngine._yield_row``);
 - ``device_busy_s`` — seconds with device work outstanding: an interval
   opens when a dispatch is enqueued with nothing outstanding and closes
   when a landed result leaves nothing newer outstanding. Seconds are added
@@ -156,6 +160,8 @@ class EngineLoopProfiler:
         self.decode_dispatches = 0
         self.decode_dispatches_cold = 0
         self.admission_deferrals = 0
+        self.pages_grown = 0
+        self.slot_yields = 0
         self.decode_steps_done = 0
         self.fused_sample_steps = 0
         self.fetch_s = 0.0
@@ -297,6 +303,16 @@ class EngineLoopProfiler:
         with self._lock:
             self.admission_deferrals += 1
 
+    def on_pages_grown(self, n: int) -> None:
+        """Rows already running took ``n`` more pages before a dispatch."""
+        with self._lock:
+            self.pages_grown += int(n)
+
+    def on_slot_yield(self) -> None:
+        """A running row gave up its slot and pages for want of pages."""
+        with self._lock:
+            self.slot_yields += 1
+
     def on_landed(self, n: int) -> None:
         """The oldest ``n`` dispatches' results are on the host: the
         device has finished them and everything enqueued before them."""
@@ -377,6 +393,8 @@ class EngineLoopProfiler:
                 "decode_dispatches": self.decode_dispatches,
                 "decode_dispatches_cold": self.decode_dispatches_cold,
                 "admission_deferrals": self.admission_deferrals,
+                "pages_grown": self.pages_grown,
+                "slot_yields": self.slot_yields,
                 "decode_steps_done": self.decode_steps_done,
                 "fused_sample_steps": self.fused_sample_steps,
                 "device_busy_s": round(self.device_busy_s, 6),

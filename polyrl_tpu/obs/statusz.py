@@ -104,7 +104,7 @@ _HIST_SUFFIXES = ("p50", "p95", "p99", "max", "mean", "count")
 # and tools/check_statusz_docs.py holds ARCHITECTURE.md to naming each.
 CUMULATIVE_INFO_KEYS = frozenset((
     "decode_dispatches", "decode_dispatches_cold", "admission_deferrals",
-    "decode_steps_done", "fused_sample_steps", "device_busy_s",
+    "pages_grown", "slot_yields", "decode_steps_done", "fused_sample_steps", "device_busy_s",
     "loop_wall_s", "loop_host_s", "programs_built", "stream_chunks",
     "stream_lag_s"))
 # cumulative too, and counters where present: a MoE model's engine alone

@@ -12,6 +12,12 @@ sglang_http_async_engine.py:286-298). Design:
 - Paged KV: slots own page lists from a shared pool
   (``decoder.make_paged_pools``); attention is
   ``ops.paged_attention`` (Pallas on TPU). No shape buckets in decode.
+  A slot holds the pages it has WRITTEN plus what the dispatches in flight
+  can write: admission takes the prompt's, every decode dispatch is
+  preceded by the pages its rows grow into (``_grow_rows``), and when the
+  pool runs out the youngest row gives up slot and pages and re-enters as
+  a continuation of itself (``_yield_row``; ARCHITECTURE.md "The life of
+  a page").
   Dispatches with live GRPO groups route through the two-phase GROUPED
   kernel (``grouped_paged_attention``): one HBM stream of the group's
   shared prompt KV serves every sibling per decode step, suffixes merge
@@ -179,6 +185,10 @@ class _Request:
     # or wrong hint degrades to per-request admission, never corrupts.
     group_id: str = ""
     group_size: int = 0
+    # tokens this rid had streamed when it last gave up its slot for want
+    # of pages (``_yield_row``): they are the tail of ``input_ids`` now,
+    # and they count when the engine looks for its youngest row
+    resumed: int = 0
 
 
 @dataclasses.dataclass
@@ -1306,7 +1316,8 @@ class CBEngine:
             return
         n_prompt = len(req.input_ids)
         remaining = n_prompt - job["pos"]
-        if remaining <= self.prefill_chunk:
+        chunk = job["chunk"]
+        if remaining <= chunk:
             # final chunk: standard suffix admission (samples the first
             # token, activates the slot, publishes the whole prompt)
             self._chunk_jobs.popleft()
@@ -1328,7 +1339,6 @@ class CBEngine:
                 self._emit_error(req, "prefill failed")
                 raise  # pools may be donation-poisoned: _recover resets
             return
-        chunk = self.prefill_chunk
         pos = job["pos"]
         prefix_pages = (job["matched_pages"]
                         + job["pages"][:job["own_filled"]])
@@ -1848,9 +1858,10 @@ class CBEngine:
             self._consume_group_preref(req)  # sibling that never attaches
 
     def _collect_wave(self) -> tuple[list, str]:
-        """Collect up to ``admit_wave`` admissible requests, reserving a
-        slot + pages for each: (req, slot, pages, budget, matched_pages,
-        matched_entries), plus the wave kind:
+        """Collect up to ``admit_wave`` admissible requests, taking a slot
+        and the PROMPT's pages for each (what its answer needs comes as it
+        is written, ``_grow_rows``): (req, slot, pages, budget,
+        matched_pages, matched_entries), plus the wave kind:
 
         - ``"fresh"`` — no cached prefix anywhere in the wave: one batched
           full-prompt prefill (or a singleton).
@@ -1867,7 +1878,11 @@ class CBEngine:
         scanning continues — up to ``admit_reorder_window`` skips, instead
         of ``break``-ing admission for every unrelated request queued
         behind it. Page exhaustion still ends the scan: skipping past a
-        page-starved head would let small requests starve big ones."""
+        page-starved head would let small requests starve big ones. So does
+        a pool without headroom: a request comes in only while, its own
+        pages taken, every row that is running could still take its next
+        page (``_pages_in_reach``), so that a row which has just yielded its
+        pages does not come back to take them from the next."""
         wave: list = []
         kind = "fresh"
         attach_len = -1  # prefix page count of a forming attach wave
@@ -1899,15 +1914,19 @@ class CBEngine:
                 self._consume_group_preref(req)  # sibling that never attaches
                 continue
             n_prompt = len(req.input_ids)
-            if n_prompt == 0 or n_prompt > min(self.max_seq_len - 1,
-                                               self.prompt_buckets[-1]):
+            budget = min(req.sampling.max_new_tokens,
+                         self.max_seq_len - n_prompt)
+            # refused at once: an input no slot holds, and one that with
+            # its whole answer is more than the pool (it could never end)
+            if (n_prompt == 0 or n_prompt > self.max_seq_len - 1
+                    or -(-(n_prompt + budget) // self.page_size)
+                    > self.num_pages - 1):
                 del self._pending[scan]
                 self._emit_error(req, f"prompt length {n_prompt} unsupported")
                 self._consume_group_preref(req)
                 continue
-            budget = min(req.sampling.max_new_tokens,
-                         self.max_seq_len - n_prompt)
-            n_pages = -(-(n_prompt + budget) // self.page_size)
+            # the prompt's pages, the first decode step's write among them
+            n_pages = -(-(n_prompt + 1) // self.page_size)
             n_full = max(0, (n_prompt - 1) // self.page_size)
             matched_pages: list[int] = []
             matched_entries: list = []
@@ -1935,8 +1954,8 @@ class CBEngine:
                               and (first_key in wave_page_keys
                                    or first_key in chunk_keys))
             prefix_cached = len(matched_pages) * self.page_size
-            chunked = (self.prefill_chunk
-                       and n_prompt - prefix_cached > self.prefill_chunk)
+            chunk = self._chunk_tokens(n_prompt - prefix_cached)
+            chunked = chunk > 0
             blocked = sibling_blocked
             if wave:
                 if kind == "attach":
@@ -1953,6 +1972,17 @@ class CBEngine:
                 scan += 1
                 continue
             need = n_pages - len(matched_pages)
+            rows = (int(self._active.sum()) + len(self._chunk_jobs)
+                    + len(wave) + 1)
+            want = need + rows
+            if rows > 1 and not self._retry_landed(
+                    lambda: self._pages_in_reach(want) >= want):
+                # no headroom: wait (a lone row needs none: it fits the
+                # pool, or it was refused above)
+                if self.prefix_cache is not None:
+                    self.prefix_cache.release(matched_entries)
+                self._admission_waiting = True
+                break
             pages = self._try_alloc(need, matched_entries)
             if pages is None:
                 # pages exhausted: wait (no skip — alloc fairness)
@@ -1962,8 +1992,8 @@ class CBEngine:
             slot = free[0]
             assigned.add(slot)
             if self.kvledger is not None:
-                # the single alloc site (every _try_alloc caller lands
-                # here): pages become slot-owned active-decode
+                # pages become slot-owned active-decode (here and where a
+                # row grows, ``_grow_rows``)
                 self.kvledger.on_alloc(pages,
                                        owner=req.group_id or req.rid)
             if self.prefix_cache is not None:
@@ -1981,7 +2011,7 @@ class CBEngine:
                     "req": req, "slot": slot, "pages": list(pages),
                     "matched_pages": list(matched_pages),
                     "matched_entries": list(matched_entries),
-                    "budget": budget, "pos": prefix_cached,
+                    "budget": budget, "pos": prefix_cached, "chunk": chunk,
                     "own_filled": 0, "version": self.weight_version,
                     "first_key": first_key,
                 })
@@ -2010,7 +2040,17 @@ class CBEngine:
         in the pipeline returns its pages when the loop's throttle lands
         it, and the request is placed on the iteration after."""
         pages = self._retry_landed(lambda: self.allocator.alloc(need))
-        if pages is None and self.kvspill is not None:
+        if pages is None:
+            pages = self._alloc_reclaiming(need)
+        if pages is None and self.prefix_cache is not None:
+            self.prefix_cache.release(matched_entries)
+        return pages
+
+    def _alloc_reclaiming(self, need: int):
+        """``need`` pages once the free list has too few: from what the
+        cache holds unreferenced, spilled before evicted."""
+        pages = None
+        if self.kvspill is not None:
             # allocation pressure: page unreferenced published KV out to
             # host BEFORE evicting it — spilling preserves what eviction
             # destroys, which is what lets sessions oversubscribe HBM
@@ -2021,9 +2061,144 @@ class CBEngine:
             # pool pressure: evict unreferenced cached pages and retry
             if self.prefix_cache.evict(need - self.allocator.free_count):
                 pages = self.allocator.alloc(need)
-        if pages is None and self.prefix_cache is not None:
-            self.prefix_cache.release(matched_entries)
         return pages
+
+    def _chunk_tokens(self, n_new: int) -> int:
+        """Tokens a chunk for a prefill of ``n_new`` tokens the cache does
+        not hold: 0 where it goes whole, ``prefill_chunk`` where that is
+        set and exceeded, and for an input longer than the largest prompt
+        bucket (a row that yielded comes back with its answer so far as
+        input, up to ``max_seq_len - 1`` tokens) the largest bucket's whole
+        pages."""
+        if self.prefill_chunk and n_new > self.prefill_chunk:
+            return self.prefill_chunk
+        if n_new <= self.prompt_buckets[-1]:
+            return 0
+        return self.prompt_buckets[-1] // self.page_size * self.page_size
+
+    def _pages_in_reach(self, want: int) -> int:
+        """Pages an allocation could get without taking any from a row:
+        the free ones and, only where those are fewer than ``want``, the
+        cache's unreferenced resident pages (``_try_alloc`` spills or
+        evicts them)."""
+        n = self.allocator.free_count
+        if n < want and self.prefix_cache is not None:
+            n += len(self.prefix_cache.spill_candidates())
+        return n
+
+    def _grow_rows(self) -> None:
+        """Before a decode dispatch: every active row gets the pages that
+        cover what it has landed plus everything the dispatches in flight
+        and this one can write. The host's mirror plus ``_inflight_tok``
+        bounds the device's length when this dispatch runs (the loop is
+        ``pipeline_depth`` ahead); a dispatch writes ``steps_per_dispatch``
+        positions a row, the speculative one ``spec_tokens + 1`` a round;
+        no row writes at or past the position of its budget's last token.
+        When a row needs a page, every row is topped up further ahead
+        while the free list is roomy: its even share of half the free pages,
+        a share that shrinks to the bare need as the pool fills (each upload
+        of the table is a fresh device buffer, and a decode window wants few
+        of them: PERF.md section 6, PR 34).
+        Where the pool has no more (after what ``_try_alloc`` tries: the
+        landed finishers, spill, eviction) the youngest row yields
+        (``_yield_row``) and the count is made again. The new ids go into
+        the host's table, which is uploaded whole as the step's
+        ``page_table`` operand (``_page_table_dev``)."""
+        spec = self.spec_tokens > 0
+        width = self.spec_tokens + 1 if spec else 1
+        ahead = self.spec_rounds * width if spec else self.steps_per_dispatch
+        while True:
+            rows = np.flatnonzero(self._active)
+            if not rows.size:
+                return
+            seq = self._seq_lens[rows].astype(np.int64)
+            flight = seq + self._inflight_tok[rows] * width
+            end = seq + self._budgets[rows] - self._n_generated[rows]
+            held = np.count_nonzero(self._page_table[rows], axis=1)
+
+            def short(tokens: int):
+                """Pages each row lacks to write ``tokens`` more."""
+                reach = np.minimum(flight + tokens, end)
+                return np.maximum(-(-reach // self.page_size) - held, 0)
+
+            more = short(ahead)
+            if not more.any():
+                return
+            # some row is at its last page's end. While the free list is
+            # roomy EVERY row takes what it will ask for some way ahead
+            # (its even share of half the free pages), so that the table
+            # goes up a few times a pool's filling and not with every
+            # dispatch; as the pool fills the share shrinks to the bare need
+            stride = self.allocator.free_count // (2 * rows.size)
+            wide = short(ahead + max(1, stride) * self.page_size)
+            pages = self.allocator.alloc(int(wide.sum()))
+            if pages is not None:
+                more = wide
+                break
+            pages = self.allocator.alloc(int(more.sum()))
+            if pages is None and self._emit_landed():
+                continue  # a finisher's pages may be back: count again
+            if pages is None:
+                pages = self._alloc_reclaiming(int(more.sum()))
+            if pages is not None:
+                break
+            self._yield_row(min(
+                rows, key=lambda i: (
+                    self._slots[i].req.resumed + int(self._n_generated[i]),
+                    -self._slots[i].req.t_submit)))
+        for i, at, n in zip(rows, held, more):
+            if n:
+                info = self._slots[i]
+                got, pages = pages[:n], pages[n:]
+                self._page_table[i, at:at + n] = got
+                info.pages.extend(got)
+                if self.kvledger is not None:
+                    self.kvledger.on_alloc(
+                        got, owner=info.req.group_id or info.req.rid)
+        if self._dev_state is not None:
+            # the step's operand; a state that is gone (a row yielded) is
+            # built anew from the same table before the dispatch
+            self._dev_state["page_table"] = self._page_table_dev()
+        if self.profiler is not None:
+            self.profiler.on_pages_grown(int(more.sum()))
+
+    def _yield_row(self, slot: int) -> None:
+        """The pool ran out: row ``slot`` gives up its slot and pages and
+        goes back to the head of ``_pending`` as a continuation (the
+        paper's token-level continuation, inside one engine): its input is
+        its prompt plus what it has streamed, its budget what is left, its
+        rid and stream the same, so nothing is streamed twice and no
+        terminal line stands in between. Everything dispatched lands first,
+        so the mirrors are exact. With a prefix cache its full pages are
+        published (``_salvage_publish``: not KV written under older
+        weights) and its re-entry attaches to what is still there; a
+        stateful model has none and prefills the row anew, in chunks where
+        it is longer than a bucket. It comes back when admission's
+        headroom test passes (``_collect_wave``)."""
+        self._drain_emit_q()
+        info = self._slots[slot]
+        if info is None or not self._active[slot]:
+            return  # it ended in the drain: its pages are back
+        req = info.req
+        left = int(self._budgets[slot] - self._n_generated[slot])
+        self._active[slot] = False
+        self._slot_gen[slot] += 1
+        self._salvage_publish(slot, info)
+        self._finalize(slot, cause="yield")
+        self._invalidate_dev_state()
+        self._pending.appendleft(_Request(
+            req.rid, list(req.input_ids) + [int(t) for t in info.emitted],
+            dataclasses.replace(req.sampling, max_new_tokens=left),
+            req.out, req.abort, time.monotonic(),
+            resumed=req.resumed + len(info.emitted)))
+        self.num_running = int(self._active.sum())
+        if self.profiler is not None:
+            self.profiler.on_slot_yield()
+
+    def _page_table_dev(self):
+        """The host's page table and the sink's null row, on the device."""
+        return jnp.asarray(np.concatenate(
+            [self._page_table, np.zeros((1, self.pages_per_slot), np.int32)]))
 
     def _prefill_wave(self, wave: list) -> None:
         """Batched fused admission: ONE dispatch prefills every request in
@@ -2514,9 +2689,7 @@ class CBEngine:
         # null), so padded batch prefills can't collide with a real slot's
         # sampled token / active flag
         self._dev_state = {
-            "page_table": jnp.asarray(np.concatenate(
-                [self._page_table,
-                 np.zeros((1, self.pages_per_slot), np.int32)])),
+            "page_table": self._page_table_dev(),
             "seq_lens": jnp.asarray(np.append(self._seq_lens, 0).astype(np.int32)),
             "last_tokens": jnp.asarray(np.append(
                 self._last_tokens, self.pad_token_id).astype(np.int32)),
@@ -2925,6 +3098,10 @@ class CBEngine:
             out = self._outstanding()
             if out:
                 self._drain_emit_q(keep=out - 1)
+            return
+        with self._phase("accounting"):
+            self._grow_rows()
+        if not self._active.any():  # the pool had room for none of them
             return
         use_filters = bool(np.any(
             (self._top_ps[self._active] < 1.0) | (self._top_ks[self._active] > 0)))

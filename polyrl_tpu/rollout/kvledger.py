@@ -20,7 +20,8 @@ engine loop thread at every page transition:
   touches every page of every active slot's page row (the pages the
   attention kernels logically attend), so idle age = ticks since a decode
   last read the page.
-- **free cause** — ``finalize`` / ``abort`` / ``salvage`` /
+- **free cause** — ``finalize`` / ``abort`` / ``salvage`` / ``yield`` (a
+  running row gave its pages up because the pool had no more) /
   ``cache_pressure`` / ``flush`` / ``preref_ttl``; page lifetime
   (free − birth) and idle-at-free age feed log2 histograms.
 
@@ -71,8 +72,8 @@ ROLE_NAMES = ("free", "active_decode", "prefix_cache_published",
 # on the allocator free list — so it is tracked as a scalar count beside
 # the physical role array, and role_counts() reports it as a fifth role
 
-FREE_CAUSES = ("finalize", "abort", "salvage", "cache_pressure", "flush",
-               "preref_ttl")
+FREE_CAUSES = ("finalize", "abort", "salvage", "yield", "cache_pressure",
+               "flush", "preref_ttl")
 
 _GB = 1e9
 

@@ -5,9 +5,13 @@ one waits the loop keeps the device one program ahead (by what the device
 has finished, not by what has reached the host); with nobody waiting the
 throttle is ``pipeline_depth``. Timing moves, results do not.
 
-The engines here are the tiny preset with a pool that admits 2 of 4
-requests: prompts of 8 tokens (no full page, so nothing is published),
-answers long enough that nobody ends unless a case wants it."""
+The engines here are the tiny preset with 2 slots for 4 requests: prompts
+of 8 tokens (no full page, so nothing is published), answers long enough
+that nobody ends unless a case wants it, and a pool that holds what two
+rows write. (Until PR 34 it was the pool that admitted 2 of 4, each
+reserving prompt + answer at admission; pages now follow what a row has
+written, ``tests/test_page_growth.py``, so what a request waits for here
+is a slot, except in (f), where it is pages a long row has written.)"""
 
 import threading
 import time
@@ -31,8 +35,8 @@ def tiny():
 
 def _mk_engine(tiny, **kw):
     cfg, params = tiny
-    # 63 pages to hand out; a request of 8 + 240 tokens reserves 31
-    defaults = dict(max_slots=4, page_size=8, max_seq_len=256,
+    # 63 pages to hand out; a request of 8 + 240 tokens writes 31
+    defaults = dict(max_slots=2, page_size=8, max_seq_len=256,
                     prompt_buckets=(16,), num_pages=64)
     defaults.update(kw)
     return CBEngine(cfg, params, **defaults)
@@ -95,7 +99,7 @@ def _old_drain(self, attempt):
 
 def _iterate_with_two_waiting(eng, cfg, n_iters=20):
     """Drive the loop by hand (no threads: a landing happens only where the
-    loop asks for one, so the counts are exact): 4 long requests, 2 fit.
+    loop asks for one, so the counts are exact): 4 long requests, 2 slots.
     Returns the counters after the first iteration and after ``n_iters``
     more."""
     for i, p in enumerate(_prompts(cfg)):
@@ -154,12 +158,12 @@ def test_draining_admission_runs_every_dispatch_cold(tiny, monkeypatch):
 # -- (c) how far the loop runs ahead, with and without a queue ----------------
 
 
-def test_freed_pages_are_refilled_within_two_dispatches(tiny):
-    """A (41 tokens) ends in its 5th decode dispatch; C, waiting for pages,
-    is prefilled no later than 2 decode dispatches after A's last output
-    landed, and D goes on waiting."""
+def test_a_freed_slot_is_refilled_within_two_dispatches(tiny):
+    """A (41 tokens) ends in its 5th decode dispatch; C, waiting for a
+    slot, is prefilled no later than 2 decode dispatches after A's last
+    output landed, and D goes on waiting."""
     cfg, _ = tiny
-    eng = _mk_engine(tiny, num_pages=63)  # A 7 + B 31 of 62; C needs 31
+    eng = _mk_engine(tiny)
     events: list[str] = []
     enqueue, finalize = eng._enqueue_output, eng._finalize
 
@@ -178,7 +182,7 @@ def test_freed_pages_are_refilled_within_two_dispatches(tiny):
                 for i, (p, sp) in enumerate(zip(_prompts(cfg), budgets))]
         eng.start()
         assert _end_of(outs[0]) == "length"
-        assert _read(outs[2], 1), "C must be admitted once A's pages return"
+        assert _read(outs[2], 1), "C must be admitted once A's slot returns"
         seen = list(events)
         fin = seen.index("finalize")
         assert seen[:fin].count("step") >= 5
@@ -220,7 +224,7 @@ def test_run_ahead_is_bounded_by_the_device_only_while_a_request_waits(
         eng.stop()
 
     done.clear()
-    eng = _mk_engine(tiny, num_pages=256)  # room for all four
+    eng = _mk_engine(tiny, max_slots=4, num_pages=256)  # room for all four
     try:
         for i, p in enumerate(_prompts(cfg)):
             eng.submit(f"r{i}", p, LONG)
@@ -235,7 +239,7 @@ def test_run_ahead_is_bounded_by_the_device_only_while_a_request_waits(
 
 def test_with_nobody_waiting_the_throttle_is_pipeline_depth(tiny):
     cfg, _ = tiny
-    eng = _mk_engine(tiny, num_pages=256, pipeline_depth=3)
+    eng = _mk_engine(tiny, max_slots=4, num_pages=256, pipeline_depth=3)
     try:
         for i, p in enumerate(_prompts(cfg)):
             eng.submit(f"r{i}", p, LONG)
@@ -259,16 +263,17 @@ def test_streams_equal_an_engine_with_room_for_all(tiny):
     prompts = _prompts(cfg, seed=1)
     sp = _greedy(40)  # 6 pages each
 
-    def run(num_pages):
-        eng = _mk_engine(tiny, num_pages=num_pages)
+    def run(max_slots):
+        eng = _mk_engine(tiny, max_slots=max_slots)
         try:
             return eng.generate(prompts, sp, timeout=120.0), \
                 eng.profiler.counters()
         finally:
             eng.stop()
 
-    tight, c_tight = run(13)   # 12 pages: two at a time
-    roomy, c_roomy = run(64)
+    tight, c_tight = run(2)   # two at a time
+    roomy, c_roomy = run(4)
+    assert c_tight["slot_yields"] == c_roomy["slot_yields"] == 0
     assert c_tight["admission_deferrals"] > 0 == \
         c_roomy["admission_deferrals"]
     for a, b in zip(tight, roomy):
@@ -281,7 +286,7 @@ def test_streams_equal_an_engine_with_room_for_all(tiny):
 
 
 def test_abort_of_a_waiting_request_is_honoured_at_once(tiny):
-    """D waits behind C, which waits for pages: the scan never reaches D,
+    """D waits behind C, which waits for a slot: the scan never reaches D,
     and D's abort is still honoured on the next iteration (one decode
     dispatch); A and B decode on; then the head's."""
     cfg, _ = tiny
@@ -318,12 +323,31 @@ def test_abort_of_a_waiting_request_is_honoured_at_once(tiny):
 # -- (f) the spill tier's restore under pool pressure -------------------------
 
 
+def _pump(eng, queues, max_iters=2000):
+    """Drive the loop by hand until every stream has ended; the tokens of
+    each."""
+    toks = [[] for _ in queues]
+    ended = [False] * len(queues)
+    for _ in range(max_iters):
+        eng._loop_iter()
+        for i, q in enumerate(queues):
+            while not ended[i] and not q.empty():
+                item = q.get_nowait()
+                if item is STREAM_END:
+                    ended[i] = True
+                else:
+                    toks[i].extend(item["token_ids"])
+        if all(ended):
+            return toks
+    raise AssertionError("streams did not end")
+
+
 def test_restore_under_pressure_truncates_or_restores_and_never_drains(tiny):
-    """A prefix hit on spilled pages while a long request holds the pool:
-    the restore finds no pages, the hit truncates and the request waits;
-    when the holder ends the chain is restored and attached. No admission
-    step calls the blocking drain, and the tokens are those of an engine
-    that never spilled."""
+    """A prefix hit on spilled pages while a long request holds the pool
+    (16 of 18 pages, written or in flight): the restore finds no pages, the
+    hit truncates and the request waits; when the holder ends the chain is
+    restored and attached. No admission step calls the blocking drain, and
+    the tokens are those of an engine that never spilled."""
     cfg, _ = tiny
     [p] = _prompts(cfg, 1, length=32, seed=2)
     [h] = _prompts(cfg, 1, length=32, seed=3)
@@ -352,16 +376,20 @@ def test_restore_under_pressure_truncates_or_restores_and_never_drains(tiny):
 
         eng._collect_wave, eng._drain_emit_q = rec_collect, rec_drain
         try:
-            first = eng.generate([p], short, timeout=120.0)[0]
-            _wait(lambda: not eng._active.any(), what="quiescence")
-            time.sleep(0.2)
+            # by hand, no threads: a landing happens only where the loop
+            # asks for one, so the holder's run-ahead is what the loop's
+            # throttle lets it be and the pool's state is exact
+            [first] = _pump(eng, [eng.submit("first", p, short)])
             if spill:
                 n = len(eng.prefix_cache.spill_candidates())
                 assert n == 3 and eng._spill_pages(n, cold_only=False) == n
             qh = eng.submit("holder", h, holder)
+            while eng.allocator.free_count > num_pages - 1 - 16:
+                eng._loop_iter()   # the holder's pages, as it runs ahead
+            assert eng._active.any()
             qp = eng.submit("resume", p, short)
-            both = [_read(q, 10 ** 6) for q in (qh, qp)]
-            return first["token_ids"], both, drains_in_scan, eng
+            both = _pump(eng, [qh, qp])
+            return first, both, drains_in_scan, eng
         finally:
             eng.stop()
 
@@ -369,7 +397,8 @@ def test_restore_under_pressure_truncates_or_restores_and_never_drains(tiny):
     first, (held, resumed), drains, eng = run(19, True)
     ref_first, (ref_held, ref_resumed), _d, _e = run(128, False)
     assert drains == [], "admission put a barrier on the pipeline"
-    assert eng.profiler.counters()["admission_deferrals"] > 0
+    c = eng.profiler.counters()
+    assert c["admission_deferrals"] > 0 == c["slot_yields"]
     assert eng.kvledger.pages_restored >= 3
     assert first == ref_first
     assert held == ref_held and len(held) == 90

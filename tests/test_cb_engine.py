@@ -117,11 +117,24 @@ def test_budget_and_long_prompt_errors(tiny):
     cbe = _mk_engine(tiny)
     cbe.start()
     from polyrl_tpu.rollout.cb_engine import STREAM_END
-    # prompt longer than the largest bucket → error
-    out = cbe.submit("too-long", list(range(40)), SamplingParams(max_new_tokens=4))
+    # prompt longer than a slot holds → error
+    out = cbe.submit("too-long", list(range(1, 129)),
+                     SamplingParams(max_new_tokens=4))
     item = out.get(timeout=60)
     assert item["finish_reason"] == "error"
     assert out.get(timeout=10) is STREAM_END
+    # one longer than the largest bucket goes in chunks of that bucket (a
+    # row that gave up its pages comes back with such an input)
+    out1 = cbe.submit("over-bucket", list(range(1, 41)),
+                      SamplingParams(temperature=0.0, max_new_tokens=4))
+    n = 0
+    while True:
+        item = out1.get(timeout=120)
+        if item is STREAM_END:
+            break
+        assert item["finish_reason"] in ("", "length")
+        n += len(item["token_ids"])
+    assert n == 4 and cbe.chunk_dispatches == 1
     # budget clamped by max_seq_len
     out2 = cbe.submit("clamped", [1, 2], SamplingParams(temperature=0.0,
                                                         max_new_tokens=10_000))

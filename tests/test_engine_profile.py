@@ -122,7 +122,8 @@ def test_unattributed_residual_lands_in_other():
 PROFILER_INFO_KEYS = {
     "device_frac", "host_overhead_frac", "accounting_frac",
     "loop_attributed_frac", "decode_dispatches", "decode_dispatches_cold",
-    "admission_deferrals", "decode_steps_done", "fused_sample_steps", "device_busy_s", "device_busy_at_s", "loop_wall_s",
+    "admission_deferrals", "pages_grown", "slot_yields", "decode_steps_done",
+    "fused_sample_steps", "device_busy_s", "device_busy_at_s", "loop_wall_s",
     "loop_host_s", "programs_built"}
 
 

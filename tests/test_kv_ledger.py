@@ -94,8 +94,8 @@ def test_ledger_reconciles_exactly_under_churn(tiny):
     """attributed_frac == 1.0 EXACTLY at quiescence: every page the
     allocator or cache holds is attributed after completion churn
     (finalize + publish), salvage-abort churn, and a full cache flush."""
-    # salvage_partials=True, prefix cache on; room for the aborted
-    # request's 400 tokens (see _AbortAtFirstChunk)
+    # salvage_partials=True, prefix cache on; a pool that the aborted
+    # request's 400 tokens would fit (see _AbortAtFirstChunk)
     eng = _mk_engine(tiny, max_seq_len=512, num_pages=128)
     eng.start()
     try:
@@ -106,7 +106,9 @@ def test_ledger_reconciles_exactly_under_churn(tiny):
             assert len(toks) == 8
         # salvage churn: abort mid-generation (salvage_partials finalizes
         # the slot through the salvage path, publishing decoded pages)
-        _abort_mid_generation(eng, "kill-me", [7, 9, 11, 13] * 4)
+        # (13 tokens: a row holds the pages it has written, so its last
+        # page is part full whenever it stops, and salvage frees that one)
+        _abort_mid_generation(eng, "kill-me", [7, 9, 11, 13] * 3 + [5])
         _quiesce(eng)
 
         # mid-run quiescent reconcile: cache still resident

@@ -158,7 +158,9 @@ def _render_engine(loop: dict) -> list[str]:
             f"{steps} decode steps landed of {c.get('decode_dispatches', 0)} "
             f"dispatches ({c.get('decode_dispatches_cold', 0)} onto a dry "
             f"device, {c.get('admission_deferrals', 0)} admissions "
-            f"deferred; {c.get('fused_sample_steps', 0)} steps sampled "
+            f"deferred, {c.get('pages_grown', 0)} pages grown into, "
+            f"{c.get('slot_yields', 0)} rows yielded; "
+            f"{c.get('fused_sample_steps', 0)} steps sampled "
             f"inside the head): device busy {_fmt(c.get('device_busy_s'))} s "
             f"({_fmt(1e3 * c.get('device_busy_s', 0.0) / steps)} ms a step); "
             f"loop host {_fmt(c.get('loop_host_s'))} s of "
