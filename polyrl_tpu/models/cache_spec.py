@@ -7,23 +7,32 @@ three short convolutions) or ``mla`` (multi-head latent attention: one
 normed latent row and one shared rope key a token). An MLP is ``dense``
 (SwiGLU) or ``moe`` (routed experts, ``decoder._moe_mlp``). Every preset
 from before the hybrid family is the uniform pattern: ``gqa`` in every
-layer, with the same MLP in every layer.
+layer, with the same MLP in every layer. The DeepSeek-V3 family
+(``kv_lora_rank`` without a ``layer_group_size``) is ``mla`` in every
+layer behind leading dense layers.
 
 What a sequence keeps is either PAGED (so many values a token, in pages
 that the engine's allocator hands out: a K/V pair of ``[Hkv, N, page, D]``
 for ``gqa``, one latent pool ``[1, N, page, row]`` for ``mla``, ``row`` being ``rank +
 rope`` rounded up to whole lanes) or
-a SLOT (fixed-size arrays indexed by the engine's slot, for ``kda``). A
-state in a slot has no page boundary to snapshot at, so a model with one
-is STATEFUL: ``CBEngine`` turns off every feature that re-enters a
-sequence anywhere but at its last token (ARCHITECTURE.md, "Cache
-specification").
+a SLOT (fixed-size arrays indexed by the engine's slot, for ``kda``).
+
+``CBEngine`` asks two questions of a model's layers (ARCHITECTURE.md,
+"Cache specification"). Does a sequence keep anything outside pages
+(``is_stateful``)? A state in a slot has no page boundary to snapshot at,
+so the engine then turns off every feature that re-enters a sequence
+anywhere but at its last token. And which features that act on pages have
+a kernel for every mixer of the plan (``without_kernel``)? What acts on
+pages alone (prefix cache, a group's shared prompt, salvage, the ledger,
+page growth and yield) runs on any paged pool; the grouped two-phase
+decode kernel, speculation's multi-token verify and the spill tier's page
+copies are written for a K/V pair.
 
 Readers: ``decoder.make_paged_pools`` and ``CBEngine._make_pools`` (the
 arrays; the page ledger takes its bytes a page from the paged ones),
-``CBEngine`` (``is_stateful``: whether ``prefix_cache``, ``kvspill`` and
-speculation may run at all), ``models/hybrid.py`` (the layer loop);
-``benchmark/lib/costs_hybrid.py`` repeats the arithmetic on its own."""
+``CBEngine`` (the two questions), ``models/hybrid.py`` (the layer loop);
+``benchmark/lib/costs_hybrid.py`` and ``costs_latent.py`` repeat the
+arithmetic on their own."""
 
 from __future__ import annotations
 
@@ -74,9 +83,11 @@ STATE_DTYPE = jnp.float32
 def layer_plan(cfg) -> tuple[LayerPlan, ...]:
     """The model's layers in order. ``layer_group_size`` > 0 is the hybrid
     family: published layer ``i`` is ``mla`` where ``(i + 1) %
-    layer_group_size == 0`` and ``kda`` otherwise; ``first_k_dense_replace``
-    leading published layers keep the dense MLP. ``kept_layers`` names the
-    published layers that run here (a depth cut), all of them by default."""
+    layer_group_size == 0`` and ``kda`` otherwise. ``kv_lora_rank`` > 0
+    without it is latent attention in every layer; neither is ``gqa`` in
+    every layer. ``first_k_dense_replace`` leading published layers keep
+    the dense MLP. ``kept_layers`` names the published layers that run
+    here (a depth cut), all of them by default."""
     kept = cfg.kept_layers or tuple(range(cfg.num_layers))
     if len(kept) != cfg.num_layers:
         raise ValueError(f"kept_layers {kept} names {len(kept)} layers, "
@@ -86,7 +97,7 @@ def layer_plan(cfg) -> tuple[LayerPlan, ...]:
         if cfg.layer_group_size:
             mixer = "mla" if (i + 1) % cfg.layer_group_size == 0 else "kda"
         else:
-            mixer = "gqa"
+            mixer = "mla" if cfg.kv_lora_rank else "gqa"
         sparse = bool(cfg.num_experts) and i >= cfg.first_k_dense_replace
         plan.append(LayerPlan(mixer, "moe" if sparse else "dense", i))
     return tuple(plan)
@@ -94,7 +105,7 @@ def layer_plan(cfg) -> tuple[LayerPlan, ...]:
 
 def is_uniform(cfg) -> bool:
     """Every layer alike and ``gqa``: the stacked-scan decoder."""
-    return not cfg.layer_group_size and not (
+    return not cfg.layer_group_size and not cfg.kv_lora_rank and not (
         cfg.num_experts and cfg.first_k_dense_replace)
 
 
@@ -148,6 +159,22 @@ def is_stateful(cfg) -> bool:
     return any(isinstance(c, Slot) for c in cache_spec(cfg))
 
 
+# features of the engine that act on pages through a kernel (or a copy)
+# written for one kind of paged cache, and the mixers that have it
+FEATURE_KERNELS = {
+    "decode_group_share": ("gqa",),   # ops.paged_attention's grouped kernel
+    "spec_tokens": ("gqa",),          # the multi-token verify forward
+    "kv_spill": ("gqa",),             # rollout/kvspill.py copies a K/V pair
+}
+
+
+def without_kernel(cfg, feature: str) -> tuple[str, ...]:
+    """The mixers of this model's layers for which the engine's
+    ``feature`` has no kernel: empty where it may run."""
+    have = FEATURE_KERNELS[feature]
+    return tuple(sorted({p.mixer for p in layer_plan(cfg)} - set(have)))
+
+
 def paged_bytes_per_token(cfg, dtype=None) -> int:
     item = jnp.dtype(dtype or cfg.dtype).itemsize
     return sum(c.values_per_token() * item for c in cache_spec(cfg, dtype)
@@ -168,8 +195,8 @@ def make_pools(cfg, num_pages: int, page_size: int, slots: int = 0,
     with one ``[1, num_pages, page_size, width]`` latent pool for each
     ``mla`` layer in order, ``state`` a tuple with one ``(state [slots, H,
     Dk, Dv] float32, conv [slots, K-1, channels])`` pair for each ``kda``
-    layer in order. The engine hands ``slots = max_slots + 1``: the last
-    row is the sink that padding rows of an admission wave write to."""
+    layer in order (none for a model that is ``mla`` in every layer). The
+    engine hands ``slots = max_slots + 1``: the last row is the sink that padding rows of an admission wave write to."""
     dtype = dtype or cfg.dtype
     spec = cache_spec(cfg, dtype)
     if is_uniform(cfg):

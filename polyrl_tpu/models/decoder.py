@@ -44,13 +44,25 @@ from polyrl_tpu.parallel.mesh import DP, EP, FSDP, SP, TP
 
 @dataclasses.dataclass(frozen=True)
 class RopeScaling:
-    """llama3-style NTK-by-parts frequency scaling (frozen → ModelConfig stays
-    hashable for use as a jit static argument)."""
+    """Frequency scaling of the rope (frozen → ModelConfig stays hashable
+    for use as a jit static argument). ``rope_type`` ``llama3``: NTK by
+    parts between ``low_freq_factor`` and ``high_freq_factor``. ``yarn``
+    (DeepSeek-V3's reading, ``models/hybrid.py::rope_inv_freq``): each
+    frequency is divided by ``factor`` below the dimension at which the
+    original length makes ``beta_slow`` turns, kept above the one at which
+    it makes ``beta_fast``, blended linearly between; cos and sin are
+    scaled by ``mscale / mscale_all_dim``'s ratio and the logits by the
+    square of ``0.1 * mscale_all_dim * ln(factor) + 1``."""
 
     factor: float = 8.0
     low_freq_factor: float = 1.0
     high_freq_factor: float = 4.0
     original_max_position_embeddings: int = 8192
+    rope_type: str = "llama3"
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -98,7 +110,14 @@ class ModelConfig:
     layer_group_size: int = 0
     first_k_dense_replace: int = 0
     kept_layers: tuple | None = None
+    # multi-head latent attention: ``kv_lora_rank`` > 0 without a
+    # ``layer_group_size`` is MLA in every layer (DeepSeek-V3's decoder);
+    # ``q_lora_rank`` > 0 puts a normed latent between the input and the
+    # queries; ``mla_head_gate``: sigmoid(x Wgate)_h on each head's output
+    # (Ling's ``gated_attention_proj_granularity_type`` head_wise)
     kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    mla_head_gate: bool = False
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
@@ -223,19 +242,21 @@ PRESETS["deepseek-r1-distill-llama-8b"] = PRESETS["llama3-8b"]
 
 
 
-def cut_to_share(cfg: ModelConfig, kept_layers: tuple, chips: int
-                 ) -> ModelConfig:
+def cut_to_share(cfg: ModelConfig, kept_layers: tuple, chips: int,
+                 vocabulary_shares: int | None = None) -> ModelConfig:
     """One chip's view of a deployment in which ``chips`` chips share each
     layer and the layers not in ``kept_layers`` (published indices) lie on
     further chips as pipeline stages: this chip's experts (the first
     ``num_experts / chips``; the router keeps its width) and its slice of
-    the vocabulary (the first ``vocab_size / chips`` rows). Attention and
-    a shared expert are whole on every chip."""
+    the vocabulary (the first ``vocab_size / vocabulary_shares`` rows; as
+    many slices as chips unless told apart). Attention and a shared expert
+    are whole on every chip. ``first_k_dense_replace`` stays the published
+    count: ``cache_spec.layer_plan`` reads it against published indices."""
     held = cfg.num_experts // chips
     return dataclasses.replace(
         cfg, num_layers=len(kept_layers), kept_layers=tuple(kept_layers),
         experts_held=(0, held) if cfg.num_experts else None,
-        vocab_size=cfg.vocab_size // chips)
+        vocab_size=cfg.vocab_size // (vocabulary_shares or chips))
 
 
 # Ling-3.0-flash (HF config: inclusionAI/Ling-3.0-flash, model_type
@@ -252,7 +273,7 @@ PRESETS["ling-3.0-flash"] = ModelConfig(
     scoring_func="sigmoid", n_group=8, topk_group=4,
     routed_scaling_factor=2.5, moe_shared_expert_intermediate_size=768,
     layer_group_size=6, first_k_dense_replace=2, kv_lora_rank=512,
-    qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+    mla_head_gate=True, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
     short_conv_kernel_size=4, kda_lower_bound=-5.0,
 )
 # one chip of four that share each layer, the leading dense layer once and
@@ -269,8 +290,53 @@ PRESETS["hybrid-tiny"] = ModelConfig(
     scoring_func="sigmoid", n_group=4, topk_group=2,
     routed_scaling_factor=2.5, moe_shared_expert_intermediate_size=32,
     experts_held=(0, 4), layer_group_size=3, first_k_dense_replace=1,
-    kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    kv_lora_rank=32, mla_head_gate=True, qk_nope_head_dim=16,
+    qk_rope_head_dim=8, v_head_dim=16,
     short_conv_kernel_size=4, kda_lower_bound=-5.0,
+)
+
+# dots.vlm1.inst's language model (HF config: rednote-hilab/dots.vlm1.inst,
+# model_type dots_vlm): DeepSeek-V3's decoder key for key. Latent attention
+# in every layer at 128 heads with a query latent and YaRN, three leading
+# dense layers, then 256 routed experts of width 2048 behind the
+# ``noaux_tc`` router, one shared expert, an untied head. The
+# multi-token-prediction layer and the vision tower are not part of the
+# decoder. ``num_kv_heads`` and ``head_dim`` are unused: no layer keeps a
+# K/V pair.
+PRESETS["dots.vlm1"] = ModelConfig(
+    vocab_size=129280, hidden_size=7168, intermediate_size=18432,
+    num_layers=61, num_heads=128, num_kv_heads=128, rope_theta=10000.0,
+    rope_scaling=RopeScaling(
+        rope_type="yarn", factor=40.0, beta_fast=32.0, beta_slow=1.0,
+        mscale=1.0, mscale_all_dim=1.0,
+        original_max_position_embeddings=4096),
+    rms_norm_eps=1e-6, max_position_embeddings=163840,
+    num_experts=256, num_experts_per_tok=8, moe_intermediate_size=2048,
+    scoring_func="sigmoid", n_group=8, topk_group=4,
+    routed_scaling_factor=2.5, moe_shared_expert_intermediate_size=2048,
+    first_k_dense_replace=3, kv_lora_rank=512, q_lora_rank=1536,
+    qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+)
+# one chip of sixteen that share each layer (the vocabulary in eight
+# slices), the three dense layers once and four sparse layers
+# (benchmark/configs/dots.vlm1.json)
+PRESETS["dots.vlm1-share16"] = cut_to_share(
+    PRESETS["dots.vlm1"], (0, 3, 4, 5, 6), 16, vocabulary_shares=8)
+# test-size model of the same family: 3 MLA layers, the first dense, a
+# query latent, YaRN, 16 experts in 4 groups of which 4 are held
+PRESETS["mla-moe-tiny"] = ModelConfig(
+    vocab_size=512, hidden_size=64, intermediate_size=128, num_layers=3,
+    num_heads=4, num_kv_heads=4, rope_theta=10000.0,
+    rope_scaling=RopeScaling(
+        rope_type="yarn", factor=40.0, beta_fast=32.0, beta_slow=1.0,
+        mscale=1.0, mscale_all_dim=1.0,
+        original_max_position_embeddings=64),
+    rms_norm_eps=1e-6, max_position_embeddings=2560,
+    num_experts=16, num_experts_per_tok=4, moe_intermediate_size=32,
+    scoring_func="sigmoid", n_group=4, topk_group=2,
+    routed_scaling_factor=2.5, moe_shared_expert_intermediate_size=32,
+    experts_held=(0, 4), first_k_dense_replace=1, kv_lora_rank=32,
+    q_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
 )
 
 
@@ -388,6 +454,10 @@ def _rope_freqs(cfg: ModelConfig) -> np.ndarray:
     hd = cfg.head_dim_
     freqs = 1.0 / (cfg.rope_theta ** (np.arange(0, hd, 2, dtype=np.float64) / hd))
     if cfg.rope_scaling:
+        if cfg.rope_scaling.rope_type != "llama3":
+            raise NotImplementedError(
+                f"rope scaling {cfg.rope_scaling.rope_type!r} on a GQA "
+                "layer (models/hybrid.py has YaRN for latent attention)")
         # llama3 NTK-by-parts frequency scaling (HF rope_scaling type="llama3")
         s = cfg.rope_scaling
         factor = s.factor

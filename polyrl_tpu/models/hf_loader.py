@@ -78,24 +78,41 @@ def _shard_files(ckpt_dir: str) -> list[str]:
     raise FileNotFoundError(f"no safetensors checkpoint under {ckpt_dir}")
 
 
+def rope_scaling_from_hf(rs: dict | None) -> decoder.RopeScaling | None:
+    """A config.json's ``rope_scaling`` entry as ``decoder.RopeScaling``:
+    ``llama3`` and ``yarn`` (DeepSeek-V3's keys; a key the entry leaves
+    out takes HF's default)."""
+    rs = rs or {}
+    rs_type = rs.get("rope_type", rs.get("type"))
+    if rs_type == "llama3":
+        return decoder.RopeScaling(
+            factor=rs["factor"], low_freq_factor=rs["low_freq_factor"],
+            high_freq_factor=rs["high_freq_factor"],
+            original_max_position_embeddings=rs["original_max_position_embeddings"])
+    if rs_type == "yarn":
+        return decoder.RopeScaling(
+            rope_type="yarn", factor=float(rs["factor"]),
+            beta_fast=float(rs.get("beta_fast", 32)),
+            beta_slow=float(rs.get("beta_slow", 1)),
+            mscale=float(rs.get("mscale", 1)),
+            mscale_all_dim=float(rs.get("mscale_all_dim", 0)),
+            original_max_position_embeddings=int(
+                rs["original_max_position_embeddings"]))
+    if rs_type not in (None, "default"):
+        # silently running linear/dynamic checkpoints with UNSCALED
+        # frequencies would be quietly wrong at long context
+        raise NotImplementedError(
+            f"rope_scaling type {rs_type!r} is not supported (llama3 and "
+            "yarn only)")
+    return None
+
+
 def config_from_hf(ckpt_dir: str, dtype=jnp.bfloat16) -> decoder.ModelConfig:
     """Build a ModelConfig from the checkpoint's config.json (llama/qwen2/
     qwen3 architectures)."""
     with open(os.path.join(ckpt_dir, "config.json")) as f:
         hf = json.load(f)
-    rope_scaling = None
-    rs = hf.get("rope_scaling") or {}
-    rs_type = rs.get("rope_type", rs.get("type"))
-    if rs_type == "llama3":
-        rope_scaling = decoder.RopeScaling(
-            factor=rs["factor"], low_freq_factor=rs["low_freq_factor"],
-            high_freq_factor=rs["high_freq_factor"],
-            original_max_position_embeddings=rs["original_max_position_embeddings"])
-    elif rs_type not in (None, "default"):
-        # silently running yarn/linear/dynamic checkpoints with UNSCALED
-        # frequencies would be quietly wrong at long context
-        raise NotImplementedError(
-            f"rope_scaling type {rs_type!r} is not supported (llama3 only)")
+    rope_scaling = rope_scaling_from_hf(hf.get("rope_scaling"))
     moe: dict = {}
     if hf.get("num_experts"):  # Qwen3-MoE family
         if hf.get("mlp_only_layers") or (hf.get("decoder_sparse_step", 1) != 1):

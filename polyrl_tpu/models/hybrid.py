@@ -1,7 +1,11 @@
 """Decoders whose layers differ in kind (``cache_spec.layer_plan``): the
 Ling-3.0 / Ring hybrid family (``bailing_hybrid``) of Kimi Delta Attention
 (KDA) layers, multi-head latent attention (MLA) layers and a routed MLP
-with a sigmoid router behind leading dense layers.
+with a sigmoid router behind leading dense layers; and DeepSeek-V3's
+decoder (dots.vlm1 / dots.llm1 share it key for key): MLA in every layer,
+the same routed MLP behind leading dense layers. One MLA block serves
+both; what differs is an option of the configuration (``q_lora_rank``: a
+normed query latent; ``mla_head_gate``; ``rope_scaling``: YaRN).
 
 One block a kind, parameters stacked per kind::
 
@@ -10,9 +14,12 @@ One block a kind, parameters stacked per kind::
       "kda":   {wq wk wv wf wg [Lk, d, H*D], conv_q conv_k conv_v [Lk, K, H*D],
                 a_log [Lk, H], f_bias [Lk, H*D], wb [Lk, d, H],
                 o_norm [Lk, D], wo [Lk, H*D, d]}
-      "mla":   {wq [Lm, d, H*(nope+rope)], wkv_a [Lm, d, rank+rope],
+      "mla":   {wq [Lm, d, H*(nope+rope)]   (or, with a query latent:
+                wq_a [Lm, d, qrank], q_norm [Lm, qrank],
+                wq_b [Lm, qrank, H*(nope+rope)]),
+                wkv_a [Lm, d, rank+rope],
                 kv_norm [Lm, rank], wkv_b [Lm, rank, H*(nope+v)],
-                wgate [Lm, d, H], wo [Lm, H*v, d]}
+                wgate [Lm, d, H] (with ``mla_head_gate``), wo [Lm, H*v, d]}
       "dense": {w_gate w_up [Ld, d, f], w_down [Ld, f, d]}
       "moe":   {router [Ls, d, E_all], router_bias [Ls, E_all] float32,
                 we_gate we_up [Ls, E_held, d, fe], we_down [Ls, E_held, fe, d],
@@ -33,12 +40,16 @@ in float32, zero at position 0::
     S' = diag(exp(g)) S ;  S = S' + beta k (v - S'^T k)^T ;  o = S^T q / sqrt(D)
     out = (rms_head(o) * sigmoid(x Wg)) Wo
 
-MLA without a query latent: the cache holds ``[rms(c) | rope(kr)]``, one
-row of ``rank + rope`` a token; prefill expands it through ``wkv_b``,
-decode folds ``wkv_b``'s key half into the query and applies its value
-half after the sum (the absorbed form)."""
+MLA: the cache holds ``[rms(c) | rope(kr)]``, one row of ``rank + rope`` a
+token; prefill expands it through ``wkv_b`` a block of keys at a time
+(``mla_expanded``), decode folds ``wkv_b``'s key half into the query and
+applies its value half after the sum (the absorbed form). The logits'
+scale is ``(nope + rope) ** -0.5``, times YaRN's ``m ** 2`` where the
+configuration scales its rope (``mla_scale``)."""
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -132,12 +143,18 @@ def init_params(rng: jax.Array, cfg) -> dict:
         m = n["mla"]
         r, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
         rope, vd = cfg.qk_rope_head_dim, cfg.v_head_dim
+        qr = cfg.q_lora_rank
+        query = ({"wq_a": norm(m, d, qr), "q_norm": ones(m, qr),
+                  "wq_b": norm(m, qr, h * (nope + rope))} if qr
+                 else {"wq": norm(m, d, h * (nope + rope))})
         layers["mla"] = {
-            "wq": norm(m, d, h * (nope + rope)),
+            **query,
             "wkv_a": norm(m, d, r + rope), "kv_norm": ones(m, r),
             "wkv_b": norm(m, r, h * (nope + vd)),
-            "wgate": norm(m, d, h), "wo": norm(m, h * vd, d),
+            "wo": norm(m, h * vd, d),
         }
+        if cfg.mla_head_gate:
+            layers["mla"]["wgate"] = norm(m, d, h)
     if n["dense"]:
         f = cfg.intermediate_size
         layers["dense"] = {"w_gate": norm(n["dense"], d, f),
@@ -207,14 +224,65 @@ def _l2norm(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
 
 
-def rope_interleaved(x, positions, theta: float):
+def _yarn_mscale(factor: float, m: float) -> float:
+    return 1.0 if factor <= 1.0 else 0.1 * m * math.log(factor) + 1.0
+
+
+def rope_inv_freq(cfg) -> np.ndarray:
+    """The ``qk_rope_head_dim / 2`` frequencies of a latent layer's rope,
+    float64: ``theta ** (-2i / R)``, under YaRN (``rope_scaling``,
+    DeepSeek-V3's reading) divided by ``factor`` from the dimension up at
+    which ``original_max_position_embeddings`` positions make ``beta_slow``
+    turns (rounded up), kept below the one at which they make
+    ``beta_fast`` (rounded down), blended linearly between."""
+    r = cfg.qk_rope_head_dim
+    inv = 1.0 / (cfg.rope_theta ** (np.arange(0, r, 2, dtype=np.float64) / r))
+    s = cfg.rope_scaling
+    if s is None:
+        return inv
+    if s.rope_type != "yarn":
+        raise NotImplementedError(
+            f"rope scaling {s.rope_type!r} on a latent attention layer")
+
+    def dim_of(turns: float) -> float:
+        return (r * math.log(s.original_max_position_embeddings
+                             / (turns * 2 * math.pi))
+                / (2 * math.log(cfg.rope_theta)))
+
+    low = max(math.floor(dim_of(s.beta_fast)), 0)
+    high = min(math.ceil(dim_of(s.beta_slow)), r - 1)
+    ramp = np.clip((np.arange(r // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return inv / s.factor * ramp + inv * (1 - ramp)
+
+
+def rope_amplitude(cfg) -> float:
+    """What YaRN multiplies cos and sin by: 1 without it, and 1 where
+    ``mscale`` equals ``mscale_all_dim``."""
+    s = cfg.rope_scaling
+    if s is None or s.rope_type != "yarn":
+        return 1.0
+    return (_yarn_mscale(s.factor, s.mscale)
+            / _yarn_mscale(s.factor, s.mscale_all_dim))
+
+
+def mla_scale(cfg) -> float:
+    """The logits' scale: ``(nope + rope) ** -0.5``, times the square of
+    YaRN's ``0.1 * mscale_all_dim * ln(factor) + 1`` where it is set."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    s = cfg.rope_scaling
+    if s is not None and s.rope_type == "yarn" and s.mscale_all_dim:
+        scale *= _yarn_mscale(s.factor, s.mscale_all_dim) ** 2
+    return scale
+
+
+def rope_interleaved(x, positions, inv_freq, amplitude: float = 1.0):
     """``x`` [..., T, H, R] float32, ``positions`` [..., T]: pairs
-    ``(x[2i], x[2i+1])`` turned by ``pos * theta ** (-2i / R)``."""
+    ``(x[2i], x[2i+1])`` turned by ``pos * inv_freq[i]``."""
     r = x.shape[-1]
-    inv = 1.0 / (theta ** (np.arange(0, r, 2, dtype=np.float64) / r))
     ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(
-        inv, jnp.float32)
-    cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
+        inv_freq, jnp.float32)
+    cos = jnp.cos(ang)[..., None, :] * amplitude
+    sin = jnp.sin(ang)[..., None, :] * amplitude
     pairs = x.reshape(*x.shape[:-1], r // 2, 2)
     a, b = pairs[..., 0], pairs[..., 1]
     return jnp.stack([a * cos - b * sin, b * cos + a * sin],
@@ -360,18 +428,25 @@ def _kda_sequence(cfg, lp, h_in, valid, state, conv):
 
 def _mla_qkv(cfg, lp, h_in, positions):
     """``h_in`` [..., T, d] -> (q_nope [..., T, H, nope], q_rope [..., T,
-    H, rope] after rope, latent rows [..., T, row] in the model's dtype:
+    H, rope] after rope, the queries through their normed latent where the
+    configuration has one, latent rows [..., T, row] in the model's dtype:
     ``rms(c)`` beside ``rope(kr)``, zeros up to ``cache_spec.latent_row``)."""
     hh = cfg.num_heads
     nope, rope, r = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
     lead = h_in.shape[:-1]
-    q = mm(h_in, lp["wq"]).reshape(*lead, hh, nope + rope)
+    if cfg.q_lora_rank:
+        cq = _rms(mm(h_in, lp["wq_a"]), lp["q_norm"], cfg.rms_norm_eps)
+        q = mm(cq, lp["wq_b"])
+    else:
+        q = mm(h_in, lp["wq"])
+    q = q.reshape(*lead, hh, nope + rope)
     kv = mm(h_in, lp["wkv_a"])
     c = _rms(kv[..., :r], lp["kv_norm"], cfg.rms_norm_eps)
+    inv, amp = rope_inv_freq(cfg), rope_amplitude(cfg)
     kr = rope_interleaved(kv[..., None, r:].astype(jnp.float32), positions,
-                          cfg.rope_theta)[..., 0, :]
+                          inv, amp)[..., 0, :]
     q_rope = rope_interleaved(q[..., nope:].astype(jnp.float32), positions,
-                              cfg.rope_theta)
+                              inv, amp)
     pad = cache_spec.latent_row(cfg) - r - rope
     latent = jnp.concatenate(
         [c, kr.astype(c.dtype), jnp.zeros((*lead, pad), c.dtype)], axis=-1)
@@ -379,51 +454,89 @@ def _mla_qkv(cfg, lp, h_in, positions):
 
 
 def _mla_out(cfg, lp, h_in, o):
-    """Head-wise gate, then ``Wo``; ``o`` [..., H, v]."""
-    gate = jax.nn.sigmoid(mm(h_in, lp["wgate"]).astype(jnp.float32))
-    o = (o.astype(jnp.float32) * gate[..., None]).astype(h_in.dtype)
-    return mm(o.reshape(*h_in.shape[:-1], -1), lp["wo"])
+    """The head-wise gate where the configuration has one, then ``Wo``;
+    ``o`` [..., H, v]."""
+    if cfg.mla_head_gate:
+        gate = jax.nn.sigmoid(mm(h_in, lp["wgate"]).astype(jnp.float32))
+        o = o.astype(jnp.float32) * gate[..., None]
+    return mm(o.astype(h_in.dtype).reshape(*h_in.shape[:-1], -1), lp["wo"])
 
 
-_Q_BLOCK = 128
+# float32 bytes the scores of one block of keys may take against all the
+# queries of a call, and the fewest keys a block holds
+_SCORE_BYTES = 128 << 20
+_MIN_KEY_BLOCK = 128
 
 
-def mla_expanded(cfg, lp, q_nope, q_rope, latents, key_ok, q_at):
+def key_block(cfg, b: int, t: int) -> int:
+    """Keys a block of ``mla_expanded`` holds for ``b`` rows of ``t``
+    queries: what keeps the [B, H, T, block] float32 scores within
+    ``_SCORE_BYTES``, in whole multiples of ``_MIN_KEY_BLOCK`` (at 128
+    heads and a 512-token chunk: 512 keys; at 32 heads: 2048)."""
+    fit = _SCORE_BYTES // (4 * b * cfg.num_heads * t)
+    return max(_MIN_KEY_BLOCK, fit // _MIN_KEY_BLOCK * _MIN_KEY_BLOCK)
+
+
+def mla_expanded(cfg, lp, q_nope, q_rope, latents, key_ok, q_at,
+                 block: int | None = None):
     """The expanded form for a batch: queries [B, T, H, ...] against the
     latent rows ``latents`` [B, Tk, rank + rope]; ``key_ok`` [B, Tk] marks
     rows that hold a token, ``q_at`` [B, T] each query's place among the
-    keys (it sees keys at or before it). Returns o [B, T, H, v]."""
-    hh, r = cfg.num_heads, cfg.kv_lora_rank
+    keys (it sees keys at or before it). Returns o [B, T, H, v] float32.
+
+    Blocked over the keys (``block`` of them a step, ``key_block`` by
+    default) with a running softmax: a block's rows are expanded through
+    ``wkv_b`` when its turn comes and dropped after, so neither a whole
+    prefix's K and V for all heads ([Tk, H, nope + v]: 1.07 GB at 16k keys
+    and 128 heads) nor its scores ever stand at once, and a block no
+    query can see (a bucket's padding past the prefix) is skipped. The
+    absorbed form against the prefix would expand nothing, but multiplies
+    every key by ``rank + rope`` and ``rank`` columns a head where this
+    multiplies by ``nope + rope`` and ``v`` and expands once: 2.3 against
+    1.2 TFLOP for a 512-token chunk over 16k keys at 128 heads."""
+    hh, r, rope = cfg.num_heads, cfg.kv_lora_rank, cfg.qk_rope_head_dim
     nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
     b, t = q_nope.shape[:2]
     tk = latents.shape[1]
-    kv = mm(latents[..., :r], lp["wkv_b"]).reshape(b, tk, hh, nope + vd)
-    k_nope, v = kv[..., :nope], kv[..., nope:]
-    kr = latents[..., r:r + cfg.qk_rope_head_dim]
-    scale = (nope + cfg.qk_rope_head_dim) ** -0.5
-    kpos = jnp.arange(tk)
-    pad = -t % _Q_BLOCK
-    qn, qr, at = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-                  for a in (q_nope, q_rope, q_at))
-    nb = (t + pad) // _Q_BLOCK
+    kb = min(block or key_block(cfg, b, t), tk)
+    pad = -tk % kb
+    if pad:
+        latents = jnp.pad(latents, ((0, 0), (0, pad), (0, 0)))
+        key_ok = jnp.pad(key_ok, ((0, 0), (0, pad)))
+    scale = mla_scale(cfg)
+    last = jnp.max(q_at)          # the furthest key any query sees
 
-    def block(xs):
-        qn, qr, at = xs                            # [B, Qb, ...]
-        s = (jnp.einsum("bqhd,bkhd->bhqk", qn, k_nope,
+    def attend(carry, i):
+        m, l, acc = carry
+        rows = jax.lax.dynamic_slice_in_dim(latents, i * kb, kb, 1)
+        ok = jax.lax.dynamic_slice_in_dim(key_ok, i * kb, kb, 1)
+        kv = mm(rows[..., :r], lp["wkv_b"]).reshape(b, kb, hh, nope + vd)
+        v = kv[..., nope:]
+        s = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, kv[..., :nope],
                         preferred_element_type=jnp.float32)
-             + jnp.einsum("bqhd,bkd->bhqk", qr, kr,
+             + jnp.einsum("bqhd,bkd->bhqk", q_rope, rows[..., r:r + rope],
                           preferred_element_type=jnp.float32)) * scale
-        ok = key_ok[:, None, :] & (kpos[None, None, :] <= at[:, :, None])
-        s = jnp.where(ok[:, None], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
-                          preferred_element_type=jnp.float32)
+        kpos = i * kb + jnp.arange(kb)
+        seen = (ok[:, None, :]
+                & (kpos[None, None, :] <= q_at[:, :, None]))[:, None]
+        m_new = jnp.maximum(m, jnp.max(jnp.where(seen, s, -1e30), axis=-1,
+                                       keepdims=True))
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        pv = jnp.einsum("bhqk,bkhd->bhqd", p.astype(v.dtype), v,
+                        preferred_element_type=jnp.float32)
+        return (m_new, alpha * l + jnp.sum(p, axis=-1, keepdims=True),
+                alpha * acc + pv)
 
-    def blocks(a):
-        return a.reshape(b, nb, _Q_BLOCK, *a.shape[2:]).swapaxes(0, 1)
+    def step(carry, i):
+        return jax.lax.cond(i * kb <= last, attend, lambda c, _i: c,
+                            carry, i), None
 
-    o = jax.lax.map(block, (blocks(qn), blocks(qr), blocks(at)))
-    return o.swapaxes(0, 1).reshape(b, t + pad, hh, vd)[:, :t]
+    init = (jnp.full((b, hh, t, 1), -1e30, jnp.float32),
+            jnp.zeros((b, hh, t, 1), jnp.float32),
+            jnp.zeros((b, hh, t, vd), jnp.float32))
+    (_m, l, acc), _ = jax.lax.scan(step, init, jnp.arange((tk + pad) // kb))
+    return (acc / jnp.maximum(l, 1e-30)).swapaxes(1, 2)
 
 
 def mla_absorb(cfg, lp, q_nope, q_rope):
@@ -633,12 +746,13 @@ def _set_rows(whole, rows):
 
 def load_width(cfg) -> int:
     """Entries of the load a decode step counts: a routed model's three
-    (``decoder._moe_mlp``), and for a model of several kinds of layer two
-    more: every (row, choice) of live rows whether or not its expert is
-    held here, and live rows times KDA layers."""
+    (``decoder._moe_mlp``), and for a model of several kinds of layer
+    three more: every (row, choice) of live rows whether or not its expert
+    is held here, live rows times KDA layers, and the latent rows the
+    live rows attend over, summed over the MLA layers."""
     if cache_spec.is_uniform(cfg):
         return 3 if cfg.num_experts else 0
-    return 5
+    return 6
 
 
 def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
@@ -653,16 +767,18 @@ def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
     paged, state = list(pools[0]), list(pools[1])
     s = tokens.shape[0]
     ps = paged[0].shape[2]
+    scale = mla_scale(cfg)
     live = jnp.ones((s,), bool) if active is None else active
     write_page = jnp.where(live, page_table[jnp.arange(s), seq_lens // ps], 0)
     write_off = jnp.where(live, seq_lens % ps, 0)
     attn_lens = jnp.where(live, seq_lens + 1, 0)
     n_live = jnp.sum(live.astype(jnp.int32))
+    rows_read = jnp.sum(attn_lens)
     hh, dk, dv = cache_spec.kda_dims(cfg)
     r = cfg.kv_lora_rank
 
     x = params["embed"][tokens]
-    load = jnp.zeros((5,), jnp.int32)
+    load = jnp.zeros((load_width(cfg),), jnp.int32)
     for l, p in enumerate(plan):
         i = kind_index(cfg)[l][0]
         mixer_lp, mlp_lp = _layer_params(cfg, layers, l)
@@ -696,9 +812,9 @@ def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
             with jax.named_scope("mla_core"):
                 paged[i] = _scatter_token_kv(
                     paged[i], write_page, write_off, lat)
-                scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
                 o_lat = latent_paged_attention(
                     q_lat, paged[i], page_table, attn_lens, r, scale)
+            load = load.at[5].add(rows_read)
             with jax.named_scope("mla_proj"):
                 out = _mla_out(cfg, mixer_lp, h_in,
                                mla_unabsorb(cfg, mixer_lp, o_lat))

@@ -266,12 +266,13 @@ class CBEngine:
                     "paged attention shard on the head dim")
             params = self._shard_params_for_mesh(params)
         self.params = params
-        # a model that keeps a recurrent state in its slot (decided from
-        # its layers, models/cache_spec.py, and by no option) has no
-        # snapshot to re-enter a sequence from: no prefix cache (so no hit,
-        # no publish, no spill, no salvage publish; a GRPO group's siblings
-        # and a resumed partial prefill from token 0), no shared-prefix
-        # decode groups, and no prompt-lookup speculation
+        # two questions of the model's layers (models/cache_spec.py), and
+        # no option. Does a sequence keep anything outside pages? A model
+        # with a recurrent state in its slot has no snapshot to re-enter a
+        # sequence from: no prefix cache (so no hit, no publish, no spill,
+        # no salvage publish; a GRPO group's siblings and a resumed
+        # partial prefill from token 0), no shared-prefix decode groups,
+        # and no prompt-lookup speculation
         self.stateful = cache_spec.is_stateful(cfg)
         if self.stateful:
             if int(o.spec_tokens) > 0:
@@ -284,6 +285,26 @@ class CBEngine:
                     "a model with a recurrent state on a mesh of several "
                     "chips")
             enable_prefix_cache = False
+        # and which features that act on pages have a kernel for every
+        # mixer of the plan? What needs none (prefix cache, a group's
+        # shared prompt, salvage, the ledger, growth and yield) runs on
+        # any paged pool; speculation is refused, the grouped decode
+        # kernel and the spill tier are off, with the mixer named
+        self._no_kernel = {f: cache_spec.without_kernel(cfg, f)
+                           for f in cache_spec.FEATURE_KERNELS}
+        if not self.stateful:
+            if int(o.spec_tokens) > 0 and self._no_kernel["spec_tokens"]:
+                raise ValueError(
+                    "spec_tokens > 0 verifies several tokens a row in one "
+                    "forward, which is written for gqa layers; this model "
+                    f"has {'/'.join(self._no_kernel['spec_tokens'])} layers "
+                    "(models/cache_spec.py::without_kernel)")
+            for feature, on in (("decode_group_share", o.decode_group_share),
+                                ("kv_spill", o.kv_spill)):
+                if on and self._no_kernel[feature]:
+                    log.info("%s is off: no kernel for %s layers "
+                             "(models/cache_spec.py::without_kernel)",
+                             feature, "/".join(self._no_kernel[feature]))
         self.max_slots = max_slots
         self.page_size = page_size
         self.max_seq_len = o.max_seq_len
@@ -340,7 +361,8 @@ class CBEngine:
         # pool absent none can).
         self.kvspill = (HostSpillPool(
             capacity_bytes=int(float(o.kv_spill_host_gb) * 1e9))
-            if (o.kv_spill and o.kv_ledger and enable_prefix_cache)
+            if (o.kv_spill and o.kv_ledger and enable_prefix_cache
+                and not self._no_kernel["kv_spill"])
             else None)
         self.kv_spill_high_watermark = float(o.kv_spill_high_watermark)
         self.kv_spill_low_watermark = float(o.kv_spill_low_watermark)
@@ -458,8 +480,9 @@ class CBEngine:
         # prompt KV per group instead of one per sibling); singleton
         # leftovers and decode_group_share=False degrade to the ungrouped
         # kernel (bitwise the pre-PR decode path). Loop-thread only.
-        self.decode_group_share = (bool(o.decode_group_share)
-                                   and not self.stateful)
+        self.decode_group_share = (
+            bool(o.decode_group_share) and not self.stateful
+            and not self._no_kernel["decode_group_share"])
         self._decode_groups: dict[str, dict] = {}
         self._slot_decode_gid: dict[int, str] = {}
         self._grouped_attn = None  # built lazily (TP wrapper under a mesh)
@@ -574,7 +597,8 @@ class CBEngine:
                          if hasattr(x, "nbytes"))
         if self.kvledger is not None and pool_b:
             # bytes a page: the paged arrays', not a recurrent state's
-            paged = pools if not self.stateful else pools[0]
+            # (a model of several kinds of layer: (paged, state rows))
+            paged = pools if cache_spec.is_uniform(self.cfg) else pools[0]
             self.kvledger.page_bytes = sum(
                 int(x.nbytes) for x in jax.tree_util.tree_leaves(paged)
             ) // max(1, self.num_pages)
@@ -595,8 +619,10 @@ class CBEngine:
         if more:
             # a model of several kinds of layer (hybrid.load_width): every
             # choice of a live row, held here or not (``moe_routed`` counts
-            # the held ones), and live rows times KDA layers
-            info["moe_choices"], info["kda_state_rows"] = more
+            # the held ones), live rows times KDA layers, and the latent
+            # rows attended, summed over the MLA layers
+            (info["moe_choices"], info["kda_state_rows"],
+             info["mla_rows_read"]) = more
         return info
 
     def recurrent_state(self, rid: str):

@@ -35,6 +35,14 @@ def hybrid():
     return cfg, decoder.init_params(jax.random.PRNGKey(0), cfg)
 
 
+@pytest.fixture(scope="module")
+def latent():
+    """Latent attention in every layer: all pages, no K/V pair, no state
+    (``tests/test_latent_moe.py``)."""
+    cfg = decoder.get_config("mla-moe-tiny", dtype=jnp.float32)
+    return cfg, decoder.init_params(jax.random.PRNGKey(0), cfg)
+
+
 def _engine(model, **kw):
     cfg, params = model
     opts = dict(max_slots=4, page_size=PS, max_seq_len=128,
@@ -264,13 +272,18 @@ def _run(model, num_pages, budget=60, n=4, length=12, **kw):
         eng.stop()
 
 
-@pytest.mark.parametrize("kind", ["cached", "recomputed", "spec"])
-def test_a_pool_too_small_makes_the_youngest_yield_and_come_back(dense, kind):
+@pytest.mark.parametrize("kind", ["cached", "recomputed", "spec",
+                                  "latent-cached", "latent-recomputed"])
+def test_a_pool_too_small_makes_the_youngest_yield_and_come_back(
+        request, dense, kind):
+    """The dense model, and (``latent-``) the model whose cache is a
+    latent pool in every layer: a page is a page whatever it holds."""
     opts = {"cached": {}, "recomputed": {"enable_prefix_cache": False},
-            "spec": {"spec_tokens": 2}}[kind]
+            "spec": {"spec_tokens": 2}}[kind.removeprefix("latent-")]
+    model = request.getfixturevalue("latent") if "latent" in kind else dense
     # 4 rows x (12 + 60 tokens) write 36 pages; 19 are there
-    tight, c_tight, eng = _run(dense, 20, **opts)
-    roomy, c_roomy, _ = _run(dense, 64, **opts)
+    tight, c_tight, eng = _run(model, 20, **opts)
+    roomy, c_roomy, _ = _run(model, 64, **opts)
     assert c_tight["slot_yields"] > 0 == c_roomy["slot_yields"]
     assert c_roomy["pages_grown"] > 0
     for (toks, lps, fins, ends), (r_toks, r_lps, *_r) in zip(tight, roomy):
@@ -280,7 +293,7 @@ def test_a_pool_too_small_makes_the_youngest_yield_and_come_back(dense, kind):
         assert fins == [False] * 59 + [True] and ends == 1
         assert toks == r_toks
         np.testing.assert_allclose(lps, r_lps, atol=5e-4)
-    if kind == "cached":
+    if kind.endswith("cached"):
         # the row's full pages were published as it left and its re-entry
         # attached to them: it did not prefill its whole input again
         assert eng.salvage_published_pages > 0
