@@ -554,7 +554,9 @@ def mla_absorb(cfg, lp, q_nope, q_rope):
 
 def mla_unabsorb(cfg, lp, o_latent):
     """``wkv_b``'s value half applied to the attention's output over the
-    latent rows ``o_latent`` [S, H, rank] -> [S, H, v]."""
+    latent rows ``o_latent`` [S, H, rank] -> [S, H, v]. The TPU kernel
+    hands ``o_latent`` over in the pool's dtype, so the cast below is the
+    oracle's alone."""
     hh, r, nope = cfg.num_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim
     wv = lp["wkv_b"].reshape(r, hh, -1)[..., nope:]
     return jnp.einsum("shr,rhd->shd", o_latent.astype(lp["wkv_b"].dtype), wv,

@@ -264,7 +264,10 @@ class PrefixCache:
         abort/flush-while-spilled frees both tiers)."""
         freed: list[int] = []
         spilled: list[_Entry] = []
-        for e in self._map.values():
+        # a snapshot: `CBEngine.stop` joins its loop thread for 10 s and
+        # then flushes whether or not it has ended; a publish from that
+        # thread meanwhile made the iteration raise and the process exit 1
+        for e in list(self._map.values()):
             if e.spilled:
                 spilled.append(e)
             elif e.refcount == 0:

@@ -348,24 +348,39 @@ def test_qwen3_30b_a3b_preset_equals_the_benchmark_file():
 # -- the Ling-3.0 hybrid at its published widths ------------------------------
 
 
-def test_latent_attention_kernel_compiles_for_v5e(one_chip):
-    """The absorbed MLA decode kernel at the cell's shapes: 129 rows, 32
-    heads, latent rows of 576 values in 640 lanes, 15,617 pages of 64, a
-    table 192 pages wide. (A pool 576 wide is refused: a DMA slices a
-    pool at whole 128-lane tiles.)"""
-    from polyrl_tpu.ops.mla_attention import latent_paged_attention_pallas
+# (rows, heads, pages in the pool, table width): Ling's cell (32 heads, the
+# DMAs bound the kernel: a MiB of pages in one piece, three buffers) and
+# dots.vlm1's (128 heads, on the ridge: 2048 keys in two sub-blocks)
+@pytest.mark.parametrize("s,h,n_pages,width", [
+    (129, 32, 15617, 192),
+    (65, 128, 10241, 320),
+])
+def test_latent_attention_kernel_compiles_for_v5e(one_chip, s, h, n_pages,
+                                                  width):
+    """The absorbed MLA decode kernel at the two cells' shapes: latent
+    rows of 576 values in 640 lanes, pages of 64. (A pool 576 wide is
+    refused: a DMA slices a pool at whole 128-lane tiles.) It writes
+    [rows, heads, 512] in bf16 and nothing else: no float32 copy of the
+    output over all 640 lanes (21 MB at 65 x 128) is left among the
+    temporaries; and Mosaic, which refuses a kernel over its scoped VMEM,
+    takes the rule's buffers at under half of v5e's 16 MiB."""
+    from polyrl_tpu.ops import mla_attention as mla
 
     def arg(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    fn = functools.partial(latent_paged_attention_pallas, rank=512,
+    fn = functools.partial(mla.latent_paged_attention_pallas, rank=512,
                            scale=192 ** -0.5)
     compiled = jax.jit(fn).lower(
-        arg((129, 32, 640), jnp.bfloat16),
-        arg((1, 15617, 64, 640), jnp.bfloat16), arg((129, 192), jnp.int32),
-        arg((129,), jnp.int32)).compile()
+        arg((s, h, 640), jnp.bfloat16),
+        arg((1, n_pages, 64, 640), jnp.bfloat16), arg((s, width), jnp.int32),
+        arg((s,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**20
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 * 2**20
+    assert mem.output_size_in_bytes == s * h * 512 * 2
+    b, _subs, nbuf = mla._block_plan(h, 640, 512, 64, 2, width)
+    assert nbuf * b * 64 * 640 * 2 < 8 * 2**20
 
 
 def test_ling_decode_step_compiles_for_v5e_within_memory(one_chip,
