@@ -64,12 +64,19 @@ the ring is for cross-process request traces (its wall/monotonic anchor
 joins trainer, manager and engine), the device trace is where host phases
 meet device time.
 
-Completion-stamp counters (cumulative, monotone, flat in ``server_info``;
-two samples give a rate over any window, with no profiler session):
+Cumulative counters (monotone, flat in ``server_info``; two samples give
+a rate over any window, with no profiler session). ``CUMULATIVE_KEYS``
+below is their one declaration: ``counters()``, ``/statusz``, ``/metrics``
+and the tools take the keys from it. Each moves once a dispatch, landing,
+emission or iteration, never once a token, all on this profiler's clock:
 
 - ``decode_dispatches`` / ``decode_steps_done`` — fused decode dispatches
   enqueued, and fused steps whose results have landed on the host;
-  ``fused_sample_steps`` — those of them whose program drew its tokens
+  ``row_steps_done`` — those steps times the live rows of their dispatch
+  (a token each while no row ends: over ``decode_steps_done`` the
+  occupancy where the work happens, over ``device_busy_at_s`` the rate at
+  the engine's landings);
+  ``fused_sample_steps`` — the steps whose program drew its tokens
   inside the output matmul (``decoder.head_and_sample``);
   ``decode_dispatches_cold`` — those of the dispatches enqueued with
   NOTHING outstanding (the device had run dry: an engine that keeps its
@@ -89,10 +96,29 @@ two samples give a rate over any window, with no profiler session):
   ``delta(device_busy_s) / delta(decode_steps_done)`` between any two
   samples carries no dispatch quantum;
 - ``loop_wall_s`` / ``loop_host_s`` — loop wall, and loop wall less
-  ``idle`` and ``sample_fetch`` self-time;
-- ``programs_built`` / ``last_program_built`` — jit-cache misses of the
-  engine's program tables (:meth:`on_build`), the last 32 in
-  ``snapshot()``.
+  ``idle`` and ``sample_fetch`` self-time; ``phase_<phase>_s`` (ten keys,
+  ``PHASE_KEYS``) — the loop THREAD's self seconds by phase, folded at
+  each iteration's close from that iteration's own partition (``totals``
+  also take phases entered on other threads): the eight that are no wait
+  sum to ``loop_host_s``, all ten to ``loop_wall_s``;
+- ``emit_wait_s`` / ``dispatches_emitted`` — for each landed dispatch, the
+  seconds from its landing (fetcher thread) to the start of its ``emit``
+  on the loop thread, and how many were emitted;
+- ``landing_gap_hist`` — log2 bucket counts (``Histogram.bucket_counts``)
+  of the time between consecutive landings while work that lands stayed
+  outstanding throughout (about a program's length on a fed device; a
+  dispatch that lands nothing, a chunked prefill's mid-chunk, voids the
+  gap it falls in, and so does a program's build, which holds the loop
+  for the compiler's seconds, and a stopping engine counts none:
+  :meth:`on_stop`); ``stalls`` — such gaps longer than ``STALL_GAP_S``,
+  each of which the engine logs once, at the landing that ends it, with
+  the loop thread's open phase and the queues' lengths
+  (``CBEngine._log_stall``). Nothing in the process watches for a stall
+  while it lasts: the stacks of an engine that hangs are read from
+  outside it (``py-spy dump``, ``gdb``);
+- ``programs_built`` / ``build_s`` — jit-cache misses of the engine's
+  program tables and the seconds to their first return
+  (:meth:`on_build`), the last 32 in ``snapshot()``.
 """
 
 from __future__ import annotations
@@ -119,6 +145,29 @@ SPAN_PHASES = frozenset(
 # dispatch kinds whose results are fused decode steps
 DECODE_KINDS = frozenset(("step", "spec"))
 MAX_BUILDS_KEPT = 32
+# a phase's cumulative loop-thread seconds in ``server_info``
+PHASE_KEYS = {p: f"phase_{p.removesuffix('_device')}_s" for p in PHASES}
+# a landing gap longer than this is a stall: the longest program of any
+# benchmark cell runs 0.18 s and a prefill chunk 0.1 s
+STALL_GAP_S = 2.0
+# THE declaration of the profiler's cumulative ``server_info`` keys (module
+# docstring): ``counters()`` reports exactly these and the clock
+# ``device_busy_at_s``; ``statusz.CUMULATIVE_INFO_KEYS`` (and so /statusz's
+# counters, /metrics' types and the docs lint), ``tools/engine_report.py``
+# and ``tools/check_metric_names.py`` read this tuple. Keys in ``_s`` are
+# seconds, ``_hist`` keys ``Histogram.bucket_counts()``, the rest counts.
+CUMULATIVE_KEYS = (
+    "decode_dispatches", "decode_dispatches_cold", "admission_deferrals",
+    "pages_grown", "slot_yields", "decode_steps_done", "fused_sample_steps",
+    "row_steps_done", "device_busy_s", "loop_wall_s", "loop_host_s",
+    *PHASE_KEYS.values(), "emit_wait_s", "dispatches_emitted",
+    "landing_gap_hist", "stalls", "programs_built", "build_s")
+
+
+def _zero(key: str):
+    if key.endswith("_hist"):
+        return Histogram()
+    return 0.0 if key.endswith("_s") else 0
 
 
 class EngineLoopProfiler:
@@ -138,8 +187,6 @@ class EngineLoopProfiler:
         self._tls = threading.local()
         self.window_s = float(window_s)
         self.iters = 0
-        self.wall_s = 0.0
-        self.loop_host_s = 0.0
         self.totals = {p: 0.0 for p in PHASES}
         self.counts = {p: 0 for p in PHASES}
         self.hists = {p: Histogram() for p in PHASES if p != "other"}
@@ -151,24 +198,26 @@ class EngineLoopProfiler:
         self._win_busy_mark = 0.0  # device_busy_s at the last iteration close
         # completion stamps: dispatches whose results will land, oldest
         # first, as (their fused decode steps (0 for a prefill), whether
-        # those sampled inside the head)
+        # those sampled inside the head, their live rows)
         self._landing: collections.deque = collections.deque()
         self._tail_unlanded = False  # dispatched after them, lands nothing
         self._busy_from: float | None = None  # busy not yet counted, since
-        self.device_busy_s = 0.0
+        # the last landing's clock while what it left outstanding all lands
+        self._gap_from: float | None = None
+        # landing clocks of the dispatches not yet emitted, oldest first
+        self._landed_at: collections.deque = collections.deque()
+        self._loop_state: dict | None = None  # the loop thread's _state()
+        self._stopping = False  # on_stop: landings count no gap from here
+        self._cum = {k: _zero(k) for k in CUMULATIVE_KEYS}
         self.device_busy_at_s = 0.0
-        self.decode_dispatches = 0
-        self.decode_dispatches_cold = 0
-        self.admission_deferrals = 0
-        self.pages_grown = 0
-        self.slot_yields = 0
-        self.decode_steps_done = 0
-        self.fused_sample_steps = 0
         self.fetch_s = 0.0
         self.fetch_n = 0
-        self.programs_built = 0
         self.builds: collections.deque = collections.deque(
             maxlen=MAX_BUILDS_KEPT)
+
+    @property
+    def wall_s(self) -> float:
+        return self._cum["loop_wall_s"]
 
     # -- thread-local attribution state --------------------------------------
 
@@ -241,7 +290,7 @@ class EngineLoopProfiler:
         iteration's partition; the leftover wall (empty-stack time between
         phases) lands in ``other`` so the sum equals the iteration wall by
         construction."""
-        st = self._state()
+        st = self._loop_state = self._state()
         t0 = self._clock()
         st["iter_phases"] = {}
         st["iter_t0"] = t0
@@ -255,76 +304,114 @@ class EngineLoopProfiler:
             st["iter_t0"] = None
             wall = now - t0
             attributed = sum(phases.values())
-            other = max(0.0, wall - attributed)
+            phases["other"] = other = max(0.0, wall - attributed)
             waits = sum(phases.get(p, 0.0) for p in WAIT_PHASES)
             acct = sum(phases.get(p, 0.0) for p in ACCOUNTING_PHASES)
             with self._lock:
+                c = self._cum
                 self.iters += 1
-                self.wall_s += wall
-                self.loop_host_s += max(0.0, wall - waits)
+                c["loop_wall_s"] += wall
+                c["loop_host_s"] += max(0.0, wall - waits)
+                for name, self_s in phases.items():
+                    c[PHASE_KEYS[name]] += self_s
                 self.totals["other"] += other
                 cur = self._win_cur
                 cur[0] += wall
-                cur[1] += self.device_busy_s - self._win_busy_mark
+                cur[1] += c["device_busy_s"] - self._win_busy_mark
                 cur[2] += acct
                 cur[3] += phases.get("idle", 0.0)
                 cur[4] += waits
-                self._win_busy_mark = self.device_busy_s
+                self._win_busy_mark = c["device_busy_s"]
                 if cur[0] >= self.window_s / 2.0:
                     self._win_prev = cur
                     self._win_cur = [0.0] * 5
 
+    def loop_open_phase(self) -> str:
+        """The loop thread's innermost open phase right now ("" between
+        phases or before its first iteration), from any thread."""
+        st = self._loop_state
+        top = st["stack"][-1:] if st is not None else []
+        return top[0][0] if top else ""
+
     # -- completion stamps ----------------------------------------------------
 
     def on_dispatch(self, kind: str, steps: int = 0, lands: bool = True,
-                    fused_sample: bool = False) -> None:
+                    fused_sample: bool = False, rows: int = 0) -> None:
         """A dispatch was just enqueued on the device; ``steps``: the
-        decode steps it fuses, ``fused_sample``: they sample inside the
-        head. ``lands`` False: it returns nothing the
-        host fetches (a chunked prefill's mid-chunk),
+        decode steps it fuses, over ``rows`` live rows, ``fused_sample``:
+        they sample inside the head. ``lands`` False: it returns nothing
+        the host fetches (a chunked prefill's mid-chunk),
         so a later dispatch's landing stands for it."""
         now = self._clock()
         with self._lock:
+            c = self._cum
+            cold = self._busy_from is None
             if kind in DECODE_KINDS:
-                self.decode_dispatches += 1
-                if self._busy_from is None:
-                    self.decode_dispatches_cold += 1
-            if self._busy_from is None:
+                c["decode_dispatches"] += 1
+                if cold:
+                    c["decode_dispatches_cold"] += 1
+            if cold:
                 self._busy_from = now
             if lands:
-                self._landing.append((steps, fused_sample))
+                self._landing.append((steps, fused_sample, rows))
                 self._tail_unlanded = False
             else:
                 self._tail_unlanded = True
+                self._gap_from = None
 
     def on_admission_deferred(self) -> None:
         """Admission left a request pending (no pages, no slot) and the
         loop went on to dispatch without waiting for either."""
         with self._lock:
-            self.admission_deferrals += 1
+            self._cum["admission_deferrals"] += 1
 
     def on_pages_grown(self, n: int) -> None:
         """Rows already running took ``n`` more pages before a dispatch."""
         with self._lock:
-            self.pages_grown += int(n)
+            self._cum["pages_grown"] += int(n)
 
     def on_slot_yield(self) -> None:
         """A running row gave up its slot and pages for want of pages."""
         with self._lock:
-            self.slot_yields += 1
+            self._cum["slot_yields"] += 1
 
-    def on_landed(self, n: int) -> None:
+    def on_landed(self, n: int) -> float | None:
         """The oldest ``n`` dispatches' results are on the host: the
-        device has finished them and everything enqueued before them."""
+        device has finished them and everything enqueued before them.
+        Returns the landing gap this one closed where it was a stall
+        (module docstring), else None."""
         now = self._clock()
         with self._lock:
+            c = self._cum
             for _ in range(min(n, len(self._landing))):
-                steps, fused_sample = self._landing.popleft()
-                self.decode_steps_done += steps
+                steps, fused_sample, rows = self._landing.popleft()
+                c["decode_steps_done"] += steps
+                c["row_steps_done"] += steps * rows
                 if fused_sample:
-                    self.fused_sample_steps += steps
-            self._count_busy(now, bool(self._landing)
-                             or self._tail_unlanded)
+                    c["fused_sample_steps"] += steps
+                self._landed_at.append(now)
+            gap = None if self._gap_from is None else now - self._gap_from
+            if gap is not None:
+                c["landing_gap_hist"].observe(gap)
+                if gap > STALL_GAP_S:
+                    c["stalls"] += 1
+                else:
+                    gap = None
+            still_busy = bool(self._landing) or self._tail_unlanded
+            self._count_busy(now, still_busy)
+            self._gap_from = (now if self._landing and not self._stopping
+                              else None)
+        return gap
+
+    def on_emit(self, n: int) -> None:
+        """The loop thread starts to emit the oldest ``n`` landed
+        dispatches: each has waited since its landing."""
+        now = self._clock()
+        with self._lock:
+            c = self._cum
+            for _ in range(min(n, len(self._landed_at))):
+                c["emit_wait_s"] += max(0.0, now - self._landed_at.popleft())
+                c["dispatches_emitted"] += 1
 
     def drop_outstanding(self, tail_only: bool = False) -> None:
         """Dispatches were abandoned and will never land (an engine reset
@@ -335,12 +422,22 @@ class EngineLoopProfiler:
             self._tail_unlanded = False
             if not tail_only:
                 self._landing.clear()
+                self._landed_at.clear()
             if not self._landing:
                 self._count_busy(now, False)
+                self._gap_from = None
+
+    def on_stop(self) -> None:
+        """The engine is stopping: what is still on the device lands in
+        one get that waits for the newest of it (``pipeline_depth``
+        programs), or is dropped. Its gaps are no stalls."""
+        with self._lock:
+            self._gap_from = None
+            self._stopping = True
 
     def _count_busy(self, now: float, still_busy: bool) -> None:
         if self._busy_from is not None:
-            self.device_busy_s += max(0.0, now - self._busy_from)
+            self._cum["device_busy_s"] += max(0.0, now - self._busy_from)
             self.device_busy_at_s = now
         self._busy_from = now if still_busy else None
 
@@ -350,7 +447,10 @@ class EngineLoopProfiler:
         """A miss of the engine's program tables, after the program's first
         call returned (trace, lower, compile or cache read, enqueue)."""
         with self._lock:
-            self.programs_built += 1
+            self._cum["programs_built"] += 1
+            self._cum["build_s"] += seconds
+            # a landing gap that holds a build has timed the compiler
+            self._gap_from = None
             self.builds.append({"kind": kind, "key": str(key),
                                 "seconds": round(seconds, 4)})
 
@@ -362,9 +462,10 @@ class EngineLoopProfiler:
         > 1.0 means double-counted attribution. 1.0 before any
         iteration."""
         with self._lock:
-            if self.wall_s <= 0.0:
+            wall = self._cum["loop_wall_s"]
+            if wall <= 0.0:
                 return 1.0
-            return (self.wall_s - self.totals["other"]) / self.wall_s
+            return (wall - self.totals["other"]) / wall
 
     def window_fracs(self) -> dict:
         """The windowed split over ~window_s of recent loop wall; zeros
@@ -387,25 +488,14 @@ class EngineLoopProfiler:
         }
 
     def counters(self) -> dict:
-        """The cumulative counters, one consistent copy."""
+        """The cumulative counters (``CUMULATIVE_KEYS``) and the clock of
+        the landing up to which ``device_busy_s`` is booked, one
+        consistent copy."""
         with self._lock:
-            out = {
-                "decode_dispatches": self.decode_dispatches,
-                "decode_dispatches_cold": self.decode_dispatches_cold,
-                "admission_deferrals": self.admission_deferrals,
-                "pages_grown": self.pages_grown,
-                "slot_yields": self.slot_yields,
-                "decode_steps_done": self.decode_steps_done,
-                "fused_sample_steps": self.fused_sample_steps,
-                "device_busy_s": round(self.device_busy_s, 6),
-                "device_busy_at_s": round(self.device_busy_at_s, 6),
-                "loop_wall_s": round(self.wall_s, 6),
-                "loop_host_s": round(self.loop_host_s, 6),
-                "programs_built": self.programs_built,
-            }
-            if self.builds:
-                last = self.builds[-1]
-                out["last_program_built"] = f"{last['kind']} {last['key']}"
+            out = {k: (v.bucket_counts() if isinstance(v, Histogram)
+                       else round(v, 6) if isinstance(v, float) else v)
+                   for k, v in self._cum.items()}
+            out["device_busy_at_s"] = round(self.device_busy_at_s, 6)
         return out
 
     def server_info_fields(self) -> dict:
@@ -428,7 +518,7 @@ class EngineLoopProfiler:
             totals = dict(self.totals)
             counts = dict(self.counts)
             iters = self.iters
-            wall = self.wall_s
+            wall = self._cum["loop_wall_s"]
             fetch = {"seconds": round(self.fetch_s, 4), "n": self.fetch_n}
             builds = list(self.builds)
             hists = {p: {

@@ -22,6 +22,21 @@ _IDX_MIN = -40 * SUBDIV
 _IDX_MAX = 40 * SUBDIV
 
 
+def bucket_edge(index: float) -> float:
+    """The lower edge of bucket ``index`` (``index + 1``: its upper edge,
+    ``index + 0.5``: its geometric middle)."""
+    return 2.0 ** (index / SUBDIV)
+
+
+def bucket_index(value: float) -> int:
+    """The bucket ``value`` counts in; a non-positive one in the lowest
+    (where ``Histogram.bucket_counts`` reports them)."""
+    if value <= 0.0:
+        return _IDX_MIN
+    return min(max(math.floor(math.log2(value) * SUBDIV), _IDX_MIN),
+               _IDX_MAX)
+
+
 class Histogram:
     """Log2-bucketed distribution: counts per fixed geometric bucket plus
     exact count/sum/min/max. Non-positive observations are counted but only
@@ -46,7 +61,7 @@ class Histogram:
         if v <= 0.0:
             self.zeros += 1
             return
-        idx = min(max(math.floor(math.log2(v) * SUBDIV), _IDX_MIN), _IDX_MAX)
+        idx = bucket_index(v)
         self.buckets[idx] = self.buckets.get(idx, 0) + 1
 
     def observe_many(self, values) -> None:
@@ -94,9 +109,20 @@ class Histogram:
         for idx in sorted(self.buckets):
             seen += self.buckets[idx]
             if seen >= rank:
-                mid = 2.0 ** ((idx + 0.5) / SUBDIV)
+                mid = bucket_edge(idx + 0.5)
                 return min(max(mid, self.vmin), self.vmax)
         return self.vmax
+
+    def bucket_counts(self) -> list[list[int]]:
+        """``[[bucket index, count], ...]`` by rising index, JSON-ready:
+        bucket ``i`` holds the values in ``[bucket_edge(i),
+        bucket_edge(i + 1))``, and non-positive ones count in the lowest.
+        Every count only grows, so two copies taken at two times differ by
+        the distribution of what was observed between them."""
+        counts = dict(self.buckets)
+        if self.zeros:
+            counts[_IDX_MIN] = counts.get(_IDX_MIN, 0) + self.zeros
+        return [[i, counts[i]] for i in sorted(counts)]
 
     @property
     def mean(self) -> float:

@@ -90,6 +90,8 @@ import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Callable
 
+from polyrl_tpu.obs.engine_profile import CUMULATIVE_KEYS
+
 log = logging.getLogger(__name__)
 
 SCHEMA = "polyrl/statusz/v8"
@@ -98,15 +100,15 @@ _HIST_SUFFIXES = ("p50", "p95", "p99", "max", "mean", "count")
 
 # every key the schema guarantees on EVERY snapshot, both planes — the
 # conformance contract consumers (and the conformance test) rely on
-# server_info keys that only ever grow: the engine profiler's
-# completion-stamp counters and the server's stream counters. They are
-# counters (not gauges) in the rollout plane's snapshot and at /metrics,
-# and tools/check_statusz_docs.py holds ARCHITECTURE.md to naming each.
-CUMULATIVE_INFO_KEYS = frozenset((
-    "decode_dispatches", "decode_dispatches_cold", "admission_deferrals",
-    "pages_grown", "slot_yields", "decode_steps_done", "fused_sample_steps", "device_busy_s",
-    "loop_wall_s", "loop_host_s", "programs_built", "stream_chunks",
-    "stream_lag_s"))
+# server_info keys that only ever grow: the engine profiler's cumulative
+# counters (declared there, once) and the server's stream counters. They
+# are counters (not gauges) in the rollout plane's snapshot and at
+# /metrics, and tools/check_statusz_docs.py holds ARCHITECTURE.md to naming
+# each. ``*_hist`` keys are lists (``Histogram.bucket_counts``), the rest
+# numbers.
+STREAM_INFO_KEYS = ("stream_chunks", "stream_lines", "stream_lag_s",
+                    "stream_lag_hist")
+CUMULATIVE_INFO_KEYS = frozenset(CUMULATIVE_KEYS + STREAM_INFO_KEYS)
 # cumulative too, and counters where present: a MoE model's engine alone
 # reports them (``CBEngine.moe_info``)
 MOE_INFO_KEYS = frozenset(("moe_routed", "moe_experts_hit", "moe_load_max"))

@@ -548,7 +548,27 @@ class CBEngine:
             if entry[0] == "step" and arrs[3] is not None:
                 self._moe_load += arrs[3]
         if self.profiler is not None:
-            self.profiler.on_landed(len(batch))
+            stalled_s = self.profiler.on_landed(len(batch))
+            if stalled_s is not None:
+                self._log_stall(stalled_s)
+
+    def _log_stall(self, gap_s: float) -> None:
+        """The one record of a stall (``engine_profile.STALL_GAP_S``), at
+        the landing that ended it, from the thread that landed it and
+        before the others have moved far. Reads no lock: a thread that is
+        stuck may hold it."""
+        from polyrl_tpu.rollout.kvledger import hbm_truth
+
+        devices = self.kvledger.devices if self.kvledger is not None else None
+        used_gb = hbm_truth(0.0, devices).get("hbm_used_gb")
+        log.warning(
+            "stall: %.2f s between landings with work outstanding "
+            "throughout; loop thread in phase %r, fetcher held %d "
+            "dispatches, %d more outstanding (_emit_q), %d landed and not "
+            "emitted (_fetched_q), device memory in use %s",
+            gap_s, self.profiler.loop_open_phase(), self._fetch_inflight,
+            len(self._emit_q), len(self._fetched_q),
+            "unknown" if used_gb is None else f"{used_gb:.2f} GB")
 
     def loop_profile_info(self) -> dict:
         """Flat server_info fields for the loop profiler ({} when off).
@@ -1616,6 +1636,8 @@ class CBEngine:
 
     def stop(self) -> None:
         self._stop.set()
+        if self.profiler is not None:
+            self.profiler.on_stop()
         if self._loop_thread is not None:
             self._loop_thread.join(timeout=10.0)
         if self._fetch_thread is not None:
@@ -2746,9 +2768,10 @@ class CBEngine:
         if self.profiler is not None:
             # before the fetcher can see the entry: it lands them in order
             kind = entry[0]
+            decode = kind in ("step", "spec")
             self.profiler.on_dispatch(
-                kind, entry[3] if kind in ("step", "spec") else 0,
-                fused_sample=fused_sample)
+                kind, entry[3] if decode else 0, fused_sample=fused_sample,
+                rows=len(entry[2]) if decode else 0)
         self._last_two.append(entry[1])
         with self._fetch_cv:
             self._emit_q.append(entry)
@@ -2837,6 +2860,8 @@ class CBEngine:
             exc, self._fetch_exc = self._fetch_exc, None
             epoch = self._fetch_epoch
         if ready:
+            if self.profiler is not None:
+                self.profiler.on_emit(len(ready))
             with self._phase("emit"):
                 for ep, entry, arrs in ready:
                     if ep == epoch:

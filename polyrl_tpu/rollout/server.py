@@ -38,6 +38,7 @@ import jax
 import numpy as np
 
 from polyrl_tpu import obs
+from polyrl_tpu.obs.histogram import bucket_index
 from polyrl_tpu.obs.statusz import CUMULATIVE_INFO_KEYS, MOE_INFO_KEYS
 from polyrl_tpu.rollout.cb_engine import STREAM_END
 from polyrl_tpu.rollout.flightdeck import ThroughputEWMA
@@ -125,11 +126,15 @@ class RolloutServer:
         # aggregates + slopes as the "timeseries" section
         self._timeseries = obs.TimeSeriesStore()
         self._ts_samples = 0
-        # bursts written to clients, and the seconds each took from the
-        # engine's put of its first line to the return of the flush
+        # bursts and lines written to clients, and the seconds each burst
+        # took from the engine's put of its first line to the return of
+        # the flush: their sum, and their counts by log2 bucket
+        # (``obs/histogram``'s) for a window's tail
         self._stream_lock = threading.Lock()
         self.stream_chunks = 0
+        self.stream_lines = 0
         self.stream_lag_s = 0.0
+        self._stream_lag_buckets: dict[int, int] = {}
 
         outer = self
 
@@ -284,7 +289,7 @@ class RolloutServer:
                                     "server/stream_write"):
                                 chunk("".join(outer._serialize_line(
                                     rid, i, abort_ev) for i in items))
-                            outer._count_stream_chunk(items[0])
+                            outer._count_stream_chunk(items)
                     self.wfile.write(b"0\r\n\r\n")
                 except (BrokenPipeError, ConnectionResetError):
                     outer.abort_request(rid)
@@ -451,16 +456,21 @@ class RolloutServer:
                 return replaced
         return json.dumps(line) + "\n"
 
-    def _count_stream_chunk(self, first_line) -> None:
-        """One burst reached the socket: its lag runs from the engine's put
-        of its first line (``StreamLine.t_put``, never serialized)."""
-        t_put = getattr(first_line, "t_put", None)
+    def _count_stream_chunk(self, lines: list) -> None:
+        """One burst of ``lines`` reached the socket: its lag runs from the
+        engine's put of its first line (``StreamLine.t_put``, never
+        serialized)."""
+        t_put = getattr(lines[0], "t_put", None)
         if t_put is None:   # a terminal the server or an abort path wrote
             return
         lag = time.monotonic() - t_put
+        bucket = bucket_index(lag)   # the logarithm outside the lock
         with self._stream_lock:
             self.stream_chunks += 1
+            self.stream_lines += len(lines)
             self.stream_lag_s += lag
+            self._stream_lag_buckets[bucket] = \
+                self._stream_lag_buckets.get(bucket, 0) + 1
 
     def _drop_abort(self, rid: str, ev: threading.Event | None = None) -> None:
         with self._aborts_lock:
@@ -638,7 +648,10 @@ class RolloutServer:
             info.update(moe_info())
         with self._stream_lock:
             info["stream_chunks"] = self.stream_chunks
+            info["stream_lines"] = self.stream_lines
             info["stream_lag_s"] = round(self.stream_lag_s, 6)
+            info["stream_lag_hist"] = [
+                [i, n] for i, n in sorted(self._stream_lag_buckets.items())]
         kv_info = getattr(self.engine, "kv_memory_info", None)
         if kv_info is not None:
             # KV memory plane (rollout/kvledger.py): residency tiers, the
@@ -673,7 +686,8 @@ class RolloutServer:
         from polyrl_tpu.obs import statusz
 
         info = self.server_info()
-        counters = {k: float(v) for k, v in info.items()
+        counters = {k: v if isinstance(v, list) else float(v)
+                    for k, v in info.items()
                     if k in ("tokens_salvaged", "salvage_published_pages",
                              "drained_requests", "spec_emitted",
                              "spec_dispatches", "prefill_dispatches",

@@ -19,8 +19,10 @@ import pytest
 
 from polyrl_tpu.models import decoder
 from polyrl_tpu.obs import statusz
-from polyrl_tpu.obs.engine_profile import (ACCOUNTING_PHASES, PHASES,
-                                           WAIT_PHASES, EngineLoopProfiler)
+from polyrl_tpu.obs.engine_profile import (ACCOUNTING_PHASES,
+                                           CUMULATIVE_KEYS, PHASE_KEYS,
+                                           PHASES, STALL_GAP_S, WAIT_PHASES,
+                                           EngineLoopProfiler)
 from polyrl_tpu.rollout.cb_engine import STREAM_END, CBEngine
 from polyrl_tpu.rollout.sampling import SamplingParams
 
@@ -121,10 +123,7 @@ def test_unattributed_residual_lands_in_other():
 
 PROFILER_INFO_KEYS = {
     "device_frac", "host_overhead_frac", "accounting_frac",
-    "loop_attributed_frac", "decode_dispatches", "decode_dispatches_cold",
-    "admission_deferrals", "pages_grown", "slot_yields", "decode_steps_done",
-    "fused_sample_steps", "device_busy_s", "device_busy_at_s", "loop_wall_s",
-    "loop_host_s", "programs_built"}
+    "loop_attributed_frac", "device_busy_at_s"} | set(CUMULATIVE_KEYS)
 
 
 def test_window_flip_and_device_host_split():
@@ -257,7 +256,7 @@ def test_real_engine_attribution_under_churn(tiny):
     assert snap["phase_n"]["decode_dispatch_device"] > 0
     assert snap["latency"]["decode_dispatch_device"]["count"] > 0
     info = eng.loop_profile_info()
-    assert set(info) == PROFILER_INFO_KEYS | {"last_program_built"}
+    assert set(info) == PROFILER_INFO_KEYS
     assert info["device_frac"] > 0.0        # work was outstanding
     assert info["loop_attributed_frac"] >= 0.90
     # the completion stamps: every dispatched step landed or was dropped
@@ -725,8 +724,8 @@ def test_jit_cache_miss_records_kind_and_key(tiny, caplog):
     step = next(b for b in builds if b["kind"] == "step")
     assert step["key"] == "(False, 2, None)" and step["seconds"] > 0
     assert first["programs_built"] == again["programs_built"] == len(builds)
-    assert again["last_program_built"] == \
-        f"{builds[-1]['kind']} {builds[-1]['key']}"
+    assert again["build_s"] == first["build_s"] == pytest.approx(
+        sum(b["seconds"] for b in builds), abs=1e-3)
     lines = [r.getMessage() for r in caplog.records
              if r.getMessage().startswith("built program")]
     assert len(lines) == len(builds)
@@ -801,3 +800,429 @@ def test_server_info_keeps_its_readers_keys(tiny):
             == info["decode_steps_done"]
     finally:
         server.stop()
+
+
+# -- the host half of a dispatch, from cumulative counters (PR 37) ------------
+
+
+def _bucket_total(pairs) -> int:
+    return sum(n for _i, n in pairs)
+
+
+def test_phase_keys_partition_loop_wall_and_host_on_the_loop_thread():
+    """The ten ``phase_*_s`` keys are the loop THREAD's partition: they
+    sum to ``loop_wall_s`` and the eight non-waits to ``loop_host_s``,
+    while a phase another thread entered meanwhile reaches ``totals``
+    (which then exceed the wall) and none of the keys."""
+    clock = _FakeClock()
+    prof = EngineLoopProfiler(window_s=1e9, clock=clock)
+
+    def fetcher():
+        with prof.phase("sample_fetch"):
+            clock.advance(2.0)               # while the loop thread emits
+
+    for _ in range(3):
+        with prof.iteration():
+            with prof.phase("collect_wave"):
+                clock.advance(0.25)
+            with prof.phase("decode_dispatch_device"):
+                clock.advance(0.5)
+                with prof.phase("accounting"):
+                    clock.advance(0.125)
+            clock.advance(0.0625)            # between phases: other
+            with prof.phase("emit"):
+                t = threading.Thread(target=fetcher)
+                t.start()
+                t.join(timeout=10)
+                assert not t.is_alive()
+            with prof.phase("sample_fetch"):
+                clock.advance(1.0)
+            with prof.phase("idle"):
+                clock.advance(0.5)
+    c = prof.counters()
+    waits = {PHASE_KEYS[p] for p in WAIT_PHASES}
+    assert set(PHASE_KEYS.values()) <= set(CUMULATIVE_KEYS)
+    assert len(PHASE_KEYS) == 10 and len(waits) == 2
+    assert sum(c[k] for k in PHASE_KEYS.values()) \
+        == pytest.approx(c["loop_wall_s"]) == pytest.approx(3 * 4.4375)
+    assert sum(c[k] for k in PHASE_KEYS.values() if k not in waits) \
+        == pytest.approx(c["loop_host_s"]) == pytest.approx(3 * 2.9375)
+    assert c["phase_decode_dispatch_s"] == pytest.approx(1.5)
+    assert c["phase_accounting_s"] == pytest.approx(0.375)
+    assert c["phase_other_s"] == pytest.approx(0.1875)
+    assert c["phase_emit_s"] == pytest.approx(6.0)   # its own wall went by
+    # the other thread's wait is in the totals and in no key
+    assert c["phase_sample_fetch_s"] == pytest.approx(3.0)
+    assert prof.totals["sample_fetch"] == pytest.approx(9.0)
+    assert sum(prof.totals.values()) > c["loop_wall_s"] + 5.0
+
+
+@pytest.mark.parametrize("rows", [18, 64, 128])
+def test_rows_at_the_landing_give_an_exact_rate_between_landings(rows):
+    """``row_steps_done`` moves only at a landing, by steps x the
+    dispatch's live rows, at ``device_busy_at_s``'s clock: between ANY two
+    samples that a landing separates, delta over delta is the engine's
+    token rate exactly, and over ``decode_steps_done`` the rows."""
+    clock = _FakeClock()
+    prof = EngineLoopProfiler(clock=clock)
+    k, step_s = 8, 0.0175
+    samples = []
+    prof.on_dispatch("step", steps=k, rows=rows)
+    for _ in range(30):
+        prof.on_dispatch("step", steps=k, rows=rows)  # run-ahead
+        before = prof.counters()
+        clock.advance(k * step_s * 0.41)
+        mid = prof.counters()                # mid-dispatch: nothing moved
+        assert mid["row_steps_done"] == before["row_steps_done"]
+        assert mid["device_busy_at_s"] == before["device_busy_at_s"]
+        samples.append(mid)
+        clock.advance(k * step_s * 0.59)
+        prof.on_landed(1)
+        assert prof.counters()["row_steps_done"] \
+            == before["row_steps_done"] + k * rows
+    for a, b in ((samples[2], samples[3]), (samples[5], samples[29]),
+                 (samples[11], samples[20])):
+        tokens = b["row_steps_done"] - a["row_steps_done"]
+        assert tokens / (b["device_busy_at_s"] - a["device_busy_at_s"]) \
+            == pytest.approx(rows / step_s, rel=1e-6)
+        assert tokens / (b["decode_steps_done"] - a["decode_steps_done"]) \
+            == rows
+    # a prefill's landing carries no rows
+    prof.on_dispatch("prefill")
+    done = prof.counters()["row_steps_done"]
+    clock.advance(0.1)
+    prof.on_landed(2)
+    assert prof.counters()["row_steps_done"] == done + k * rows
+
+
+def test_emit_wait_runs_from_the_landing_to_the_start_of_its_emit():
+    """Each landed dispatch waits from its landing (fetcher) to the loop
+    thread's ``on_emit``; a batch landed in one get shares its landing's
+    clock; what an engine reset dropped is never counted."""
+    clock = _FakeClock()
+    prof = EngineLoopProfiler(clock=clock)
+    for _ in range(4):
+        prof.on_dispatch("step", steps=8, rows=4)
+    clock.advance(1.0)
+    prof.on_landed(1)                        # lands at +1.0
+    clock.advance(0.25)
+    prof.on_landed(2)                        # two land together at +1.25
+    clock.advance(0.5)
+    assert prof.counters()["dispatches_emitted"] == 0
+    prof.on_emit(3)                          # waited 0.75, 0.5 and 0.5
+    c = prof.counters()
+    assert c["dispatches_emitted"] == 3
+    assert c["emit_wait_s"] == pytest.approx(1.75)
+    prof.on_emit(2)                          # nothing landed is left
+    assert prof.counters()["dispatches_emitted"] == 3
+    clock.advance(1.0)
+    prof.on_landed(1)
+    prof.drop_outstanding()                  # reset: its emit never comes
+    clock.advance(5.0)
+    prof.on_emit(1)
+    c = prof.counters()
+    assert c["dispatches_emitted"] == 3
+    assert c["emit_wait_s"] == pytest.approx(1.75)
+
+
+def test_landing_gaps_count_only_while_landing_work_stayed_outstanding():
+    """A gap is the time between two landings with work that lands
+    outstanding throughout: none across an idle device, none across a
+    chunked prefill's mid-chunk (the device then runs work whose end the
+    host never sees), none after a reset."""
+    clock = _FakeClock()
+    prof = EngineLoopProfiler(clock=clock)
+
+    def gaps():
+        return _bucket_total(prof.counters()["landing_gap_hist"])
+
+    prof.on_dispatch("step", steps=8, rows=4)
+    prof.on_dispatch("step", steps=8, rows=4)
+    clock.advance(0.125)
+    prof.on_landed(1)                        # first landing: no gap yet
+    assert gaps() == 0
+    clock.advance(0.125)
+    prof.on_landed(1)                        # 0.125 s after the last
+    assert gaps() == 1
+    assert prof.counters()["landing_gap_hist"] == [[-24, 1]]  # 2**-3 s
+    clock.advance(30.0)                      # the device idle
+    prof.on_dispatch("step", steps=8, rows=4)
+    clock.advance(0.125)
+    prof.on_landed(1)
+    assert gaps() == 1                       # the idle stretch is no gap
+    # mid-chunks between two landings void the gap
+    prof.on_dispatch("step", steps=8, rows=4)
+    prof.on_dispatch("step", steps=8, rows=4)
+    clock.advance(0.125)
+    prof.on_landed(1)
+    prof.on_dispatch("prefill_extend", lands=False)
+    clock.advance(3.0)
+    prof.on_landed(1)
+    assert gaps() == 1 and prof.counters()["stalls"] == 0
+    prof.drop_outstanding(tail_only=True)
+    # so does a program's build: the loop stood for the compiler
+    prof.on_dispatch("step", steps=8, rows=4)
+    prof.on_dispatch("step", steps=8, rows=4)
+    clock.advance(0.125)
+    prof.on_landed(1)
+    clock.advance(3.0)
+    prof.on_build("step", (False, 8, None), 3.0)
+    prof.on_landed(1)
+    assert gaps() == 1 and prof.counters()["stalls"] == 0
+    # a reset between two landings voids it too
+    prof.on_dispatch("step", steps=8, rows=4)
+    prof.on_dispatch("step", steps=8, rows=4)
+    clock.advance(0.125)
+    prof.on_landed(1)
+    prof.drop_outstanding()
+    clock.advance(3.0)
+    prof.on_landed(1)                        # a stale fetch lands late
+    assert gaps() == 1 and prof.counters()["stalls"] == 0
+
+
+@pytest.mark.parametrize("gap_s,records", [(3.0, 1), (1.0, 0)])
+def test_a_stall_leaves_one_record_at_the_landing_that_ends_it(
+        tiny, caplog, gap_s, records):
+    """The fetcher blocked for ``gap_s`` with a dispatch outstanding: over
+    ``STALL_GAP_S`` the engine logs ONE warning with the gap, the loop
+    thread's open phase and the queues, and counts it; under it, nothing.
+    Later sound landings add no record."""
+    import logging
+
+    clock = _FakeClock()
+    eng = _mk_engine(tiny)                   # never started: no threads
+    prof = eng.profiler = EngineLoopProfiler(clock=clock)
+    entry = ("step", None, [(0, 0), (1, 0)], 8, 0)
+    arrs = (None, None, None, None)
+    try:
+        with caplog.at_level(logging.WARNING,
+                             logger="polyrl_tpu.rollout.cb_engine"), \
+                prof.iteration(), prof.phase("sample_fetch"):
+            for _ in range(4):
+                prof.on_dispatch("step", steps=8, rows=2)
+            clock.advance(0.125)
+            eng._landed([entry], [arrs])
+            eng._fetch_inflight = 1          # the fetcher holds the next
+            clock.advance(gap_s)             # ... and is blocked this long
+            eng._landed([entry], [arrs])
+            for _ in range(2):
+                clock.advance(0.125)
+                eng._landed([entry], [arrs])
+    finally:
+        eng._fetch_inflight = 0
+        eng.stop()
+    lines = [r.getMessage() for r in caplog.records
+             if r.getMessage().startswith("stall:")]
+    assert len(lines) == records == prof.counters()["stalls"]
+    assert (gap_s > STALL_GAP_S) == bool(records)
+    if records:
+        assert lines[0].startswith("stall: 3.00 s between landings")
+        assert "phase 'sample_fetch'" in lines[0]
+        assert "fetcher held 1 dispatches" in lines[0]
+        assert "0 landed and not emitted" in lines[0]
+    assert _bucket_total(prof.counters()["landing_gap_hist"]) == 3
+
+
+def test_the_loop_threads_open_phase_reads_from_any_thread():
+    """What the stall's record says of the loop: its innermost open phase,
+    read from the fetcher's thread without a lock; "" between phases,
+    before the first iteration, and whatever another thread has open."""
+    import threading
+
+    prof = EngineLoopProfiler(clock=_FakeClock())
+    seen = []
+
+    def look():
+        # from another thread, with a phase of its own open
+        with prof.phase("emit"):
+            seen.append(prof.loop_open_phase())
+
+    def from_the_fetcher():
+        t = threading.Thread(target=look)
+        t.start()
+        t.join()
+
+    from_the_fetcher()                       # no iteration yet
+    with prof.iteration():
+        from_the_fetcher()                   # between phases
+        with prof.phase("accounting"):
+            with prof.phase("spill_sweep"):
+                from_the_fetcher()           # the innermost
+            from_the_fetcher()
+    from_the_fetcher()                       # the iteration closed
+    assert seen == ["", "", "spill_sweep", "accounting", ""]
+
+
+def test_a_stopping_engine_voids_its_gaps(tiny, caplog):
+    """``CBEngine.stop`` lands what is still on the device in ONE get that
+    waits for the newest of ``pipeline_depth`` programs: seconds after the
+    fetcher's last landing, and no stall. From ``on_stop`` on no gap is
+    counted; the landings still count their steps."""
+    import logging
+
+    clock = _FakeClock()
+    prof = EngineLoopProfiler(clock=clock)
+    for _ in range(4):
+        prof.on_dispatch("step", steps=8, rows=1)
+    clock.advance(0.125)
+    prof.on_landed(1)                        # the fetcher's last landing
+    prof.on_stop()
+    clock.advance(3.0)                       # the drain's one long get
+    assert prof.on_landed(2) is None
+    clock.advance(0.125)
+    prof.on_landed(1)
+    c = prof.counters()
+    assert c["stalls"] == 0 and c["landing_gap_hist"] == []
+    assert c["decode_steps_done"] == 32
+    # the engine says so itself, first thing in stop()
+    eng = _mk_engine(tiny)                   # never started: no threads
+    prof = eng.profiler = EngineLoopProfiler(clock=clock)
+    entry = ("step", None, [(0, 0)], 8, 0)
+    with caplog.at_level(logging.WARNING,
+                         logger="polyrl_tpu.rollout.cb_engine"):
+        eng.stop()
+        for _ in range(3):
+            prof.on_dispatch("step", steps=8, rows=1)
+        for _ in range(3):
+            clock.advance(3.0)
+            eng._landed([entry], [(None, None, None, None)])
+    assert prof.counters()["stalls"] == 0
+    assert not [r for r in caplog.records
+                if r.getMessage().startswith("stall:")]
+
+
+def test_stream_lines_and_lag_buckets_ride_server_info_as_lists(tiny):
+    """``stream_lines`` counts the lines of the bursts, ``stream_lag_hist``
+    their lags by log2 bucket; both histograms are JSON lists in the flat
+    ``server_info`` that the numeric consumers (the time-series feed,
+    /metrics, the statusz gauges) pass over and /statusz' counters keep."""
+    from polyrl_tpu.rollout.server import RolloutServer
+
+    eng = _mk_engine(tiny)
+    server = RolloutServer(eng, host="127.0.0.1", port=0).start()
+    try:
+        body = json.dumps({"rid": "h", "input_ids": [3] * 12,
+                           "sampling_params": {"temperature": 0.0,
+                                               "max_new_tokens": 6}})
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{server.port}/generate",
+            data=body.encode(), method="POST",
+            headers={"Content-Type": "application/json"})
+        with urllib.request.urlopen(req, timeout=120) as r:
+            r.read()
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{server.port}/get_server_info",
+                timeout=30) as r:
+            info = json.loads(r.read())
+        assert info["stream_lines"] == 6 >= info["stream_chunks"] >= 1
+        for key in ("stream_lag_hist", "landing_gap_hist"):
+            assert isinstance(info[key], list)
+            assert all(isinstance(i, int) and n > 0 for i, n in info[key])
+            assert info[key] == sorted(info[key])
+        assert _bucket_total(info["stream_lag_hist"]) \
+            == info["stream_chunks"]
+        snap = server.statusz_snapshot()
+        assert _bucket_total(snap["counters"]["stream_lag_hist"]) \
+            == snap["counters"]["stream_chunks"]
+        assert not {"stream_lag_hist", "landing_gap_hist"} \
+            & set(snap["gauges"])
+        series = snap["timeseries"]["keys"]
+        assert "engine/stream_lines" in series
+        assert "engine/phase_emit_s" in series
+        assert not any(k.endswith("_hist") for k in series)
+        text = server.metrics_text()
+        assert "# TYPE polyrl_stream_lines counter" in text
+        assert "# TYPE polyrl_row_steps_done counter" in text
+        assert "_hist" not in text
+        json.dumps(snap)
+    finally:
+        server.stop()
+
+
+def test_the_managers_poller_passes_over_list_fields():
+    """The C++ stats poller (``main.cc``, ``json.h``) parses a
+    ``server_info`` that carries the two histograms as lists and still
+    forwards the fields it indexes."""
+    import time
+
+    from fake_engine import FakeEngine
+    from polyrl_tpu.manager.client import ManagerClient, spawn_rollout_manager
+
+    proc, port = spawn_rollout_manager(
+        "127.0.0.1:0", extra_args=["--health-check-interval-s", "0.1",
+                                   "--stats-poll-interval-s", "0.1"])
+    client = ManagerClient(f"127.0.0.1:{port}")
+    eng = FakeEngine().start()
+    eng.server_info_extra = {
+        "landing_gap_hist": [[-24, 7], [-23, 180], [9, 1]],
+        "stream_lag_hist": [], "stalls": 1, "phase_emit_s": 12.5,
+        "device_frac": 0.875, "occupancy": 0.5}
+    try:
+        client.wait_healthy()
+        client.register_rollout_instance(eng.endpoint)
+        inst, t0 = None, time.monotonic()
+        while inst is None and time.monotonic() - t0 < 10.0:
+            inst = next((i for i in client.get_instances_status()["instances"]
+                         if i["endpoint"] == eng.endpoint
+                         and i.get("device_frac") == 0.875), None)
+            time.sleep(0.05)
+        assert inst is not None, "the poller never forwarded device_frac"
+        assert inst["occupancy"] == 0.5
+    finally:
+        eng.stop()
+        proc.kill()
+
+
+def test_every_cumulative_key_is_declared_once_and_monotone(tiny):
+    """``CUMULATIVE_KEYS`` is the one declaration: ``counters()`` reports
+    exactly those keys and the landing's clock, the statusz set is built
+    from it, the lint holds them flat, and over a real engine's run no key
+    ever falls (a histogram: no bucket's count)."""
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
+                                    "tools"))
+    try:
+        import check_metric_names
+    finally:
+        sys.path.pop(0)
+    new = {"row_steps_done", "emit_wait_s", "dispatches_emitted",
+           "landing_gap_hist", "stalls", "build_s", *PHASE_KEYS.values()}
+    assert new <= set(CUMULATIVE_KEYS)
+    assert len(set(CUMULATIVE_KEYS)) == len(CUMULATIVE_KEYS)
+    assert set(CUMULATIVE_KEYS) | set(statusz.STREAM_INFO_KEYS) \
+        == statusz.CUMULATIVE_INFO_KEYS
+    assert "last_program_built" not in statusz.CUMULATIVE_INFO_KEYS
+    assert check_metric_names.check_flat_keys() == []
+
+    eng = _mk_engine(tiny, steps_per_dispatch=2)
+    eng.start()
+    samples = [eng.profiler.counters()]
+    try:
+        sp = SamplingParams(temperature=0.0, max_new_tokens=12)
+        for i in range(3):
+            qs = [eng.submit(f"m{i}-{j}", [i + j + 1] * 16, sp)
+                  for j in range(3)]
+            for q in qs:
+                assert len(_drain(q)[0]) == 12
+                samples.append(eng.profiler.counters())
+    finally:
+        eng.stop()
+    samples.append(eng.profiler.counters())
+    for a, b in zip(samples, samples[1:]):
+        assert set(a) == set(CUMULATIVE_KEYS) | {"device_busy_at_s"}
+        for key in a:
+            if key.endswith("_hist"):
+                was, now = dict(a[key]), dict(b[key])
+                assert all(now.get(i, 0) >= n for i, n in was.items()), key
+            else:
+                assert b[key] >= a[key], key
+    last = samples[-1]
+    # three rows decoded together: every landed decode step had 1-3 rows
+    assert last["decode_steps_done"] <= last["row_steps_done"] \
+        <= 3 * last["decode_steps_done"]
+    assert last["dispatches_emitted"] >= last["decode_dispatches"] > 0
+    assert last["emit_wait_s"] >= 0.0 and last["stalls"] == 0
+    assert last["build_s"] > 0.0 and last["programs_built"] > 0
+    assert last["phase_emit_s"] > 0.0
+    assert sum(last[k] for k in PHASE_KEYS.values()) \
+        == pytest.approx(last["loop_wall_s"], abs=1e-4)

@@ -63,11 +63,11 @@ deck/ledger/spill bookkeeping share), ``engine/host_overhead_frac``
 (loop wall outside its two waits) and ``engine/loop_attributed_frac`` —
 riding the flat ``server_info`` fields the manager forwards per
 instance, plus the balancer-side ``pool/balance_device_frac`` windowed
-median. The same fields carry the cumulative completion-stamp counters
-(``decode_steps_done``, ``fused_sample_steps``, ``device_busy_s``,
-``loop_host_s``, ``stream_lag_s``, ``programs_built``, ...:
-``statusz.CUMULATIVE_INFO_KEYS``),
-which the server's time-series feed also lands as ``engine/<key>``.
+median. The same fields carry the cumulative counters that
+``obs/engine_profile.py::CUMULATIVE_KEYS`` declares (with the server's
+stream counters: ``statusz.CUMULATIVE_INFO_KEYS``); the numeric ones the
+server's time-series feed also lands as ``engine/<key>``, so this lint
+holds each declared key to the flat form (:func:`check_flat_keys`).
 The training health
 plane (obs/rlhealth.py) emits ``training/*`` — distribution summaries
 (``training/adv_abs``, ``training/tis_weight``, ``training/staleness``,
@@ -253,6 +253,26 @@ def check_file(path: str) -> list[str]:
     return violations
 
 
+def check_flat_keys() -> list[str]:
+    """The declared cumulative ``server_info`` keys are flat: no ``/`` (the
+    C++ manager's poller indexes them bare and the time-series feed
+    prefixes ``engine/``), lower case, declared once."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from polyrl_tpu.obs.statusz import CUMULATIVE_INFO_KEYS, STREAM_INFO_KEYS
+    from polyrl_tpu.obs.engine_profile import CUMULATIVE_KEYS
+
+    declared = CUMULATIVE_KEYS + STREAM_INFO_KEYS
+    violations = [f"cumulative server_info key {k!r} is not flat "
+                  f"([a-z0-9_]+)" for k in sorted(CUMULATIVE_INFO_KEYS)
+                  if not re.fullmatch(r"[a-z0-9_]+", k)]
+    violations += [f"cumulative server_info key {k!r} is declared twice"
+                   for k in sorted(set(declared))
+                   if declared.count(k) > 1]
+    return violations
+
+
 def check_tree(roots: list[str]) -> list[str]:
     violations: list[str] = []
     for root in roots:
@@ -276,7 +296,7 @@ def default_roots() -> list[str]:
 
 def main(argv: list[str] | None = None) -> int:
     roots = (argv if argv else default_roots())
-    violations = check_tree(roots)
+    violations = check_tree(roots) + check_flat_keys()
     for v in violations:
         print(v)
     if violations:

@@ -31,6 +31,13 @@ import os
 import sys
 import urllib.request
 
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if _REPO not in sys.path:
+    sys.path.insert(0, _REPO)
+
+from polyrl_tpu.obs.engine_profile import CUMULATIVE_KEYS  # noqa: E402
+from polyrl_tpu.obs.histogram import bucket_edge  # noqa: E402
+
 _HIST_COLS = ("p50", "p95", "p99", "max", "mean", "count")
 _BAR_WIDTH = 60
 # phase → bar glyph, in display order (matches engine_profile.PHASES)
@@ -58,6 +65,17 @@ def _fmt(v) -> str:
             return f"{v:.3g}"
         return f"{v:.4f}".rstrip("0").rstrip(".")
     return str(v)
+
+
+def _counter(v) -> str:
+    """A cumulative value; of a ``*_hist`` key (``[[bucket, count], ...]``)
+    its count and the upper edge of its highest bucket."""
+    if isinstance(v, list):
+        if not v:
+            return "0"
+        return (f"{sum(n for _i, n in v)} "
+                f"<={_fmt(bucket_edge(v[-1][0] + 1))}")
+    return _fmt(v)
 
 
 def load(target: str) -> tuple[dict, dict]:
@@ -165,6 +183,13 @@ def _render_engine(loop: dict) -> list[str]:
             f"({_fmt(1e3 * c.get('device_busy_s', 0.0) / steps)} ms a step); "
             f"loop host {_fmt(c.get('loop_host_s'))} s of "
             f"{_fmt(c.get('loop_wall_s'))} s wall")
+    # every cumulative key the profiler declares, as the block carries it
+    shown = [k for k in CUMULATIVE_KEYS if k in c]
+    if shown:
+        out.append("")
+        out.append(f"{'cumulative counter':<28} {'value':>14}")
+        for key in shown:
+            out.append(f"{key:<28} {_counter(c[key]):>14}")
     for b in loop.get("builds", []):
         out.append(f"built {b.get('kind')} {b.get('key')}: "
                    f"{_fmt(b.get('seconds'))} s")
