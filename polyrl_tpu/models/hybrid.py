@@ -757,11 +757,24 @@ def load_width(cfg) -> int:
     return 6
 
 
+def kda_in_kernel(cfg) -> bool:
+    """Whether a decode step of this model updates its KDA states in the
+    one-pass kernel (``ops/kda_state.py``), from what its program is built
+    on: a KDA layer in the plan, the state's shape and dtype, the
+    backend."""
+    from polyrl_tpu.ops import kda_state
+
+    return (any(p.mixer == "kda" for p in cache_spec.layer_plan(cfg))
+            and kda_state.in_kernel((0, *cache_spec.kda_dims(cfg)),
+                                    cache_spec.STATE_DTYPE))
+
+
 def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
                  active=None, head_fn=None):
     """``decoder.forward_paged_decode`` for a model of several kinds of
     layer: one token a slot. A row without a request leaves its state
     rows as they are and writes its latent row to the null page."""
+    from polyrl_tpu.ops.kda_state import kda_state_update
     from polyrl_tpu.ops.mla_attention import latent_paged_attention
 
     layers = params["layers"]
@@ -796,12 +809,7 @@ def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
                 conv = _set_rows(conv, jnp.where(
                     live[:, None, None], window[:, 1:], conv[:s]))
             with jax.named_scope("kda_core"):
-                old = st[:s]
-                s1, o = kda_recurrent_step(old.astype(jnp.float32), q, k, v,
-                                           g, beta)
-                s1 = jnp.where(live[:, None, None, None], s1.astype(st.dtype),
-                               old)
-                st = _set_rows(st, s1)
+                st, o = kda_state_update(st, q, k, v, g, beta, live)
             with jax.named_scope("kda_proj"):
                 out = _kda_out(cfg, mixer_lp, h_in, o)
             state[i] = (st, conv)

@@ -78,6 +78,8 @@ emission or iteration, never once a token, all on this profiler's clock:
   the engine's landings);
   ``fused_sample_steps`` — the steps whose program drew its tokens
   inside the output matmul (``decoder.head_and_sample``);
+  ``kda_kernel_steps`` — the steps whose program updated its KDA states
+  in the one-pass kernel (``ops/kda_state.py``);
   ``decode_dispatches_cold`` — those of the dispatches enqueued with
   NOTHING outstanding (the device had run dry: an engine that keeps its
   run-ahead does it once a burst, one that drains before every dispatch
@@ -159,7 +161,8 @@ STALL_GAP_S = 2.0
 CUMULATIVE_KEYS = (
     "decode_dispatches", "decode_dispatches_cold", "admission_deferrals",
     "pages_grown", "slot_yields", "decode_steps_done", "fused_sample_steps",
-    "row_steps_done", "device_busy_s", "loop_wall_s", "loop_host_s",
+    "kda_kernel_steps", "row_steps_done", "device_busy_s", "loop_wall_s",
+    "loop_host_s",
     *PHASE_KEYS.values(), "emit_wait_s", "dispatches_emitted",
     "landing_gap_hist", "stalls", "programs_built", "build_s")
 
@@ -198,7 +201,8 @@ class EngineLoopProfiler:
         self._win_busy_mark = 0.0  # device_busy_s at the last iteration close
         # completion stamps: dispatches whose results will land, oldest
         # first, as (their fused decode steps (0 for a prefill), whether
-        # those sampled inside the head, their live rows)
+        # those sampled inside the head, their live rows, whether their
+        # KDA layers took the kernel)
         self._landing: collections.deque = collections.deque()
         self._tail_unlanded = False  # dispatched after them, lands nothing
         self._busy_from: float | None = None  # busy not yet counted, since
@@ -336,10 +340,12 @@ class EngineLoopProfiler:
     # -- completion stamps ----------------------------------------------------
 
     def on_dispatch(self, kind: str, steps: int = 0, lands: bool = True,
-                    fused_sample: bool = False, rows: int = 0) -> None:
+                    fused_sample: bool = False, rows: int = 0,
+                    kda_kernel: bool = False) -> None:
         """A dispatch was just enqueued on the device; ``steps``: the
         decode steps it fuses, over ``rows`` live rows, ``fused_sample``:
-        they sample inside the head. ``lands`` False: it returns nothing
+        they sample inside the head, ``kda_kernel``: their KDA layers
+        update the state in the kernel. ``lands`` False: it returns nothing
         the host fetches (a chunked prefill's mid-chunk),
         so a later dispatch's landing stands for it."""
         now = self._clock()
@@ -353,7 +359,7 @@ class EngineLoopProfiler:
             if cold:
                 self._busy_from = now
             if lands:
-                self._landing.append((steps, fused_sample, rows))
+                self._landing.append((steps, fused_sample, rows, kda_kernel))
                 self._tail_unlanded = False
             else:
                 self._tail_unlanded = True
@@ -384,11 +390,14 @@ class EngineLoopProfiler:
         with self._lock:
             c = self._cum
             for _ in range(min(n, len(self._landing))):
-                steps, fused_sample, rows = self._landing.popleft()
+                steps, fused_sample, rows, kda_kernel = (
+                    self._landing.popleft())
                 c["decode_steps_done"] += steps
                 c["row_steps_done"] += steps * rows
                 if fused_sample:
                     c["fused_sample_steps"] += steps
+                if kda_kernel:
+                    c["kda_kernel_steps"] += steps
                 self._landed_at.append(now)
             gap = None if self._gap_from is None else now - self._gap_from
             if gap is not None:

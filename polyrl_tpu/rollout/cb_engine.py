@@ -285,6 +285,10 @@ class CBEngine:
                     "a model with a recurrent state on a mesh of several "
                     "chips")
             enable_prefix_cache = False
+        # whether a decode step's KDA layers update their states in the
+        # one-pass kernel (the profiler's ``kda_kernel_steps``): one answer
+        # for the engine's life
+        self._kda_kernel = self.stateful and hybrid.kda_in_kernel(cfg)
         # and which features that act on pages have a kernel for every
         # mixer of the plan? What needs none (prefix cache, a group's
         # shared prompt, salvage, the ledger, growth and yield) runs on
@@ -2771,7 +2775,8 @@ class CBEngine:
             decode = kind in ("step", "spec")
             self.profiler.on_dispatch(
                 kind, entry[3] if decode else 0, fused_sample=fused_sample,
-                rows=len(entry[2]) if decode else 0)
+                rows=len(entry[2]) if decode else 0,
+                kda_kernel=decode and self._kda_kernel)
         self._last_two.append(entry[1])
         with self._fetch_cv:
             self._emit_q.append(entry)

@@ -174,6 +174,32 @@ def test_window_flip_and_device_host_split():
     assert prof.counters()["decode_steps_done"] == 16
 
 
+def test_kda_kernel_steps_move_at_the_landing_of_a_kernel_dispatch():
+    """``kda_kernel_steps`` is declared with the other cumulative keys
+    and moves once a landing, by the steps of a dispatch whose program's
+    KDA layers took the kernel; a dispatch whose program took the oracle
+    moves ``decode_steps_done`` alone."""
+    from polyrl_tpu.obs.engine_profile import CUMULATIVE_KEYS
+
+    assert "kda_kernel_steps" in CUMULATIVE_KEYS
+    clock = _FakeClock()
+    prof = EngineLoopProfiler(clock=clock)
+    assert prof.counters()["kda_kernel_steps"] == 0
+    prof.on_dispatch("step", steps=8, rows=128, kda_kernel=True)
+    prof.on_dispatch("step", steps=8, rows=128)
+    clock.advance(0.2)
+    assert prof.counters()["kda_kernel_steps"] == 0
+    prof.on_landed(1)
+    assert prof.counters()["kda_kernel_steps"] == 8
+    assert prof.counters()["decode_steps_done"] == 8
+    clock.advance(0.2)
+    prof.on_landed(1)                      # the oracle's dispatch
+    assert prof.counters()["kda_kernel_steps"] == 8
+    assert prof.counters()["decode_steps_done"] == 16
+    assert prof.counters()["fused_sample_steps"] == 0
+    assert prof.server_info_fields()["kda_kernel_steps"] == 8
+
+
 def test_phase_taxonomy_and_fetch_counters():
     """The taxonomy is closed (wait/accounting subsets of PHASES, other
     last) and the fetcher's transfers are counted beside the partition:

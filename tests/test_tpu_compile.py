@@ -10,6 +10,7 @@ test file), and all such tests live in this one file."""
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -383,6 +384,31 @@ def test_latent_attention_kernel_compiles_for_v5e(one_chip, s, h, n_pages,
     assert nbuf * b * 64 * 640 * 2 < 8 * 2**20
 
 
+# (slots, rows, heads): Ling's cell (a row's 32 heads are one block of
+# 2 MiB) and a wider model's (64 heads in two blocks, a stack longer than
+# the step)
+@pytest.mark.parametrize("slots,s,h", [(129, 129, 32), (40, 33, 64)])
+def test_kda_state_kernel_compiles_for_v5e(one_chip, slots, s, h):
+    """The one-pass KDA state update at head sizes of 128: Mosaic takes
+    its transposes of the ``[heads, 128]`` blocks of ``k``, ``q`` and
+    ``exp(g)``, the lane broadcasts of their columns and its four 2 MiB
+    buffers inside the VMEM it asks for, and the state stack
+    is the call's input and output: aliased whole, nothing of its size
+    among the temporaries."""
+    from polyrl_tpu.ops import kda_state
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    compiled = jax.jit(kda_state.kda_state_pallas, donate_argnums=(0,)).lower(
+        arg(slots, h, 128, 128), arg(s, h, 128), arg(s, h, 128),
+        arg(s, h, 128), arg(s, h, 128), arg(s, h)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == slots * h * 128 * 128 * 4
+    assert mem.temp_size_in_bytes < 2**20 + 5 * s * h * 128 * 4
+
+
 def test_ling_decode_step_compiles_for_v5e_within_memory(one_chip,
                                                          chip_precision,
                                                          on_tpu):
@@ -390,7 +416,9 @@ def test_ling_decode_step_compiles_for_v5e_within_memory(one_chip,
     at 129 rows, the state slots and the latent pool donated, the token
     drawn inside the head. Everything it holds at once fits a 16 GB chip
     with a gigabyte to spare, and the states are updated in place (no
-    copy of a 270 MB state array among the temporaries)."""
+    copy of a 270 MB state array among the temporaries: the six KDA
+    layers' kernel takes the stack as input and output, and no fusion,
+    copy or select over a whole stack is left in the program)."""
     from polyrl_tpu.models import decoder
 
     cfg = decoder.get_config("ling-3.0-flash-share4")
@@ -432,5 +460,11 @@ def test_ling_decode_step_compiles_for_v5e_within_memory(one_chip,
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert live < 15 * 10**9
     assert m.temp_size_in_bytes < 200 * 2**20
-    # the latent attention, six grouped gate/up and six down matmuls, the head
-    assert compiled.as_text().count("tpu_custom_call") >= 14
+    # the latent attention, six grouped gate/up and six down matmuls, the
+    # head, six state updates
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 20
+    made = [line.split(" = ", 1)[1] for line in text.splitlines()
+            if " = " in line]
+    assert not [op for op in made if re.match(
+        r"f32\[129,32,128,128\]\S* (copy|fusion|select)\(", op)]

@@ -228,3 +228,12 @@ def test_each_pallas_call_we_own_has_a_name():
         lambda *a: pa.grouped_paged_attention_pallas(*a, interpret=True),
         q, pool, pool, table, lens + ps, g_slots, g_pages, g_lens) == [
             "grouped_prefix", "grouped_suffix"]
+    from polyrl_tpu.ops import kda_state
+
+    # the event ``kda_state`` in a device trace is what says the one-pass
+    # state update ran (it notes no key in ops/dispatch.py)
+    rows = jnp.zeros((s, 4, d), jnp.float32)
+    assert names(
+        lambda *a: kda_state.kda_state_pallas(*a, interpret=True),
+        jnp.zeros((3, 4, d, d), jnp.float32), rows, rows, rows, rows,
+        jnp.zeros((s, 4), jnp.float32)) == ["kda_state"]
