@@ -1,6 +1,7 @@
 """The blocks that every decoder of this package is built from, whatever
-the kinds of its layers: the RMS norm, the routed mixture MLP with its two
-routers (``_moe_mlp``), the output head, and the row scatter of one token
+the kinds of its layers: the RMS norm, the routed mixture MLP with its three
+routers (``_moe_mlp``: softmax top-k, sigmoid ``noaux_tc``, and an MLP on
+a latent that layers hand on, ``_latent_route``), the output head, and the row scatter of one token
 into a paged pool. ``models/decoder.py`` (the uniform stacked-scan
 decoder) and ``models/hybrid.py`` (layers of several kinds) both import
 them from here, and neither imports the other's blocks; ``decoder``
@@ -160,8 +161,33 @@ def _sigmoid_route(cfg, x: jnp.ndarray, lp: dict):
     return top_s * cfg.routed_scaling_factor, top_i
 
 
+def _latent_route(cfg, x: jnp.ndarray, lp: dict, carried: jnp.ndarray):
+    """ZAYA1's router on ``x`` [N, d]: a latent ``s = x Wd + gamma *
+    carried`` [N, R] float32, ``carried`` being the layer before's latent
+    of the same token (zeros in the first layer); probabilities ``p =
+    softmax(gelu(gelu(rms(s) W1) W2) W3)`` over all experts in float32; the
+    choice is made on ``p + bias`` (a balancing bias, as ``noaux_tc``'s),
+    the weights are the chosen ``p`` as they are. The three small matmuls
+    run at the highest precision: with one choice a token, a flipped
+    choice is a whole expert's difference. Returns (weights [N, k]
+    float32, experts [N, k], the latent to hand on)."""
+    f32, hi = jnp.float32, jax.lax.Precision.HIGHEST
+    s = jnp.dot(x, lp["router_down"], preferred_element_type=f32)
+    s = s + lp["router_gamma"].astype(f32) * carried
+    z = rms_norm(s, lp["router_norm"], cfg.rms_norm_eps)
+    for w in (lp["router_w1"], lp["router_w2"]):
+        z = jax.nn.gelu(jnp.dot(z, w.astype(f32), precision=hi),
+                        approximate=False)
+    probs = jax.nn.softmax(
+        jnp.dot(z, lp["router"].astype(f32), precision=hi), axis=-1)
+    top_i = jax.lax.top_k(probs + lp["router_bias"].astype(f32),
+                          cfg.num_experts_per_tok)[1]
+    return jnp.take_along_axis(probs, top_i, axis=-1), top_i, s
+
+
 def _moe_mlp(cfg, x: jnp.ndarray, lp: dict,
-             valid: jnp.ndarray | None = None, layer: int | None = None
+             valid: jnp.ndarray | None = None, layer: int | None = None,
+             route: tuple | None = None
              ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Routed mixture MLP on flattened tokens ``x`` [N, d] -> [N, d],
     dropless, with static shapes.
@@ -181,13 +207,19 @@ def _moe_mlp(cfg, x: jnp.ndarray, lp: dict,
     ``layer``: ``lp``'s experts are whole stacks, and that layer of them
     is meant (``_unrolled_layer``).
 
+    ``route``: (weights [N, k] float32, experts [N, k]) where the caller
+    has routed already: a router with a latent carried from layer to layer
+    (``_latent_route``) is the layer loop's to thread.
+
     Also returns the step's load, int32 [3]: (token, expert) pairs routed,
     experts with at least one row, rows of the busiest expert."""
     n, d = x.shape
     k = cfg.num_experts_per_tok
     first, e = cache_spec.experts_held(cfg)
     with jax.named_scope("moe_route"):
-        if cfg.scoring_func == "sigmoid":
+        if route is not None:
+            top_p, top_i = route
+        elif cfg.scoring_func == "sigmoid":
             top_p, top_i = _sigmoid_route(cfg, x, lp)
         else:
             probs = jax.nn.softmax(mm(x, lp["router"]).astype(jnp.float32),
@@ -266,4 +298,17 @@ def _scatter_token_kv(pool, write_page, write_off, upd):
     idx = (head_off + (write_page * ps + write_off)[None, :]).reshape(-1)
     flat = flat.at[idx].set(
         upd.transpose(1, 0, 2).reshape(hkv * s, d).astype(pool.dtype))
+    return flat.reshape(hkv, n, ps, d)
+
+
+def _scatter_pages_kv(pool, page_ids, upd):
+    """Scatter whole pages into ``pool`` [Hkv, N, ps, D]; ``upd`` is
+    [Hkv, n_pg, ps, D]. Same flat-row trick as ``_scatter_token_kv``
+    ([Hkv·N, ps·D] rows) to keep the pool in standard layout."""
+    hkv, n, ps, d = pool.shape
+    npg = page_ids.shape[0]
+    flat = pool.reshape(hkv * n, ps * d)
+    idx = (jnp.arange(hkv, dtype=jnp.int32)[:, None] * n
+           + page_ids[None, :].astype(jnp.int32)).reshape(-1)
+    flat = flat.at[idx].set(upd.reshape(hkv * npg, ps * d).astype(pool.dtype))
     return flat.reshape(hkv, n, ps, d)

@@ -3,30 +3,44 @@ runs and what a sequence keeps for that layer between steps.
 
 A mixer is ``gqa`` (grouped-query softmax attention with RoPE), ``kda``
 (Kimi Delta Attention: a recurrent float32 state a head plus the tails of
-three short convolutions) or ``mla`` (multi-head latent attention: one
-normed latent row and one shared rope key a token). An MLP is ``dense``
+three short convolutions), ``mla`` (multi-head latent attention: one
+normed latent row and one shared rope key a token) or ``cca`` (compressed
+convolutional attention: grouped-query attention over query and key
+latents that two short causal convolutions mix, half the value heads
+shifted by one token). An MLP is ``dense``
 (SwiGLU) or ``moe`` (routed experts, ``decoder._moe_mlp``). Every preset
 from before the hybrid family is the uniform pattern: ``gqa`` in every
 layer, with the same MLP in every layer. The DeepSeek-V3 family
 (``kv_lora_rank`` without a ``layer_group_size``) is ``mla`` in every
-layer behind leading dense layers.
+layer behind leading dense layers. ``cca_time0`` > 0 is ``cca`` in every
+layer (ZAYA1's decoder).
 
-What a sequence keeps is either PAGED (so many values a token, in pages
+What a sequence keeps is PAGED (so many values a token, in pages
 that the engine's allocator hands out: a K/V pair of ``[Hkv, N, page, D]``
 for ``gqa``, one latent pool ``[1, N, page, row]`` for ``mla``, ``row`` being ``rank +
-rope`` rounded up to whole lanes) or
-a SLOT (fixed-size arrays indexed by the engine's slot, for ``kda``).
+rope`` rounded up to whole lanes), or
+a SLOT (fixed-size arrays indexed by the engine's slot, for ``kda``), or,
+for ``cca``, BOTH in one layer (``PagedAndSlot``): the finished keys and
+the (shifted) values of every token as a K/V pair in pages, and in the
+slot what the next token's convolutions and value shift read of the
+tokens before it (the pre-convolution latents' tail, the first
+convolution's output's tail, the last token's second value half).
 
 ``CBEngine`` asks two questions of a model's layers (ARCHITECTURE.md,
 "Cache specification"). Does a sequence keep anything outside pages
 (``is_stateful``)? A state in a slot has no page boundary to snapshot at,
 so the engine then turns off every feature that re-enters a sequence
-anywhere but at its last token. And which features that act on pages have
+anywhere but at its last token; that holds for ``cca``'s tails as for a
+``kda`` state, so a ``cca`` model has no prefix cache, no shared prompt in
+a group and no salvage, and a yielded row prefills anew from token 0,
+which recomputes the tails with the pages. And which features that act on pages have
 a kernel for every mixer of the plan (``without_kernel``)? What acts on
 pages alone (prefix cache, a group's shared prompt, salvage, the ledger,
 page growth and yield) runs on any paged pool; the grouped two-phase
 decode kernel, speculation's multi-token verify and the spill tier's page
-copies are written for a K/V pair.
+copies are written for a K/V pair without tails: ``without_kernel`` names
+``cca`` for all three (its one-token decode runs the ``gqa`` kernels,
+``ops.paged_attention``'s write and attention, on its K/V pair).
 
 Readers: ``decoder.make_paged_pools`` and ``CBEngine._make_pools`` (the
 arrays; the page ledger takes its bytes a page from the paged ones),
@@ -43,7 +57,7 @@ import jax.numpy as jnp
 
 @dataclasses.dataclass(frozen=True)
 class LayerPlan:
-    mixer: str        # "gqa" | "kda" | "mla"
+    mixer: str        # "gqa" | "kda" | "mla" | "cca"
     mlp: str          # "dense" | "moe"
     published: int    # the layer's index in the published model
 
@@ -74,6 +88,21 @@ class Slot:
         return total
 
 
+@dataclasses.dataclass(frozen=True)
+class PagedAndSlot:
+    """A layer that keeps both: pages a token and arrays a slot."""
+    paged: Paged
+    slot: Slot
+
+
+def paged_part(c) -> Paged | None:
+    return c if isinstance(c, Paged) else getattr(c, "paged", None)
+
+
+def slot_part(c) -> Slot | None:
+    return c if isinstance(c, Slot) else getattr(c, "slot", None)
+
+
 # the type the recurrent state is kept in (Ling's config: float32). A
 # module constant and no option: the benchmark's control of ``correct``
 # computes the reference with a bfloat16 state, not the program.
@@ -96,6 +125,8 @@ def layer_plan(cfg) -> tuple[LayerPlan, ...]:
     for i in kept:
         if cfg.layer_group_size:
             mixer = "mla" if (i + 1) % cfg.layer_group_size == 0 else "kda"
+        elif cfg.cca_time0:
+            mixer = "cca"
         else:
             mixer = "mla" if cfg.kv_lora_rank else "gqa"
         sparse = bool(cfg.num_experts) and i >= cfg.first_k_dense_replace
@@ -105,8 +136,9 @@ def layer_plan(cfg) -> tuple[LayerPlan, ...]:
 
 def is_uniform(cfg) -> bool:
     """Every layer alike and ``gqa``: the stacked-scan decoder."""
-    return not cfg.layer_group_size and not cfg.kv_lora_rank and not (
-        cfg.num_experts and cfg.first_k_dense_replace)
+    return (not cfg.layer_group_size and not cfg.kv_lora_rank
+            and not cfg.cca_time0
+            and not (cfg.num_experts and cfg.first_k_dense_replace))
 
 
 def experts_held(cfg) -> tuple[int, int]:
@@ -138,12 +170,28 @@ def latent_row(cfg) -> int:
     return -(-latent_width(cfg) // LANES) * LANES
 
 
-def layer_cache(cfg, plan: LayerPlan, dtype=None) -> Paged | Slot:
+def cca_dims(cfg) -> tuple[int, int, int]:
+    """(query heads, key/value heads, head size) of a CCA layer: the query
+    latent is ``Hq * D`` wide, the key latent ``Hkv * D``, a value half
+    ``Hkv * D / 2``."""
+    return cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+
+
+def layer_cache(cfg, plan: LayerPlan, dtype=None
+                ) -> Paged | Slot | PagedAndSlot:
     dtype = dtype or cfg.dtype
     if plan.mixer == "gqa":
         return Paged(2, cfg.num_kv_heads, cfg.head_dim_)
     if plan.mixer == "mla":
         return Paged(1, 1, latent_row(cfg))
+    if plan.mixer == "cca":
+        hq, hkv, d = cca_dims(cfg)
+        mixed = (hq + hkv) * d
+        return PagedAndSlot(
+            Paged(2, hkv, d),
+            Slot((("latent", (cfg.cca_time0 - 1, mixed), dtype),
+                  ("mixed", (cfg.cca_time1 - 1, mixed), dtype),
+                  ("value", (hkv * d // 2,), dtype))))
     h, dk, dv = kda_dims(cfg)
     k = cfg.short_conv_kernel_size
     return Slot((("state", (h, dk, dv), STATE_DTYPE),
@@ -156,11 +204,13 @@ def cache_spec(cfg, dtype=None) -> tuple:
 
 def is_stateful(cfg) -> bool:
     """Some layer keeps a state that is not paged."""
-    return any(isinstance(c, Slot) for c in cache_spec(cfg))
+    return any(slot_part(c) is not None for c in cache_spec(cfg))
 
 
 # features of the engine that act on pages through a kernel (or a copy)
 # written for one kind of paged cache, and the mixers that have it
+# (``cca`` keeps a K/V pair too, and has none of the three: each re-enters
+# a sequence where its tails are not)
 FEATURE_KERNELS = {
     "decode_group_share": ("gqa",),   # ops.paged_attention's grouped kernel
     "spec_tokens": ("gqa",),          # the multi-token verify forward
@@ -177,14 +227,26 @@ def without_kernel(cfg, feature: str) -> tuple[str, ...]:
 
 def paged_bytes_per_token(cfg, dtype=None) -> int:
     item = jnp.dtype(dtype or cfg.dtype).itemsize
-    return sum(c.values_per_token() * item for c in cache_spec(cfg, dtype)
-               if isinstance(c, Paged))
+    return sum(paged_part(c).values_per_token() * item
+               for c in cache_spec(cfg, dtype) if paged_part(c) is not None)
 
 
 def slot_bytes(cfg, dtype=None) -> int:
     """Bytes one slot's state takes, all layers."""
-    return sum(c.bytes_per_slot() for c in cache_spec(cfg, dtype)
-               if isinstance(c, Slot))
+    return sum(slot_part(c).bytes_per_slot()
+               for c in cache_spec(cfg, dtype) if slot_part(c) is not None)
+
+
+def pool_index(cfg) -> tuple[tuple[int | None, int | None], ...]:
+    """For each layer: (its place among the layers that keep pages, its
+    place among the layers that keep a slot), None where it keeps none:
+    the indices into ``make_pools``' two tuples."""
+    out, n_paged, n_slot = [], 0, 0
+    for c in cache_spec(cfg):
+        has_p, has_s = paged_part(c) is not None, slot_part(c) is not None
+        out.append((n_paged if has_p else None, n_slot if has_s else None))
+        n_paged, n_slot = n_paged + has_p, n_slot + has_s
+    return tuple(out)
 
 
 def make_pools(cfg, num_pages: int, page_size: int, slots: int = 0,
@@ -192,10 +254,14 @@ def make_pools(cfg, num_pages: int, page_size: int, slots: int = 0,
     """The arrays. For the uniform ``gqa`` pattern ``(k, v)``, each a
     per-layer tuple of ``[Hkv, num_pages, page_size, D]`` (page 0 is the
     null page). For any other pattern ``(paged, state)``: ``paged`` a tuple
-    with one ``[1, num_pages, page_size, width]`` latent pool for each
-    ``mla`` layer in order, ``state`` a tuple with one ``(state [slots, H,
-    Dk, Dv] float32, conv [slots, K-1, channels])`` pair for each ``kda``
-    layer in order (none for a model that is ``mla`` in every layer). The
+    with one entry for each layer that keeps pages, in order (a ``[1,
+    num_pages, page_size, width]`` latent pool for ``mla``, a ``(k, v)``
+    pair of ``[Hkv, num_pages, page_size, D]`` for ``cca``), ``state`` a
+    tuple with one tuple of ``[slots, *shape]`` arrays for each layer that
+    keeps a slot, in order (``(state [slots, H, Dk, Dv] float32, conv
+    [slots, K-1, channels])`` for ``kda``; the three tails for ``cca``;
+    none for a model that is ``mla`` in every layer). A ``cca`` layer has
+    an entry in both (``pool_index``). The
     engine hands ``slots = max_slots + 1``: the last row is the sink that padding rows of an admission wave write to."""
     dtype = dtype or cfg.dtype
     spec = cache_spec(cfg, dtype)
@@ -205,13 +271,13 @@ def make_pools(cfg, num_pages: int, page_size: int, slots: int = 0,
                 tuple(jnp.zeros(shape, dtype) for _ in spec))
     paged, state = [], []
     for c in spec:
-        if isinstance(c, Paged):
-            if c.arrays != 1:
-                raise NotImplementedError(
-                    "a K/V pair beside a recurrent state: no such model yet")
-            paged.append(jnp.zeros((c.heads, num_pages, page_size, c.width),
-                                   dtype))
-        else:
+        pages, slot = paged_part(c), slot_part(c)
+        if pages is not None:
+            pool = [jnp.zeros((pages.heads, num_pages, page_size,
+                               pages.width), dtype)
+                    for _ in range(pages.arrays)]
+            paged.append(pool[0] if pages.arrays == 1 else tuple(pool))
+        if slot is not None:
             state.append(tuple(jnp.zeros((slots, *shape), dt)
-                               for _name, shape, dt in c.arrays))
+                               for _name, shape, dt in slot.arrays))
     return tuple(paged), tuple(state)

@@ -36,7 +36,8 @@ from jax.sharding import PartitionSpec as P
 
 from polyrl_tpu.models import cache_spec, hybrid
 from polyrl_tpu.models.blocks import (EXPERT_KEYS, _head, _moe_mlp,  # noqa: F401
-                                      _scatter_token_kv, rms_norm)
+                                      _scatter_pages_kv, _scatter_token_kv,
+                                      rms_norm)
 from polyrl_tpu.models.quant import LoraWeight, QuantWeight, mm
 from polyrl_tpu.ops.attention import attention, causal_mask
 from polyrl_tpu.parallel.mesh import DP, EP, FSDP, SP, TP
@@ -123,6 +124,17 @@ class ModelConfig:
     v_head_dim: int = 0
     short_conv_kernel_size: int = 0
     kda_lower_bound: float = -5.0
+    # compressed convolutional attention (ZAYA1's decoder, ``models/
+    # hybrid.py``): ``cca_time0`` > 0 makes every layer ``cca``: the taps of
+    # the depthwise convolution over the query and key latents and of the
+    # head-wise one after it; rope turns the first ``partial_rotary_factor``
+    # of a head's columns (read by ``cca`` layers alone)
+    cca_time0: int = 0
+    cca_time1: int = 0
+    partial_rotary_factor: float = 1.0
+    # > 0: the routed MLP's router is an MLP on a latent of this width that
+    # each layer hands to the next (``blocks._latent_route``)
+    router_hidden_size: int = 0
     dtype: Any = jnp.bfloat16
 
     @property
@@ -337,6 +349,36 @@ PRESETS["mla-moe-tiny"] = ModelConfig(
     routed_scaling_factor=2.5, moe_shared_expert_intermediate_size=32,
     experts_held=(0, 4), first_k_dense_replace=1, kv_lora_rank=32,
     q_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+)
+
+
+# ZAYA1-8B (HF config: Zyphra/ZAYA1-8B, model_type zaya): every layer a
+# compressed-convolutional-attention sublayer (8 query heads over 2 K/V
+# heads of 128 in a latent of half the hidden size) and a top-1 routed MLP
+# of 16 whole-width experts behind a router MLP whose 256-wide latent is
+# carried from layer to layer; both sublayers' residuals scaled; a tied
+# head over 262,272 rows. ``intermediate_size`` is unused: no dense MLP.
+PRESETS["zaya1-8b"] = ModelConfig(
+    vocab_size=262272, hidden_size=2048, intermediate_size=2048,
+    num_layers=40, num_heads=8, num_kv_heads=2, head_dim=128,
+    rope_theta=5000000.0, rms_norm_eps=1e-5, tie_word_embeddings=True,
+    max_position_embeddings=131072, partial_rotary_factor=0.5,
+    num_experts=16, num_experts_per_tok=1, moe_intermediate_size=2048,
+    norm_topk_prob=False, cca_time0=2, cca_time1=2, router_hidden_size=256,
+)
+# published layers 0-11, every expert and the whole vocabulary: the first
+# of the pipeline stages one chip holds (benchmark/configs/zaya1-8b.json)
+PRESETS["zaya1-8b-depth12"] = dataclasses.replace(
+    PRESETS["zaya1-8b"], num_layers=12, kept_layers=tuple(range(12)))
+# test-size model of the same family: 3 layers, 4 query heads over 2 K/V
+# heads of 16, 4 experts at top-1, a router latent of 8
+PRESETS["cca-tiny"] = ModelConfig(
+    vocab_size=512, hidden_size=64, intermediate_size=64, num_layers=3,
+    num_heads=4, num_kv_heads=2, head_dim=16, rope_theta=10000.0,
+    rms_norm_eps=1e-5, tie_word_embeddings=True,
+    max_position_embeddings=512, partial_rotary_factor=0.5,
+    num_experts=4, num_experts_per_tok=1, moe_intermediate_size=32,
+    norm_topk_prob=False, cca_time0=2, cca_time1=2, router_hidden_size=8,
 )
 
 
@@ -763,19 +805,6 @@ def make_paged_pools(cfg: ModelConfig, num_pages: int, page_size: int,
     their garbage KV there so every decode step has uniform static shapes
     (the TPU answer to SGLang's paged allocator, SURVEY.md §2.2 row 1)."""
     return cache_spec.make_pools(cfg, num_pages, page_size, slots, dtype)
-
-
-def _scatter_pages_kv(pool, page_ids, upd):
-    """Scatter whole pages into ``pool`` [Hkv, N, ps, D]; ``upd`` is
-    [Hkv, n_pg, ps, D]. Same flat-row trick as ``_scatter_token_kv``
-    ([Hkv·N, ps·D] rows) to keep the pool in standard layout."""
-    hkv, n, ps, d = pool.shape
-    npg = page_ids.shape[0]
-    flat = pool.reshape(hkv * n, ps * d)
-    idx = (jnp.arange(hkv, dtype=jnp.int32)[:, None] * n
-           + page_ids[None, :].astype(jnp.int32)).reshape(-1)
-    flat = flat.at[idx].set(upd.reshape(hkv * npg, ps * d).astype(pool.dtype))
-    return flat.reshape(hkv, n, ps, d)
 
 
 def forward_paged_decode(

@@ -107,11 +107,45 @@ def rope_scaling_from_hf(rs: dict | None) -> decoder.RopeScaling | None:
     return None
 
 
+def zaya_config(hf: dict, dtype=jnp.bfloat16) -> decoder.ModelConfig:
+    """A ``ModelConfig`` from a ``zaya`` config.json (Zyphra/ZAYA1-8B):
+    every key the published file has that the decoder reads. A sliding
+    window (ZAYA1-74B's ``hybrid_sliding`` layers) is not supported. The
+    checkpoint's tensors have no key map yet (``load_hf_params`` says
+    so)."""
+    if hf.get("sliding_window") or set(hf.get("layer_types", ())) - {"hybrid"}:
+        raise NotImplementedError(
+            "zaya layers with a sliding window (layer_types other than "
+            "'hybrid'): only full CCA attention is written")
+    rope = (hf.get("rope_parameters") or {}).get("hybrid") or {}
+    return decoder.ModelConfig(
+        vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+        intermediate_size=hf["moe_intermediate_size"],
+        num_layers=hf["num_hidden_layers"],
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"], head_dim=hf["head_dim"],
+        rope_theta=float(rope.get("rope_theta", 10000.0)),
+        partial_rotary_factor=float(rope.get(
+            "partial_rotary_factor", hf.get("partial_rotary_factor", 1.0))),
+        rms_norm_eps=float(hf["rms_norm_eps"]),
+        tie_word_embeddings=bool(hf.get("tie_word_embeddings", True)),
+        attention_bias=bool(hf.get("attention_bias", False)),
+        max_position_embeddings=hf["max_position_embeddings"],
+        num_experts=hf["num_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        norm_topk_prob=False, cca_time0=hf["cca_time0"],
+        cca_time1=hf["cca_time1"],
+        router_hidden_size=hf["router_hidden_size"], dtype=dtype)
+
+
 def config_from_hf(ckpt_dir: str, dtype=jnp.bfloat16) -> decoder.ModelConfig:
     """Build a ModelConfig from the checkpoint's config.json (llama/qwen2/
-    qwen3 architectures)."""
+    qwen3 architectures; ``zaya``: ``zaya_config``)."""
     with open(os.path.join(ckpt_dir, "config.json")) as f:
         hf = json.load(f)
+    if hf.get("model_type") == "zaya":
+        return zaya_config(hf, dtype)
     rope_scaling = rope_scaling_from_hf(hf.get("rope_scaling"))
     moe: dict = {}
     if hf.get("num_experts"):  # Qwen3-MoE family
@@ -181,6 +215,12 @@ def load_hf_params(ckpt_dir: str, cfg: decoder.ModelConfig | None = None,
     if quantize not in ("", "int8"):
         raise ValueError(f"unknown quantize mode {quantize!r}")
     cfg = cfg or config_from_hf(ckpt_dir)
+    if cfg.cca_time0:
+        # the published checkpoint's tensor names have not been seen from
+        # here: a key map written without them would be a guess
+        raise NotImplementedError(
+            "no key map for a zaya (CCA) checkpoint yet: write it from the "
+            "published model.safetensors.index.json (ROADMAP.md Queue 2)")
     dtype = dtype or cfg.dtype
     np_dtype = jnp.dtype(dtype)
 

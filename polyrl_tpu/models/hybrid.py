@@ -5,7 +5,14 @@ with a sigmoid router behind leading dense layers; and DeepSeek-V3's
 decoder (dots.vlm1 / dots.llm1 share it key for key): MLA in every layer,
 the same routed MLP behind leading dense layers. One MLA block serves
 both; what differs is an option of the configuration (``q_lora_rank``: a
-normed query latent; ``mla_head_gate``; ``rope_scaling``: YaRN).
+normed query latent; ``mla_head_gate``; ``rope_scaling``: YaRN). And
+ZAYA1's decoder (``zaya``): compressed convolutional attention (CCA) in
+every layer, which keeps a K/V pair in pages AND convolution tails in the
+slot, a top-1 routed MLP whose router is an MLP on a latent that each
+layer hands to the next, and both sublayers' residuals scaled. Every one
+of its layers is alike, so the stacked scan would fit its trainer's
+forward; it lives here because the slot state and a second tensor carried
+beside the residual stream are what this file's unrolled loop threads.
 
 One block a kind, parameters stacked per kind::
 
@@ -20,10 +27,17 @@ One block a kind, parameters stacked per kind::
                 wkv_a [Lm, d, rank+rope],
                 kv_norm [Lm, rank], wkv_b [Lm, rank, H*(nope+v)],
                 wgate [Lm, d, H] (with ``mla_head_gate``), wo [Lm, H*v, d]}
+      "cca":   {w_in [Lc, d, (Hq+Hkv)*D + Hkv*D]  (q~ | k~ | va | vb),
+                conv0 [Lc, K0, (Hq+Hkv)*D], conv1 [Lc, K1, Hq+Hkv, D, D],
+                tau [Lc, Hkv] float32, wo [Lc, Hq*D, d]}
+      "attn_res", "mlp_res": [L, 4, d]  (a_r, b_r, a_o, b_o; ``cca`` models)
       "dense": {w_gate w_up [Ld, d, f], w_down [Ld, f, d]}
       "moe":   {router [Ls, d, E_all], router_bias [Ls, E_all] float32,
                 we_gate we_up [Ls, E_held, d, fe], we_down [Ls, E_held, fe, d],
                 ws_gate ws_up [Ls, d, fs], ws_down [Ls, fs, d]}
+               (with ``router_hidden_size`` R: router_down [Ls, d, R],
+                router_gamma [Ls] float32, router_norm [Ls, R],
+                router_w1 router_w2 [Ls, R, R], router [Ls, R, E_all])
     }
 
 The equations (ISSUE 33, section 1; every reading the published config
@@ -45,7 +59,25 @@ token; prefill expands it through ``wkv_b`` a block of keys at a time
 (``mla_expanded``), decode folds ``wkv_b``'s key half into the query and
 applies its value half after the sum (the absorbed form). The logits'
 scale is ``(nope + rope) ** -0.5``, times YaRN's ``m ** 2`` where the
-configuration scales its rope (``mla_scale``)."""
+configuration scales its rope (``mla_scale``).
+
+CCA (ISSUE 41; the nine steps are in the docstring of
+``benchmark/references/cca_moe.py``, the choices the published config
+does not settle in ``benchmark/configs/zaya1-8b.json`` under ``assumed``),
+Hq query heads over Hkv K/V heads of size D, ``c = [q~ ; k~]``::
+
+    [q~ | k~ | va | vb] = x W_in
+    v[t] = (va[t], vb[t-1])                     half the value heads shifted
+    u[t] = sum_j conv0[j] * c[t-j]              depthwise, K0 taps
+    w[t] = sum_j u[t-j] @ conv1[j, g]           head g's columns, K1 taps
+    q = w_q + (q~ + repeat(k~)) / 2    k = w_k + (group_mean(q~) + k~) / 2
+    q = sqrt(D) q / |q|   k = tau_g sqrt(D) k / |k|   rope on the first
+        ``partial_rotary_factor`` of a head's columns, after the norm
+    o = softmax(q k^T / sqrt(D)) v  (causal, grouped)    out = o Wo
+
+Pages hold the finished ``k`` and ``v``; the slot holds the last K0-1 rows
+of ``c``, the last K1-1 rows of ``u`` and the last token's ``vb``. A
+sublayer's residual is ``(a_r x + b_r) + (a_o F(rms(x)) + b_o)``."""
 
 from __future__ import annotations
 
@@ -56,7 +88,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from polyrl_tpu.models import cache_spec
-from polyrl_tpu.models.blocks import (EXPERT_KEYS, _head, _moe_mlp,
+from polyrl_tpu.models.blocks import (EXPERT_KEYS, _head, _latent_route,
+                                      _moe_mlp, _scatter_pages_kv,
                                       _scatter_token_kv, rms_norm)
 from polyrl_tpu.models.quant import mm
 
@@ -69,6 +102,8 @@ _MAX_LOG_DECAY = 80.0
 
 # the decay's bias over a head's key channels, first to last (init_params)
 F_BIAS = (-8.0, -1.0)
+# what a router's latent keeps of the layer before's (init_params)
+ROUTER_GAMMA = 0.5
 
 
 def kda_chunk(cfg) -> int:
@@ -82,6 +117,7 @@ def _counts(cfg) -> dict:
     plan = cache_spec.layer_plan(cfg)
     return {"kda": sum(p.mixer == "kda" for p in plan),
             "mla": sum(p.mixer == "mla" for p in plan),
+            "cca": sum(p.mixer == "cca" for p in plan),
             "dense": sum(p.mlp == "dense" for p in plan),
             "moe": sum(p.mlp == "moe" for p in plan)}
 
@@ -112,11 +148,11 @@ def init_params(rng: jax.Array, cfg) -> dict:
     std = 0.02
     count = [0]
 
-    def norm(*shape, dtype=None):
+    def norm(*shape, dtype=None, scale=1.0):
         count[0] += 1
         key = jax.random.fold_in(rng, count[0])
-        return (jax.random.normal(key, shape, jnp.float32) * std).astype(
-            dtype or cfg.dtype)
+        return (jax.random.normal(key, shape, jnp.float32)
+                * (std * scale)).astype(dtype or cfg.dtype)
 
     def ones(*shape):
         return jnp.ones(shape, cfg.dtype)
@@ -155,6 +191,23 @@ def init_params(rng: jax.Array, cfg) -> dict:
         }
         if cfg.mla_head_gate:
             layers["mla"]["wgate"] = norm(m, d, h)
+    if n["cca"]:
+        m, (hq, hkv, hd) = n["cca"], cache_spec.cca_dims(cfg)
+        mixed = (hq + hkv) * hd
+        layers["cca"] = {
+            "w_in": norm(m, d, mixed + hkv * hd),
+            # both convolutions start near the identity on the newest
+            # position, as KDA's do
+            "conv0": norm(m, cfg.cca_time0, mixed).at[:, 0].add(1.0),
+            "conv1": norm(m, cfg.cca_time1, hq + hkv, hd, hd).at[:, 0].add(
+                jnp.eye(hd, dtype=cfg.dtype)),
+            "tau": jnp.ones((m, hkv), jnp.float32),
+            "wo": norm(m, hq * hd, d),
+        }
+        # a_r and a_o one, b_r and b_o drawn
+        one = jnp.array([1.0, 0.0, 1.0, 0.0], cfg.dtype)[None, :, None]
+        for name in ("attn_res", "mlp_res"):
+            layers[name] = norm(L, 4, d) * (1 - one) + one
     if n["dense"]:
         f = cfg.intermediate_size
         layers["dense"] = {"w_gate": norm(n["dense"], d, f),
@@ -164,8 +217,19 @@ def init_params(rng: jax.Array, cfg) -> dict:
         s, fe = n["moe"], cfg.moe_intermediate_size
         fs = cfg.moe_shared_expert_intermediate_size
         held = cache_spec.experts_held(cfg)[1]
+        r = cfg.router_hidden_size
+        # the router MLP's matrices by their fan-in: at 0.02 three layers
+        # shrink a normed latent to logits a hundredth wide
+        fan = {"scale": r ** -0.5 / std} if r else {}
+        router = ({"router_down": norm(s, d, r),
+                   "router_gamma": jnp.full((s,), ROUTER_GAMMA, jnp.float32),
+                   "router_norm": ones(s, r),
+                   "router_w1": norm(s, r, r, **fan),
+                   "router_w2": norm(s, r, r, **fan),
+                   "router": norm(s, r, cfg.num_experts, **fan)} if r
+                  else {"router": norm(s, d, cfg.num_experts)})
         layers["moe"] = {
-            "router": norm(s, d, cfg.num_experts),
+            **router,
             "router_bias": norm(s, cfg.num_experts, dtype=jnp.float32),
             "we_gate": norm(s, held, d, fe), "we_up": norm(s, held, d, fe),
             "we_down": norm(s, held, fe, d),
@@ -426,6 +490,117 @@ def _kda_sequence(cfg, lp, h_in, valid, state, conv):
         return _kda_out(cfg, lp, h_in, o), state, tail.astype(conv.dtype)
 
 
+def cca_rope(cfg, x, positions):
+    """Rope on the first ``partial_rotary_factor`` of each head's columns
+    of ``x`` [B, T, H, D] float32 (rotate-half within them, frequencies
+    ``theta ** (-2i / rot)``), the rest as they are."""
+    d = x.shape[-1]
+    rot = int(d * cfg.partial_rotary_factor)
+    inv = 1.0 / (cfg.rope_theta ** (np.arange(0, rot, 2, dtype=np.float64)
+                                    / rot))
+    ang = positions.astype(jnp.float32)[..., None, None] * jnp.asarray(
+        inv, jnp.float32)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    a, b = x[..., :rot // 2], x[..., rot // 2:rot]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin,
+                            x[..., rot:]], axis=-1)
+
+
+def _cca_mix(cfg, lp, proj, positions, tails):
+    """Steps 2 to 5 of a CCA layer over ``proj`` [B, T, (Hq+Hkv)*D + Hkv*D]
+    (``x W_in``) from the tails ``(latent [B, K0-1, C], mixed [B, K1-1, C],
+    value [B, Hkv*D/2])`` of the tokens before: returns (q [B, T, Hq, D],
+    k [B, T, Hkv, D], v [B, T, Hkv, D] in the model's dtype, and the three
+    sequences a later token's tails are rows of: ``[tail | chunk]`` of the
+    latents, of the first convolution's output, of the second value half)."""
+    hq, hkv, hd = cache_spec.cca_dims(cfg)
+    b, t, _ = proj.shape
+    mixed, half = (hq + hkv) * hd, hkv * hd // 2
+    k0, k1 = cfg.cca_time0, cfg.cca_time1
+    f32 = jnp.float32
+    c_tail, u_tail, vb_tail = tails
+    full_c = jnp.concatenate([c_tail.astype(proj.dtype), proj[..., :mixed]], 1)
+    va, vb = proj[..., mixed:mixed + half], proj[..., mixed + half:]
+    full_vb = jnp.concatenate([vb_tail[:, None].astype(proj.dtype), vb], 1)
+    v = jnp.concatenate([va, full_vb[:, :t]], -1).reshape(b, t, hkv, hd)
+    w0 = lp["conv0"].astype(f32)
+    u = sum(full_c[:, k0 - 1 - j:k0 - 1 - j + t].astype(f32) * w0[j]
+            for j in range(k0)).astype(proj.dtype)
+    full_u = jnp.concatenate([u_tail.astype(proj.dtype), u], 1)
+    heads = full_u.reshape(b, -1, hq + hkv, hd)
+    w = sum(jnp.einsum("btgd,gde->btge", heads[:, k1 - 1 - j:k1 - 1 - j + t],
+                       lp["conv1"][j], preferred_element_type=f32)
+            for j in range(k1))
+    c = proj[..., :mixed].astype(f32).reshape(b, t, hq + hkv, hd)
+    qm = c[:, :, :hq].reshape(b, t, hkv, hq // hkv, hd)
+    km = c[:, :, hq:]
+    q = w[:, :, :hq] + ((qm + km[:, :, :, None]) / 2).reshape(b, t, hq, hd)
+    k = w[:, :, hq:] + (jnp.mean(qm, axis=3) + km) / 2
+    q = _l2norm(q) * hd ** 0.5
+    k = _l2norm(k) * (hd ** 0.5 * lp["tau"].astype(f32)[:, None])
+    qk = cca_rope(cfg, jnp.concatenate([q, k], axis=2),
+                  positions).astype(proj.dtype)
+    return qk[:, :, :hq], qk[:, :, hq:], v, (full_c, full_u, full_vb)
+
+
+def _cca_tails(cfg, fulls, n_valid):
+    """The tails after ``n_valid`` tokens of a chunk ([B], or one whole
+    number for every row: a decode step's 1), from ``_cca_mix``'s ``[tail |
+    chunk]`` sequences."""
+    full_c, full_u, full_vb = fulls
+
+    def rows(full, k):
+        if isinstance(n_valid, int):
+            return full[:, n_valid:n_valid + k]
+        return jax.vmap(lambda f, s: jax.lax.dynamic_slice_in_dim(
+            f, s, k, 0))(full, n_valid)
+
+    return (rows(full_c, cfg.cca_time0 - 1), rows(full_u, cfg.cca_time1 - 1),
+            rows(full_vb, 1)[:, 0])
+
+
+def _cca_sequence(cfg, lp, h_in, positions, valid, tails, prefix):
+    """A CCA mixer over ``h_in`` [B, T, d] (``valid`` [B, T], padding on the
+    right) from the tails at the chunk's start and, with ``prefix`` = ((k,
+    v) [B, Tp, Hkv, D] of the tokens before, how many are real [B]), over
+    their keys too. Returns (out [B, T, d], this chunk's (k, v), the tails
+    after the last valid position)."""
+    from polyrl_tpu.ops.attention import attention
+
+    b, t, _ = h_in.shape
+    with jax.named_scope("cca_proj"):
+        proj = mm(h_in, lp["w_in"])
+    with jax.named_scope("cca_mix"):
+        q, k, v, fulls = _cca_mix(cfg, lp, proj, positions, tails)
+        new_tails = _cca_tails(cfg, fulls,
+                               jnp.sum(valid.astype(jnp.int32), axis=1))
+    with jax.named_scope("attn_core"):
+        keys, values, key_ok, tp = k, v, valid, 0
+        if prefix is not None:
+            (pk, pv), pre_len = prefix
+            tp = pk.shape[1]
+            keys = jnp.concatenate([pk.astype(k.dtype), k], axis=1)
+            values = jnp.concatenate([pv.astype(v.dtype), v], axis=1)
+            key_ok = jnp.concatenate(
+                [jnp.arange(tp)[None] < pre_len[:, None], valid], axis=1)
+        seen = (jnp.arange(tp + t)[None, :] <= tp + jnp.arange(t)[:, None])
+        mask = (seen[None] & key_ok[:, None, :])[:, None]
+        o = attention(q, keys, values, mask=mask).reshape(b, t, -1)
+    with jax.named_scope("cca_proj"):
+        return mm(o, lp["wo"]), (k, v), new_tails
+
+
+def _residual(x, out, res):
+    """A sublayer's residual: ``x + out``, or with ``res`` [4, d] = (a_r,
+    b_r, a_o, b_o) the scaled ``(a_r x + b_r) + (a_o out + b_o)``."""
+    if res is None:
+        return x + out
+    a_r, b_r, a_o, b_o = res.astype(jnp.float32)
+    return (a_r * x.astype(jnp.float32) + b_r
+            + a_o * out.astype(jnp.float32) + b_o).astype(x.dtype)
+
+
+
 def _mla_qkv(cfg, lp, h_in, positions):
     """``h_in`` [..., T, d] -> (q_nope [..., T, H, nope], q_rope [..., T,
     H, rope] after rope, the queries through their normed latent where the
@@ -579,52 +754,76 @@ def _layer_params(cfg, layers: dict, l: int) -> tuple[dict, dict]:
     return mixer, mlp
 
 
-def _mlp(cfg, x, layers, l, mlp_lp, valid):
+def _res(layers, name: str, l: int):
+    """Layer ``l``'s residual scales, None for a model without them."""
+    return layers[name][l] if name in layers else None
+
+
+def router_carry(cfg, lead: tuple):
+    """What the first layer's router is handed: zeros [*lead, R] float32,
+    None for a router without a carried latent."""
+    r = cfg.router_hidden_size
+    return jnp.zeros((*lead, r), jnp.float32) if r else None
+
+
+def _mlp(cfg, x, layers, l, mlp_lp, valid, carry=None):
+    """The MLP sublayer of layer ``l`` with its residual: (x, the routed
+    block's load or None, the router's latent for the next layer or
+    None). ``carry`` [..., R]: the layer before's latent of each token."""
     plan = cache_spec.layer_plan(cfg)[l]
     j = kind_index(cfg)[l][1]
+    res = _res(layers, "mlp_res", l)
     with jax.named_scope("mlp"):
         h = _rms(x, layers["mlp_norm"][l], cfg.rms_norm_eps)
         if plan.mlp == "dense":
             gate = jax.nn.silu(mm(h, mlp_lp["w_gate"]).astype(jnp.float32))
-            return x + mm(gate.astype(h.dtype) * mm(h, mlp_lp["w_up"]),
-                          mlp_lp["w_down"]), None
+            out = mm(gate.astype(h.dtype) * mm(h, mlp_lp["w_up"]),
+                     mlp_lp["w_down"])
+            return _residual(x, out, res), None, carry
         shape = h.shape
+        rows = h.reshape(-1, shape[-1])
         v = valid.reshape(-1) if valid is not None else None
-        out, load = _moe_mlp(cfg, h.reshape(-1, shape[-1]), mlp_lp,
-                                     v, j)
-        return x + out.reshape(shape), load
+        route = None
+        if carry is not None:
+            with jax.named_scope("moe_route"):
+                *route, latent = _latent_route(
+                    cfg, rows, mlp_lp, carry.reshape(rows.shape[0], -1))
+            carry = latent.reshape(carry.shape)
+        out, load = _moe_mlp(cfg, rows, mlp_lp, v, j, route)
+        return _residual(x, out.reshape(shape), res), load, carry
 
 
 def run_sequence(params, cfg, x, positions, valid, states=None,
                  prefix=None, remat: bool = False):
     """Every layer over whole (chunks of) sequences ``x`` [B, T, d] with
     right padding (``valid`` [B, T]): the trainer's forward and the
-    engine's prefill. ``states``: for each KDA layer in order its (state,
-    conv) rows at the chunk's start, zeros when None. ``prefix``: for each
-    MLA layer in order (latent rows [B, Tp, w] of the tokens before the
-    chunk, how many of them are real [B]), none when None. Returns (x,
-    new states, this chunk's latent rows a MLA layer)."""
+    engine's prefill. ``states``: for each layer that keeps a slot, in
+    order, its rows at the chunk's start (KDA: (state, conv); CCA: the
+    three tails), zeros when None. ``prefix``: for each layer that keeps
+    pages, in order, (what the tokens before the chunk keep there [B, Tp,
+    ..]: latent rows for MLA, a (k, v) pair for CCA; how many of them are
+    real [B]), none when None. Returns (x, new states, what this chunk
+    keeps in pages a paged layer). A router's carried latent starts from
+    zero at the first layer and never leaves the call: it is a token's
+    own, layer to layer."""
     layers = params["layers"]
     plan = cache_spec.layer_plan(cfg)
     b, t, _ = x.shape
-    hh, dk, dv = cache_spec.kda_dims(cfg)
     new_states, latents = [], []
+    carry = router_carry(cfg, (b, t))
+    index = cache_spec.pool_index(cfg)
     for l, p in enumerate(plan):
-        i = kind_index(cfg)[l][0]
+        at_pages, at_slot = index[l]
 
-        def layer(x, l=l, p=p, i=i):
+        def layer(x, carry, l=l, p=p, at_pages=at_pages, at_slot=at_slot):
             mixer_lp, mlp_lp = _layer_params(cfg, layers, l)
             h_in = _rms(x, layers["attn_norm"][l], cfg.rms_norm_eps)
-            extra = None
+            st = states[at_slot] if states is not None and at_slot is not None \
+                else _zero_state(cfg, p, b, x.dtype)
+            kept = state = None
             if p.mixer == "kda":
-                if states is None:
-                    kk = cfg.short_conv_kernel_size
-                    st = (jnp.zeros((b, hh, dk, dv), jnp.float32),
-                          jnp.zeros((b, kk - 1, hh * (2 * dk + dv)), x.dtype))
-                else:
-                    st = states[i]
                 out, s1, c1 = _kda_sequence(cfg, mixer_lp, h_in, valid, *st)
-                extra = (s1, c1)
+                state = (s1, c1)
             elif p.mixer == "mla":
                 with jax.named_scope("mla_proj"):
                     q_nope, q_rope, lat = _mla_qkv(cfg, mixer_lp, h_in,
@@ -634,7 +833,7 @@ def run_sequence(params, cfg, x, positions, valid, states=None,
                         keys, key_ok = lat, valid
                         q_at = jnp.broadcast_to(jnp.arange(t), (b, t))
                     else:
-                        pre, pre_len = prefix[i]
+                        pre, pre_len = prefix[at_pages]
                         tp = pre.shape[1]
                         keys = jnp.concatenate([pre, lat], axis=1)
                         key_ok = jnp.concatenate(
@@ -645,17 +844,33 @@ def run_sequence(params, cfg, x, positions, valid, states=None,
                                      key_ok, q_at)
                 with jax.named_scope("mla_proj"):
                     out = _mla_out(cfg, mixer_lp, h_in, o)
-                extra = lat
+                kept = lat
+            elif p.mixer == "cca":
+                out, kept, state = _cca_sequence(
+                    cfg, mixer_lp, h_in, positions, valid, st,
+                    None if prefix is None else prefix[at_pages])
             else:
                 raise NotImplementedError(
                     f"mixer {p.mixer!r} beside other kinds of layer")
-            x = x + out
-            x, _load = _mlp(cfg, x, layers, l, mlp_lp, valid)
-            return x, extra
+            x = _residual(x, out, _res(layers, "attn_res", l))
+            x, _load, carry = _mlp(cfg, x, layers, l, mlp_lp, valid, carry)
+            return x, carry, kept, state
 
-        x, extra = (jax.checkpoint(layer) if remat else layer)(x)
-        (new_states if p.mixer == "kda" else latents).append(extra)
+        x, carry, kept, state = (jax.checkpoint(layer) if remat
+                                 else layer)(x, carry)
+        if at_pages is not None:
+            latents.append(kept)
+        if at_slot is not None:
+            new_states.append(state)
     return x, new_states, latents
+
+
+def _zero_state(cfg, p, b: int, dtype):
+    """What a layer's slot holds before a sequence's first token."""
+    slot = cache_spec.slot_part(cache_spec.layer_cache(cfg, p, dtype))
+    if slot is None:
+        return None
+    return tuple(jnp.zeros((b, *shape), dt) for _n, shape, dt in slot.arrays)
 
 
 def forward(params, cfg, input_ids, positions, attn_mask, remat=False,
@@ -717,26 +932,57 @@ def prefill(params, cfg, ids, lens, prefix_len, pools, prefix_page_ids,
     positions = jnp.broadcast_to(prefix_len + jnp.arange(pb, dtype=jnp.int32),
                                  (b, pb))
     fresh = prefix_len == 0
-    states = [(jnp.where(fresh, 0.0, s[slots]),
-               jnp.where(fresh, jnp.zeros((), c.dtype), c[slots]))
-              for s, c in state]
+    states = [tuple(jnp.where(fresh, jnp.zeros((), a.dtype), a[slots])
+                    for a in rows) for rows in state]
     prefix = None
+    # a K/V pair's scope is ``attn_core``, a latent pool's ``mla_core``
+    pair = bool(paged) and isinstance(paged[0], tuple)
     if prefix_page_ids.shape[1]:
-        with jax.named_scope("mla_core"):
-            prefix = [(_gather_pages(pool, prefix_page_ids),
-                       jnp.broadcast_to(prefix_len, (b,))) for pool in paged]
+        with jax.named_scope("attn_core" if pair else "mla_core"):
+            pre_len = jnp.broadcast_to(prefix_len, (b,))
+            prefix = [((_gather_kv if pair else _gather_pages)(
+                pool, prefix_page_ids), pre_len) for pool in paged]
     x = params["embed"][ids]
     x, new_states, latents = run_sequence(params, cfg, x, positions, valid,
                                           states, prefix)
-    with jax.named_scope("mla_core"):
-        paged = tuple(_scatter_tokens(pool, page_ids, lat, valid)
-                      for pool, lat in zip(paged, latents))
-    with jax.named_scope("kda_core"):
-        state = tuple((s.at[slots].set(s1.astype(s.dtype)),
-                       c.at[slots].set(c1.astype(c.dtype)))
-                      for (s, c), (s1, c1) in zip(state, new_states))
+    with jax.named_scope("attn_core" if pair else "mla_core"):
+        paged = tuple(_scatter_kv(pool, page_ids, kept) if pair
+                      else _scatter_tokens(pool, page_ids, kept, valid)
+                      for pool, kept in zip(paged, latents))
+    with jax.named_scope("cca_mix" if pair else "kda_core"):
+        state = tuple(tuple(a.at[slots].set(a1.astype(a.dtype))
+                            for a, a1 in zip(rows, new))
+                      for rows, new in zip(state, new_states))
     logits = _head(cfg, params, x, jnp.maximum(lens - 1, 0))
     return (paged, state), logits
+
+
+def _gather_kv(pool, page_ids):
+    """A K/V pair of ``[Hkv, N, ps, D]`` pools, ``page_ids`` [B, n] -> (k,
+    v) each [B, n*ps, Hkv, D]: whole pages, as the uniform decoder's
+    prefix gather takes them."""
+    def one(a):
+        hkv, _n, ps, d = a.shape
+        b, n = page_ids.shape
+        return a[:, page_ids].transpose(1, 2, 3, 0, 4).reshape(
+            b, n * ps, hkv, d)
+
+    return one(pool[0]), one(pool[1])
+
+
+def _scatter_kv(pool, page_ids, kv):
+    """Write a chunk's (k, v), each [B, T, Hkv, D], to the pages
+    ``page_ids`` [B, T // ps] of a K/V pair of pools, whole pages at a
+    time (``blocks._scatter_pages_kv``). A padded position lands in the
+    tail of the row's last page or in the null page, where no length
+    reaches it."""
+    def one(a, new):
+        hkv, _n, ps, d = a.shape
+        b, t = new.shape[:2]
+        pages = new.reshape(b * (t // ps), ps, hkv, d).transpose(2, 0, 1, 3)
+        return _scatter_pages_kv(a, page_ids.reshape(-1), pages)
+
+    return one(pool[0], kv[0]), one(pool[1], kv[1])
 
 
 def _set_rows(whole, rows):
@@ -751,10 +997,22 @@ def load_width(cfg) -> int:
     (``decoder._moe_mlp``), and for a model of several kinds of layer
     three more: every (row, choice) of live rows whether or not its expert
     is held here, live rows times KDA layers, and the latent rows the
-    live rows attend over, summed over the MLA layers."""
+    live rows attend over, summed over the MLA layers; with CCA layers a
+    seventh: live rows times CCA layers (the tails read and written)."""
     if cache_spec.is_uniform(cfg):
         return 3 if cfg.num_experts else 0
-    return 6
+    return 6 + any(p.mixer == "cca" for p in cache_spec.layer_plan(cfg))
+
+
+def held_state(cfg, arrays: tuple, slot: int) -> np.ndarray:
+    """What ``CBEngine.recurrent_state`` reads of one layer's slot arrays
+    for the engine's slot ``slot``, float32 on the host: a KDA layer's
+    recurrent state (its convolution tails are left out, as ever), a CCA
+    layer's tails, flattened side by side."""
+    if any(p.mixer == "cca" for p in cache_spec.layer_plan(cfg)):
+        return np.concatenate([np.asarray(a[slot], np.float32).reshape(-1)
+                               for a in arrays])
+    return np.asarray(arrays[0][slot]).astype(np.float32)
 
 
 def kda_in_kernel(cfg) -> bool:
@@ -773,16 +1031,19 @@ def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
                  active=None, head_fn=None):
     """``decoder.forward_paged_decode`` for a model of several kinds of
     layer: one token a slot. A row without a request leaves its state
-    rows as they are and writes its latent row to the null page."""
+    rows as they are and writes what it would keep in pages to the null
+    page. A CCA layer writes and attends its K/V pair through the GQA
+    decode kernels (``ops.paged_attention``)."""
     from polyrl_tpu.ops.kda_state import kda_state_update
     from polyrl_tpu.ops.mla_attention import latent_paged_attention
+    from polyrl_tpu.ops.paged_attention import paged_attention, paged_kv_write
 
     layers = params["layers"]
     plan = cache_spec.layer_plan(cfg)
     paged, state = list(pools[0]), list(pools[1])
     s = tokens.shape[0]
-    ps = paged[0].shape[2]
-    scale = mla_scale(cfg)
+    ps = jax.tree_util.tree_leaves(paged)[0].shape[2]
+    scale = mla_scale(cfg) if cfg.kv_lora_rank else None
     live = jnp.ones((s,), bool) if active is None else active
     write_page = jnp.where(live, page_table[jnp.arange(s), seq_lens // ps], 0)
     write_off = jnp.where(live, seq_lens % ps, 0)
@@ -794,8 +1055,10 @@ def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
 
     x = params["embed"][tokens]
     load = jnp.zeros((load_width(cfg),), jnp.int32)
+    carry = router_carry(cfg, (s,))
+    index = cache_spec.pool_index(cfg)
     for l, p in enumerate(plan):
-        i = kind_index(cfg)[l][0]
+        at_pages, i = index[l]
         mixer_lp, mlp_lp = _layer_params(cfg, layers, l)
         h_in = _rms(x, layers["attn_norm"][l], cfg.rms_norm_eps)
         if p.mixer == "kda":
@@ -815,6 +1078,7 @@ def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
             state[i] = (st, conv)
             load = load.at[4].add(n_live)
         elif p.mixer == "mla":
+            i = at_pages
             with jax.named_scope("mla_proj"):
                 q_nope, q_rope, lat = _mla_qkv(cfg, mixer_lp, h_in[:, None],
                                                positions[:, None])
@@ -828,10 +1092,32 @@ def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
             with jax.named_scope("mla_proj"):
                 out = _mla_out(cfg, mixer_lp, h_in,
                                mla_unabsorb(cfg, mixer_lp, o_lat))
+        elif p.mixer == "cca":
+            with jax.named_scope("cca_proj"):
+                proj = mm(h_in, mixer_lp["w_in"])
+            with jax.named_scope("cca_mix"):
+                tails = tuple(a[:s] for a in state[i])
+                q, k, v, fulls = _cca_mix(cfg, mixer_lp, proj[:, None],
+                                          positions[:, None], tails)
+                new = _cca_tails(cfg, fulls, 1)
+                state[i] = tuple(
+                    _set_rows(a, jnp.where(
+                        live.reshape(-1, *[1] * (a.ndim - 1)),
+                        n.astype(a.dtype), a[:s]))
+                    for a, n in zip(state[i], new))
+            with jax.named_scope("attn_core"):
+                paged[at_pages] = paged_kv_write(
+                    *paged[at_pages], write_page, write_off, k[:, 0], v[:, 0])
+                o = paged_attention(q[:, 0], *paged[at_pages], page_table,
+                                    attn_lens).reshape(s, -1)
+            load = load.at[6].add(n_live)
+            with jax.named_scope("cca_proj"):
+                out = mm(o, mixer_lp["wo"])
         else:
             raise NotImplementedError(
                 f"mixer {p.mixer!r} beside other kinds of layer")
-        x, moe = _mlp(cfg, x + out, layers, l, mlp_lp, active)
+        x = _residual(x, out, _res(layers, "attn_res", l))
+        x, moe, carry = _mlp(cfg, x, layers, l, mlp_lp, active, carry)
         if moe is not None:
             load = load.at[:3].add(moe)
             load = load.at[3].add(n_live * cfg.num_experts_per_tok)

@@ -379,7 +379,8 @@ class CBEngine:
         self._pools = self._make_pools()
         if self.kvledger is not None:
             # HBM truth reads the chips THIS engine's pools live on
-            self.kvledger.devices = tuple(self._pools[0][0].devices())
+            self.kvledger.devices = tuple(
+                jax.tree_util.tree_leaves(self._pools)[0].devices())
         self._rng = jax.random.PRNGKey(seed)
 
         self._queue: "queue.Queue[_Request]" = queue.Queue()
@@ -621,7 +622,8 @@ class CBEngine:
                          if hasattr(x, "nbytes"))
         if self.kvledger is not None and pool_b:
             # bytes a page: the paged arrays', not a recurrent state's
-            # (a model of several kinds of layer: (paged, state rows))
+            # (a model of several kinds of layer: (paged, state rows); a
+            # layer may have a part in both)
             paged = pools if cache_spec.is_uniform(self.cfg) else pools[0]
             self.kvledger.page_bytes = sum(
                 int(x.nbytes) for x in jax.tree_util.tree_leaves(paged)
@@ -643,17 +645,20 @@ class CBEngine:
         if more:
             # a model of several kinds of layer (hybrid.load_width): every
             # choice of a live row, held here or not (``moe_routed`` counts
-            # the held ones), live rows times KDA layers, and the latent
-            # rows attended, summed over the MLA layers
-            (info["moe_choices"], info["kda_state_rows"],
-             info["mla_rows_read"]) = more
+            # the held ones), live rows times KDA layers, the latent rows
+            # attended, summed over the MLA layers, and for a model with
+            # CCA layers live rows times those
+            info.update(zip(("moe_choices", "kda_state_rows",
+                             "mla_rows_read", "cca_tail_rows"), more))
         return info
 
     def recurrent_state(self, rid: str):
         """What the slot of the running request ``rid`` holds of its
         recurrent state now: (tokens it has consumed, prompt and fed-back
-        answer alike; one float32 array ``[H, Dk, Dv]`` a KDA layer in
-        order), on the host. None for a model without such a state or a
+        answer alike; one float32 array a layer that keeps a slot, in
+        order: a KDA layer's ``[H, Dk, Dv]`` state, a CCA layer's three
+        tails side by side ``[(K0-1 + K1-1) * C + Hkv*D/2]``), on the
+        host. None for a model without such a state or a
         request that is not decoding. Waits for the programs in flight;
         the read-only half of a state snapshot (ROADMAP M8)."""
         if not self.stateful:
@@ -667,8 +672,8 @@ class CBEngine:
                 return None
             self._ensure_dev_state()
             consumed = int(np.asarray(self._dev_state["seq_lens"])[i])
-            rows = [np.asarray(state[i]).astype(np.float32)
-                    for state, _conv in self._pools[1]]
+            rows = [hybrid.held_state(self.cfg, arrays, i)
+                    for arrays in self._pools[1]]
         return consumed, rows
 
     def kv_memory_info(self) -> dict:

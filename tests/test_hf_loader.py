@@ -111,3 +111,50 @@ def test_train_entry_builds_from_hf_checkpoint(tmp_path):
     rand = decoder.init_params(jax.random.PRNGKey(cfg.trainer.seed), mcfg)
     assert not np.allclose(np.asarray(params["embed"]),
                            np.asarray(rand["embed"]))
+
+
+# -- the zaya family (models/hybrid.py's CCA decoder) -----------------------
+
+
+def test_zaya_config_is_the_published_preset():
+    """``config_from_hf`` on ZAYA1-8B's published config.json (the
+    benchmark's file holds every key of it) gives the ``zaya1-8b`` preset,
+    but for the depth the file cuts."""
+    import dataclasses
+    import json
+    import os
+
+    from polyrl_tpu.models import hf_loader
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "zaya1-8b.json")) as f:
+        hf = json.load(f)
+    assert hf["model_type"] == "zaya"
+    got = hf_loader.zaya_config(hf)
+    want = decoder.get_config("zaya1-8b-depth12")
+    assert got == dataclasses.replace(want, kept_layers=None)
+    whole = hf_loader.zaya_config({**hf, **hf["published"]})
+    assert whole == decoder.get_config("zaya1-8b")
+    with pytest.raises(NotImplementedError, match="sliding window"):
+        hf_loader.zaya_config({**hf, "sliding_window": 4096})
+
+
+def test_zaya_checkpoint_is_refused_until_its_key_map_exists(tmp_path):
+    """A ``zaya`` checkpoint's config.json is read (``config_from_hf``),
+    its tensors are not: ``load_hf_params`` refuses by name what it has no
+    key map for, before it opens a shard."""
+    import json
+    import os
+
+    from polyrl_tpu.models import hf_loader
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "zaya1-8b.json")) as f:
+        hf = json.load(f)
+    (tmp_path / "config.json").write_text(json.dumps(hf))
+    cfg = hf_loader.config_from_hf(str(tmp_path))
+    assert cfg.cca_time0 == 2 and cfg.router_hidden_size == 256
+    with pytest.raises(NotImplementedError, match="no key map for a zaya"):
+        hf_loader.load_hf_params(str(tmp_path))

@@ -43,6 +43,14 @@ def latent():
     return cfg, decoder.init_params(jax.random.PRNGKey(0), cfg)
 
 
+@pytest.fixture(scope="module")
+def cca():
+    """Compressed convolutional attention in every layer: a K/V pair in
+    pages AND convolution tails in the slot (``tests/test_cca.py``)."""
+    cfg = decoder.get_config("cca-tiny", dtype=jnp.float32)
+    return cfg, decoder.init_params(jax.random.PRNGKey(0), cfg)
+
+
 def _engine(model, **kw):
     cfg, params = model
     opts = dict(max_slots=4, page_size=PS, max_seq_len=128,
@@ -327,7 +335,13 @@ def test_the_row_that_yields_is_the_one_with_least_to_redo(dense):
     _books_balance(eng, 13)
 
 
-def test_the_tiny_hybrid_rebuilds_a_yielded_rows_state(hybrid):
+@pytest.mark.parametrize("family", ["hybrid", "cca"])
+def test_the_tiny_hybrid_rebuilds_a_yielded_rows_state(request, family):
+    """A model with a state in its slot (``hybrid``: KDA states beside a
+    latent pool; ``cca``: convolution tails beside a K/V pair in the same
+    layer) re-enters from token 0: the chunks recompute the slot's rows
+    with the pages, and nothing is streamed twice."""
+    hybrid = request.getfixturevalue(family)
     opts = dict(prompt_buckets=(16, 64), prefill_chunk=16,
                 steps_per_dispatch=4)
     tight, c_tight, eng = _run(hybrid, 16, budget=40, n=3, **opts)

@@ -57,6 +57,7 @@ def one_chip(v5e_2x2):
     (65, 32, 4, 128, jnp.bfloat16, 64, 64),
     (65, 7, 1, 128, jnp.bfloat16, 64, 192),
     (65, 28, 4, 128, jnp.float32, 64, 192),
+    (129, 8, 2, 128, jnp.bfloat16, 64, 192),     # zaya1-8b's CCA layers
 ])
 def test_decode_kernel_compiles_for_v5e(one_chip, s, hq, hkv, d, dtype, page,
                                         width):
@@ -80,6 +81,7 @@ def test_decode_kernel_compiles_for_v5e(one_chip, s, hq, hkv, d, dtype, page,
     (65, 3584, 152064, False),
     (65, 2048, 151936, False),
     (65, 2048, 151936, True),
+    (129, 2048, 262272, True),                   # zaya1-8b's tied head
 ])
 def test_head_sample_kernel_compiles_for_v5e(one_chip, s, d, v, tied):
     """No ``chip_precision`` here: the kernel pins DEFAULT on its dot, so
@@ -468,3 +470,96 @@ def test_ling_decode_step_compiles_for_v5e_within_memory(one_chip,
             if " = " in line]
     assert not [op for op in made if re.match(
         r"f32\[129,32,128,128\]\S* (copy|fusion|select)\(", op)]
+
+
+# -- ZAYA1-8B's cell (benchmark/configs/zaya1-8b.json) ----------------------
+
+
+def _zaya_shapes(one_chip, s, n_pages, page):
+    from polyrl_tpu.models import decoder
+
+    cfg = decoder.get_config("zaya1-8b-depth12")
+
+    def shapes(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = shapes(jax.eval_shape(
+        lambda: decoder.init_params(jax.random.PRNGKey(0), cfg)))
+    pools = shapes(jax.eval_shape(
+        lambda: decoder.make_paged_pools(cfg, n_pages, page, slots=s + 1)))
+    return cfg, params, pools
+
+
+def test_zaya_decode_step_compiles_for_v5e_within_memory(one_chip,
+                                                         chip_precision,
+                                                         on_tpu):
+    """The cell's whole decode program: 8 fused steps of the 12-layer cut
+    at 128 rows, the K/V pools and the tails donated, the token drawn
+    inside the tied head. Weights (6.06 GB), pool (6.85 GB) and everything
+    the step holds at once fit a 16 GB chip; the GQA write and attention
+    kernels, the grouped matmuls and the head are Mosaic's."""
+    from polyrl_tpu.models import decoder
+
+    s, width, n_pages, page = 128, 192, 8705, 64
+    cfg, params, pools = _zaya_shapes(one_chip, s, n_pages, page)
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(params, paged, state, rng, table, lens, last, active, temps):
+        def body(carry, _):
+            paged, state, rng, lens, last = carry
+            rng, sub = jax.random.split(rng)
+            head = functools.partial(decoder.head_and_sample, rng=sub,
+                                     temps=temps)
+            (tok, logp), (paged, state), load = decoder.forward_paged_decode(
+                params, cfg, last, lens, (paged, state), table, lens,
+                active=active, head_fn=head)
+            return (paged, state, rng, lens + 1, tok), (tok, logp, load)
+        return jax.lax.scan(body, (paged, state, rng, lens, last), None,
+                            length=8)
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, pools[0], pools[1], arg((2,), jnp.uint32),
+        arg((s, width), jnp.int32), arg((s,), jnp.int32),
+        arg((s,), jnp.int32), arg((s,), jnp.bool_),
+        arg((s,), jnp.float32)).compile()
+    m = compiled.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert 12.5e9 < live < 14.5e9
+    # no copy of a 285 MB pool among the temporaries
+    assert m.temp_size_in_bytes < 200 * 2**20
+    # a write and an attention a layer, a gate/up and a down matmul a
+    # layer, the head
+    assert compiled.as_text().count("tpu_custom_call") >= 4 * 12 + 1
+
+
+def test_zaya_prefill_chunk_compiles_for_v5e_within_memory(one_chip,
+                                                           chip_precision,
+                                                           on_tpu):
+    """The longest prompt's last chunk: 512 tokens from the slot's tails
+    over 128 pages of prefix, beside the weights and the pool."""
+    from polyrl_tpu.models import decoder
+
+    n_pages, page, pb, n_pre = 8705, 64, 512, 128
+    cfg, params, pools = _zaya_shapes(one_chip, 128, n_pages, page)
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def chunk(params, paged, state, ids, n, at, pre_pages, pages, slot):
+        return decoder.prefill_suffix_into_pages(
+            params, cfg, ids, n, at, (paged, state), pre_pages, pages, slot)
+
+    compiled = jax.jit(chunk, donate_argnums=(1, 2)).lower(
+        params, pools[0], pools[1], arg((pb,), jnp.int32),
+        arg((), jnp.int32), arg((), jnp.int32), arg((n_pre,), jnp.int32),
+        arg((pb // page,), jnp.int32), arg((), jnp.int32)).compile()
+    m = compiled.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert live < 15 * 10**9
+    assert m.temp_size_in_bytes < 2 * 10**9

@@ -649,6 +649,27 @@ def test_streaming_push_fans_out_to_multiple_receivers():
         sender.stop()
 
 
+class _TicketLock:
+    """A lock that serves its waiters in the order they asked (``with``
+    only). ``threading.Lock`` hands itself to whichever contender the
+    scheduler runs first, and a thread that releases it and asks again at
+    once wins that race whenever the cores are busy."""
+
+    def __init__(self):
+        self._cv = threading.Condition()
+        self._next = self._serving = 0
+
+    def __enter__(self):
+        with self._cv:
+            mine, self._next = self._next, self._next + 1
+            self._cv.wait_for(lambda: self._serving == mine)
+
+    def __exit__(self, *exc):
+        with self._cv:
+            self._serving += 1
+            self._cv.notify_all()
+
+
 def test_completion_tail_survives_same_version_repush():
     """Regression (advisor r5): a SAME-version re-push arming mid-tail must
     not let the tail emit buffer bytes the retry's streams are overwriting.
@@ -663,7 +684,13 @@ def test_completion_tail_survives_same_version_repush():
                        num_streams=1, listen_host="127.0.0.1",
                        advertise_host="127.0.0.1")
     # NOT started: the test drives receiver state directly, playing the
-    # control-channel roles (prepare/transfer_done) itself
+    # control-channel roles (prepare/transfer_done) itself. The install
+    # lock is made fair: what is tested is the tail's re-check once a
+    # re-push HAS armed between two emissions, not whether the scheduler
+    # lets the re-push's thread win the lock from a tail that drops it and
+    # takes it again within microseconds (on loaded cores it never does,
+    # and the tail ends before the re-push arms)
+    rx._install_lock = _TicketLock()
     total = layout.total_bytes
     pattern_a, pattern_b = 0xA5, 0x5A
     rx.buffer[:] = pattern_a
