@@ -614,7 +614,10 @@ def _mla_qkv(cfg, lp, h_in, positions):
         q = mm(cq, lp["wq_b"])
     else:
         q = mm(h_in, lp["wq"])
-    q = q.reshape(*lead, hh, nope + rope)
+    # the heads are cut out of the PRODUCT: without the barrier XLA moves
+    # the reshape onto the weight and writes a layer's ``wq_b`` out anew,
+    # heads major, before every product (75 MB a layer at 128 heads)
+    q = jax.lax.optimization_barrier(q).reshape(*lead, hh, nope + rope)
     kv = mm(h_in, lp["wkv_a"])
     c = _rms(kv[..., :r], lp["kv_norm"], cfg.rms_norm_eps)
     inv, amp = rope_inv_freq(cfg), rope_amplitude(cfg)
@@ -714,24 +717,42 @@ def mla_expanded(cfg, lp, q_nope, q_rope, latents, key_ok, q_at,
     return (acc / jnp.maximum(l, 1e-30)).swapaxes(1, 2)
 
 
-def mla_absorb(cfg, lp, q_nope, q_rope):
+def mla_absorb(cfg, lp, q_nope, q_rope, in_stack=None):
     """Decode's query in the latent's space: ``wkv_b``'s key half folded
-    into ``q_nope``, beside the rope part, zeros up to the row: [S, H, row]."""
+    into ``q_nope``, beside the rope part, zeros up to the row: [S, H, row].
+
+    ``in_stack``, where ``mla_in_kernel`` says so: (the stacked ``wkv_b``
+    [Lm, rank, H * (nope + v)], this layer's index in it), and the product
+    reads the layer where it lies (``ops/mla_proj.py``; interpreted off a
+    TPU: tests alone get there). Without it the einsum on ``lp``'s slice,
+    which XLA feeds from a copy of the layer with the heads major, and
+    which is the oracle."""
+    from polyrl_tpu.ops import mla_proj
+
     hh, r, nope = cfg.num_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    wk = lp["wkv_b"].reshape(r, hh, -1)[..., :nope]
-    q_abs = jnp.einsum("shd,rhd->shr", q_nope, wk,
-                       preferred_element_type=jnp.float32)
+    if in_stack is not None:
+        q_abs = mla_proj.absorb(q_nope, *in_stack,
+                                interpret=jax.default_backend() != "tpu")
+    else:
+        wk = lp["wkv_b"].reshape(r, hh, -1)[..., :nope]
+        q_abs = jnp.einsum("shd,rhd->shr", q_nope, wk,
+                           preferred_element_type=jnp.float32)
     pad = cache_spec.latent_row(cfg) - cache_spec.latent_width(cfg)
     return jnp.concatenate(
         [q_abs.astype(q_nope.dtype), q_rope,
          jnp.zeros((*q_rope.shape[:-1], pad), q_rope.dtype)], axis=-1)
 
 
-def mla_unabsorb(cfg, lp, o_latent):
+def mla_unabsorb(cfg, lp, o_latent, in_stack=None):
     """``wkv_b``'s value half applied to the attention's output over the
-    latent rows ``o_latent`` [S, H, rank] -> [S, H, v]. The TPU kernel
-    hands ``o_latent`` over in the pool's dtype, so the cast below is the
-    oracle's alone."""
+    latent rows ``o_latent`` [S, H, rank] -> [S, H, v] float32;
+    ``in_stack`` as for ``mla_absorb``. The TPU kernel hands ``o_latent``
+    over in the pool's dtype, so the cast is the oracle's alone."""
+    from polyrl_tpu.ops import mla_proj
+
+    if in_stack is not None:
+        return mla_proj.unabsorb(o_latent, *in_stack,
+                                 interpret=jax.default_backend() != "tpu")
     hh, r, nope = cfg.num_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim
     wv = lp["wkv_b"].reshape(r, hh, -1)[..., nope:]
     return jnp.einsum("shr,rhd->shd", o_latent.astype(lp["wkv_b"].dtype), wv,
@@ -1027,6 +1048,16 @@ def kda_in_kernel(cfg) -> bool:
                                     cache_spec.STATE_DTYPE))
 
 
+def mla_in_kernel(cfg, rows: int) -> bool:
+    """Whether a decode step of ``rows`` rows multiplies its MLA layers'
+    ``wkv_b`` where it lies in the stack (``ops/mla_proj.py``): an MLA
+    layer in the plan, head sizes the kernels take, the backend."""
+    from polyrl_tpu.ops import mla_proj
+
+    return (any(p.mixer == "mla" for p in cache_spec.layer_plan(cfg))
+            and mla_proj.in_kernel(cfg, rows))
+
+
 def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
                  active=None, head_fn=None):
     """``decoder.forward_paged_decode`` for a model of several kinds of
@@ -1078,11 +1109,14 @@ def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
             state[i] = (st, conv)
             load = load.at[4].add(n_live)
         elif p.mixer == "mla":
+            wkv_b = ((layers["mla"]["wkv_b"], kind_index(cfg)[l][0])
+                     if mla_in_kernel(cfg, s) else None)
             i = at_pages
             with jax.named_scope("mla_proj"):
                 q_nope, q_rope, lat = _mla_qkv(cfg, mixer_lp, h_in[:, None],
                                                positions[:, None])
-                q_lat = mla_absorb(cfg, mixer_lp, q_nope[:, 0], q_rope[:, 0])
+                q_lat = mla_absorb(cfg, mixer_lp, q_nope[:, 0], q_rope[:, 0],
+                                   wkv_b)
             with jax.named_scope("mla_core"):
                 paged[i] = _scatter_token_kv(
                     paged[i], write_page, write_off, lat)
@@ -1091,7 +1125,7 @@ def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
             load = load.at[5].add(rows_read)
             with jax.named_scope("mla_proj"):
                 out = _mla_out(cfg, mixer_lp, h_in,
-                               mla_unabsorb(cfg, mixer_lp, o_lat))
+                               mla_unabsorb(cfg, mixer_lp, o_lat, wkv_b))
         elif p.mixer == "cca":
             with jax.named_scope("cca_proj"):
                 proj = mm(h_in, mixer_lp["w_in"])

@@ -80,6 +80,8 @@ emission or iteration, never once a token, all on this profiler's clock:
   inside the output matmul (``decoder.head_and_sample``);
   ``kda_kernel_steps`` — the steps whose program updated its KDA states
   in the one-pass kernel (``ops/kda_state.py``);
+  ``mla_proj_kernel_steps`` — the steps whose program multiplied its MLA
+  layers' ``wkv_b`` where it lies in the stack (``ops/mla_proj.py``);
   ``decode_dispatches_cold`` — those of the dispatches enqueued with
   NOTHING outstanding (the device had run dry: an engine that keeps its
   run-ahead does it once a burst, one that drains before every dispatch
@@ -161,8 +163,8 @@ STALL_GAP_S = 2.0
 CUMULATIVE_KEYS = (
     "decode_dispatches", "decode_dispatches_cold", "admission_deferrals",
     "pages_grown", "slot_yields", "decode_steps_done", "fused_sample_steps",
-    "kda_kernel_steps", "row_steps_done", "device_busy_s", "loop_wall_s",
-    "loop_host_s",
+    "kda_kernel_steps", "mla_proj_kernel_steps", "row_steps_done",
+    "device_busy_s", "loop_wall_s", "loop_host_s",
     *PHASE_KEYS.values(), "emit_wait_s", "dispatches_emitted",
     "landing_gap_hist", "stalls", "programs_built", "build_s")
 
@@ -341,11 +343,13 @@ class EngineLoopProfiler:
 
     def on_dispatch(self, kind: str, steps: int = 0, lands: bool = True,
                     fused_sample: bool = False, rows: int = 0,
-                    kda_kernel: bool = False) -> None:
+                    kda_kernel: bool = False,
+                    mla_proj_kernel: bool = False) -> None:
         """A dispatch was just enqueued on the device; ``steps``: the
         decode steps it fuses, over ``rows`` live rows, ``fused_sample``:
         they sample inside the head, ``kda_kernel``: their KDA layers
-        update the state in the kernel. ``lands`` False: it returns nothing
+        update the state in the kernel, ``mla_proj_kernel``: their MLA
+        layers read ``wkv_b`` in place. ``lands`` False: it returns nothing
         the host fetches (a chunked prefill's mid-chunk),
         so a later dispatch's landing stands for it."""
         now = self._clock()
@@ -359,7 +363,8 @@ class EngineLoopProfiler:
             if cold:
                 self._busy_from = now
             if lands:
-                self._landing.append((steps, fused_sample, rows, kda_kernel))
+                self._landing.append((steps, fused_sample, rows, kda_kernel,
+                                      mla_proj_kernel))
                 self._tail_unlanded = False
             else:
                 self._tail_unlanded = True
@@ -390,7 +395,7 @@ class EngineLoopProfiler:
         with self._lock:
             c = self._cum
             for _ in range(min(n, len(self._landing))):
-                steps, fused_sample, rows, kda_kernel = (
+                steps, fused_sample, rows, kda_kernel, mla_proj_kernel = (
                     self._landing.popleft())
                 c["decode_steps_done"] += steps
                 c["row_steps_done"] += steps * rows
@@ -398,6 +403,8 @@ class EngineLoopProfiler:
                     c["fused_sample_steps"] += steps
                 if kda_kernel:
                     c["kda_kernel_steps"] += steps
+                if mla_proj_kernel:
+                    c["mla_proj_kernel_steps"] += steps
                 self._landed_at.append(now)
             gap = None if self._gap_from is None else now - self._gap_from
             if gap is not None:

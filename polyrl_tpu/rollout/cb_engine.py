@@ -289,6 +289,9 @@ class CBEngine:
         # one-pass kernel (the profiler's ``kda_kernel_steps``): one answer
         # for the engine's life
         self._kda_kernel = self.stateful and hybrid.kda_in_kernel(cfg)
+        # and whether its MLA layers multiply ``wkv_b`` where it lies in
+        # the stack (``mla_proj_kernel_steps``), at the step's rows
+        self._mla_proj_kernel = hybrid.mla_in_kernel(cfg, max_slots + 1)
         # and which features that act on pages have a kernel for every
         # mixer of the plan? What needs none (prefix cache, a group's
         # shared prompt, salvage, the ledger, growth and yield) runs on
@@ -2781,7 +2784,8 @@ class CBEngine:
             self.profiler.on_dispatch(
                 kind, entry[3] if decode else 0, fused_sample=fused_sample,
                 rows=len(entry[2]) if decode else 0,
-                kda_kernel=decode and self._kda_kernel)
+                kda_kernel=decode and self._kda_kernel,
+                mla_proj_kernel=decode and self._mla_proj_kernel)
         self._last_two.append(entry[1])
         with self._fetch_cv:
             self._emit_q.append(entry)

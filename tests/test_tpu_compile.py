@@ -9,6 +9,7 @@ at a time may load the TPU's library, and every xdist worker imports every
 test file), and all such tests live in this one file."""
 
 import functools
+import math
 import os
 import re
 
@@ -409,6 +410,117 @@ def test_kda_state_kernel_compiles_for_v5e(one_chip, slots, s, h):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == slots * h * 128 * 128 * 4
     assert mem.temp_size_in_bytes < 2**20 + 5 * s * h * 128 * 4
+
+
+# -- the MLA projections of a decode step (ops/mla_proj.py) ------------------
+
+MLA_LAYERS = 5
+
+
+def _written(text: str):
+    """(shape's element count, instruction) of everything the optimised
+    program writes: the instructions of every computation that no fusion
+    calls (a fusion's inside is registers and VMEM, its result is the
+    fusion instruction's own), parameters left out."""
+    fused = set(re.findall(r"fusion\([^\n]*calls=%?([\w.\-]+)", text))
+    comp = None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            comp = head.group(1)
+        elif line.startswith("}"):
+            comp = None
+        elif comp is not None and comp not in fused:
+            made = re.match(
+                r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(",
+                line)
+            if made and made.group(2) != "parameter":
+                dims = made.group(1)
+                yield (math.prod(map(int, dims.split(","))) if dims else 1,
+                       line.strip())
+
+
+def _mla_projections(one_chip, preset, rows):
+    """The MLA layers of a decode step alone, lowered for the described
+    chip: ``MLA_LAYERS`` layers of ``preset``'s widths stacked, each one's
+    ``_mla_qkv``, absorb, ``latent_paged_attention`` (the real custom
+    call: what XLA does beside it is what counts), unabsorb and
+    ``_mla_out`` as ``hybrid.paged_decode`` runs them. Returns (the
+    optimised HLO, a whole layer's element count by weight)."""
+    from polyrl_tpu.models import cache_spec, decoder, hybrid
+    from polyrl_tpu.ops.mla_attention import latent_paged_attention
+
+    cfg = decoder.get_config(preset)
+    width, n_pages, page = 192, 2049, 64
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    mla = jax.eval_shape(lambda: decoder.init_params(
+        jax.random.PRNGKey(0), cfg))["layers"]["mla"]
+    mla = {k: arg((MLA_LAYERS, *a.shape[1:]), a.dtype)
+           for k, a in mla.items()}
+
+    def step(mla, x, positions, pool, table, lens):
+        for l in range(MLA_LAYERS):
+            lp = {k: a[l] for k, a in mla.items()}
+            wkv_b = ((mla["wkv_b"], l) if hybrid.mla_in_kernel(cfg, rows)
+                     else None)
+            q_nope, q_rope, _lat = hybrid._mla_qkv(cfg, lp, x[:, None],
+                                                   positions[:, None])
+            q_lat = hybrid.mla_absorb(cfg, lp, q_nope[:, 0], q_rope[:, 0],
+                                      wkv_b)
+            o_lat = latent_paged_attention(q_lat, pool, table, lens,
+                                           cfg.kv_lora_rank,
+                                           hybrid.mla_scale(cfg))
+            x = x + hybrid._mla_out(
+                cfg, lp, x, hybrid.mla_unabsorb(cfg, lp, o_lat, wkv_b))
+        return x
+
+    compiled = jax.jit(step).lower(
+        mla, arg((rows, cfg.hidden_size), cfg.dtype),
+        arg((rows,), jnp.int32),
+        arg((1, n_pages, page, cache_spec.latent_row(cfg)), cfg.dtype),
+        arg((rows, width), jnp.int32), arg((rows,), jnp.int32)).compile()
+    layer = {math.prod(mla[k].shape[1:]): k
+             for k in ("wq_b", "wq", "wkv_b") if k in mla}
+    return compiled.as_text(), layer
+
+
+@pytest.mark.parametrize("rows", [65, 129])
+@pytest.mark.parametrize("preset", ["dots.vlm1-share16",
+                                    "ling-3.0-flash-share4"])
+def test_mla_projections_read_the_stacks_in_place_on_v5e(
+        one_chip, chip_precision, on_tpu, preset, rows):
+    """At ``dots.vlm1``'s widths (128 heads, a query latent) and Ling's (32
+    heads, none) Mosaic takes the two ``wkv_b`` kernels with their
+    windows of the five-layer stack, and nothing the program writes holds
+    a whole layer of ``wq_b`` (``wq``) or ``wkv_b``, by element count
+    whatever the shape (``[1,1536,24576]``, ``[128,192,1536]``,
+    ``[1,512,32768]``, ``[512,128,256]``): the query product takes the
+    stack inside its own fusion and lays out the PRODUCT anew."""
+    text, layer = _mla_projections(one_chip, preset, rows)
+    assert len(layer) == 2
+    # absorb, attention and unabsorb a layer
+    for kernel in ("mla_absorb", "latent_paged_attention", "mla_unabsorb"):
+        assert len(re.findall(rf"%{kernel}[\w.]* = \S+ custom-call\(",
+                              text)) == MLA_LAYERS
+    assert not [op for count, op in _written(text) if count in layer]
+
+
+def test_mla_projections_through_the_einsum_do_write_a_layer_out(
+        one_chip, chip_precision, on_tpu, monkeypatch):
+    """The same program through the einsum (the oracle, which runs off a
+    TPU and for the shapes the kernels refuse): XLA feeds the product
+    batched over heads from a copy of the layer's ``wkv_b`` with the
+    heads major, so the test above cannot pass by matching nothing."""
+    from polyrl_tpu.ops import mla_proj
+
+    monkeypatch.setattr(mla_proj, "in_kernel", lambda cfg, rows: False)
+    text, layer = _mla_projections(one_chip, "dots.vlm1-share16", 65)
+    assert not re.search(r"%mla_(un)?absorb[\w.]* = ", text)
+    hit = {layer[count] for count, _op in _written(text) if count in layer}
+    assert hit == {"wkv_b"}
 
 
 def test_ling_decode_step_compiles_for_v5e_within_memory(one_chip,

@@ -237,3 +237,14 @@ def test_each_pallas_call_we_own_has_a_name():
         lambda *a: kda_state.kda_state_pallas(*a, interpret=True),
         jnp.zeros((3, 4, d, d), jnp.float32), rows, rows, rows, rows,
         jnp.zeros((s, 4), jnp.float32)) == ["kda_state"]
+    from polyrl_tpu.ops import mla_proj
+
+    # and ``mla_absorb`` / ``mla_unabsorb`` that a step's MLA layers
+    # multiplied ``wkv_b`` in the stack (``mla_proj_kernel_steps`` beside)
+    stack = jnp.zeros((2, d, 4 * 2 * d), jnp.float32)
+    assert names(
+        lambda *a: mla_proj.absorb(*a, layer=1, interpret=True),
+        rows, stack) == ["mla_absorb"]
+    assert names(
+        lambda *a: mla_proj.unabsorb(*a, layer=1, interpret=True),
+        rows, stack) == ["mla_unabsorb"]
