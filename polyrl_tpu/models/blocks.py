@@ -30,6 +30,29 @@ def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float) -> jnp.ndarray:
     return (x * weight.astype(jnp.float32)).astype(dtype)
 
 
+def layer_norm(x: jnp.ndarray, weight: jnp.ndarray, bias: jnp.ndarray,
+               eps: float) -> jnp.ndarray:
+    """LayerNorm with a weight and a bias, in float32 as ``rms_norm``."""
+    dtype = x.dtype
+    x = x.astype(jnp.float32)
+    x = x - jnp.mean(x, axis=-1, keepdims=True)
+    x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    return (x * weight.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(dtype)
+
+
+def norm(tree: dict, name: str, x: jnp.ndarray, eps: float,
+         l: int | None = None) -> jnp.ndarray:
+    """The norm ``name`` of ``tree`` (row ``l`` of a stack): a LayerNorm
+    where the tree holds ``<name>_bias`` beside the weight, else the RMS
+    norm."""
+    pick = (lambda a: a) if l is None else (lambda a: a[l])
+    if name + "_bias" in tree:
+        return layer_norm(x, pick(tree[name]), pick(tree[name + "_bias"]),
+                          eps)
+    return rms_norm(x, pick(tree[name]), eps)
+
+
 EXPERT_KEYS = ("we_gate", "we_up", "we_down")
 
 
@@ -268,7 +291,7 @@ def _head(cfg, params, x, logits_for=None):
     """Final norm and the output matmul; ``logits_for`` [B] unembeds one
     position a row of ``x`` [B, T, d]."""
     with jax.named_scope("head"):
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        x = norm(params, "final_norm", x, cfg.rms_norm_eps)
         head = (params["embed"].T if cfg.tie_word_embeddings
                 else params["lm_head"])
         if logits_for is not None:

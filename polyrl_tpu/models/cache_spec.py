@@ -7,13 +7,27 @@ three short convolutions), ``mla`` (multi-head latent attention: one
 normed latent row and one shared rope key a token) or ``cca`` (compressed
 convolutional attention: grouped-query attention over query and key
 latents that two short causal convolutions mix, half the value heads
-shifted by one token). An MLP is ``dense``
+shifted by one token); and the six kinds of the SambaY family
+(``mb_per_layer`` > 0, below). An MLP is ``dense``
 (SwiGLU) or ``moe`` (routed experts, ``decoder._moe_mlp``). Every preset
 from before the hybrid family is the uniform pattern: ``gqa`` in every
 layer, with the same MLP in every layer. The DeepSeek-V3 family
 (``kv_lora_rank`` without a ``layer_group_size``) is ``mla`` in every
 layer behind leading dense layers. ``cca_time0`` > 0 is ``cca`` in every
 layer (ZAYA1's decoder).
+
+The SambaY family (``mb_per_layer`` > 0: a decoder, a cross-decoder and
+differential attention without positions) has six kinds that follow from
+``mb_per_layer``, ``sliding_window`` and the layer's index ``i``, with ``h``
+half the layers: below ``h``, ``ssm`` (a Mamba-1 selective scan) where ``i %
+mb_per_layer == 0`` and ``swa`` (attention over the last
+``sliding_window`` keys) otherwise; at ``h``, ``ssm_mem``: the same scan,
+whose output ``m`` before its gate is handed down the layers of the same
+step; at ``h + 1``, ``diff``: full attention that WRITES the one paged K/V
+pair the model has; above, ``gmu`` (a gated memory unit on ``m``, which
+keeps nothing) where ``i % mb_per_layer == 0`` and ``cross`` (attention
+with its own queries over the ``diff`` layer's pages, which keeps nothing
+and READS another layer's pool) otherwise.
 
 What a sequence keeps is PAGED (so many values a token, in pages
 that the engine's allocator hands out: a K/V pair of ``[Hkv, N, page, D]``
@@ -24,7 +38,20 @@ for ``cca``, BOTH in one layer (``PagedAndSlot``): the finished keys and
 the (shifted) values of every token as a K/V pair in pages, and in the
 slot what the next token's convolutions and value shift read of the
 tokens before it (the pre-convolution latents' tail, the first
-convolution's output's tail, the last token's second value half).
+convolution's output's tail, the last token's second value half). A
+``swa`` layer keeps a RING: a K/V pair of the last ``window`` tokens in
+pages that belong to the SLOT (``window / page_size`` pages a slot in a
+pool of the layer's own, at a place fixed when the pool is made: token
+``t`` lies at ``t % window``, and a row's length is ``min(t + 1,
+window)``): attention without positions does not care for the order of
+its keys, so the paged write and attention kernels serve it as they are,
+nothing is ever freed, and the allocator and the ledger never see it: a
+sequence's window costs the same whatever its length. A ``cross`` layer
+keeps NOTHING and names the layer whose pages it reads (``Reads``;
+``pool_index`` hands it the producer's pool), so a page's bytes are ONE
+layer's for the whole model. What a layer hands to later layers of the
+same step (``ssm_mem``'s ``m``, as a router's carried latent) is cached
+nowhere.
 
 ``CBEngine`` asks two questions of a model's layers (ARCHITECTURE.md,
 "Cache specification"). Does a sequence keep anything outside pages
@@ -45,8 +72,8 @@ copies are written for a K/V pair without tails: ``without_kernel`` names
 Readers: ``decoder.make_paged_pools`` and ``CBEngine._make_pools`` (the
 arrays; the page ledger takes its bytes a page from the paged ones),
 ``CBEngine`` (the two questions), ``models/hybrid.py`` (the layer loop);
-``benchmark/lib/costs_hybrid.py`` and ``costs_latent.py`` repeat the
-arithmetic on their own."""
+``benchmark/lib/costs_hybrid.py``, ``costs_latent.py``, ``costs_cca.py``
+and ``costs_sambay.py`` repeat the arithmetic on their own."""
 
 from __future__ import annotations
 
@@ -57,7 +84,9 @@ import jax.numpy as jnp
 
 @dataclasses.dataclass(frozen=True)
 class LayerPlan:
-    mixer: str        # "gqa" | "kda" | "mla" | "cca"
+    # "gqa" | "kda" | "mla" | "cca" | "ssm" | "swa" | "ssm_mem" | "diff" |
+    # "gmu" | "cross"
+    mixer: str
     mlp: str          # "dense" | "moe"
     published: int    # the layer's index in the published model
 
@@ -95,12 +124,35 @@ class PagedAndSlot:
     slot: Slot
 
 
+@dataclasses.dataclass(frozen=True)
+class Ring:
+    """A K/V pair of the last ``window`` tokens a slot, ``[heads, 1 + slots
+    * window / page_size, page_size, width]`` each: pages that belong to
+    the slot (slot ``i``'s are ``1 + i * n .. 1 + i * n + n - 1``, page 0
+    the null page)."""
+    heads: int
+    width: int
+    window: int
+    dtype: object
+
+    def bytes_per_slot(self) -> int:
+        return (2 * self.heads * self.width * self.window
+                * jnp.dtype(self.dtype).itemsize)
+
+
+@dataclasses.dataclass(frozen=True)
+class Reads:
+    """A layer that keeps nothing and reads the pages of layer ``layer``
+    (its place in the plan)."""
+    layer: int
+
+
 def paged_part(c) -> Paged | None:
     return c if isinstance(c, Paged) else getattr(c, "paged", None)
 
 
-def slot_part(c) -> Slot | None:
-    return c if isinstance(c, Slot) else getattr(c, "slot", None)
+def slot_part(c) -> Slot | Ring | None:
+    return c if isinstance(c, (Slot, Ring)) else getattr(c, "slot", None)
 
 
 # the type the recurrent state is kept in (Ling's config: float32). A
@@ -114,16 +166,29 @@ def layer_plan(cfg) -> tuple[LayerPlan, ...]:
     family: published layer ``i`` is ``mla`` where ``(i + 1) %
     layer_group_size == 0`` and ``kda`` otherwise. ``kv_lora_rank`` > 0
     without it is latent attention in every layer; neither is ``gqa`` in
-    every layer. ``first_k_dense_replace`` leading published layers keep
+    every layer; ``mb_per_layer`` > 0 is the SambaY family's six kinds
+    (module docstring). ``first_k_dense_replace`` leading published layers keep
     the dense MLP. ``kept_layers`` names the published layers that run
     here (a depth cut), all of them by default."""
     kept = cfg.kept_layers or tuple(range(cfg.num_layers))
     if len(kept) != cfg.num_layers:
         raise ValueError(f"kept_layers {kept} names {len(kept)} layers, "
                          f"num_layers is {cfg.num_layers}")
+    if cfg.mb_per_layer and cfg.kept_layers:
+        raise ValueError("a depth cut of a model whose upper layers read "
+                         "what layers below them keep")
+    half = cfg.num_layers // 2
     plan = []
     for i in kept:
-        if cfg.layer_group_size:
+        if cfg.mb_per_layer:
+            scan = i % cfg.mb_per_layer == 0
+            if i < half:
+                mixer = "ssm" if scan else "swa"
+            elif i < half + 2:
+                mixer = "ssm_mem" if i == half else "diff"
+            else:
+                mixer = "gmu" if scan else "cross"
+        elif cfg.layer_group_size:
             mixer = "mla" if (i + 1) % cfg.layer_group_size == 0 else "kda"
         elif cfg.cca_time0:
             mixer = "cca"
@@ -137,7 +202,7 @@ def layer_plan(cfg) -> tuple[LayerPlan, ...]:
 def is_uniform(cfg) -> bool:
     """Every layer alike and ``gqa``: the stacked-scan decoder."""
     return (not cfg.layer_group_size and not cfg.kv_lora_rank
-            and not cfg.cca_time0
+            and not cfg.cca_time0 and not cfg.mb_per_layer
             and not (cfg.num_experts and cfg.first_k_dense_replace))
 
 
@@ -177,9 +242,45 @@ def cca_dims(cfg) -> tuple[int, int, int]:
     return cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
 
 
+def ssm_dims(cfg) -> tuple[int, int, int, int]:
+    """(inner width, state size, convolution taps, rank of dt) of a Mamba
+    layer: ``expand * hidden``, and a sixteenth of the hidden size where
+    the configuration names no rank."""
+    return (cfg.ssm_expand * cfg.hidden_size, cfg.ssm_state_size,
+            cfg.ssm_conv_kernel, cfg.ssm_dt_rank or cfg.hidden_size // 16)
+
+
+def diff_dims(cfg) -> tuple[int, int, int]:
+    """(differential heads, K/V pairs, a pair's width) of a differential
+    attention layer: adjacent query heads and adjacent K/V heads paired,
+    a pair's two heads kept side by side."""
+    return cfg.num_heads // 2, cfg.num_kv_heads // 2, 2 * cfg.head_dim_
+
+
+def producer(cfg, mixer: str) -> int:
+    """The place in the plan of the one layer of kind ``mixer``
+    (``ssm_mem``, ``diff``) that later layers of a step read."""
+    return next(l for l, p in enumerate(layer_plan(cfg)) if p.mixer == mixer)
+
+
 def layer_cache(cfg, plan: LayerPlan, dtype=None
-                ) -> Paged | Slot | PagedAndSlot:
+                ) -> Paged | Slot | PagedAndSlot | Ring | Reads | None:
     dtype = dtype or cfg.dtype
+    if plan.mixer in ("ssm", "ssm_mem"):
+        # the state with the inner width on the lanes: ``[state, inner]``
+        # is whole (8, 128) tiles, ``[inner, state]`` would be padded
+        # eightfold on the chip
+        inner, n, k, _rank = ssm_dims(cfg)
+        return Slot((("state", (n, inner), STATE_DTYPE),
+                     ("conv", (k - 1, inner), dtype)))
+    if plan.mixer in ("swa", "diff"):
+        _h, pairs, width = diff_dims(cfg)
+        return (Paged(2, pairs, width) if plan.mixer == "diff"
+                else Ring(pairs, width, cfg.sliding_window, dtype))
+    if plan.mixer == "cross":
+        return Reads(producer(cfg, "diff"))
+    if plan.mixer == "gmu":
+        return None
     if plan.mixer == "gqa":
         return Paged(2, cfg.num_kv_heads, cfg.head_dim_)
     if plan.mixer == "mla":
@@ -210,7 +311,10 @@ def is_stateful(cfg) -> bool:
 # features of the engine that act on pages through a kernel (or a copy)
 # written for one kind of paged cache, and the mixers that have it
 # (``cca`` keeps a K/V pair too, and has none of the three: each re-enters
-# a sequence where its tails are not)
+# a sequence where its tails are not; the SambaY family's kinds have none
+# either: its one K/V pair holds two heads side by side under queries that
+# are half zero, and a scan's state and a ring have no page to share,
+# verify over or spill)
 FEATURE_KERNELS = {
     "decode_group_share": ("gqa",),   # ops.paged_attention's grouped kernel
     "spec_tokens": ("gqa",),          # the multi-token verify forward
@@ -240,13 +344,16 @@ def slot_bytes(cfg, dtype=None) -> int:
 def pool_index(cfg) -> tuple[tuple[int | None, int | None], ...]:
     """For each layer: (its place among the layers that keep pages, its
     place among the layers that keep a slot), None where it keeps none:
-    the indices into ``make_pools``' two tuples."""
+    the indices into ``make_pools``' two tuples. A layer that reads
+    another layer's pages (``Reads``) is handed that layer's place."""
+    spec = cache_spec(cfg)
     out, n_paged, n_slot = [], 0, 0
-    for c in cache_spec(cfg):
+    for c in spec:
         has_p, has_s = paged_part(c) is not None, slot_part(c) is not None
         out.append((n_paged if has_p else None, n_slot if has_s else None))
         n_paged, n_slot = n_paged + has_p, n_slot + has_s
-    return tuple(out)
+    return tuple((out[c.layer][0], None) if isinstance(c, Reads) else o
+                 for o, c in zip(out, spec))
 
 
 def make_pools(cfg, num_pages: int, page_size: int, slots: int = 0,
@@ -260,6 +367,10 @@ def make_pools(cfg, num_pages: int, page_size: int, slots: int = 0,
     tuple with one tuple of ``[slots, *shape]`` arrays for each layer that
     keeps a slot, in order (``(state [slots, H, Dk, Dv] float32, conv
     [slots, K-1, channels])`` for ``kda``; the three tails for ``cca``;
+    ``(state [slots, N, inner] float32, conv [slots, K-1, inner])`` for
+    ``ssm``; a ``swa`` layer's ring, a ``(k, v)`` pair of ``[pairs, 1 +
+    slots * window / page_size, page_size, width]`` whose pages are the
+    slot's (``Ring``);
     none for a model that is ``mla`` in every layer). A ``cca`` layer has
     an entry in both (``pool_index``). The
     engine hands ``slots = max_slots + 1``: the last row is the sink that padding rows of an admission wave write to."""
@@ -277,7 +388,15 @@ def make_pools(cfg, num_pages: int, page_size: int, slots: int = 0,
                                pages.width), dtype)
                     for _ in range(pages.arrays)]
             paged.append(pool[0] if pages.arrays == 1 else tuple(pool))
-        if slot is not None:
+        if isinstance(slot, Ring):
+            if slot.window % page_size:
+                raise ValueError(f"a window of {slot.window} keys in pages "
+                                 f"of {page_size}")
+            ring = (slot.heads, 1 + slots * (slot.window // page_size),
+                    page_size, slot.width)
+            state.append((jnp.zeros(ring, slot.dtype),
+                          jnp.zeros(ring, slot.dtype)))
+        elif slot is not None:
             state.append(tuple(jnp.zeros((slots, *shape), dt)
                                for _name, shape, dt in slot.arrays))
     return tuple(paged), tuple(state)

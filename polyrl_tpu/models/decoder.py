@@ -37,7 +37,7 @@ from jax.sharding import PartitionSpec as P
 from polyrl_tpu.models import cache_spec, hybrid
 from polyrl_tpu.models.blocks import (EXPERT_KEYS, _head, _moe_mlp,  # noqa: F401
                                       _scatter_pages_kv, _scatter_token_kv,
-                                      rms_norm)
+                                      norm, rms_norm)
 from polyrl_tpu.models.quant import LoraWeight, QuantWeight, mm
 from polyrl_tpu.ops.attention import attention, causal_mask
 from polyrl_tpu.parallel.mesh import DP, EP, FSDP, SP, TP
@@ -135,6 +135,21 @@ class ModelConfig:
     # > 0: the routed MLP's router is an MLP on a latent of this width that
     # each layer hands to the next (``blocks._latent_route``)
     router_hidden_size: int = 0
+    # the SambaY family (``phi4flash``; ``models/hybrid.py``):
+    # ``mb_per_layer`` > 0 makes the six kinds of ``cache_spec.layer_plan``
+    # (a Mamba-1 scan every ``mb_per_layer`` layers of the lower half with
+    # attention over the last ``sliding_window`` keys between, then one
+    # full-attention layer whose K/V the cross layers of the upper half
+    # read, gated memory units between those), differential attention
+    # without positions in every attention layer, LayerNorm with a bias
+    # (``rms_norm_eps`` is its epsilon). The Mamba sizes are the family's
+    # (no published key): ``ssm_dt_rank`` 0 is a sixteenth of the hidden size
+    mb_per_layer: int = 0
+    sliding_window: int = 0
+    ssm_state_size: int = 16
+    ssm_conv_kernel: int = 4
+    ssm_expand: int = 2
+    ssm_dt_rank: int = 0
     dtype: Any = jnp.bfloat16
 
     @property
@@ -379,6 +394,31 @@ PRESETS["cca-tiny"] = ModelConfig(
     max_position_embeddings=512, partial_rotary_factor=0.5,
     num_experts=4, num_experts_per_tok=1, moe_intermediate_size=32,
     norm_topk_prob=False, cca_time0=2, cca_time1=2, router_hidden_size=8,
+)
+
+
+# Phi-4-mini-flash-reasoning (HF config: microsoft/Phi-4-mini-flash-
+# reasoning, model_type phi4flash; ``hf_loader.phi4flash_config`` of the
+# published keys gives this, tested): SambaY. 9 Mamba-1 layers (0, 2, ...,
+# 16), 8 layers of attention over the last 512 keys (1, 3, ..., 15), one
+# full-attention layer (17) whose K/V the 7 cross layers (19, ..., 31) read,
+# 7 gated memory units (18, ..., 30) on layer 16's scan output;
+# differential attention at 40 query heads over 20 K/V heads of 64, paired;
+# no rope (``rope_theta`` unused); a tied head over 200,064 rows
+PRESETS["phi-4-mini-flash-reasoning"] = ModelConfig(
+    vocab_size=200064, hidden_size=2560, intermediate_size=10240,
+    num_layers=32, num_heads=40, num_kv_heads=20, rms_norm_eps=1e-5,
+    tie_word_embeddings=True, max_position_embeddings=262144,
+    mb_per_layer=2, sliding_window=512,
+)
+# test-size model of the same family: 12 layers in the same pattern (3
+# scans and 3 window layers of 8 keys, the scan that feeds 2 gated memory
+# units, the K/V layer that 2 cross layers read), 8 query heads over 4
+PRESETS["sambay-tiny"] = ModelConfig(
+    vocab_size=512, hidden_size=64, intermediate_size=128, num_layers=12,
+    num_heads=8, num_kv_heads=4, rms_norm_eps=1e-5,
+    tie_word_embeddings=True, max_position_embeddings=512,
+    mb_per_layer=2, sliding_window=8, ssm_state_size=4,
 )
 
 
@@ -639,7 +679,7 @@ def head_and_sample(cfg, params, x, rng, temps):
     from polyrl_tpu.ops.fused_sample import head_sample_pallas
 
     with jax.named_scope("head"):
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        x = norm(params, "final_norm", x, cfg.rms_norm_eps)
         tied = cfg.tie_word_embeddings
         return head_sample_pallas(
             x, params["embed" if tied else "lm_head"], rng, temps, tied=tied,

@@ -139,13 +139,39 @@ def zaya_config(hf: dict, dtype=jnp.bfloat16) -> decoder.ModelConfig:
         router_hidden_size=hf["router_hidden_size"], dtype=dtype)
 
 
+def phi4flash_config(hf: dict, dtype=jnp.bfloat16) -> decoder.ModelConfig:
+    """A ``ModelConfig`` from a ``phi4flash`` config.json (microsoft/
+    Phi-4-mini-flash-reasoning): every key the published file has that the
+    decoder reads. The Mamba sizes have no published key and stay the
+    family's (``ModelConfig``'s defaults); a bias on the MLP or the head is
+    not written. The checkpoint's tensors have no key map yet
+    (``load_hf_params`` says so)."""
+    if hf.get("mlp_bias") or hf.get("lm_head_bias"):
+        raise NotImplementedError(
+            "phi4flash with mlp_bias or lm_head_bias: neither is written")
+    return decoder.ModelConfig(
+        vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        num_layers=hf["num_hidden_layers"],
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"],
+        rms_norm_eps=float(hf["layer_norm_eps"]),
+        tie_word_embeddings=bool(hf.get("tie_word_embeddings", True)),
+        max_position_embeddings=hf["max_position_embeddings"],
+        mb_per_layer=hf["mb_per_layer"],
+        sliding_window=hf["sliding_window"], dtype=dtype)
+
+
 def config_from_hf(ckpt_dir: str, dtype=jnp.bfloat16) -> decoder.ModelConfig:
     """Build a ModelConfig from the checkpoint's config.json (llama/qwen2/
-    qwen3 architectures; ``zaya``: ``zaya_config``)."""
+    qwen3 architectures; ``zaya``: ``zaya_config``; ``phi4flash``:
+    ``phi4flash_config``)."""
     with open(os.path.join(ckpt_dir, "config.json")) as f:
         hf = json.load(f)
     if hf.get("model_type") == "zaya":
         return zaya_config(hf, dtype)
+    if hf.get("model_type") == "phi4flash":
+        return phi4flash_config(hf, dtype)
     rope_scaling = rope_scaling_from_hf(hf.get("rope_scaling"))
     moe: dict = {}
     if hf.get("num_experts"):  # Qwen3-MoE family
@@ -221,6 +247,11 @@ def load_hf_params(ckpt_dir: str, cfg: decoder.ModelConfig | None = None,
         raise NotImplementedError(
             "no key map for a zaya (CCA) checkpoint yet: write it from the "
             "published model.safetensors.index.json (ROADMAP.md Queue 2)")
+    if cfg.mb_per_layer:
+        raise NotImplementedError(
+            "no key map for a phi4flash (SambaY) checkpoint yet: write it "
+            "from the published model.safetensors.index.json (ROADMAP.md "
+            "Queue 2)")
     dtype = dtype or cfg.dtype
     np_dtype = jnp.dtype(dtype)
 

@@ -14,6 +14,12 @@ of its layers is alike, so the stacked scan would fit its trainer's
 forward; it lives here because the slot state and a second tensor carried
 beside the residual stream are what this file's unrolled loop threads.
 
+And the SambaY family (``phi4flash``: Phi-4-mini-flash-reasoning): a
+decoder of Mamba-1 scans and window attention, one full-attention layer
+whose K/V the cross-decoder's layers read, gated memory units on the last
+scan's output of the same step, differential attention without positions
+in every attention layer, LayerNorm with a bias (equations below).
+
 One block a kind, parameters stacked per kind::
 
     params["layers"] = {
@@ -31,6 +37,16 @@ One block a kind, parameters stacked per kind::
                 conv0 [Lc, K0, (Hq+Hkv)*D], conv1 [Lc, K1, Hq+Hkv, D, D],
                 tau [Lc, Hkv] float32, wo [Lc, Hq*D, d]}
       "attn_res", "mlp_res": [L, 4, d]  (a_r, b_r, a_o, b_o; ``cca`` models)
+      "attn_norm_bias", "mlp_norm_bias": [L, d]   (SambaY: LayerNorm)
+      "ssm":   {w_in [Ls, d, 2*I]  (xi | z), conv [Ls, K, I], conv_bias [Ls, I],
+                w_x [Ls, I, R + 2*N]  (dt | B | C), w_dt [Ls, R, I],
+                dt_bias [Ls, I] a_log [Ls, N, I] d_skip [Ls, I] float32,
+                w_out [Ls, I, d]}                  (``ssm`` and ``ssm_mem``)
+      "attn":  {wqkv [La, d, (Hq + 2*Hkv)*D], bqkv, wo [La, Hq*D, d], bo [La, d],
+                lq1 lk1 lq2 lk2 [La, D] float32, sub_norm [La, 2*D]}
+                                                   (``swa`` and ``diff``)
+      "cross": {wq [Lc, d, Hq*D], bq, wo, bo, lq1 lk1 lq2 lk2, sub_norm}
+      "gmu":   {w_in [Lg, d, I], w_out [Lg, I, d]}
       "dense": {w_gate w_up [Ld, d, f], w_down [Ld, f, d]}
       "moe":   {router [Ls, d, E_all], router_bias [Ls, E_all] float32,
                 we_gate we_up [Ls, E_held, d, fe], we_down [Ls, E_held, fe, d],
@@ -77,10 +93,39 @@ Hq query heads over Hkv K/V heads of size D, ``c = [q~ ; k~]``::
 
 Pages hold the finished ``k`` and ``v``; the slot holds the last K0-1 rows
 of ``c``, the last K1-1 rows of ``u`` and the last token's ``vb``. A
-sublayer's residual is ``(a_r x + b_r) + (a_o F(rms(x)) + b_o)``."""
+sublayer's residual is ``(a_r x + b_r) + (a_o F(rms(x)) + b_o)``.
+
+SambaY (ISSUE 43; the reference's docstring, ``benchmark/references/
+sambay_diff.py``, carries every line and each choice the published config
+does not settle; ``benchmark/configs/phi-4-mini-flash-reasoning.json``
+lists them under ``assumed``). Mamba-1, inner width I, state N a channel::
+
+    xi, z = split(x W_in)       c[t] = silu(sum_j conv[j] * xi[t-K+1+j] + b)
+    dt, B, C = split(c W_x)     dt = softplus(dt W_dt + dt_bias)
+    s[t] = exp(dt[t] A) * s[t-1] + (dt[t] c[t]) B[t]^T     A = -exp(a_log)
+    m[t] = s[t] C[t] + d_skip * c[t]      out = (m[t] * silu(z[t])) W_out
+
+The state is kept ``[N, I]`` float32 (the inner width on the lanes) with
+the last K-1 rows of ``xi``; a decode step updates it in place in one
+kernel a layer (``ops/ssm_state.py``), prefill scans ``ssm_step`` position
+by position. A gated memory unit is ``(silu(x W_1) * m) W_2``
+with ``m`` the ``ssm_mem`` layer's of the same token. Differential
+attention, Hd = Hq/2 heads over Hkv/2 K/V pairs, head j on pair j // 2::
+
+    a1 = softmax(q[j,0] k[g,0]^T / sqrt(D))   a2 = softmax(q[j,1] k[g,1]^T / sqrt(D))
+    o_j = rms_2D((a1 - lam a2) [v[g,0] | v[g,1]]) * sub_norm * (1 - lam_init)
+    lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lam_init
+    lam_init = 0.8 - 0.6 exp(-0.3 i)          (i the published layer)
+
+A pair's two heads lie side by side in the cache (``[k0 | k1]``, ``[v0 |
+v1]``, 2D = 128 wide), and a decode step's queries are ``(q[j,0] | 0)`` and
+``(0 | q[j,1])``: the paged kernels written for one softmax a head of 128
+then give ``a1 [v0 | v1]`` and ``a2 [v0 | v1]`` exactly. A window layer's
+keys are the last ``sliding_window`` tokens, the token itself among them."""
 
 from __future__ import annotations
 
+import collections
 import math
 
 import jax
@@ -90,7 +135,7 @@ import numpy as np
 from polyrl_tpu.models import cache_spec
 from polyrl_tpu.models.blocks import (EXPERT_KEYS, _head, _latent_route,
                                       _moe_mlp, _scatter_pages_kv,
-                                      _scatter_token_kv, rms_norm)
+                                      _scatter_token_kv, norm, rms_norm)
 from polyrl_tpu.models.quant import mm
 
 _HI = jax.lax.Precision.HIGHEST
@@ -113,13 +158,24 @@ def kda_chunk(cfg) -> int:
 # -- parameters -----------------------------------------------------------------
 
 
-def _counts(cfg) -> dict:
+# the stack of ``params["layers"]`` a mixer's weights lie in, where that
+# is not the mixer's own name: kinds of one shape share a stack
+_STACK = {"ssm_mem": "ssm", "swa": "attn", "diff": "attn"}
+# the softplus of a scan's ``dt_bias`` as drawn: log-uniform between these
+DT_INIT = (0.001, 0.1)
+# a differential layer's four lambda vectors as drawn
+LAMBDA_STD = 0.1
+
+
+def stack_of(mixer: str) -> str:
+    return _STACK.get(mixer, mixer)
+
+
+def _counts(cfg) -> collections.Counter:
+    """Layers a stack of mixer weights and a kind of MLP holds."""
     plan = cache_spec.layer_plan(cfg)
-    return {"kda": sum(p.mixer == "kda" for p in plan),
-            "mla": sum(p.mixer == "mla" for p in plan),
-            "cca": sum(p.mixer == "cca" for p in plan),
-            "dense": sum(p.mlp == "dense" for p in plan),
-            "moe": sum(p.mlp == "moe" for p in plan)}
+    return collections.Counter([stack_of(p.mixer) for p in plan]
+                               + [p.mlp for p in plan])
 
 
 def kind_index(cfg) -> list[tuple[int, int]]:
@@ -128,8 +184,9 @@ def kind_index(cfg) -> list[tuple[int, int]]:
     seen: dict = {}
     out = []
     for p in cache_spec.layer_plan(cfg):
-        i, j = seen.get(p.mixer, 0), seen.get(p.mlp, 0)
-        seen[p.mixer], seen[p.mlp] = i + 1, j + 1
+        mixer = stack_of(p.mixer)
+        i, j = seen.get(mixer, 0), seen.get(p.mlp, 0)
+        seen[mixer], seen[p.mlp] = i + 1, j + 1
         out.append((i, j))
     return out
 
@@ -208,6 +265,50 @@ def init_params(rng: jax.Array, cfg) -> dict:
         one = jnp.array([1.0, 0.0, 1.0, 0.0], cfg.dtype)[None, :, None]
         for name in ("attn_res", "mlp_res"):
             layers[name] = norm(L, 4, d) * (1 - one) + one
+    if n["ssm"]:
+        m = n["ssm"]
+        inner, ns, kk, rank = cache_spec.ssm_dims(cfg)
+        count[0] += 1
+        u = jax.random.uniform(jax.random.fold_in(rng, count[0]), (m, inner))
+        dt = jnp.exp(u * math.log(DT_INIT[1] / DT_INIT[0])
+                     + math.log(DT_INIT[0]))
+        layers["ssm"] = {
+            "w_in": norm(m, d, 2 * inner),
+            "conv": norm(m, kk, inner).at[:, -1].add(1.0),
+            "conv_bias": jnp.zeros((m, inner), cfg.dtype),
+            "w_x": norm(m, inner, rank + 2 * ns),
+            "w_dt": norm(m, rank, inner),
+            # the inverse of softplus at ``dt``
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "a_log": jnp.broadcast_to(jnp.log(jnp.arange(
+                1, ns + 1, dtype=jnp.float32))[None, :, None], (m, ns, inner)),
+            "d_skip": jnp.ones((m, inner), jnp.float32),
+            "w_out": norm(m, inner, d),
+        }
+        for name in ("attn_norm", "mlp_norm"):
+            layers[name + "_bias"] = jnp.zeros((L, d), cfg.dtype)
+    hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+
+    def diff_heads(m):
+        lam = {k: norm(m, hd, dtype=jnp.float32, scale=LAMBDA_STD / std)
+               for k in ("lq1", "lk1", "lq2", "lk2")}
+        return {**lam, "sub_norm": ones(m, 2 * hd),
+                "wo": norm(m, hq * hd, d), "bo": jnp.zeros((m, d), cfg.dtype)}
+
+    if n["attn"]:
+        m, wide = n["attn"], (hq + 2 * hkv) * hd
+        layers["attn"] = {"wqkv": norm(m, d, wide),
+                          "bqkv": jnp.zeros((m, wide), cfg.dtype),
+                          **diff_heads(m)}
+    if n["cross"]:
+        m = n["cross"]
+        layers["cross"] = {"wq": norm(m, d, hq * hd),
+                           "bq": jnp.zeros((m, hq * hd), cfg.dtype),
+                           **diff_heads(m)}
+    if n["gmu"]:
+        inner = cache_spec.ssm_dims(cfg)[0]
+        layers["gmu"] = {"w_in": norm(n["gmu"], d, inner),
+                         "w_out": norm(n["gmu"], inner, d)}
     if n["dense"]:
         f = cfg.intermediate_size
         layers["dense"] = {"w_gate": norm(n["dense"], d, f),
@@ -239,6 +340,8 @@ def init_params(rng: jax.Array, cfg) -> dict:
                                  ws_down=norm(s, fs, d))
     params = {"embed": norm(cfg.vocab_size, d), "final_norm": ones(d),
               "layers": layers}
+    if cfg.mb_per_layer:
+        params["final_norm_bias"] = jnp.zeros((d,), cfg.dtype)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = norm(d, cfg.vocab_size)
     return params
@@ -254,7 +357,7 @@ def param_specs(cfg) -> dict:
 
     col, row, rep2 = P(None, FSDP, TP), P(None, TP, FSDP), P(None, None)
     shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
-    rows = {"wo", "w_down", "ws_down"}
+    rows = {"wo", "w_down", "ws_down", "w_out"}
 
     def spec(path, leaf):
         name = path[-1].key
@@ -268,7 +371,7 @@ def param_specs(cfg) -> dict:
             return P(FSDP, TP)
         if leaf.ndim == 3 and name.startswith(("w", "router")) \
                 and name != "router_bias":
-            if name in ("wb", "wgate", "router", "wkv_a"):
+            if name in ("wb", "wgate", "router", "wkv_a", "w_x", "w_dt"):
                 return P(None, FSDP, None)
             return row if name in rows else col
         return P(*([None] * leaf.ndim)) if leaf.ndim != 2 else rep2
@@ -601,6 +704,239 @@ def _residual(x, out, res):
 
 
 
+# -- the SambaY family's blocks ---------------------------------------------
+
+
+def _ssm_inputs(cfg, lp, h_in, tail):
+    """Everything of a Mamba layer before its recurrence, for ``h_in``
+    [B, T, d] after the convolution tail ``tail`` [B, K-1, I] (the rows of
+    ``xi`` before the chunk): (c [B, T, I] float32 after convolution and
+    silu, z [B, T, I], dt [B, T, I] float32 after the softplus, B and C
+    [B, T, N] float32, ``[tail | xi]`` [B, K-1+T, I])."""
+    inner, n, kk, rank = cache_spec.ssm_dims(cfg)
+    t = h_in.shape[1]
+    xz = mm(h_in, lp["w_in"])
+    xi, z = xz[..., :inner], xz[..., inner:]
+    full = jnp.concatenate([tail.astype(xi.dtype), xi], axis=1)
+    w = lp["conv"].astype(jnp.float32)
+    c = jax.nn.silu(sum(full[:, j:j + t].astype(jnp.float32) * w[j]
+                        for j in range(kk))
+                    + lp["conv_bias"].astype(jnp.float32))
+    dbc = mm(c.astype(h_in.dtype), lp["w_x"])
+    dt = jax.nn.softplus(mm(dbc[..., :rank], lp["w_dt"]).astype(jnp.float32)
+                         + lp["dt_bias"])
+    bm = dbc[..., rank:rank + n].astype(jnp.float32)
+    cm = dbc[..., rank + n:].astype(jnp.float32)
+    return c, z, dt, bm, cm, full
+
+
+def ssm_step(lp, state, c, dt, bm, cm):
+    """One position of the selective scan for rows ``state`` [S, N, I]:
+    (new state, m [S, I]); everything float32."""
+    a = -jnp.exp(lp["a_log"])                              # [N, I]
+    new = (jnp.exp(dt[:, None, :] * a) * state
+           + (dt * c)[:, None, :] * bm[:, :, None])
+    m = jnp.sum(new * cm[:, :, None], axis=1) + lp["d_skip"] * c
+    return new, m
+
+
+def ssm_scan(lp, state, c, dt, bm, cm):
+    """``ssm_step`` over ``T`` positions, one after the other: ``state``
+    [B, N, I], c dt [B, T, I], bm cm [B, T, N] -> (state after T, m [B, T,
+    I]). A position with ``dt`` 0 leaves the state as it is. The one form
+    that decode and the reference have: a blocked form (16 positions an
+    iteration, the decays between them in one fusion) cost a 512-token
+    chunk's nine scans 14.0 ms on the chip where this costs 4.7
+    (``tools/trace_prefill_chunk.py``; PERF.md section 6, PR 43)."""
+    def step(s, xs):
+        return ssm_step(lp, s, *xs)
+
+    state, m = jax.lax.scan(step, state, tuple(x.swapaxes(0, 1)
+                                               for x in (c, dt, bm, cm)))
+    return state, m.swapaxes(0, 1)
+
+
+def _ssm_out(lp, m, z):
+    return mm((m * jax.nn.silu(z.astype(jnp.float32))).astype(z.dtype),
+              lp["w_out"])
+
+
+def _ssm_sequence(cfg, lp, h_in, valid, state, tail):
+    """A Mamba mixer over ``h_in`` [B, T, d] (``valid`` [B, T], padding on
+    the right) from (``state`` [B, N, I] float32, ``tail`` [B, K-1, I]):
+    (out [B, T, d], the scan's output ``m`` [B, T, I] float32 before its
+    gate, state, tail after the last valid position)."""
+    kk = cfg.ssm_conv_kernel
+    with jax.named_scope("ssm_proj"):
+        h_in = h_in * valid[..., None].astype(h_in.dtype)
+        c, z, dt, bm, cm, full = _ssm_inputs(cfg, lp, h_in, tail)
+        dt = jnp.where(valid[..., None], dt, 0.0)
+        n_valid = jnp.sum(valid.astype(jnp.int32), axis=1)
+        new_tail = jax.vmap(lambda f, s: jax.lax.dynamic_slice_in_dim(
+            f, s, kk - 1, 0))(full, n_valid)
+    with jax.named_scope("ssm_core"):
+        state, m = ssm_scan(lp, state.astype(jnp.float32), c, dt, bm, cm)
+    with jax.named_scope("ssm_proj"):
+        return _ssm_out(lp, m, z), m, state, new_tail.astype(tail.dtype)
+
+
+def _gmu(lp, h_in, m):
+    """A gated memory unit: ``(silu(x W_1) * m) W_2``."""
+    with jax.named_scope("gmu"):
+        gate = jax.nn.silu(mm(h_in, lp["w_in"]).astype(jnp.float32))
+        return mm((gate * m).astype(h_in.dtype), lp["w_out"])
+
+
+def lambda_init(published: int) -> float:
+    return 0.8 - 0.6 * math.exp(-0.3 * published)
+
+
+def _diff_qkv(cfg, lp, h_in):
+    """(q [..., Hd, 2, D], and for a layer with keys of its own k and v
+    [..., pairs, 2D]: a pair's two heads side by side, as they are
+    cached)."""
+    hd, pairs, width = cache_spec.diff_dims(cfg)
+    lead = h_in.shape[:-1]
+    with jax.named_scope("attn_qkv"):
+        if "wq" in lp:
+            q = mm(h_in, lp["wq"]) + lp["bq"]
+            return q.reshape(*lead, hd, 2, width // 2), None, None
+        qkv = mm(h_in, lp["wqkv"]) + lp["bqkv"]
+        nq, nk = hd * width, pairs * width
+        return (qkv[..., :nq].reshape(*lead, hd, 2, width // 2),
+                qkv[..., nq:nq + nk].reshape(*lead, pairs, width),
+                qkv[..., nq + nk:].reshape(*lead, pairs, width))
+
+
+def paired_queries(q):
+    """``q`` [..., Hd, 2, D] -> [..., 2 * Hd, 2D]: ``(q[j,0] | 0)`` and
+    ``(0 | q[j,1])``, which against a pair's ``[k0 | k1]`` score ``q[j,0]
+    k0`` and ``q[j,1] k1`` exactly."""
+    zero = jnp.zeros_like(q[..., 0, :])
+    both = jnp.stack([jnp.concatenate([q[..., 0, :], zero], -1),
+                      jnp.concatenate([zero, q[..., 1, :]], -1)], axis=-2)
+    return both.reshape(*q.shape[:-3], 2 * q.shape[-3], 2 * q.shape[-1])
+
+
+def _diff_out(cfg, lp, o, published: int):
+    """From the two softmaxes' outputs ``o`` [..., Hd, 2, 2D] (``a1 [v0 |
+    v1]``, ``a2 [v0 | v1]``) to the sublayer's output: the difference
+    under lambda, the head-wise norm, ``(1 - lam_init)``, ``W_o``."""
+    f32 = jnp.float32
+    with jax.named_scope("diff_mix"):
+        init = lambda_init(published)
+        lam = (jnp.exp(jnp.sum(lp["lq1"] * lp["lk1"]))
+               - jnp.exp(jnp.sum(lp["lq2"] * lp["lk2"])) + init)
+        o = o.astype(f32)
+        o = rms_norm(o[..., 0, :] - lam * o[..., 1, :], lp["sub_norm"],
+                     cfg.rms_norm_eps) * (1.0 - init)
+        o = o.reshape(*o.shape[:-2], -1).astype(lp["wo"].dtype)
+    with jax.named_scope("attn_out"):
+        return mm(o, lp["wo"]) + lp["bo"]
+
+
+def diff_attention(cfg, q, k, v, q_at, k_at, window: int = 0):
+    """Both softmaxes of every differential head for a batch: ``q`` [B, T,
+    Hd, 2, D] against keys ``k`` and values ``v`` [B, Tk, pairs, 2D];
+    ``q_at`` [B, T] and ``k_at`` [B, Tk] are positions in the sequence
+    (``k_at`` < 0: no key there); a query sees the keys at or before it,
+    with ``window`` only the last ``window`` of them. Returns o [B, T, Hd,
+    2, 2D] float32. Blocked over the keys with a running softmax, as
+    ``mla_expanded`` is, so that the scores of 16k keys never stand at
+    once, and a block no query sees is skipped."""
+    b, t, hd = q.shape[:3]
+    tk, pairs, width = k.shape[1:]
+    d = width // 2
+    kb = min(key_block(cfg, b, t), tk)
+    pad = -tk % kb
+    if pad:
+        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        k_at = jnp.pad(k_at, ((0, 0), (0, pad)), constant_values=-1)
+    qg = q.reshape(b, t, pairs, hd // pairs, 2, d)
+    scale = d ** -0.5
+    last = jnp.max(q_at)
+
+    def attend(carry, i):
+        m, l, acc = carry
+        kk = jax.lax.dynamic_slice_in_dim(k, i * kb, kb, 1)
+        vv = jax.lax.dynamic_slice_in_dim(v, i * kb, kb, 1)
+        at = jax.lax.dynamic_slice_in_dim(k_at, i * kb, kb, 1)
+        s = jnp.einsum("bqgjcd,bkgcd->bgjcqk", qg,
+                       kk.reshape(b, kb, pairs, 2, d),
+                       preferred_element_type=jnp.float32) * scale
+        seen = (at[:, None, :] >= 0) & (at[:, None, :] <= q_at[:, :, None])
+        if window:
+            seen &= at[:, None, :] > q_at[:, :, None] - window
+        seen = seen[:, None, None, None]
+        m_new = jnp.maximum(m, jnp.max(jnp.where(seen, s, -1e30), axis=-1,
+                                       keepdims=True))
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        pv = jnp.einsum("bgjcqk,bkgw->bgjcqw", p.astype(vv.dtype), vv,
+                        preferred_element_type=jnp.float32)
+        return (m_new, alpha * l + jnp.sum(p, axis=-1, keepdims=True),
+                alpha * acc + pv)
+
+    def step(carry, i):
+        at = jax.lax.dynamic_slice_in_dim(k_at, i * kb, kb, 1)
+        near = jnp.any((at >= 0) & (at <= last))
+        return jax.lax.cond(near, attend, lambda c, _i: c, carry, i), None
+
+    shape = (b, pairs, hd // pairs, 2, t)
+    init = (jnp.full((*shape, 1), -1e30, jnp.float32),
+            jnp.zeros((*shape, 1), jnp.float32),
+            jnp.zeros((*shape, width), jnp.float32))
+    (_m, l, acc), _ = jax.lax.scan(step, init, jnp.arange((tk + pad) // kb))
+    o = acc / jnp.maximum(l, 1e-30)                   # [B, g, j, c, T, 2D]
+    return o.transpose(0, 4, 1, 2, 3, 5).reshape(b, t, hd, 2, width)
+
+
+def ring_pages(cfg, slots, ps: int):
+    """The pages of a window layer's ring that belong to the slots
+    ``slots`` [B]: [B, window / ps], fixed when the pool was made
+    (``cache_spec.Ring``)."""
+    n = cfg.sliding_window // ps
+    return (1 + slots[:, None] * n
+            + jnp.arange(n, dtype=jnp.int32)[None, :]).astype(jnp.int32)
+
+
+def _ring_read(cfg, ring, slots, prefix_len):
+    """What the rings of ``slots`` [B] hold after ``prefix_len`` tokens
+    (a scalar): (k, v [B, window, pairs, 2D], the position of the token
+    each row holds [B, window], -1 where it holds none). Token ``t`` lies
+    at row ``t % window``."""
+    w = cfg.sliding_window
+    k, v = _gather_slabs_kv(ring, ring_pages(cfg, slots, ring[0].shape[2]))
+    newest = prefix_len - 1
+    at = newest - (newest - jnp.arange(w, dtype=jnp.int32)) % w
+    at = jnp.where((prefix_len > 0) & (at >= 0), at, -1)
+    return k, v, jnp.broadcast_to(at, (slots.shape[0], w))
+
+
+def _ring_write(cfg, ring, slots, prefix_len, lens, kv, old):
+    """The rings of ``slots`` [B] after a chunk's (k, v) [B, T, pairs, 2D]
+    of ``lens`` [B] real tokens that follow ``prefix_len``: each of the
+    last ``window`` of them at its position modulo the window, every other
+    row as ``old`` has it (``_ring_read``'s (k, v) at the chunk's start).
+    The whole ring is written back, by pages (``_scatter_slabs``)."""
+    w = cfg.sliding_window
+    ps = ring[0].shape[2]
+    r = jnp.arange(w, dtype=jnp.int32)[None, :]
+    last = lens[:, None] - 1
+    # the chunk's newest token that lies at ring row r
+    c = last - (prefix_len + last - r) % w
+    pages = ring_pages(cfg, slots, ps)
+
+    def one(a, new, was):
+        rows = jnp.take_along_axis(new, jnp.maximum(c, 0)[:, :, None, None],
+                                   axis=1)
+        rows = jnp.where((c >= 0)[:, :, None, None], rows.astype(a.dtype),
+                         was.astype(a.dtype))
+        return _scatter_slabs(a, pages, rows)
+
+    return one(ring[0], kv[0], old[0]), one(ring[1], kv[1], old[1])
+
 def _mla_qkv(cfg, lp, h_in, positions):
     """``h_in`` [..., T, d] -> (q_nope [..., T, H, nope], q_rope [..., T,
     H, rope] after rope, the queries through their normed latent where the
@@ -768,7 +1104,8 @@ def _layer_params(cfg, layers: dict, l: int) -> tuple[dict, dict]:
     the layer's index among the sparse layers)."""
     plan = cache_spec.layer_plan(cfg)[l]
     i, j = kind_index(cfg)[l]
-    mixer = jax.tree_util.tree_map(lambda a: a[i], layers[plan.mixer])
+    mixer = jax.tree_util.tree_map(lambda a: a[i],
+                                   layers[stack_of(plan.mixer)])
     mlp = {k: v if k in EXPERT_KEYS
            else jax.tree_util.tree_map(lambda a: a[j], v)
            for k, v in layers[plan.mlp].items()}
@@ -795,7 +1132,7 @@ def _mlp(cfg, x, layers, l, mlp_lp, valid, carry=None):
     j = kind_index(cfg)[l][1]
     res = _res(layers, "mlp_res", l)
     with jax.named_scope("mlp"):
-        h = _rms(x, layers["mlp_norm"][l], cfg.rms_norm_eps)
+        h = norm(layers, "mlp_norm", x, cfg.rms_norm_eps, l)
         if plan.mlp == "dense":
             gate = jax.nn.silu(mm(h, mlp_lp["w_gate"]).astype(jnp.float32))
             out = mm(gate.astype(h.dtype) * mm(h, mlp_lp["w_up"]),
@@ -826,23 +1163,35 @@ def run_sequence(params, cfg, x, positions, valid, states=None,
     real [B]), none when None. Returns (x, new states, what this chunk
     keeps in pages a paged layer). A router's carried latent starts from
     zero at the first layer and never leaves the call: it is a token's
-    own, layer to layer."""
+    own, layer to layer; so are the SambaY family's two: the ``ssm_mem``
+    layer's scan output, which the gated memory units read, and the
+    ``diff`` layer's keys and values with those before the chunk, which
+    the ``cross`` layers read. A ``swa`` layer's state is (k, v, the
+    positions they hold) of its ring before the chunk, None for none, and
+    what it returns for it the chunk's (k, v)."""
     layers = params["layers"]
     plan = cache_spec.layer_plan(cfg)
     b, t, _ = x.shape
     new_states, latents = [], []
     carry = router_carry(cfg, (b, t))
+    if cfg.mb_per_layer:
+        carry = {}
     index = cache_spec.pool_index(cfg)
     for l, p in enumerate(plan):
         at_pages, at_slot = index[l]
 
         def layer(x, carry, l=l, p=p, at_pages=at_pages, at_slot=at_slot):
             mixer_lp, mlp_lp = _layer_params(cfg, layers, l)
-            h_in = _rms(x, layers["attn_norm"][l], cfg.rms_norm_eps)
+            h_in = norm(layers, "attn_norm", x, cfg.rms_norm_eps, l)
             st = states[at_slot] if states is not None and at_slot is not None \
                 else _zero_state(cfg, p, b, x.dtype)
             kept = state = None
-            if p.mixer == "kda":
+            if p.mixer in _SAMBAY:
+                out, carry, kept, state = _sambay_sequence(
+                    cfg, p, mixer_lp, h_in, positions, valid, st,
+                    None if prefix is None or at_pages is None
+                    else prefix[at_pages], carry)
+            elif p.mixer == "kda":
                 out, s1, c1 = _kda_sequence(cfg, mixer_lp, h_in, valid, *st)
                 state = (s1, c1)
             elif p.mixer == "mla":
@@ -879,17 +1228,65 @@ def run_sequence(params, cfg, x, positions, valid, states=None,
 
         x, carry, kept, state = (jax.checkpoint(layer) if remat
                                  else layer)(x, carry)
-        if at_pages is not None:
+        if at_pages is not None and p.mixer != "cross":
             latents.append(kept)
         if at_slot is not None:
             new_states.append(state)
     return x, new_states, latents
 
 
+_SAMBAY = ("ssm", "ssm_mem", "swa", "diff", "gmu", "cross")
+
+
+def _sambay_sequence(cfg, p, lp, h_in, positions, valid, st, prefix, carry):
+    """The mixer of a SambaY layer of kind ``p.mixer`` over a chunk: (out,
+    what later layers of the call read (``carry``: ``m`` of the
+    ``ssm_mem`` layer, ``kv`` of the ``diff`` layer), what the chunk keeps
+    in pages, its slot's new state)."""
+    kept = state = None
+    if p.mixer in ("ssm", "ssm_mem"):
+        out, m, s1, tail = _ssm_sequence(cfg, lp, h_in, valid, *st)
+        state = (s1, tail)
+        if p.mixer == "ssm_mem":
+            carry = {**carry, "m": m}
+    elif p.mixer == "gmu":
+        out = _gmu(lp, h_in, carry["m"])
+    else:
+        q, k, v = _diff_qkv(cfg, lp, h_in)
+        at = jnp.where(valid, positions, -1)
+        if p.mixer == "swa":
+            scope, window = "swa_core", cfg.sliding_window
+            keys, values, k_at = k, v, at
+            if st is not None:
+                keys = jnp.concatenate([st[0].astype(k.dtype), k], axis=1)
+                values = jnp.concatenate([st[1].astype(v.dtype), v], axis=1)
+                k_at = jnp.concatenate([st[2], at], axis=1)
+            state = (k, v)
+        else:
+            scope, window = "attn_core", 0
+            if p.mixer == "diff":
+                keys, values, k_at, kept = k, v, at, (k, v)
+                if prefix is not None:
+                    (pk, pv), pre_len = prefix
+                    tp = jnp.arange(pk.shape[1], dtype=jnp.int32)[None]
+                    keys = jnp.concatenate([pk.astype(k.dtype), k], axis=1)
+                    values = jnp.concatenate([pv.astype(v.dtype), v], axis=1)
+                    k_at = jnp.concatenate(
+                        [jnp.where(tp < pre_len[:, None], tp, -1), at], axis=1)
+                carry = {**carry, "kv": (keys, values, k_at)}
+            else:
+                keys, values, k_at = carry["kv"]
+        with jax.named_scope(scope):
+            o = diff_attention(cfg, q, keys, values, positions, k_at, window)
+        out = _diff_out(cfg, lp, o, p.published)
+    return out, carry, kept, state
+
+
 def _zero_state(cfg, p, b: int, dtype):
-    """What a layer's slot holds before a sequence's first token."""
+    """What a layer's slot holds before a sequence's first token (a
+    window layer's ring: nothing, None)."""
     slot = cache_spec.slot_part(cache_spec.layer_cache(cfg, p, dtype))
-    if slot is None:
+    if slot is None or isinstance(slot, cache_spec.Ring):
         return None
     return tuple(jnp.zeros((b, *shape), dt) for _n, shape, dt in slot.arrays)
 
@@ -946,36 +1343,92 @@ def prefill(params, cfg, ids, lens, prefix_len, pools, prefix_page_ids,
     page], the recurrent state after the chunk to ``slots``. Returns
     (pools, last-token logits [B, V]). A state is read only where
     ``prefix_len`` > 0: a slot's first chunk starts from zero, whatever
-    the last request left there."""
+    the last request left there (and from an empty ring: a window layer's
+    rows hold no token until one is written)."""
     paged, state = pools
     b, pb = ids.shape
     valid = jnp.arange(pb)[None, :] < lens[:, None]
     positions = jnp.broadcast_to(prefix_len + jnp.arange(pb, dtype=jnp.int32),
                                  (b, pb))
     fresh = prefix_len == 0
-    states = [tuple(jnp.where(fresh, jnp.zeros((), a.dtype), a[slots])
-                    for a in rows) for rows in state]
+    # the mixer of each layer that keeps a slot, in order (a window
+    # layer's slot is its ring)
+    slot_mixers = [p.mixer for p, (_pg, at) in zip(
+        cache_spec.layer_plan(cfg), cache_spec.pool_index(cfg))
+        if at is not None]
+    states = []
+    for rows, mixer in zip(state, slot_mixers):
+        if mixer == "swa":
+            with jax.named_scope("swa_core"):
+                states.append(_ring_read(cfg, rows, slots, prefix_len))
+        else:
+            states.append(tuple(
+                jnp.where(fresh, jnp.zeros((), a.dtype), a[slots])
+                for a in rows))
     prefix = None
     # a K/V pair's scope is ``attn_core``, a latent pool's ``mla_core``
     pair = bool(paged) and isinstance(paged[0], tuple)
     if prefix_page_ids.shape[1]:
         with jax.named_scope("attn_core" if pair else "mla_core"):
             pre_len = jnp.broadcast_to(prefix_len, (b,))
-            prefix = [((_gather_kv if pair else _gather_pages)(
-                pool, prefix_page_ids), pre_len) for pool in paged]
+            prefix = [(_gather_prefix(cfg, pool, prefix_page_ids), pre_len)
+                      for pool in paged]
     x = params["embed"][ids]
     x, new_states, latents = run_sequence(params, cfg, x, positions, valid,
                                           states, prefix)
     with jax.named_scope("attn_core" if pair else "mla_core"):
-        paged = tuple(_scatter_kv(pool, page_ids, kept) if pair
-                      else _scatter_tokens(pool, page_ids, kept, valid)
+        paged = tuple(_scatter_chunk(cfg, pool, page_ids, kept, valid)
                       for pool, kept in zip(paged, latents))
-    with jax.named_scope("cca_mix" if pair else "kda_core"):
-        state = tuple(tuple(a.at[slots].set(a1.astype(a.dtype))
-                            for a, a1 in zip(rows, new))
-                      for rows, new in zip(state, new_states))
+    written = []
+    for rows, new, was, mixer in zip(state, new_states, states, slot_mixers):
+        with jax.named_scope(_SLOT_SCOPE[mixer]):
+            written.append(
+                _ring_write(cfg, rows, slots, prefix_len, lens, new, was[:2])
+                if mixer == "swa"
+                else tuple(a.at[slots].set(a1.astype(a.dtype))
+                           for a, a1 in zip(rows, new)))
+    state = tuple(written)
     logits = _head(cfg, params, x, jnp.maximum(lens - 1, 0))
     return (paged, state), logits
+
+
+# the scope a layer's slot is written back under, by its mixer
+_SLOT_SCOPE = {"kda": "kda_core", "cca": "cca_mix", "ssm": "ssm_core",
+               "ssm_mem": "ssm_core", "swa": "swa_core"}
+
+
+def _kv_by_slabs(cfg) -> bool:
+    """Whether a K/V pair's pages move as slabs of the pool's ``[H N, ps,
+    w]`` view (``_gather_slabs_kv``, ``_scatter_slabs``) and not as rows of
+    its ``[H N, ps w]`` view (``_gather_kv``, ``_scatter_kv``): for the
+    SambaY family's pools of ten heads, which by rows XLA lays out anew,
+    1.7-2.7 GB of temporaries beside a 900 MB pool that the chip's
+    compiler refused. The CCA model's programs are the rows' and are kept
+    to the byte (an accepted benchmark cell's); one form for both is for
+    the PR that can measure that cell (ROADMAP Queue 1 item 21)."""
+    return bool(cfg.mb_per_layer)
+
+
+def _gather_prefix(cfg, pool, page_ids):
+    """What the pages ``page_ids`` [B, n] of a paged layer's ``pool``
+    hold: a latent pool's rows [B, n * ps, w], a K/V pair's (k, v) each
+    [B, n * ps, H, w]."""
+    if not isinstance(pool, tuple):
+        return _gather_pages(pool, page_ids)
+    return (_gather_slabs_kv if _kv_by_slabs(cfg) else _gather_kv)(
+        pool, page_ids)
+
+
+def _scatter_chunk(cfg, pool, page_ids, kept, valid):
+    """``pool`` with a chunk's ``kept`` written to its pages ``page_ids``
+    [B, T // ps]. (A K/V pair's padded position lands in the tail of the
+    row's last page or in the null page, where no length reaches it.)"""
+    if not isinstance(pool, tuple):
+        return _scatter_tokens(pool, page_ids, kept, valid)
+    if _kv_by_slabs(cfg):
+        return tuple(_scatter_slabs(a, page_ids, x)
+                     for a, x in zip(pool, kept))
+    return _scatter_kv(pool, page_ids, kept)
 
 
 def _gather_kv(pool, page_ids):
@@ -989,6 +1442,40 @@ def _gather_kv(pool, page_ids):
             b, n * ps, hkv, d)
 
     return one(pool[0]), one(pool[1])
+
+
+def _slab_ids(a, page_ids):
+    """Slabs of ``a``'s ``[H * N, ps, w]`` view that hold the pages
+    ``page_ids`` [B, n] of every head, head-major: [H * B * n]. The view's
+    last two dimensions are the pool's own tiles, so a gather or a scatter
+    over its slabs leaves the pool's layout as it is."""
+    h, n = a.shape[:2]
+    return (jnp.arange(h, dtype=jnp.int32)[:, None] * n
+            + page_ids.reshape(-1)[None, :].astype(jnp.int32)).reshape(-1)
+
+
+def _gather_slabs_kv(pool, page_ids):
+    """``_gather_kv`` by slabs (``_slab_ids``): 2,560 slabs of 16 KB for a
+    prefix of 256 pages, where token rows of the flat view were 164k
+    gathers of 256 B (1.4 ms less of a chunk's 56 ms on the chip)."""
+    b, n_pg = page_ids.shape
+
+    def one(a):
+        h, n, ps, w = a.shape
+        got = a.reshape(h * n, ps, w)[_slab_ids(a, page_ids)]
+        return got.reshape(h, b, n_pg * ps, w).transpose(1, 2, 0, 3)
+
+    return one(pool[0]), one(pool[1])
+
+
+def _scatter_slabs(a, page_ids, rows):
+    """``a`` [H, N, ps, w] with the pages ``page_ids`` [B, n] holding
+    ``rows`` [B, n * ps, H, w], by slabs (``_slab_ids``)."""
+    h, n, ps, w = a.shape
+    b, n_pg = page_ids.shape
+    pages = rows.reshape(b * n_pg, ps, h, w).transpose(2, 0, 1, 3)
+    return a.reshape(h * n, ps, w).at[_slab_ids(a, page_ids)].set(
+        pages.reshape(-1, ps, w).astype(a.dtype)).reshape(a.shape)
 
 
 def _scatter_kv(pool, page_ids, kv):
@@ -1019,21 +1506,45 @@ def load_width(cfg) -> int:
     three more: every (row, choice) of live rows whether or not its expert
     is held here, live rows times KDA layers, and the latent rows the
     live rows attend over, summed over the MLA layers; with CCA layers a
-    seventh: live rows times CCA layers (the tails read and written)."""
+    seventh: live rows times CCA layers (the tails read and written). The
+    SambaY family counts ``CACHE_ROW_KEYS`` instead."""
     if cache_spec.is_uniform(cfg):
         return 3 if cfg.num_experts else 0
+    if cfg.mb_per_layer:
+        return len(CACHE_ROW_KEYS)
     return 6 + any(p.mixer == "cca" for p in cache_spec.layer_plan(cfg))
+
+
+# what a decode step of the SambaY family counts, in this order: live rows
+# times Mamba layers (a state read and written each); keys of the shared
+# pool read, summed over the layers that attend over it; keys of the rings
+# read, summed over the window layers (``obs/engine_profile.py``)
+CACHE_ROW_KEYS = ("ssm_state_rows", "shared_kv_rows_read",
+                  "window_rows_read")
 
 
 def held_state(cfg, arrays: tuple, slot: int) -> np.ndarray:
     """What ``CBEngine.recurrent_state`` reads of one layer's slot arrays
     for the engine's slot ``slot``, float32 on the host: a KDA layer's
     recurrent state (its convolution tails are left out, as ever), a CCA
-    layer's tails, flattened side by side."""
+    layer's tails, flattened side by side; a Mamba layer's state, ``[I,
+    N]``; a window layer's ring, ``[window, pairs, 2 * 2D]`` (a row's keys
+    beside its values, token ``t`` at row ``t % window``)."""
     if any(p.mixer == "cca" for p in cache_spec.layer_plan(cfg)):
         return np.concatenate([np.asarray(a[slot], np.float32).reshape(-1)
                                for a in arrays])
-    return np.asarray(arrays[0][slot]).astype(np.float32)
+    if cfg.mb_per_layer and arrays[0].ndim == 4:
+        # a window layer's ring: the slot's pages, rows in ring order, as
+        # (k | v) [window, pairs, 2 * 2D]
+        pairs, _n, ps, width = arrays[0].shape
+        n = cfg.sliding_window // ps
+        return np.concatenate(
+            [np.asarray(a[:, 1 + slot * n:1 + (slot + 1) * n], np.float32)
+             .reshape(pairs, n * ps, width).swapaxes(0, 1) for a in arrays],
+            axis=-1)
+    held = np.asarray(arrays[0][slot]).astype(np.float32)
+    # a Mamba state is kept [N, I] and read as the published [I, N]
+    return held.T if cfg.mb_per_layer else held
 
 
 def kda_in_kernel(cfg) -> bool:
@@ -1068,6 +1579,7 @@ def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
     from polyrl_tpu.ops.kda_state import kda_state_update
     from polyrl_tpu.ops.mla_attention import latent_paged_attention
     from polyrl_tpu.ops.paged_attention import paged_attention, paged_kv_write
+    from polyrl_tpu.ops.ssm_state import ssm_state_update
 
     layers = params["layers"]
     plan = cache_spec.layer_plan(cfg)
@@ -1088,11 +1600,61 @@ def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
     load = jnp.zeros((load_width(cfg),), jnp.int32)
     carry = router_carry(cfg, (s,))
     index = cache_spec.pool_index(cfg)
+    if cfg.mb_per_layer:
+        # a window layer's ring: row r's pages are slot r's, the token at
+        # its position modulo the window, the row as long as it has tokens
+        w = cfg.sliding_window
+        ring_table = ring_pages(cfg, jnp.arange(s, dtype=jnp.int32), ps)
+        at = seq_lens % w
+        ring_page = jnp.where(live, ring_table[jnp.arange(s), at // ps], 0)
+        ring_off = jnp.where(live, at % ps, 0)
+        ring_lens = jnp.minimum(attn_lens, w)
+        window_read = jnp.sum(ring_lens)
+        d_scale = cfg.head_dim_ ** -0.5
     for l, p in enumerate(plan):
         at_pages, i = index[l]
         mixer_lp, mlp_lp = _layer_params(cfg, layers, l)
-        h_in = _rms(x, layers["attn_norm"][l], cfg.rms_norm_eps)
-        if p.mixer == "kda":
+        h_in = norm(layers, "attn_norm", x, cfg.rms_norm_eps, l)
+        if p.mixer in ("ssm", "ssm_mem"):
+            st, tail = state[i]
+            with jax.named_scope("ssm_proj"):
+                c, z, dt, bm, cm, full = _ssm_inputs(
+                    cfg, mixer_lp, h_in[:, None], tail[:s])
+                tail = _set_rows(tail, jnp.where(
+                    live[:, None, None], full[:, 1:].astype(tail.dtype),
+                    tail[:s]))
+            with jax.named_scope("ssm_core"):
+                st, m = ssm_state_update(mixer_lp, st, c[:, 0], dt[:, 0],
+                                         bm[:, 0], cm[:, 0], live)
+            with jax.named_scope("ssm_proj"):
+                out = _ssm_out(mixer_lp, m, z[:, 0])
+            state[i] = (st, tail)
+            if p.mixer == "ssm_mem":
+                carry = m
+            load = load.at[0].add(n_live)
+        elif p.mixer == "gmu":
+            out = _gmu(mixer_lp, h_in, carry)
+        elif p.mixer in ("swa", "diff", "cross"):
+            q, k, v = _diff_qkv(cfg, mixer_lp, h_in)
+            q = paired_queries(q)
+            if p.mixer == "swa":
+                with jax.named_scope("swa_core"):
+                    state[i] = paged_kv_write(*state[i], ring_page, ring_off,
+                                              k, v)
+                    o = paged_attention(q, *state[i], ring_table, ring_lens,
+                                        d_scale)
+                load = load.at[2].add(window_read)
+            else:
+                with jax.named_scope("attn_core"):
+                    if p.mixer == "diff":
+                        paged[at_pages] = paged_kv_write(
+                            *paged[at_pages], write_page, write_off, k, v)
+                    o = paged_attention(q, *paged[at_pages], page_table,
+                                        attn_lens, d_scale)
+                load = load.at[1].add(rows_read)
+            out = _diff_out(cfg, mixer_lp,
+                            o.reshape(s, -1, 2, o.shape[-1]), p.published)
+        elif p.mixer == "kda":
             st, conv = state[i]
             with jax.named_scope("kda_proj"):
                 new = _kda_proj(mixer_lp, h_in)

@@ -82,6 +82,12 @@ emission or iteration, never once a token, all on this profiler's clock:
   in the one-pass kernel (``ops/kda_state.py``);
   ``mla_proj_kernel_steps`` — the steps whose program multiplied its MLA
   layers' ``wkv_b`` where it lies in the stack (``ops/mla_proj.py``);
+  ``ssm_state_rows`` / ``shared_kv_rows_read`` / ``window_rows_read`` —
+  for a model of the SambaY family, counted by the landed steps on the
+  device (``hybrid.CACHE_ROW_KEYS``): live rows times Mamba layers (a
+  state read and written each), keys of the one shared K/V pool read,
+  summed over the layers that attend over it, and keys of the window
+  layers' rings read (0 for every other model);
   ``decode_dispatches_cold`` — those of the dispatches enqueued with
   NOTHING outstanding (the device had run dry: an engine that keeps its
   run-ahead does it once a burst, one that drains before every dispatch
@@ -164,6 +170,9 @@ CUMULATIVE_KEYS = (
     "decode_dispatches", "decode_dispatches_cold", "admission_deferrals",
     "pages_grown", "slot_yields", "decode_steps_done", "fused_sample_steps",
     "kda_kernel_steps", "mla_proj_kernel_steps", "row_steps_done",
+    "ssm_state_rows",
+    "shared_kv_rows_read",
+    "window_rows_read",
     "device_busy_s", "loop_wall_s", "loop_host_s",
     *PHASE_KEYS.values(), "emit_wait_s", "dispatches_emitted",
     "landing_gap_hist", "stalls", "programs_built", "build_s")
@@ -385,6 +394,13 @@ class EngineLoopProfiler:
         """A running row gave up its slot and pages for want of pages."""
         with self._lock:
             self._cum["slot_yields"] += 1
+
+    def on_cache_rows(self, rows: dict) -> None:
+        """Landed decode steps counted ``rows`` (key -> count) more of
+        the cache rows they touch (``hybrid.CACHE_ROW_KEYS``)."""
+        with self._lock:
+            for key, n in rows.items():
+                self._cum[key] += n
 
     def on_landed(self, n: int) -> float | None:
         """The oldest ``n`` dispatches' results are on the host: the

@@ -274,17 +274,38 @@ class CBEngine:
         # partial prefill from token 0), no shared-prefix decode groups,
         # and no prompt-lookup speculation
         self.stateful = cache_spec.is_stateful(cfg)
+        # and which features that act on pages have a kernel for every
+        # mixer of the plan? What needs none (prefix cache, a group's
+        # shared prompt, salvage, the ledger, growth and yield) runs on
+        # any paged pool; speculation is refused, the grouped decode
+        # kernel and the spill tier are off, with the mixers named
+        self._no_kernel = {f: cache_spec.without_kernel(cfg, f)
+                           for f in cache_spec.FEATURE_KERNELS}
         if self.stateful:
             if int(o.spec_tokens) > 0:
                 raise ValueError(
                     "spec_tokens > 0 needs a state to roll back to after a "
                     "rejected draft; this model keeps a recurrent state "
-                    "with no snapshot (models/cache_spec.py)")
+                    "with no snapshot in its "
+                    f"{'/'.join(self._no_kernel['spec_tokens'])} layers "
+                    "(models/cache_spec.py)")
             if mesh is not None and mesh.size > 1:
                 raise NotImplementedError(
                     "a model with a recurrent state on a mesh of several "
                     "chips")
             enable_prefix_cache = False
+        elif int(o.spec_tokens) > 0 and self._no_kernel["spec_tokens"]:
+            raise ValueError(
+                "spec_tokens > 0 verifies several tokens a row in one "
+                "forward, which is written for gqa layers; this model "
+                f"has {'/'.join(self._no_kernel['spec_tokens'])} layers "
+                "(models/cache_spec.py::without_kernel)")
+        for feature, on in (("decode_group_share", o.decode_group_share),
+                            ("kv_spill", o.kv_spill)):
+            if on and self._no_kernel[feature]:
+                log.info("%s is off: no kernel for %s layers "
+                         "(models/cache_spec.py::without_kernel)",
+                         feature, "/".join(self._no_kernel[feature]))
         # whether a decode step's KDA layers update their states in the
         # one-pass kernel (the profiler's ``kda_kernel_steps``): one answer
         # for the engine's life
@@ -292,26 +313,6 @@ class CBEngine:
         # and whether its MLA layers multiply ``wkv_b`` where it lies in
         # the stack (``mla_proj_kernel_steps``), at the step's rows
         self._mla_proj_kernel = hybrid.mla_in_kernel(cfg, max_slots + 1)
-        # and which features that act on pages have a kernel for every
-        # mixer of the plan? What needs none (prefix cache, a group's
-        # shared prompt, salvage, the ledger, growth and yield) runs on
-        # any paged pool; speculation is refused, the grouped decode
-        # kernel and the spill tier are off, with the mixer named
-        self._no_kernel = {f: cache_spec.without_kernel(cfg, f)
-                           for f in cache_spec.FEATURE_KERNELS}
-        if not self.stateful:
-            if int(o.spec_tokens) > 0 and self._no_kernel["spec_tokens"]:
-                raise ValueError(
-                    "spec_tokens > 0 verifies several tokens a row in one "
-                    "forward, which is written for gqa layers; this model "
-                    f"has {'/'.join(self._no_kernel['spec_tokens'])} layers "
-                    "(models/cache_spec.py::without_kernel)")
-            for feature, on in (("decode_group_share", o.decode_group_share),
-                                ("kv_spill", o.kv_spill)):
-                if on and self._no_kernel[feature]:
-                    log.info("%s is off: no kernel for %s layers "
-                             "(models/cache_spec.py::without_kernel)",
-                             feature, "/".join(self._no_kernel[feature]))
         self.max_slots = max_slots
         self.page_size = page_size
         self.max_seq_len = o.max_seq_len
@@ -552,10 +553,19 @@ class CBEngine:
         """The oldest ``len(batch)`` queued dispatch outputs reached the
         host as ``fetched``: the completion-stamp counters move, and with
         them (the same steps) the MoE load the decode steps counted."""
+        # a SambaY model's vector counts cache rows (hybrid.CACHE_ROW_KEYS:
+        # what the steps' Mamba, shared-pool and window layers touched),
+        # which the profiler's cumulative counters of those names carry
+        rows = self.profiler is not None and bool(self.cfg.mb_per_layer)
+        before = self._moe_load.copy() if rows else None
         for entry, arrs in zip(batch, fetched):
             if entry[0] == "step" and arrs[3] is not None:
                 self._moe_load += arrs[3]
         if self.profiler is not None:
+            if rows:
+                self.profiler.on_cache_rows(dict(zip(
+                    hybrid.CACHE_ROW_KEYS,
+                    (int(v) for v in self._moe_load - before))))
             stalled_s = self.profiler.on_landed(len(batch))
             if stalled_s is not None:
                 self._log_stall(stalled_s)

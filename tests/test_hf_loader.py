@@ -158,3 +158,43 @@ def test_zaya_checkpoint_is_refused_until_its_key_map_exists(tmp_path):
     assert cfg.cca_time0 == 2 and cfg.router_hidden_size == 256
     with pytest.raises(NotImplementedError, match="no key map for a zaya"):
         hf_loader.load_hf_params(str(tmp_path))
+
+
+# -- the SambaY family (models/hybrid.py; phi4flash) ------------------------
+
+
+def _phi4flash_keys() -> dict:
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "phi-4-mini-flash-reasoning.json")) as f:
+        return json.load(f)
+
+
+def test_phi4flash_config_is_the_published_preset():
+    """``phi4flash_config`` on Phi-4-mini-flash-reasoning's published
+    config.json (the benchmark's file holds every key of it) gives the
+    preset, whole; a bias the decoder has not written is refused."""
+    from polyrl_tpu.models import hf_loader
+
+    hf = _phi4flash_keys()
+    assert hf["model_type"] == "phi4flash" and hf["reduced"] == []
+    assert hf_loader.phi4flash_config(hf) == \
+        decoder.get_config("phi-4-mini-flash-reasoning")
+    with pytest.raises(NotImplementedError, match="mlp_bias"):
+        hf_loader.phi4flash_config({**hf, "mlp_bias": True})
+
+
+def test_phi4flash_checkpoint_is_refused_until_its_key_map_exists(tmp_path):
+    import json
+
+    from polyrl_tpu.models import hf_loader
+
+    (tmp_path / "config.json").write_text(json.dumps(_phi4flash_keys()))
+    cfg = hf_loader.config_from_hf(str(tmp_path))
+    assert cfg.mb_per_layer == 2 and cfg.sliding_window == 512
+    with pytest.raises(NotImplementedError,
+                       match="no key map for a phi4flash"):
+        hf_loader.load_hf_params(str(tmp_path))

@@ -51,6 +51,14 @@ def cca():
     return cfg, decoder.init_params(jax.random.PRNGKey(0), cfg)
 
 
+@pytest.fixture(scope="module")
+def sambay():
+    """The SambaY family: Mamba states and window rings in the slot, ONE
+    paged K/V layer that the cross layers read (``tests/test_sambay.py``)."""
+    cfg = decoder.get_config("sambay-tiny", dtype=jnp.float32)
+    return cfg, decoder.init_params(jax.random.PRNGKey(0), cfg)
+
+
 def _engine(model, **kw):
     cfg, params = model
     opts = dict(max_slots=4, page_size=PS, max_seq_len=128,
@@ -335,10 +343,11 @@ def test_the_row_that_yields_is_the_one_with_least_to_redo(dense):
     _books_balance(eng, 13)
 
 
-@pytest.mark.parametrize("family", ["hybrid", "cca"])
+@pytest.mark.parametrize("family", ["hybrid", "cca", "sambay"])
 def test_the_tiny_hybrid_rebuilds_a_yielded_rows_state(request, family):
     """A model with a state in its slot (``hybrid``: KDA states beside a
     latent pool; ``cca``: convolution tails beside a K/V pair in the same
+    layer; ``sambay``: Mamba states and window rings beside one shared K/V
     layer) re-enters from token 0: the chunks recompute the slot's rows
     with the pages, and nothing is streamed twice."""
     hybrid = request.getfixturevalue(family)

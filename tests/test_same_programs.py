@@ -1,0 +1,39 @@
+"""``tools/same_programs.py`` at tiny presets of two families: an edit to
+one family's program shows, and the other family's programs are the
+same."""
+
+import os
+import shutil
+import subprocess
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TOOL = os.path.join(ROOT, "tools", "same_programs.py")
+
+
+def _run(other):
+    return subprocess.run(
+        [sys.executable, TOOL, other, "hybrid-tiny", "cca-tiny"],
+        capture_output=True, text=True, cwd=ROOT)
+
+
+def test_an_edited_program_shows(tmp_path):
+    shutil.copytree(os.path.join(ROOT, "polyrl_tpu"),
+                    tmp_path / "polyrl_tpu",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    path = tmp_path / "polyrl_tpu" / "models" / "hybrid.py"
+    text = path.read_text()
+    # the KDA recurrence with a delta rule twice as strong
+    old = "    u = beta[..., None] * (v - pred)\n"
+    assert text.count(old) == 1
+    path.write_text(text.replace(
+        old, "    u = 2 * beta[..., None] * (v - pred)\n"))
+    ran = _run(str(tmp_path))
+    assert ran.returncode == 1, ran.stderr[-2000:]
+    verdicts = dict(l.split(": ")[:2] for l in (
+        x.rsplit(" ", 2)[0] + ": " for x in ran.stdout.strip().splitlines()))
+    assert "DIFFERENT" in verdicts["hybrid-tiny step"]
+    # (prefill runs the chunked form, which the edit leaves alone)
+    assert "same" in verdicts["hybrid-tiny prefill"]
+    assert "same" in verdicts["cca-tiny step"]
+    assert "same" in verdicts["cca-tiny prefill"]

@@ -412,6 +412,31 @@ def test_kda_state_kernel_compiles_for_v5e(one_chip, slots, s, h):
     assert mem.temp_size_in_bytes < 2**20 + 5 * s * h * 128 * 4
 
 
+# (slots, rows): the SambaY cell (129 rows, the last block one row) and a
+# stack longer than a step whose rows end inside a block
+@pytest.mark.parametrize("slots,s", [(129, 129), (40, 33)])
+def test_ssm_state_kernel_compiles_for_v5e(one_chip, slots, s):
+    """The one-pass Mamba state update at Phi-4-mini-flash's sizes (a
+    state of 16 x 5120 float32 a row): Mosaic takes its sublane and lane
+    broadcasts, a last block that reaches past the rows and its four
+    2.6 MB buffers inside the VMEM it asks for, and the layer's states are
+    the call's input and output: aliased whole, nothing of their size
+    among the temporaries."""
+    from polyrl_tpu.ops import ssm_state
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    n, inner = 16, 5120
+    compiled = jax.jit(ssm_state.ssm_state_pallas, donate_argnums=(0,)).lower(
+        arg(slots, n, inner), arg(n, inner), arg(s, inner), arg(s, inner),
+        arg(s, n), arg(s, n)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == slots * n * inner * 4
+    assert mem.temp_size_in_bytes < 2**20 + 2 * s * n * 128 * 4
+
+
 # -- the MLA projections of a decode step (ops/mla_proj.py) ------------------
 
 MLA_LAYERS = 5
@@ -675,3 +700,115 @@ def test_zaya_prefill_chunk_compiles_for_v5e_within_memory(one_chip,
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert live < 15 * 10**9
     assert m.temp_size_in_bytes < 2 * 10**9
+
+
+# -- Phi-4-mini-flash-reasoning's cell (benchmark/configs/phi-4-mini-flash-reasoning.json)
+
+
+def _sambay_shapes(one_chip, s, n_pages, page):
+    from polyrl_tpu.models import decoder
+
+    cfg = decoder.get_config("phi-4-mini-flash-reasoning")
+
+    def shapes(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = shapes(jax.eval_shape(
+        lambda: decoder.init_params(jax.random.PRNGKey(0), cfg)))
+    pools = shapes(jax.eval_shape(
+        lambda: decoder.make_paged_pools(cfg, n_pages, page, slots=s + 1)))
+    return cfg, params, pools
+
+
+SAMBAY_PAGES = 10987
+
+
+def test_sambay_decode_step_compiles_for_v5e_and_copies_no_cache(
+        one_chip, chip_precision, on_tpu):
+    """The cell's whole decode program: 8 fused steps of all 32 layers at
+    128 rows, the shared pool, the rings and the Mamba states donated, the
+    token drawn inside the tied head. The write and attention kernels take
+    10 K/V heads of 128 under 40 query rows (Mosaic's word on it). Weights
+    (7.70 GB), the one shared K/V pool (3.60 GB), 129 slots' rings (2.70
+    GB) and states (0.42 GB) and everything the step holds at once fit a
+    16 GB chip, and nothing the optimised program writes is as large as
+    the shared pool's K or a window layer's ring but the write kernel's
+    own in-place result; a Mamba layer's state rows are read once and
+    written once a step by the update's kernel (``ops/ssm_state.py``), in
+    place, and nothing else of their size is written."""
+    from polyrl_tpu.models import decoder
+
+    s, width, page = 128, 320, 64
+    cfg, params, pools = _sambay_shapes(one_chip, s, SAMBAY_PAGES, page)
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(params, paged, state, rng, table, lens, last, active, temps):
+        def body(carry, _):
+            paged, state, rng, lens, last = carry
+            rng, sub = jax.random.split(rng)
+            head = functools.partial(decoder.head_and_sample, rng=sub,
+                                     temps=temps)
+            (tok, logp), (paged, state), load = decoder.forward_paged_decode(
+                params, cfg, last, lens, (paged, state), table, lens,
+                active=active, head_fn=head)
+            return (paged, state, rng, lens + 1, tok), (tok, logp, load)
+        return jax.lax.scan(body, (paged, state, rng, lens, last), None,
+                            length=8)
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, pools[0], pools[1], arg((2,), jnp.uint32),
+        arg((s, width), jnp.int32), arg((s,), jnp.int32),
+        arg((s,), jnp.int32), arg((s,), jnp.bool_),
+        arg((s,), jnp.float32)).compile()
+    m = compiled.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert 14.0e9 < live < 15.2e9
+    text = compiled.as_text()
+    # a write and an attention a window layer, a write and 8 attentions
+    # over the shared pool, a state update a Mamba layer, the head
+    assert text.count("tpu_custom_call") >= 2 * 8 + 1 + 8 + 9 + 1
+    state_rows = 129 * 16 * 5120          # a Mamba layer's states, float32
+    ring = 10 * (1 + 129 * 8) * 64 * 128  # a window layer's K (or V)
+    plumbing = re.compile(r"= \(?\w+\[[\d,]*\]\S* (while|tuple|"
+                          r"get-tuple-element|bitcast|custom-call)\(")
+    made = [(n, line) for n, line in _written(text)
+            if n >= state_rows and not plumbing.search(line)]
+    # nothing of a ring's or the pool's size is written but by the write
+    # kernel, in place, and nothing of a Mamba layer's states' size but by
+    # the update's kernel, in place (their results are the custom calls'
+    # own): no fusion's result, no ``copy``
+    assert not [line[:200] for _n, line in made]
+
+
+def test_sambay_prefill_chunk_compiles_for_v5e_within_memory(one_chip,
+                                                             chip_precision,
+                                                             on_tpu):
+    """The longest prompt's last chunk: 512 tokens from the slot's state
+    and rings over 256 pages of the shared pool's prefix, beside the
+    weights, the pool, the rings and the states."""
+    from polyrl_tpu.models import decoder
+
+    page, pb, n_pre = 64, 512, 256
+    cfg, params, pools = _sambay_shapes(one_chip, 128, SAMBAY_PAGES, page)
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def chunk(params, paged, state, ids, n, at, pre_pages, pages, slot):
+        return decoder.prefill_suffix_into_pages(
+            params, cfg, ids, n, at, (paged, state), pre_pages, pages, slot)
+
+    compiled = jax.jit(chunk, donate_argnums=(1, 2)).lower(
+        params, pools[0], pools[1], arg((pb,), jnp.int32),
+        arg((), jnp.int32), arg((), jnp.int32), arg((n_pre,), jnp.int32),
+        arg((pb // page,), jnp.int32), arg((), jnp.int32)).compile()
+    m = compiled.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert live < 15.6 * 10**9
+    assert m.temp_size_in_bytes < 1.2 * 10**9

@@ -633,9 +633,16 @@ def test_push_chaos_fit_two_fake_engines(monkeypatch):
         assert last["fault/transfer_stalls"] >= 2.0
         assert last["transfer/retry_budget"] == 1.0
         # ...and the per-engine sync health rides the /statusz pool section
-        snap = trainer.statusz_snapshot()
-        rows = {r["endpoint"]: r for r in snap["pool"]["engines"]}
-        assert rows[eng_a.endpoint]["transfer"]["pushed_version"] == final_v
+        # (the receiver can hold the version a moment before the sender's
+        # thread has booked the push: on a loaded box the snapshot read
+        # 4 for 5)
+        def pushed():
+            snap = trainer.statusz_snapshot()
+            rows = {r["endpoint"]: r for r in snap["pool"]["engines"]}
+            return rows[eng_a.endpoint]["transfer"]["pushed_version"]
+
+        wait_for(lambda: pushed() == final_v, timeout=10.0,
+                 msg="the survivor's push booked")
         health = iface.sync_health()
         assert health[eng_b.endpoint]["escalated"] is True
     finally:

@@ -61,8 +61,9 @@ def inputs(rows: int, heads: int, size: int, layers: int, seed: int):
     return tuple(m[0] for m in made), tuple(m[1] for m in made)
 
 
-def device_ms(trace_dir: str, program: str) -> tuple[list[float], list[float]]:
-    """(durations of the kernel's events, durations of the whole
+def device_ms(trace_dir: str, program: str,
+              kernel: str = KERNEL) -> tuple[list[float], list[float]]:
+    """(durations of the events of ``kernel``, durations of the whole
     ``program``'s runs) on the first device in the newest trace, ms."""
     path = max(glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
                                       "*.xplane.pb")), key=os.path.getmtime)
@@ -74,7 +75,7 @@ def device_ms(trace_dir: str, program: str) -> tuple[list[float], list[float]]:
         for line in plane.lines:
             if line.name == "XLA Ops":
                 kernels += [e.duration_ns / 1e6 for e in line.events
-                            if e.name.lstrip("%").startswith(KERNEL)]
+                            if e.name.lstrip("%").startswith(kernel)]
             elif line.name == "XLA Modules":
                 programs += [e.duration_ns / 1e6 for e in line.events
                              if e.name.startswith(f"jit_{program}")]

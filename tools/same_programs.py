@@ -1,0 +1,118 @@
+"""Whether two checkouts build the SAME programs for a preset: the SHA-256
+of the StableHLO that the engine's fused decode step (8 steps, the token
+drawn inside the head) and its 512-token prefill chunk over 64 pages of
+prefix lower to for a TPU, kernels included, from abstract arguments at
+the preset's widths. No chip and no weights: a PR that edits code an
+accepted benchmark cell shares can show here that the cell's programs are
+the parent's to the byte.
+
+    python tools/same_programs.py <other checkout> [preset ...]
+
+Each checkout is copied to the same scratch path in turn (a kernel's
+serialized body carries its source's path) and lowered in a process of its
+own, without caller frames in the locations (a line that moved in a file
+is no other program). Prints a line a program and exits 1 where two
+differ."""
+
+from __future__ import annotations
+
+import functools
+import hashlib
+import json
+import os
+import shutil
+import subprocess
+import sys
+import tempfile
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PRESETS = ("qwen2.5-7b", "qwen3-30b-a3b", "ling-3.0-flash-share4",
+           "dots.vlm1-share16", "zaya1-8b-depth12")
+
+
+def digests(presets) -> dict:
+    """{"<preset> <program>": sha256} from the checkout on ``sys.path``."""
+    import jax
+    import jax.numpy as jnp
+
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    jax.default_backend = lambda: "tpu"     # the dispatchers' question
+    from polyrl_tpu.models import decoder
+
+    rows, pages, page, width = 128, 2048, 64, 192
+    arg = jax.ShapeDtypeStruct
+    out = {}
+    for preset in presets:
+        cfg = decoder.get_config(preset)
+        params = jax.eval_shape(
+            lambda: decoder.init_params(jax.random.PRNGKey(0), cfg))
+        pools = jax.eval_shape(lambda: decoder.make_paged_pools(
+            cfg, pages, page, slots=rows + 1))
+
+        def step(params, paged, state, rng, table, lens, last, active, temps):
+            def body(carry, _):
+                paged, state, rng, lens, last = carry
+                rng, sub = jax.random.split(rng)
+                head = functools.partial(decoder.head_and_sample, rng=sub,
+                                         temps=temps)
+                (tok, logp), pools, load = decoder.forward_paged_decode(
+                    params, cfg, last, lens, (paged, state), table, lens,
+                    active=active, head_fn=head)
+                return (*pools, rng, lens + 1, tok), (tok, logp, load)
+            return jax.lax.scan(body, (paged, state, rng, lens, last), None,
+                                length=8)
+
+        def chunk(params, paged, state, ids, n, at, pre_pages, own, slot):
+            return decoder.prefill_suffix_into_pages(
+                params, cfg, ids, n, at, (paged, state), pre_pages, own, slot)
+
+        i32 = jnp.int32
+        programs = {
+            "step": (step, (arg((2,), jnp.uint32), arg((rows, width), i32),
+                            arg((rows,), i32), arg((rows,), i32),
+                            arg((rows,), jnp.bool_),
+                            arg((rows,), jnp.float32))),
+            "prefill": (chunk, (arg((512,), i32), arg((), i32), arg((), i32),
+                                arg((64,), i32), arg((512 // page,), i32),
+                                arg((), i32)))}
+        for name, (fn, rest) in programs.items():
+            text = jax.jit(fn, donate_argnums=(1, 2)).trace(
+                params, *pools, *rest).lower(
+                    lowering_platforms=("tpu",)).as_text()
+            out[f"{preset} {name}"] = hashlib.sha256(text.encode()).hexdigest()
+    return out
+
+
+def main(argv) -> int:
+    if argv and argv[0] == "--digests":
+        sys.path.insert(0, os.getcwd())
+        print(json.dumps(digests(argv[1:])))
+        return 0
+    if not argv:
+        print(__doc__, file=sys.stderr)
+        return 2
+    other, presets = os.path.abspath(argv[0]), argv[1:] or list(PRESETS)
+    got = []
+    with tempfile.TemporaryDirectory() as scratch:
+        at = os.path.join(scratch, "tree")
+        for checkout in (other, ROOT):
+            shutil.rmtree(at, ignore_errors=True)
+            shutil.copytree(os.path.join(checkout, "polyrl_tpu"),
+                            os.path.join(at, "polyrl_tpu"),
+                            ignore=shutil.ignore_patterns("__pycache__"))
+            ran = subprocess.run(
+                [sys.executable, os.path.abspath(__file__), "--digests",
+                 *presets], cwd=at, check=True, capture_output=True,
+                text=True, env={**os.environ, "JAX_PLATFORMS": "cpu"})
+            got.append(json.loads(ran.stdout.strip().splitlines()[-1]))
+    same = True
+    for key in got[0]:
+        verdict = "same" if got[0][key] == got[1][key] else "DIFFERENT"
+        same &= verdict == "same"
+        print(f"{key}: {verdict} {got[0][key][:16]} {got[1][key][:16]}")
+    return 0 if same else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))
