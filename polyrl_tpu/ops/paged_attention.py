@@ -15,16 +15,23 @@ Two implementations with identical semantics for rows that hold a request:
 - ``paged_attention_pallas`` — the TPU kernel, and the only one for
   ungrouped decode. One program per row handles every KV head: a loop over
   the row's own blocks of pages (none for a row of length 0, which returns
-  zeros), each block fetched by hand-written double-buffered DMAs, one
-  descriptor a page for all heads (``pool.at[:, page]``), the next block
-  (of the next row with a request, if need be) in flight while this one
-  is multiplied. q and K, then the probabilities and V, meet on the MXU in
-  the pool's dtype (bf16) with float32 accumulation; the online-softmax
-  statistics stay float32. The page table, the lengths and the
+  zeros), each block fetched by hand-written DMAs into a ring of three
+  buffers a pool, one descriptor a page for all heads
+  (``pool.at[:, page]``). The look-ahead is two blocks of the BATCH: this
+  row's next blocks, then the first blocks of the rows with a request that
+  follow, so a row's end, the output's write-back and the next program's
+  start run with two blocks in flight. q and K, then the probabilities
+  and V, meet on the MXU in the pool's dtype (bf16) with float32
+  accumulation; the online-softmax statistics stay float32. A block whose
+  every key is live takes no mask and ONE wait a pool; a row's part-filled
+  last block fetches the pages the row owns and is multiplied by
+  sub-blocks, the dead ones skipped. The page table, the lengths and the
   next-live-row table ride as scalar prefetch. (PR 26; before it the TPU
   path called ``jax.experimental.pallas.ops.tpu.paged_attention``, which
   for 7 query heads a KV head multiplies in float32 one head a program
-  and ran at a third of the HBM bandwidth. Measured: PERF.md section 6.)
+  and ran at a third of the HBM bandwidth. PR 44: the ring; until then
+  two buffers and one block ahead, which a row's short last block did not
+  cover. Measured: PERF.md section 6.)
 
 Shared-prefix GROUPED decode (``grouped_paged_attention*``): GRPO's
 G-samples-per-prompt traffic means G slots share one physical prompt-KV
@@ -107,66 +114,111 @@ def _sublane_tile(dtype) -> int:
 
 
 # VMEM bytes of one block of K (or of V) pages, every KV head: the kernel
-# keeps two of each (double buffering), so four times this is its footprint
+# keeps a ring of ``_RING`` of each, so six times this is its footprint
 _KV_BLOCK_BYTES = 512 * 1024
+# buffers a pool: the block at hand and two in flight behind it
+_RING = 3
+# a row's part-filled last block is taken in at most this many sub-blocks
+# of whole pages and whole lane tiles of keys; dead ones are skipped. Two:
+# in four, a row that is ONE nearly full block paid for the pieces what
+# the ring had gained (PERF.md section 6, PR 44)
+_LAST_BLOCK_SUBS, _LANES = 2, 128
 
 
-def _pages_per_block(hkv: int, page_size: int, d: int, itemsize: int,
-                     p: int) -> int:
-    """Pages one loop iteration of the decode kernel fetches (for every KV
-    head): what fits ``_KV_BLOCK_BYTES``, at most the table's width."""
-    return max(1, min(p, _KV_BLOCK_BYTES // (hkv * page_size * d * itemsize)))
+def _block_plan(hkv: int, page_size: int, d: int, itemsize: int,
+                p: int) -> tuple[int, int, int]:
+    """(pages a block, sub-blocks of a row's last block, buffers a pool)
+    of the decode kernel, from the static shapes alone. A block is what
+    fits ``_KV_BLOCK_BYTES`` for every KV head, at most the table's width.
+    Every GQA shape is DMA-bound (``rep`` query rows a KV head are a few
+    FLOPs a byte), so the ring is ``_RING`` deep at all of them: what
+    ``mla_attention._block_plan`` gives its shapes below half the ridge."""
+    b = max(1, min(p, _KV_BLOCK_BYTES // (hkv * page_size * d * itemsize)))
+    subs = next(n for n in range(_LAST_BLOCK_SUBS, 0, -1)
+                if b % n == 0 and (n == 1 or b // n * page_size >= _LANES))
+    return b, subs, _RING
 
 
 def _paged_attn_kernel(lens_ref, live_from_ref, table_ref,  # scalar prefetch
                        q_ref,         # [1, Hkv, R, D] pre-scaled, pool dtype
                        k_hbm, v_hbm,  # [Hkv, N, page_size, D], left in HBM
                        out_ref,       # [1, Hkv, R, D]
-                       kbuf, vbuf,    # VMEM [2, Hkv, b * page_size, D]
-                       sems,          # DMA [2 pools, 2 buffers]
+                       kbuf, vbuf,    # VMEM [nbuf, Hkv, b * page_size, D]
+                       sems,          # DMA [2 pools, nbuf buffers]
                        buf_ref,       # SMEM [1]: buffer of the row's first block
-                       *, pages_per_block: int, page_size: int):
+                       *, pages_per_block: int, subs: int, nbuf: int,
+                       page_size: int):
     """One program per attention row, every KV head at once. A loop
     iteration is one block of ``b`` pages: ``b`` descriptors a pool, each
     fetching a page for all heads (``pool.at[:, page]``), one batched
     [Hkv, R, D] x [Hkv, b*page, D] product on the MXU in the pool's dtype
     with float32 accumulation, online softmax in float32. The loop runs
     over the row's own blocks only: a row of length 0 runs none and writes
-    zeros. While a block is computed the next one is in flight; past a
-    row's last block that is the first block of the next row WITH a
-    request (``live_from_ref[r]``: the first such row at or after ``r``, the
-    number of rows if none), so only the call's first live row waits for a
-    cold DMA. Only the pages a row owns are fetched; the rest of a last
-    block keeps an older block's (finite) values, masked by length."""
+    zeros.
+
+    The blocks live in a ring of ``nbuf`` buffers a pool, and the
+    look-ahead is counted in blocks of the BATCH: at the top of an
+    iteration the kernel starts the block ``nbuf - 1`` after the one at
+    hand in the order the grid reads them (this row's next blocks, then
+    the first blocks of the rows WITH a request that follow,
+    ``live_from_ref[r]``: the first such row at or after ``r``, the number
+    of rows if none), then waits for the block at hand. A row change so
+    has ``nbuf - 1`` blocks of later rows in flight, and the division, the
+    output's write-back and the next program's prologue run under them;
+    only the call's first live row waits for cold DMAs. A block whose
+    every key is live takes no mask and one wait a pool; the row's
+    part-filled last block fetches the pages the row owns, is taken by
+    sub-blocks and skips the dead ones (the rest of a live sub-block
+    keeps an older block's finite values, masked by length)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b = pages_per_block
     bt = b * page_size
+    sub = bt // subs
     n_rows = pl.num_programs(0)
     p = table_ref.shape[0] // n_rows
     row = pl.program_id(0)
     length = lens_ref[row]
+    n_full = length // bt
     n_blk = (length + bt - 1) // bt
 
-    def block_dma(r, blk, buf, start: bool):
-        """Start (or wait for) the copies of block ``blk`` of row ``r``."""
-        n_pg = (lens_ref[r] + page_size - 1) // page_size
-        for j in range(b):
-            col = blk * b + j
+    def page_dma(r, col, j, which, start: bool):
+        pg = table_ref[r * p + col]
+        at = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
+        for pool, dst, sem in ((k_hbm, kbuf, sems.at[0, which]),
+                               (v_hbm, vbuf, sems.at[1, which])):
+            cp = pltpu.make_async_copy(pool.at[:, pg], dst.at[which, :, at],
+                                       sem)
+            if start:
+                cp.start()
+            else:
+                cp.wait()
 
-            @pl.when(col < n_pg)
-            def _():
-                pg = table_ref[r * p + col]
-                for pool, dst, sem in ((k_hbm, kbuf, sems.at[0, buf]),
-                                       (v_hbm, vbuf, sems.at[1, buf])):
-                    cp = pltpu.make_async_copy(
-                        pool.at[:, pg],
-                        dst.at[buf, :, pl.ds(j * page_size, page_size)], sem)
-                    if start:
-                        cp.start()
-                    else:
-                        cp.wait()
+    def pages_dma(r, blk, which, start: bool, unrolled: bool = False):
+        """Start, or wait for, the copies of block ``blk`` of row ``r``
+        into buffer ``which``, two a live page. In a whole block's
+        iteration the starts are ``unrolled``, each under its predicate
+        (the block ahead may be any row's last). Elsewhere (the cold start
+        and a row's last block) the descriptors are a loop: unrolled
+        everywhere, the sister kernel (``mla_attention``) lowered four
+        times as slowly, which every decode program pays at set-up."""
+        n_pg = (lens_ref[r] + page_size - 1) // page_size - blk * b
+        if unrolled:
+            for j in range(b):
+                pl.when(j < n_pg)(functools.partial(
+                    page_dma, r, blk * b + j, j, which, start))
+        else:
+            jax.lax.fori_loop(
+                0, jnp.clip(n_pg, 0, b),
+                lambda j, _: page_dma(r, blk * b + j, j, which, start), None)
+
+    def wait_whole(which):
+        """Wait for a block of ``b`` live pages: every page's copy signals
+        the buffer's semaphore, so one wait for the buffer's bytes a pool
+        stands for all of them."""
+        for dst, sem in ((kbuf, sems.at[0, which]), (vbuf, sems.at[1, which])):
+            pltpu.make_async_copy(dst.at[which], dst.at[which], sem).wait()
 
     @pl.when(row == 0)
     def _first_program():
@@ -176,55 +228,106 @@ def _paged_attn_kernel(lens_ref, live_from_ref, table_ref,  # scalar prefetch
 
     buf0 = buf_ref[0]
 
+    def after(r, blk):
+        """The block after (r, blk) in the order the grid reads them;
+        row ``n_rows`` when there is none."""
+        n = (lens_ref[jnp.minimum(r, n_rows - 1)] + bt - 1) // bt
+        last = blk + 1 >= n
+        nxt = live_from_ref[jnp.minimum(r + 1, n_rows)]
+        return jnp.where(last, nxt, r), jnp.where(last, 0, blk + 1)
+
+    def start_block(r, blk, which, unrolled: bool = False):
+        @pl.when(r < n_rows)
+        def _():
+            pages_dma(r, blk, which, start=True, unrolled=unrolled)
+
+    def ring(which, k):
+        """The buffer ``k`` places after ``which``."""
+        return jax.lax.rem(which + k, nbuf)
+
     @pl.when(row == live_from_ref[0])
     def _cold_start():  # the call's first live row: nobody prefetched for it
-        block_dma(row, 0, buf0, start=True)
+        r, blk = row, 0
+        for k in range(nbuf - 1):
+            start_block(r, blk, ring(buf0, k))
+            r, blk = after(r, blk)
 
     q = q_ref[0]                                        # [Hkv, R, D]
     hkv, r_pad, d = q.shape
 
-    def body(i, carry):
+    def attend(carry, which, lo: int, n: int, left=None):
+        """Keys ``lo`` to ``lo + n`` of buffer ``which`` into the running
+        softmax; ``left``: how many of the block's keys are live, where
+        not all of these are."""
         m_prev, l_prev, acc = carry
-        buf = (buf0 + i) & 1
-        last = i + 1 == n_blk
-        nxt_row = jnp.where(last, live_from_ref[row + 1], row)
-        nxt_blk = jnp.where(last, 0, i + 1)
-
-        @pl.when(nxt_row < n_rows)
-        def _prefetch():
-            block_dma(nxt_row, nxt_blk, 1 - buf, start=True)
-
-        block_dma(row, i, buf, start=False)
         # one MXU pass in the pool's dtype whatever the process-wide
         # default says (Mosaic refuses bf16 operands at "highest")
         logits = jax.lax.dot_general(
-            q, kbuf[buf], (((2,), (2,)), ((0,), (0,))),
+            q, kbuf[which, :, pl.ds(lo, n), :], (((2,), (2,)), ((0,), (0,))),
             precision=jax.lax.Precision.DEFAULT,
-            preferred_element_type=jnp.float32)         # [Hkv, R, bt]
-        pos = i * bt + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2)
-        logits = jnp.where(pos < length, logits, NEG_INF)
+            preferred_element_type=jnp.float32)         # [Hkv, R, n]
+        if left is not None:
+            pos = lo + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2)
+            logits = jnp.where(pos < left, logits, NEG_INF)
         m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         probs = jnp.exp(logits - m_new)
         l_new = alpha * l_prev + jnp.sum(probs, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
-            probs.astype(vbuf.dtype), vbuf[buf],
+            probs.astype(vbuf.dtype), vbuf[which, :, pl.ds(lo, n), :],
             (((2,), (1,)), ((0,), (0,))),
             precision=jax.lax.Precision.DEFAULT,
             preferred_element_type=jnp.float32)         # [Hkv, R, D]
         return m_new, l_new, acc * alpha + pv
 
-    _, l, acc = jax.lax.fori_loop(
-        0, n_blk, body,
+    def fetch(i, whole: bool):
+        """Start the block ``nbuf - 1`` after ``i`` (of this row or of the
+        live rows that follow), then wait for block ``i``, ``whole`` or
+        the row's last: returns its buffer. The starts come first: issued
+        after the wait, the scheduler sinks them into the block's products
+        and the DMAs, which bound the kernel, start late."""
+        which = ring(buf0, i)
+        last = i + 1 == n_blk    # ``after(row, i)`` without its SMEM reads
+        r = jnp.where(last, live_from_ref[row + 1], row)
+        blk = jnp.where(last, 0, i + 1)
+        for _ in range(nbuf - 2):
+            r, blk = after(r, blk)
+        start_block(r, blk, ring(which, nbuf - 1), unrolled=whole)
+        if whole:
+            wait_whole(which)
+        else:
+            pages_dma(row, i, which, start=False)
+        return which
+
+    def whole_block(i, state):
+        # every key live: no mask, one basic block
+        return attend(state, fetch(i, whole=True), 0, bt)
+
+    def last_block(state):
+        # the row's part-filled block: dead sub-blocks are skipped
+        which = fetch(n_full, whole=False)
+        left = length - n_full * bt
+        state = attend(state, which, 0, sub, left)   # left >= 1: always live
+        for j in range(1, subs):
+            state = jax.lax.cond(
+                j * sub < left,
+                functools.partial(attend, which=which, lo=j * sub, n=sub,
+                                  left=left),
+                lambda c: c, state)
+        return state
+
+    carry = jax.lax.fori_loop(
+        0, n_full, whole_block,
         (jnp.full((hkv, r_pad, 1), NEG_INF, jnp.float32),
          jnp.zeros((hkv, r_pad, 1), jnp.float32),
          jnp.zeros((hkv, r_pad, d), jnp.float32)))
-    buf_ref[0] = (buf0 + n_blk) & 1
+    _, l, acc = jax.lax.cond(n_blk > n_full, last_block, lambda c: c, carry)
+    buf_ref[0] = ring(buf0, n_blk)
     # a row with no request: l == 0, acc == 0 -> zeros
     out_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "plan"))
 def paged_attention_pallas(
     q: jnp.ndarray,
     k_pool: jnp.ndarray,
@@ -233,12 +336,15 @@ def paged_attention_pallas(
     seq_lens: jnp.ndarray,
     scale: float | None = None,
     interpret: bool = False,
+    plan: tuple[int, int, int] | None = None,
 ) -> jnp.ndarray:
     """The TPU decode kernel (``_paged_attn_kernel``). Everything it needs
     follows from the shapes: q is scaled, rounded to the pool's dtype and
     its ``rep`` rows a KV head padded to that dtype's sublane tile (7 -> 16
-    in bf16; rows are free on the MXU up to 128); the pages a block holds
-    come from ``_pages_per_block``. A row of length 0 returns zeros."""
+    in bf16; rows are free on the MXU up to 128); the pages a block holds,
+    the last block's sub-blocks and the ring's depth come from
+    ``_block_plan`` unless a test or ``tools/bench_paged_attention.py``
+    hands the kernel another ``plan``. A row of length 0 returns zeros."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -250,7 +356,7 @@ def paged_attention_pallas(
     dtype = k_pool.dtype
     tile = _sublane_tile(dtype)
     r_pad = -(-rep // tile) * tile
-    b = _pages_per_block(hkv, page_size, d, dtype.itemsize, p)
+    b, subs, nbuf = plan or _block_plan(hkv, page_size, d, dtype.itemsize, p)
 
     qr = (q * scale).astype(dtype).reshape(s, hkv, rep, d)
     if r_pad != rep:
@@ -273,20 +379,20 @@ def paged_attention_pallas(
         out_specs=pl.BlockSpec((1, hkv, r_pad, d),
                                lambda si, *_: (si, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, hkv, b * page_size, d), dtype),
-            pltpu.VMEM((2, hkv, b * page_size, d), v_pool.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((nbuf, hkv, b * page_size, d), dtype),
+            pltpu.VMEM((nbuf, hkv, b * page_size, d), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, nbuf)),
             pltpu.SMEM((1,), jnp.int32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_attn_kernel, pages_per_block=b,
-                          page_size=page_size),
+        functools.partial(_paged_attn_kernel, pages_per_block=b, subs=subs,
+                          nbuf=nbuf, page_size=page_size),
         out_shape=jax.ShapeDtypeStruct((s, hkv, r_pad, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
         name="paged_attention",
-        # rows run in order: each hands the next live row its first block
+        # rows run in order: each hands the live rows after it their blocks
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(lens, live_from, page_table.astype(jnp.int32).reshape(-1),

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from polyrl_tpu.ops import mla_attention as mla
-from polyrl_tpu.ops.paged_attention import _pages_per_block
+from polyrl_tpu.ops.paged_attention import _block_plan
 
 W, RANK, PAGE = 640, 512, 16
 SCALE = 192 ** -0.5
@@ -112,14 +112,18 @@ def test_block_plan_follows_the_intensity_not_a_name():
 
 
 @pytest.mark.parametrize("hkv,page,d,itemsize,p,want", [
-    (4, 64, 128, 2, 192, 8),     # qwen2.5-7b, rollout-long and rollout-short
-    (4, 64, 128, 2, 64, 8),      # qwen3-30b-a3b.rollout-wide
-    (4, 64, 128, 4, 192, 4),     # a float32 pool
-    (1, 64, 128, 2, 192, 32),    # a tp shard left with one KV head
-    (8, 64, 128, 2, 32, 4),      # chip_smoke's model
-    (4, 64, 128, 2, 5, 5),       # a table narrower than the block
+    (4, 64, 128, 2, 192, (8, 2, 3)),   # qwen2.5-7b, rollout-long and -short
+    (4, 64, 128, 2, 64, (8, 2, 3)),    # qwen3-30b-a3b.rollout-wide
+    (4, 64, 128, 4, 192, (4, 2, 3)),   # a float32 pool
+    (1, 64, 128, 2, 192, (32, 2, 3)),  # a tp shard left with one KV head
+    (8, 64, 128, 2, 32, (4, 2, 3)),    # chip_smoke's model
+    (4, 64, 128, 2, 5, (5, 1, 3)),     # a table narrower than the block
+    (2, 64, 128, 2, 192, (16, 2, 3)),  # zaya1-8b.rollout-wide-cca
+    (10, 64, 128, 2, 320, (3, 1, 3)),  # phi-4-mini-flash's shared pool
+    (10, 64, 128, 2, 8, (3, 1, 3)),    # and its window layers' rings
 ])
 def test_gqa_pages_per_block_is_pinned(hkv, page, d, itemsize, p, want):
-    """The GQA kernels' block rule at the three GQA cells' shapes (and
-    its neighbours): an edit of it moves those cells' programs."""
-    assert _pages_per_block(hkv, page, d, itemsize, p) == want
+    """The GQA decode kernel's plan (pages a block, sub-blocks of a row's
+    last block, buffers a pool) at the five GQA cells' shapes (and their
+    neighbours): an edit of it moves those cells' programs."""
+    assert _block_plan(hkv, page, d, itemsize, p) == want

@@ -70,17 +70,34 @@ _KERNEL_CASES = [
     (8, 8, 32, np.float32, 8, 4),
     (8, 2, 128, np.float32, 8, 4),
     # the shapes that run on the chip: bf16 q and pools, 64-token pages,
-    # several pages a block (8, 4, 8)
+    # several pages a block (8, 4, 8, 16, 3)
     (28, 4, 128, jnp.bfloat16, 64, 20),   # qwen2.5-7b; 8 does not divide 20
     (16, 8, 128, jnp.bfloat16, 64, 9),    # qwen3-1.7b; 4 does not divide 9
     (32, 4, 128, jnp.bfloat16, 64, 16),   # qwen3-30b-a3b; two whole blocks
+    (8, 2, 128, jnp.bfloat16, 64, 40),    # zaya1-8b; 16 does not divide 40
+    (40, 10, 128, jnp.bfloat16, 64, 8),   # phi-4-mini-flash's paired heads
+                                          # on a ring: blocks of 3, 3, 2
 ]
+
+
+def _check_against_ref(q, kp, vp, table, lens, tol, **kw):
+    ref = paged_attention_ref(q, kp, vp, table, lens)
+    pal = paged_attention_pallas(q, kp, vp, table, lens, interpret=True, **kw)
+    assert pal.dtype == q.dtype and pal.shape == q.shape
+    live = np.asarray(lens) > 0
+    np.testing.assert_allclose(np.asarray(pal, np.float32)[live],
+                               np.asarray(ref, np.float32)[live],
+                               rtol=tol, atol=tol)
+    # a row without a request is zeros (the oracle attends one token of
+    # the null page there: nothing reads either)
+    assert not np.asarray(pal, np.float32)[~live].any()
 
 
 @pytest.mark.parametrize("hq,hkv,d,dtype,page,width", _KERNEL_CASES)
 def test_pallas_interpret_matches_ref(hq, hkv, d, dtype, page, width):
     rng = np.random.default_rng(1)
-    b = pa._pages_per_block(hkv, page, d, jnp.dtype(dtype).itemsize, width)
+    b, _subs, _nbuf = pa._block_plan(hkv, page, d,
+                                     jnp.dtype(dtype).itemsize, width)
     if page == PAGE:
         lens = (5, 17, 1, 32)
     else:
@@ -90,39 +107,82 @@ def test_pallas_interpret_matches_ref(hq, hkv, d, dtype, page, width):
         rng, s=len(lens), hq=hq, hkv=hkv, d=d, lens=lens, page=page,
         max_pages=width, n_pool=1 + sum(-(-ln // page) for ln in lens))
     q, kp, vp = (jnp.asarray(a, dtype) for a in (q, kp, vp))
-    ref = paged_attention_ref(q, kp, vp, table, lens)
-    pal = paged_attention_pallas(q, kp, vp, table, lens, interpret=True)
-    assert pal.dtype == q.dtype and pal.shape == q.shape
-    live = lens > 0
     # bf16: q is rounded once more after scaling and the probabilities go
     # to the MXU in bf16; both stay under a bf16 ulp of the output
-    tol = 2e-5 if dtype == np.float32 else 1e-2
-    np.testing.assert_allclose(np.asarray(pal, np.float32)[live],
-                               np.asarray(ref, np.float32)[live],
-                               rtol=tol, atol=tol)
-    # a row without a request is zeros (the oracle attends one token of
-    # the null page there: nothing reads either)
-    assert not np.asarray(pal, np.float32)[~live].any()
+    _check_against_ref(q, kp, vp, table, lens,
+                       2e-5 if dtype == np.float32 else 1e-2)
 
 
 def test_pallas_interpret_small_blocks_exact(monkeypatch):
     """float32 at a tight tolerance with the block cut to two 8-token
     pages, so that the online softmax runs over several blocks, the
     table's width (7) is not a multiple of the block and rows hand their
-    successor its first block across rows without a request."""
+    successors their first blocks across rows without a request."""
     monkeypatch.setattr(pa, "_KV_BLOCK_BYTES", 2 * 2 * PAGE * 24 * 4)
-    assert pa._pages_per_block(2, PAGE, 24, 4, 7) == 2
+    assert pa._block_plan(2, PAGE, 24, 4, 7) == (2, 1, 3)
     rng = np.random.default_rng(5)
     lens = (0, 0) + _edge_lens(2 * PAGE, 7 * PAGE) + (3,)
     q, kp, vp, table, lens, _, _ = _make_case(
         rng, s=len(lens), hq=6, hkv=2, d=24, lens=lens, max_pages=7,
         n_pool=40)
-    ref = paged_attention_ref(q, kp, vp, table, lens)
+    _check_against_ref(q, kp, vp, table, lens, 2e-5)
+
+
+def _ring_lens(pattern, bt, sub, nbuf):
+    """Row lengths, in keys, that walk the ring of ``nbuf`` buffers over
+    row boundaries: the look-ahead is ``nbuf - 1`` blocks of the BATCH."""
+    return {
+        # rows of 0, 1, nbuf - 1, nbuf and nbuf + 1 blocks, whole and not
+        "blocks_around_the_ring": (
+            0, bt, (nbuf - 1) * bt, nbuf * bt, (nbuf + 1) * bt, 0,
+            bt - 1, (nbuf - 1) * bt - 1, nbuf * bt + 1, (nbuf + 1) * bt - 1),
+        # rows without a request first, between and last: the look-ahead
+        # steps over them, however many lie together
+        "empty_rows_first_between_last": (
+            0, 0, 5, 0, 0, 0, bt + 3, 0, 2 * bt, 0, 0),
+        # the look-ahead spans more rows than one: every row is one
+        # part-filled block
+        "every_row_one_part_filled_block": (
+            3, bt - 1, 1, sub, sub + 1, bt - sub, 7, bt - 1, 2),
+        # a last block whose first sub-block alone is live, after 0, 1 and
+        # 2 whole blocks, filled to its end, by one key and by one less
+        "last_block_one_live_sub_block": (
+            sub, bt + 1, 2 * bt + sub, sub - 1, bt + sub - 1),
+        # every row dead but the last: the cold start falls on it
+        "one_live_row_last": (0, 0, 0, nbuf * bt + sub + 1),
+    }[pattern]
+
+
+# (pages a block, sub-blocks of a last block, buffers): two and three
+# blocks in flight, one block (the parent's depth), one page a block
+_RING_PLANS = [(2, 2, 3), (4, 4, 3), (4, 2, 4), (2, 1, 2), (1, 1, 3)]
+
+
+@pytest.mark.parametrize("plan", _RING_PLANS,
+                         ids=lambda p: "x".join(map(str, p)))
+@pytest.mark.parametrize("pattern", [
+    "blocks_around_the_ring", "empty_rows_first_between_last",
+    "every_row_one_part_filled_block", "last_block_one_live_sub_block",
+    "one_live_row_last"])
+def test_ring_runs_across_rows(pattern, plan):
+    """float32 at a tight tolerance under a handed plan: the ring's
+    position is carried from program to program, and blocks of later rows
+    are in flight while a row ends."""
+    b, subs, nbuf = plan
+    lens = _ring_lens(pattern, b * PAGE, b * PAGE // subs, nbuf)
+    width = -(-max(lens) // PAGE) + 1
+    rng = np.random.default_rng(9)
+    q, kp, vp, table, lens, _, _ = _make_case(
+        rng, s=len(lens), hq=6, hkv=2, d=24, lens=lens, max_pages=width,
+        n_pool=1 + sum(-(-ln // PAGE) for ln in lens))
+    _check_against_ref(q, kp, vp, table, lens, 2e-5, plan=plan)
+
+
+def test_every_row_dead():
+    rng = np.random.default_rng(6)
+    q, kp, vp, table, lens, _, _ = _make_case(rng, lens=(0, 0, 0))
     pal = paged_attention_pallas(q, kp, vp, table, lens, interpret=True)
-    live = lens > 0
-    np.testing.assert_allclose(np.asarray(pal)[live], np.asarray(ref)[live],
-                               rtol=2e-5, atol=2e-5)
-    assert not np.asarray(pal)[~live].any()
+    assert not np.asarray(pal).any()
 
 
 def test_empty_row_is_finite():

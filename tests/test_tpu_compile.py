@@ -59,6 +59,9 @@ def one_chip(v5e_2x2):
     (65, 7, 1, 128, jnp.bfloat16, 64, 192),
     (65, 28, 4, 128, jnp.float32, 64, 192),
     (129, 8, 2, 128, jnp.bfloat16, 64, 192),     # zaya1-8b's CCA layers
+    # phi-4-mini-flash-reasoning's paired heads: the shared pool, a ring
+    (129, 40, 10, 128, jnp.bfloat16, 64, 320),
+    (129, 40, 10, 128, jnp.bfloat16, 64, 8),
 ])
 def test_decode_kernel_compiles_for_v5e(one_chip, s, hq, hkv, d, dtype, page,
                                         width):

@@ -70,19 +70,19 @@ def inputs(lengths: list[int], heads: int, table_width: int, seed: int):
     return q, pool, jnp.asarray(table), lens
 
 
-def load_module(tree: str):
-    """``polyrl_tpu.ops.mla_attention`` of another checkout, under a name
-    of its own (its imports resolve to this tree's package, which the
-    kernel file shares only helpers with)."""
-    path = os.path.join(tree, "polyrl_tpu", "ops", "mla_attention.py")
+def load_module(tree: str, name: str = "mla_attention"):
+    """``polyrl_tpu.ops.<name>`` of another checkout, under a name of its
+    own (its imports resolve to this tree's package, which the kernel file
+    shares only helpers with)."""
+    path = os.path.join(tree, "polyrl_tpu", "ops", name + ".py")
     spec = importlib.util.spec_from_file_location(
-        "mla_attention_" + os.path.basename(os.path.abspath(tree)), path)
+        name + "_" + os.path.basename(os.path.abspath(tree)), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
 
 
-def kernel_ms(trace_dir: str) -> list[float]:
+def kernel_ms(trace_dir: str, kernel: str = KERNEL) -> list[float]:
     """Device durations of the kernel's events in the newest trace."""
     path = max(glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
                                       "*.xplane.pb")), key=os.path.getmtime)
@@ -95,7 +95,7 @@ def kernel_ms(trace_dir: str) -> list[float]:
             if line.name != "XLA Ops":
                 continue
             out += [e.duration_ns / 1e6 for e in line.events
-                    if e.name.lstrip("%").startswith(KERNEL)]
+                    if e.name.lstrip("%").startswith(kernel)]
     return out
 
 

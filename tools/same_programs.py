@@ -9,10 +9,15 @@ the parent's to the byte.
     python tools/same_programs.py <other checkout> [preset ...]
 
 Each checkout is copied to the same scratch path in turn (a kernel's
-serialized body carries its source's path) and lowered in a process of its
-own, without caller frames in the locations (a line that moved in a file
-is no other program). Prints a line a program and exits 1 where two
-differ."""
+serialized body carries its source's path) and each preset lowered in a
+process of its own, without caller frames in the locations (a line that
+moved in a file is no other program). A process a preset, because a
+kernel's serialized body carries the locations of its operations, and JAX
+traces a jitted helper of ``jax.numpy`` (``//``, ``%``, ``where``,
+``minimum``) once a process and keeps the location of that FIRST call: in
+one process Ling's latent kernel named the line of ``paged_attention.py``
+where qwen's step had first divided, and read different once that line had
+moved (PR 44). Prints a line a program and exits 1 where two differ."""
 
 from __future__ import annotations
 
@@ -101,11 +106,14 @@ def main(argv) -> int:
             shutil.copytree(os.path.join(checkout, "polyrl_tpu"),
                             os.path.join(at, "polyrl_tpu"),
                             ignore=shutil.ignore_patterns("__pycache__"))
-            ran = subprocess.run(
-                [sys.executable, os.path.abspath(__file__), "--digests",
-                 *presets], cwd=at, check=True, capture_output=True,
-                text=True, env={**os.environ, "JAX_PLATFORMS": "cpu"})
-            got.append(json.loads(ran.stdout.strip().splitlines()[-1]))
+            found = {}
+            for preset in presets:
+                ran = subprocess.run(
+                    [sys.executable, os.path.abspath(__file__), "--digests",
+                     preset], cwd=at, check=True, capture_output=True,
+                    text=True, env={**os.environ, "JAX_PLATFORMS": "cpu"})
+                found.update(json.loads(ran.stdout.strip().splitlines()[-1]))
+            got.append(found)
     same = True
     for key in got[0]:
         verdict = "same" if got[0][key] == got[1][key] else "DIFFERENT"
