@@ -144,32 +144,41 @@ def on_tpu(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
+# (hidden, expert width, experts a layer, layers of the stack, choices a
+# token, rows, rows a tile): qwen3-30b-a3b's decode step and prefill
+# chunk; the decode steps of dots.vlm1 (an expert's gate and up are 58.7
+# MB: slabs along K) and of ZAYA1-8B (16.8 MB; its down is 8 MiB whole)
+GROUPED_SHAPES = {
+    "qwen3-30b-a3b decode": (2048, 768, 128, 7, 8, 64, 16),
+    "qwen3-30b-a3b prefill": (2048, 768, 128, 7, 8, 512, 64),
+    "dots.vlm1 decode": (7168, 2048, 16, 4, 8, 65, 128),
+    "zaya1-8b decode": (2048, 2048, 16, 12, 1, 129, 32),
+}
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8])
-@pytest.mark.parametrize("rows", MOE_ROWS)
+@pytest.mark.parametrize("shape", GROUPED_SHAPES)
 def test_grouped_expert_matmul_compiles_for_v5e(one_chip, chip_precision,
-                                                rows, dtype):
-    """The owned grouped matmul over the rows' top-8 choices in whole
-    tiles an expert, 128 experts of 2048x768 (one layer of a stack of 7),
-    in bf16 and with int8 experts: a whole expert's block fits VMEM."""
+                                                shape, dtype):
+    """The owned grouped matmul over the rows' choices in whole tiles an
+    expert, one layer's experts of a whole stack, in bf16 and with int8
+    experts: a step's slabs, the float32 sums and the blocks fit VMEM."""
     from polyrl_tpu.ops import grouped_matmul as gm
 
-    cfg = _moe_cfg()
-    e, k = cfg.num_experts, cfg.num_experts_per_tok
-    d, f = cfg.hidden_size, cfg.moe_intermediate_size
-    tile = gm.row_tile(rows * k, e)
+    d, f, e, stack, k, rows, tile = GROUPED_SHAPES[shape]
+    assert tile == gm.row_tile(rows * k, e)
     n_tiles = rows * k // tile + e
-    assert tile == (16 if rows == 64 else 64)
 
     def arg(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     for n_w, shape in [(2, (d, f)), (1, (f, d))]:    # SwiGLU, then down
-        scales = ((arg((7 * e, shape[1]), jnp.float32),) * n_w
+        scales = ((arg((stack * e, shape[1]), jnp.float32),) * n_w
                   if dtype == jnp.int8 else None)
         compiled = jax.jit(functools.partial(
             gm.grouped_matmul_pallas, tile=tile)).lower(
                 arg((n_tiles * tile, shape[0]), jnp.bfloat16),
-                (arg((7 * e, *shape), dtype),) * n_w,
+                (arg((stack * e, *shape), dtype),) * n_w,
                 arg((n_tiles,), jnp.int32), arg((1,), jnp.int32),
                 scales).compile()
         assert "tpu_custom_call" in compiled.as_text()
