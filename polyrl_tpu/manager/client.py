@@ -19,6 +19,7 @@ lands there.
 
 from __future__ import annotations
 
+import fcntl
 import http.client
 import json
 import os
@@ -61,12 +62,20 @@ def build_manager(force: bool = False) -> str:
     about the sources in it. ``force`` rebuilds unconditionally
     (``make -B``)."""
     cmd = ["make", "-C", _CPP_DIR] + (["-B"] if force else [])
+    # one build at a time, under a lock on the sources' directory: in a
+    # fresh checkout the first callers (six test workers) all find no
+    # binary, and one that starts the binary while another's link is
+    # still writing it fails with ETXTBSY ("Text file busy")
+    lock = os.open(_CPP_DIR, os.O_RDONLY)
     try:
+        fcntl.flock(lock, fcntl.LOCK_EX)
         subprocess.run(cmd, check=True, capture_output=True, text=True)
     except subprocess.CalledProcessError as exc:
         raise RuntimeError(
             f"building the rollout manager failed ({' '.join(cmd)}):\n"
             f"{exc.stderr[-2000:]}") from exc
+    finally:
+        os.close(lock)
     return _BINARY
 
 

@@ -335,3 +335,37 @@ def _scatter_pages_kv(pool, page_ids, upd):
            + page_ids[None, :].astype(jnp.int32)).reshape(-1)
     flat = flat.at[idx].set(upd.reshape(hkv * npg, ps * d).astype(pool.dtype))
     return flat.reshape(hkv, n, ps, d)
+
+
+def _slab_ids(a, page_ids):
+    """Slabs of ``a``'s ``[H * N, ps, w]`` view that hold the pages
+    ``page_ids`` [B, n] of every head, head-major: [H * B * n]. The view's
+    last two dimensions are the pool's own tiles, so a gather or a scatter
+    over its slabs leaves the pool's layout as it is."""
+    h, n = a.shape[:2]
+    return (jnp.arange(h, dtype=jnp.int32)[:, None] * n
+            + page_ids.reshape(-1)[None, :].astype(jnp.int32)).reshape(-1)
+
+
+def _gather_slabs_kv(pool, page_ids):
+    """``_gather_kv`` by slabs (``_slab_ids``): 2,560 slabs of 16 KB for a
+    prefix of 256 pages, where token rows of the flat view were 164k
+    gathers of 256 B (1.4 ms less of a chunk's 56 ms on the chip)."""
+    b, n_pg = page_ids.shape
+
+    def one(a):
+        h, n, ps, w = a.shape
+        got = a.reshape(h * n, ps, w)[_slab_ids(a, page_ids)]
+        return got.reshape(h, b, n_pg * ps, w).transpose(1, 2, 0, 3)
+
+    return one(pool[0]), one(pool[1])
+
+
+def _scatter_slabs(a, page_ids, rows):
+    """``a`` [H, N, ps, w] with the pages ``page_ids`` [B, n] holding
+    ``rows`` [B, n * ps, H, w], by slabs (``_slab_ids``)."""
+    h, n, ps, w = a.shape
+    b, n_pg = page_ids.shape
+    pages = rows.reshape(b * n_pg, ps, h, w).transpose(2, 0, 1, 3)
+    return a.reshape(h * n, ps, w).at[_slab_ids(a, page_ids)].set(
+        pages.reshape(-1, ps, w).astype(a.dtype)).reshape(a.shape)

@@ -29,25 +29,23 @@ keeps nothing) where ``i % mb_per_layer == 0`` and ``cross`` (attention
 with its own queries over the ``diff`` layer's pages, which keeps nothing
 and READS another layer's pool) otherwise.
 
-What a sequence keeps is PAGED (so many values a token, in pages
-that the engine's allocator hands out: a K/V pair of ``[Hkv, N, page, D]``
-for ``gqa``, one latent pool ``[1, N, page, row]`` for ``mla``, ``row`` being ``rank +
-rope`` rounded up to whole lanes), or
-a SLOT (fixed-size arrays indexed by the engine's slot, for ``kda``), or,
-for ``cca``, BOTH in one layer (``PagedAndSlot``): the finished keys and
-the (shifted) values of every token as a K/V pair in pages, and in the
-slot what the next token's convolutions and value shift read of the
-tokens before it (the pre-convolution latents' tail, the first
-convolution's output's tail, the last token's second value half). A
-``swa`` layer keeps a RING: a K/V pair of the last ``window`` tokens in
-pages that belong to the SLOT (``window / page_size`` pages a slot in a
-pool of the layer's own, at a place fixed when the pool is made: token
-``t`` lies at ``t % window``, and a row's length is ``min(t + 1,
-window)``): attention without positions does not care for the order of
-its keys, so the paged write and attention kernels serve it as they are,
-nothing is ever freed, and the allocator and the ledger never see it: a
-sequence's window costs the same whatever its length. A ``cross`` layer
-keeps NOTHING and names the layer whose pages it reads (``Reads``;
+What a sequence keeps is said by the mixer's record (``models/mixers``:
+``Mixer.cache``, which ``layer_cache`` looks up; each module's docstring
+has its arrays) in this module's types: PAGED (so many values a token, in
+pages that the engine's allocator hands out: a K/V pair of ``[Hkv, N,
+page, D]`` for ``gqa`` and ``diff``, one latent pool ``[1, N, page, row]``
+for ``mla``, ``row`` being ``rank + rope`` rounded up to whole lanes), or a
+SLOT (fixed-size arrays indexed by the engine's slot, for ``kda`` and the
+scans), or BOTH in one layer (``PagedAndSlot``, for ``cca``). A ``swa``
+layer keeps a RING: a K/V pair of the last ``window`` tokens in pages
+that belong to the SLOT (``window / page_size`` pages a slot in a pool of
+the layer's own, at a place fixed when the pool is made: token ``t`` lies
+at ``t % window``, and a row's length is ``min(t + 1, window)``):
+attention without positions does not care for the order of its keys, so
+the paged write and attention kernels serve it as they are, nothing is
+ever freed, and the allocator and the ledger never see it: a sequence's
+window costs the same whatever its length. A ``cross`` layer keeps
+NOTHING and names the layer whose pages it reads (``Reads``;
 ``pool_index`` hands it the producer's pool), so a page's bytes are ONE
 layer's for the whole model. What a layer hands to later layers of the
 same step (``ssm_mem``'s ``m``, as a router's carried latent) is cached
@@ -71,7 +69,8 @@ copies are written for a K/V pair without tails: ``without_kernel`` names
 
 Readers: ``decoder.make_paged_pools`` and ``CBEngine._make_pools`` (the
 arrays; the page ledger takes its bytes a page from the paged ones),
-``CBEngine`` (the two questions), ``models/hybrid.py`` (the layer loop);
+``CBEngine`` (the two questions), ``models/hybrid.py`` (the layer loop)
+and ``models/mixers`` (the dimensions);
 ``benchmark/lib/costs_hybrid.py``, ``costs_latent.py``, ``costs_cca.py``
 and ``costs_sambay.py`` repeat the arithmetic on their own."""
 
@@ -257,46 +256,14 @@ def diff_dims(cfg) -> tuple[int, int, int]:
     return cfg.num_heads // 2, cfg.num_kv_heads // 2, 2 * cfg.head_dim_
 
 
-def producer(cfg, mixer: str) -> int:
-    """The place in the plan of the one layer of kind ``mixer``
-    (``ssm_mem``, ``diff``) that later layers of a step read."""
-    return next(l for l, p in enumerate(layer_plan(cfg)) if p.mixer == mixer)
-
-
 def layer_cache(cfg, plan: LayerPlan, dtype=None
                 ) -> Paged | Slot | PagedAndSlot | Ring | Reads | None:
-    dtype = dtype or cfg.dtype
-    if plan.mixer in ("ssm", "ssm_mem"):
-        # the state with the inner width on the lanes: ``[state, inner]``
-        # is whole (8, 128) tiles, ``[inner, state]`` would be padded
-        # eightfold on the chip
-        inner, n, k, _rank = ssm_dims(cfg)
-        return Slot((("state", (n, inner), STATE_DTYPE),
-                     ("conv", (k - 1, inner), dtype)))
-    if plan.mixer in ("swa", "diff"):
-        _h, pairs, width = diff_dims(cfg)
-        return (Paged(2, pairs, width) if plan.mixer == "diff"
-                else Ring(pairs, width, cfg.sliding_window, dtype))
-    if plan.mixer == "cross":
-        return Reads(producer(cfg, "diff"))
-    if plan.mixer == "gmu":
-        return None
-    if plan.mixer == "gqa":
-        return Paged(2, cfg.num_kv_heads, cfg.head_dim_)
-    if plan.mixer == "mla":
-        return Paged(1, 1, latent_row(cfg))
-    if plan.mixer == "cca":
-        hq, hkv, d = cca_dims(cfg)
-        mixed = (hq + hkv) * d
-        return PagedAndSlot(
-            Paged(2, hkv, d),
-            Slot((("latent", (cfg.cca_time0 - 1, mixed), dtype),
-                  ("mixed", (cfg.cca_time1 - 1, mixed), dtype),
-                  ("value", (hkv * d // 2,), dtype))))
-    h, dk, dv = kda_dims(cfg)
-    k = cfg.short_conv_kernel_size
-    return Slot((("state", (h, dk, dv), STATE_DTYPE),
-                 ("conv", (k - 1, h * (2 * dk + dv)), dtype)))
+    """What a sequence keeps for a layer: its mixer's record says
+    (``models/mixers``; imported here, not above: the records are built
+    from this module's types)."""
+    from polyrl_tpu.models.mixers import MIXERS
+
+    return MIXERS[plan.mixer].cache(cfg, plan, dtype or cfg.dtype)
 
 
 def cache_spec(cfg, dtype=None) -> tuple:

@@ -84,10 +84,10 @@ emission or iteration, never once a token, all on this profiler's clock:
   layers' ``wkv_b`` where it lies in the stack (``ops/mla_proj.py``);
   ``ssm_state_rows`` / ``shared_kv_rows_read`` / ``window_rows_read`` —
   for a model of the SambaY family, counted by the landed steps on the
-  device (``hybrid.CACHE_ROW_KEYS``): live rows times Mamba layers (a
-  state read and written each), keys of the one shared K/V pool read,
-  summed over the layers that attend over it, and keys of the window
-  layers' rings read (0 for every other model);
+  device (the ``counts`` of its mixers' records, ``models/mixers``): live
+  rows times Mamba layers (a state read and written each), keys of the
+  one shared K/V pool read, summed over the layers that attend over it,
+  and keys of the window layers' rings read (0 for every other model);
   ``decode_dispatches_cold`` — those of the dispatches enqueued with
   NOTHING outstanding (the device had run dry: an engine that keeps its
   run-ahead does it once a burst, one that drains before every dispatch
@@ -211,9 +211,8 @@ class EngineLoopProfiler:
         self._win_prev = [0.0] * 5
         self._win_busy_mark = 0.0  # device_busy_s at the last iteration close
         # completion stamps: dispatches whose results will land, oldest
-        # first, as (their fused decode steps (0 for a prefill), whether
-        # those sampled inside the head, their live rows, whether their
-        # KDA layers took the kernel)
+        # first, as (their fused decode steps (0 for a prefill), their
+        # live rows, the counters each of those steps moves)
         self._landing: collections.deque = collections.deque()
         self._tail_unlanded = False  # dispatched after them, lands nothing
         self._busy_from: float | None = None  # busy not yet counted, since
@@ -351,16 +350,14 @@ class EngineLoopProfiler:
     # -- completion stamps ----------------------------------------------------
 
     def on_dispatch(self, kind: str, steps: int = 0, lands: bool = True,
-                    fused_sample: bool = False, rows: int = 0,
-                    kda_kernel: bool = False,
-                    mla_proj_kernel: bool = False) -> None:
+                    rows: int = 0, counters: tuple = ()) -> None:
         """A dispatch was just enqueued on the device; ``steps``: the
-        decode steps it fuses, over ``rows`` live rows, ``fused_sample``:
-        they sample inside the head, ``kda_kernel``: their KDA layers
-        update the state in the kernel, ``mla_proj_kernel``: their MLA
-        layers read ``wkv_b`` in place. ``lands`` False: it returns nothing
-        the host fetches (a chunked prefill's mid-chunk),
-        so a later dispatch's landing stands for it."""
+        decode steps it fuses, over ``rows`` live rows; ``counters``: the
+        keys of ``CUMULATIVE_KEYS`` that each of those steps moves by one
+        when it lands (``fused_sample_steps``: they sample inside the
+        head; a kernel's share counter: their layers take it). ``lands``
+        False: it returns nothing the host fetches (a chunked prefill's
+        mid-chunk), so a later dispatch's landing stands for it."""
         now = self._clock()
         with self._lock:
             c = self._cum
@@ -372,8 +369,7 @@ class EngineLoopProfiler:
             if cold:
                 self._busy_from = now
             if lands:
-                self._landing.append((steps, fused_sample, rows, kda_kernel,
-                                      mla_proj_kernel))
+                self._landing.append((steps, rows, counters))
                 self._tail_unlanded = False
             else:
                 self._tail_unlanded = True
@@ -397,7 +393,8 @@ class EngineLoopProfiler:
 
     def on_cache_rows(self, rows: dict) -> None:
         """Landed decode steps counted ``rows`` (key -> count) more of
-        the cache rows they touch (``hybrid.CACHE_ROW_KEYS``)."""
+        the cache rows they touch (the entries of ``hybrid.load_names``
+        that are keys of ``CUMULATIVE_KEYS``)."""
         with self._lock:
             for key, n in rows.items():
                 self._cum[key] += n
@@ -411,16 +408,11 @@ class EngineLoopProfiler:
         with self._lock:
             c = self._cum
             for _ in range(min(n, len(self._landing))):
-                steps, fused_sample, rows, kda_kernel, mla_proj_kernel = (
-                    self._landing.popleft())
+                steps, rows, counters = self._landing.popleft()
                 c["decode_steps_done"] += steps
                 c["row_steps_done"] += steps * rows
-                if fused_sample:
-                    c["fused_sample_steps"] += steps
-                if kda_kernel:
-                    c["kda_kernel_steps"] += steps
-                if mla_proj_kernel:
-                    c["mla_proj_kernel_steps"] += steps
+                for key in counters:
+                    c[key] += steps
                 self._landed_at.append(now)
             gap = None if self._gap_from is None else now - self._gap_from
             if gap is not None:

@@ -153,7 +153,7 @@ def kda_state_update(state, q, k, v, g, beta, live):
             state, q, k, v, jnp.where(live[:, None, None], g, 0.0),
             jnp.where(live[:, None], beta, 0.0),
             interpret=jax.default_backend() != "tpu")
-    from polyrl_tpu.models.hybrid import kda_recurrent_step
+    from polyrl_tpu.models.mixers.kda import kda_recurrent_step
 
     old = state[:s]
     new, o = kda_recurrent_step(old.astype(jnp.float32), q, k, v, g, beta)

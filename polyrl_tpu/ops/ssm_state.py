@@ -1,12 +1,12 @@
 """One position of a Mamba-1 selective scan over the decode step's rows,
-in one pass over the state and in place: what ``hybrid.ssm_step``
+in one pass over the state and in place: what ``mixers.ssm.ssm_step``
 computes, for the leading ``S`` rows of a layer's states ``[slots, N, I]``
 float32 (``N`` the state size on the sublanes, ``I`` the inner width on
 the lanes).
 
 ``ssm_state_update`` is the dispatcher: on a TPU, for a float32 state
 whose ``I`` is whole lane tiles and ``N`` whole sublane tiles, the kernel
-below; elsewhere (and for every other shape) ``hybrid.ssm_step`` on
+below; elsewhere (and for every other shape) ``mixers.ssm.ssm_step`` on
 ``state[:S]``, the rows without a request kept by a ``where``, written
 back. It notes nothing in ``ops/dispatch.py`` (as ``ops/kda_state.py``
 notes nothing): what says that the kernel ran is its own event,
@@ -150,7 +150,7 @@ def ssm_state_update(lp, state, c, dt, bm, cm, live):
     """``state`` [slots, N, I] with its leading ``S`` rows advanced one
     position where ``live`` [S] says so and kept where not, and the scan's
     output ``m`` [S, I] float32 (a kept row's is not for use): the kernel
-    where ``in_kernel`` says so, else ``hybrid.ssm_step`` on those rows, a
+    where ``in_kernel`` says so, else ``mixers.ssm.ssm_step`` on those rows, a
     ``where`` and the write-back. ``lp``: the layer's ``a_log`` [N, I] and
     ``d_skip`` [I]; ``c dt`` [S, I], ``bm cm`` [S, N], float32."""
     s = c.shape[0]
@@ -159,7 +159,7 @@ def ssm_state_update(lp, state, c, dt, bm, cm, live):
         new, m = ssm_state_pallas(state, -jnp.exp(lp["a_log"]), dt, dt * c,
                                   bm, cm)
         return new, m + lp["d_skip"] * c
-    from polyrl_tpu.models.hybrid import ssm_step
+    from polyrl_tpu.models.mixers.ssm import ssm_step
 
     old = state[:s]
     new, m = ssm_step(lp, old, c, dt, bm, cm)

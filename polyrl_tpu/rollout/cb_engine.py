@@ -72,7 +72,8 @@ import numpy as np
 from polyrl_tpu import obs
 from polyrl_tpu.engine_options import EngineOptions
 from polyrl_tpu.models import cache_spec, decoder, hybrid
-from polyrl_tpu.obs.engine_profile import EngineLoopProfiler
+from polyrl_tpu.obs.engine_profile import (CUMULATIVE_KEYS,
+                                           EngineLoopProfiler)
 from polyrl_tpu.rollout.engine import next_bucket
 from polyrl_tpu.rollout.flightdeck import EngineFlightDeck, ThroughputEWMA
 from polyrl_tpu.rollout.kvledger import PageLedger
@@ -306,13 +307,14 @@ class CBEngine:
                 log.info("%s is off: no kernel for %s layers "
                          "(models/cache_spec.py::without_kernel)",
                          feature, "/".join(self._no_kernel[feature]))
-        # whether a decode step's KDA layers update their states in the
-        # one-pass kernel (the profiler's ``kda_kernel_steps``): one answer
-        # for the engine's life
-        self._kda_kernel = self.stateful and hybrid.kda_in_kernel(cfg)
-        # and whether its MLA layers multiply ``wkv_b`` where it lies in
-        # the stack (``mla_proj_kernel_steps``), at the step's rows
-        self._mla_proj_kernel = hybrid.mla_in_kernel(cfg, max_slots + 1)
+        # the profiler's counters that every decode step of a dispatch
+        # moves: the share counters of the kernels the plan's layers take
+        # at the step's rows (``hybrid.step_counters``), and where the
+        # dispatch samples inside the head the sampler's; one answer for
+        # the engine's life
+        kernels = hybrid.step_counters(cfg, max_slots + 1)
+        self._step_counters = {False: kernels,
+                               True: (*kernels, "fused_sample_steps")}
         self.max_slots = max_slots
         self.page_size = page_size
         self.max_seq_len = o.max_seq_len
@@ -471,8 +473,16 @@ class CBEngine:
         # ``decode_steps_done``), as the model counted it on the device
         # (decoder._moe_mlp), summed over fused steps and layers: (row,
         # expert) pairs of live rows, experts with a row, rows of the
-        # busiest expert. Stays zero for a dense model.
-        self._moe_load = np.zeros(hybrid.load_width(cfg), np.int64)
+        # busiest expert; then what the plan's mixers count
+        # (``hybrid.load_names``, the one declaration of the vector's
+        # entries). Empty for a dense uniform model.
+        self._load_names = hybrid.load_names(cfg)
+        self._moe_load = np.zeros(len(self._load_names), np.int64)
+        # the entries that the profiler's cumulative counters carry, by
+        # their names; ``moe_info`` has the rest
+        self._load_profiled = tuple(
+            i for i, name in enumerate(self._load_names)
+            if name in CUMULATIVE_KEYS)
         # group pre-ref registry: leader publish pre-takes group_size-1 refs
         # on the shared prefix entries so pool-pressure eviction can't race
         # the siblings' attach; consumed per attach, TTL-swept for groups
@@ -552,20 +562,18 @@ class CBEngine:
     def _landed(self, batch: list, fetched: list) -> None:
         """The oldest ``len(batch)`` queued dispatch outputs reached the
         host as ``fetched``: the completion-stamp counters move, and with
-        them (the same steps) the MoE load the decode steps counted."""
-        # a SambaY model's vector counts cache rows (hybrid.CACHE_ROW_KEYS:
-        # what the steps' Mamba, shared-pool and window layers touched),
-        # which the profiler's cumulative counters of those names carry
-        rows = self.profiler is not None and bool(self.cfg.mb_per_layer)
+        them (the same steps) the load the decode steps counted."""
+        # the entries the profiler carries move by what these steps added
+        rows = self._load_profiled if self.profiler is not None else ()
         before = self._moe_load.copy() if rows else None
         for entry, arrs in zip(batch, fetched):
             if entry[0] == "step" and arrs[3] is not None:
                 self._moe_load += arrs[3]
         if self.profiler is not None:
             if rows:
-                self.profiler.on_cache_rows(dict(zip(
-                    hybrid.CACHE_ROW_KEYS,
-                    (int(v) for v in self._moe_load - before))))
+                self.profiler.on_cache_rows({
+                    self._load_names[i]: int(self._moe_load[i] - before[i])
+                    for i in rows})
             stalled_s = self.profiler.on_landed(len(batch))
             if stalled_s is not None:
                 self._log_stall(stalled_s)
@@ -648,22 +656,17 @@ class CBEngine:
                 if self.prefix_cache is not None else 0)
 
     def moe_info(self) -> dict:
-        """Flat cumulative server_info fields of the MoE blocks' load over
-        the decode steps landed so far ({} for a dense model)."""
+        """Flat cumulative server_info fields of the load a routed model's
+        decode steps counted, landed so far, under the names of
+        ``hybrid.load_names`` ({} for a dense model): the MoE blocks' (in a
+        model of several kinds of layer ``moe_choices`` too: every choice
+        of a live row, held here or not, where ``moe_routed`` counts the
+        held ones) and what the plan's mixers count."""
         if not self.cfg.num_experts:
             return {}
-        routed, hit, load_max, *more = (int(v) for v in self._moe_load)
-        info = {"moe_routed": routed, "moe_experts_hit": hit,
-                "moe_load_max": load_max}
-        if more:
-            # a model of several kinds of layer (hybrid.load_width): every
-            # choice of a live row, held here or not (``moe_routed`` counts
-            # the held ones), live rows times KDA layers, the latent rows
-            # attended, summed over the MLA layers, and for a model with
-            # CCA layers live rows times those
-            info.update(zip(("moe_choices", "kda_state_rows",
-                             "mla_rows_read", "cca_tail_rows"), more))
-        return info
+        return {name: int(v)
+                for name, v in zip(self._load_names, self._moe_load)
+                if name not in CUMULATIVE_KEYS}
 
     def recurrent_state(self, rid: str):
         """What the slot of the running request ``rid`` holds of its
@@ -685,8 +688,7 @@ class CBEngine:
                 return None
             self._ensure_dev_state()
             consumed = int(np.asarray(self._dev_state["seq_lens"])[i])
-            rows = [hybrid.held_state(self.cfg, arrays, i)
-                    for arrays in self._pools[1]]
+            rows = hybrid.held_state(self.cfg, self._pools[1], i)
         return consumed, rows
 
     def kv_memory_info(self) -> dict:
@@ -2792,10 +2794,9 @@ class CBEngine:
             kind = entry[0]
             decode = kind in ("step", "spec")
             self.profiler.on_dispatch(
-                kind, entry[3] if decode else 0, fused_sample=fused_sample,
+                kind, entry[3] if decode else 0,
                 rows=len(entry[2]) if decode else 0,
-                kda_kernel=decode and self._kda_kernel,
-                mla_proj_kernel=decode and self._mla_proj_kernel)
+                counters=self._step_counters[fused_sample] if decode else ())
         self._last_two.append(entry[1])
         with self._fetch_cv:
             self._emit_q.append(entry)

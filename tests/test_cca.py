@@ -117,7 +117,7 @@ def test_the_ledger_counts_a_page_by_its_pages_not_its_slot(cfg, params):
     eng._accounted_bytes()
     assert eng.kvledger.page_bytes == 3 * 2 * 2 * 16 * 4 * 8
     assert eng.stateful and eng.prefix_cache is None
-    assert not eng._kda_kernel
+    assert eng._step_counters[False] == ()
     with pytest.raises(ValueError, match="spec_tokens"):
         _engine(cfg, params, spec_tokens=2)
 
@@ -189,9 +189,9 @@ def test_chunked_prefill_then_decode_agrees_with_the_full_forward(
         np.testing.assert_array_equal(np.asarray(a[0]), b)
     # the slot's tails after the last token are the reference's
     tr = ref.trace(params, file_keys(cfg), ids.tolist(), n_prompt, n_new)
-    for l, tails in enumerate(pools[1]):
-        mine = hybrid.held_state(cfg, tails, 1)
-        np.testing.assert_allclose(mine, tr["states"][l], atol=LOGIT_TOL)
+    for mine, theirs in zip(hybrid.held_state(cfg, pools[1], 1),
+                            tr["states"]):
+        np.testing.assert_allclose(mine, theirs, atol=LOGIT_TOL)
 
 
 def test_each_layers_router_is_handed_the_layer_befores_latent_of_its_token(

@@ -167,7 +167,7 @@ def test_window_flip_and_device_host_split():
     assert fields["decode_steps_done"] == 8
     # steps sampled inside the head move at the landing with them
     assert fields["fused_sample_steps"] == 0
-    prof.on_dispatch("step", steps=8, fused_sample=True)
+    prof.on_dispatch("step", steps=8, counters=("fused_sample_steps",))
     assert prof.counters()["fused_sample_steps"] == 0
     prof.on_landed(1)
     assert prof.counters()["fused_sample_steps"] == 8
@@ -185,7 +185,8 @@ def test_kda_kernel_steps_move_at_the_landing_of_a_kernel_dispatch():
     clock = _FakeClock()
     prof = EngineLoopProfiler(clock=clock)
     assert prof.counters()["kda_kernel_steps"] == 0
-    prof.on_dispatch("step", steps=8, rows=128, kda_kernel=True)
+    prof.on_dispatch("step", steps=8, rows=128,
+                     counters=("kda_kernel_steps",))
     prof.on_dispatch("step", steps=8, rows=128)
     clock.advance(0.2)
     assert prof.counters()["kda_kernel_steps"] == 0

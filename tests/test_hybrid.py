@@ -25,6 +25,7 @@ import pytest
 
 from benchmark.lib import harness
 from polyrl_tpu.models import blocks, cache_spec, decoder, hybrid
+from polyrl_tpu.models.mixers import base, kda, mla
 from polyrl_tpu.rollout.cb_engine import CBEngine
 from polyrl_tpu.rollout.sampling import SamplingParams
 
@@ -142,24 +143,24 @@ def test_forward_has_a_gradient(cfg, params):
 def test_kda_chunked_form_is_the_recurrence():
     b, t, h, d = 2, 48, 4, 16
     ks = jax.random.split(jax.random.PRNGKey(2), 6)
-    q = hybrid._l2norm(jax.random.normal(ks[0], (b, t, h, d)))
-    k = hybrid._l2norm(jax.random.normal(ks[1], (b, t, h, d)))
+    q = base.l2norm(jax.random.normal(ks[0], (b, t, h, d)))
+    k = base.l2norm(jax.random.normal(ks[1], (b, t, h, d)))
     v = jax.random.normal(ks[2], (b, t, h, d))
     # decays from none to the bound of -5 a position
     g = -5 * jax.nn.sigmoid(jax.random.normal(ks[3], (b, t, h, d)) * 3)
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, t, h)))
     s0 = jax.random.normal(ks[5], (b, h, d, d)) * 0.1
-    s1, o1 = hybrid.kda_chunked(s0, q, k, v, g, beta, 16)
+    s1, o1 = kda.kda_chunked(s0, q, k, v, g, beta, 16)
     s, outs = s0, []
     for i in range(t):
-        s, o = hybrid.kda_recurrent_step(s, q[:, i], k[:, i], v[:, i],
+        s, o = kda.kda_recurrent_step(s, q[:, i], k[:, i], v[:, i],
                                          g[:, i], beta[:, i])
         outs.append(o)
     assert float(jnp.abs(o1 - jnp.stack(outs, 1)).max()) < 5e-6
     assert float(jnp.abs(s1 - s).max()) < 5e-6
     # the bound of -5 a position over a step of 16 stays inside float32
     worst = jnp.full_like(g, -5.0)
-    s2, o2 = hybrid.kda_chunked(s0, q, k, v, worst, beta, 16)
+    s2, o2 = kda.kda_chunked(s0, q, k, v, worst, beta, 16)
     assert bool(jnp.isfinite(o2).all() and jnp.isfinite(s2).all())
 
 
@@ -168,9 +169,9 @@ def test_absorbed_mla_is_the_expanded_form(cfg, params):
     t = 21
     h_in = jax.random.normal(jax.random.PRNGKey(3), (1, t, cfg.hidden_size))
     pos = jnp.arange(t)[None]
-    q_nope, q_rope, lat = hybrid._mla_qkv(cfg, lp, h_in, pos)
+    q_nope, q_rope, lat = mla._mla_qkv(cfg, lp, h_in, pos)
     assert lat.shape == (1, t, 128) and not bool(jnp.any(lat[..., 40:]))
-    want = hybrid.mla_expanded(cfg, lp, q_nope, q_rope, lat,
+    want = mla.mla_expanded(cfg, lp, q_nope, q_rope, lat,
                                jnp.ones((1, t), bool), pos)[0, -1]
     # the last token as a decode step over pages of 8
     from polyrl_tpu.ops.mla_attention import (latent_paged_attention_pallas,
@@ -180,13 +181,13 @@ def test_absorbed_mla_is_the_expanded_form(cfg, params):
         jnp.pad(lat[0], ((0, 3), (0, 0))).reshape(3, 8, 128))
     table = jnp.asarray([[1, 2, 3, 0], [0, 0, 0, 0]], jnp.int32)
     lens = jnp.asarray([t, 0], jnp.int32)
-    q_lat = hybrid.mla_absorb(cfg, lp, q_nope[0, -1:], q_rope[0, -1:])
+    q_lat = mla.mla_absorb(cfg, lp, q_nope[0, -1:], q_rope[0, -1:])
     q_lat = jnp.concatenate([q_lat, q_lat])
     scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
     for fn in (latent_paged_attention_ref,
                lambda *a: latent_paged_attention_pallas(*a, interpret=True)):
         o_lat = fn(q_lat, pool, table, lens, cfg.kv_lora_rank, scale)
-        got = hybrid.mla_unabsorb(cfg, lp, o_lat)
+        got = mla.mla_unabsorb(cfg, lp, o_lat)
         assert float(jnp.abs(got[0] - want).max()) < 2e-6
         assert not bool(jnp.any(got[1]))          # a row without a request
 

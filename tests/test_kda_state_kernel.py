@@ -1,5 +1,5 @@
 """The one-pass KDA state-update kernel (``ops/kda_state.py``) against
-``hybrid.kda_recurrent_step``, interpreted on the CPU: live and dead rows,
+``kda.kda_recurrent_step``, interpreted on the CPU: live and dead rows,
 a stack with more slots than the step has rows, the decay at its bound and
 at none, ``beta`` at both ends, two head counts, a row's heads in several
 blocks; and one decode step of a model whose head size the kernel accepts,
@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from polyrl_tpu.models import decoder, hybrid
+from polyrl_tpu.models.mixers import base, kda
 from polyrl_tpu.ops import kda_state
 
 # what tests/test_hybrid.py holds the chunked form to
@@ -22,8 +23,8 @@ D = 128
 
 def _operands(rows, heads, seed):
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
-    q = hybrid._l2norm(jax.random.normal(ks[0], (rows, heads, D))) * D ** -0.5
-    k = hybrid._l2norm(jax.random.normal(ks[1], (rows, heads, D)))
+    q = base.l2norm(jax.random.normal(ks[0], (rows, heads, D))) * D ** -0.5
+    k = base.l2norm(jax.random.normal(ks[1], (rows, heads, D)))
     v = jax.random.normal(ks[2], (rows, heads, D))
     # decays from none to the bound of -5 a position
     g = -5 * jax.nn.sigmoid(jax.random.normal(ks[3], (rows, heads, D)) * 3)
@@ -52,7 +53,7 @@ def test_the_kernel_is_the_recurrence(case, slots, rows, heads, hb):
          "g at none": jnp.zeros_like(g)}.get(case, g)
     beta = {"beta 0": jnp.zeros_like(beta),
             "beta 1": jnp.ones_like(beta)}.get(case, beta)
-    want_s, want_o = hybrid.kda_recurrent_step(state[:rows], q, k, v, g, beta)
+    want_s, want_o = kda.kda_recurrent_step(state[:rows], q, k, v, g, beta)
     new, o = kda_state.kda_state_pallas(
         state, q, k, v, jnp.where(live[:, None, None], g, 0.0),
         jnp.where(live[:, None], beta, 0.0), interpret=True, hb=hb)
@@ -94,7 +95,7 @@ def test_a_decode_step_through_the_kernel_is_the_oracles(monkeypatch):
     cfg = dataclasses.replace(
         decoder.get_config("hybrid-tiny", dtype=jnp.float32), head_dim=D)
     params = decoder.init_params(jax.random.PRNGKey(0), cfg)
-    assert not hybrid.kda_in_kernel(cfg)
+    assert not "kda_kernel_steps" in hybrid.step_counters(cfg, 3)
     pools = decoder.make_paged_pools(cfg, 8, 8, dtype=jnp.float32, slots=4)
     key = jax.random.PRNGKey(1)
     pools = (pools[0], tuple(
@@ -113,7 +114,7 @@ def test_a_decode_step_through_the_kernel_is_the_oracles(monkeypatch):
 
     want_logits, want_pools, want_load = step()
     monkeypatch.setattr(kda_state, "in_kernel", kda_state.accepts)
-    assert hybrid.kda_in_kernel(cfg)
+    assert "kda_kernel_steps" in hybrid.step_counters(cfg, 3)
     logits, got_pools, load = step()
     lv = np.asarray(active)
     assert float(jnp.abs(logits[lv] - want_logits[lv]).max()) < 1e-4

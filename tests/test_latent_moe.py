@@ -26,7 +26,9 @@ import numpy as np
 import pytest
 
 from benchmark.lib import harness
-from polyrl_tpu.models import cache_spec, decoder, hf_loader, hybrid
+from polyrl_tpu.models import cache_spec, decoder, hf_loader
+from polyrl_tpu.models.mixers import mla
+from polyrl_tpu.models.mixers.base import key_block
 from polyrl_tpu.rollout.cb_engine import CBEngine
 from polyrl_tpu.rollout.sampling import SamplingParams
 
@@ -154,7 +156,7 @@ def test_yarn_frequencies_and_scale_for_the_published_keys(ref):
     23-31 are divided by 40, and 16 is 6/13 of the way; m = 0.1 ln 40 + 1
     = 1.368888, the logits' scale 192^-0.5 * m^2 = 0.135234."""
     cfg = decoder.get_config("dots.vlm1")
-    inv = hybrid.rope_inv_freq(cfg)
+    inv = mla.rope_inv_freq(cfg)
     base = 10000.0 ** (-np.arange(32) / 32.0)
     assert inv.shape == (32,)
     np.testing.assert_allclose(inv[:11], base[:11], rtol=1e-12)
@@ -164,9 +166,9 @@ def test_yarn_frequencies_and_scale_for_the_published_keys(ref):
     assert np.all(np.diff(inv) < 0)
     m = 0.1 * math.log(40) + 1
     assert abs(m - 1.3688879) < 1e-6
-    assert abs(hybrid.mla_scale(cfg) - 192 ** -0.5 * m * m) < 1e-12
-    assert abs(hybrid.mla_scale(cfg) - 0.135234) < 1e-6
-    assert hybrid.rope_amplitude(cfg) == 1.0
+    assert abs(mla.mla_scale(cfg) - 192 ** -0.5 * m * m) < 1e-12
+    assert abs(mla.mla_scale(cfg) - 0.135234) < 1e-6
+    assert mla.rope_amplitude(cfg) == 1.0
     # the reference reads the published keys to the same numbers
     with open(os.path.join(harness.BENCH_DIR, "configs",
                            "dots.vlm1.json")) as f:
@@ -177,9 +179,9 @@ def test_yarn_frequencies_and_scale_for_the_published_keys(ref):
     # no scaling, no change (Ling's rope)
     ling = decoder.get_config("ling-3.0-flash")
     np.testing.assert_allclose(
-        hybrid.rope_inv_freq(ling), 6e6 ** (-np.arange(32) / 32.0),
+        mla.rope_inv_freq(ling), 6e6 ** (-np.arange(32) / 32.0),
         rtol=1e-12)
-    assert hybrid.mla_scale(ling) == 192 ** -0.5
+    assert mla.mla_scale(ling) == 192 ** -0.5
 
 
 def test_absorbed_mla_is_the_expanded_form(cfg, params):
@@ -187,9 +189,9 @@ def test_absorbed_mla_is_the_expanded_form(cfg, params):
     t = 21
     h_in = jax.random.normal(jax.random.PRNGKey(3), (1, t, cfg.hidden_size))
     pos = jnp.arange(t)[None]
-    q_nope, q_rope, lat = hybrid._mla_qkv(cfg, lp, h_in, pos)
+    q_nope, q_rope, lat = mla._mla_qkv(cfg, lp, h_in, pos)
     assert lat.shape == (1, t, 128) and not bool(jnp.any(lat[..., 40:]))
-    want = hybrid.mla_expanded(cfg, lp, q_nope, q_rope, lat,
+    want = mla.mla_expanded(cfg, lp, q_nope, q_rope, lat,
                                jnp.ones((1, t), bool), pos)[0, -1]
     from polyrl_tpu.ops.mla_attention import (latent_paged_attention_pallas,
                                               latent_paged_attention_ref)
@@ -198,13 +200,13 @@ def test_absorbed_mla_is_the_expanded_form(cfg, params):
         jnp.pad(lat[0], ((0, 3), (0, 0))).reshape(3, 8, 128))
     table = jnp.asarray([[1, 2, 3, 0], [0, 0, 0, 0]], jnp.int32)
     lens = jnp.asarray([t, 0], jnp.int32)
-    q_lat = hybrid.mla_absorb(cfg, lp, q_nope[0, -1:], q_rope[0, -1:])
+    q_lat = mla.mla_absorb(cfg, lp, q_nope[0, -1:], q_rope[0, -1:])
     q_lat = jnp.concatenate([q_lat, q_lat])
     for fn in (latent_paged_attention_ref,
                lambda *a: latent_paged_attention_pallas(*a, interpret=True)):
         o_lat = fn(q_lat, pool, table, lens, cfg.kv_lora_rank,
-                   hybrid.mla_scale(cfg))
-        got = hybrid.mla_unabsorb(cfg, lp, o_lat)
+                   mla.mla_scale(cfg))
+        got = mla.mla_unabsorb(cfg, lp, o_lat)
         assert float(jnp.abs(got[0] - want).max()) < 2e-6
         assert not bool(jnp.any(got[1]))          # a row without a request
 
@@ -221,7 +223,7 @@ def test_prefill_blocked_over_keys_is_the_unblocked_form(cfg, params, block):
                                                      cfg.hidden_size))
     pre_len = jnp.asarray([37, 20])
     pos = jnp.broadcast_to(jnp.arange(tp + t), (2, tp + t))
-    q_nope, q_rope, lat = hybrid._mla_qkv(cfg, lp, h_in, pos)
+    q_nope, q_rope, lat = mla._mla_qkv(cfg, lp, h_in, pos)
     key_ok = jnp.concatenate(
         [jnp.arange(tp)[None] < pre_len[:, None],
          jnp.arange(t)[None] < jnp.asarray([[16], [9]])], axis=1)
@@ -229,14 +231,14 @@ def test_prefill_blocked_over_keys_is_the_unblocked_form(cfg, params, block):
     # what a padded prefix row holds must not matter: poison it
     lat = jnp.where(key_ok[..., None], lat, 1e4)
     args = (cfg, lp, q_nope[:, tp:], q_rope[:, tp:], lat, key_ok, q_at)
-    whole = hybrid.mla_expanded(*args)
-    assert hybrid.key_block(cfg, 2, t) >= tp + t       # one block by default
-    got = hybrid.mla_expanded(*args, block=block)
+    whole = mla.mla_expanded(*args)
+    assert key_block(cfg, 2, t) >= tp + t       # one block by default
+    got = mla.mla_expanded(*args, block=block)
     assert got.shape == (2, t, cfg.num_heads, cfg.v_head_dim)
     assert float(jnp.abs(got - whole).max()) < 2e-6
     # at the published size: 512 keys a block at 128 heads, 2048 at 32
-    assert hybrid.key_block(decoder.get_config("dots.vlm1"), 1, 512) == 512
-    assert hybrid.key_block(decoder.get_config("ling-3.0-flash"), 1,
+    assert key_block(decoder.get_config("dots.vlm1"), 1, 512) == 512
+    assert key_block(decoder.get_config("ling-3.0-flash"), 1,
                             512) == 2048
 
 

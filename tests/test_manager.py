@@ -444,3 +444,30 @@ def test_sender_ip_acl_bad_cidr_fails_startup():
         spawn_rollout_manager(
             "127.0.0.1:0",
             extra_args=["--allowed-sender-ips", "not-an-ip/8"])
+
+
+def test_one_build_of_the_manager_at_a_time(monkeypatch):
+    """The binary is git-ignored, so in a fresh checkout the first tests
+    of every xdist worker all build it: ``build_manager`` runs ``make``
+    under an exclusive lock, or a worker starts the binary while another's
+    link still writes it (ETXTBSY: what failed ``test_chip_smoke``'s
+    rehearsal in the driver's run of PR 45's tree)."""
+    import fcntl
+    import os
+
+    from polyrl_tpu.manager import client
+
+    ran = []
+
+    def make(cmd, **kw):
+        other = os.open(client._CPP_DIR, os.O_RDONLY)
+        try:
+            with pytest.raises(BlockingIOError):
+                fcntl.flock(other, fcntl.LOCK_EX | fcntl.LOCK_NB)
+        finally:
+            os.close(other)
+        ran.append(cmd)
+
+    monkeypatch.setattr(client.subprocess, "run", make)
+    assert client.build_manager() == client._BINARY
+    assert ran == [["make", "-C", client._CPP_DIR]]

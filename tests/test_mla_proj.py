@@ -1,6 +1,6 @@
 """The in-place ``wkv_b`` products of a decode step's latent attention
-(``ops/mla_proj.py``) against ``hybrid.mla_absorb``'s and
-``hybrid.mla_unabsorb``'s einsum, interpreted on the CPU: 128 and 32
+(``ops/mla_proj.py``) against ``mla.mla_absorb``'s and
+``mla.mla_unabsorb``'s einsum, interpreted on the CPU: 128 and 32
 heads, a layer other than the first of a stack of three, float32 and
 bfloat16, several heads a grid step; the shapes the kernels refuse; one
 decode step of the tiny hybrid and of the tiny all-latent model at head
@@ -17,6 +17,7 @@ import pytest
 
 from benchmark.lib import harness
 from polyrl_tpu.models import decoder, hybrid
+from polyrl_tpu.models.mixers import mla
 from polyrl_tpu.ops import mla_proj
 from polyrl_tpu.rollout.cb_engine import CBEngine
 from polyrl_tpu.rollout.sampling import SamplingParams
@@ -45,8 +46,8 @@ def _oracle(cfg, stack, layer, q, o):
     ``mla_absorb``'s first ``rank`` columns are the product."""
     lp = {"wkv_b": stack[layer]}
     q_rope = jnp.zeros((*q.shape[:2], cfg.qk_rope_head_dim), q.dtype)
-    q_lat = hybrid.mla_absorb(cfg, lp, q, q_rope)
-    return q_lat[..., :cfg.kv_lora_rank], hybrid.mla_unabsorb(cfg, lp, o)
+    q_lat = mla.mla_absorb(cfg, lp, q, q_rope)
+    return q_lat[..., :cfg.kv_lora_rank], mla.mla_unabsorb(cfg, lp, o)
 
 
 @pytest.mark.parametrize("heads,rank,layer,dtype,hb", [
@@ -112,7 +113,7 @@ def test_the_block_of_heads_follows_the_static_shapes():
     assert not mla_proj.accepts(decoder.get_config("tiny"), 5)
     # off a TPU the dispatch takes the einsum whatever the shape
     assert not mla_proj.in_kernel(dots, 65)
-    assert not hybrid.mla_in_kernel(dots, 65)
+    assert not "mla_proj_kernel_steps" in hybrid.step_counters(dots, 65)
 
 
 def test_a_refused_shape_takes_the_einsum(monkeypatch):
@@ -123,7 +124,7 @@ def test_a_refused_shape_takes_the_einsum(monkeypatch):
     monkeypatch.setattr(mla_proj, "in_kernel", mla_proj.accepts)
     monkeypatch.setattr(mla_proj, "absorb", None)
     monkeypatch.setattr(mla_proj, "unabsorb", None)
-    assert not hybrid.mla_in_kernel(cfg, 2)
+    assert not "mla_proj_kernel_steps" in hybrid.step_counters(cfg, 2)
     params = decoder.init_params(jax.random.PRNGKey(0), cfg)
     pools = decoder.make_paged_pools(cfg, 4, 8, dtype=jnp.float32, slots=2)
     lens = jnp.asarray([3, 9], jnp.int32)
@@ -156,7 +157,7 @@ def test_a_decode_step_through_the_kernels_is_the_oracles(monkeypatch,
         return decoder.forward_paged_decode(
             params, cfg, tokens, lens, pools, table, lens, active=active)
 
-    assert not hybrid.mla_in_kernel(cfg, 3)
+    assert not "mla_proj_kernel_steps" in hybrid.step_counters(cfg, 3)
     want_logits, want_pools, want_load = step()
     calls = []
     real = mla_proj.absorb
@@ -165,7 +166,7 @@ def test_a_decode_step_through_the_kernels_is_the_oracles(monkeypatch,
         mla_proj, "absorb",
         lambda q, w, layer, **kw: calls.append((w.shape[0], layer))
         or real(q, w, layer=layer, **kw))
-    assert hybrid.mla_in_kernel(cfg, 3)
+    assert "mla_proj_kernel_steps" in hybrid.step_counters(cfg, 3)
     logits, got_pools, load = step()
     # every MLA layer took its own block of the stack
     n_mla = sum(p.mixer == "mla" for p in hybrid.cache_spec.layer_plan(cfg))
@@ -192,7 +193,7 @@ def test_mla_proj_kernel_steps_move_with_an_engine_that_took_the_kernel(
     eng = CBEngine(cfg, params, max_slots=2, page_size=8, max_seq_len=32,
                    prompt_buckets=(16,), num_pages=16, steps_per_dispatch=4,
                    kv_cache_dtype=jnp.float32)
-    assert eng._mla_proj_kernel is kernel
+    assert ("mla_proj_kernel_steps" in eng._step_counters[False]) is kernel
     eng.start()
     try:
         assert eng.loop_profile_info()["mla_proj_kernel_steps"] == 0
@@ -214,7 +215,8 @@ def test_the_profiler_counts_a_kernel_dispatch_at_its_landing():
 
     assert "mla_proj_kernel_steps" in CUMULATIVE_KEYS
     prof = EngineLoopProfiler()
-    prof.on_dispatch("step", steps=8, rows=64, mla_proj_kernel=True)
+    prof.on_dispatch("step", steps=8, rows=64,
+                     counters=("mla_proj_kernel_steps",))
     prof.on_dispatch("step", steps=8, rows=64)
     assert prof.counters()["mla_proj_kernel_steps"] == 0
     prof.on_landed(1)

@@ -15,6 +15,7 @@ value of order 1, through 12 layers: 5e-6 on logits of at most 0.7 in
 magnitude; readings are 2e-7 to 6e-7. A wrong position, mask, page, ring
 row, state row or carried ``m`` moves a logit by 1e-2 or more."""
 
+import dataclasses
 import logging
 
 import jax
@@ -23,7 +24,8 @@ import numpy as np
 import pytest
 
 from benchmark.lib import harness
-from polyrl_tpu.models import cache_spec, decoder, hybrid
+from polyrl_tpu.models import cache_spec, decoder, hybrid, mixers
+from polyrl_tpu.models.mixers import diff, ssm
 from polyrl_tpu.rollout.cb_engine import CBEngine
 from polyrl_tpu.rollout.sampling import SamplingParams
 
@@ -153,7 +155,7 @@ def test_the_ledger_counts_one_layers_bytes_a_page(cfg, params, caplog):
     eng._accounted_bytes()
     assert eng.kvledger.page_bytes == 2 * 2 * 16 * 4 * PAGE
     assert eng.stateful and eng.prefix_cache is None
-    assert not eng._kda_kernel
+    assert eng._step_counters[False] == ()
     said = [r.getMessage() for r in caplog.records]
     for feature in ("decode_group_share", "kv_spill"):
         assert any(m.startswith(f"{feature} is off") and "ssm" in m
@@ -241,7 +243,7 @@ def test_chunked_prefill_then_decode_agrees_with_the_full_forward(
         np.testing.assert_array_equal(np.asarray(a[0]), b)
     n = n_prompt + n_new
     tr = ref.trace(params, file_keys(cfg), ids.tolist(), n_prompt, n_new)
-    held = [hybrid.held_state(cfg, rows, 1) for rows in pools[1]]
+    held = hybrid.held_state(cfg, pools[1], 1)
     for mine, theirs in zip(held[0::2], tr["states"]):
         assert mine.shape == theirs.shape == (128, 4)
         np.testing.assert_allclose(mine, theirs, atol=LOGIT_TOL)
@@ -270,8 +272,9 @@ def test_the_ring_as_a_set_is_the_last_window_tokens(ref, cfg, params,
         jnp.arange(1, 1 + CHUNK // PAGE, dtype=jnp.int32)[None],
         jnp.array([2]))
     tr = ref.trace(params, file_keys(cfg), ids.tolist(), length - 1, 1)
-    for rows, (theirs, first) in zip(pools[1][1::2], tr["rings"]):
-        mine = hybrid.held_state(cfg, rows, 2)
+    held = [hybrid.held_state(cfg, pools[1], slot)[1::2] for slot in range(3)]
+    for l, (theirs, first) in enumerate(tr["rings"]):
+        mine = held[2][l]
         assert first == max(0, length - WINDOW)
         np.testing.assert_allclose(_ring_rows(cfg, mine, length), theirs,
                                    atol=LOGIT_TOL)
@@ -279,7 +282,7 @@ def test_the_ring_as_a_set_is_the_last_window_tokens(ref, cfg, params,
         assert (mine[untouched] == 3.0).all()
         # and no other slot's pages were written
         for other in (0, 1):
-            assert (hybrid.held_state(cfg, rows, other) == 3.0).all()
+            assert (held[other][l] == 3.0).all()
 
 
 def test_the_paired_layout_is_the_64_wide_form_bit_for_bit(cfg):
@@ -298,7 +301,7 @@ def test_the_paired_layout_is_the_64_wide_form_bit_for_bit(cfg):
     v = jax.random.normal(keys[2], (pairs, 4, PAGE, width))
     table = jnp.asarray([[1, 2, 3], [2, 3, 1], [3, 1, 2]], jnp.int32)
     lens = jnp.asarray([11, 5, 1], jnp.int32)
-    got = paged_attention_ref(hybrid.paired_queries(q), k, v, table, lens,
+    got = paged_attention_ref(diff.paired_queries(q), k, v, table, lens,
                               d ** -0.5).reshape(s, hd, 2, width)
     # the 2 x D form: K head (g, c) on its own; query (j, c) belongs to it
     g = hd // pairs
@@ -321,19 +324,22 @@ def test_a_gated_memory_unit_reads_the_same_tokens_scan_output(cfg, params,
     ``m`` of that call, row for row, in a whole-sequence forward and in a
     decode step alike."""
     made, read = [], []
-    scan, gmu = hybrid._ssm_sequence, hybrid._gmu
 
-    def spy_scan(*a, **kw):
-        out = scan(*a, **kw)
-        made.append(np.asarray(out[1]))
-        return out
+    def spy_scan(hand):
+        def run(cfg, p, lp, h_in, ctx):
+            out, kept = ssm.sequence(cfg, p, lp, h_in, ctx, hand=True)
+            made.append(np.asarray(kept.hands["m"]))
+            return out, kept if hand else kept._replace(hands={})
+        return run
 
-    def spy_gmu(lp, h_in, m):
-        read.append(np.asarray(m))
-        return gmu(lp, h_in, m)
+    def spy_gmu(cfg, p, lp, h_in, ctx):
+        read.append(np.asarray(ctx.hands["m"]))
+        return ssm.gmu(cfg, p, lp, h_in, ctx)
 
-    monkeypatch.setattr(hybrid, "_ssm_sequence", spy_scan)
-    monkeypatch.setattr(hybrid, "_gmu", spy_gmu)
+    for name, form in (("ssm", spy_scan(False)), ("ssm_mem", spy_scan(True)),
+                       ("gmu", spy_gmu)):
+        monkeypatch.setitem(mixers.MIXERS, name, dataclasses.replace(
+            mixers.MIXERS[name], sequence=form))
     ids = jnp.asarray(_prompts([13])[0])[None]
     hybrid.forward(params, cfg, ids, jnp.arange(13)[None], jnp.ones((1, 13)))
     assert len(made) == 4 and len(read) == 2

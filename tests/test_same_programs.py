@@ -1,5 +1,6 @@
-"""``tools/same_programs.py`` at tiny presets of two families: an edit to
-one family's program shows, and the other family's programs are the
+"""``tools/same_programs.py`` at tiny presets of three families: an edit
+to one family's mixer shows in that family's program, and the other
+families' programs, and every family's draw of the weights, are the
 same."""
 
 import os
@@ -13,7 +14,8 @@ TOOL = os.path.join(ROOT, "tools", "same_programs.py")
 
 def _run(other):
     return subprocess.run(
-        [sys.executable, TOOL, other, "hybrid-tiny", "cca-tiny"],
+        [sys.executable, TOOL, other, "hybrid-tiny", "cca-tiny",
+         "sambay-tiny"],
         capture_output=True, text=True, cwd=ROOT)
 
 
@@ -21,7 +23,7 @@ def test_an_edited_program_shows(tmp_path):
     shutil.copytree(os.path.join(ROOT, "polyrl_tpu"),
                     tmp_path / "polyrl_tpu",
                     ignore=shutil.ignore_patterns("__pycache__"))
-    path = tmp_path / "polyrl_tpu" / "models" / "hybrid.py"
+    path = tmp_path / "polyrl_tpu" / "models" / "mixers" / "kda.py"
     text = path.read_text()
     # the KDA recurrence with a delta rule twice as strong
     old = "    u = beta[..., None] * (v - pred)\n"
@@ -35,5 +37,7 @@ def test_an_edited_program_shows(tmp_path):
     assert "DIFFERENT" in verdicts["hybrid-tiny step"]
     # (prefill runs the chunked form, which the edit leaves alone)
     assert "same" in verdicts["hybrid-tiny prefill"]
-    assert "same" in verdicts["cca-tiny step"]
-    assert "same" in verdicts["cca-tiny prefill"]
+    assert "same" in verdicts["hybrid-tiny init"]
+    for preset in ("cca-tiny", "sambay-tiny"):
+        for program in ("step", "prefill", "init"):
+            assert "same" in verdicts[f"{preset} {program}"]

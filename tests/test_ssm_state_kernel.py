@@ -1,5 +1,5 @@
 """The one-pass Mamba state-update kernel (``ops/ssm_state.py``) against
-``hybrid.ssm_step``, interpreted on the CPU: live and dead rows, a stack
+``ssm.ssm_step``, interpreted on the CPU: live and dead rows, a stack
 with more slots than the step has rows, rows that do not fill the last
 block, ``dt`` at none, an inner width of several lane chunks and of one
 that 1,024 does not divide; and one decode step of a model whose state the
@@ -12,7 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from polyrl_tpu.models import decoder, hybrid
+from polyrl_tpu.models import decoder
+from polyrl_tpu.models.mixers import ssm
 from polyrl_tpu.ops import ssm_state
 
 # what tests/test_sambay.py holds the scan to
@@ -49,7 +50,7 @@ def test_the_kernel_is_the_recurrence(case, slots, rows, n, inner):
         live = jnp.arange(rows) % 3 != 1
     if case == "dt at none":
         dt = jnp.zeros_like(dt)
-    want_s, want_m = hybrid.ssm_step(lp, state[:rows], c, dt, bm, cm)
+    want_s, want_m = ssm.ssm_step(lp, state[:rows], c, dt, bm, cm)
     held = jnp.where(live[:, None], dt, 0.0)
     new, m = ssm_state.ssm_state_pallas(
         state, -jnp.exp(lp["a_log"]), held, held * c, bm, cm, interpret=True)

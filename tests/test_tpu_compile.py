@@ -484,7 +484,8 @@ def _mla_projections(one_chip, preset, rows):
     call: what XLA does beside it is what counts), unabsorb and
     ``_mla_out`` as ``hybrid.paged_decode`` runs them. Returns (the
     optimised HLO, a whole layer's element count by weight)."""
-    from polyrl_tpu.models import cache_spec, decoder, hybrid
+    from polyrl_tpu.models import cache_spec, decoder
+    from polyrl_tpu.models.mixers import mla as mixer
     from polyrl_tpu.ops.mla_attention import latent_paged_attention
 
     cfg = decoder.get_config(preset)
@@ -501,17 +502,17 @@ def _mla_projections(one_chip, preset, rows):
     def step(mla, x, positions, pool, table, lens):
         for l in range(MLA_LAYERS):
             lp = {k: a[l] for k, a in mla.items()}
-            wkv_b = ((mla["wkv_b"], l) if hybrid.mla_in_kernel(cfg, rows)
+            wkv_b = ((mla["wkv_b"], l) if mixer.in_kernel(cfg, rows)
                      else None)
-            q_nope, q_rope, _lat = hybrid._mla_qkv(cfg, lp, x[:, None],
-                                                   positions[:, None])
-            q_lat = hybrid.mla_absorb(cfg, lp, q_nope[:, 0], q_rope[:, 0],
-                                      wkv_b)
+            q_nope, q_rope, _lat = mixer._mla_qkv(cfg, lp, x[:, None],
+                                                  positions[:, None])
+            q_lat = mixer.mla_absorb(cfg, lp, q_nope[:, 0], q_rope[:, 0],
+                                     wkv_b)
             o_lat = latent_paged_attention(q_lat, pool, table, lens,
                                            cfg.kv_lora_rank,
-                                           hybrid.mla_scale(cfg))
-            x = x + hybrid._mla_out(
-                cfg, lp, x, hybrid.mla_unabsorb(cfg, lp, o_lat, wkv_b))
+                                           mixer.mla_scale(cfg))
+            x = x + mixer._mla_out(
+                cfg, lp, x, mixer.mla_unabsorb(cfg, lp, o_lat, wkv_b))
         return x
 
     compiled = jax.jit(step).lower(

@@ -248,3 +248,75 @@ def test_each_pallas_call_we_own_has_a_name():
     assert names(
         lambda *a: mla_proj.unabsorb(*a, layer=1, interpret=True),
         rows, stack) == ["mla_unabsorb"]
+
+
+# -- the families of mixers (models/mixers) -----------------------------------
+
+# preset -> (the benchmark cell of its family, the load a decode step counts
+# by its ``server_info`` names, as the accepted cells' programs lay it out)
+_MOE = ("moe_routed", "moe_experts_hit", "moe_load_max")
+_HYBRID = _MOE + ("moe_choices", "kda_state_rows", "mla_rows_read")
+FAMILIES = {
+    "moe-tiny": ("qwen3-30b-a3b.rollout-wide", _MOE),
+    "hybrid-tiny": ("ling-3.0-flash.rollout-long-wide", _HYBRID),
+    "mla-moe-tiny": ("dots.vlm1.rollout-long-latent", _HYBRID),
+    "cca-tiny": ("zaya1-8b.rollout-wide-cca", _HYBRID + ("cca_tail_rows",)),
+    "sambay-tiny": ("phi-4-mini-flash-reasoning.rollout-long-shared-kv",
+                    ("ssm_state_rows", "shared_kv_rows_read",
+                     "window_rows_read")),
+}
+
+
+def _scopes_read(cell: str) -> set:
+    """The scopes that ``BENCHMARK.json``'s per-layer metrics of ``cell``
+    read device time by (``xspans.scope_seconds`` in their files)."""
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        metrics = json.load(f)["per_layer"]
+    found = set()
+    for m in metrics:
+        if cell in m["workloads"] and m["source"] == "device_trace":
+            with open(os.path.join(root, "benchmark", "layer_metrics",
+                                   m["name"] + ".py")) as f:
+                found.update(re.findall(
+                    r'scope_seconds\([^"]*"(\w+)",\s*"jit_step"\)', f.read()))
+    return found
+
+
+@pytest.mark.parametrize("preset", list(FAMILIES))
+def test_a_familys_programs_carry_the_scopes_and_the_load_its_cell_reads(
+        preset):
+    """What an edit of a mixer must keep: the decode step and the prefill
+    chunk carry every scope that the per-layer metrics of the family's
+    benchmark cell read (a lost scope shows as a metric of None on the
+    chip and nowhere else), and the step's load vector has the entries and
+    the order that ``server_info`` names it by."""
+    from polyrl_tpu.models import hybrid
+    from polyrl_tpu.obs.engine_profile import CUMULATIVE_KEYS
+
+    cell, load = FAMILIES[preset]
+    cfg = decoder.get_config(preset, dtype=jnp.float32)
+    scopes = _scopes_read(cell)
+    assert {"head", "sample"} < scopes and len(scopes) >= 4
+    params = decoder.init_params(jax.random.PRNGKey(0), cfg)
+    eng = CBEngine(cfg, params, max_slots=4, page_size=8, max_seq_len=64,
+                   prompt_buckets=(16,), num_pages=32, steps_per_dispatch=2,
+                   kv_cache_dtype=jnp.float32)
+    assert hybrid.load_names(cfg) == load
+    assert hybrid.load_width(cfg) == len(load)
+    # where ``server_info`` has them: a routed model's in ``moe_info``,
+    # the rest among the profiler's cumulative counters
+    if cfg.num_experts:
+        assert tuple(eng.moe_info()) == load
+    else:
+        assert eng.moe_info() == {} and set(load) <= set(CUMULATIVE_KEYS)
+    step = _lower_step(eng).as_text(debug_info=True)
+    prefill = _lower_prefill(eng).as_text(debug_info=True)
+    assert "module @jit_step " in step
+    for text in (step, prefill):
+        for scope in scopes:
+            assert re.search(rf'loc\("(?:[^"]*[/(])?{scope}\)*/', text), \
+                (preset, scope, text is step)

@@ -2,7 +2,7 @@
 shapes: ``kda_state_pallas`` over as many state stacks as the cell's share
 has KDA layers, updated in place by one donated program as a decode step
 does, timed by the device's own clock (a ``jax.profiler`` trace of the
-calls) beside the oracle (``hybrid.kda_recurrent_step`` with the
+calls) beside the oracle (``mixers.kda.kda_recurrent_step`` with the
 ``where`` and the write-back the decode step wrapped it in), and checked
 against the oracle on four rows.
 
@@ -40,13 +40,13 @@ def inputs(rows: int, heads: int, size: int, layers: int, seed: int):
     """A decode step's operands a layer (q and k normed, g inside the
     bound of -5, the last row dead as the engine's spare slot is) and the
     layers' state stacks."""
-    from polyrl_tpu.models import hybrid
+    from polyrl_tpu.models.mixers import base
 
     def layer(key):
         ks = jax.random.split(key, 6)
         live = jnp.arange(rows) < rows - 1
-        q = hybrid._l2norm(jax.random.normal(ks[0], (rows, heads, size)))
-        k = hybrid._l2norm(jax.random.normal(ks[1], (rows, heads, size)))
+        q = base.l2norm(jax.random.normal(ks[0], (rows, heads, size)))
+        k = base.l2norm(jax.random.normal(ks[1], (rows, heads, size)))
         v = jax.random.normal(ks[2], (rows, heads, size))
         g = -5 * jax.nn.sigmoid(
             jax.random.normal(ks[3], (rows, heads, size)) * 3)
@@ -98,19 +98,19 @@ def main() -> int:
         print("no TPU: this measures nothing elsewhere", file=sys.stderr)
         return 1
 
-    from polyrl_tpu.models import hybrid
+    from polyrl_tpu.models.mixers import kda
     from polyrl_tpu.ops import kda_state
 
     states, operands = inputs(args.rows, args.heads, args.size, args.layers,
                               args.seed)
     check = jnp.asarray([0, args.rows // 2, args.rows - 2, args.rows - 1])
-    want = [hybrid.kda_recurrent_step(s[check], *(a[check] for a in ops))
+    want = [kda.kda_recurrent_step(s[check], *(a[check] for a in ops))
             for s, ops in zip(states, operands)]
 
     def oracle(state, q, k, v, g, beta):
         """The parent's decode step: the recurrence, the rows kept where
         no request lives, the write-back."""
-        new, o = hybrid.kda_recurrent_step(state, q, k, v, g, beta)
+        new, o = kda.kda_recurrent_step(state, q, k, v, g, beta)
         return jnp.where((beta > 0).any(-1)[:, None, None, None], new,
                          state), o
 

@@ -2,8 +2,8 @@
 the chip, at a benchmark cell's shapes: ``ops/mla_proj.py``'s ``absorb`` and
 ``unabsorb`` over every layer of one stacked ``wkv_b``, in one program as a
 decode step runs them, timed by the device's own clock (a ``jax.profiler``
-trace of the calls) beside the oracle (``hybrid.mla_absorb``'s and
-``hybrid.mla_unabsorb``'s einsum on the layer's slice), and checked against
+trace of the calls) beside the oracle (``mixers.mla.mla_absorb``'s and
+``mla_unabsorb``'s einsum on the layer's slice), and checked against
 the oracle.
 
     chiprun -- python tools/bench_mla_proj.py \
@@ -51,7 +51,7 @@ def inputs(rows: int, heads: int, rank: int, size: int, layers: int,
 
 
 def oracle(q_nope, o_latent, stack, layer: int):
-    """The einsums of ``hybrid.mla_absorb`` and ``hybrid.mla_unabsorb`` on
+    """The einsums of ``mixers.mla.mla_absorb`` and ``mla_unabsorb`` on
     ``stack[layer]``."""
     rank, heads, size = stack.shape[1], q_nope.shape[1], q_nope.shape[2]
     w = stack[layer].reshape(rank, heads, -1)

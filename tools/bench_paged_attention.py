@@ -55,7 +55,7 @@ TOLERANCE = 0.01
 def cell_shapes(cell: str) -> dict:
     """Query heads, K/V heads, head size, page size, table width and the
     prompt lengths of a cell of ``BENCHMARK.json``. Heads narrower than a
-    lane tile lie side by side in the pool, as ``hybrid.paired_queries``
+    lane tile lie side by side in the pool, as ``mixers.diff.paired_queries``
     has them (Phi: 20 K/V heads of 64 are 10 of 128, under 40 query rows)."""
     from benchmark.lib import traffic
 

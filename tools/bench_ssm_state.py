@@ -2,7 +2,7 @@
 shapes: ``ssm_state_pallas`` over as many layers' states as the model has
 scans, updated in place by one donated program as a decode step does,
 timed by the device's own clock (a ``jax.profiler`` trace of the calls)
-beside the oracle (``hybrid.ssm_step`` with the ``where`` and the
+beside the oracle (``mixers.ssm.ssm_step`` with the ``where`` and the
 write-back the decode step wrapped it in), and checked against the oracle
 on four rows.
 
@@ -67,20 +67,20 @@ def main() -> int:
         print("no TPU: this measures nothing elsewhere", file=sys.stderr)
         return 1
 
-    from polyrl_tpu.models import hybrid
+    from polyrl_tpu.models.mixers import ssm
     from polyrl_tpu.ops import ssm_state
 
     states, operands = inputs(args.rows, args.state, args.inner, args.layers,
                               args.seed)
     live = jnp.arange(args.rows) < args.rows - 1
     check = jnp.asarray([0, args.rows // 2, args.rows - 2, args.rows - 1])
-    want = [hybrid.ssm_step(ops[0], s[check], *(a[check] for a in ops[1:]))
+    want = [ssm.ssm_step(ops[0], s[check], *(a[check] for a in ops[1:]))
             for s, ops in zip(states, operands)]
 
     def oracle(lp, state, c, dt, bm, cm, live):
         """The decode step without the kernel: the recurrence, the rows
         kept where no request lives, the write-back."""
-        new, m = hybrid.ssm_step(lp, state, c, dt, bm, cm)
+        new, m = ssm.ssm_step(lp, state, c, dt, bm, cm)
         return jnp.where(live[:, None, None], new, state), m
 
     def program(update):

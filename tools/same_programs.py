@@ -1,12 +1,18 @@
 """Whether two checkouts build the SAME programs for a preset: the SHA-256
 of the StableHLO that the engine's fused decode step (8 steps, the token
-drawn inside the head) and its 512-token prefill chunk over 64 pages of
-prefix lower to for a TPU, kernels included, from abstract arguments at
-the preset's widths. No chip and no weights: a PR that edits code an
+drawn inside the head), its 512-token prefill chunk over 64 pages of
+prefix and the program that draws the weights (``init``: the draw order
+decides every leaf's key, and a routed cell's rate follows its weights)
+lower to for a TPU, kernels included, from abstract arguments at the
+preset's widths. No chip and no weights: a PR that edits code an
 accepted benchmark cell shares can show here that the cell's programs are
 the parent's to the byte.
 
-    python tools/same_programs.py <other checkout> [preset ...]
+    python tools/same_programs.py [--keep DIR] <other checkout> [preset ...]
+
+``--keep DIR`` leaves the texts in ``DIR/other`` and ``DIR/here`` (``diff``
+them where a digest differs: a difference is a change of behaviour until
+the texts show otherwise).
 
 Each checkout is copied to the same scratch path in turn (a kernel's
 serialized body carries its source's path) and each preset lowered in a
@@ -24,6 +30,7 @@ from __future__ import annotations
 import functools
 import hashlib
 import json
+import math
 import os
 import shutil
 import subprocess
@@ -32,11 +39,13 @@ import tempfile
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PRESETS = ("qwen2.5-7b", "qwen3-30b-a3b", "ling-3.0-flash-share4",
-           "dots.vlm1-share16", "zaya1-8b-depth12")
+           "dots.vlm1-share16", "zaya1-8b-depth12",
+           "phi-4-mini-flash-reasoning")
 
 
-def digests(presets) -> dict:
-    """{"<preset> <program>": sha256} from the checkout on ``sys.path``."""
+def digests(presets, keep: str = "") -> dict:
+    """{"<preset> <program>": sha256} from the checkout on ``sys.path``;
+    the texts too, in the directory ``keep``."""
     import jax
     import jax.numpy as jnp
 
@@ -45,11 +54,14 @@ def digests(presets) -> dict:
     jax.default_backend = lambda: "tpu"     # the dispatchers' question
     from polyrl_tpu.models import decoder
 
-    rows, pages, page, width = 128, 2048, 64, 192
+    rows, pages, width = 128, 2048, 192
     arg = jax.ShapeDtypeStruct
     out = {}
     for preset in presets:
         cfg = decoder.get_config(preset)
+        # pages of 64 tokens, or what a tiny preset's window is whole
+        # pages of (a ring is, ``cache_spec.Ring``)
+        page = math.gcd(64, cfg.sliding_window or 64)
         params = jax.eval_shape(
             lambda: decoder.init_params(jax.random.PRNGKey(0), cfg))
         pools = jax.eval_shape(lambda: decoder.make_paged_pools(
@@ -81,19 +93,31 @@ def digests(presets) -> dict:
             "prefill": (chunk, (arg((512,), i32), arg((), i32), arg((), i32),
                                 arg((64,), i32), arg((512 // page,), i32),
                                 arg((), i32)))}
-        for name, (fn, rest) in programs.items():
-            text = jax.jit(fn, donate_argnums=(1, 2)).trace(
-                params, *pools, *rest).lower(
+        texts = {name: jax.jit(fn, donate_argnums=(1, 2)).trace(
+            params, *pools, *rest).lower(
+                lowering_platforms=("tpu",)).as_text()
+                 for name, (fn, rest) in programs.items()}
+        texts["init"] = jax.jit(
+            lambda key: decoder.init_params(key, cfg)).trace(
+                arg((2,), jnp.uint32)).lower(
                     lowering_platforms=("tpu",)).as_text()
+        for name, text in texts.items():
             out[f"{preset} {name}"] = hashlib.sha256(text.encode()).hexdigest()
+            if keep:
+                with open(os.path.join(keep, f"{preset}.{name}.mlir"),
+                          "w") as f:
+                    f.write(text)
     return out
 
 
 def main(argv) -> int:
     if argv and argv[0] == "--digests":
         sys.path.insert(0, os.getcwd())
-        print(json.dumps(digests(argv[1:])))
+        print(json.dumps(digests(argv[2:], argv[1])))
         return 0
+    keep = ""
+    if argv and argv[0] == "--keep":
+        keep, argv = os.path.abspath(argv[1]), argv[2:]
     if not argv:
         print(__doc__, file=sys.stderr)
         return 2
@@ -101,7 +125,10 @@ def main(argv) -> int:
     got = []
     with tempfile.TemporaryDirectory() as scratch:
         at = os.path.join(scratch, "tree")
-        for checkout in (other, ROOT):
+        for checkout, side in ((other, "other"), (ROOT, "here")):
+            texts = os.path.join(keep, side) if keep else ""
+            if texts:
+                os.makedirs(texts, exist_ok=True)
             shutil.rmtree(at, ignore_errors=True)
             shutil.copytree(os.path.join(checkout, "polyrl_tpu"),
                             os.path.join(at, "polyrl_tpu"),
@@ -110,7 +137,7 @@ def main(argv) -> int:
             for preset in presets:
                 ran = subprocess.run(
                     [sys.executable, os.path.abspath(__file__), "--digests",
-                     preset], cwd=at, check=True, capture_output=True,
+                     texts, preset], cwd=at, check=True, capture_output=True,
                     text=True, env={**os.environ, "JAX_PLATFORMS": "cpu"})
                 found.update(json.loads(ran.stdout.strip().splitlines()[-1]))
             got.append(found)
