@@ -217,7 +217,9 @@ def _moe_mlp(cfg, x: jnp.ndarray, lp: dict,
 
     Routing follows HF Qwen3MoeSparseMoeBlock: router logits in the
     model's dtype, softmax in float32 over ALL experts, top-k, optional
-    renormalisation of the k probabilities. The N*k (token, expert)
+    renormalisation of the k probabilities, times
+    ``routed_scaling_factor`` where the configuration has one (Laguna's
+    ``moe_routed_scaling_factor``). The N*k (token, expert)
     choices are sorted by expert, the three expert projections run as
     grouped matmuls over the sorted rows (``_expert_mix``: whatever the
     imbalance, no choice is dropped and no expert multiplies a row that
@@ -250,6 +252,8 @@ def _moe_mlp(cfg, x: jnp.ndarray, lp: dict,
             top_p, top_i = jax.lax.top_k(probs, k)                # [N, k]
             if cfg.norm_topk_prob:
                 top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            if cfg.routed_scaling_factor != 1.0:
+                top_p = top_p * cfg.routed_scaling_factor
         if e != cfg.num_experts:
             # this chip's share: a choice that falls on an expert held
             # elsewhere is left out, as expert ``e`` (none) with weight 0

@@ -11,7 +11,12 @@ shifted by one token); and the six kinds of the SambaY family
 (``mb_per_layer`` > 0, below). An MLP is ``dense``
 (SwiGLU) or ``moe`` (routed experts, ``decoder._moe_mlp``). Every preset
 from before the hybrid family is the uniform pattern: ``gqa`` in every
-layer, with the same MLP in every layer. The DeepSeek-V3 family
+layer, with the same MLP in every layer. A published ``layer_types`` list
+(``full_attention`` / ``sliding_attention``: Laguna) makes ``gqa`` where
+the list says full and ``gqa_window`` where it says sliding: the same
+rope'd softmax attention over the last ``sliding_window`` keys, kept in a
+RING as ``swa``'s are, with a head count and a rope of its kind's own
+(``gqa_heads``, ``gqa_rope``). The DeepSeek-V3 family
 (``kv_lora_rank`` without a ``layer_group_size``) is ``mla`` in every
 layer behind leading dense layers. ``cca_time0`` > 0 is ``cca`` in every
 layer (ZAYA1's decoder).
@@ -84,7 +89,7 @@ import jax.numpy as jnp
 @dataclasses.dataclass(frozen=True)
 class LayerPlan:
     # "gqa" | "kda" | "mla" | "cca" | "ssm" | "swa" | "ssm_mem" | "diff" |
-    # "gmu" | "cross"
+    # "gmu" | "cross" | "gqa_window"
     mixer: str
     mlp: str          # "dense" | "moe"
     published: int    # the layer's index in the published model
@@ -166,7 +171,8 @@ def layer_plan(cfg) -> tuple[LayerPlan, ...]:
     layer_group_size == 0`` and ``kda`` otherwise. ``kv_lora_rank`` > 0
     without it is latent attention in every layer; neither is ``gqa`` in
     every layer; ``mb_per_layer`` > 0 is the SambaY family's six kinds
-    (module docstring). ``first_k_dense_replace`` leading published layers keep
+    (module docstring); ``layer_types`` names ``gqa`` and ``gqa_window``
+    layer by published layer (``LAYER_TYPES``). ``first_k_dense_replace`` leading published layers keep
     the dense MLP. ``kept_layers`` names the published layers that run
     here (a depth cut), all of them by default."""
     kept = cfg.kept_layers or tuple(range(cfg.num_layers))
@@ -191,6 +197,8 @@ def layer_plan(cfg) -> tuple[LayerPlan, ...]:
             mixer = "mla" if (i + 1) % cfg.layer_group_size == 0 else "kda"
         elif cfg.cca_time0:
             mixer = "cca"
+        elif cfg.layer_types:
+            mixer = LAYER_TYPES[cfg.layer_types[i]]
         else:
             mixer = "mla" if cfg.kv_lora_rank else "gqa"
         sparse = bool(cfg.num_experts) and i >= cfg.first_k_dense_replace
@@ -198,10 +206,15 @@ def layer_plan(cfg) -> tuple[LayerPlan, ...]:
     return tuple(plan)
 
 
+# a published ``layer_types`` entry -> the mixer that runs it
+LAYER_TYPES = {"full_attention": "gqa", "sliding_attention": "gqa_window"}
+
+
 def is_uniform(cfg) -> bool:
     """Every layer alike and ``gqa``: the stacked-scan decoder."""
     return (not cfg.layer_group_size and not cfg.kv_lora_rank
             and not cfg.cca_time0 and not cfg.mb_per_layer
+            and not cfg.layer_types
             and not (cfg.num_experts and cfg.first_k_dense_replace))
 
 
@@ -256,6 +269,21 @@ def diff_dims(cfg) -> tuple[int, int, int]:
     return cfg.num_heads // 2, cfg.num_kv_heads // 2, 2 * cfg.head_dim_
 
 
+def gqa_heads(cfg, p: LayerPlan) -> int:
+    """Query heads of a ``gqa`` or ``gqa_window`` layer: the published
+    ``num_attention_heads_per_layer`` entry, the model's count without."""
+    per_layer = cfg.num_heads_per_layer
+    return per_layer[p.published] if per_layer else cfg.num_heads
+
+
+def gqa_rope(cfg, p: LayerPlan):
+    """The rope of a ``gqa`` or ``gqa_window`` layer
+    (``decoder.RopeParameters``): the published ``rope_parameters`` block
+    of its layer type, the model's own rope without."""
+    return cfg.rope_of(cfg.layer_types[p.published] if cfg.layer_types
+                       else None)
+
+
 def layer_cache(cfg, plan: LayerPlan, dtype=None
                 ) -> Paged | Slot | PagedAndSlot | Ring | Reads | None:
     """What a sequence keeps for a layer: its mixer's record says
@@ -281,7 +309,9 @@ def is_stateful(cfg) -> bool:
 # a sequence where its tails are not; the SambaY family's kinds have none
 # either: its one K/V pair holds two heads side by side under queries that
 # are half zero, and a scan's state and a ring have no page to share,
-# verify over or spill)
+# verify over or spill). The kernels are the stacked-scan decoder's: a
+# model of several kinds of layer has none of the three, ``gqa`` layers or
+# not)
 FEATURE_KERNELS = {
     "decode_group_share": ("gqa",),   # ops.paged_attention's grouped kernel
     "spec_tokens": ("gqa",),          # the multi-token verify forward
@@ -292,7 +322,7 @@ FEATURE_KERNELS = {
 def without_kernel(cfg, feature: str) -> tuple[str, ...]:
     """The mixers of this model's layers for which the engine's
     ``feature`` has no kernel: empty where it may run."""
-    have = FEATURE_KERNELS[feature]
+    have = FEATURE_KERNELS[feature] if is_uniform(cfg) else ()
     return tuple(sorted({p.mixer for p in layer_plan(cfg)} - set(have)))
 
 

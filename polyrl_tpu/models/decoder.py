@@ -48,7 +48,7 @@ class RopeScaling:
     """Frequency scaling of the rope (frozen → ModelConfig stays hashable
     for use as a jit static argument). ``rope_type`` ``llama3``: NTK by
     parts between ``low_freq_factor`` and ``high_freq_factor``. ``yarn``
-    (DeepSeek-V3's reading, ``models/hybrid.py::rope_inv_freq``): each
+    (DeepSeek-V3's reading, ``mixers.base.yarn_inv_freq``): each
     frequency is divided by ``factor`` below the dimension at which the
     original length makes ``beta_slow`` turns, kept above the one at which
     it makes ``beta_fast``, blended linearly between; cos and sin are
@@ -64,6 +64,20 @@ class RopeScaling:
     beta_slow: float = 1.0
     mscale: float = 1.0
     mscale_all_dim: float = 0.0
+    # YaRN's factor on cos and sin as published (``attention_factor``);
+    # 0: what ``mscale`` and ``mscale_all_dim`` give
+    attention_factor: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeParameters:
+    """The rope of one kind of layer (a published ``rope_parameters``
+    block): its base, the share of a head's columns it turns (the first
+    ones), its frequency scaling."""
+
+    rope_theta: float
+    partial_rotary_factor: float = 1.0
+    scaling: RopeScaling | None = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -150,11 +164,31 @@ class ModelConfig:
     ssm_conv_kernel: int = 4
     ssm_expand: int = 2
     ssm_dt_rank: int = 0
+    # rope'd softmax attention of two kinds in one model (``laguna``;
+    # ``models/mixers/gqa.py``): ``layer_types`` names each published layer
+    # ``full_attention`` (keys and values in pages) or ``sliding_attention``
+    # (the last ``sliding_window`` of them in a ring of the slot's),
+    # ``num_heads_per_layer`` its query heads (``num_heads`` without),
+    # ``rope_parameters`` pairs (layer type, ``RopeParameters``): a rope a
+    # KIND; ``attn_head_gate``: sigmoid(x Wg)_h on each head's output
+    # (the published ``gating``)
+    layer_types: tuple | None = None
+    num_heads_per_layer: tuple | None = None
+    rope_parameters: tuple | None = None
+    attn_head_gate: bool = False
     dtype: Any = jnp.bfloat16
 
     @property
     def head_dim_(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
+
+    def rope_of(self, layer_type: str | None) -> RopeParameters:
+        """The rope of the layers of a published layer type: its
+        ``rope_parameters`` block, the model's own rope without."""
+        if self.rope_parameters:
+            return dict(self.rope_parameters)[layer_type]
+        return RopeParameters(self.rope_theta, self.partial_rotary_factor,
+                              self.rope_scaling)
 
 
 # -- presets ----------------------------------------------------------------
@@ -419,6 +453,66 @@ PRESETS["sambay-tiny"] = ModelConfig(
     num_heads=8, num_kv_heads=4, rms_norm_eps=1e-5,
     tie_word_embeddings=True, max_position_embeddings=512,
     mb_per_layer=2, sliding_window=8, ssm_state_size=4,
+)
+
+
+# Laguna-XS.2 (HF config: poolside/Laguna-XS.2, model_type laguna;
+# ``hf_loader.laguna_config`` of the published keys gives this, tested):
+# 40 layers of rope'd softmax GQA over 8 K/V heads of 128, every fourth
+# (0, 4, ..., 36) full attention at 48 query heads under YaRN on half of a
+# head's columns, the rest attention over the last 512 keys at 64 query
+# heads under a plain rope on all of them; a sigmoid gate a head; a dense
+# MLP in layer 0, then 256 experts of width 512 behind a softmax router at
+# top-8 whose renormalised weights are scaled by 2.5, beside one shared
+# expert; an untied head over 100,352 rows. ``rope_theta`` and
+# ``partial_rotary_factor`` are the full layers' (the published top-level
+# keys) and unused: ``rope_parameters`` says each kind's.
+_LAGUNA_ROPE = (
+    ("full_attention", RopeParameters(
+        500000.0, 0.5, RopeScaling(
+            rope_type="yarn", factor=64.0, beta_fast=64.0, beta_slow=1.0,
+            original_max_position_embeddings=4096,
+            attention_factor=1.4158883083359672))),
+    ("sliding_attention", RopeParameters(10000.0, 1.0)))
+PRESETS["laguna-xs.2"] = ModelConfig(
+    vocab_size=100352, hidden_size=2048, intermediate_size=8192,
+    num_layers=40, num_heads=48, num_kv_heads=8, head_dim=128,
+    rope_theta=500000.0, partial_rotary_factor=0.5, rms_norm_eps=1e-6,
+    max_position_embeddings=262144,
+    num_experts=256, num_experts_per_tok=8, moe_intermediate_size=512,
+    routed_scaling_factor=2.5, moe_shared_expert_intermediate_size=512,
+    first_k_dense_replace=1, sliding_window=512, attn_head_gate=True,
+    layer_types=("full_attention", *["sliding_attention"] * 3) * 10,
+    num_heads_per_layer=(48, 64, 64, 64) * 10,
+    rope_parameters=_LAGUNA_ROPE,
+)
+# one chip of eight that share each layer, the whole vocabulary: the
+# leading dense layer and two whole periods of window, window, window,
+# full (benchmark/configs/laguna-xs.2.json)
+PRESETS["laguna-xs.2-share8"] = cut_to_share(
+    PRESETS["laguna-xs.2"], tuple(range(9)), 8, vocabulary_shares=1)
+# test-size model of the same family: 5 layers (full, three of a window
+# of 8 keys, full), 6 and 8 query heads over 2 K/V heads of 16, the first
+# MLP dense, 16 experts at top-4 of which 4 are held, a shared expert
+PRESETS["mixed-tiny"] = ModelConfig(
+    vocab_size=512, hidden_size=64, intermediate_size=128, num_layers=5,
+    num_heads=6, num_kv_heads=2, head_dim=16, rope_theta=10000.0,
+    partial_rotary_factor=0.5, rms_norm_eps=1e-6,
+    max_position_embeddings=2048,
+    num_experts=16, num_experts_per_tok=4, moe_intermediate_size=32,
+    routed_scaling_factor=2.5, moe_shared_expert_intermediate_size=32,
+    experts_held=(0, 4), first_k_dense_replace=1, sliding_window=8,
+    attn_head_gate=True,
+    layer_types=("full_attention", *["sliding_attention"] * 3,
+                 "full_attention"),
+    num_heads_per_layer=(6, 8, 8, 8, 6),
+    rope_parameters=(
+        ("full_attention", RopeParameters(
+            10000.0, 0.5, RopeScaling(
+                rope_type="yarn", factor=16.0, beta_fast=64.0, beta_slow=1.0,
+                original_max_position_embeddings=32,
+                attention_factor=1.2772588722239782))),
+        ("sliding_attention", RopeParameters(100.0, 1.0))),
 )
 
 

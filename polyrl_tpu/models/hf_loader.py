@@ -96,6 +96,7 @@ def rope_scaling_from_hf(rs: dict | None) -> decoder.RopeScaling | None:
             beta_slow=float(rs.get("beta_slow", 1)),
             mscale=float(rs.get("mscale", 1)),
             mscale_all_dim=float(rs.get("mscale_all_dim", 0)),
+            attention_factor=float(rs.get("attention_factor") or 0.0),
             original_max_position_embeddings=int(
                 rs["original_max_position_embeddings"]))
     if rs_type not in (None, "default"):
@@ -162,12 +163,65 @@ def phi4flash_config(hf: dict, dtype=jnp.bfloat16) -> decoder.ModelConfig:
         sliding_window=hf["sliding_window"], dtype=dtype)
 
 
+def laguna_config(hf: dict, dtype=jnp.bfloat16) -> decoder.ModelConfig:
+    """A ``ModelConfig`` from a ``laguna`` config.json (poolside/
+    Laguna-XS.2): every key the published file has that the decoder reads.
+    ``mlp_layer_types`` has to be dense layers and then sparse ones
+    (``first_k_dense_replace`` is how many lead); the router is the
+    softmax one with its chosen weights renormalised (no published key
+    says otherwise). The checkpoint's tensors have no key map yet
+    (``load_hf_params`` says so)."""
+    n = hf["num_hidden_layers"]
+    mlps = list(hf.get("mlp_layer_types") or ["sparse"] * n)
+    dense = mlps.index("sparse") if "sparse" in mlps else n
+    if mlps != ["dense"] * dense + ["sparse"] * (n - dense):
+        raise NotImplementedError(
+            "laguna with dense MLPs among the sparse ones: "
+            f"mlp_layer_types {mlps}")
+    if hf.get("attention_bias") or hf.get("moe_apply_router_weight_on_input"):
+        raise NotImplementedError(
+            "laguna with attention_bias or moe_apply_router_weight_on_"
+            "input: neither is written")
+    ropes = tuple(
+        (kind, decoder.RopeParameters(
+            float(block["rope_theta"]),
+            float(block.get("partial_rotary_factor", 1.0)),
+            rope_scaling_from_hf(block)))
+        for kind, block in hf["rope_parameters"].items()
+        if isinstance(block, dict))
+    full = dict(ropes)["full_attention"]
+    return decoder.ModelConfig(
+        vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"], num_layers=n,
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"], head_dim=hf["head_dim"],
+        rope_theta=full.rope_theta,
+        partial_rotary_factor=float(hf.get("partial_rotary_factor",
+                                           full.partial_rotary_factor)),
+        rms_norm_eps=float(hf["rms_norm_eps"]),
+        tie_word_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        max_position_embeddings=hf["max_position_embeddings"],
+        num_experts=hf["num_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        routed_scaling_factor=float(hf.get("moe_routed_scaling_factor", 1.0)),
+        moe_shared_expert_intermediate_size=hf.get(
+            "shared_expert_intermediate_size", 0),
+        first_k_dense_replace=dense, sliding_window=hf["sliding_window"],
+        attn_head_gate=bool(hf.get("gating")),
+        layer_types=tuple(hf["layer_types"]),
+        num_heads_per_layer=tuple(hf["num_attention_heads_per_layer"]),
+        rope_parameters=ropes, dtype=dtype)
+
+
 def config_from_hf(ckpt_dir: str, dtype=jnp.bfloat16) -> decoder.ModelConfig:
     """Build a ModelConfig from the checkpoint's config.json (llama/qwen2/
     qwen3 architectures; ``zaya``: ``zaya_config``; ``phi4flash``:
-    ``phi4flash_config``)."""
+    ``phi4flash_config``; ``laguna``: ``laguna_config``)."""
     with open(os.path.join(ckpt_dir, "config.json")) as f:
         hf = json.load(f)
+    if hf.get("model_type") == "laguna":
+        return laguna_config(hf, dtype)
     if hf.get("model_type") == "zaya":
         return zaya_config(hf, dtype)
     if hf.get("model_type") == "phi4flash":
@@ -252,6 +306,10 @@ def load_hf_params(ckpt_dir: str, cfg: decoder.ModelConfig | None = None,
             "no key map for a phi4flash (SambaY) checkpoint yet: write it "
             "from the published model.safetensors.index.json (ROADMAP.md "
             "Queue 2)")
+    if cfg.layer_types:
+        raise NotImplementedError(
+            "no key map for a laguna checkpoint yet: write it from the "
+            "published model.safetensors.index.json (ROADMAP.md Queue 2)")
     dtype = dtype or cfg.dtype
     np_dtype = jnp.dtype(dtype)
 

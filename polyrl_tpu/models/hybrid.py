@@ -12,10 +12,13 @@ router is an MLP on a latent that each layer hands to the next, both
 sublayers' residuals scaled; every one of its layers is alike, so the
 stacked scan would fit its trainer's forward: it lives here because the
 slot state and a second tensor carried beside the residual stream are what
-this file's unrolled loop threads); and the SambaY family (``phi4flash``:
+this file's unrolled loop threads); the SambaY family (``phi4flash``:
 Mamba-1 scans, window attention, one full-attention layer whose K/V the
 cross-decoder's layers read, gated memory units, LayerNorm with a bias;
-``mixers/ssm.py``, ``mixers/diff.py``).
+``mixers/ssm.py``, ``mixers/diff.py``); and Laguna's (``laguna``: rope'd
+softmax GQA in every layer, full layers in pages and window layers in
+rings with a head count and a rope a kind, a softmax router's scaled
+weights beside a shared expert; ``mixers/gqa.py``).
 
 One block a kind, parameters stacked per kind::
 
@@ -25,7 +28,8 @@ One block a kind, parameters stacked per kind::
                          keeps beside the stacks ([L, ..]: residual scales,
                          norm biases)
       "dense": {w_gate w_up [Ld, d, f], w_down [Ld, f, d]}
-      "moe":   {router [Ls, d, E_all], router_bias [Ls, E_all] float32,
+      "moe":   {router [Ls, d, E_all], router_bias [Ls, E_all] float32
+                (the sigmoid router's and the router MLP's),
                 we_gate we_up [Ls, E_held, d, fe], we_down [Ls, E_held, fe, d],
                 ws_gate ws_up [Ls, d, fs], ws_down [Ls, fs, d]}
                (with ``router_hidden_size`` R: router_down [Ls, d, R],
@@ -132,9 +136,13 @@ def init_params(rng: jax.Array, cfg) -> dict:
                    "router_w2": norm(s, r, r, **fan),
                    "router": norm(s, r, cfg.num_experts, **fan)} if r
                   else {"router": norm(s, d, cfg.num_experts)})
+        # the bias that enters the choice: the sigmoid router's and the
+        # router MLP's; the softmax router has none
+        if r or cfg.scoring_func == "sigmoid":
+            router["router_bias"] = norm(s, cfg.num_experts,
+                                         dtype=jnp.float32)
         layers["moe"] = {
             **router,
-            "router_bias": norm(s, cfg.num_experts, dtype=jnp.float32),
             "we_gate": norm(s, held, d, fe), "we_up": norm(s, held, d, fe),
             "we_down": norm(s, held, fe, d),
         }
@@ -400,7 +408,7 @@ def prefill(params, cfg, ids, lens, prefix_len, pools, prefix_page_ids,
         prefix = []
         for pool, rec in zip(paged, keep_pages):
             with jax.named_scope(rec.pages_scope):
-                prefix.append((_gather_prefix(cfg, pool, prefix_page_ids),
+                prefix.append((_gather_prefix(rec, pool, prefix_page_ids),
                                pre_len))
     x = params["embed"][ids]
     x, new_states, kept = run_sequence(params, cfg, x, positions, valid,
@@ -408,7 +416,7 @@ def prefill(params, cfg, ids, lens, prefix_len, pools, prefix_page_ids,
     written = []
     for pool, new, rec in zip(paged, kept, keep_pages):
         with jax.named_scope(rec.pages_scope):
-            written.append(_scatter_chunk(cfg, pool, page_ids, new, valid))
+            written.append(_scatter_chunk(rec, pool, page_ids, new, valid))
     paged = tuple(written)
     written = []
     for rows, new, was, rec in zip(state, new_states, states, keep_slot):
@@ -418,36 +426,24 @@ def prefill(params, cfg, ids, lens, prefix_len, pools, prefix_page_ids,
     return (paged, tuple(written)), logits
 
 
-def _kv_by_slabs(cfg) -> bool:
-    """Whether a K/V pair's pages move as slabs of the pool's ``[H N, ps,
-    w]`` view (``blocks._gather_slabs_kv``, ``_scatter_slabs``) and not as
-    rows of its ``[H N, ps w]`` view (``_gather_kv``, ``_scatter_kv``): for
-    the SambaY family's pools of ten heads, which by rows XLA lays out
-    anew, 1.7-2.7 GB of temporaries beside a 900 MB pool that the chip's
-    compiler refused. The CCA model's programs are the rows' and are kept
-    to the byte (an accepted benchmark cell's). This is the ONE function
-    of this file that chooses a path by a family's field: one form for
-    both is for the PR that can measure that cell (ROADMAP D9 (3))."""
-    return bool(cfg.mb_per_layer)
-
-
-def _gather_prefix(cfg, pool, page_ids):
-    """What the pages ``page_ids`` [B, n] of a paged layer's ``pool``
-    hold: a latent pool's rows [B, n * ps, w], a K/V pair's (k, v) each
-    [B, n * ps, H, w]."""
+def _gather_prefix(rec, pool, page_ids):
+    """What the pages ``page_ids`` [B, n] of the ``pool`` of a layer of
+    the kind ``rec`` hold: a latent pool's rows [B, n * ps, w], a K/V
+    pair's (k, v) each [B, n * ps, H, w] (by slabs or by rows:
+    ``Mixer.pages_by_slabs``)."""
     if not isinstance(pool, tuple):
         return _gather_pages(pool, page_ids)
-    return (_gather_slabs_kv if _kv_by_slabs(cfg) else _gather_kv)(
+    return (_gather_slabs_kv if rec.pages_by_slabs else _gather_kv)(
         pool, page_ids)
 
 
-def _scatter_chunk(cfg, pool, page_ids, kept, valid):
+def _scatter_chunk(rec, pool, page_ids, kept, valid):
     """``pool`` with a chunk's ``kept`` written to its pages ``page_ids``
     [B, T // ps]. (A K/V pair's padded position lands in the tail of the
     row's last page or in the null page, where no length reaches it.)"""
     if not isinstance(pool, tuple):
         return _scatter_tokens(pool, page_ids, kept, valid)
-    if _kv_by_slabs(cfg):
+    if rec.pages_by_slabs:
         return tuple(_scatter_slabs(a, page_ids, x)
                      for a, x in zip(pool, kept))
     return _scatter_kv(pool, page_ids, kept)

@@ -7,12 +7,12 @@ that more than one family uses are here too."""
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
-
-from polyrl_tpu.models import cache_spec
+import numpy as np
 
 L2_EPS = 1e-6
 
@@ -141,6 +141,15 @@ class Mixer:
     # its slot written back under
     pages_scope: str = ""
     slot_scope: str = ""
+    # a prefill chunk moves its K/V pair's pages as slabs of the pool's
+    # ``[H N, ps, w]`` view (``blocks._gather_slabs_kv``, ``_scatter_slabs``)
+    # and not as rows of its ``[H N, ps w]`` view (``hybrid._gather_kv``,
+    # ``_scatter_kv``): a pool of eight or ten heads by rows XLA lays out
+    # anew, 1.7-2.7 GB of temporaries beside a 900 MB pool that the chip's
+    # compiler refused. The CCA model's programs are the rows' and are kept
+    # to the byte (an accepted benchmark cell's): one form for all is for
+    # the PR that can measure that cell (ROADMAP D9 (3))
+    pages_by_slabs: bool = False
     # its slot around a prefill chunk: (cfg, arrays, SlotRows) -> rows;
     # (cfg, arrays, SlotRows, new rows, rows read) -> arrays
     read_slot: Callable = read_rows
@@ -161,10 +170,63 @@ class Mixer:
     kernel: tuple | None = None
 
 
-# the uniform decoder's mixer: ``decoder.py``'s stacked scan runs it, so
-# only its cache is asked here (ROADMAP D9 (2))
-GQA = Mixer("gqa", cache=lambda cfg, p, dtype: cache_spec.Paged(
-    2, cfg.num_kv_heads, cfg.head_dim_))
+def yarn_inv_freq(theta: float, r: int, s) -> np.ndarray:
+    """The ``r / 2`` frequencies of a rope over ``r`` columns, float64:
+    ``theta ** (-2i / r)``, under YaRN (``s``: a ``decoder.RopeScaling``,
+    DeepSeek-V3's reading) divided by ``factor`` from the dimension up at
+    which ``original_max_position_embeddings`` positions make ``beta_slow``
+    turns (rounded up), kept below the one at which they make
+    ``beta_fast`` (rounded down), blended linearly between."""
+    inv = 1.0 / (theta ** (np.arange(0, r, 2, dtype=np.float64) / r))
+    if s is None:
+        return inv
+    if s.rope_type != "yarn":
+        raise NotImplementedError(
+            f"rope scaling {s.rope_type!r} in a model of several kinds of "
+            "layer (yarn only)")
+
+    def dim_of(turns: float) -> float:
+        return (r * math.log(s.original_max_position_embeddings
+                             / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(s.beta_fast)), 0)
+    high = min(math.ceil(dim_of(s.beta_slow)), r - 1)
+    ramp = np.clip((np.arange(r // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return inv / s.factor * ramp + inv * (1 - ramp)
+
+
+def yarn_mscale(factor: float, m: float) -> float:
+    return 1.0 if factor <= 1.0 else 0.1 * m * math.log(factor) + 1.0
+
+
+def yarn_amplitude(s) -> float:
+    """What YaRN multiplies cos and sin by: 1 without it; the published
+    ``attention_factor`` where the configuration has one; else
+    ``mscale``'s factor over ``mscale_all_dim``'s (1 where they are
+    equal)."""
+    if s is None or s.rope_type != "yarn":
+        return 1.0
+    if s.attention_factor:
+        return s.attention_factor
+    return (yarn_mscale(s.factor, s.mscale)
+            / yarn_mscale(s.factor, s.mscale_all_dim))
+
+
+def rope_partial(x, positions, inv_freq, amplitude: float = 1.0):
+    """Rope on the first ``2 * len(inv_freq)`` columns of each head of
+    ``x`` [..., T, H, D] float32 at ``positions`` [..., T] (rotate-half
+    within them: columns ``i`` and ``i + len(inv_freq)`` are a pair), the
+    rest as they are; cos and sin times ``amplitude`` (YaRN's)."""
+    half = len(inv_freq)
+    ang = positions.astype(jnp.float32)[..., None, None] * jnp.asarray(
+        inv_freq, jnp.float32)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if amplitude != 1.0:
+        cos, sin = cos * amplitude, sin * amplitude
+    a, b = x[..., :half], x[..., half:2 * half]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin,
+                            x[..., 2 * half:]], axis=-1)
 
 
 def l2norm(x):
@@ -201,11 +263,12 @@ _SCORE_BYTES = 128 << 20
 _MIN_KEY_BLOCK = 128
 
 
-def key_block(cfg, b: int, t: int) -> int:
-    """Keys a block of ``mla_expanded`` or ``diff_attention`` holds for
-    ``b`` rows of ``t`` queries: what keeps the [B, H, T, block] float32
+def key_block(cfg, b: int, t: int, heads: int = 0) -> int:
+    """Keys a block of ``mla_expanded``, ``diff_attention`` or
+    ``gqa_attention`` holds for ``b`` rows of ``t`` queries at ``heads``
+    heads (the model's without): what keeps the [B, H, T, block] float32
     scores within ``_SCORE_BYTES``, in whole multiples of
     ``_MIN_KEY_BLOCK`` (at 128 heads and a 512-token chunk: 512 keys; at
     32 heads: 2048)."""
-    fit = _SCORE_BYTES // (4 * b * cfg.num_heads * t)
+    fit = _SCORE_BYTES // (4 * b * (heads or cfg.num_heads) * t)
     return max(_MIN_KEY_BLOCK, fit // _MIN_KEY_BLOCK * _MIN_KEY_BLOCK)

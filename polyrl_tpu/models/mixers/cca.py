@@ -34,8 +34,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from polyrl_tpu.models import cache_spec
-from polyrl_tpu.models.mixers.base import (Kept, Mixer, l2norm, tail_after,
-                                           set_rows)
+from polyrl_tpu.models.mixers.base import (Kept, Mixer, l2norm,
+                                           rope_partial, set_rows,
+                                           tail_after)
 from polyrl_tpu.models.quant import mm
 
 
@@ -75,16 +76,10 @@ def cca_rope(cfg, x, positions):
     """Rope on the first ``partial_rotary_factor`` of each head's columns
     of ``x`` [B, T, H, D] float32 (rotate-half within them, frequencies
     ``theta ** (-2i / rot)``), the rest as they are."""
-    d = x.shape[-1]
-    rot = int(d * cfg.partial_rotary_factor)
+    rot = int(x.shape[-1] * cfg.partial_rotary_factor)
     inv = 1.0 / (cfg.rope_theta ** (np.arange(0, rot, 2, dtype=np.float64)
                                     / rot))
-    ang = positions.astype(jnp.float32)[..., None, None] * jnp.asarray(
-        inv, jnp.float32)
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    a, b = x[..., :rot // 2], x[..., rot // 2:rot]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin,
-                            x[..., rot:]], axis=-1)
+    return rope_partial(x, positions, inv)
 
 
 def _cca_mix(cfg, lp, proj, positions, tails):

@@ -184,7 +184,7 @@ def ring_pages(cfg, slots, ps: int):
             + jnp.arange(n, dtype=jnp.int32)[None, :]).astype(jnp.int32)
 
 
-def _ring_read(cfg, ring, at):
+def ring_read(cfg, ring, at):
     """What the rings of ``at.slots`` [B] hold after ``at.prefix_len``
     tokens: (k, v [B, window, pairs, 2D], the position of the token each
     row holds [B, window], -1 where it holds none: a ring's rows hold no
@@ -199,11 +199,11 @@ def _ring_read(cfg, ring, at):
         return k, v, jnp.broadcast_to(held, (at.slots.shape[0], w))
 
 
-def _ring_write(cfg, ring, at, kv, old):
+def ring_write(cfg, ring, at, kv, old):
     """The rings of ``at.slots`` [B] after a chunk's (k, v) [B, T, pairs,
     2D] of ``at.lens`` [B] real tokens that follow ``at.prefix_len``: each
     of the last ``window`` of them at its position modulo the window,
-    every other row as ``old`` has it (``_ring_read``'s at the chunk's
+    every other row as ``old`` has it (``ring_read``'s at the chunk's
     start). The whole ring is written back, by pages
     (``_scatter_slabs``)."""
     w = cfg.sliding_window
@@ -354,14 +354,14 @@ def _producer(cfg) -> int:
 DIFF = Mixer(
     "diff", lambda cfg, p, dtype: cache_spec.Paged(2, *_pair(cfg)),
     stack="attn", init=init, sequence=sequence_diff, step=step_paged,
-    row_parallel=("wo",), pages_scope="attn_core",
+    row_parallel=("wo",), pages_scope="attn_core", pages_by_slabs=True,
     counts=("shared_kv_rows_read",))
 SWA = Mixer(
     "swa", lambda cfg, p, dtype: cache_spec.Ring(
         *_pair(cfg), cfg.sliding_window, dtype),
     stack="attn", init=init, sequence=sequence_swa, step=step_swa,
-    per_step=per_step, slot_scope="swa_core", read_slot=_ring_read,
-    write_slot=_ring_write, held=held, row_parallel=("wo",),
+    per_step=per_step, slot_scope="swa_core", read_slot=ring_read,
+    write_slot=ring_write, held=held, row_parallel=("wo",),
     counts=("window_rows_read",))
 CROSS = Mixer(
     "cross", lambda cfg, p, dtype: cache_spec.Reads(_producer(cfg)),

@@ -21,15 +21,15 @@ The stack ``params["layers"]["mla"]``::
 
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from polyrl_tpu.models import cache_spec
 from polyrl_tpu.models.blocks import _scatter_token_kv, rms_norm
-from polyrl_tpu.models.mixers.base import Kept, Mixer, key_block
+from polyrl_tpu.models.mixers.base import (Kept, Mixer, key_block,
+                                           yarn_amplitude, yarn_inv_freq,
+                                           yarn_mscale)
 from polyrl_tpu.models.quant import mm
 
 
@@ -57,45 +57,17 @@ def cache(cfg, p, dtype):
     return cache_spec.Paged(1, 1, cache_spec.latent_row(cfg))
 
 
-def _yarn_mscale(factor: float, m: float) -> float:
-    return 1.0 if factor <= 1.0 else 0.1 * m * math.log(factor) + 1.0
-
-
 def rope_inv_freq(cfg) -> np.ndarray:
     """The ``qk_rope_head_dim / 2`` frequencies of a latent layer's rope,
-    float64: ``theta ** (-2i / R)``, under YaRN (``rope_scaling``,
-    DeepSeek-V3's reading) divided by ``factor`` from the dimension up at
-    which ``original_max_position_embeddings`` positions make ``beta_slow``
-    turns (rounded up), kept below the one at which they make
-    ``beta_fast`` (rounded down), blended linearly between."""
-    r = cfg.qk_rope_head_dim
-    inv = 1.0 / (cfg.rope_theta ** (np.arange(0, r, 2, dtype=np.float64) / r))
-    s = cfg.rope_scaling
-    if s is None:
-        return inv
-    if s.rope_type != "yarn":
-        raise NotImplementedError(
-            f"rope scaling {s.rope_type!r} on a latent attention layer")
-
-    def dim_of(turns: float) -> float:
-        return (r * math.log(s.original_max_position_embeddings
-                             / (turns * 2 * math.pi))
-                / (2 * math.log(cfg.rope_theta)))
-
-    low = max(math.floor(dim_of(s.beta_fast)), 0)
-    high = min(math.ceil(dim_of(s.beta_slow)), r - 1)
-    ramp = np.clip((np.arange(r // 2) - low) / max(high - low, 1e-3), 0, 1)
-    return inv / s.factor * ramp + inv * (1 - ramp)
+    float64, under YaRN where the configuration scales it
+    (``base.yarn_inv_freq``)."""
+    return yarn_inv_freq(cfg.rope_theta, cfg.qk_rope_head_dim,
+                         cfg.rope_scaling)
 
 
 def rope_amplitude(cfg) -> float:
-    """What YaRN multiplies cos and sin by: 1 without it, and 1 where
-    ``mscale`` equals ``mscale_all_dim``."""
-    s = cfg.rope_scaling
-    if s is None or s.rope_type != "yarn":
-        return 1.0
-    return (_yarn_mscale(s.factor, s.mscale)
-            / _yarn_mscale(s.factor, s.mscale_all_dim))
+    """What YaRN multiplies cos and sin by (``base.yarn_amplitude``)."""
+    return yarn_amplitude(cfg.rope_scaling)
 
 
 def mla_scale(cfg) -> float:
@@ -104,7 +76,7 @@ def mla_scale(cfg) -> float:
     scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
     s = cfg.rope_scaling
     if s is not None and s.rope_type == "yarn" and s.mscale_all_dim:
-        scale *= _yarn_mscale(s.factor, s.mscale_all_dim) ** 2
+        scale *= yarn_mscale(s.factor, s.mscale_all_dim) ** 2
     return scale
 
 

@@ -88,6 +88,9 @@ emission or iteration, never once a token, all on this profiler's clock:
   rows times Mamba layers (a state read and written each), keys of the
   one shared K/V pool read, summed over the layers that attend over it,
   and keys of the window layers' rings read (0 for every other model);
+  ``paged_rows_read`` — for a model of ``gqa`` layers beside other kinds
+  (Laguna: ``mixers/gqa.py``), keys of the full layers' pages read, summed
+  over them (its window layers move ``window_rows_read``);
   ``decode_dispatches_cold`` — those of the dispatches enqueued with
   NOTHING outstanding (the device had run dry: an engine that keeps its
   run-ahead does it once a burst, one that drains before every dispatch
@@ -173,6 +176,7 @@ CUMULATIVE_KEYS = (
     "ssm_state_rows",
     "shared_kv_rows_read",
     "window_rows_read",
+    "paged_rows_read",
     "device_busy_s", "loop_wall_s", "loop_host_s",
     *PHASE_KEYS.values(), "emit_wait_s", "dispatches_emitted",
     "landing_gap_hist", "stalls", "programs_built", "build_s")

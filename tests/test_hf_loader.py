@@ -198,3 +198,56 @@ def test_phi4flash_checkpoint_is_refused_until_its_key_map_exists(tmp_path):
     with pytest.raises(NotImplementedError,
                        match="no key map for a phi4flash"):
         hf_loader.load_hf_params(str(tmp_path))
+
+
+# -- the laguna family (models/mixers/gqa.py) --------------------------------
+
+
+def _laguna_keys() -> dict:
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "laguna-xs.2.json")) as f:
+        return json.load(f)
+
+
+def test_laguna_config_is_the_published_preset():
+    """``laguna_config`` on Laguna-XS.2's published config.json (the
+    benchmark's file holds every key of it, the depth, the experts and the
+    per-layer lists cut and the published ones under ``published``) gives
+    the ``laguna-xs.2`` preset, and the share cut from it is the
+    benchmark's; what the decoder has not written is refused."""
+    from polyrl_tpu.models import hf_loader
+
+    hf = _laguna_keys()
+    assert hf["model_type"] == "laguna"
+    whole = hf_loader.laguna_config({**hf, **hf["published"]})
+    assert whole == decoder.get_config("laguna-xs.2")
+    assert decoder.cut_to_share(
+        whole, hf["kept_layers"], hf["chips_sharing_a_layer"],
+        vocabulary_shares=1) == decoder.get_config("laguna-xs.2-share8")
+    full, window = (r for _kind, r in whole.rope_parameters)
+    assert full.scaling.rope_type == "yarn" and window.scaling is None
+    assert full.scaling.attention_factor == 1.4158883083359672
+    assert (full.partial_rotary_factor, window.partial_rotary_factor) == \
+        (0.5, 1.0)
+    with pytest.raises(NotImplementedError, match="attention_bias"):
+        hf_loader.laguna_config({**hf, "attention_bias": True})
+    with pytest.raises(NotImplementedError, match="mlp_layer_types"):
+        hf_loader.laguna_config(
+            {**hf, "mlp_layer_types": ["sparse", "dense"] + ["sparse"] * 7})
+
+
+def test_laguna_checkpoint_is_refused_until_its_key_map_exists(tmp_path):
+    import json
+
+    from polyrl_tpu.models import hf_loader
+
+    (tmp_path / "config.json").write_text(json.dumps(_laguna_keys()))
+    cfg = hf_loader.config_from_hf(str(tmp_path))
+    assert cfg.layer_types[:2] == ("full_attention", "sliding_attention")
+    assert cfg.sliding_window == 512 and cfg.attn_head_gate
+    with pytest.raises(NotImplementedError, match="no key map for a laguna"):
+        hf_loader.load_hf_params(str(tmp_path))

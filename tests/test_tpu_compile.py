@@ -825,3 +825,117 @@ def test_sambay_prefill_chunk_compiles_for_v5e_within_memory(one_chip,
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert live < 15.6 * 10**9
     assert m.temp_size_in_bytes < 1.2 * 10**9
+
+
+# -- Laguna-XS.2's cell (benchmark/configs/laguna-xs.2.json) -------------------
+
+
+def _mixed_shapes(one_chip, s, n_pages, page):
+    from polyrl_tpu.models import decoder
+
+    cfg = decoder.get_config("laguna-xs.2-share8")
+
+    def shapes(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = shapes(jax.eval_shape(
+        lambda: decoder.init_params(jax.random.PRNGKey(0), cfg)))
+    pools = shapes(jax.eval_shape(
+        lambda: decoder.make_paged_pools(cfg, n_pages, page, slots=s + 1)))
+    return cfg, params, pools
+
+
+MIXED_PAGES = 10241
+
+
+def test_mixed_decode_step_compiles_for_v5e_and_copies_no_cache(
+        one_chip, chip_precision, on_tpu):
+    """The cell's whole decode program: 8 fused steps of the 9 layers at
+    64 rows, the three full layers' pools and the six rings donated, the
+    token drawn inside the untied head. The write and attention kernels
+    take 8 K/V heads of 128 under 48 query heads (rows of 6, on pages) and
+    under 64 (rows of 8, on rings) in ONE program (Mosaic's word on it).
+    Weights (3.22 GB), the pools (8.05 GB), 65 slots' rings (0.82 GB) and
+    everything the step holds at once fit a 16 GB chip, and nothing the
+    optimised program writes is as large as a ring's K but the write
+    kernel's own in-place result."""
+    from polyrl_tpu.models import decoder
+
+    s, width, page = 64, 320, 64
+    cfg, params, pools = _mixed_shapes(one_chip, s, MIXED_PAGES, page)
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(params, paged, state, rng, table, lens, last, active, temps):
+        def body(carry, _):
+            paged, state, rng, lens, last = carry
+            rng, sub = jax.random.split(rng)
+            head = functools.partial(decoder.head_and_sample, rng=sub,
+                                     temps=temps)
+            (tok, logp), (paged, state), load = decoder.forward_paged_decode(
+                params, cfg, last, lens, (paged, state), table, lens,
+                active=active, head_fn=head)
+            return (paged, state, rng, lens + 1, tok), (tok, logp, load)
+        return jax.lax.scan(body, (paged, state, rng, lens, last), None,
+                            length=8)
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, pools[0], pools[1], arg((2,), jnp.uint32),
+        arg((s, width), jnp.int32), arg((s,), jnp.int32),
+        arg((s,), jnp.int32), arg((s,), jnp.bool_),
+        arg((s,), jnp.float32)).compile()
+    m = compiled.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert 12.0e9 < live < 13.2e9
+    text = compiled.as_text()
+    # a write and an attention a layer, two grouped matmuls a sparse
+    # layer, the head
+    assert text.count("tpu_custom_call") >= 2 * 9 + 2 * 8 + 1
+    ring = 8 * (1 + 65 * 8) * 64 * 128    # a window layer's K (or V)
+    # (``copy-start`` / ``copy-done``: XLA's memory-space assignment keeps
+    # ONE ring's K, 68 MB, in VMEM across a step's kernels and moves it
+    # back: a ring of this size fits there, where Phi's 169 MB did not;
+    # no layout changes and nothing is computed into a new array)
+    plumbing = re.compile(r"= \(?\w+\[[\d,]*\]\S* (while|tuple|"
+                          r"get-tuple-element|bitcast|custom-call|"
+                          r"copy-start|copy-done)\(")
+    made = [(n, line) for n, line in _written(text)
+            if n >= ring and not plumbing.search(line)]
+    assert not [line[:200] for _n, line in made]
+    moved = [line for n, line in _written(text)
+             if n >= ring and " copy-done(" in line]
+    assert len(moved) <= 2, [line[:200] for line in moved]
+
+
+def test_mixed_prefill_chunk_compiles_for_v5e_within_memory(one_chip,
+                                                            chip_precision,
+                                                            on_tpu):
+    """The longest prompt's last chunk: 512 tokens from the slot's rings
+    over 256 pages of the three full layers' prefix, beside the weights,
+    the pools and the rings; the pools are moved by slabs and never laid
+    out anew."""
+    from polyrl_tpu.models import decoder
+
+    page, pb, n_pre = 64, 512, 256
+    cfg, params, pools = _mixed_shapes(one_chip, 64, MIXED_PAGES, page)
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def chunk(params, paged, state, ids, n, at, pre_pages, pages, slot):
+        return decoder.prefill_suffix_into_pages(
+            params, cfg, ids, n, at, (paged, state), pre_pages, pages, slot)
+
+    compiled = jax.jit(chunk, donate_argnums=(1, 2)).lower(
+        params, pools[0], pools[1], arg((pb,), jnp.int32),
+        arg((), jnp.int32), arg((), jnp.int32), arg((n_pre,), jnp.int32),
+        arg((pb // page,), jnp.int32), arg((), jnp.int32)).compile()
+    m = compiled.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert live < 14.5 * 10**9
+    assert m.temp_size_in_bytes < 1.5 * 10**9

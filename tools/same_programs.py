@@ -23,7 +23,8 @@ traces a jitted helper of ``jax.numpy`` (``//``, ``%``, ``where``,
 ``minimum``) once a process and keeps the location of that FIRST call: in
 one process Ling's latent kernel named the line of ``paged_attention.py``
 where qwen's step had first divided, and read different once that line had
-moved (PR 44). Prints a line a program and exits 1 where two differ."""
+moved (PR 44). Prints a line a program and exits 1 where two differ; a
+preset that the other checkout does not have reads ``new``."""
 
 from __future__ import annotations
 
@@ -40,7 +41,7 @@ import tempfile
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PRESETS = ("qwen2.5-7b", "qwen3-30b-a3b", "ling-3.0-flash-share4",
            "dots.vlm1-share16", "zaya1-8b-depth12",
-           "phi-4-mini-flash-reasoning")
+           "phi-4-mini-flash-reasoning", "laguna-xs.2-share8")
 
 
 def digests(presets, keep: str = "") -> dict:
@@ -58,6 +59,8 @@ def digests(presets, keep: str = "") -> dict:
     arg = jax.ShapeDtypeStruct
     out = {}
     for preset in presets:
+        if preset not in decoder.PRESETS:
+            continue    # a checkout from before the preset: ``new`` below
         cfg = decoder.get_config(preset)
         # pages of 64 tokens, or what a tiny preset's window is whole
         # pages of (a ring is, ``cache_spec.Ring``)
@@ -142,7 +145,10 @@ def main(argv) -> int:
                 found.update(json.loads(ran.stdout.strip().splitlines()[-1]))
             got.append(found)
     same = True
-    for key in got[0]:
+    for key in got[1]:
+        if key not in got[0]:
+            print(f"{key}: new {got[1][key][:16]}")
+            continue
         verdict = "same" if got[0][key] == got[1][key] else "DIFFERENT"
         same &= verdict == "same"
         print(f"{key}: {verdict} {got[0][key][:16]} {got[1][key][:16]}")
