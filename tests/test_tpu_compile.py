@@ -395,8 +395,28 @@ def test_latent_attention_kernel_compiles_for_v5e(one_chip, s, h, n_pages,
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2 * 2**20
     assert mem.output_size_in_bytes == s * h * 512 * 2
-    b, _subs, nbuf = mla._block_plan(h, 640, 512, 64, 2, width)
+    b, _subs, nbuf, _piece = mla._block_plan(h, 640, 512, 64, 2, width)
     assert nbuf * b * 64 * 640 * 2 < 8 * 2**20
+
+
+def test_latent_attention_kernel_unrolls_no_more_dma_starts():
+    """A DMA descriptor that is unrolled code is a start in the kernel's
+    jaxpr, and what made the kernel lower slowly once (every page of every
+    path unrolled: 0.4-0.8 s a program that holds it, +4.4 s of the
+    cell's ``setup_s``; PR 36). At ``dots.vlm1``'s shape the parent of
+    PR 48 has 34: a whole block's 32 pages and one loop each for the
+    call's first block and for the block a row's last one starts. No
+    chip and no compiler: the count is the trace's."""
+    from polyrl_tpu.ops import mla_attention as mla
+
+    fn = functools.partial(mla.latent_paged_attention_pallas, rank=512,
+                           scale=192 ** -0.5)
+    arg = jax.ShapeDtypeStruct
+    text = str(jax.make_jaxpr(fn)(
+        arg((65, 128, 640), jnp.bfloat16),
+        arg((1, 10241, 64, 640), jnp.bfloat16), arg((65, 320), jnp.int32),
+        arg((65,), jnp.int32)))
+    assert 0 < text.count("dma_start") <= 34
 
 
 # (slots, rows, heads): Ling's cell (a row's 32 heads are one block of

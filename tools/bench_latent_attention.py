@@ -6,13 +6,25 @@ against the gather-based oracle on a few rows.
 
     chiprun -- python tools/bench_latent_attention.py \
         [--heads 128] [--mix rollout-long-latent] \
-        [--plans "6,1,2;16,2,3"] [--also-tree .parent]
+        [--into-answer 564,1247,1930] \
+        [--plans "32,2,2,256;32,2,2,1024"] [--also-tree .parent]
 
 ``--plans`` times the kernel under other (pages a block, sub-blocks a
-block, buffers) than ``mla_attention._block_plan`` returns for the shapes: the
-sweep behind the rule's constants. ``--also-tree`` times another
-checkout's kernel beside this one (parent against change in one call).
-Prints one JSON line a variant; fails without a TPU."""
+block, buffers, keys a piece of a row's last block) than
+``mla_attention._block_plan`` returns for the shapes: the sweep behind the
+rule's constants. ``--also-tree`` times other checkouts' kernels beside
+this one (parent against change in one call; several separated by
+commas). ``--into-answer`` is a LIST of how many tokens every row has
+generated. The tool gives every row the same number, and a row's last
+block is what the variants differ in: at one offset the rows that have
+just crossed a block's edge, and how far the others are into a piece, are
+one draw of what a window walks through (a row's last block fills once
+every 2,048 steps), and a plan that wins there may lose a third of a block
+on (ROADMAP Queue 1 item 4 found the same of
+``tools/bench_paged_attention.py``). So the default is three offsets a
+third of a block apart, and a plan is judged on all three. Prints one JSON
+line a variant and offset (time, share of the roof, keys the plan
+multiplies over keys live); fails without a TPU."""
 
 from __future__ import annotations
 
@@ -103,8 +115,9 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--mix", default="rollout-long-latent")
     ap.add_argument("--heads", type=int, default=128)
-    ap.add_argument("--into-answer", type=int, default=564,
-                    help="tokens generated so far (64 warm + mid-window)")
+    ap.add_argument("--into-answer", default="564,1247,1930",
+                    help="tokens generated so far, a list: 64 warm + part "
+                         "of the window, a third of a 2,048-key block apart")
     ap.add_argument("--table-width", type=int, default=320)
     ap.add_argument("--live", type=int, default=0,
                     help="only every (rows // live)-th request is live, the "
@@ -122,56 +135,63 @@ def main() -> int:
 
     from polyrl_tpu.ops import mla_attention as here
 
-    lengths = cell_lengths(args.mix, args.into_answer)
-    if args.live:
-        every = len(lengths) // args.live
-        lengths = [t if k % every == 0 else 0 for k, t in enumerate(lengths)]
-    q, pool, table, lens = inputs(lengths, args.heads, args.table_width,
-                                  args.seed)
     scale = 192 ** -0.5
-    check = jnp.asarray([0, len(lengths) // 2, len(lengths) - 1, len(lengths)])
-    want = here.latent_paged_attention_ref(q[check], pool, table[check],
-                                           lens[check], RANK, scale)
-    rows = sum(lengths)
-    # the least the chip could take: the slower of the FLOP and byte roofs
-    least_ms = 1e3 * max(2 * args.heads * (2 * RANK + 64) * rows / 197e12,
-                         2 * (RANK + 64) * rows / 819e9)
-
+    rule = here._block_plan(args.heads, WIDTH, RANK, PAGE, 2, args.table_width)
     variants = [("change", here, None)]
     variants += [(f"change {p}", here, tuple(int(x) for x in p.split(",")))
                  for p in args.plans.split(";") if p]
-    if args.also_tree:
-        variants.append((args.also_tree, load_module(args.also_tree), None))
+    variants += [(tree, load_module(tree), None)
+                 for tree in args.also_tree.split(",") if tree]
     os.makedirs(args.out, exist_ok=True)
-    for k, (name, mod, plan) in enumerate(variants):
-        fn = mod.latent_paged_attention_pallas
-        if plan is not None:
-            fn = functools.partial(fn, plan=plan)
-        try:
-            got = jax.block_until_ready(fn(q, pool, table, lens, RANK, scale))
-        except Exception as e:  # a plan the compiler refuses: say so, go on
-            print(json.dumps({"variant": name, "error": str(e)[:300]}),
-                  flush=True)
-            continue
-        err = float(jnp.abs(got[check].astype(jnp.float32) - want).max())
-        trace_dir = os.path.join(args.out, f"trace{k}")
-        with jax.profiler.trace(trace_dir):
-            for _ in range(args.calls):
-                got = fn(q, pool, table, lens, RANK, scale)
-            jax.block_until_ready(got)
-        ms = kernel_ms(trace_dir)
-        med = statistics.median(ms)
-        print(json.dumps({
-            "variant": name, "plan": plan or (
-                list(here._block_plan(args.heads, WIDTH, RANK, PAGE, 2,
-                          args.table_width)) if mod is here else None),
-            "device": jax.devices()[0].device_kind, "heads": args.heads,
-            "rows": len(lengths) + 1, "latent_rows": rows,
-            "out": [str(got.dtype), list(got.shape)],
-            "kernel_ms_median": med, "kernel_ms_min": min(ms),
-            "kernel_ms_max": max(ms), "events": len(ms),
-            "roofline_share": 100 * least_ms / med,
-            "max_abs_err_vs_oracle": err}), flush=True)
+    for into in (int(t) for t in args.into_answer.split(",")):
+        lengths = cell_lengths(args.mix, into)
+        if args.live:
+            every = len(lengths) // args.live
+            lengths = [t if k % every == 0 else 0
+                       for k, t in enumerate(lengths)]
+        q, pool, table, lens = inputs(lengths, args.heads, args.table_width,
+                                      args.seed)
+        check = jnp.asarray([0, len(lengths) // 2, len(lengths) - 1,
+                             len(lengths)])
+        want = here.latent_paged_attention_ref(q[check], pool, table[check],
+                                               lens[check], RANK, scale)
+        rows = sum(lengths)
+        # the least the chip could take: the slower of the FLOP and byte roofs
+        least_ms = 1e3 * max(2 * args.heads * (2 * RANK + 64) * rows / 197e12,
+                             2 * (RANK + 64) * rows / 819e9)
+        for k, (name, mod, plan) in enumerate(variants):
+            fn = mod.latent_paged_attention_pallas
+            if plan is not None:
+                fn = functools.partial(fn, plan=plan)
+            try:
+                got = jax.block_until_ready(fn(q, pool, table, lens, RANK,
+                                               scale))
+            except Exception as e:  # a plan the compiler refuses: say so
+                print(json.dumps({"variant": name, "error": str(e)[:300]}),
+                      flush=True)
+                continue
+            err = float(jnp.abs(got[check].astype(jnp.float32) - want).max())
+            trace_dir = os.path.join(args.out, f"trace{into}_{k}")
+            with jax.profiler.trace(trace_dir):
+                for _ in range(args.calls):
+                    got = fn(q, pool, table, lens, RANK, scale)
+                jax.block_until_ready(got)
+            ms = kernel_ms(trace_dir)
+            med = statistics.median(ms)
+            line = {
+                "variant": name, "into_answer": into,
+                "plan": plan or (list(rule) if mod is here else None),
+                "device": jax.devices()[0].device_kind, "heads": args.heads,
+                "rows": len(lengths) + 1, "latent_rows": rows,
+                "out": [str(got.dtype), list(got.shape)],
+                "kernel_ms_median": med, "kernel_ms_min": min(ms),
+                "kernel_ms_max": max(ms), "events": len(ms),
+                "roofline_share": 100 * least_ms / med,
+                "max_abs_err_vs_oracle": err}
+            if mod is here:    # another tree's plan may mean another form
+                line["keys_multiplied_over_live"] = here.keys_multiplied(
+                    lengths, plan or rule, PAGE) / rows
+            print(json.dumps(line), flush=True)
     return 0
 
 
