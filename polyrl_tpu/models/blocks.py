@@ -154,26 +154,43 @@ def _group_limited_topk(cfg, biased: jnp.ndarray) -> jnp.ndarray:
     """The k experts a token chooses from its choice scores ``biased`` [N,
     E]: ``n_group`` groups of consecutive experts, a group's score the sum
     of its two highest, the best ``topk_group`` groups kept, the k highest
-    among them: [N, k]."""
+    among them: [N, k] in ``lax.top_k``'s order (descending score, the
+    lower index first among equals), to the bit what ``lax.top_k`` would
+    choose, without its sort: on a TPU a ``top_k`` is a full key-and-index
+    sort of the last axis, whatever k. A group's two highest are its
+    maximum and, where that stands once, the maximum of what lies under
+    it; the k choices are k maxima, each masked out once taken
+    (``argmax`` takes the first of equals). The ``topk_group`` of
+    ``n_group`` scores stay a ``top_k``: a sort of 8 keys costs nothing."""
     n, e = biased.shape
     g = cfg.n_group
     if g > 1:
-        group = jnp.sum(jax.lax.top_k(biased.reshape(n, g, e // g), 2)[0],
-                        axis=-1)                                   # [N, g]
+        x = biased.reshape(n, g, e // g)
+        best = jnp.max(x, axis=-1, keepdims=True)
+        under = jnp.max(jnp.where(x < best, x, -jnp.inf), axis=-1)
+        # counted in float32: an integer sum over the lanes is 4 us a layer
+        twice = jnp.sum(x == best, axis=-1, dtype=jnp.float32) > 1
+        group = best[..., 0] + jnp.where(twice, best[..., 0], under)  # [N, g]
         _, keep = jax.lax.top_k(group, cfg.topk_group)
         kept = jnp.any(keep[:, :, None] == jnp.arange(g)[None, None, :],
                        axis=1)                                     # [N, g]
         biased = jnp.where(jnp.repeat(kept, e // g, axis=1), biased,
                            -jnp.inf)
-    return jax.lax.top_k(biased, cfg.num_experts_per_tok)[1]
+    chosen, at = [], jnp.arange(e)
+    for _ in range(cfg.num_experts_per_tok):
+        chosen.append(jnp.argmax(biased, axis=-1))
+        biased = jnp.where(at == chosen[-1][:, None], -jnp.inf, biased)
+    return jnp.stack(chosen, axis=-1)
 
 
 def _sigmoid_route(cfg, x: jnp.ndarray, lp: dict):
     """DeepSeek-V3's ``noaux_tc`` router on ``x`` [N, d]: scores ``s =
     sigmoid(x Wr)`` over all experts in float32; the choice is made on ``s
-    + bias`` (``_group_limited_topk``); the weights are the chosen ``s``
-    (without the bias) over their sum, times ``routed_scaling_factor``.
-    Returns (weights [N, k] float32, experts [N, k])."""
+    + bias`` (``_group_limited_topk``: by maxima, not by a sort); the
+    weights are the chosen ``s`` (without the bias) over their sum, times
+    ``routed_scaling_factor``. The choices stand in ``lax.top_k``'s order,
+    and the float32 sum adds them in that order. Returns (weights [N, k]
+    float32, experts [N, k])."""
     scores = jax.nn.sigmoid(jnp.dot(
         x, lp["router"], preferred_element_type=jnp.float32))
     top_i = _group_limited_topk(

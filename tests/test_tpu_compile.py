@@ -590,7 +590,13 @@ def test_ling_decode_step_compiles_for_v5e_within_memory(one_chip,
     with a gigabyte to spare, and the states are updated in place (no
     copy of a 270 MB state array among the temporaries: the six KDA
     layers' kernel takes the stack as input and output, and no fusion,
-    copy or select over a whole stack is left in the program)."""
+    copy or select over a whole stack is left in the program). The router
+    chooses without sorting its scores (``blocks._group_limited_topk``):
+    of the five sorts a sparse layer had, the two over the scores are
+    gone (the groups' ``[129, 8, 64]`` for a group's two best, the rows'
+    ``[129, 512]`` for the 8 choices), and 18 are left in the six layers:
+    a layer's ``top_k`` of 4 over its 8 group scores ``[129, 8]`` and
+    ``_moe_mlp``'s two ``argsort``s of the 1,032 choices by expert."""
     from polyrl_tpu.models import decoder
 
     cfg = decoder.get_config("ling-3.0-flash-share4")
@@ -640,6 +646,10 @@ def test_ling_decode_step_compiles_for_v5e_within_memory(one_chip,
             if " = " in line]
     assert not [op for op in made if re.match(
         r"f32\[129,32,128,128\]\S* (copy|fusion|select)\(", op)]
+    sorts = [op.split(" sort(")[0] for op in made if " sort(" in op]
+    assert not [op for op in sorts
+                if re.search(r"f32\[129,(8,64|512)\]", op)]
+    assert len(sorts) <= 18
 
 
 # -- ZAYA1-8B's cell (benchmark/configs/zaya1-8b.json) ----------------------
