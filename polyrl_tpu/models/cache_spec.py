@@ -56,6 +56,19 @@ layer's for the whole model. What a layer hands to later layers of the
 same step (``ssm_mem``'s ``m``, as a router's carried latent) is cached
 nowhere.
 
+A layer of the plan is a layer of WEIGHTS. What a token keeps for it may
+be several passes' worth: a looped model (``ut_steps`` > 1) runs the plan
+that many times a token, and pass ``t``'s layer ``l`` attends what pass
+``t``'s layer ``l`` kept. ``passes`` is the one place that says so: a
+PAGED layer's pools are then ``passes`` runs of the engine's
+``num_pages`` pages (``Paged.passes``), a token's bytes and a page's
+bytes ``passes`` times the one-pass figure, and the pass's index becomes
+a page offset (``pass_offset``: ``t * num_pages``, which
+``hybrid.paged_decode`` and ``hybrid.prefill`` add to the page numbers
+they hand a layer; the kernels find their pages through the table as
+ever). The engine hands out and books ``num_pages`` LOGICAL pages and
+never learns that a model loops.
+
 ``CBEngine`` asks two questions of a model's layers (ARCHITECTURE.md,
 "Cache specification"). Does a sequence keep anything outside pages
 (``is_stateful``)? A state in a slot has no page boundary to snapshot at,
@@ -97,13 +110,17 @@ class LayerPlan:
 
 @dataclasses.dataclass(frozen=True)
 class Paged:
-    """``arrays`` pools of ``[heads, pages, page_size, width]`` a layer."""
+    """``arrays`` pools of ``[heads, passes * pages, page_size, width]`` a
+    layer: a token keeps ``passes`` rows in each, one a pass of a looped
+    model (``passes``), at the same place in ``passes`` pages that lie
+    ``pages`` apart (``pass_offset``)."""
     arrays: int
     heads: int
     width: int
+    passes: int = 1
 
     def values_per_token(self) -> int:
-        return self.arrays * self.heads * self.width
+        return self.arrays * self.heads * self.width * self.passes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -211,11 +228,29 @@ LAYER_TYPES = {"full_attention": "gqa", "sliding_attention": "gqa_window"}
 
 
 def is_uniform(cfg) -> bool:
-    """Every layer alike and ``gqa``: the stacked-scan decoder."""
+    """Every layer alike and ``gqa``, run once a token between two norms a
+    layer: the stacked-scan decoder."""
     return (not cfg.layer_group_size and not cfg.kv_lora_rank
             and not cfg.cca_time0 and not cfg.mb_per_layer
             and not cfg.layer_types
-            and not (cfg.num_experts and cfg.first_k_dense_replace))
+            and not (cfg.num_experts and cfg.first_k_dense_replace)
+            and cfg.ut_steps == 1 and not cfg.sandwich_norm)
+
+
+def passes(cfg) -> int:
+    """Times a token runs the plan (a looped model's ``ut_steps``; 1 for
+    every other), and so the passes' worth of keys a token keeps in a paged
+    layer's pages (module docstring): ``layer_cache`` sets ``Paged.passes``
+    from it, and ``paged_bytes_per_token`` and ``make_pools`` follow."""
+    return cfg.ut_steps
+
+
+def pass_offset(cfg, pool, t):
+    """What pass ``t`` adds to a logical page's number to find its own
+    page in ``pool`` (an array of ``make_pools``): the pool is ``passes``
+    runs of the engine's ``num_pages`` pages, pass ``t``'s run the
+    ``t``-th, so logical page 0 is pass ``t``'s null page there."""
+    return t * (pool.shape[1] // passes(cfg))
 
 
 def experts_held(cfg) -> tuple[int, int]:
@@ -291,7 +326,14 @@ def layer_cache(cfg, plan: LayerPlan, dtype=None
     from this module's types)."""
     from polyrl_tpu.models.mixers import MIXERS
 
-    return MIXERS[plan.mixer].cache(cfg, plan, dtype or cfg.dtype)
+    c = MIXERS[plan.mixer].cache(cfg, plan, dtype or cfg.dtype)
+    if passes(cfg) == 1:
+        return c
+    if not isinstance(c, Paged):
+        raise NotImplementedError(
+            f"a {plan.mixer} layer run {passes(cfg)} times a token: only "
+            "what lies in pages alone is kept a pass")
+    return dataclasses.replace(c, passes=passes(cfg))
 
 
 def cache_spec(cfg, dtype=None) -> tuple:
@@ -381,8 +423,8 @@ def make_pools(cfg, num_pages: int, page_size: int, slots: int = 0,
     for c in spec:
         pages, slot = paged_part(c), slot_part(c)
         if pages is not None:
-            pool = [jnp.zeros((pages.heads, num_pages, page_size,
-                               pages.width), dtype)
+            pool = [jnp.zeros((pages.heads, pages.passes * num_pages,
+                               page_size, pages.width), dtype)
                     for _ in range(pages.arrays)]
             paged.append(pool[0] if pages.arrays == 1 else tuple(pool))
         if isinstance(slot, Ring):

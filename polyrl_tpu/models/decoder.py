@@ -176,6 +176,13 @@ class ModelConfig:
     num_heads_per_layer: tuple | None = None
     rope_parameters: tuple | None = None
     attn_head_gate: bool = False
+    # a looped model (``ouro``; ``models/hybrid.py``): the one stack of
+    # ``num_layers`` layers runs ``ut_steps`` times a token, the final norm
+    # between passes, and each pass keeps keys and values of its own
+    # (``cache_spec.passes``); ``sandwich_norm``: a second RMSNorm on each
+    # sublayer's OUTPUT before it joins the residual stream
+    ut_steps: int = 1
+    sandwich_norm: bool = False
     dtype: Any = jnp.bfloat16
 
     @property
@@ -513,6 +520,31 @@ PRESETS["mixed-tiny"] = ModelConfig(
                 original_max_position_embeddings=32,
                 attention_factor=1.2772588722239782))),
         ("sliding_attention", RopeParameters(100.0, 1.0))),
+)
+
+
+# Ouro-2.6B (HF config: ByteDance/Ouro-2.6B, model_type ouro;
+# ``hf_loader.ouro_config`` of the published keys gives this, tested): a
+# looped language model. ONE stack of 48 layers of plain multi-head
+# attention (16 heads of 128, a K/V head a query head, rope at 1e6 on all
+# of a head's columns) and a dense SwiGLU MLP of 5,632, four RMSNorms a
+# layer (before and after each sublayer), run ``total_ut_steps`` = 4 times a
+# token with the final norm between passes; pass t's layer l attends the
+# keys pass t's layer l kept, so a token keeps 4 x 48 K/V pairs; an exit
+# gate a pass (``exit_gate``) that at the published threshold 1.0 enters
+# no output; an untied head over 49,152 rows
+PRESETS["ouro-2.6b"] = ModelConfig(
+    vocab_size=49152, hidden_size=2048, intermediate_size=5632,
+    num_layers=48, num_heads=16, num_kv_heads=16, head_dim=128,
+    rope_theta=1000000.0, rms_norm_eps=1e-6, max_position_embeddings=65536,
+    ut_steps=4, sandwich_norm=True,
+)
+# test-size model of the same family: 3 layers run 3 times, 4 heads of 16
+PRESETS["ouro-tiny"] = ModelConfig(
+    vocab_size=512, hidden_size=64, intermediate_size=128, num_layers=3,
+    num_heads=4, num_kv_heads=4, head_dim=16, rope_theta=10000.0,
+    rms_norm_eps=1e-6, max_position_embeddings=2048,
+    ut_steps=3, sandwich_norm=True,
 )
 
 

@@ -214,12 +214,51 @@ def laguna_config(hf: dict, dtype=jnp.bfloat16) -> decoder.ModelConfig:
         rope_parameters=ropes, dtype=dtype)
 
 
+def ouro_config(hf: dict, dtype=jnp.bfloat16) -> decoder.ModelConfig:
+    """A ``ModelConfig`` from an ``ouro`` config.json (ByteDance/Ouro-2.6B):
+    every key the published file has that the decoder reads. The stack runs
+    ``total_ut_steps`` times a token and the LAST pass is served, which is
+    what ``early_exit_threshold`` 1.0 says; every layer is full attention
+    (``layer_types``, ``sliding_window`` null); the four norms a layer have
+    no published key and are the family's. The checkpoint's tensors have
+    no key map yet (``load_hf_params`` says so)."""
+    steps = int(hf["total_ut_steps"])
+    if steps < 1:
+        raise ValueError(f"total_ut_steps {steps}: a token runs the stack at "
+                         "least once")
+    if float(hf.get("early_exit_threshold", 1.0)) < 1.0:
+        raise NotImplementedError(
+            f"early_exit_threshold {hf['early_exit_threshold']} < 1: rows of "
+            "one step that leave the loop at different passes are not built "
+            "(every token is served by the last pass; ROADMAP.md Queue 2)")
+    kinds = set(hf.get("layer_types") or ["full_attention"])
+    if (kinds != {"full_attention"} or hf.get("use_sliding_window")
+            or hf.get("sliding_window") or hf.get("rope_scaling")):
+        raise NotImplementedError(
+            "ouro with window layers or a scaled rope: neither is written "
+            f"(layer_types {sorted(kinds)})")
+    return decoder.ModelConfig(
+        vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        num_layers=hf["num_hidden_layers"],
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"], head_dim=hf["head_dim"],
+        rope_theta=float(hf["rope_theta"]),
+        rms_norm_eps=float(hf["rms_norm_eps"]),
+        tie_word_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        max_position_embeddings=hf["max_position_embeddings"],
+        ut_steps=steps, sandwich_norm=True, dtype=dtype)
+
+
 def config_from_hf(ckpt_dir: str, dtype=jnp.bfloat16) -> decoder.ModelConfig:
     """Build a ModelConfig from the checkpoint's config.json (llama/qwen2/
     qwen3 architectures; ``zaya``: ``zaya_config``; ``phi4flash``:
-    ``phi4flash_config``; ``laguna``: ``laguna_config``)."""
+    ``phi4flash_config``; ``laguna``: ``laguna_config``; ``ouro``:
+    ``ouro_config``)."""
     with open(os.path.join(ckpt_dir, "config.json")) as f:
         hf = json.load(f)
+    if hf.get("model_type") == "ouro":
+        return ouro_config(hf, dtype)
     if hf.get("model_type") == "laguna":
         return laguna_config(hf, dtype)
     if hf.get("model_type") == "zaya":
@@ -310,6 +349,10 @@ def load_hf_params(ckpt_dir: str, cfg: decoder.ModelConfig | None = None,
         raise NotImplementedError(
             "no key map for a laguna checkpoint yet: write it from the "
             "published model.safetensors.index.json (ROADMAP.md Queue 2)")
+    if cfg.ut_steps > 1 or cfg.sandwich_norm:
+        raise NotImplementedError(
+            "no key map for an ouro (looped) checkpoint yet: write it from "
+            "the published model.safetensors.index.json (ROADMAP.md Queue 2)")
     dtype = dtype or cfg.dtype
     np_dtype = jnp.dtype(dtype)
 

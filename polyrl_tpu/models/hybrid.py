@@ -18,12 +18,19 @@ cross-decoder's layers read, gated memory units, LayerNorm with a bias;
 ``mixers/ssm.py``, ``mixers/diff.py``); and Laguna's (``laguna``: rope'd
 softmax GQA in every layer, full layers in pages and window layers in
 rings with a head count and a rope a kind, a softmax router's scaled
-weights beside a shared expert; ``mixers/gqa.py``).
+weights beside a shared expert; ``mixers/gqa.py``); and a looped model's
+(``ouro``: ONE stack of ``gqa`` layers with a norm after each sublayer too,
+run ``ut_steps`` times a token with the final norm between passes: the
+passes are a scan of the program around the layers below,
+``_pass_indices``, and a pass finds its own pages by an offset,
+``cache_spec.pass_offset``).
 
 One block a kind, parameters stacked per kind::
 
+    params["exit_gate"] = {w [d, 1], b [1]}            a looped model's
     params["layers"] = {
       "attn_norm", "mlp_norm": [L, d]                  every layer
+      "attn_post_norm", "mlp_post_norm": [L, d]        ``sandwich_norm``
       <a mixer's stack>: its module's docstring, with what its family
                          keeps beside the stacks ([L, ..]: residual scales,
                          norm biases)
@@ -113,6 +120,8 @@ def init_params(rng: jax.Array, cfg) -> dict:
     draw = _Draw(rng, cfg)
     norm, ones = draw.normal, draw.ones
     layers: dict = {"attn_norm": ones(L, d), "mlp_norm": ones(L, d)}
+    if cfg.sandwich_norm:
+        layers.update(attn_post_norm=ones(L, d), mlp_post_norm=ones(L, d))
     for rec in MIXERS.values():
         if rec.init is not None and n[rec.stack] and rec.stack not in layers:
             layers.update(rec.init(cfg, n[rec.stack], draw))
@@ -156,6 +165,12 @@ def init_params(rng: jax.Array, cfg) -> dict:
         params["final_norm_bias"] = jnp.zeros((d,), cfg.dtype)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = norm(d, cfg.vocab_size)
+    if cache_spec.passes(cfg) > 1:
+        # lambda_t = sigmoid(h_t w + b), a pass's share of the exit; it
+        # enters an output only where a token leaves the loop early, which
+        # no path here does (``hf_loader.ouro_config``)
+        params["exit_gate"] = {"w": norm(d, 1),
+                               "b": jnp.zeros((1,), cfg.dtype)}
     return params
 
 
@@ -207,6 +222,14 @@ def _residual(x, out, res):
             + a_o * out.astype(jnp.float32) + b_o).astype(x.dtype)
 
 
+def _post(cfg, layers: dict, name: str, out, l: int):
+    """A sublayer's output under its sandwich norm ``name``, as it is for
+    a model without one."""
+    if name not in layers:
+        return out
+    return norm(layers, name, out, cfg.rms_norm_eps, l)
+
+
 def _layer_params(cfg, layers: dict, l: int) -> tuple[dict, dict]:
     """(mixer weights, MLP weights) of layer ``l``: slices of the stacks
     of its kinds; the routed experts stay whole stacks (``moe_mm`` takes
@@ -246,6 +269,7 @@ def _mlp(cfg, x, layers, l, mlp_lp, valid, carry=None):
             gate = jax.nn.silu(mm(h, mlp_lp["w_gate"]).astype(jnp.float32))
             out = mm(gate.astype(h.dtype) * mm(h, mlp_lp["w_up"]),
                      mlp_lp["w_down"])
+            out = _post(cfg, layers, "mlp_post_norm", out, l)
             return _residual(x, out, res), None, carry
         shape = h.shape
         rows = h.reshape(-1, shape[-1])
@@ -257,7 +281,8 @@ def _mlp(cfg, x, layers, l, mlp_lp, valid, carry=None):
                     cfg, rows, mlp_lp, carry.reshape(rows.shape[0], -1))
             carry = latent.reshape(carry.shape)
         out, load = _moe_mlp(cfg, rows, mlp_lp, v, j, route)
-        return _residual(x, out.reshape(shape), res), load, carry
+        out = _post(cfg, layers, "mlp_post_norm", out.reshape(shape), l)
+        return _residual(x, out, res), load, carry
 
 
 def _form(p, form: str):
@@ -282,8 +307,57 @@ def _keepers(cfg) -> tuple[list, list]:
     return pages, slots
 
 
+def _pass_indices(cfg):
+    """What a looped model's loop over its passes scans over: the passes'
+    indices as an ARRAY. Not ``fori_loop``'s own counter: on a v5e the
+    program that read ``t > 0`` (``_next_pass``) off that counter put the
+    first pass under the final norm too, in the step and in the chunk, and
+    left the reference by 0.64 where this one leaves it by 0.008; the CPU
+    runs both alike (my chip runs, PR 51: ``PERF.md`` section 6)."""
+    return jnp.arange(cache_spec.passes(cfg), dtype=jnp.int32)
+
+
+def _pass_offset(cfg, paged, t):
+    """What pass ``t`` adds to a page's number in the pools ``paged``."""
+    return cache_spec.pass_offset(cfg, jax.tree_util.tree_leaves(paged)[0], t)
+
+
+def _next_pass(cfg, params, x, t):
+    """What pass ``t`` of a looped model starts from: the pass before's
+    output under the model's final norm, the embedding for the first. (The
+    last pass's output meets that norm in the head.)"""
+    with jax.named_scope("ut_norm"):
+        return jnp.where(t > 0, norm(params, "final_norm", x,
+                                     cfg.rms_norm_eps), x)
+
+
 def run_sequence(params, cfg, x, positions, valid, states=None,
                  prefix=None, remat: bool = False):
+    """``run_plan`` for every pass of the model (``cache_spec.passes``):
+    the plan once for all but a looped model, whose passes are a scan over
+    ``run_plan`` with the final norm between them, with neither a state
+    nor a prefix; what its chunk keeps in pages comes stacked, a pass a
+    leading row."""
+    if cache_spec.passes(cfg) == 1:
+        return run_plan(params, cfg, x, positions, valid, states, prefix,
+                        remat)
+    if states is not None or prefix is not None:
+        raise NotImplementedError("a looped model's passes over a state or "
+                                  "a prefix: hybrid.prefill runs them")
+
+    def one_pass(x, t):
+        x = _next_pass(cfg, params, x, t)
+        with jax.named_scope("ut_pass"):
+            x, _states, kept = run_plan(params, cfg, x, positions, valid,
+                                        remat=remat)
+        return x, kept
+
+    x, kept = jax.lax.scan(one_pass, x, _pass_indices(cfg))
+    return x, [], kept
+
+
+def run_plan(params, cfg, x, positions, valid, states=None,
+             prefix=None, remat: bool = False):
     """Every layer over whole (chunks of) sequences ``x`` [B, T, d] with
     right padding (``valid`` [B, T]): the trainer's forward and the
     engine's prefill. ``states``: for each layer that keeps a slot, in
@@ -315,6 +389,7 @@ def run_sequence(params, cfg, x, positions, valid, states=None,
             out, kept = _form(p, "sequence")(
                 cfg, p, mixer_lp, h_in,
                 Chunk(positions, valid, st, pre, hands))
+            out = _post(cfg, layers, "attn_post_norm", out, l)
             x = _residual(x, out, _res(layers, "attn_res", l))
             x, _load, latent = _mlp(cfg, x, layers, l, mlp_lp, valid,
                                     hands["latent"])
@@ -402,22 +477,50 @@ def prefill(params, cfg, ids, lens, prefix_len, pools, prefix_page_ids,
     keep_pages, keep_slot = _keepers(cfg)
     states = [rec.read_slot(cfg, rows, at)
               for rows, rec in zip(state, keep_slot)]
-    prefix = None
-    if prefix_page_ids.shape[1]:
+
+    def gathered(paged, off=None):
+        """What the prefix's pages hold, a paged layer; None for none.
+        ``off``: what a pass adds to a page's number to find its own."""
+        if not prefix_page_ids.shape[1]:
+            return None
         pre_len = jnp.broadcast_to(prefix_len, (b,))
         prefix = []
         for pool, rec in zip(paged, keep_pages):
             with jax.named_scope(rec.pages_scope):
-                prefix.append((_gather_prefix(rec, pool, prefix_page_ids),
-                               pre_len))
-    x = params["embed"][ids]
-    x, new_states, kept = run_sequence(params, cfg, x, positions, valid,
+                prefix.append((_gather_prefix(
+                    rec, pool, prefix_page_ids if off is None
+                    else prefix_page_ids + off), pre_len))
+        return prefix
+
+    def scattered(paged, kept, off=None):
+        written = []
+        for pool, new, rec in zip(paged, kept, keep_pages):
+            with jax.named_scope(rec.pages_scope):
+                written.append(_scatter_chunk(
+                    rec, pool, page_ids if off is None else page_ids + off,
+                    new, valid))
+        return tuple(written)
+
+    if cache_spec.passes(cfg) == 1:
+        prefix = gathered(paged)
+        x = params["embed"][ids]
+        x, new_states, kept = run_plan(params, cfg, x, positions, valid,
                                        states, prefix)
-    written = []
-    for pool, new, rec in zip(paged, kept, keep_pages):
-        with jax.named_scope(rec.pages_scope):
-            written.append(_scatter_chunk(rec, pool, page_ids, new, valid))
-    paged = tuple(written)
+        paged = scattered(paged, kept)
+    else:
+        def one_pass(carry, t):
+            """Pass ``t`` over the chunk, from its own pages into them."""
+            x, paged = carry
+            off = _pass_offset(cfg, paged, t)
+            x = _next_pass(cfg, params, x, t)
+            with jax.named_scope("ut_pass"):
+                x, _states, kept = run_plan(params, cfg, x, positions, valid,
+                                            prefix=gathered(paged, off))
+                return (x, scattered(paged, kept, off)), None
+
+        new_states = []
+        (x, paged), _ = jax.lax.scan(one_pass, (params["embed"][ids], paged),
+                                     _pass_indices(cfg))
     written = []
     for rows, new, was, rec in zip(state, new_states, states, keep_slot):
         with jax.named_scope(rec.slot_scope):
@@ -483,6 +586,9 @@ def _scatter_kv(pool, page_ids, kv):
 # (row, choice) of live rows whether or not its expert is held here
 MOE_LOAD = ("moe_routed", "moe_experts_hit", "moe_load_max")
 MOE_CHOICES = "moe_choices"
+# a looped model's: live rows times the passes a step ran, and the keys a
+# step's rows attend over times the passes that read a cache of their own
+UT_LOAD = ("ut_passes", "kv_pass_rows_read")
 
 
 def load_names(cfg) -> tuple[str, ...]:
@@ -499,6 +605,8 @@ def load_names(cfg) -> tuple[str, ...]:
     for rec in MIXERS.values():
         if rec.name in kinds or (routed and rec.counts_in_routed):
             names += [n for n in rec.counts if n not in names]
+    if cache_spec.passes(cfg) > 1:
+        names += UT_LOAD
     return tuple(names)
 
 
@@ -528,7 +636,10 @@ def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
     """``decoder.forward_paged_decode`` for a model of several kinds of
     layer: one token a slot. A row without a request leaves its state
     rows as they are and writes what it would keep in pages to the null
-    page."""
+    page. A looped model's passes are a loop of the program around the
+    plan's layers, the pools carried through it and written in place; pass
+    ``t`` reads and writes the pages ``cache_spec.pass_offset`` past the
+    table's."""
     layers = params["layers"]
     plan = cache_spec.layer_plan(cfg)
     paged, state = list(pools[0]), list(pools[1])
@@ -550,26 +661,51 @@ def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
     for kind in dict.fromkeys(p.mixer for p in plan):
         if MIXERS[kind].per_step is not None:
             ctx.per[kind] = MIXERS[kind].per_step(cfg, ctx)
-    for l, p in enumerate(plan):
-        at_pages, at_slot = index[l]
-        mixer_lp, mlp_lp = _layer_params(cfg, layers, l)
-        h_in = norm(layers, "attn_norm", x, cfg.rms_norm_eps, l)
-        own = dataclasses.replace(
-            ctx, pages=None if at_pages is None else paged[at_pages],
-            slot=None if at_slot is None else state[at_slot],
-            stack=layers[MIXERS[p.mixer].stack], index=kind_index(cfg)[l][0],
-            hands=hands)
-        out, kept = _form(p, "step")(cfg, p, mixer_lp, h_in, own)
-        if kept.pages is not None:
-            paged[at_pages] = kept.pages
-        if kept.slot is not None:
-            state[at_slot] = kept.slot
-        x = _residual(x, out, _res(layers, "attn_res", l))
-        x, moe, latent = _mlp(cfg, x, layers, l, mlp_lp, active,
-                              hands["latent"])
-        hands = {**hands, **kept.hands, "latent": latent}
-        if moe is not None:
-            load.vector = load.vector.at[:len(MOE_LOAD)].add(moe)
-            load.add(MOE_CHOICES, n_live * cfg.num_experts_per_tok)
+
+    def once(x, paged, state, ctx, hands):
+        """The plan's layers, once: (x, paged, state)."""
+        for l, p in enumerate(plan):
+            at_pages, at_slot = index[l]
+            mixer_lp, mlp_lp = _layer_params(cfg, layers, l)
+            h_in = norm(layers, "attn_norm", x, cfg.rms_norm_eps, l)
+            own = dataclasses.replace(
+                ctx, pages=None if at_pages is None else paged[at_pages],
+                slot=None if at_slot is None else state[at_slot],
+                stack=layers[MIXERS[p.mixer].stack],
+                index=kind_index(cfg)[l][0], hands=hands)
+            out, kept = _form(p, "step")(cfg, p, mixer_lp, h_in, own)
+            if kept.pages is not None:
+                paged[at_pages] = kept.pages
+            if kept.slot is not None:
+                state[at_slot] = kept.slot
+            out = _post(cfg, layers, "attn_post_norm", out, l)
+            x = _residual(x, out, _res(layers, "attn_res", l))
+            x, moe, latent = _mlp(cfg, x, layers, l, mlp_lp, active,
+                                  hands["latent"])
+            hands = {**hands, **kept.hands, "latent": latent}
+            if moe is not None:
+                load.vector = load.vector.at[:len(MOE_LOAD)].add(moe)
+                load.add(MOE_CHOICES, n_live * cfg.num_experts_per_tok)
+        return x, paged, state
+
+    if cache_spec.passes(cfg) == 1:
+        x, paged, state = once(x, paged, state, ctx, hands)
+    else:
+        def one_pass(carry, t):
+            x, paged, load.vector = carry
+            off = _pass_offset(cfg, paged, t)
+            x = _next_pass(cfg, params, x, t)
+            with jax.named_scope("ut_pass"):
+                x, paged, _state = once(
+                    x, list(paged), state,
+                    dataclasses.replace(ctx, page_table=page_table + off,
+                                        write_page=write_page + off),
+                    hands)
+            load.add(UT_LOAD[0], n_live)
+            load.add(UT_LOAD[1], rows_read)
+            return (x, tuple(paged), load.vector), None
+
+        (x, paged, load.vector), _ = jax.lax.scan(
+            one_pass, (x, tuple(paged), load.vector), _pass_indices(cfg))
     return ((head_fn or _head)(cfg, params, x),
             (tuple(paged), tuple(state)), load.vector)

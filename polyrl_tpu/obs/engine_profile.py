@@ -91,6 +91,12 @@ emission or iteration, never once a token, all on this profiler's clock:
   ``paged_rows_read`` — for a model of ``gqa`` layers beside other kinds
   (Laguna: ``mixers/gqa.py``), keys of the full layers' pages read, summed
   over them (its window layers move ``window_rows_read``);
+  ``ut_passes`` / ``kv_pass_rows_read`` — for a looped model (``ut_steps``
+  passes of one stack a token; ``hybrid.UT_LOAD``), counted by the landed
+  steps on the device: live rows times the passes a step ran (over
+  ``row_steps_done``: the passes a token, while no row ends), and the keys
+  a step's rows attend over times the passes, each of which reads a cache
+  of its own (0 for every other model);
   ``decode_dispatches_cold`` — those of the dispatches enqueued with
   NOTHING outstanding (the device had run dry: an engine that keeps its
   run-ahead does it once a burst, one that drains before every dispatch
@@ -177,6 +183,8 @@ CUMULATIVE_KEYS = (
     "shared_kv_rows_read",
     "window_rows_read",
     "paged_rows_read",
+    "ut_passes",
+    "kv_pass_rows_read",
     "device_busy_s", "loop_wall_s", "loop_host_s",
     *PHASE_KEYS.values(), "emit_wait_s", "dispatches_emitted",
     "landing_gap_hist", "stalls", "programs_built", "build_s")

@@ -2170,7 +2170,11 @@ class CBEngine:
         while the free list is roomy: its even share of half the free pages,
         a share that shrinks to the bare need as the pool fills (each upload
         of the table is a fresh device buffer, and a decode window wants few
-        of them: PERF.md section 6, PR 34).
+        of them: PERF.md section 6, PR 34). The requests that wait to be
+        taken in share too: a row that decodes alone while the others of
+        its batch stand in the queue (they arrive during its prefill) took
+        half the pool, and they yielded mid-answer for want of what it held
+        ahead (PERF.md section 6, PR 51).
         Where the pool has no more (after what ``_try_alloc`` tries: the
         landed finishers, spill, eviction) the youngest row yields
         (``_yield_row``) and the count is made again. The new ids go into
@@ -2201,7 +2205,8 @@ class CBEngine:
             # (its even share of half the free pages), so that the table
             # goes up a few times a pool's filling and not with every
             # dispatch; as the pool fills the share shrinks to the bare need
-            stride = self.allocator.free_count // (2 * rows.size)
+            sharers = rows.size + len(self._pending) + self._queue.qsize()
+            stride = self.allocator.free_count // (2 * sharers)
             wide = short(ahead + max(1, stride) * self.page_size)
             pages = self.allocator.alloc(int(wide.sum()))
             if pages is not None:

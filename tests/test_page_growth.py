@@ -68,6 +68,14 @@ def mixed():
     return cfg, decoder.init_params(jax.random.PRNGKey(0), cfg)
 
 
+@pytest.fixture(scope="module")
+def looped():
+    """A looped model: one stack of ``gqa`` layers run three times a token,
+    a page holding every pass's keys (``tests/test_looped.py``)."""
+    cfg = decoder.get_config("ouro-tiny", dtype=jnp.float32)
+    return cfg, decoder.init_params(jax.random.PRNGKey(0), cfg)
+
+
 def _engine(model, **kw):
     cfg, params = model
     opts = dict(max_slots=4, page_size=PS, max_seq_len=128,
@@ -268,6 +276,41 @@ def test_the_table_goes_up_seldom_while_the_pool_is_roomy(dense):
     _books_balance(eng, 63)
 
 
+def test_a_row_that_starts_alone_leaves_the_waiting_their_share(dense):
+    """One request is taken in and decodes alone while five more of its
+    batch stand in the engine's queue (they arrived during its prefill):
+    the pages it takes ahead are its share among the six, not half the
+    pool, and the six then run to their budgets without a yield."""
+    eng = _engine(dense, max_slots=6, num_pages=50, max_seq_len=256,
+                  pipeline_depth=2, steps_per_dispatch=8)
+    try:
+        first, *rest = _prompts(6, 15)      # a token short of two pages
+        outs = [eng.submit("r0", first, _greedy(200))]
+        real = eng._prefill_request
+
+        def while_it_prefills(*args, **kw):
+            outs.extend(eng.submit(f"r{i + 1}", p, _greedy(40))
+                        for i, p in enumerate(rest))
+            eng._prefill_request = real
+            return real(*args, **kw)
+
+        eng._prefill_request = while_it_prefills
+        eng._loop_iter()        # its prefill, then its first dispatch
+        assert eng._queue.qsize() == 5 and eng._active.sum() == 1
+        # 47 pages free: a share of 47 // 12 = 3 pages ahead of the bare
+        # need of one; alone it took 47 // 2, and the five that need 25
+        # more between them found 24
+        assert np.count_nonzero(eng._page_table[0]) <= 2 + 1 + 3 + 1
+        eng.start()
+        for q, n in zip(outs, [200] + [40] * 5):
+            toks, _lps, fins, ends = _drain(q)
+            assert len(toks) == n and ends == 1 and fins[-1]
+        assert eng.profiler.counters()["slot_yields"] == 0
+    finally:
+        eng.stop()
+    _books_balance(eng, 49)
+
+
 def test_no_row_is_given_pages_past_its_budget(dense):
     eng = _engine(dense, pipeline_depth=8, steps_per_dispatch=8)
     try:
@@ -298,14 +341,17 @@ def _run(model, num_pages, budget=60, n=4, length=12, **kw):
 
 
 @pytest.mark.parametrize("kind", ["cached", "recomputed", "spec",
-                                  "latent-cached", "latent-recomputed"])
+                                  "latent-cached", "latent-recomputed",
+                                  "looped-cached", "looped-recomputed"])
 def test_a_pool_too_small_makes_the_youngest_yield_and_come_back(
         request, dense, kind):
-    """The dense model, and (``latent-``) the model whose cache is a
-    latent pool in every layer: a page is a page whatever it holds."""
+    """The dense model, (``latent-``) the model whose cache is a latent
+    pool in every layer and (``looped-``) the one whose page holds three
+    passes' keys: a page is a page whatever it holds."""
+    family, _, how = kind.rpartition("-")
     opts = {"cached": {}, "recomputed": {"enable_prefix_cache": False},
-            "spec": {"spec_tokens": 2}}[kind.removeprefix("latent-")]
-    model = request.getfixturevalue("latent") if "latent" in kind else dense
+            "spec": {"spec_tokens": 2}}[how]
+    model = request.getfixturevalue(family) if family else dense
     # 4 rows x (12 + 60 tokens) write 36 pages; 19 are there
     tight, c_tight, eng = _run(model, 20, **opts)
     roomy, c_roomy, _ = _run(model, 64, **opts)

@@ -969,3 +969,132 @@ def test_mixed_prefill_chunk_compiles_for_v5e_within_memory(one_chip,
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert live < 14.5 * 10**9
     assert m.temp_size_in_bytes < 1.5 * 10**9
+
+
+# -- Ouro-2.6B's cell (benchmark/configs/ouro-2.6b.json) -----------------------
+
+
+def _looped_shapes(one_chip, s, n_pages, page):
+    from polyrl_tpu.models import decoder
+
+    cfg = decoder.get_config("ouro-2.6b")
+
+    def shapes(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = shapes(jax.eval_shape(
+        lambda: decoder.init_params(jax.random.PRNGKey(0), cfg)))
+    pools = shapes(jax.eval_shape(
+        lambda: decoder.make_paged_pools(cfg, n_pages, page, slots=s + 1)))
+    return cfg, params, pools
+
+
+LOOPED_PAGES = 97
+
+
+def test_looped_decode_step_holds_the_layers_once_and_compiles_for_v5e(
+        one_chip, chip_precision, on_tpu):
+    """The cell's whole decode program: 8 fused steps of 4 passes of the
+    48 layers at 7 rows (6 slots and the sink), the 96 pools donated, the
+    token drawn inside the untied head. The passes are a loop of the
+    program: the lowered text holds ONE write and ONE attention kernel a
+    layer of the stack (at 16 K/V heads under one query head each:
+    Mosaic's word on it), not four. Weights (5.34 GB), the pools (9.76 GB)
+    and everything the step holds at once fit a 16 GB chip; nothing the
+    optimised program writes is as large as a pool but the write kernel's
+    own in-place result, and of the 96 pools at most two are moved (XLA's
+    memory-space assignment takes a 102 MB pool into VMEM for a layer's
+    kernels and moves it back, as PR 47 found it do a ring)."""
+    from polyrl_tpu.models import decoder
+
+    s, width, page = 7, 72, 64
+    cfg, params, pools = _looped_shapes(one_chip, s, LOOPED_PAGES, page)
+    assert pools[1] == () and len(pools[0]) == 48
+    assert pools[0][0][0].shape == (16, 4 * LOOPED_PAGES, page, 128)
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(params, paged, state, rng, table, lens, last, active, temps):
+        def body(carry, _):
+            paged, state, rng, lens, last = carry
+            rng, sub = jax.random.split(rng)
+            head = functools.partial(decoder.head_and_sample, rng=sub,
+                                     temps=temps)
+            (tok, logp), (paged, state), load = decoder.forward_paged_decode(
+                params, cfg, last, lens, (paged, state), table, lens,
+                active=active, head_fn=head)
+            return (paged, state, rng, lens + 1, tok), (tok, logp, load)
+        return jax.lax.scan(body, (paged, state, rng, lens, last), None,
+                            length=8)
+
+    lowered = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, pools[0], pools[1], arg((2,), jnp.uint32),
+        arg((s, width), jnp.int32), arg((s,), jnp.int32),
+        arg((s,), jnp.int32), arg((s,), jnp.bool_),
+        arg((s,), jnp.float32))
+    text = lowered.as_text()
+    # the fused steps' loop and the passes' loop; the three kernels' bodies
+    # once each (a layer calls them); a layer's 4 projections' and MLP's
+    # products once a layer of the stack
+    assert text.count("stablehlo.while") == 2
+    assert text.count("tpu_custom_call") == 3
+    products = text.count("stablehlo.dot_general")
+    assert 48 * 4 <= products < 2 * 48 * 4
+    compiled = lowered.compile()
+    m = compiled.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert 15.0e9 < live < 15.3e9
+    assert m.temp_size_in_bytes < 64 * 2**20
+    text = compiled.as_text()
+    # a write and an attention a layer, the head: ONCE, under the loop
+    assert text.count("tpu_custom_call") == 2 * 48 + 1
+    pool = 16 * 4 * LOOPED_PAGES * 64 * 128    # a layer's K (or V)
+    plumbing = re.compile(r"= \(?\w+\[[\d,]*\]\S* (while|tuple|"
+                          r"get-tuple-element|bitcast|custom-call|"
+                          r"copy-start|copy-done)\(")
+    made = [(n, line) for n, line in _written(text)
+            if n >= pool and not plumbing.search(line)]
+    assert not [line[:200] for _n, line in made]
+    moved = [line for n, line in _written(text)
+             if n >= pool and " copy-done(" in line]
+    assert len(moved) <= 2, [line[:200] for line in moved]
+
+
+@pytest.mark.parametrize("n_pre", [0, 8])
+def test_looped_prefill_chunk_holds_the_layers_once_and_compiles_for_v5e(
+        one_chip, chip_precision, on_tpu, n_pre):
+    """A 512-token chunk, a prompt's first (the cell's: every prompt is one
+    chunk; compiled) and one over 8 pages of prefix (lowered): the passes
+    a loop around the 48 layers, each pass gathering its prefix from and
+    scattering its chunk to its own run of the pools, beside the weights
+    and the pools."""
+    from polyrl_tpu.models import decoder
+
+    page, pb = 64, 512
+    cfg, params, pools = _looped_shapes(one_chip, 7, LOOPED_PAGES, page)
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def chunk(params, paged, state, ids, n, at, pre_pages, pages, slot):
+        return decoder.prefill_suffix_into_pages(
+            params, cfg, ids, n, at, (paged, state), pre_pages, pages, slot)
+
+    lowered = jax.jit(chunk, donate_argnums=(1, 2)).lower(
+        params, pools[0], pools[1], arg((pb,), jnp.int32),
+        arg((), jnp.int32), arg((), jnp.int32), arg((n_pre,), jnp.int32),
+        arg((pb // page,), jnp.int32), arg((), jnp.int32))
+    products = lowered.as_text().count("stablehlo.dot_general")
+    assert 48 * 4 <= products < 2 * 48 * 4 + 48 * 4
+    if n_pre:
+        return      # a minute of compiling: the cell's own chunk alone
+    compiled = lowered.compile()
+    m = compiled.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert live < 15.7 * 10**9
+    assert m.temp_size_in_bytes < 0.5 * 10**9

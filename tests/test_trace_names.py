@@ -268,6 +268,8 @@ FAMILIES = {
                    _MOE + ("moe_choices", "paged_rows_read",
                            "kda_state_rows", "mla_rows_read",
                            "window_rows_read")),
+    "ouro-tiny": ("ouro-2.6b.rollout-short-looped",
+                  ("paged_rows_read", "ut_passes", "kv_pass_rows_read")),
 }
 
 
