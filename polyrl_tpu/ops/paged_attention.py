@@ -65,6 +65,25 @@ Layout notes (why these shapes):
   decode kernel, ``rep`` padded to the dtype's sublane tile: the kernel
   computes a [Hkv, rep, block] logits tile — contraction over D lands on
   the MXU without any in-kernel head regrouping.
+
+Where the arrays live (PR 52). Every wrapper here that takes a paged K/V
+pool tells XLA that the pool stays in HBM (``_in_hbm`` for an operand,
+``pltpu.HBM(shape, dtype)`` for the write kernel's two results, whose
+aliased operands take the results' colour), so memory-space assignment
+cannot move a pool that happens to fit VMEM: it took two of Ouro's 96
+pools (102 MB each) and one ring of Laguna's (68 MB) in whole and copied
+them back around kernels that touch a few pages of them. Everything else
+is left to XLA: q, the tables and the attention's output (small, and
+better off in VMEM between a layer's operations) and the weights'
+prefetches, which are memory-space assignment doing its job. NOT pinned,
+in other modules: ``ops/mla_attention.py``'s latent pool (0.84-1.28 GB a
+layer: it never fits, and those programs stay as they were to the byte)
+and the states of ``ops/kda_state.py`` and ``ops/ssm_state.py`` (a state
+is read and written WHOLE every step, so taking it in ahead of the kernel
+helps: Phi's step is 0.6 ms slower with its states pinned, PERF.md
+section 7). One thing XLA refuses: a program whose RESULT is the write
+kernel's own tuple with the pools donated ("Different aliasing shapes");
+every caller reads the pools after the write, in the same program.
 """
 
 from __future__ import annotations
@@ -123,6 +142,23 @@ _RING = 3
 # in four, a row that is ONE nearly full block paid for the pieces what
 # the ring had gained (PERF.md section 6, PR 44)
 _LAST_BLOCK_SUBS, _LANES = 2, 128
+
+
+def _in_hbm(pool: jnp.ndarray, interpret: bool) -> jnp.ndarray:
+    """A paged K/V pool as a kernel's operand: pinned to HBM. A kernel
+    addresses a pool by pages through its own DMAs, and a block spec
+    (``pl.ANY``, ``pltpu.HBM``) does not bind XLA: the custom call's
+    operand colours come from the avals. Uncoloured, a pool that fits VMEM
+    (Ouro's 102 MB, a ring of Laguna's 68 MB) is memory-space assignment's
+    to prefetch WHOLE: it reads pages the table may never choose and owes
+    a whole-array write-back for the 8 rows a slot that the write kernel
+    changed. Not under ``interpret``: the HLO interpreter cannot slice an
+    aval that carries a memory space."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret:
+        return pool
+    return pltpu.with_memory_space_constraint(pool, pltpu.HBM)
 
 
 def _block_plan(hkv: int, page_size: int, d: int, itemsize: int,
@@ -396,7 +432,7 @@ def paged_attention_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(lens, live_from, page_table.astype(jnp.int32).reshape(-1),
-      qr, k_pool, v_pool)
+      qr, _in_hbm(k_pool, interpret), _in_hbm(v_pool, interpret))
     return out[:, :, :rep].reshape(s, hq, d)
 
 
@@ -667,6 +703,8 @@ def grouped_paged_attention_pallas(
     qr = q.reshape(s, hkv, rep, d)
     slot_grp, slot_col, slot_npre = _group_slot_maps(
         group_slots, group_prefix_lens, s, page_size)
+    # both phases fetch a page a program from where the pools are
+    k_pool, v_pool = _in_hbm(k_pool, interpret), _in_hbm(v_pool, interpret)
 
     # ---- phase 1: one stream of the shared prefix per group ----
     flat = jnp.clip(group_slots.reshape(-1), 0, s - 1)
@@ -929,8 +967,10 @@ def paged_kv_write_pallas(k_pool, v_pool, write_page, write_off, k_upd,
     )
     return pl.pallas_call(
         functools.partial(_kv_write_kernel, rows=rows, n_slots=s),
-        out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
-                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
+        # the results' colour (``_in_hbm``'s rule), which the aliased
+        # inputs take from them
+        out_shape=[pltpu.HBM(k_pool.shape, k_pool.dtype),
+                   pltpu.HBM(v_pool.shape, v_pool.dtype)],
         grid_spec=grid_spec,
         # operand indices count the scalar-prefetch args: 0=page 1=off
         # 2=owner 3=k_pool 4=v_pool (aliased onto outputs 0/1) 5/6=updates

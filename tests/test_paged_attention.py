@@ -128,6 +128,26 @@ def test_pallas_interpret_small_blocks_exact(monkeypatch):
     _check_against_ref(q, kp, vp, table, lens, 2e-5)
 
 
+def test_interpret_takes_the_pools_unconstrained():
+    """For the chip the wrapper pins both pools to HBM (``pa._in_hbm``);
+    interpreted it hands them on as they came, because the HLO interpreter
+    cannot slice an aval that carries a memory space (``TypeError`` in its
+    ``_dynamic_slice``): the constraint is in the traced program without
+    ``interpret`` and not with it, and the interpreted kernel agrees with
+    the oracle."""
+    rng = np.random.default_rng(11)
+    q, kp, vp, table, lens, _, _ = _make_case(rng)
+
+    def traced(interpret):
+        return str(jax.make_jaxpr(
+            lambda *a: paged_attention_pallas(*a, interpret=interpret))(
+                q, kp, vp, table, lens))
+
+    assert traced(False).count("with_memory_space_constraint") == 2
+    assert "with_memory_space_constraint" not in traced(True)
+    _check_against_ref(q, kp, vp, table, lens, 2e-5)
+
+
 def _ring_lens(pattern, bt, sub, nbuf):
     """Row lengths, in keys, that walk the ring of ``nbuf`` buffers over
     row boundaries: the look-ahead is ``nbuf - 1`` blocks of the BATCH."""

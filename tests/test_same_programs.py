@@ -41,3 +41,33 @@ def test_an_edited_program_shows(tmp_path):
     for preset in ("cca-tiny", "sambay-tiny"):
         for program in ("step", "prefill", "init"):
             assert "same" in verdicts[f"{preset} {program}"]
+
+
+def test_readable_shows_a_kernel_as_text_and_its_colours():
+    """``readable`` (what ``--keep`` leaves to ``diff``): the GQA
+    attention kernel lowered for a TPU reads as its Mosaic module's text
+    without locations and a config that keeps the pools' HBM colours, with
+    no base64 body."""
+    import importlib.util
+
+    import jax
+    import jax.numpy as jnp
+
+    from polyrl_tpu.ops import paged_attention as pa
+
+    spec = importlib.util.spec_from_file_location("same_programs", TOOL)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    arg = jax.ShapeDtypeStruct
+    pool = arg((2, 9, 64, 128), jnp.bfloat16)
+
+    text = jax.jit(lambda *a: pa.paged_attention_pallas(*a)).trace(
+        arg((3, 4, 128), jnp.bfloat16), pool, pool,
+        arg((3, 4), jnp.int32), arg((3,), jnp.int32)).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert "tpu.enqueue_dma" not in text
+    got = tool.readable(text)
+    assert 'input_memory_space_colors\\22: [{\\22operand_index\\22:4' in got
+    assert "\\22body\\22: \\22<below>\\22" in got
+    assert "tpu.enqueue_dma" in got and "tpu.matmul" in got
+    assert "loc(" not in got

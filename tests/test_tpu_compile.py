@@ -9,6 +9,7 @@ at a time may load the TPU's library, and every xdist worker imports every
 test file), and all such tests live in this one file."""
 
 import functools
+import json
 import math
 import os
 import re
@@ -74,6 +75,42 @@ def test_decode_kernel_compiles_for_v5e(one_chip, s, hq, hkv, d, dtype, page,
             arg((s, hq, d), dtype), pool, pool,
             arg((s, width), jnp.int32), arg((s,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _colours(lowered) -> tuple[dict[int, int], list[int]]:
+    """What a lowered kernel's custom call tells XLA of where its arrays
+    live: ({operand: colour}, [a result's colour]); 0 is HBM, and an
+    array it says nothing of is memory-space assignment's to place."""
+    config = lowered.as_text().replace("\\22", '"')
+    ins = re.search(r'"input_memory_space_colors": (\[[^\]]*\])', config)
+    outs = re.search(r'"output_memory_colors": (\[[^\]]*\])', config)
+    return ({c["operand_index"]: c["color"]
+             for c in json.loads(ins.group(1))} if ins else {},
+            json.loads(outs.group(1)) if outs else [])
+
+
+@pytest.mark.parametrize("kernel", ["paged_attention", "paged_kv_write"])
+def test_gqa_kernels_pin_their_pools_to_hbm(one_chip, kernel):
+    """The write and the attention kernel, each lowered alone at Ouro's
+    shapes (a pool of 102 MB, which fits VMEM): the custom call colours
+    both pools HBM, as operands and, where it writes them, as results
+    (the operands count the scalar-prefetch arguments). A block spec's
+    memory space does not reach XLA; these colours do."""
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    s, h, d, page, width = 7, 16, 128, 64, 72
+    pool = arg((h, 4 * LOOPED_PAGES, page, d), jnp.bfloat16)
+    if kernel == "paged_attention":
+        lowered = jax.jit(lambda *a: pa.paged_attention_pallas(*a)).lower(
+            arg((s, h, d), jnp.bfloat16), pool, pool,
+            arg((s, width), jnp.int32), arg((s,), jnp.int32))
+        assert _colours(lowered) == ({4: 0, 5: 0}, [])
+    else:
+        lowered = jax.jit(lambda *a: pa.paged_kv_write_pallas(*a)).lower(
+            pool, pool, arg((s,), jnp.int32), arg((s,), jnp.int32),
+            arg((s, h, d), jnp.bfloat16), arg((s, h, d), jnp.bfloat16))
+        assert _colours(lowered) == ({3: 0, 4: 0}, [0, 0])
 
 
 # -- the head that samples (ops/fused_sample.py) ---------------------------
@@ -497,6 +534,30 @@ def _written(text: str):
                        line.strip())
 
 
+def _made(text: str, n: int):
+    """What the optimised program writes of ``n`` elements or more, plumbing
+    apart (a loop's or a tuple's result, a bitcast, a custom call's own
+    result): a fusion's result, a ``copy``, a ``copy-done``."""
+    plumbing = re.compile(r"= \(?\w+\[[\d,]*\]\S* (while|tuple|"
+                          r"get-tuple-element|bitcast|custom-call)\(")
+    return [line[:200] for count, line in _written(text)
+            if count >= n and not plumbing.search(line)]
+
+
+def _taken_into_vmem(text: str, shape: tuple[int, ...]):
+    """The lines of the optimised program in which memory-space assignment
+    has an array of ``shape`` in hand: the array, or a run of its leading
+    dimension, held in ``S(1)`` (VMEM), cut by ``slice-start``, joined by
+    ``ConcatBitcast`` or brought back by ``copy-done``."""
+    tail = ",".join(map(str, shape[1:]))
+    of_shape = re.compile(rf"\w+\[\d+,{tail}\]")
+    held = re.compile(of_shape.pattern + r"\{[^}]*S\(1\)\}")
+    moves = re.compile(r" (slice-start|copy-done)\(|\"ConcatBitcast\"")
+    return [line.strip()[:200] for line in text.splitlines()
+            if held.search(line)
+            or (moves.search(line) and of_shape.search(line))]
+
+
 def _mla_projections(one_chip, preset, rows):
     """The MLA layers of a decode step alone, lowered for the described
     chip: ``MLA_LAYERS`` layers of ``preset``'s widths stacked, each one's
@@ -817,15 +878,11 @@ def test_sambay_decode_step_compiles_for_v5e_and_copies_no_cache(
     assert text.count("tpu_custom_call") >= 2 * 8 + 1 + 8 + 9 + 1
     state_rows = 129 * 16 * 5120          # a Mamba layer's states, float32
     ring = 10 * (1 + 129 * 8) * 64 * 128  # a window layer's K (or V)
-    plumbing = re.compile(r"= \(?\w+\[[\d,]*\]\S* (while|tuple|"
-                          r"get-tuple-element|bitcast|custom-call)\(")
-    made = [(n, line) for n, line in _written(text)
-            if n >= state_rows and not plumbing.search(line)]
     # nothing of a ring's or the pool's size is written but by the write
     # kernel, in place, and nothing of a Mamba layer's states' size but by
     # the update's kernel, in place (their results are the custom calls'
     # own): no fusion's result, no ``copy``
-    assert not [line[:200] for _n, line in made]
+    assert _made(text, state_rows) == []
 
 
 def test_sambay_prefill_chunk_compiles_for_v5e_within_memory(one_chip,
@@ -890,7 +947,10 @@ def test_mixed_decode_step_compiles_for_v5e_and_copies_no_cache(
     Weights (3.22 GB), the pools (8.05 GB), 65 slots' rings (0.82 GB) and
     everything the step holds at once fit a 16 GB chip, and nothing the
     optimised program writes is as large as a ring's K but the write
-    kernel's own in-place result."""
+    kernel's own in-place result. A ring (68 MB) would fit VMEM: the
+    kernels pin their pools to HBM (``ops.paged_attention._in_hbm``), so
+    memory-space assignment takes none in and moves none back, as it did
+    ONE ring's K until PR 52; the weights' prefetches are its to make."""
     from polyrl_tpu.models import decoder
 
     s, width, page = 64, 320, 64
@@ -925,20 +985,12 @@ def test_mixed_decode_step_compiles_for_v5e_and_copies_no_cache(
     # a write and an attention a layer, two grouped matmuls a sparse
     # layer, the head
     assert text.count("tpu_custom_call") >= 2 * 9 + 2 * 8 + 1
-    ring = 8 * (1 + 65 * 8) * 64 * 128    # a window layer's K (or V)
-    # (``copy-start`` / ``copy-done``: XLA's memory-space assignment keeps
-    # ONE ring's K, 68 MB, in VMEM across a step's kernels and moves it
-    # back: a ring of this size fits there, where Phi's 169 MB did not;
-    # no layout changes and nothing is computed into a new array)
-    plumbing = re.compile(r"= \(?\w+\[[\d,]*\]\S* (while|tuple|"
-                          r"get-tuple-element|bitcast|custom-call|"
-                          r"copy-start|copy-done)\(")
-    made = [(n, line) for n, line in _written(text)
-            if n >= ring and not plumbing.search(line)]
-    assert not [line[:200] for _n, line in made]
-    moved = [line for n, line in _written(text)
-             if n >= ring and " copy-done(" in line]
-    assert len(moved) <= 2, [line[:200] for line in moved]
+    ring = (8, 1 + 65 * 8, 64, 128)       # a window layer's K (or V)
+    # (a ``copy-done`` of that size would be memory-space assignment
+    # bringing a ring back from VMEM; a weight stack it prefetches is
+    # read-only and is never copied back)
+    assert _made(text, math.prod(ring)) == []
+    assert _taken_into_vmem(text, ring) == []
 
 
 def test_mixed_prefill_chunk_compiles_for_v5e_within_memory(one_chip,
@@ -1004,9 +1056,11 @@ def test_looped_decode_step_holds_the_layers_once_and_compiles_for_v5e(
     Mosaic's word on it), not four. Weights (5.34 GB), the pools (9.76 GB)
     and everything the step holds at once fit a 16 GB chip; nothing the
     optimised program writes is as large as a pool but the write kernel's
-    own in-place result, and of the 96 pools at most two are moved (XLA's
-    memory-space assignment takes a 102 MB pool into VMEM for a layer's
-    kernels and moves it back, as PR 47 found it do a ring)."""
+    own in-place result, and none of the 96 pools is moved: a pool (102
+    MB) would fit VMEM, and until PR 52 memory-space assignment took two
+    of them in by quarters and copied them back in every pass; the
+    kernels now pin their pools to HBM
+    (``ops.paged_attention._in_hbm``)."""
     from polyrl_tpu.models import decoder
 
     s, width, page = 7, 72, 64
@@ -1052,16 +1106,9 @@ def test_looped_decode_step_holds_the_layers_once_and_compiles_for_v5e(
     text = compiled.as_text()
     # a write and an attention a layer, the head: ONCE, under the loop
     assert text.count("tpu_custom_call") == 2 * 48 + 1
-    pool = 16 * 4 * LOOPED_PAGES * 64 * 128    # a layer's K (or V)
-    plumbing = re.compile(r"= \(?\w+\[[\d,]*\]\S* (while|tuple|"
-                          r"get-tuple-element|bitcast|custom-call|"
-                          r"copy-start|copy-done)\(")
-    made = [(n, line) for n, line in _written(text)
-            if n >= pool and not plumbing.search(line)]
-    assert not [line[:200] for _n, line in made]
-    moved = [line for n, line in _written(text)
-             if n >= pool and " copy-done(" in line]
-    assert len(moved) <= 2, [line[:200] for line in moved]
+    pool = (16, 4 * LOOPED_PAGES, 64, 128)     # a layer's K (or V)
+    assert _made(text, math.prod(pool)) == []
+    assert _taken_into_vmem(text, pool) == []
 
 
 @pytest.mark.parametrize("n_pre", [0, 8])

@@ -12,7 +12,12 @@ the parent's to the byte.
 
 ``--keep DIR`` leaves the texts in ``DIR/other`` and ``DIR/here`` (``diff``
 them where a digest differs: a difference is a change of behaviour until
-the texts show otherwise).
+the texts show otherwise): a program's StableHLO as digested (``.mlir``)
+and as ``readable`` makes it (``.txt``), which is the one to ``diff``: a
+kernel's body decoded from its base64 to MLIR without locations, so that a
+line that moved in the kernel's own file does not show, and the private
+functions numbered in the order they appear (PR 52's GQA steps differ from
+their parent's in the kernels' memory colours alone, and read so).
 
 Each checkout is copied to the same scratch path in turn (a kernel's
 serialized body carries its source's path) and each preset lowered in a
@@ -28,11 +33,13 @@ preset that the other checkout does not have reads ``new``."""
 
 from __future__ import annotations
 
+import base64
 import functools
 import hashlib
 import json
 import math
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -42,6 +49,33 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PRESETS = ("qwen2.5-7b", "qwen3-30b-a3b", "ling-3.0-flash-share4",
            "dots.vlm1-share16", "zaya1-8b-depth12",
            "phi-4-mini-flash-reasoning", "laguna-xs.2-share8", "ouro-2.6b")
+
+
+def readable(text: str) -> str:
+    """A lowered program's text for ``diff``: each ``tpu_custom_call``'s
+    serialized Mosaic module decoded and printed without its locations
+    behind the call's line (whose config keeps everything but the body),
+    and the private functions' numbers (``@_where_188``: a counter of the
+    process) replaced by their order of appearance."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    ctx = mlir.make_ir_context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True    # the ``stable_mosaic`` wrapper
+
+    def decoded(m):
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(m.group(1)))
+            body = module.operation.get_asm(enable_debug_info=False)
+        return '\\22body\\22: \\22<below>\\22' + m.group(2) + "\n" + body
+
+    text = re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]*)\\22(.*)', decoded,
+                  text)
+    order: dict[str, str] = {}
+    return re.sub(r"@(\w+?)_(\d+)\b", lambda m: order.setdefault(
+        m.group(0), f"@{m.group(1)}_#{len(order)}"), text)
 
 
 def digests(presets, keep: str = "") -> dict:
@@ -107,9 +141,10 @@ def digests(presets, keep: str = "") -> dict:
         for name, text in texts.items():
             out[f"{preset} {name}"] = hashlib.sha256(text.encode()).hexdigest()
             if keep:
-                with open(os.path.join(keep, f"{preset}.{name}.mlir"),
-                          "w") as f:
-                    f.write(text)
+                for kind, form in ((".mlir", text), (".txt", readable(text))):
+                    with open(os.path.join(keep, f"{preset}.{name}{kind}"),
+                              "w") as f:
+                        f.write(form)
     return out
 
 
