@@ -305,7 +305,8 @@ def _moe_mlp(cfg, x: jnp.ndarray, lp: dict,
             gate = jax.nn.silu(mm(x, lp["ws_gate"]).astype(jnp.float32))
             out = out + mm(gate.astype(x.dtype) * mm(x, lp["ws_up"]),
                            lp["ws_down"]).astype(jnp.float32)
-    return out.astype(x.dtype), load
+    with jax.named_scope("glue"):
+        return out.astype(x.dtype), load
 
 
 def _head(cfg, params, x, logits_for=None):

@@ -735,20 +735,25 @@ def _mlp_block(cfg: ModelConfig, h: jnp.ndarray, lp: dict,
         v = valid.reshape(-1) if valid is not None else None
         out, load = _moe_mlp(cfg, h.reshape(-1, shape[-1]), lp, v, layer)
         return out.reshape(shape), load
-    gate = jax.nn.silu(mm(h, lp["w_gate"]).astype(jnp.float32)).astype(h.dtype)
-    return mm(gate * mm(h, lp["w_up"]), lp["w_down"]), None
+    with jax.named_scope("mlp_dense"):
+        gate = jax.nn.silu(
+            mm(h, lp["w_gate"]).astype(jnp.float32)).astype(h.dtype)
+        return mm(gate * mm(h, lp["w_up"]), lp["w_down"]), None
 
 
 # -- forward ----------------------------------------------------------------
 
 
 # Scopes of the forward pass (``jax.named_scope``: metadata only, the
-# compiled program is the same with and without them). They are the path
-# by which a device trace tells one layer's parts apart, so every forward
-# path names the same five: ``attn_qkv`` (norm, q/k/v projections, rope),
-# ``attn_core`` (the KV write, the attention, and every gather, convert or
-# reshape between them), ``attn_out``, ``mlp``, ``head`` (final norm and
-# the output matmul). The engine adds ``sample``.
+# compiled program is the same with and without them; ``models/scopes.py``
+# declares them all). They are the path by which a device trace tells one
+# layer's parts apart, so every forward path names the same: ``embed``,
+# ``attn_qkv`` (norm, q/k/v projections, rope and its angles),
+# ``attn_core`` (the KV write and where it goes, the attention, and every
+# gather, convert or reshape between them), ``attn_out``, ``mlp`` (a
+# container: the dense products under ``mlp_dense`` or the routed block's
+# ``moe_*``, its norm and residual under ``glue``), ``head`` (final norm
+# and the output matmul). The engine adds ``sample``.
 
 
 def _attn_qkv(cfg, x, lp, cos, sin, lead: tuple):
@@ -777,9 +782,11 @@ def _attn_out_mlp(cfg, x, attn_out, lp, token_valid, layer=None):
     with jax.named_scope("attn_out"):
         x = x + mm(attn_out, lp["wo"])
     with jax.named_scope("mlp"):
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+        with jax.named_scope("glue"):
+            h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
         out, load = _mlp_block(cfg, h, lp, token_valid, layer)
-        return x + out, load
+        with jax.named_scope("glue"):
+            return x + out, load
 
 
 def samples_in_head(cfg, params, use_filters: bool, many_chips: bool) -> bool:
@@ -870,9 +877,11 @@ def forward(
         return hybrid.forward(params, cfg, input_ids, positions, attn_mask,
                               remat=remat, logits_for=logits_for), None
     b, t = input_ids.shape
-    x = params["embed"][input_ids]  # gather; sharded over tp on vocab dim
+    with jax.named_scope("embed"):
+        x = params["embed"][input_ids]  # gather; sharded over tp on vocab dim
 
-    cos, sin = rope_cos_sin(cfg, positions)
+    with jax.named_scope("attn_qkv"):
+        cos, sin = rope_cos_sin(cfg, positions)
 
     if cache is None:
         if attn_fn is not None or layers_fn is not None:
@@ -880,16 +889,21 @@ def forward(
             mask = None
         else:
             # causal within the chunk + padding mask
-            cm = causal_mask(t, t)  # [T, T]
-            mask = cm[None, None, :, :] & (attn_mask[:, None, None, :] > 0)
+            with jax.named_scope("attn_core"):
+                cm = causal_mask(t, t)  # [T, T]
+                mask = (cm[None, None, :, :]
+                        & (attn_mask[:, None, None, :] > 0))
     else:
         # left-padded layout: cache slot order == temporal order, so the
         # causal constraint is expressed in slot indices, not positions.
-        s = cache[0].shape[2]
-        kv_pos = jnp.arange(s)[None, None, None, :]
-        slot_written = kv_pos <= (write_idx + t - 1)  # slots at/below the chunk
-        causal = kv_pos <= (write_idx + jnp.arange(t)[None, None, :, None])
-        mask = causal & slot_written & (attn_mask[:, None, None, :] > 0)
+        with jax.named_scope("attn_core"):
+            s = cache[0].shape[2]
+            kv_pos = jnp.arange(s)[None, None, None, :]
+            # slots at/below the chunk
+            slot_written = kv_pos <= (write_idx + t - 1)
+            causal = kv_pos <= (write_idx
+                                + jnp.arange(t)[None, None, :, None])
+            mask = causal & slot_written & (attn_mask[:, None, None, :] > 0)
 
     layers = params["layers"]
 
@@ -903,7 +917,8 @@ def forward(
             layer_attn = None
             if attn_fn is not None:
                 layer_attn = lambda q, k, v: attn_fn(q, k, v, attn_mask)  # noqa: E731
-            tok_valid = attn_mask > 0  # [B, T] — MoE routing skips pads
+            with jax.named_scope("glue"):
+                tok_valid = attn_mask > 0  # [B, T]: MoE routing skips pads
 
             def body(x, lp):
                 x, _ = _layer_forward(cfg, x, lp, cos, sin, mask, None,
@@ -928,10 +943,12 @@ def forward(
         t_chunk = x.shape[1]
         # chunk validity from the cache-slot mask (the chunk occupies slots
         # [write_idx, write_idx+t)): keeps MoE routing off padded tokens
-        chunk_valid = jax.lax.dynamic_slice_in_dim(
-            attn_mask, write_idx, t_chunk, axis=1) > 0
+        with jax.named_scope("glue"):
+            chunk_valid = jax.lax.dynamic_slice_in_dim(
+                attn_mask, write_idx, t_chunk, axis=1) > 0
         for l in range(n_layers):
-            lp = _unrolled_layer(cfg, layers, l)
+            with jax.named_scope("glue"):
+                lp = _unrolled_layer(cfg, layers, l)
             q, k, v = _attn_qkv(cfg, x, lp, cos, sin, (b, t_chunk))
             with jax.named_scope("attn_core"):
                 k_cache = jax.lax.dynamic_update_slice(
@@ -1020,17 +1037,21 @@ def forward_paged_decode(
     s = tokens.shape[0]
     page_size = pools[0][0].shape[2]
 
-    x = params["embed"][tokens]  # [S, d]
-    cos, sin = rope_cos_sin(cfg, positions[:, None])  # [S, 1, hd/2]
-    write_page = page_table[jnp.arange(s), seq_lens // page_size]  # [S]
-    write_off = seq_lens % page_size
-    attn_lens = seq_lens + 1  # include the token written this step
-    if active is not None:
-        write_page = jnp.where(active, write_page, 0)
-        write_off = jnp.where(active, write_off, 0)
-        # a row without a request attends nothing (the TPU kernel then does
-        # no work for it); the engine discards its token and log-prob
-        attn_lens = jnp.where(active, attn_lens, 0)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]  # [S, d]
+    with jax.named_scope("attn_qkv"):
+        cos, sin = rope_cos_sin(cfg, positions[:, None])  # [S, 1, hd/2]
+    with jax.named_scope("glue"):   # where the step's KV goes
+        write_page = page_table[jnp.arange(s), seq_lens // page_size]  # [S]
+        write_off = seq_lens % page_size
+        attn_lens = seq_lens + 1  # include the token written this step
+        if active is not None:
+            write_page = jnp.where(active, write_page, 0)
+            write_off = jnp.where(active, write_off, 0)
+            # a row without a request attends nothing (the TPU kernel then
+            # does no work for it); the engine discards its token and
+            # log-prob
+            attn_lens = jnp.where(active, attn_lens, 0)
 
     layers = params["layers"]
 
@@ -1042,7 +1063,8 @@ def forward_paged_decode(
     n_layers = len(k_pools)
     moe_load = None
     for l in range(n_layers):
-        lp = _unrolled_layer(cfg, layers, l)
+        with jax.named_scope("glue"):   # slices that fuse into their readers
+            lp = _unrolled_layer(cfg, layers, l)
         q, k, v = _attn_qkv(cfg, x, lp, cos, sin, (s, 1))
         with jax.named_scope("attn_core"):
             # fused K+V Pallas write on TPU (XLA row-scatter elsewhere):
@@ -1058,7 +1080,8 @@ def forward_paged_decode(
         # inactive slots route nowhere and count in no expert's load
         x, load = _attn_out_mlp(cfg, x, attn_out, lp, active, l)
         if load is not None:
-            moe_load = load if moe_load is None else moe_load + load
+            with jax.named_scope("glue"):
+                moe_load = load if moe_load is None else moe_load + load
     return ((head_fn or _head)(cfg, params, x),
             (tuple(k_pools), tuple(v_pools)), moe_load)
 

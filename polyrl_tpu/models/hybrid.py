@@ -262,27 +262,33 @@ def _mlp(cfg, x, layers, l, mlp_lp, valid, carry=None):
     None). ``carry`` [..., R]: the layer before's latent of each token."""
     plan = cache_spec.layer_plan(cfg)[l]
     j = kind_index(cfg)[l][1]
-    res = _res(layers, "mlp_res", l)
     with jax.named_scope("mlp"):
-        h = norm(layers, "mlp_norm", x, cfg.rms_norm_eps, l)
+        with jax.named_scope("glue"):
+            res = _res(layers, "mlp_res", l)
+            h = norm(layers, "mlp_norm", x, cfg.rms_norm_eps, l)
         if plan.mlp == "dense":
-            gate = jax.nn.silu(mm(h, mlp_lp["w_gate"]).astype(jnp.float32))
-            out = mm(gate.astype(h.dtype) * mm(h, mlp_lp["w_up"]),
-                     mlp_lp["w_down"])
-            out = _post(cfg, layers, "mlp_post_norm", out, l)
-            return _residual(x, out, res), None, carry
+            with jax.named_scope("mlp_dense"):
+                gate = jax.nn.silu(
+                    mm(h, mlp_lp["w_gate"]).astype(jnp.float32))
+                out = mm(gate.astype(h.dtype) * mm(h, mlp_lp["w_up"]),
+                         mlp_lp["w_down"])
+            with jax.named_scope("glue"):
+                out = _post(cfg, layers, "mlp_post_norm", out, l)
+                return _residual(x, out, res), None, carry
         shape = h.shape
-        rows = h.reshape(-1, shape[-1])
-        v = valid.reshape(-1) if valid is not None else None
+        with jax.named_scope("glue"):
+            rows = h.reshape(-1, shape[-1])
+            v = valid.reshape(-1) if valid is not None else None
         route = None
         if carry is not None:
             with jax.named_scope("moe_route"):
                 *route, latent = _latent_route(
                     cfg, rows, mlp_lp, carry.reshape(rows.shape[0], -1))
-            carry = latent.reshape(carry.shape)
+                carry = latent.reshape(carry.shape)
         out, load = _moe_mlp(cfg, rows, mlp_lp, v, j, route)
-        out = _post(cfg, layers, "mlp_post_norm", out.reshape(shape), l)
-        return _residual(x, out, res), load, carry
+        with jax.named_scope("glue"):
+            out = _post(cfg, layers, "mlp_post_norm", out.reshape(shape), l)
+            return _residual(x, out, res), load, carry
 
 
 def _form(p, form: str):
@@ -314,7 +320,8 @@ def _pass_indices(cfg):
     first pass under the final norm too, in the step and in the chunk, and
     left the reference by 0.64 where this one leaves it by 0.008; the CPU
     runs both alike (my chip runs, PR 51: ``PERF.md`` section 6)."""
-    return jnp.arange(cache_spec.passes(cfg), dtype=jnp.int32)
+    with jax.named_scope("glue"):
+        return jnp.arange(cache_spec.passes(cfg), dtype=jnp.int32)
 
 
 def _pass_offset(cfg, paged, t):
@@ -374,23 +381,27 @@ def run_plan(params, cfg, x, positions, valid, states=None,
     plan = cache_spec.layer_plan(cfg)
     b, t, _ = x.shape
     new_states, kept_pages = [], []
-    hands = {"latent": router_carry(cfg, (b, t))}
+    with jax.named_scope("moe_route"):
+        hands = {"latent": router_carry(cfg, (b, t))}
     index = cache_spec.pool_index(cfg)
     for l, p in enumerate(plan):
         at_pages, at_slot = index[l]
 
         def layer(x, hands, l=l, p=p, at_pages=at_pages, at_slot=at_slot):
-            mixer_lp, mlp_lp = _layer_params(cfg, layers, l)
-            h_in = norm(layers, "attn_norm", x, cfg.rms_norm_eps, l)
-            st = states[at_slot] if states is not None and at_slot is not None \
-                else _zero_state(cfg, p, b, x.dtype)
+            with jax.named_scope("glue"):
+                mixer_lp, mlp_lp = _layer_params(cfg, layers, l)
+                h_in = norm(layers, "attn_norm", x, cfg.rms_norm_eps, l)
+                st = states[at_slot] \
+                    if states is not None and at_slot is not None \
+                    else _zero_state(cfg, p, b, x.dtype)
             pre = None if prefix is None or at_pages is None \
                 else prefix[at_pages]
             out, kept = _form(p, "sequence")(
                 cfg, p, mixer_lp, h_in,
                 Chunk(positions, valid, st, pre, hands))
-            out = _post(cfg, layers, "attn_post_norm", out, l)
-            x = _residual(x, out, _res(layers, "attn_res", l))
+            with jax.named_scope("glue"):
+                out = _post(cfg, layers, "attn_post_norm", out, l)
+                x = _residual(x, out, _res(layers, "attn_res", l))
             x, _load, latent = _mlp(cfg, x, layers, l, mlp_lp, valid,
                                     hands["latent"])
             return (x, {**hands, **kept.hands, "latent": latent}, kept.pages,
@@ -419,8 +430,10 @@ def forward(params, cfg, input_ids, positions, attn_mask, remat=False,
     """``decoder.forward`` without a cache for a model of several kinds of
     layer. A recurrent state starts from zero at a row's first valid
     token, so padding may stand on either side."""
-    valid = attn_mask > 0
-    x = params["embed"][input_ids]
+    with jax.named_scope("glue"):
+        valid = attn_mask > 0
+    with jax.named_scope("embed"):
+        x = params["embed"][input_ids]
     x, _states, _lat = run_sequence(params, cfg, x, positions, valid,
                                     remat=remat)
     return _head(cfg, params, x, logits_for)
@@ -470,20 +483,24 @@ def prefill(params, cfg, ids, lens, prefix_len, pools, prefix_page_ids,
     whatever the last request left there."""
     paged, state = pools
     b, pb = ids.shape
-    valid = jnp.arange(pb)[None, :] < lens[:, None]
-    positions = jnp.broadcast_to(prefix_len + jnp.arange(pb, dtype=jnp.int32),
-                                 (b, pb))
-    at = SlotRows(slots, prefix_len, lens, prefix_len == 0)
+    with jax.named_scope("glue"):
+        valid = jnp.arange(pb)[None, :] < lens[:, None]
+        positions = jnp.broadcast_to(
+            prefix_len + jnp.arange(pb, dtype=jnp.int32), (b, pb))
+        at = SlotRows(slots, prefix_len, lens, prefix_len == 0)
     keep_pages, keep_slot = _keepers(cfg)
-    states = [rec.read_slot(cfg, rows, at)
-              for rows, rec in zip(state, keep_slot)]
+    states = []
+    for rows, rec in zip(state, keep_slot):
+        with jax.named_scope(rec.slot_scope):
+            states.append(rec.read_slot(cfg, rows, at))
 
     def gathered(paged, off=None):
         """What the prefix's pages hold, a paged layer; None for none.
         ``off``: what a pass adds to a page's number to find its own."""
         if not prefix_page_ids.shape[1]:
             return None
-        pre_len = jnp.broadcast_to(prefix_len, (b,))
+        with jax.named_scope("glue"):
+            pre_len = jnp.broadcast_to(prefix_len, (b,))
         prefix = []
         for pool, rec in zip(paged, keep_pages):
             with jax.named_scope(rec.pages_scope):
@@ -503,7 +520,8 @@ def prefill(params, cfg, ids, lens, prefix_len, pools, prefix_page_ids,
 
     if cache_spec.passes(cfg) == 1:
         prefix = gathered(paged)
-        x = params["embed"][ids]
+        with jax.named_scope("embed"):
+            x = params["embed"][ids]
         x, new_states, kept = run_plan(params, cfg, x, positions, valid,
                                        states, prefix)
         paged = scattered(paged, kept)
@@ -511,7 +529,8 @@ def prefill(params, cfg, ids, lens, prefix_len, pools, prefix_page_ids,
         def one_pass(carry, t):
             """Pass ``t`` over the chunk, from its own pages into them."""
             x, paged = carry
-            off = _pass_offset(cfg, paged, t)
+            with jax.named_scope("glue"):
+                off = _pass_offset(cfg, paged, t)
             x = _next_pass(cfg, params, x, t)
             with jax.named_scope("ut_pass"):
                 x, _states, kept = run_plan(params, cfg, x, positions, valid,
@@ -519,7 +538,9 @@ def prefill(params, cfg, ids, lens, prefix_len, pools, prefix_page_ids,
                 return (x, scattered(paged, kept, off)), None
 
         new_states = []
-        (x, paged), _ = jax.lax.scan(one_pass, (params["embed"][ids], paged),
+        with jax.named_scope("embed"):
+            x = params["embed"][ids]
+        (x, paged), _ = jax.lax.scan(one_pass, (x, paged),
                                      _pass_indices(cfg))
     written = []
     for rows, new, was, rec in zip(state, new_states, states, keep_slot):
@@ -645,16 +666,21 @@ def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
     paged, state = list(pools[0]), list(pools[1])
     s = tokens.shape[0]
     ps = jax.tree_util.tree_leaves(paged)[0].shape[2]
-    live = jnp.ones((s,), bool) if active is None else active
-    write_page = jnp.where(live, page_table[jnp.arange(s), seq_lens // ps], 0)
-    write_off = jnp.where(live, seq_lens % ps, 0)
-    attn_lens = jnp.where(live, seq_lens + 1, 0)
-    n_live = jnp.sum(live.astype(jnp.int32))
-    rows_read = jnp.sum(attn_lens)
+    # where every layer's token goes, and what the step counts
+    with jax.named_scope("glue"):
+        live = jnp.ones((s,), bool) if active is None else active
+        write_page = jnp.where(
+            live, page_table[jnp.arange(s), seq_lens // ps], 0)
+        write_off = jnp.where(live, seq_lens % ps, 0)
+        attn_lens = jnp.where(live, seq_lens + 1, 0)
+        n_live = jnp.sum(live.astype(jnp.int32))
+        rows_read = jnp.sum(attn_lens)
 
-    x = params["embed"][tokens]
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]
     load = Load(load_names(cfg))
-    hands = {"latent": router_carry(cfg, (s,))}
+    with jax.named_scope("moe_route"):
+        hands = {"latent": router_carry(cfg, (s,))}
     index = cache_spec.pool_index(cfg)
     ctx = Step(positions, seq_lens, live, page_table, ps, write_page,
                write_off, attn_lens, n_live, rows_read, load, per={})
@@ -666,8 +692,9 @@ def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
         """The plan's layers, once: (x, paged, state)."""
         for l, p in enumerate(plan):
             at_pages, at_slot = index[l]
-            mixer_lp, mlp_lp = _layer_params(cfg, layers, l)
-            h_in = norm(layers, "attn_norm", x, cfg.rms_norm_eps, l)
+            with jax.named_scope("glue"):
+                mixer_lp, mlp_lp = _layer_params(cfg, layers, l)
+                h_in = norm(layers, "attn_norm", x, cfg.rms_norm_eps, l)
             own = dataclasses.replace(
                 ctx, pages=None if at_pages is None else paged[at_pages],
                 slot=None if at_slot is None else state[at_slot],
@@ -678,14 +705,17 @@ def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
                 paged[at_pages] = kept.pages
             if kept.slot is not None:
                 state[at_slot] = kept.slot
-            out = _post(cfg, layers, "attn_post_norm", out, l)
-            x = _residual(x, out, _res(layers, "attn_res", l))
+            with jax.named_scope("glue"):
+                out = _post(cfg, layers, "attn_post_norm", out, l)
+                x = _residual(x, out, _res(layers, "attn_res", l))
             x, moe, latent = _mlp(cfg, x, layers, l, mlp_lp, active,
                                   hands["latent"])
             hands = {**hands, **kept.hands, "latent": latent}
             if moe is not None:
-                load.vector = load.vector.at[:len(MOE_LOAD)].add(moe)
-                load.add(MOE_CHOICES, n_live * cfg.num_experts_per_tok)
+                with jax.named_scope("glue"):
+                    load.vector = load.vector.at[:len(MOE_LOAD)].add(moe)
+                    choices = n_live * cfg.num_experts_per_tok
+                load.add(MOE_CHOICES, choices)
         return x, paged, state
 
     if cache_spec.passes(cfg) == 1:
@@ -693,14 +723,15 @@ def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
     else:
         def one_pass(carry, t):
             x, paged, load.vector = carry
-            off = _pass_offset(cfg, paged, t)
+            with jax.named_scope("glue"):
+                off = _pass_offset(cfg, paged, t)
             x = _next_pass(cfg, params, x, t)
             with jax.named_scope("ut_pass"):
-                x, paged, _state = once(
-                    x, list(paged), state,
-                    dataclasses.replace(ctx, page_table=page_table + off,
-                                        write_page=write_page + off),
-                    hands)
+                with jax.named_scope("glue"):   # the pass's own pages
+                    own = dataclasses.replace(
+                        ctx, page_table=page_table + off,
+                        write_page=write_page + off)
+                x, paged, _state = once(x, list(paged), state, own, hands)
             load.add(UT_LOAD[0], n_live)
             load.add(UT_LOAD[1], rows_read)
             return (x, tuple(paged), load.vector), None

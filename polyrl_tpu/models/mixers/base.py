@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from polyrl_tpu.models import scopes
+
 L2_EPS = 1e-6
 
 
@@ -58,10 +60,14 @@ class Load:
 
     def __init__(self, names: tuple):
         self.names = names
-        self.vector = jnp.zeros((len(names),), jnp.int32)
+        with jax.named_scope("glue"):
+            self.vector = jnp.zeros((len(names),), jnp.int32)
 
     def add(self, name: str, amount) -> None:
-        self.vector = self.vector.at[self.names.index(name)].add(amount)
+        """Called between a form's scopes, never inside one: the count is
+        the step's bookkeeping (``glue``), not the layer's work."""
+        with jax.named_scope("glue"):
+            self.vector = self.vector.at[self.names.index(name)].add(amount)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -138,7 +144,8 @@ class Mixer:
     # (cfg, Step) -> what its layers share of one step (``Step.per``)
     per_step: Callable | None = None
     # the scopes its pages are gathered and written under in prefill, and
-    # its slot written back under
+    # its slot read and written back under: leaves that
+    # ``models/scopes.py`` declares, as every scope its forms open is
     pages_scope: str = ""
     slot_scope: str = ""
     # a prefill chunk moves its K/V pair's pages as slabs of the pool's
@@ -168,6 +175,11 @@ class Mixer:
     # (its share counter in ``engine_profile.CUMULATIVE_KEYS``, (cfg, rows)
     # -> whether a decode step of ``rows`` rows takes its kernel)
     kernel: tuple | None = None
+
+    def __post_init__(self):
+        for name in (self.pages_scope, self.slot_scope):
+            if name:
+                scopes.declared(name)
 
 
 def yarn_inv_freq(theta: float, r: int, s) -> np.ndarray:

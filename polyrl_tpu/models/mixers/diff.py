@@ -281,17 +281,20 @@ def per_step(cfg, ctx):
     """A step's view of the rings: row r's pages are slot r's, the token
     at its position modulo the window, the row as long as it has tokens."""
     s, ps, w = ctx.live.shape[0], ctx.page_size, cfg.sliding_window
-    table = ring_pages(cfg, jnp.arange(s, dtype=jnp.int32), ps)
-    at = ctx.seq_lens % w
-    page = jnp.where(ctx.live, table[jnp.arange(s), at // ps], 0)
-    off = jnp.where(ctx.live, at % ps, 0)
-    lens = jnp.minimum(ctx.attn_lens, w)
-    return types.SimpleNamespace(table=table, page=page, off=off, lens=lens,
-                                 read=jnp.sum(lens))
+    with jax.named_scope("glue"):   # once a step, for every window layer
+        table = ring_pages(cfg, jnp.arange(s, dtype=jnp.int32), ps)
+        at = ctx.seq_lens % w
+        page = jnp.where(ctx.live, table[jnp.arange(s), at // ps], 0)
+        off = jnp.where(ctx.live, at % ps, 0)
+        lens = jnp.minimum(ctx.attn_lens, w)
+        return types.SimpleNamespace(table=table, page=page, off=off,
+                                     lens=lens, read=jnp.sum(lens))
 
 
 def _step_out(cfg, p, lp, o, s: int):
-    return _diff_out(cfg, lp, o.reshape(s, -1, 2, o.shape[-1]), p.published)
+    with jax.named_scope("diff_mix"):
+        o = o.reshape(s, -1, 2, o.shape[-1])
+    return _diff_out(cfg, lp, o, p.published)
 
 
 def step_swa(cfg, p, lp, h_in, ctx):
@@ -299,7 +302,8 @@ def step_swa(cfg, p, lp, h_in, ctx):
 
     ring = ctx.per["swa"]
     q, k, v = _diff_qkv(cfg, lp, h_in)
-    q = paired_queries(q)
+    with jax.named_scope("attn_qkv"):
+        q = paired_queries(q)
     with jax.named_scope("swa_core"):
         slot = paged_kv_write(*ctx.slot, ring.page, ring.off, k, v)
         o = paged_attention(q, *slot, ring.table, ring.lens,
@@ -315,7 +319,8 @@ def step_paged(cfg, p, lp, h_in, ctx):
     from polyrl_tpu.ops.paged_attention import paged_attention, paged_kv_write
 
     q, k, v = _diff_qkv(cfg, lp, h_in)
-    q = paired_queries(q)
+    with jax.named_scope("attn_qkv"):
+        q = paired_queries(q)
     pages = ctx.pages
     with jax.named_scope("attn_core"):
         if k is not None:

@@ -58,7 +58,12 @@ timed, and every phase also opens a ``jax.profiler.TraceAnnotation``
 flag test, inside one the phase lies on the device trace's clock beside
 the device's own events. The fetcher's blocking ``device_get`` is
 annotated the same way on its own thread (:meth:`fetch`, ``engine/fetch``)
-and counted outside the loop's partition. The dispatch phases also emit
+and counted outside the loop's partition. Each landing leaves one instant
+``engine/landed`` annotation on the thread that lands it, whose own
+statistics are the completion stamps as of that landing
+(:meth:`on_landed`): the counters' step time and the trace's can then be
+taken over the same dispatches, in the same seconds
+(``benchmark/lib/account.py``). The dispatch phases also emit
 spans into the process tracer ring (obs/trace.py) when that is enabled;
 the ring is for cross-process request traces (its wall/monotonic anchor
 joins trainer, manager and engine), the device trace is where host phases
@@ -104,10 +109,9 @@ emission or iteration, never once a token, all on this profiler's clock:
 - ``admission_deferrals`` — loop iterations in which admission left a
   request pending for want of pages or a slot and went on without
   waiting (``CBEngine._admit``);
-- ``pages_grown`` / ``slot_yields`` — KV pages handed to rows already
-  running, as they wrote their way into them (``CBEngine._grow_rows``),
-  and rows that gave up slot and pages because the pool had no more and
-  went back to the queue's head (``CBEngine._yield_row``);
+- ``slot_yields`` — rows that gave up slot and pages because the pool
+  had no more and went back to the queue's head
+  (``CBEngine._yield_row``);
 - ``device_busy_s`` — seconds with device work outstanding: an interval
   opens when a dispatch is enqueued with nothing outstanding and closes
   when a landed result leaves nothing newer outstanding. Seconds are added
@@ -166,6 +170,9 @@ DECODE_KINDS = frozenset(("step", "spec"))
 MAX_BUILDS_KEPT = 32
 # a phase's cumulative loop-thread seconds in ``server_info``
 PHASE_KEYS = {p: f"phase_{p.removesuffix('_device')}_s" for p in PHASES}
+# the instant annotation of a landing; its keyword statistics are the
+# stamps as of that landing
+LANDED_SPAN = "engine/landed"
 # a landing gap longer than this is a stall: the longest program of any
 # benchmark cell runs 0.18 s and a prefill chunk 0.1 s
 STALL_GAP_S = 2.0
@@ -177,7 +184,7 @@ STALL_GAP_S = 2.0
 # seconds, ``_hist`` keys ``Histogram.bucket_counts()``, the rest counts.
 CUMULATIVE_KEYS = (
     "decode_dispatches", "decode_dispatches_cold", "admission_deferrals",
-    "pages_grown", "slot_yields", "decode_steps_done", "fused_sample_steps",
+    "slot_yields", "decode_steps_done", "fused_sample_steps",
     "kda_kernel_steps", "mla_proj_kernel_steps", "row_steps_done",
     "ssm_state_rows",
     "shared_kv_rows_read",
@@ -393,11 +400,6 @@ class EngineLoopProfiler:
         with self._lock:
             self._cum["admission_deferrals"] += 1
 
-    def on_pages_grown(self, n: int) -> None:
-        """Rows already running took ``n`` more pages before a dispatch."""
-        with self._lock:
-            self._cum["pages_grown"] += int(n)
-
     def on_slot_yield(self) -> None:
         """A running row gave up its slot and pages for want of pages."""
         with self._lock:
@@ -415,11 +417,14 @@ class EngineLoopProfiler:
         """The oldest ``n`` dispatches' results are on the host: the
         device has finished them and everything enqueued before them.
         Returns the landing gap this one closed where it was a stall
-        (module docstring), else None."""
+        (module docstring), else None. Leaves the stamps as they stand
+        after it on the device trace (``engine/landed``: outside a
+        profiler session a flag test)."""
         now = self._clock()
         with self._lock:
             c = self._cum
-            for _ in range(min(n, len(self._landing))):
+            landed = min(n, len(self._landing))
+            for _ in range(landed):
                 steps, rows, counters = self._landing.popleft()
                 c["decode_steps_done"] += steps
                 c["row_steps_done"] += steps * rows
@@ -437,6 +442,12 @@ class EngineLoopProfiler:
             self._count_busy(now, still_busy)
             self._gap_from = (now if self._landing and not self._stopping
                               else None)
+            stamps = dict(decode_steps_done=c["decode_steps_done"],
+                          device_busy_s=c["device_busy_s"],
+                          device_busy_at_s=self.device_busy_at_s,
+                          dispatches=landed)
+        with self._annotate(LANDED_SPAN, **stamps):
+            pass
         return gap
 
     def on_emit(self, n: int) -> None:

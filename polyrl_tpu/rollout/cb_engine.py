@@ -972,9 +972,12 @@ class CBEngine:
                      stop_table, group_pack=None):
                 if gshape is not None:
                     o = ng * gmax
-                    g_slots = group_pack[:o].reshape(ng, gmax)
-                    g_pages = group_pack[o:o + ng * p_pre].reshape(ng, p_pre)
-                    g_lens = group_pack[o + ng * p_pre:o + ng * p_pre + ng]
+                    with jax.named_scope("glue"):   # the groups' tables
+                        g_slots = group_pack[:o].reshape(ng, gmax)
+                        g_pages = group_pack[
+                            o:o + ng * p_pre].reshape(ng, p_pre)
+                        g_lens = group_pack[
+                            o + ng * p_pre:o + ng * p_pre + ng]
 
                     def attn(q, kp_, vp_, pt, lens):
                         return grouped_attn(q, kp_, vp_, pt, lens, g_slots,
@@ -1020,7 +1023,8 @@ class CBEngine:
                     None, length=k)
                 kp, vp, rng, seq_lens, last_tokens, n_generated, active = carry
                 if moe_load is not None:   # a MoE model: [k, 3] -> [3]
-                    moe_load = jnp.sum(moe_load, axis=0)
+                    with jax.named_scope("glue"):
+                        moe_load = jnp.sum(moe_load, axis=0)
                 return (kp, vp, rng, token, logp, done, seq_lens,
                         last_tokens, n_generated, active, moe_load)
 
@@ -2236,8 +2240,6 @@ class CBEngine:
             # the step's operand; a state that is gone (a row yielded) is
             # built anew from the same table before the dispatch
             self._dev_state["page_table"] = self._page_table_dev()
-        if self.profiler is not None:
-            self.profiler.on_pages_grown(int(more.sum()))
 
     def _yield_row(self, slot: int) -> None:
         """The pool ran out: row ``slot`` gives up its slot and pages and

@@ -604,6 +604,73 @@ def test_phase_seam_opens_one_annotation_per_phase_on_both_threads(
         0.5 * len(named) / prof.wall_s)
 
 
+def test_a_landing_leaves_the_stamps_on_the_trace(monkeypatch):
+    """Each landing opens one instant ``engine/landed`` annotation on the
+    thread that lands it, whose statistics are the cumulative stamps as
+    the counters hold them AFTER that landing, and how many dispatches it
+    landed: what ``busy_ms_per_step.traced`` takes a step's cost from, on
+    the device trace's clock."""
+    from polyrl_tpu.obs.engine_profile import LANDED_SPAN
+
+    opened = []
+
+    class _Rec:
+        def __init__(self, name, **kw):
+            opened.append((name, kw, threading.get_ident()))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Rec)
+    clock = _FakeClock()
+    prof = EngineLoopProfiler(clock=clock)
+    for _ in range(3):
+        prof.on_dispatch("step", steps=8, rows=4)
+    landed_on = []
+
+    def fetcher():
+        landed_on.append(threading.get_ident())
+        clock.advance(0.2)
+        prof.on_landed(1)
+        clock.advance(0.3)
+        prof.on_landed(2)
+
+    t = threading.Thread(target=fetcher)
+    t.start()
+    t.join(timeout=10)
+    stamps = [(kw, tid) for name, kw, tid in opened if name == LANDED_SPAN]
+    assert LANDED_SPAN == "engine/landed" and len(stamps) == 2
+    assert {tid for _kw, tid in stamps} == set(landed_on)
+    first, last = (kw for kw, _tid in stamps)
+    assert first == {"decode_steps_done": 8, "dispatches": 1,
+                     "device_busy_s": pytest.approx(0.2),
+                     "device_busy_at_s": pytest.approx(clock.t - 0.3)}
+    c = prof.counters()
+    assert last == {"decode_steps_done": c["decode_steps_done"],
+                    "dispatches": 2,
+                    "device_busy_s": pytest.approx(c["device_busy_s"]),
+                    "device_busy_at_s": pytest.approx(
+                        c["device_busy_at_s"])}
+    assert c["decode_steps_done"] == 24
+    # a landing of nothing outstanding still stamps, and lands nothing
+    prof.on_landed(1)
+    assert opened[-1][1]["dispatches"] == 0
+
+
+def test_pages_grown_is_gone_from_server_info(tiny):
+    """``pages_grown`` had no reader (a page a row every ``page_size``
+    tokens): neither the declaration, the profiler's seam nor
+    ``server_info`` carries it."""
+    assert "pages_grown" not in CUMULATIVE_KEYS
+    assert not hasattr(EngineLoopProfiler, "on_pages_grown")
+    eng = _mk_engine(tiny)
+    assert "pages_grown" not in eng.loop_profile_info()
+    assert "slot_yields" in eng.loop_profile_info()
+
+
 def test_marked_timer_annotates_the_trainer_phase(monkeypatch):
     """``marked_timer`` opens ``trainer/<name>`` on the device trace's
     clock unconditionally: ``obs.jax_annotations`` is gone."""

@@ -234,7 +234,7 @@ def test_pages_cover_every_position_written_with_the_pipeline_full(dense,
         if kind == "grouped":
             assert eng.grouped_decode_dispatches > 0
         c = eng.profiler.counters()
-        assert c["pages_grown"] > 0 and c["slot_yields"] == 0
+        assert c["slot_yields"] == 0
     finally:
         eng.stop()
     for q in outs:
@@ -356,7 +356,8 @@ def test_a_pool_too_small_makes_the_youngest_yield_and_come_back(
     tight, c_tight, eng = _run(model, 20, **opts)
     roomy, c_roomy, _ = _run(model, 64, **opts)
     assert c_tight["slot_yields"] > 0 == c_roomy["slot_yields"]
-    assert c_roomy["pages_grown"] > 0
+    # how many pages rows grew into is no counter: the tables above say it
+    assert "pages_grown" not in c_roomy
     for (toks, lps, fins, ends), (r_toks, r_lps, *_r) in zip(tight, roomy):
         # exactly its budget, each token once with its log-prob, one
         # terminal line and one end, none in between

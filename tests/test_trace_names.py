@@ -16,6 +16,9 @@ from polyrl_tpu.models import decoder
 from polyrl_tpu.rollout.cb_engine import CBEngine
 
 SCOPES = ("attn_qkv", "attn_core", "attn_out", "mlp", "head")
+# the dense decoder and one preset a family of mixers (``FAMILIES`` below)
+STEP_PRESETS = ("tiny", "moe-tiny", "hybrid-tiny", "mla-moe-tiny", "cca-tiny",
+                "sambay-tiny", "mixed-tiny", "ouro-tiny")
 ENGINE_PROGRAMS = {
     "step": lambda e: e._get_step(False, 2),
     "spec_step": lambda e: e._get_spec_step(False, 3, 2),
@@ -164,16 +167,17 @@ def test_trainer_programs_are_named_by_role(tiny):
     assert actor._logprob_fns[True].__wrapped__.__name__ == "actor_logprob"
 
 
-def test_decode_step_hlo_is_the_same_without_the_scopes(tiny, monkeypatch):
+@pytest.mark.parametrize("preset", STEP_PRESETS)
+def test_decode_step_hlo_is_the_same_without_the_scopes(preset, monkeypatch):
     """Scopes are metadata: with ``jax.named_scope`` made a no-op the
-    decode step lowers to the same StableHLO and compiles to the same HLO,
-    metadata aside — so no device number can move."""
-    with_scopes = _lower_step(_engine(tiny))
+    decode step of every family lowers to the same StableHLO and compiles
+    to the same HLO, metadata aside — so no device number can move."""
+    with_scopes = _lower_step(_family_engine(preset))
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
-    without = _lower_step(_engine(tiny))
-    assert "attn_core" in with_scopes.as_text(debug_info=True)
-    assert "attn_core" not in without.as_text(debug_info=True)
+    without = _lower_step(_family_engine(preset))
+    assert '"head/' in with_scopes.as_text(debug_info=True)
+    assert '"head/' not in without.as_text(debug_info=True)
     assert with_scopes.as_text() == without.as_text()
 
     def hlo(lowered):
@@ -292,6 +296,148 @@ def _scopes_read(cell: str) -> set:
     return found
 
 
+def _family_engine(preset: str):
+    cfg = decoder.get_config(preset, dtype=jnp.float32)
+    params = decoder.init_params(jax.random.PRNGKey(0), cfg)
+    return CBEngine(cfg, params, max_slots=4, page_size=8, max_seq_len=64,
+                    prompt_buckets=(16,), num_pages=32, steps_per_dispatch=2,
+                    kv_cache_dtype=jnp.float32)
+
+
+# -- the step's scope declaration (models/scopes.py) --------------------------
+
+# what a lowered line is besides an operation the device runs: constants,
+# a region's or a function's terminator, a call (its callee's operations
+# are walked with the call's path before theirs) and the loops themselves
+_NOT_OPERATIONS = ("stablehlo.constant", "stablehlo.return", "return",
+                   "func.return", "call", "func.call", "stablehlo.while",
+                   "stablehlo.case")
+# the only operations of a step under no scope: a ``lax.scan``'s own (its
+# counter, the slice of what it scans over, the stacking of its outputs),
+# which JAX emits directly in the loop's condition and body, outside the
+# body's function
+_SCANS_OWN = re.compile(
+    r"(?:/while/(?:cond|body))?/"
+    r"(?:lt|add|broadcast_in_dim|dynamic_update_slice|dynamic_slice|squeeze)")
+
+
+def _step_paths(text: str) -> list:
+    """(operation, scope path) of every operation of a lowered program
+    that carries a named location. ``as_text(debug_info=True)`` names an
+    operation by the scopes open around it INSIDE its function; the
+    scopes around a private function's call stand at the call, so the
+    functions are walked from ``main`` down, a call's path before its
+    callee's, once a distinct path."""
+    locs = dict(re.findall(r'^(#loc\d*) = loc\((.*)\)$', text, re.M))
+
+    def name_of(ref):
+        m = re.match(r'"([^"]*)"\(', locs.get(ref, ""))
+        return m.group(1) if m else None
+
+    funcs, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r'\s*func\.func (?:public|private) @([\w.]+)\(', line)
+        if m:
+            cur = funcs.setdefault(m.group(1), [])
+            continue
+        m = re.search(r'loc\((#loc\d*)\)\s*$', line)
+        if cur is None or m is None or line.lstrip().startswith("}"):
+            continue
+        op = re.match(r'\s*(?:%[\w:#]+(?:, %[\w:#]+)* = )?"?([\w.]+)"?', line)
+        callee = re.search(r'call @([\w.]+)\(', line)
+        cur.append((op.group(1), name_of(m.group(1)),
+                    callee.group(1) if callee else None))
+    out, seen = [], set()
+
+    def walk(fn, ctx):
+        if (fn, ctx) in seen:
+            return
+        seen.add((fn, ctx))
+        for op, name, callee in funcs[fn]:
+            path = ctx + ("/" + name if name else "")
+            if callee is not None:
+                walk(callee, path)
+            elif name is not None and op not in _NOT_OPERATIONS:
+                out.append((op, path))
+
+    walk("main", "")
+    return out
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["sampler", "fused"])
+@pytest.mark.parametrize("preset", STEP_PRESETS)
+def test_every_operation_of_a_step_lies_under_one_declared_leaf(
+        preset, fused, monkeypatch):
+    """``models/scopes.py``'s invariant, a family: every operation of the
+    lowered decode step that carries a location lies under exactly ONE
+    declared leaf scope, inside no container but those the declaration
+    lets that leaf sit in; the exceptions are a scan's own operations.
+    ``benchmark/lib/account.py`` partitions a traced step by these
+    leaves, and what is left under none is what XLA made itself."""
+    from polyrl_tpu.models.scopes import CONTAINER_SCOPES, LEAF_SCOPES
+
+    if fused:
+        monkeypatch.setattr(decoder, "samples_in_head",
+                            lambda cfg, params, use_filters, many: True)
+    text = _lower_step(_family_engine(preset)).as_text(debug_info=True)
+    found = _step_paths(text)
+    assert len(found) > 500
+    seen = set()
+    for op, path in found:
+        parts = re.findall(r"[\w.]+", path)
+        leaves = {c for c in parts if c in LEAF_SCOPES}
+        seen |= leaves
+        if not leaves:
+            # what follows the innermost function's call, or the program
+            own = path.rsplit("/closed_call", 1)[-1].removeprefix(
+                "/jit(step)")
+            assert _SCANS_OWN.fullmatch(own), (preset, op, path)
+            continue
+        assert len(leaves) == 1, (preset, op, path)
+        for box in (c for c in parts if c in CONTAINER_SCOPES):
+            assert leaves <= set(CONTAINER_SCOPES[box]), (preset, op, path)
+    assert {"embed", "glue", "head", "sample"} <= seen
+    if preset == "ouro-tiny":
+        assert "ut_norm" in seen
+
+
+def test_the_declaration_holds_every_scope_the_models_open():
+    """One declaration: every scope that ``polyrl_tpu/models`` and the
+    engine's step open by name is a declared leaf or container, every
+    declared leaf is opened somewhere (none is stale), a container's
+    leaves are leaves, and a mixer's record names declared leaves for its
+    pages and its slot (``Mixer.__post_init__`` refuses another)."""
+    import glob
+    import os
+
+    from polyrl_tpu.models import scopes
+    from polyrl_tpu.models.mixers import MIXERS
+    from polyrl_tpu.models.mixers.base import Mixer
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    opened = set()
+    for path in (glob.glob(os.path.join(root, "polyrl_tpu", "models",
+                                        "**", "*.py"), recursive=True)
+                 + [os.path.join(root, "polyrl_tpu", "rollout",
+                                 "cb_engine.py")]):
+        with open(path) as f:
+            opened.update(re.findall(r'named_scope\("(\w+)"\)', f.read()))
+    declared = set(scopes.LEAF_SCOPES) | set(scopes.CONTAINER_SCOPES)
+    assert opened == declared, opened ^ declared
+    assert len(set(scopes.LEAF_SCOPES)) == len(scopes.LEAF_SCOPES)
+    assert not set(scopes.LEAF_SCOPES) & set(scopes.CONTAINER_SCOPES)
+    for leaves in scopes.CONTAINER_SCOPES.values():
+        assert set(leaves) <= set(scopes.LEAF_SCOPES)
+    for rec in MIXERS.values():
+        for name in (rec.pages_scope, rec.slot_scope):
+            assert name == "" or name in scopes.LEAF_SCOPES, (rec.name, name)
+        assert rec.pages_scope or rec.slot_scope or rec.name in (
+            "gmu", "cross")
+    with pytest.raises(ValueError, match="not declared"):
+        Mixer("other", cache=None, slot_scope="other_core")
+    assert set(STEP_PRESETS[1:]) == set(FAMILIES)
+
+
 @pytest.mark.parametrize("preset", list(FAMILIES))
 def test_a_familys_programs_carry_the_scopes_and_the_load_its_cell_reads(
         preset):
@@ -304,13 +450,10 @@ def test_a_familys_programs_carry_the_scopes_and_the_load_its_cell_reads(
     from polyrl_tpu.obs.engine_profile import CUMULATIVE_KEYS
 
     cell, load = FAMILIES[preset]
-    cfg = decoder.get_config(preset, dtype=jnp.float32)
     scopes = _scopes_read(cell)
     assert {"head", "sample"} < scopes and len(scopes) >= 4
-    params = decoder.init_params(jax.random.PRNGKey(0), cfg)
-    eng = CBEngine(cfg, params, max_slots=4, page_size=8, max_seq_len=64,
-                   prompt_buckets=(16,), num_pages=32, steps_per_dispatch=2,
-                   kv_cache_dtype=jnp.float32)
+    eng = _family_engine(preset)
+    cfg = eng.cfg
     assert hybrid.load_names(cfg) == load
     assert hybrid.load_width(cfg) == len(load)
     # where ``server_info`` has them: a routed model's in ``moe_info``,

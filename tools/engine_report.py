@@ -176,7 +176,7 @@ def _render_engine(loop: dict) -> list[str]:
             f"{steps} decode steps landed of {c.get('decode_dispatches', 0)} "
             f"dispatches ({c.get('decode_dispatches_cold', 0)} onto a dry "
             f"device, {c.get('admission_deferrals', 0)} admissions "
-            f"deferred, {c.get('pages_grown', 0)} pages grown into, "
+            f"deferred, "
             f"{c.get('slot_yields', 0)} rows yielded; "
             f"{c.get('fused_sample_steps', 0)} steps sampled "
             f"inside the head, {c.get('kda_kernel_steps', 0)} updated "

@@ -28,17 +28,14 @@ sys.path.insert(0, ROOT)
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-SCOPES = ("ssm_proj", "ssm_core", "swa_core", "attn_core", "attn_qkv",
-          "attn_out", "diff_mix", "gmu", "kda_proj", "kda_core", "mla_proj",
-          "mla_core", "cca_proj", "cca_mix", "moe_route", "moe_experts",
-          "mlp", "head")
-
-
 def reduce(trace_dir: str, program: str, top: int) -> dict:
     """Device ms a run of ``jit_<program>`` in the newest trace under
-    ``trace_dir``: whole programs, by the innermost scope of ``SCOPES`` an
-    operation lies under, and the ``top`` operations with their scope."""
-    from benchmark.lib import tracered, xspans
+    ``trace_dir``: whole programs, by the innermost leaf scope an
+    operation lies under of those the program declares
+    (``models/scopes.py``, as ``benchmark/lib/account.py`` partitions a
+    decode step), and the ``top`` operations with their scope."""
+    from benchmark.lib import account, tracered, xspans
+    from polyrl_tpu.models.scopes import LEAF_SCOPES
 
     path = max(glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
                                       "*.xplane.pb")), key=os.path.getmtime)
@@ -53,8 +50,7 @@ def reduce(trace_dir: str, program: str, top: int) -> dict:
                 or tracered.op_key(name).split(" ")[0] == "cond"
                 or not any(a <= start < b for a, b in progs)):
             continue
-        at = max(((scope_path.rfind(s), s) for s in SCOPES
-                  if xspans._in_scope(scope_path, s)), default=(0, "none"))[1]
+        at = account.innermost(scope_path, LEAF_SCOPES)
         by[at] += dur
         ops[f"{at}: {tracered.op_key(name)}"] += dur
     n = max(len(progs), 1)
