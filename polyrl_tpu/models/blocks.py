@@ -17,8 +17,10 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from polyrl_tpu.models import cache_spec
-from polyrl_tpu.models.quant import QuantWeight, mm, moe_mm, unembed
-from polyrl_tpu.ops.grouped_matmul import row_tile, tiled_layout
+from polyrl_tpu.models.quant import (QuantWeight, mm, moe_mm, moe_rows,
+                                     unembed)
+from polyrl_tpu.ops import grouped_matmul
+from polyrl_tpu.ops.grouped_matmul import row_tables, row_tile, tiled_layout
 from polyrl_tpu.parallel.mesh import EP, TP
 
 
@@ -84,6 +86,28 @@ def _context_mesh():
     has more than one device, else None."""
     mesh = jax.sharding.get_abstract_mesh()
     return None if mesh.empty or mesh.size == 1 else mesh
+
+
+def experts_in_kernel(cfg, rows: int) -> bool:
+    """Whether ``_moe_mlp`` over ``rows`` tokens on one chip takes its
+    experts' rows by table (``grouped_matmul.expert_rows``): the engine's
+    question for its ``moe_gather_kernel_steps``; the shapes alone
+    (``grouped_matmul.rows_by_table``) and a TPU decide."""
+    return bool(cfg.num_experts) and grouped_matmul.in_kernel(
+        rows, cfg.hidden_size, jnp.dtype(cfg.dtype).itemsize,
+        rows * cfg.num_experts_per_tok, cache_spec.experts_held(cfg)[1])
+
+
+def _expert_rows(x, experts, layer, token_of, weight, sizes):
+    """``_expert_mix`` at a decode step's shapes on one TPU
+    (``grouped_matmul.in_kernel``), where no tiled copy of the rows is
+    made: the two kernels take a tile's rows from ``x`` by ``token_of``
+    and add each product, times ``weight`` [N*k] (the routing weights in
+    the sorted rows' order), into its token's sum themselves
+    (``moe_rows``). A token's k terms are added in its experts' order."""
+    tile = row_tile(token_of.shape[0], sizes.shape[0])
+    return moe_rows(x, experts, row_tables(sizes, token_of, tile), weight,
+                    tile, layer)
 
 
 def _expert_mix(x, experts, layer, token_of, place, choice, top_p, sizes,
@@ -241,7 +265,10 @@ def _moe_mlp(cfg, x: jnp.ndarray, lp: dict,
     grouped matmuls over the sorted rows (``_expert_mix``: whatever the
     imbalance, no choice is dropped and no expert multiplies a row that
     did not choose it), and each token sums its k results with the routing
-    weights in float32. One block serves decode, prefill and the trainer.
+    weights in float32. One block serves decode, prefill and the trainer;
+    at a decode step's shapes on one TPU the kernels take the sorted rows
+    from ``x`` and sum them back themselves (``_expert_rows``: the shapes
+    choose, ``grouped_matmul.in_kernel``).
 
     ``valid`` [N] (padding, decode rows without a request): an invalid
     token routes nowhere and returns zero.
@@ -283,23 +310,35 @@ def _moe_mlp(cfg, x: jnp.ndarray, lp: dict,
             top_p = jnp.where(valid[:, None], top_p, 0.0)
             # expert ``e`` is none: it sorts last and counts nowhere
             choice = jnp.where(jnp.repeat(valid, k), choice, e)
-        order = jnp.argsort(choice, stable=True)   # sorted row -> choice
-        place = jnp.argsort(order)                 # choice -> sorted row
+        mesh = _context_mesh()
+        by_table = mesh is None and grouped_matmul.in_kernel(
+            n, d, x.dtype.itemsize, n * k, e)
+        if by_table:
+            # ONE sort: the kernels read a sorted row's weight beside its
+            # token, and no choice looks its row up
+            _, order, weight = jax.lax.sort(
+                (choice, jnp.arange(n * k), top_p.reshape(n * k)), num_keys=1)
+        else:
+            order = jnp.argsort(choice, stable=True)  # sorted row -> choice
+            place = jnp.argsort(order)                # choice -> sorted row
         sizes = jnp.sum(jax.nn.one_hot(choice, e, dtype=jnp.int32), axis=0)
         load = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0),
                           jnp.max(sizes)])
     with jax.named_scope("moe_experts"):
         experts = {key: lp[key] for key in EXPERT_KEYS}
-        route = (order // k, place, choice, top_p, sizes)
-        mesh = _context_mesh()
-        if mesh is None:
-            out = _expert_mix(x, experts, layer, *route)
+        token_of = order // k
+        if by_table:
+            out = _expert_rows(x, experts, layer, token_of, weight, sizes)
+        elif mesh is None:
+            out = _expert_mix(x, experts, layer, token_of, place, choice,
+                              top_p, sizes)
         elif e != cfg.num_experts:
             raise NotImplementedError(
                 "a share of the experts (experts_held) on a mesh: the ep "
                 "axis holds them all")
         else:
-            out = _expert_mix_sharded(mesh, x, experts, layer, *route)
+            out = _expert_mix_sharded(mesh, x, experts, layer, token_of,
+                                      place, choice, top_p, sizes)
     if "ws_gate" in lp:
         with jax.named_scope("moe_shared"):
             gate = jax.nn.silu(mm(x, lp["ws_gate"]).astype(jnp.float32))

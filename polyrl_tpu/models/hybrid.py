@@ -55,7 +55,8 @@ import jax.numpy as jnp
 from polyrl_tpu.models import cache_spec
 from polyrl_tpu.models.blocks import (EXPERT_KEYS, _gather_slabs_kv, _head,
                                       _latent_route, _moe_mlp,
-                                      _scatter_pages_kv, _scatter_slabs, norm)
+                                      _scatter_pages_kv, _scatter_slabs,
+                                      experts_in_kernel, norm)
 from polyrl_tpu.models.mixers import MIXERS
 from polyrl_tpu.models.mixers.base import Chunk, Load, SlotRows, Step
 from polyrl_tpu.models.quant import mm
@@ -635,13 +636,18 @@ def load_width(cfg) -> int:
     return len(load_names(cfg))
 
 
-def step_counters(cfg, rows: int) -> tuple[str, ...]:
+def step_counters(cfg, rows: int, one_chip: bool = True) -> tuple[str, ...]:
     """The share counters (``engine_profile.CUMULATIVE_KEYS``) that every
     decode step of ``rows`` rows moves: those of the kernels its layers
-    take (``Mixer.kernel``)."""
+    take (``Mixer.kernel``), and the routed MLP's where its experts take
+    their rows by table (``blocks.experts_in_kernel``; on a mesh of
+    several chips they keep the tiled form)."""
     kinds = dict.fromkeys(p.mixer for p in cache_spec.layer_plan(cfg))
+    routed = (("moe_gather_kernel_steps",)
+              if one_chip and experts_in_kernel(cfg, rows) else ())
     return tuple(MIXERS[k].kernel[0] for k in kinds
-                 if MIXERS[k].kernel and MIXERS[k].kernel[1](cfg, rows))
+                 if MIXERS[k].kernel and MIXERS[k].kernel[1](cfg, rows)
+                 ) + routed
 
 
 def held_state(cfg, state: tuple, slot: int) -> list:

@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from polyrl_tpu.ops.grouped_matmul import grouped_matmul
+from polyrl_tpu.ops.grouped_matmul import expert_rows, grouped_matmul
 
 # layer-stacked matmul weights that get quantized ([L, in, out]);
 # embed stays bf16 (it is a gather, not a matmul), norms/biases are tiny
@@ -156,6 +156,15 @@ def moe_mm(x, ws: tuple, lay, layer: int | None = None):
     (1.2 GB at qwen3-30b-a3b's widths) every step. So the stack goes in
     whole, as L*E groups of which only this layer's have rows; a group
     without rows is not read."""
+    ws, scales, lay = _layer_groups(ws, lay, layer)
+    return grouped_matmul(x, ws, scales, lay)
+
+
+def _layer_groups(ws: tuple, lay, layer: int | None):
+    """``moe_mm``'s weights as the kernels take them: (the stacks, their
+    scales or None, ``lay``), QuantWeights apart into both, and with
+    ``layer`` the stacks [L, E, ..] as L*E groups, ``lay``'s tiles on that
+    layer's (``lay``: a ``TiledLayout`` or a ``RowTables``)."""
     scales = None
     if isinstance(ws[0], QuantWeight):
         ws, scales = tuple(w.q for w in ws), tuple(w.scale for w in ws)
@@ -168,7 +177,21 @@ def moe_mm(x, ws: tuple, lay, layer: int | None = None):
             tile_group=lay.tile_group + layer * e,
             padded_sizes=jnp.pad(lay.padded_sizes,
                                  (layer * e, (n_layers - 1 - layer) * e)))
-    return grouped_matmul(x, ws, scales, lay)
+    return ws, scales, lay
+
+
+def moe_rows(x, experts: dict, tab, weight, tile: int,
+             layer: int | None = None):
+    """The routed experts of the tokens ``x`` [N, d] at a decode step's
+    shapes, [N, d] float32 (``ops.grouped_matmul.expert_rows``): SwiGLU of
+    ``we_gate`` and ``we_up`` and ``we_down`` over the rows ``tab`` names
+    (``row_tables``), each times its ``weight`` and summed into its token;
+    QuantWeights and ``layer`` as ``moe_mm`` takes them."""
+    ws, scales, tab = _layer_groups(
+        (experts["we_gate"], experts["we_up"], experts["we_down"]), tab,
+        layer)
+    return expert_rows(x, ws[:2], ws[2:], scales and scales[:2],
+                       scales and scales[2:], tab, weight, tile)
 
 
 def unembed(x, head, eq: str):

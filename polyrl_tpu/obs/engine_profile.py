@@ -87,6 +87,9 @@ emission or iteration, never once a token, all on this profiler's clock:
   in the one-pass kernel (``ops/kda_state.py``);
   ``mla_proj_kernel_steps`` — the steps whose program multiplied its MLA
   layers' ``wkv_b`` where it lies in the stack (``ops/mla_proj.py``);
+  ``moe_gather_kernel_steps`` — the steps whose program's expert calls
+  took their rows from the tokens by table and summed them back
+  themselves (``ops/grouped_matmul.py::expert_rows``);
   ``ssm_state_rows`` / ``shared_kv_rows_read`` / ``window_rows_read`` —
   for a model of the SambaY family, counted by the landed steps on the
   device (the ``counts`` of its mixers' records, ``models/mixers``): live
@@ -185,7 +188,8 @@ STALL_GAP_S = 2.0
 CUMULATIVE_KEYS = (
     "decode_dispatches", "decode_dispatches_cold", "admission_deferrals",
     "slot_yields", "decode_steps_done", "fused_sample_steps",
-    "kda_kernel_steps", "mla_proj_kernel_steps", "row_steps_done",
+    "kda_kernel_steps", "mla_proj_kernel_steps", "moe_gather_kernel_steps",
+    "row_steps_done",
     "ssm_state_rows",
     "shared_kv_rows_read",
     "window_rows_read",

@@ -1,5 +1,11 @@
 """Grouped matmul for the MoE expert projections: every row multiplies
-with its own group's matrix.
+with its own group's matrix, in one of two forms chosen by the shapes
+alone (``rows_by_table``; on a TPU, ``in_kernel``): a decode step's few
+rows an expert are taken from the tokens by a prefetched table inside the
+gate/up kernel and summed back into each token by the down kernel
+(``expert_rows``, the file's second half: no tiled copy of the rows exists
+outside the kernels); a prefill chunk's or the trainer's thousands come
+tiled, as follows, and so does everything off a TPU and on a mesh.
 
 The rows come tiled (``tiled_layout``): group g's rows are contiguous and
 padded with zero rows to whole tiles of ``tile`` rows, so a tile belongs to
@@ -13,28 +19,22 @@ each slab's product summed into a float32 accumulator a weight, the
 output block written once a tile. The grid is (tiles, slabs), slabs
 innermost, under ``BlockSpec``'s own double-buffered pipeline. Matrices
 that fit 8 MiB a step (gate and up together) go in whole, ONE slab, and
-then consecutive tiles of one group reuse the block already in VMEM: a
-group's weights are read once a call however many tiles it fills. Larger
-ones are cut into slabs of about 4 MiB, and a group of several tiles then
-reads its weights once a TILE: decode has one tile a group (``row_tile``
-is twice the mean); a prefill chunk or the trainer's forward over such
-matrices in tiles of 256 rows is bound by the MXU either way (256 FLOPs a
-weight byte against the chip's 240). Why slabs and not column blocks: a
-block [K, tn] of a row-major matrix is K strided runs, which at
-dots.vlm1's widths (runs of 512 B) read 86% of 819 GB/s where a slab
-reads 92%, what a whole matrix reads and this chip's ceiling for a
-stream; and nothing beyond the pipeline's two buffers is needed for it
-(PERF.md section 6, PR 45).
+then consecutive tiles of one group reuse the block already in VMEM.
+Larger ones are cut into slabs of about 4 MiB, and a group of several
+tiles then reads its weights once a TILE: decode has one tile a group
+(``row_tile`` is twice the mean); tiles of 256 rows are bound by the MXU
+either way (256 FLOPs a weight byte against the chip's 240). Why slabs
+and not column blocks: a block [K, tn] of a row-major matrix is K strided
+runs, which at dots.vlm1's widths (runs of 512 B) read 86% of 819 GB/s
+where a slab reads 92%, this chip's ceiling (PERF.md section 6, PR 45).
 
 Why not ``jax.lax.ragged_dot`` on the TPU: XLA lowers it to a grouped
 kernel of its own, which at decode shapes (512 rows over 128 experts of
 2048x768) reads the weights at 210 GB/s, against 708 GB/s for the dense
 batched einsum over every expert that this block replaces (PERF.md section
-6, PR 27). It stays the CPU path and the gradient's (``grouped_matmul`` has
-a custom VJP whose cotangents are ``ragged_dot``'s own). So there are two
-implementations, not one: the forward is this kernel on a TPU, every
-gradient is XLA's. At a trainer's shapes the pair was timed once and
-tuned never: a layer's forward and backward over 16,384 tokens take 106 ms,
+6, PR 27). It stays the CPU path and every gradient's (the custom VJPs'
+cotangents are XLA's over ``_ragged``). At a trainer's shapes the pair was
+timed once: a layer's forward and backward over 16,384 tokens take 106 ms,
 35 TFLOP/s of useful work (PERF.md section 6, PR 27).
 """
 
@@ -257,3 +257,289 @@ def _bwd(res, dy):
 
 
 grouped_matmul.defvjp(_fwd, _bwd)
+
+
+# --- a decode step's rows: taken from the tokens by table, summed back ---
+
+# what the tokens' two blocks [n, d] and their float32 result's two may
+# take of VMEM beside the slabs (``rows_by_table``)
+_TOKENS_BYTES = 8 * 2**20
+
+
+class RowTables(NamedTuple):
+    """Where the rows of the group-sorted (token, group) choices lie in
+    whole tiles a group, by tile and not by row (``row_tables``): what
+    the two kernels below prefetch."""
+
+    tile_group: jnp.ndarray    # [T] group of each tile
+    tiles_run: jnp.ndarray     # [1] tiles the grid visits: max(used, 1)
+    tile_start: jnp.ndarray    # [T] sorted row a tile's first row holds
+    tile_count: jnp.ndarray    # [T] live rows of a tile
+    padded_sizes: jnp.ndarray  # [G] rows a group, padded to whole tiles
+    token_of: jnp.ndarray      # [M] token of each sorted row
+
+
+def rows_by_table(n: int, d: int, itemsize: int, m: int, g: int) -> bool:
+    """Whether ``n`` tokens of width ``d`` whose ``m`` choices fall on
+    ``g`` groups take the table form (``expert_rows``) and not the tiled
+    one: a few rows a group, ``row_tile(m, g) < 256`` (at 256 a tile is
+    bound by the MXU and its rows are a prefill chunk's or the trainer's,
+    thousands: copying each costs what it multiplies), and the tokens and
+    their float32 result stay in VMEM for a whole call: two blocks of each,
+    ``2 * n * d * (itemsize + 4)`` bytes, within ``_TOKENS_BYTES``, which
+    with two slabs in flight (2 x 8 MiB at most), the gate/up call's
+    float32 copy of the tokens (half the result's two blocks) and a tile's
+    sums and rows (a tile under 256 rows: under 8 MiB at d = 7168) stays
+    under ``_VMEM_LIMIT_BYTES``. In bf16 at d = 2048 that is 341 tokens:
+    every decode step of the cells (65 or 129 rows: 1.6 to 5.6 MiB), and
+    no 512-token prefill chunk (12 MiB and more) nor trainer batch."""
+    return (row_tile(m, g) < 256
+            and 2 * n * d * (itemsize + 4) <= _TOKENS_BYTES)
+
+
+def in_kernel(n: int, d: int, itemsize: int, m: int, g: int) -> bool:
+    """Whether the experts of such a call run ``expert_rows`` here: on a
+    TPU, at the shapes ``rows_by_table`` takes."""
+    return jax.default_backend() == "tpu" and rows_by_table(n, d, itemsize,
+                                                            m, g)
+
+
+def row_tables(sizes: jnp.ndarray, token_of: jnp.ndarray, tile: int,
+               first_row=0) -> RowTables:
+    """``tiled_layout``'s tiles without its rows: ``sizes`` [G] rows a
+    group, ``token_of`` [M] the token of each choice sorted by group, of
+    which these groups' rows start at sorted row ``first_row``. Tile j
+    holds the ``tile_count[j]`` sorted rows from ``tile_start[j]`` on, all
+    of group ``tile_group[j]``; T = M // tile + G tiles bound every
+    routing, and a call without a row still visits tile 0, which then
+    holds none. A group's numbers reach its tiles through the [T, G]
+    one-hot, as in ``tiled_layout``."""
+    g = sizes.shape[0]
+    n_tiles = token_of.shape[0] // tile + g
+    tiles = -(-sizes // tile)                                   # [G]
+    tile_end = jnp.cumsum(tiles)
+    at = jnp.arange(n_tiles)
+    j = jnp.minimum(at, tile_end[-1] - 1)
+    tile_group = jnp.sum(tile_end[None, :] <= j[:, None], axis=1)
+    of_group = tile_group[:, None] == jnp.arange(g)[None, :]     # [T, G]
+
+    def a_tile(table):       # table[tile_group], without a gather
+        return jnp.sum(jnp.where(of_group, table[None, :], 0), axis=1)
+
+    before = (at - a_tile(tile_end - tiles)) * tile   # of the group's rows
+    i32 = jnp.int32
+    return RowTables(
+        tile_group.astype(i32), jnp.maximum(tile_end[-1:], 1).astype(i32),
+        (first_row + a_tile(jnp.cumsum(sizes) - sizes) + before).astype(i32),
+        jnp.clip(a_tile(sizes) - before, 0, tile).astype(i32),
+        (tiles * tile).astype(i32), token_of.astype(i32))
+
+
+def _gather_kernel(tile_group_ref, tiles_run_ref, start_ref, count_ref,
+                   token_ref, x_ref, *refs, n_w, scaled, n_slabs):
+    """``_kernel`` on a tile whose rows it takes from the tokens itself:
+    before a tile's first slab, row r of the tile is token
+    ``token_of[start + r]``'s for r under the tile's count, copied from
+    the tokens' float32 copy (made once a call), and a zero row past it."""
+    rows_ref, rows32_ref, x32_ref = refs[-3:]
+    j, s = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((j == 0) & (s == 0))
+    def _tokens():
+        x32_ref[...] = x_ref[...].astype(jnp.float32)
+
+    @pl.when(s == 0)
+    def _rows():
+        start, count = start_ref[j], count_ref[j]
+
+        def copy(r, carry):
+            rows32_ref[pl.ds(r, 1), :] = x32_ref[
+                pl.ds(token_ref[start + r], 1), :]
+            return carry
+
+        jax.lax.fori_loop(0, count, copy, 0)
+        tile, tk = rows_ref.shape[1:]
+        live = jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0) < count
+        rows = jnp.where(live, rows32_ref[...], 0.0).astype(rows_ref.dtype)
+        for i in range(n_slabs):
+            rows_ref[i] = rows[:, i * tk:(i + 1) * tk]
+
+    _kernel(tile_group_ref, tiles_run_ref, rows_ref.at[s], *refs[:-3],
+            n_w=n_w, scaled=scaled, n_slabs=n_slabs)
+
+
+def _scatter_kernel(tile_group_ref, tiles_run_ref, start_ref, count_ref,
+                    token_ref, weight_ref, h_ref, *refs, scaled, n_slabs):
+    """``_kernel`` on a tile of ``hidden`` whose products it hands to
+    their tokens itself: after a tile's last slab the products, rounded
+    once as the tiled form rounds them, are added row by live row, each
+    times its sorted row's weight, into the tokens' float32 result, which is
+    zeroed at the call's first step and stays in VMEM to its last. A pad
+    row is never read."""
+    o_ref, y_ref, y32_ref = refs[1 + scaled], refs[-2], refs[-1]
+    j, s = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((j == 0) & (s == 0))
+    def _zero():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    _kernel(tile_group_ref, tiles_run_ref, h_ref, *refs[:1 + scaled], y_ref,
+            *refs[2 + scaled:-2], n_w=1, scaled=scaled, n_slabs=n_slabs)
+
+    @pl.when(s == n_slabs - 1)
+    def _add():
+        y32_ref[...] = y_ref[...].astype(jnp.float32)
+        start = start_ref[j]
+
+        def add(r, carry):
+            o_ref[pl.ds(token_ref[start + r], 1), :] += (
+                weight_ref[start + r] * y32_ref[pl.ds(r, 1), :])
+            return carry
+
+        jax.lax.fori_loop(0, count_ref[j], add, 0)
+
+
+def _tables_call(kernel, tab: RowTables, prefetch, operands, in_specs,
+                 out_shape, out_spec, scratch, n_slabs, interpret):
+    """One of the two kernels over the tiles ``tab`` visits and the slabs
+    of each, slabs innermost, ``prefetch`` in SMEM."""
+    return pl.pallas_call(
+        kernel, out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(tab.tiles_run[0], n_slabs), in_specs=in_specs,
+            out_specs=out_spec, scratch_shapes=scratch),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        name="grouped_matmul", interpret=interpret,
+    )(*prefetch, *operands)
+
+
+def _weight_specs(g, n, tk, n_w, scales):
+    """The slabs' and the scales' blocks, chosen by a tile's group, and
+    the scales as the kernels take them."""
+    w_spec = pl.BlockSpec((None, tk, n), lambda j, s, tg, *_: (tg[j], s, 0))
+    s_spec = pl.BlockSpec((None, 1, n), lambda j, s, tg, *_: (tg[j], 0, 0))
+    scales = [s.astype(jnp.float32).reshape(g, 1, n) for s in scales or ()]
+    return [w_spec] * n_w + [s_spec] * len(scales), scales
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret", "slab"))
+def gather_matmul_pallas(x, ws, tab: RowTables, scales=None, *, tile: int,
+                         interpret: bool = False, slab: int | None = None):
+    """``grouped_matmul_pallas`` of the tokens ``x`` [n, K] themselves:
+    the result [T*tile, N] in the tiled layout, tile j's rows the tokens
+    ``tab`` names (zero rows past its count). ``x`` is one block, fetched
+    once a call; no tiled copy of it exists outside the kernel."""
+    n_tok, k = x.shape
+    g, _k, n = ws[0].shape
+    n_w = len(ws)
+    tk = slab or _slab_plan(k, n, ws[0].dtype.itemsize, n_w)
+    n_slabs = k // tk
+    w_specs, scales = _weight_specs(g, n, tk, n_w, scales)
+    prefetch = (tab.tile_group, tab.tiles_run, tab.tile_start,
+                tab.tile_count, tab.token_of)
+    f32 = jnp.float32
+    return _tables_call(
+        functools.partial(_gather_kernel, n_w=n_w, scaled=bool(scales),
+                          n_slabs=n_slabs),
+        tab, prefetch, (x, *ws, *scales),
+        [pl.BlockSpec((n_tok, k), lambda j, s, *_: (0, 0))] + w_specs,
+        jax.ShapeDtypeStruct((tab.tile_group.shape[0] * tile, n), x.dtype),
+        pl.BlockSpec((tile, n), lambda j, s, *_: (j, 0)),
+        [pltpu.VMEM((tile, n), f32)] * (n_w if n_slabs > 1 else 0)
+        # a tile's rows a slab, the same in float32, the tokens in float32
+        + [pltpu.VMEM((n_slabs, tile, tk), x.dtype),
+           pltpu.VMEM((tile, k), f32), pltpu.VMEM((n_tok, k), f32)],
+        n_slabs, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("n_tokens", "tile", "interpret",
+                                             "slab"))
+def matmul_scatter_pallas(hidden, ws, tab: RowTables, weights, scales=None,
+                          *, n_tokens: int, tile: int,
+                          interpret: bool = False, slab: int | None = None):
+    """``hidden`` [T*tile, K] in the tiled layout times ``ws[0]`` [G, K,
+    N] as ``grouped_matmul_pallas`` multiplies it, and each live row of
+    the rounded product, times its weight (``weights`` [M] float32, in
+    the sorted rows' order), summed into its token's row: [n_tokens, N]
+    float32, ONE block that is written once a call; zero for a token
+    without a row, and for every token of a call without one."""
+    _m, k = hidden.shape
+    g, _k, n = ws[0].shape
+    tk = slab or _slab_plan(k, n, ws[0].dtype.itemsize, 1)
+    n_slabs = k // tk
+    w_specs, scales = _weight_specs(g, n, tk, 1, scales)
+    prefetch = (tab.tile_group, tab.tiles_run, tab.tile_start,
+                tab.tile_count, tab.token_of, weights.astype(jnp.float32))
+    f32 = jnp.float32
+    return _tables_call(
+        functools.partial(_scatter_kernel, scaled=bool(scales),
+                          n_slabs=n_slabs),
+        tab, prefetch, (hidden, *ws, *scales),
+        [pl.BlockSpec((tile, tk), lambda j, s, *_: (j, s))] + w_specs,
+        jax.ShapeDtypeStruct((n_tokens, n), f32),
+        pl.BlockSpec((n_tokens, n), lambda j, s, *_: (0, 0)),
+        [pltpu.VMEM((tile, n), f32)] * (n_slabs > 1)
+        # a tile's products as rounded, and the same in float32
+        + [pltpu.VMEM((tile, n), hidden.dtype), pltpu.VMEM((tile, n), f32)],
+        n_slabs, interpret)
+
+
+def _rows_plain(x, ws_in, ws_out, scales_in, scales_out, tab: RowTables,
+                weights, tile: int):
+    """What the two kernels compute, in XLA's operations: the tokens
+    gathered into the tiled layout, ``_ragged`` twice, each live row times
+    its weight summed into its token. The gradient's form."""
+    m = tab.token_of.shape[0]
+    r = jnp.arange(tile)[None, :]
+    live = (r < tab.tile_count[:, None]).reshape(-1)
+    src = jnp.clip(tab.tile_start[:, None] + r, 0, m - 1).reshape(-1)
+    token = tab.token_of[src]
+    lay = TiledLayout(tab.tile_group, tab.tiles_run, tab.padded_sizes, src,
+                      live, None)
+    xs = jnp.where(live[:, None], x[token], 0)
+    ys = _ragged(_ragged(xs, ws_in, scales_in, lay), ws_out, scales_out, lay)
+    w = weights.astype(jnp.float32)[src]
+    ys = jnp.where(live[:, None], ys.astype(jnp.float32) * w[:, None], 0.0)
+    return jnp.zeros((x.shape[0], ys.shape[1]), jnp.float32).at[token].add(ys)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def expert_rows(x, ws_in, ws_out, scales_in, scales_out, tab: RowTables,
+                weights, tile: int):
+    """Each token's weighted sum over its choices' experts, [n, N] float32:
+    ``x`` [n, K] through SwiGLU of ``ws_in`` (gate, up: [G, K, F]) and
+    ``ws_out`` ((down,): [G, F, N]), ``scales_*`` as
+    ``grouped_matmul_pallas`` takes them, the choices and their tiles in
+    ``tab`` (``row_tables``), ``weights`` [M] float32 a sorted row. The two
+    kernels (interpreted off a TPU: tests alone get there; the callers ask
+    ``in_kernel``), between them only ``hidden`` in the tiled layout.
+    Differentiable in ``x``, the weights' stacks and ``weights``: the
+    cotangents are XLA's over ``_rows_plain``."""
+    interpret = jax.default_backend() != "tpu"
+    hidden = gather_matmul_pallas(x, ws_in, tab, scales_in, tile=tile,
+                                  interpret=interpret)
+    return matmul_scatter_pallas(hidden, ws_out, tab, weights, scales_out,
+                                 n_tokens=x.shape[0], tile=tile,
+                                 interpret=interpret)
+
+
+def _rows_fwd(x, ws_in, ws_out, scales_in, scales_out, tab, weights, tile):
+    return (expert_rows(x, ws_in, ws_out, scales_in, scales_out, tab, weights,
+                        tile),
+            (x, ws_in, ws_out, scales_in, scales_out, tab, weights))
+
+
+def _rows_bwd(tile, res, dy):
+    x, ws_in, ws_out, scales_in, scales_out, tab, weights = res
+    _y, vjp = jax.vjp(
+        lambda x, ws_in, ws_out, weights: _rows_plain(
+            x, ws_in, ws_out, scales_in, scales_out, tab, weights, tile),
+        x, ws_in, ws_out, weights)
+    dx, dws_in, dws_out, dweights = vjp(dy)
+    return dx, dws_in, dws_out, None, None, None, dweights
+
+
+expert_rows.defvjp(_rows_fwd, _rows_bwd)

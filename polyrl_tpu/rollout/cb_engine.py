@@ -312,7 +312,8 @@ class CBEngine:
         # at the step's rows (``hybrid.step_counters``), and where the
         # dispatch samples inside the head the sampler's; one answer for
         # the engine's life
-        kernels = hybrid.step_counters(cfg, max_slots + 1)
+        kernels = hybrid.step_counters(
+            cfg, max_slots + 1, one_chip=mesh is None or mesh.size == 1)
         self._step_counters = {False: kernels,
                                True: (*kernels, "fused_sample_steps")}
         self.max_slots = max_slots

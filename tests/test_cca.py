@@ -350,3 +350,19 @@ def test_the_engine_serves_it_and_scores_as_the_reference_does(ref, cfg,
         np.testing.assert_allclose(lps, want, atol=LOGP_TOL, rtol=0)
     assert info["cca_tail_rows"] > 0 and info["kda_state_rows"] == 0
     assert info["moe_choices"] == info["moe_routed"]
+
+
+@pytest.mark.parametrize("rows", [9, 33])
+def test_one_choice_a_token_by_table_is_the_tiled_path(monkeypatch, cfg,
+                                                       params, rows):
+    """A decode step's form on a TPU (``blocks._expert_rows``),
+    interpreted, with ONE choice a token, routed by the carried latent."""
+    from tests.moe_forms import assert_both_forms_agree
+
+    lp = hybrid._layer_params(cfg, params["layers"], 1)[1]
+    x = jax.random.normal(jax.random.PRNGKey(5), (rows, cfg.hidden_size))
+    carried = jax.random.normal(jax.random.PRNGKey(6),
+                                (rows, cfg.router_hidden_size))
+    p, i, _ = blocks._latent_route(cfg, x, lp, carried)
+    assert_both_forms_agree(monkeypatch, cfg, x, lp, jnp.arange(rows) != 1,
+                            1, route=(p, i))

@@ -221,3 +221,252 @@ def test_slab_plan_at_the_cells_shapes(call):
 def test_slab_plan_counts_bytes_and_keeps_an_odd_k_whole():
     assert gm._slab_plan(7168, 2048, 1, 2) == 1024     # the same 4 MiB
     assert gm._slab_plan(7168 + 64, 2048, 2, 2) == 7168 + 64
+
+
+# --- the rows taken by table (``expert_rows``) ---
+
+def _routed(n, k, routed, held, first=0, seed=0, valid=None, all_on=None):
+    """A step's routing over ``routed`` experts of which ``held`` from
+    ``first`` on are here: (token of each sorted row, weight of each,
+    rows of every expert, the choices [n, k], their weights [n, k])."""
+    rng = np.random.default_rng(seed)
+    choice = np.stack([rng.choice(routed, k, replace=False)
+                       for _ in range(n)])
+    if all_on is not None:
+        choice[:] = all_on
+    top_p = rng.random((n, k)).astype(np.float32) + 0.1
+    if valid is not None:                  # an invalid row chooses nothing
+        choice[~valid] = routed
+    flat = choice.reshape(-1)
+    order = np.argsort(flat, kind="stable")
+    sizes = np.bincount(flat, minlength=routed + 1)[:routed]
+    return (jnp.asarray(order // k, jnp.int32),
+            jnp.asarray(top_p.reshape(-1)[order]),
+            jnp.asarray(sizes, jnp.int32), choice, top_p)
+
+
+def _weights(d, f, held, seed=0, dtype=jnp.float32):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return [0.1 * jax.random.normal(key, (held, *shape), dtype)
+            for key, shape in zip(keys, [(d, f), (d, f), (f, d)])]
+
+
+def _plain(x, ws, choice, top_p, first, scales=None):
+    """The plain float32 form: token by token, choice by choice."""
+    held = ws[0].shape[0]
+    gate, up, down = (np.asarray(w, np.float32) * (
+        1.0 if scales is None else np.asarray(s)[:, None, :])
+        for w, s in zip(ws, scales or (None,) * 3))
+    x = np.asarray(x, np.float32)
+    out = np.zeros((x.shape[0], down.shape[-1]), np.float32)
+    for t, (experts, weights) in enumerate(zip(choice, top_p)):
+        for e, w in zip(experts - first, weights):
+            if 0 <= e < held:
+                h = np.asarray(jax.nn.silu(x[t] @ gate[e])) * (x[t] @ up[e])
+                out[t] += w * (h @ down[e])
+    return out
+
+
+def _by_kernels(x, ws, token_of, weight, sizes, first, scales=None,
+                slabs=(None, None), spoil=None):
+    """The two kernels, interpreted, over the experts ``ws`` holds."""
+    held = ws[0].shape[0]
+    tile = gm.row_tile(token_of.shape[0], held)
+    first_row = int(jnp.sum(sizes[:first]))
+    tab = gm.row_tables(sizes[first:first + held], token_of, tile, first_row)
+    hidden = gm.gather_matmul_pallas(
+        x, tuple(ws[:2]), tab, scales and tuple(scales[:2]), tile=tile,
+        interpret=True, slab=slabs[0])
+    if spoil is not None:
+        hidden = spoil(hidden, tab, tile)
+    return gm.matmul_scatter_pallas(
+        hidden, (ws[2],), tab, weight, scales and (scales[2],),
+        n_tokens=x.shape[0], tile=tile, interpret=True, slab=slabs[1])
+
+
+# (tokens, d, f, choices a token, experts routed over, held, the first
+# held, slabs of gate+up and of down): the five cells' decode steps in
+# small, and what a routing can do to them
+ROWS_CASES = {
+    "qwen3-30b-a3b: all 16 held": (9, 256, 128, 4, 16, 16, 0, (None, None)),
+    "ling-3.0-flash: 8 of 32": (17, 256, 128, 4, 32, 8, 0, (None, None)),
+    "dots.vlm1: 2 of 32, slabs": (9, 512, 256, 4, 32, 2, 0, (128, 128)),
+    "zaya1-8b: one choice, slabs": (17, 256, 256, 1, 4, 4, 0, (128, None)),
+    "laguna-xs.2: 4 of 32": (9, 256, 128, 4, 32, 4, 0, (None, None)),
+    "a share that starts at expert 8": (17, 128, 128, 4, 16, 4, 8,
+                                        (None, 128)),
+}
+
+
+@pytest.mark.parametrize("case", ROWS_CASES)
+def test_rows_by_table_are_the_plain_float32_form(case):
+    n, d, f, k, routed, held, first, slabs = ROWS_CASES[case]
+    token_of, weight, sizes, choice, top_p = _routed(n, k, routed, held,
+                                                     first)
+    x = jax.random.normal(jax.random.PRNGKey(7), (n, d))
+    ws = _weights(d, f, held)
+    got = _by_kernels(x, ws, token_of, weight, sizes, first, slabs=slabs)
+    want = _plain(x, ws, choice, top_p, first)
+    assert np.abs(want).max() > 0.1
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=2e-5)
+    # a token with no choice here is exactly zero
+    none_here = ~((choice >= first) & (choice < first + held)).any(axis=1)
+    assert np.all(np.asarray(got)[none_here] == 0.0)
+
+
+ROUTINGS = {
+    # every row on one expert: a group of several tiles
+    "all rows on one expert": dict(all_on=2),
+    # no row on any held expert: zeros, not an unwritten block
+    "no row on a held expert": dict(all_on=9),
+    # rows without a request choose nothing
+    "invalid rows": dict(valid=np.arange(17) % 3 != 1),
+}
+
+
+@pytest.mark.parametrize("routing", ROUTINGS)
+def test_rows_by_table_whatever_the_routing(routing):
+    n, d, f, k, routed, held = 17, 128, 128, 1, 12, 4
+    token_of, weight, sizes, choice, top_p = _routed(
+        n, k, routed, held, **ROUTINGS[routing])
+    x = jax.random.normal(jax.random.PRNGKey(8), (n, d))
+    ws = _weights(d, f, held)
+    got = np.asarray(_by_kernels(x, ws, token_of, weight, sizes, 0))
+    np.testing.assert_allclose(got, _plain(x, ws, choice, top_p, 0),
+                               rtol=1e-4, atol=2e-5)
+    if routing == "no row on a held expert":
+        assert np.all(got == 0.0)
+    if routing == "invalid rows":
+        assert np.all(got[~ROUTINGS[routing]["valid"]] == 0.0)
+    if routing == "all rows on one expert":
+        tile = gm.row_tile(n * k, held)
+        assert n > tile             # more than one tile of the one group
+
+
+def test_pad_rows_of_hidden_may_hold_anything():
+    """What the down kernel never reads: a used tile's rows past its
+    count, and every tile past the last used (NaN under ``interpret``)."""
+    n, d, f, k, routed, held = 17, 128, 128, 2, 8, 8
+    token_of, weight, sizes, choice, top_p = _routed(n, k, routed, held)
+    x = jax.random.normal(jax.random.PRNGKey(9), (n, d))
+    ws = _weights(d, f, held)
+
+    def spoil(hidden, tab, tile):
+        rows = hidden.reshape(-1, tile, f)
+        pad = jnp.arange(tile)[None, :] >= tab.tile_count[:, None]
+        assert bool(pad.any()) and bool(jnp.all(
+            jnp.where(pad[:int(tab.tiles_run[0]), :, None],
+                      rows[:int(tab.tiles_run[0])], 0) == 0))   # zero rows
+        return jnp.where(pad[:, :, None], jnp.nan, rows).reshape(-1, f)
+
+    got = _by_kernels(x, ws, token_of, weight, sizes, 0, spoil=spoil)
+    np.testing.assert_allclose(got, _plain(x, ws, choice, top_p, 0),
+                               rtol=1e-4, atol=2e-5)
+
+
+def test_rows_by_table_read_int8_experts_with_their_scales():
+    n, d, f, k, routed, held = 9, 256, 128, 2, 8, 4
+    token_of, weight, sizes, choice, top_p = _routed(n, k, routed, held)
+    x = jax.random.normal(jax.random.PRNGKey(10), (n, d))
+    qs, scales = zip(*(_int8(w, seed) for seed, w in
+                       enumerate(_weights(d, f, held))))
+    scales = [s * 0.05 for s in scales]
+    got = _by_kernels(x, qs, token_of, weight, sizes, 0, scales,
+                      slabs=(128, None))
+    want = _plain(x, qs, choice, top_p, 0, scales)
+    assert np.abs(want).max() > 0.1
+    np.testing.assert_allclose(got, want, rtol=1e-4,
+                               atol=1e-5 * np.abs(want).max())
+
+
+def test_expert_rows_gradient_is_the_tiled_paths():
+    from polyrl_tpu.models import blocks
+
+    n, d, f, k, held = 9, 64, 32, 2, 4
+    token_of, weight, sizes, choice, top_p = _routed(n, k, held, held)
+    x = jax.random.normal(jax.random.PRNGKey(11), (n, d))
+    experts = dict(zip(blocks.EXPERT_KEYS, _weights(d, f, held)))
+    flat = choice.reshape(-1)
+    place = jnp.argsort(jnp.argsort(jnp.asarray(flat), stable=True))
+
+    def rows(x, experts, top_p):
+        w = top_p.reshape(-1)[jnp.argsort(jnp.asarray(flat), stable=True)]
+        return blocks._expert_rows(x, experts, None, token_of, w, sizes)
+
+    def tiled(x, experts, top_p):
+        return blocks._expert_mix(x, experts, None, token_of, place,
+                                  jnp.asarray(flat), top_p, sizes)
+
+    def grads(fn):
+        return jax.grad(lambda *a: jnp.sum(jnp.sin(fn(*a))),
+                        argnums=(0, 1, 2))(x, experts, jnp.asarray(top_p))
+
+    np.testing.assert_allclose(rows(x, experts, jnp.asarray(top_p)),
+                               tiled(x, experts, jnp.asarray(top_p)),
+                               rtol=1e-5, atol=1e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(grads(rows)),
+                    jax.tree_util.tree_leaves(grads(tiled))):
+        assert np.abs(np.asarray(b)).max() > 0
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("sizes,n_rows,first_row", [
+    ([3, 0, 17, 4], 24, 0), ([0, 0, 0, 40], 40, 5), ([40, 0, 0, 0], 48, 0),
+    ([0, 0, 0, 0], 16, 7)])
+def test_row_tables_are_the_tiled_layouts_tiles(sizes, n_rows, first_row):
+    tile = 8
+    lay = gm.tiled_layout(jnp.asarray(sizes, jnp.int32), n_rows, tile)
+    tab = gm.row_tables(jnp.asarray(sizes, jnp.int32),
+                        jnp.arange(n_rows) // 2, tile, first_row)
+    used = int(lay.tiles_used[0])
+    assert int(tab.tiles_run[0]) == max(used, 1)
+    assert tab.tile_group.tolist() == lay.tile_group.tolist()
+    assert tab.padded_sizes.tolist() == lay.padded_sizes.tolist()
+    live = np.asarray(lay.live).reshape(-1, tile)
+    src = np.asarray(lay.src).reshape(-1, tile)
+    assert tab.tile_count[:used].tolist() == live[:used].sum(1).tolist()
+    assert int(tab.tile_count[used:].sum()) == 0    # tile 0 of a call without
+    assert ((tab.tile_start[:used] - first_row).tolist()
+            == src[:used, 0].tolist())
+    assert tab.token_of.tolist() == (np.arange(n_rows) // 2).tolist()
+
+
+# (tokens, d, choices a token, experts held) -> the form: the five cells'
+# decode steps take their rows by table; a 512-token prefill chunk and the
+# trainer's batch keep the tiled form
+FORMS = {
+    "qwen3-30b-a3b decode": ((65, 2048, 8, 128), True),
+    "ling-3.0-flash decode": ((129, 2560, 8, 128), True),
+    "dots.vlm1 decode": ((65, 7168, 8, 16), True),
+    "zaya1-8b decode": ((129, 2048, 1, 16), True),
+    "laguna-xs.2 decode": ((65, 2048, 8, 32), True),
+    "qwen3-30b-a3b prefill chunk": ((512, 2048, 8, 128), False),
+    "ling-3.0-flash prefill chunk": ((512, 2560, 8, 128), False),
+    "zaya1-8b prefill chunk": ((512, 2048, 1, 16), False),
+    "dots.vlm1 prefill chunk": ((512, 7168, 8, 16), False),
+    "a trainer batch": ((16384, 2048, 8, 128), False),
+}
+# expert widths of the cells' configurations
+CELL_INTER = {2048: 768, 2560: 768, 7168: 2048}
+
+
+@pytest.mark.parametrize("call", FORMS)
+def test_the_form_follows_the_shapes(call, monkeypatch):
+    (n, d, k, g), by_table = FORMS[call]
+    assert gm.rows_by_table(n, d, 2, n * k, g) is by_table
+    assert not gm.in_kernel(n, d, 2, n * k, g)      # no TPU here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert gm.in_kernel(n, d, 2, n * k, g) is by_table
+    if not by_table:
+        return
+    # what the two calls keep in VMEM (``rows_by_table``'s arithmetic)
+    tile, f = gm.row_tile(n * k, g), CELL_INTER[d]
+    for (kk, nn, n_w) in ((d, f, 2), (f, d, 1)):
+        tk = gm._slab_plan(kk, nn, 2, n_w)
+        sums = n_w * tile * nn * 4
+        need = 2 * n_w * tk * nn * 2 + (sums if tk < kk else 0) + sums
+        if n_w == 2:    # the tokens twice, their float32 copy, a tile's rows
+            need += 2 * n * d * 2 + n * d * 4 + tile * d * 6 + 2 * tile * f * 2
+        else:           # hidden's block twice, the result twice, the products
+            need += 2 * tile * tk * 2 + 2 * n * d * 4 + tile * d * 6
+        assert need <= gm._VMEM_LIMIT_BYTES - 8 * 2**20

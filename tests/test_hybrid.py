@@ -589,3 +589,19 @@ def test_ling_preset_equals_the_benchmark_file():
         for key, value in row["config"].items():
             if key not in config["reduced"]:
                 assert on_disk[key] == value, key
+
+
+@pytest.mark.parametrize("rows", [9, 33])
+def test_a_share_of_the_experts_by_table_is_the_tiled_path(monkeypatch, cfg,
+                                                           params, rows):
+    """A decode step's form on a TPU (``blocks._expert_rows``),
+    interpreted, on the 4 of 16 experts the preset holds, the sigmoid
+    router's group-limited choices, a layer of the whole stacks."""
+    from tests.moe_forms import assert_both_forms_agree
+
+    l = next(l for l, p in enumerate(cache_spec.layer_plan(cfg))
+             if p.mlp == "moe")
+    lp = hybrid._layer_params(cfg, params["layers"], l)[1]
+    x = jax.random.normal(jax.random.PRNGKey(7), (rows, cfg.hidden_size))
+    assert_both_forms_agree(monkeypatch, cfg, x, lp, jnp.arange(rows) != 1,
+                            hybrid.kind_index(cfg)[l][1])

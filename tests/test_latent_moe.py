@@ -510,3 +510,20 @@ def test_yarn_comes_through_the_hf_loader():
     gqa = decoder.get_config("tiny", rope_scaling=got)
     with pytest.raises(NotImplementedError, match="yarn"):
         decoder.rope_cos_sin(gqa, jnp.arange(4)[None])
+
+
+@pytest.mark.parametrize("rows", [9, 33])
+def test_a_share_of_the_experts_by_table_is_the_tiled_path(monkeypatch, cfg,
+                                                           params, rows):
+    """A decode step's form on a TPU (``blocks._expert_rows``),
+    interpreted, on the experts the preset holds, the last sparse layer of
+    the whole stacks."""
+    from polyrl_tpu.models import cache_spec, hybrid
+    from tests.moe_forms import assert_both_forms_agree
+
+    l = max(l for l, p in enumerate(cache_spec.layer_plan(cfg))
+            if p.mlp == "moe")
+    lp = hybrid._layer_params(cfg, params["layers"], l)[1]
+    x = jax.random.normal(jax.random.PRNGKey(7), (rows, cfg.hidden_size))
+    assert_both_forms_agree(monkeypatch, cfg, x, lp, jnp.arange(rows) != 1,
+                            hybrid.kind_index(cfg)[l][1])

@@ -550,3 +550,19 @@ def test_the_scopes_of_a_decode_step(cfg, params):
         assert scope in text, scope
     assert gqa.GQA.counts == ("paged_rows_read",)
     assert gqa.GQA_WINDOW.counts == ("window_rows_read",)
+
+
+@pytest.mark.parametrize("rows", [9, 40])
+def test_a_share_of_the_experts_by_table_is_the_tiled_path(monkeypatch, cfg,
+                                                           params, rows):
+    """A decode step's form on a TPU (``blocks._expert_rows``),
+    interpreted, on the share of the experts the preset holds, with the
+    routed scaling factor and the shared expert."""
+    from tests.moe_forms import assert_both_forms_agree
+
+    l = max(l for l, p in enumerate(cache_spec.layer_plan(cfg))
+            if p.mlp == "moe")
+    lp = hybrid._layer_params(cfg, params["layers"], l)[1]
+    x = jax.random.normal(jax.random.PRNGKey(7), (rows, cfg.hidden_size))
+    assert_both_forms_agree(monkeypatch, cfg, x, lp, jnp.arange(rows) != 1,
+                            hybrid.kind_index(cfg)[l][1], atol=LOGIT_TOL)

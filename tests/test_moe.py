@@ -402,3 +402,61 @@ def test_moe_cb_engine_under_ep_matches_single(devices8, monkeypatch, tp, ep):
     # every layer of a prefill and of a decode program, at the least
     assert len(calls) >= 2 * cfg.num_layers, len(calls)
     assert got == want, (got, want)
+
+
+@pytest.mark.parametrize("rows,quant", [(9, False), (33, False), (9, True)],
+                         ids=["9 rows", "33 rows", "int8 experts"])
+def test_moe_block_by_table_is_the_tiled_path(monkeypatch, rows, quant):
+    """A decode step's form on a TPU (the kernels take a tile's rows from
+    the tokens by table and sum them back: ``blocks._expert_rows``),
+    interpreted here, against the tiled path, with a row without a
+    request."""
+    from polyrl_tpu.models.quant import quantize_tensor
+    from tests.moe_forms import assert_both_forms_agree
+
+    cfg, params = _mk()
+    lp = dict(jax.tree_util.tree_map(lambda a: a[0], params["layers"]))
+    if quant:
+        for key in blocks.EXPERT_KEYS:
+            lp[key] = quantize_tensor(lp[key], contract_axis=-2)
+    x = jax.random.normal(jax.random.PRNGKey(3), (rows, cfg.hidden_size))
+    valid = jnp.arange(rows) != 2
+    out = assert_both_forms_agree(monkeypatch, cfg, x, lp, valid)
+    assert np.all(np.asarray(out)[2] == 0.0)
+
+
+@pytest.mark.parametrize("fused_steps", [4, 1])
+def test_moe_gather_kernel_steps_move_with_an_engine_that_took_the_kernels(
+        monkeypatch, fused_steps):
+    """The engine asks once, at construction (``hybrid.step_counters``);
+    a dispatch's steps reach ``moe_gather_kernel_steps`` when it lands,
+    beside ``decode_steps_done``, and stay out of it on an engine whose
+    program took the tiled form; the tokens are the same either way."""
+    from polyrl_tpu.ops import grouped_matmul
+    from polyrl_tpu.rollout.cb_engine import CBEngine
+    from polyrl_tpu.rollout.sampling import SamplingParams
+
+    cfg, params = _mk()
+    tokens = {}
+    for kernel in (False, True):
+        if kernel:
+            monkeypatch.setattr(grouped_matmul, "in_kernel",
+                                grouped_matmul.rows_by_table)
+        eng = CBEngine(cfg, params, max_slots=2, page_size=8, max_seq_len=32,
+                       prompt_buckets=(16,), num_pages=16,
+                       steps_per_dispatch=fused_steps,
+                       kv_cache_dtype=jnp.float32)
+        assert ("moe_gather_kernel_steps"
+                in eng._step_counters[False]) is kernel
+        eng.start()
+        try:
+            (out,) = eng.generate([[3, 1, 4, 1, 5]], SamplingParams(
+                temperature=0.0, max_new_tokens=9))
+            info = eng.loop_profile_info()
+        finally:
+            eng.stop()
+        tokens[kernel] = out["token_ids"]
+        assert info["decode_steps_done"] >= 8
+        assert info["moe_gather_kernel_steps"] == (
+            info["decode_steps_done"] if kernel else 0)
+    assert len(tokens[True]) == 9 and tokens[True] == tokens[False]

@@ -221,6 +221,76 @@ def test_grouped_expert_matmul_compiles_for_v5e(one_chip, chip_precision,
         assert "tpu_custom_call" in compiled.as_text()
 
 
+# (hidden, expert width, experts held, layers of the stack, choices a
+# token, rows): the five MoE cells' decode steps
+ROWS_SHAPES = {
+    "qwen3-30b-a3b": (2048, 768, 128, 7, 8, 65),
+    "ling-3.0-flash": (2560, 768, 128, 6, 8, 129),
+    "dots.vlm1": (7168, 2048, 16, 4, 8, 65),
+    "zaya1-8b": (2048, 2048, 16, 12, 1, 129),
+    "laguna-xs.2": (2048, 512, 32, 9, 8, 65),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8])
+@pytest.mark.parametrize("cell", ROWS_SHAPES)
+def test_expert_rows_kernels_compile_for_v5e(one_chip, chip_precision, cell,
+                                             dtype):
+    """The two kernels of a decode step's experts (``ops/grouped_matmul``:
+    gate and up over rows taken from the tokens by table, down with each
+    token's weighted sum) at the five cells' shapes, one layer's experts
+    of a whole stack, in bf16 and with int8 experts: the dynamic row
+    copies lower, and the tokens, their float32 result, a step's slabs and
+    a tile's rows and sums fit VMEM."""
+    from polyrl_tpu.ops import grouped_matmul as gm
+
+    d, f, e, stack, k, n = ROWS_SHAPES[cell]
+    m = n * k
+    assert gm.rows_by_table(n, d, 2, m, e)
+    tile = gm.row_tile(m, e)
+    n_tiles = m // tile + e
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def scales(n_w, width):
+        return ((arg((stack * e, width), jnp.float32),) * n_w
+                if dtype == jnp.int8 else None)
+
+    i32 = jnp.int32
+    tab = gm.RowTables(arg((n_tiles,), i32), arg((1,), i32),
+                       arg((n_tiles,), i32), arg((n_tiles,), i32),
+                       arg((stack * e,), i32), arg((m,), i32))
+    gate_up = jax.jit(functools.partial(
+        gm.gather_matmul_pallas, tile=tile)).lower(
+            arg((n, d), jnp.bfloat16), (arg((stack * e, d, f), dtype),) * 2,
+            tab, scales(2, f)).compile()
+    down = jax.jit(functools.partial(
+        gm.matmul_scatter_pallas, n_tokens=n, tile=tile)).lower(
+            arg((n_tiles * tile, f), jnp.bfloat16),
+            (arg((stack * e, f, d), dtype),), tab, arg((m,), jnp.float32),
+            scales(1, d)).compile()
+    assert "tpu_custom_call" in gate_up.as_text()
+    assert "tpu_custom_call" in down.as_text()
+
+
+def _assert_no_tiled_rows(text: str, n: int, k: int, e: int, d: int, f: int):
+    """A decode step compiled for the chip keeps no tiled copy of its
+    experts' rows: no ``[T*tile, d]`` array (the rows gathered in, the
+    products to gather back), no table a tiled row (``[T*tile]``: the
+    one-hot fusions that made them), and of the arrays of a megabyte or
+    more in VMEM none with a row a tiled row or a choice but ``hidden``,
+    which the two kernels hand each other as they did."""
+    from polyrl_tpu.ops import grouped_matmul as gm
+
+    m = n * k
+    tiled = (m // gm.row_tile(m, e) + e) * gm.row_tile(m, e)
+    assert f"[{tiled},{d}]" not in text and f"[{tiled}]" not in text
+    by_row = {a for a in _large_in_vmem(text)
+              if re.match(rf"\w+\[({tiled}|{m}),", a)}
+    assert by_row <= {f"bf16[{tiled},{f}]"}
+
+
 @pytest.mark.parametrize("rows", MOE_ROWS)
 def test_moe_block_compiles_for_v5e(one_chip, chip_precision, on_tpu, rows):
     """One layer's whole routed MLP (route, sort, the gather into tiles,
@@ -544,6 +614,19 @@ def _made(text: str, n: int):
             if count >= n and not plumbing.search(line)]
 
 
+def _large_in_vmem(text: str, least: int = 2**20) -> set[str]:
+    """The shapes (``bf16[5120,768]``) of the arrays of ``least`` bytes or
+    more that the optimised program's entry computation or its loops hold
+    in ``S(1)`` (VMEM): a fusion's own result or a custom call's, an
+    operand memory-space assignment moved there."""
+    sizes = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1, "pred": 1}
+    found = set()
+    for dt, dims in re.findall(r"(\w+)\[([\d,]+)\]\{[^}]*S\(1\)\}", text):
+        if sizes.get(dt, 4) * math.prod(map(int, dims.split(","))) >= least:
+            found.add(f"{dt}[{dims}]")
+    return found
+
+
 def _taken_into_vmem(text: str, shape: tuple[int, ...]):
     """The lines of the optimised program in which memory-space assignment
     has an array of ``shape`` in hand: the array, or a run of its leading
@@ -655,9 +738,12 @@ def test_ling_decode_step_compiles_for_v5e_within_memory(one_chip,
     chooses without sorting its scores (``blocks._group_limited_topk``):
     of the five sorts a sparse layer had, the two over the scores are
     gone (the groups' ``[129, 8, 64]`` for a group's two best, the rows'
-    ``[129, 512]`` for the 8 choices), and 18 are left in the six layers:
+    ``[129, 512]`` for the 8 choices), and 12 are left in the six layers:
     a layer's ``top_k`` of 4 over its 8 group scores ``[129, 8]`` and
-    ``_moe_mlp``'s two ``argsort``s of the 1,032 choices by expert."""
+    ``_moe_mlp``'s ONE sort of the 1,032 choices by expert, their weights
+    beside them. The experts' rows never lie in tiles outside the kernels
+    (``_assert_no_tiled_rows``: the parent held ``bf16[5120,2560]`` twice
+    a layer and made ``s32[5120]`` tables for it)."""
     from polyrl_tpu.models import decoder
 
     cfg = decoder.get_config("ling-3.0-flash-share4")
@@ -710,7 +796,8 @@ def test_ling_decode_step_compiles_for_v5e_within_memory(one_chip,
     sorts = [op.split(" sort(")[0] for op in made if " sort(" in op]
     assert not [op for op in sorts
                 if re.search(r"f32\[129,(8,64|512)\]", op)]
-    assert len(sorts) <= 18
+    assert len(sorts) <= 12
+    _assert_no_tiled_rows(text, s, 8, 128, 2560, 768)
 
 
 # -- ZAYA1-8B's cell (benchmark/configs/zaya1-8b.json) ----------------------
@@ -991,6 +1078,9 @@ def test_mixed_decode_step_compiles_for_v5e_and_copies_no_cache(
     # read-only and is never copied back)
     assert _made(text, math.prod(ring)) == []
     assert _taken_into_vmem(text, ring) == []
+    # the 32 held experts' rows: taken by table, never in ``[1536, 2048]``
+    # tiles outside the kernels
+    _assert_no_tiled_rows(text, s, 8, 32, 2048, 512)
 
 
 def test_mixed_prefill_chunk_compiles_for_v5e_within_memory(one_chip,

@@ -22,8 +22,15 @@ other rows a slab than ``grouped_matmul._slab_plan`` returns for the
 shapes (gate+up's, then after a ``/`` down's, which else keeps the
 rule's). ``--also-tree`` times other checkouts' kernels
 beside this one (parent against change in one call; several,
-comma-separated). Prints one JSON line a variant and call; fails without a
-TPU."""
+comma-separated). After the kernels, a layer's experts WHOLE in both forms
+a decode step can take (``--forms tiled,rows``; ``blocks._expert_mix``: the
+rows gathered into tiles and read back by XLA's operations around the two
+kernels; ``blocks._expert_rows``: the kernels take their rows by table and
+sum them back), each ONE program from the router's outputs (the choices
+and their weights) on: the sort, the tables, the calls; a line a form with
+the program's device time a call, its kernels' share of it, and how far
+the two forms' results lie apart. Prints one JSON line a variant and call;
+fails without a TPU."""
 
 from __future__ import annotations
 
@@ -79,15 +86,59 @@ def cell_shapes(config: str) -> dict:
         "rows": traffic.load_mix(work["traffic"])["engine"]["max_slots"] + 1}
 
 
-def step_sizes(shapes: dict, rows: int, seed: int) -> np.ndarray:
-    """Rows of each held expert: every row but the spare one chooses
-    ``top_k`` distinct experts evenly, the held ones are the first."""
+def step_choices(shapes: dict, rows: int, seed: int) -> np.ndarray:
+    """A step's choices [rows, top_k]: every row but the spare one chooses
+    ``top_k`` distinct experts evenly, the held ones are the first; a
+    choice held elsewhere, and each of the spare row's, is expert ``held``
+    (none), as ``blocks._moe_mlp`` hands them on."""
     rng = np.random.default_rng(seed)
-    sizes = np.zeros(shapes["held"], np.int64)
-    for _ in range(rows - 1):
+    held = shapes["held"]
+    choices = np.full((rows, shapes["top_k"]), held, np.int64)
+    for row in range(rows - 1):
         chosen = rng.choice(shapes["routed"], shapes["top_k"], replace=False)
-        np.add.at(sizes, chosen[chosen < shapes["held"]], 1)
-    return sizes
+        choices[row] = np.where(chosen < held, chosen, held)
+    return choices
+
+
+def layer_forms(shapes: dict, layer: int) -> dict:
+    """A layer's experts from the router's outputs on, a form a program:
+    (tokens [N, d], the three stacks [L*E, ..] and their scales or None,
+    choices [N, k], weights [N, k]) -> [N, d] float32, as
+    ``blocks._moe_mlp`` runs each."""
+    from polyrl_tpu.models import blocks
+
+    held, k = shapes["held"], shapes["top_k"]
+
+    def sizes_of(choice):
+        return jnp.sum(jax.nn.one_hot(choice, held, dtype=jnp.int32), axis=0)
+
+    def stacks(ws, scales):      # [L*E, ..] as the layers' [L, E, ..]
+        from polyrl_tpu.models.quant import QuantWeight
+
+        ws = [w.reshape(-1, held, *w.shape[1:]) for w in ws]
+        if scales:
+            ws = [QuantWeight(q=q, scale=s.reshape(-1, held, s.shape[-1]))
+                  for q, s in zip(ws, scales)]
+        return dict(zip(("we_gate", "we_up", "we_down"), ws))
+
+    def tiled(x, ws, scales, choices, top_p):
+        experts = stacks(ws, scales)
+        choice = choices.reshape(-1)
+        order = jnp.argsort(choice, stable=True)
+        return blocks._expert_mix(x, experts, layer, order // k,
+                                  jnp.argsort(order), choice, top_p,
+                                  sizes_of(choice))
+
+    def rows(x, ws, scales, choices, top_p):
+        experts = stacks(ws, scales)
+        choice = choices.reshape(-1)
+        _, order, weight = jax.lax.sort(
+            (choice, jnp.arange(choice.shape[0]), top_p.reshape(-1)),
+            num_keys=1)
+        return blocks._expert_rows(x, experts, layer, order // k, weight,
+                                   sizes_of(choice))
+
+    return {"tiled": jax.jit(tiled), "rows": jax.jit(rows)}
 
 
 def three_tiles(gm, lay, used: int):
@@ -108,33 +159,45 @@ def three_tiles(gm, lay, used: int):
     return jnp.asarray(at), sub
 
 
+def device_ops(trace_dir: str) -> list[tuple[str, float]]:
+    """(name, ms) of the events of the first chip's ``XLA Ops`` line in
+    the newest trace under ``trace_dir``."""
+    path = max(glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                      "*.xplane.pb")), key=os.path.getmtime)
+    data = jax.profiler.ProfileData.from_file(path)    # owns its events
+    return [(e.name, e.duration_ns / 1e6) for plane in data.planes
+            if plane.name.startswith("/device:TPU:0")
+            for line in plane.lines if line.name == "XLA Ops"
+            for e in line.events]
+
+
+def device_ms(trace_dir: str) -> tuple[float, float]:
+    """(every operation's, the kernel's events') device time in the newest
+    trace, ms: the device runs one operation at a time."""
+    ops = device_ops(trace_dir)
+    return (sum(ms for _name, ms in ops),
+            sum(ms for name, ms in ops
+                if name.lstrip("%").startswith(KERNEL)))
+
+
 def kernel_events(trace_dir: str) -> list[tuple]:
     """(stacked weights, ms, [rows in VMEM, result in VMEM]) of the
     kernel's events in the newest trace. An event's name
     is its HLO instruction, layouts and all: ``S(1)`` in a layout is
     memory space 1, VMEM, where XLA's memory-space assignment may keep a
     custom call's operand or result."""
-    path = max(glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
-                                      "*.xplane.pb")), key=os.path.getmtime)
     out = []
-    for plane in jax.profiler.ProfileData.from_file(path).planes:
-        if not plane.name.startswith("/device:TPU:0"):
+    for name, ms in device_ops(trace_dir):
+        hit = re.match(r"%?" + KERNEL + r"\S* = \w+\[\d+,\d+\](\S*) "
+                       r"custom-call\((.*?)\), custom_call", name)
+        if not hit:
             continue
-        for line in plane.lines:
-            if line.name != "XLA Ops":
-                continue
-            for e in line.events:
-                hit = re.match(r"%?" + KERNEL + r"\S* = \w+\[\d+,\d+\](\S*) "
-                               r"custom-call\((.*?)\), custom_call", e.name)
-                if not hit:
-                    continue
-                operands = hit.group(2).split(", ")
-                stacks = [o for o in operands
-                          if re.match(r"(bf16|s8)\[\d+,\d+,\d+\]", o)]
-                rows = operands[operands.index(stacks[0]) - 1]
-                out.append((len(stacks), e.duration_ns / 1e6,
-                            ["S(1)" in rows.split(" %")[0],
-                             "S(1)" in hit.group(1)]))
+        operands = hit.group(2).split(", ")
+        stacks = [o for o in operands
+                  if re.match(r"(bf16|s8)\[\d+,\d+,\d+\]", o)]
+        rows = operands[operands.index(stacks[0]) - 1]
+        out.append((len(stacks), ms,
+                    ["S(1)" in rows.split(" %")[0], "S(1)" in hit.group(1)]))
     return out
 
 
@@ -147,6 +210,8 @@ def main() -> int:
                     help="int8 experts with a scale an output channel")
     ap.add_argument("--plans", default="")
     ap.add_argument("--also-tree", default="")
+    ap.add_argument("--forms", default="tiled,rows",
+                    help="a layer's experts whole, in these forms")
     ap.add_argument("--calls", type=int, default=20)
     ap.add_argument("--seed", type=int, default=3000000019)
     ap.add_argument("--out", default=os.path.join(ROOT, "chiprun_out",
@@ -164,7 +229,8 @@ def main() -> int:
     layer = stack // 2
     m = rows * shapes["top_k"]
     tile = args.tile or here.row_tile(m, held)
-    sizes = step_sizes(shapes, rows, args.seed)
+    choices = step_choices(shapes, rows, args.seed)
+    sizes = np.bincount(choices.reshape(-1), minlength=held + 1)[:held]
     lay = here.tiled_layout(jnp.asarray(sizes, jnp.int32), m, tile)
     used = int(lay.tiles_used[0])
     lay = lay._replace(
@@ -268,6 +334,42 @@ def main() -> int:
             print(line, flush=True)
             with open(os.path.join(args.out, "results.jsonl"), "a") as out:
                 out.write(line + "\n")   # the call shows its last lines only
+    # a layer's experts whole, a form a program
+    forms = layer_forms(shapes, layer)
+    x = jax.random.normal(kx, (rows, d), jnp.bfloat16)
+    top_p = jnp.where(jnp.asarray(choices) < held, jax.random.uniform(
+        kx, choices.shape, jnp.float32), 0.0)
+    operands = (x, ws, scales, jnp.asarray(choices, jnp.int32), top_p)
+    results = {}
+    for v, name in enumerate(f for f in args.forms.split(",") if f):
+        try:
+            results[name] = jax.block_until_ready(forms[name](*operands))
+        except Exception as e:
+            failed += 1
+            print(json.dumps({"form": name, "error": str(e)[:300]}),
+                  flush=True)
+            continue
+        trace_dir = os.path.join(args.out, f"trace_form_{v}")
+        with jax.profiler.trace(trace_dir):
+            for _ in range(args.calls):
+                got = forms[name](*operands)
+            jax.block_until_ready(got)
+        total, kernel = device_ms(trace_dir)
+        weight_bytes = hit * 3 * d * f * ws[0].dtype.itemsize
+        line = json.dumps({
+            "form": name, "config": args.config, "rows": rows, "tile": tile,
+            "device": jax.devices()[0].device_kind, "experts_hit": hit,
+            "weights": str(ws[0].dtype), "layer_of": [layer, stack],
+            "program_ms": total / args.calls,
+            "kernels_ms": kernel / args.calls,
+            "around_the_kernels_ms": (total - kernel) / args.calls,
+            "roofline_share": 100 * weight_bytes / HBM_BYTES_S
+            / (total / args.calls / 1e3),
+            "rel_diff_to_tiled": apart(results[name], results["tiled"])
+            if "tiled" in results else None})
+        print(line, flush=True)
+        with open(os.path.join(args.out, "results.jsonl"), "a") as out:
+            out.write(line + "\n")
     return 1 if failed else 0
 
 

@@ -181,7 +181,9 @@ def _render_engine(loop: dict) -> list[str]:
             f"{c.get('fused_sample_steps', 0)} steps sampled "
             f"inside the head, {c.get('kda_kernel_steps', 0)} updated "
             f"their KDA states in the kernel, "
-            f"{c.get('mla_proj_kernel_steps', 0)} read wkv_b in place): "
+            f"{c.get('mla_proj_kernel_steps', 0)} read wkv_b in place, "
+            f"{c.get('moe_gather_kernel_steps', 0)} took their experts' "
+            f"rows by table): "
             f"device busy {_fmt(c.get('device_busy_s'))} s "
             f"({_fmt(1e3 * c.get('device_busy_s', 0.0) / steps)} ms a step); "
             f"loop host {_fmt(c.get('loop_host_s'))} s of "
