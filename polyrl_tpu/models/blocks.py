@@ -348,11 +348,20 @@ def _moe_mlp(cfg, x: jnp.ndarray, lp: dict,
         return out.astype(x.dtype), load
 
 
+def head_input(cfg, x):
+    """What the output matmul reads of the normed ``x``: under muP
+    (``dim_model_base`` > 0) ``x / (hidden_size / dim_model_base)``."""
+    if not cfg.dim_model_base:
+        return x
+    return (x.astype(jnp.float32)
+            * (cfg.dim_model_base / cfg.hidden_size)).astype(x.dtype)
+
+
 def _head(cfg, params, x, logits_for=None):
     """Final norm and the output matmul; ``logits_for`` [B] unembeds one
     position a row of ``x`` [B, T, d]."""
     with jax.named_scope("head"):
-        x = norm(params, "final_norm", x, cfg.rms_norm_eps)
+        x = head_input(cfg, norm(params, "final_norm", x, cfg.rms_norm_eps))
         head = (params["embed"].T if cfg.tie_word_embeddings
                 else params["lm_head"])
         if logits_for is not None:

@@ -19,7 +19,11 @@ RING as ``swa``'s are, with a head count and a rope of its kind's own
 (``gqa_heads``, ``gqa_rope``). The DeepSeek-V3 family
 (``kv_lora_rank`` without a ``layer_group_size``) is ``mla`` in every
 layer behind leading dense layers. ``cca_time0`` > 0 is ``cca`` in every
-layer (ZAYA1's decoder).
+layer (ZAYA1's decoder). A published ``mixer_types`` list
+(``minicpm4`` / ``lightning-attn``: MiniCPM-SALA) makes ``sparse`` (softmax
+attention over the blocks of keys a token's queries choose, a K/V head at
+a time, through pooled keys) and ``lightning`` (linear attention: a float32
+state a head under a constant decay).
 
 The SambaY family (``mb_per_layer`` > 0: a decoder, a cross-decoder and
 differential attention without positions) has six kinds that follow from
@@ -40,8 +44,14 @@ has its arrays) in this module's types: PAGED (so many values a token, in
 pages that the engine's allocator hands out: a K/V pair of ``[Hkv, N,
 page, D]`` for ``gqa`` and ``diff``, one latent pool ``[1, N, page, row]``
 for ``mla``, ``row`` being ``rank + rope`` rounded up to whole lanes), or a
-SLOT (fixed-size arrays indexed by the engine's slot, for ``kda`` and the
-scans), or BOTH in one layer (``PagedAndSlot``, for ``cca``). A ``swa``
+SLOT (fixed-size arrays indexed by the engine's slot, for ``kda``,
+``lightning`` and the scans), or BOTH in one layer (``PagedAndSlot``, for
+``cca`` and ``sparse``: the latter's slot is the table of pages its last
+decode step attended, int32). A ``sparse`` layer's pages carry a
+POOLED-KEY STORE beside the K/V pair (``Paged.pooled``): one float32 row
+every ``pooled`` tokens a K/V head, ``[N, page_size / pooled * heads, width]``, a third array of the
+layer's pool that the SAME page numbers index, so the allocator, the
+ledger, growth, yield and the page table know one kind of page. A ``swa``
 layer keeps a RING: a K/V pair of the last ``window`` tokens in pages
 that belong to the SLOT (``window / page_size`` pages a slot in a pool of
 the layer's own, at a place fixed when the pool is made: token ``t`` lies
@@ -102,7 +112,7 @@ import jax.numpy as jnp
 @dataclasses.dataclass(frozen=True)
 class LayerPlan:
     # "gqa" | "kda" | "mla" | "cca" | "ssm" | "swa" | "ssm_mem" | "diff" |
-    # "gmu" | "cross" | "gqa_window"
+    # "gmu" | "cross" | "gqa_window" | "sparse" | "lightning"
     mixer: str
     mlp: str          # "dense" | "moe"
     published: int    # the layer's index in the published model
@@ -113,14 +123,25 @@ class Paged:
     """``arrays`` pools of ``[heads, passes * pages, page_size, width]`` a
     layer: a token keeps ``passes`` rows in each, one a pass of a looped
     model (``passes``), at the same place in ``passes`` pages that lie
-    ``pages`` apart (``pass_offset``)."""
+    ``pages`` apart (``pass_offset``). ``pooled`` > 0: one more array,
+    ``[pages, page_size / pooled * heads, width]`` in ``POOLED_DTYPE``,
+    a row every ``pooled`` tokens a head (a ``sparse`` layer's pooled keys;
+    a page's rows of every head are one tile of the chip's at 2 heads and
+    4 rows a page)."""
     arrays: int
     heads: int
     width: int
     passes: int = 1
+    pooled: int = 0
 
     def values_per_token(self) -> int:
         return self.arrays * self.heads * self.width * self.passes
+
+    def bytes_per_token(self, itemsize: int) -> int:
+        extra = (self.heads * self.width
+                 * jnp.dtype(POOLED_DTYPE).itemsize // self.pooled
+                 if self.pooled else 0)
+        return self.values_per_token() * itemsize + extra
 
 
 @dataclasses.dataclass(frozen=True)
@@ -180,6 +201,12 @@ def slot_part(c) -> Slot | Ring | None:
 # module constant and no option: the benchmark's control of ``correct``
 # computes the reference with a bfloat16 state, not the program.
 STATE_DTYPE = jnp.float32
+# the type a ``sparse`` layer's pooled keys are kept in: the mean of
+# ``sparse_kernel_size`` keys, which the selection's scores are taken
+# against; float32 rows of ``[heads * rows a page, width]`` are whole
+# (8, 128) tiles at the published sizes, where bfloat16 rows would be
+# padded to the same bytes
+POOLED_DTYPE = jnp.float32
 
 
 def layer_plan(cfg) -> tuple[LayerPlan, ...]:
@@ -216,6 +243,8 @@ def layer_plan(cfg) -> tuple[LayerPlan, ...]:
             mixer = "cca"
         elif cfg.layer_types:
             mixer = LAYER_TYPES[cfg.layer_types[i]]
+        elif cfg.mixer_types:
+            mixer = MIXER_TYPES[cfg.mixer_types[i]]
         else:
             mixer = "mla" if cfg.kv_lora_rank else "gqa"
         sparse = bool(cfg.num_experts) and i >= cfg.first_k_dense_replace
@@ -225,6 +254,8 @@ def layer_plan(cfg) -> tuple[LayerPlan, ...]:
 
 # a published ``layer_types`` entry -> the mixer that runs it
 LAYER_TYPES = {"full_attention": "gqa", "sliding_attention": "gqa_window"}
+# a published ``mixer_types`` entry -> the mixer that runs it
+MIXER_TYPES = {"minicpm4": "sparse", "lightning-attn": "lightning"}
 
 
 def is_uniform(cfg) -> bool:
@@ -232,7 +263,7 @@ def is_uniform(cfg) -> bool:
     layer: the stacked-scan decoder."""
     return (not cfg.layer_group_size and not cfg.kv_lora_rank
             and not cfg.cca_time0 and not cfg.mb_per_layer
-            and not cfg.layer_types
+            and not cfg.layer_types and not cfg.mixer_types
             and not (cfg.num_experts and cfg.first_k_dense_replace)
             and cfg.ut_steps == 1 and not cfg.sandwich_norm)
 
@@ -251,6 +282,13 @@ def pass_offset(cfg, pool, t):
     runs of the engine's ``num_pages`` pages, pass ``t``'s run the
     ``t``-th, so logical page 0 is pass ``t``'s null page there."""
     return t * (pool.shape[1] // passes(cfg))
+
+
+def published_depth(cfg) -> int:
+    """Layers of the published model, of which ``num_layers`` run here: a
+    per-layer list's length where the configuration has one."""
+    per_layer = cfg.mixer_types or cfg.layer_types
+    return len(per_layer) if per_layer else cfg.num_layers
 
 
 def experts_held(cfg) -> tuple[int, int]:
@@ -370,7 +408,7 @@ def without_kernel(cfg, feature: str) -> tuple[str, ...]:
 
 def paged_bytes_per_token(cfg, dtype=None) -> int:
     item = jnp.dtype(dtype or cfg.dtype).itemsize
-    return sum(paged_part(c).values_per_token() * item
+    return sum(paged_part(c).bytes_per_token(item)
                for c in cache_spec(cfg, dtype) if paged_part(c) is not None)
 
 
@@ -402,10 +440,13 @@ def make_pools(cfg, num_pages: int, page_size: int, slots: int = 0,
     null page). For any other pattern ``(paged, state)``: ``paged`` a tuple
     with one entry for each layer that keeps pages, in order (a ``[1,
     num_pages, page_size, width]`` latent pool for ``mla``, a ``(k, v)``
-    pair of ``[Hkv, num_pages, page_size, D]`` for ``cca``), ``state`` a
+    pair of ``[Hkv, num_pages, page_size, D]`` for ``cca``, and for
+    ``sparse`` with the pooled keys ``[num_pages, Hkv * page_size / stride,
+    D]`` float32 as its third), ``state`` a
     tuple with one tuple of ``[slots, *shape]`` arrays for each layer that
     keeps a slot, in order (``(state [slots, H, Dk, Dv] float32, conv
     [slots, K-1, channels])`` for ``kda``; the three tails for ``cca``;
+    a ``sparse`` layer's ``picked [slots, Hkv, W + 1]`` int32;
     ``(state [slots, N, inner] float32, conv [slots, K-1, inner])`` for
     ``ssm``; a ``swa`` layer's ring, a ``(k, v)`` pair of ``[pairs, 1 +
     slots * window / page_size, page_size, width]`` whose pages are the
@@ -426,7 +467,14 @@ def make_pools(cfg, num_pages: int, page_size: int, slots: int = 0,
             pool = [jnp.zeros((pages.heads, pages.passes * num_pages,
                                page_size, pages.width), dtype)
                     for _ in range(pages.arrays)]
-            paged.append(pool[0] if pages.arrays == 1 else tuple(pool))
+            if pages.pooled:
+                if page_size % pages.pooled:
+                    raise ValueError(f"a pooled row every {pages.pooled} "
+                                     f"tokens in pages of {page_size}")
+                pool.append(jnp.zeros(
+                    (num_pages, pages.heads * (page_size // pages.pooled),
+                     pages.width), POOLED_DTYPE))
+            paged.append(pool[0] if len(pool) == 1 else tuple(pool))
         if isinstance(slot, Ring):
             if slot.window % page_size:
                 raise ValueError(f"a window of {slot.window} keys in pages "

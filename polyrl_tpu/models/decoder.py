@@ -37,7 +37,7 @@ from jax.sharding import PartitionSpec as P
 from polyrl_tpu.models import cache_spec, hybrid
 from polyrl_tpu.models.blocks import (EXPERT_KEYS, _head, _moe_mlp,  # noqa: F401
                                       _scatter_pages_kv, _scatter_token_kv,
-                                      norm, rms_norm)
+                                      head_input, norm, rms_norm)
 from polyrl_tpu.models.quant import LoraWeight, QuantWeight, mm
 from polyrl_tpu.ops.attention import attention, causal_mask
 from polyrl_tpu.parallel.mesh import DP, EP, FSDP, SP, TP
@@ -183,6 +183,33 @@ class ModelConfig:
     # sublayer's OUTPUT before it joins the residual stream
     ut_steps: int = 1
     sandwich_norm: bool = False
+    # block-sparse softmax attention beside linear attention
+    # (``minicpm_sala``; ``models/mixers/sparse.py``, ``lightning.py``):
+    # ``mixer_types`` names each published layer ``minicpm4`` (InfLLM-V2:
+    # a token attends the ``sparse_topk`` blocks of ``sparse_block_size``
+    # keys a K/V head that its queries score highest against pooled keys,
+    # the mean of ``sparse_kernel_size`` keys every ``sparse_kernel_stride``;
+    # the first ``sparse_init_blocks`` and the last ``sparse_window_size``
+    # keys' blocks always; every block up to ``sparse_dense_len`` keys) or
+    # ``lightning-attn`` (``lightning_heads`` heads of ``lightning_head_dim``,
+    # the model's own where 0, a float32 state a head under a constant
+    # decay). muP: the embedding times ``scale_emb``, a sublayer's output
+    # times ``scale_depth / sqrt(published depth)`` where ``scale_depth`` >
+    # 0, the head's input over ``hidden_size / dim_model_base`` where the
+    # latter > 0
+    mixer_types: tuple | None = None
+    sparse_kernel_size: int = 32
+    sparse_kernel_stride: int = 16
+    sparse_block_size: int = 64
+    sparse_topk: int = 64
+    sparse_init_blocks: int = 1
+    sparse_window_size: int = 2048
+    sparse_dense_len: int = 8192
+    lightning_heads: int = 0
+    lightning_head_dim: int = 0
+    scale_emb: float = 1.0
+    scale_depth: float = 0.0
+    dim_model_base: int = 0
     dtype: Any = jnp.bfloat16
 
     @property
@@ -548,6 +575,52 @@ PRESETS["ouro-tiny"] = ModelConfig(
 )
 
 
+# MiniCPM-SALA (HF config: openbmb/MiniCPM-SALA, model_type minicpm_sala;
+# ``hf_loader.minicpm_sala_config`` of the published keys gives this,
+# tested): 32 layers of hidden 4096 with a SwiGLU MLP of 16384, 8 of them
+# ``minicpm4`` (InfLLM-V2 block-sparse softmax attention: 32 query heads
+# over 2 K/V heads of 128, no rope, q/k norms, an output gate) among 24
+# ``lightning-attn`` (linear attention: 32 heads of 128, rope, q/k norms, a
+# constant decay a head, an output norm and gate); MiniCPM's muP scalings;
+# an untied head over 73,448 rows. The sparse sizes are the family's
+# (openbmb/MiniCPM4.1-8B's ``sparse_config``: no key of this model's
+# catalog row names them).
+# Published layers 9-20 run here (S L L L L L L S S L L L), every width and
+# the whole vocabulary: the middle of three pipeline stages, which here
+# holds the embedding and the head too (benchmark/configs/minicpm-sala.json)
+PRESETS["minicpm-sala"] = ModelConfig(
+    vocab_size=73448, hidden_size=4096, intermediate_size=16384,
+    num_layers=12, num_heads=32, num_kv_heads=2, head_dim=128,
+    rope_theta=10000.0, rms_norm_eps=1e-6, use_qk_norm=True,
+    max_position_embeddings=524288,
+    mixer_types=(
+        "minicpm4", *["lightning-attn"] * 8, "minicpm4",
+        *["lightning-attn"] * 6, "minicpm4", "minicpm4",
+        *["lightning-attn"] * 4, "minicpm4", *["lightning-attn"] * 6,
+        "minicpm4", "minicpm4", "minicpm4"),
+    kept_layers=tuple(range(9, 21)),
+    lightning_heads=32, lightning_head_dim=128,
+    scale_emb=12.0, scale_depth=1.4, dim_model_base=256,
+)
+# test-size model of the same family: published layers 1-6 of 8 (S L L S S
+# L kept), 4 query heads over 2 K/V heads of 16, 4 lightning heads of 16,
+# pooled keys of 8 tokens every 4, blocks of 8, the best 4 blocks of which
+# the first and the last 2 are forced, every block up to 32 keys
+PRESETS["minicpm-sala-tiny"] = ModelConfig(
+    vocab_size=512, hidden_size=64, intermediate_size=128, num_layers=6,
+    num_heads=4, num_kv_heads=2, head_dim=16, rope_theta=10000.0,
+    rms_norm_eps=1e-6, use_qk_norm=True, max_position_embeddings=2048,
+    mixer_types=("lightning-attn", "minicpm4", "lightning-attn",
+                 "lightning-attn", "minicpm4", "minicpm4", "lightning-attn",
+                 "minicpm4"),
+    kept_layers=tuple(range(1, 7)),
+    sparse_kernel_size=8, sparse_kernel_stride=4, sparse_block_size=8,
+    sparse_topk=4, sparse_init_blocks=1, sparse_window_size=16,
+    sparse_dense_len=32, lightning_heads=4, lightning_head_dim=16,
+    scale_emb=12.0, scale_depth=1.4, dim_model_base=16,
+)
+
+
 def get_config(name: str, **overrides) -> ModelConfig:
     return dataclasses.replace(PRESETS[name], **overrides)
 
@@ -812,10 +885,19 @@ def head_and_sample(cfg, params, x, rng, temps):
     from polyrl_tpu.ops.fused_sample import head_sample_pallas
 
     with jax.named_scope("head"):
-        x = norm(params, "final_norm", x, cfg.rms_norm_eps)
+        x = head_input(cfg, norm(params, "final_norm", x, cfg.rms_norm_eps))
         tied = cfg.tie_word_embeddings
+        head = params["embed" if tied else "lm_head"]
+        if not tied and cfg.vocab_size % 128:
+            # a head [d, V] whose V is no whole number of lane tiles lies
+            # on the chip with d minor (the device's own layout for the
+            # shape): read as the [V, d] rows a tied head is, it is taken
+            # where it lies; as [d, V] the program copies all of it before
+            # every dispatch's steps (0.6 GB at 73,448 rows of 4096, which
+            # a chip filled to 14.9 GB has no room for)
+            head, tied = head.T, True
         return head_sample_pallas(
-            x, params["embed" if tied else "lm_head"], rng, temps, tied=tied,
+            x, head, rng, temps, tied=tied,
             interpret=jax.default_backend() != "tpu")
 
 

@@ -35,7 +35,7 @@ import os
 import jax.numpy as jnp
 import numpy as np
 
-from polyrl_tpu.models import decoder
+from polyrl_tpu.models import cache_spec, decoder
 
 _LAYER_MAP = {
     "input_layernorm.weight": "attn_norm",
@@ -250,15 +250,85 @@ def ouro_config(hf: dict, dtype=jnp.bfloat16) -> decoder.ModelConfig:
         ut_steps=steps, sandwich_norm=True, dtype=dtype)
 
 
+# the family's sparse sizes where a config.json has no ``sparse_config``
+# (openbmb/MiniCPM4.1-8B's; benchmark/configs/minicpm-sala.json, assumed)
+_SALA_SPARSE = {"kernel_size": 32, "kernel_stride": 16, "block_size": 64,
+                "topk": 64, "init_blocks": 1, "window_size": 2048,
+                "dense_len": 8192}
+
+
+def minicpm_sala_config(hf: dict, dtype=jnp.bfloat16) -> decoder.ModelConfig:
+    """A ``ModelConfig`` from a ``minicpm_sala`` config.json
+    (openbmb/MiniCPM-SALA): every key the published file has that the
+    decoder reads. ``mixer_types`` names each layer ``minicpm4`` or
+    ``lightning-attn``; a file cut in depth (``published_mixer_types`` and
+    ``published_layers_kept`` [first, last] beside a shorter
+    ``mixer_types``: the benchmark's) gives the published list with the
+    kept layers named. The checkpoint's tensors have no key map yet
+    (``load_hf_params`` says so)."""
+    kinds = tuple(hf["mixer_types"])
+    if set(kinds) - set(cache_spec.MIXER_TYPES):
+        raise NotImplementedError(f"mixer_types {sorted(set(kinds))}")
+    if hf.get("attn_use_rope") or not hf.get("lightning_use_rope", True) \
+            or not hf.get("qk_norm") or hf.get("attention_bias"):
+        raise NotImplementedError(
+            "minicpm_sala with rope in its softmax layers, without it in "
+            "its lightning layers, without q/k norms or with projection "
+            "biases: none is written")
+    if not (hf.get("use_output_gate") and hf.get("use_output_norm")
+            and hf.get("attn_use_output_gate")):
+        raise NotImplementedError(
+            "minicpm_sala without its output gates or output norm")
+    if hf.get("lightning_nkv", hf["lightning_nh"]) != hf["lightning_nh"]:
+        raise NotImplementedError("lightning layers with grouped K/V heads")
+    kept = None
+    if "published_mixer_types" in hf:
+        first, last = hf["published_layers_kept"]
+        kept = tuple(range(first, last + 1))
+        whole = tuple(hf["published_mixer_types"])
+        if (len(whole) != hf["published_num_hidden_layers"]
+                or whole[first:last + 1] != kinds):
+            raise ValueError("a depth cut that is no run of the published "
+                             "mixer_types")
+        kinds = whole
+    if len(kept or kinds) != hf["num_hidden_layers"]:
+        raise ValueError(f"{len(kept or kinds)} mixer_types for "
+                         f"{hf['num_hidden_layers']} layers")
+    sp = {**_SALA_SPARSE, **(hf.get("sparse_config") or {})}
+    return decoder.ModelConfig(
+        vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        num_layers=hf["num_hidden_layers"],
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"], head_dim=hf["head_dim"],
+        rope_theta=float(hf["rope_theta"]),
+        rms_norm_eps=float(hf["rms_norm_eps"]), use_qk_norm=True,
+        tie_word_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        max_position_embeddings=hf["max_position_embeddings"],
+        mixer_types=kinds, kept_layers=kept,
+        sparse_kernel_size=sp["kernel_size"],
+        sparse_kernel_stride=sp["kernel_stride"],
+        sparse_block_size=sp["block_size"], sparse_topk=sp["topk"],
+        sparse_init_blocks=sp["init_blocks"],
+        sparse_window_size=sp["window_size"],
+        sparse_dense_len=sp["dense_len"],
+        lightning_heads=hf["lightning_nh"],
+        lightning_head_dim=hf["lightning_head_dim"],
+        scale_emb=float(hf["scale_emb"]), scale_depth=float(hf["scale_depth"]),
+        dim_model_base=int(hf["dim_model_base"]), dtype=dtype)
+
+
 def config_from_hf(ckpt_dir: str, dtype=jnp.bfloat16) -> decoder.ModelConfig:
     """Build a ModelConfig from the checkpoint's config.json (llama/qwen2/
     qwen3 architectures; ``zaya``: ``zaya_config``; ``phi4flash``:
     ``phi4flash_config``; ``laguna``: ``laguna_config``; ``ouro``:
-    ``ouro_config``)."""
+    ``ouro_config``; ``minicpm_sala``: ``minicpm_sala_config``)."""
     with open(os.path.join(ckpt_dir, "config.json")) as f:
         hf = json.load(f)
     if hf.get("model_type") == "ouro":
         return ouro_config(hf, dtype)
+    if hf.get("model_type") == "minicpm_sala":
+        return minicpm_sala_config(hf, dtype)
     if hf.get("model_type") == "laguna":
         return laguna_config(hf, dtype)
     if hf.get("model_type") == "zaya":
@@ -352,6 +422,10 @@ def load_hf_params(ckpt_dir: str, cfg: decoder.ModelConfig | None = None,
     if cfg.ut_steps > 1 or cfg.sandwich_norm:
         raise NotImplementedError(
             "no key map for an ouro (looped) checkpoint yet: write it from "
+            "the published model.safetensors.index.json (ROADMAP.md Queue 2)")
+    if cfg.mixer_types:
+        raise NotImplementedError(
+            "no key map for a minicpm_sala checkpoint yet: write it from "
             "the published model.safetensors.index.json (ROADMAP.md Queue 2)")
     dtype = dtype or cfg.dtype
     np_dtype = jnp.dtype(dtype)

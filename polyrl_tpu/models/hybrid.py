@@ -23,7 +23,11 @@ weights beside a shared expert; ``mixers/gqa.py``); and a looped model's
 run ``ut_steps`` times a token with the final norm between passes: the
 passes are a scan of the program around the layers below,
 ``_pass_indices``, and a pass finds its own pages by an offset,
-``cache_spec.pass_offset``).
+``cache_spec.pass_offset``); and MiniCPM-SALA's (``minicpm_sala``:
+block-sparse softmax attention whose pages carry pooled keys beside the
+K/V pair, among linear-attention layers with a float32 state in the slot,
+under muP's scalings of the embedding, of each sublayer's output and of
+the head's input; ``mixers/sparse.py``, ``mixers/lightning.py``).
 
 One block a kind, parameters stacked per kind::
 
@@ -213,6 +217,24 @@ def param_specs(cfg) -> dict:
 # -- the layer loop -------------------------------------------------------------
 
 
+def _embed(cfg, params, ids):
+    """The tokens' rows of the embedding, times ``scale_emb`` (muP)."""
+    x = params["embed"][ids]
+    if cfg.scale_emb == 1.0:
+        return x
+    return (x.astype(jnp.float32) * cfg.scale_emb).astype(x.dtype)
+
+
+def _branch(cfg, out):
+    """A sublayer's output as it joins the residual stream: under muP
+    (``scale_depth`` > 0) times ``scale_depth / sqrt(depth)``, the depth
+    the published model's and not a cut's."""
+    if not cfg.scale_depth:
+        return out
+    scale = cfg.scale_depth / cache_spec.published_depth(cfg) ** 0.5
+    return (out.astype(jnp.float32) * scale).astype(out.dtype)
+
+
 def _residual(x, out, res):
     """A sublayer's residual: ``x + out``, or with ``res`` [4, d] = (a_r,
     b_r, a_o, b_o) the scaled ``(a_r x + b_r) + (a_o out + b_o)``."""
@@ -275,7 +297,7 @@ def _mlp(cfg, x, layers, l, mlp_lp, valid, carry=None):
                          mlp_lp["w_down"])
             with jax.named_scope("glue"):
                 out = _post(cfg, layers, "mlp_post_norm", out, l)
-                return _residual(x, out, res), None, carry
+                return _residual(x, _branch(cfg, out), res), None, carry
         shape = h.shape
         with jax.named_scope("glue"):
             rows = h.reshape(-1, shape[-1])
@@ -289,7 +311,7 @@ def _mlp(cfg, x, layers, l, mlp_lp, valid, carry=None):
         out, load = _moe_mlp(cfg, rows, mlp_lp, v, j, route)
         with jax.named_scope("glue"):
             out = _post(cfg, layers, "mlp_post_norm", out.reshape(shape), l)
-            return _residual(x, out, res), load, carry
+            return _residual(x, _branch(cfg, out), res), load, carry
 
 
 def _form(p, form: str):
@@ -402,7 +424,8 @@ def run_plan(params, cfg, x, positions, valid, states=None,
                 Chunk(positions, valid, st, pre, hands))
             with jax.named_scope("glue"):
                 out = _post(cfg, layers, "attn_post_norm", out, l)
-                x = _residual(x, out, _res(layers, "attn_res", l))
+                x = _residual(x, _branch(cfg, out),
+                              _res(layers, "attn_res", l))
             x, _load, latent = _mlp(cfg, x, layers, l, mlp_lp, valid,
                                     hands["latent"])
             return (x, {**hands, **kept.hands, "latent": latent}, kept.pages,
@@ -434,7 +457,7 @@ def forward(params, cfg, input_ids, positions, attn_mask, remat=False,
     with jax.named_scope("glue"):
         valid = attn_mask > 0
     with jax.named_scope("embed"):
-        x = params["embed"][input_ids]
+        x = _embed(cfg, params, input_ids)
     x, _states, _lat = run_sequence(params, cfg, x, positions, valid,
                                     remat=remat)
     return _head(cfg, params, x, logits_for)
@@ -514,6 +537,10 @@ def prefill(params, cfg, ids, lens, prefix_len, pools, prefix_page_ids,
         written = []
         for pool, new, rec in zip(paged, kept, keep_pages):
             with jax.named_scope(rec.pages_scope):
+                if rec.scatter is not None:
+                    written.append(rec.scatter(cfg, pool, prefix_page_ids,
+                                               page_ids, new, prefix_len))
+                    continue
                 written.append(_scatter_chunk(
                     rec, pool, page_ids if off is None else page_ids + off,
                     new, valid))
@@ -522,7 +549,7 @@ def prefill(params, cfg, ids, lens, prefix_len, pools, prefix_page_ids,
     if cache_spec.passes(cfg) == 1:
         prefix = gathered(paged)
         with jax.named_scope("embed"):
-            x = params["embed"][ids]
+            x = _embed(cfg, params, ids)
         x, new_states, kept = run_plan(params, cfg, x, positions, valid,
                                        states, prefix)
         paged = scattered(paged, kept)
@@ -540,7 +567,7 @@ def prefill(params, cfg, ids, lens, prefix_len, pools, prefix_page_ids,
 
         new_states = []
         with jax.named_scope("embed"):
-            x = params["embed"][ids]
+            x = _embed(cfg, params, ids)
         (x, paged), _ = jax.lax.scan(one_pass, (x, paged),
                                      _pass_indices(cfg))
     written = []
@@ -683,7 +710,7 @@ def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
         rows_read = jnp.sum(attn_lens)
 
     with jax.named_scope("embed"):
-        x = params["embed"][tokens]
+        x = _embed(cfg, params, tokens)
     load = Load(load_names(cfg))
     with jax.named_scope("moe_route"):
         hands = {"latent": router_carry(cfg, (s,))}
@@ -713,7 +740,8 @@ def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
                 state[at_slot] = kept.slot
             with jax.named_scope("glue"):
                 out = _post(cfg, layers, "attn_post_norm", out, l)
-                x = _residual(x, out, _res(layers, "attn_res", l))
+                x = _residual(x, _branch(cfg, out),
+                              _res(layers, "attn_res", l))
             x, moe, latent = _mlp(cfg, x, layers, l, mlp_lp, active,
                                   hands["latent"])
             hands = {**hands, **kept.hands, "latent": latent}

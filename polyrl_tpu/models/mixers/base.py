@@ -157,12 +157,16 @@ class Mixer:
     # to the byte (an accepted benchmark cell's): one form for all is for
     # the PR that can measure that cell (ROADMAP D9 (3))
     pages_by_slabs: bool = False
+    # a kind whose pages hold more than what ``hybrid._scatter_chunk``
+    # writes: (cfg, pool, prefix_page_ids, page_ids, what its sequence form
+    # kept, tokens before the chunk) -> pool
+    scatter: Callable | None = None
     # its slot around a prefill chunk: (cfg, arrays, SlotRows) -> rows;
     # (cfg, arrays, SlotRows, new rows, rows read) -> arrays
     read_slot: Callable = read_rows
     write_slot: Callable = write_rows
     # (cfg, arrays, slot) -> what ``CBEngine.recurrent_state`` reads of its
-    # slot, float32 on the host
+    # slot, on the host (float32; a ``sparse`` layer's table int32)
     held: Callable | None = None
     # the entries of the step's load vector its one-token form moves, by
     # their ``server_info`` names

@@ -102,12 +102,16 @@ def _out(lp, o, gate):
         return mm(o.reshape(*o.shape[:-2], -1), lp["wo"])
 
 
-def gqa_attention(cfg, q, k, v, q_at, k_at, window: int = 0):
+def gqa_attention(cfg, q, k, v, q_at, k_at, window: int = 0, chosen=None,
+                  block: int = 0):
     """Softmax attention of every head for a batch: ``q`` [B, T, H, D]
     against keys ``k`` and values ``v`` [B, Tk, Hkv, D]; ``q_at`` [B, T]
     and ``k_at`` [B, Tk] are positions in the sequence (``k_at`` < 0: no
     key there); a query sees the keys at or before it, with ``window``
-    only the last ``window`` of them. Returns o [B, T, H, D] in ``q``'s
+    only the last ``window`` of them, with ``chosen`` [B, Hkv, T, Tk /
+    block] only those in the blocks of ``block`` keys (by their place in
+    ``k``, Tk whole blocks) that its K/V head's mask names
+    (``mixers/sparse.py``). Returns o [B, T, H, D] in ``q``'s
     type. Blocked over the keys with a running softmax, as
     ``diff_attention`` is, so that the scores of 16k keys never stand at
     once, and a block no query sees is skipped."""
@@ -119,6 +123,8 @@ def gqa_attention(cfg, q, k, v, q_at, k_at, window: int = 0):
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
         k_at = jnp.pad(k_at, ((0, 0), (0, pad)), constant_values=-1)
+        if chosen is not None:
+            chosen = jnp.pad(chosen, ((0, 0),) * 3 + ((0, pad // block),))
     qg = q.reshape(b, t, hkv, h // hkv, d)
     scale = d ** -0.5
     last = jnp.max(q_at)
@@ -134,6 +140,10 @@ def gqa_attention(cfg, q, k, v, q_at, k_at, window: int = 0):
         if window:
             seen &= at[:, None, :] > q_at[:, :, None] - window
         seen = seen[:, None, None]
+        if chosen is not None:
+            took = jax.lax.dynamic_slice_in_dim(
+                chosen, i * (kb // block), kb // block, 3)
+            seen = seen & jnp.repeat(took, block, axis=3)[:, :, None]
         m_new = jnp.maximum(m, jnp.max(jnp.where(seen, s, -1e30), axis=-1,
                                        keepdims=True))
         pr = jnp.where(seen, jnp.exp(s - m_new), 0.0)

@@ -30,6 +30,10 @@ LEAF_SCOPES = (
     # (``*_proj``, ``cca_mix``) and its recurrence or attention
     "mla_proj", "mla_core", "kda_proj", "kda_core", "cca_proj", "cca_mix",
     "ssm_proj", "ssm_core", "gmu", "diff_mix",
+    # block-sparse attention's choice of a row's blocks (the pooled key a
+    # token completes, the scores against the pooled keys, the chosen
+    # pages' table); linear attention's products and its state's step
+    "sparse_select", "lightning_proj", "lightning_core",
     # inside ``mlp``: the router, the routed experts, the shared expert,
     # and the gate, up and down products of a dense MLP
     "moe_route", "moe_experts", "moe_shared", "mlp_dense",

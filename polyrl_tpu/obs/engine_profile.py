@@ -105,6 +105,17 @@ emission or iteration, never once a token, all on this profiler's clock:
   ``row_steps_done``: the passes a token, while no row ends), and the keys
   a step's rows attend over times the passes, each of which reads a cache
   of its own (0 for every other model);
+  ``sparse_pages_read`` / ``sparse_pooled_scored`` / ``sparse_dense_rows``
+  — for a model with block-sparse attention layers (``mixers/sparse.py``),
+  counted by the landed steps on the device: the pages its (row, K/V head)s
+  attended over, summed over the sparse layers; the pooled keys a row's
+  queries were scored against where the row is past ``sparse_dense_len``,
+  summed likewise; and the live rows at or under it, which attend every
+  block (0 for every other model);
+  ``lightning_state_rows`` / ``lightning_kernel_steps`` — live rows times
+  linear-attention layers (a float32 state read and written each;
+  ``mixers/lightning.py``), and the steps whose program updated those
+  states in the one-pass kernel (``ops/lightning_state.py``);
   ``decode_dispatches_cold`` — those of the dispatches enqueued with
   NOTHING outstanding (the device had run dry: an engine that keeps its
   run-ahead does it once a burst, one that drains before every dispatch
@@ -196,6 +207,8 @@ CUMULATIVE_KEYS = (
     "paged_rows_read",
     "ut_passes",
     "kv_pass_rows_read",
+    "sparse_pages_read", "sparse_pooled_scored", "sparse_dense_rows",
+    "lightning_state_rows", "lightning_kernel_steps",
     "device_busy_s", "loop_wall_s", "loop_host_s",
     *PHASE_KEYS.values(), "emit_wait_s", "dispatches_emitted",
     "landing_gap_hist", "stalls", "programs_built", "build_s")

@@ -251,3 +251,42 @@ def test_laguna_checkpoint_is_refused_until_its_key_map_exists(tmp_path):
     assert cfg.sliding_window == 512 and cfg.attn_head_gate
     with pytest.raises(NotImplementedError, match="no key map for a laguna"):
         hf_loader.load_hf_params(str(tmp_path))
+
+
+# -- the minicpm_sala family (sparse and lightning layers) -------------------
+
+
+def test_minicpm_sala_config_is_the_published_preset(tmp_path):
+    """``minicpm_sala_config`` on the benchmark's file (the catalog's keys,
+    cut to published layers 9-20) gives the ``minicpm-sala`` preset, on
+    the published keys alone the whole model; a checkpoint's config.json
+    is read, its tensors are refused by name until a key map exists."""
+    import json
+    import os
+
+    from polyrl_tpu.models import hf_loader
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "minicpm-sala.json")) as f:
+        hf = json.load(f)
+    assert hf["model_type"] == "minicpm_sala"
+    assert hf_loader.minicpm_sala_config(hf) == decoder.get_config(
+        "minicpm-sala")
+    whole = {**hf, "num_hidden_layers": 32,
+             "mixer_types": hf["published_mixer_types"]}
+    for key in ("published_num_hidden_layers", "published_layers_kept",
+                "published_mixer_types"):
+        del whole[key]
+    assert hf_loader.minicpm_sala_config(whole) == decoder.get_config(
+        "minicpm-sala", num_layers=32, kept_layers=None)
+    with pytest.raises(NotImplementedError, match="rope in its softmax"):
+        hf_loader.minicpm_sala_config({**hf, "attn_use_rope": True})
+    with pytest.raises(ValueError, match="no run of the published"):
+        hf_loader.minicpm_sala_config({**hf, "published_layers_kept": [8, 19]})
+    (tmp_path / "config.json").write_text(json.dumps(hf))
+    cfg = hf_loader.config_from_hf(str(tmp_path))
+    assert cfg.mixer_types and cfg.kept_layers == tuple(range(9, 21))
+    with pytest.raises(NotImplementedError,
+                       match="no key map for a minicpm_sala"):
+        hf_loader.load_hf_params(str(tmp_path))

@@ -76,6 +76,15 @@ def looped():
     return cfg, decoder.init_params(jax.random.PRNGKey(0), cfg)
 
 
+@pytest.fixture(scope="module")
+def sala():
+    """MiniCPM-SALA's family: block-sparse layers whose pages carry pooled
+    keys beside the K/V pair, among linear-attention layers with a float32
+    state in the slot (``tests/test_sala.py``); the page is its block."""
+    cfg = decoder.get_config("minicpm-sala-tiny", dtype=jnp.float32)
+    return cfg, decoder.init_params(jax.random.PRNGKey(0), cfg)
+
+
 def _engine(model, **kw):
     cfg, params = model
     opts = dict(max_slots=4, page_size=PS, max_seq_len=128,
@@ -399,13 +408,15 @@ def test_the_row_that_yields_is_the_one_with_least_to_redo(dense):
     _books_balance(eng, 13)
 
 
-@pytest.mark.parametrize("family", ["hybrid", "cca", "sambay", "mixed"])
+@pytest.mark.parametrize("family", ["hybrid", "cca", "sambay", "mixed",
+                                    "sala"])
 def test_the_tiny_hybrid_rebuilds_a_yielded_rows_state(request, family):
     """A model with a state in its slot (``hybrid``: KDA states beside a
     latent pool; ``cca``: convolution tails beside a K/V pair in the same
     layer; ``sambay``: Mamba states and window rings beside one shared K/V
     layer; ``mixed``: rope'd window layers' rings beside full layers'
-    pages) re-enters from token 0: the chunks recompute the slot's rows
+    pages; ``sala``: linear-attention states beside sparse layers' pages
+    and their pooled keys) re-enters from token 0: the chunks recompute the slot's rows
     with the pages, and nothing is streamed twice."""
     hybrid = request.getfixturevalue(family)
     opts = dict(prompt_buckets=(16, 64), prefill_chunk=16,

@@ -1235,3 +1235,119 @@ def test_looped_prefill_chunk_holds_the_layers_once_and_compiles_for_v5e(
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert live < 15.7 * 10**9
     assert m.temp_size_in_bytes < 0.5 * 10**9
+
+
+# -- MiniCPM-SALA's cell (benchmark/configs/minicpm-sala.json) ------------------
+
+
+def _sala_shapes(one_chip, s, n_pages, page):
+    from polyrl_tpu.models import decoder
+
+    cfg = decoder.get_config("minicpm-sala")
+
+    def shapes(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = shapes(jax.eval_shape(
+        lambda: decoder.init_params(jax.random.PRNGKey(0), cfg)))
+    pools = shapes(jax.eval_shape(
+        lambda: decoder.make_paged_pools(cfg, n_pages, page, slots=s + 1)))
+    return cfg, params, pools
+
+
+SALA_PAGES = 22978
+
+
+def test_sala_decode_step_compiles_for_v5e_and_moves_no_pool(
+        one_chip, chip_precision, on_tpu):
+    """The cell's whole decode program: 8 fused steps of the 12 layers at
+    96 rows, the pages with their pooled stores and the 9 layers' states
+    donated, the token drawn inside the untied head. The three sparse
+    layers' write and attention kernels take the pools as ONE K/V head of
+    ``2 * N`` pages under 16 query rows (Mosaic's word on it); the nine
+    lightning layers update their states in the one-pass kernel. Weights
+    (7.86 GB), pages (4.80 GB) and states (1.83 GB) and everything the step
+    holds at once fit a 16 GB chip; nothing the optimised program writes
+    is as large as a sparse layer's K pool or pooled store, or a lightning
+    layer's states, but the kernels' own in-place results and a pooled
+    store's scatter, and no K/V pool is taken into VMEM."""
+    from polyrl_tpu.models import decoder
+
+    s, width, page = 96, 448, 64
+    cfg, params, pools = _sala_shapes(one_chip, s, SALA_PAGES, page)
+    assert len(pools[0]) == 3 and len(pools[1]) == 12
+    assert pools[0][0][0].shape == (2, SALA_PAGES, page, 128)
+    assert pools[0][0][2].shape == (SALA_PAGES, 8, 128)
+    # the first layer's table of the pages a step attended, then a state
+    assert pools[1][0][0].shape == (97, 2, 129)
+    assert pools[1][1][0].shape == (97, 32, 128, 128)
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(params, paged, state, rng, table, lens, last, active, temps):
+        def body(carry, _):
+            paged, state, rng, lens, last = carry
+            rng, sub = jax.random.split(rng)
+            head = functools.partial(decoder.head_and_sample, rng=sub,
+                                     temps=temps)
+            (tok, logp), (paged, state), load = decoder.forward_paged_decode(
+                params, cfg, last, lens, (paged, state), table, lens,
+                active=active, head_fn=head)
+            return (paged, state, rng, lens + 1, tok), (tok, logp, load)
+        return jax.lax.scan(body, (paged, state, rng, lens, last), None,
+                            length=8)
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, pools[0], pools[1], arg((2,), jnp.uint32),
+        arg((s, width), jnp.int32), arg((s,), jnp.int32),
+        arg((s,), jnp.int32), arg((s,), jnp.bool_),
+        arg((s,), jnp.float32)).compile()
+    m = compiled.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert 14.3e9 < live < 15.2e9
+    text = compiled.as_text()
+    # a write and an attention a sparse layer, a state update a lightning
+    # layer, the head
+    assert text.count("tpu_custom_call") == 2 * 3 + 9 + 1
+    pool = (2, SALA_PAGES, 64, 128)            # a sparse layer's K (or V)
+    assert _made(text, math.prod(pool)) == []
+    assert _taken_into_vmem(text, pool) == []
+    assert _taken_into_vmem(text, (1, 2 * SALA_PAGES, 64, 128)) == []
+    # (a pooled store, 94 MB, IS taken into VMEM around its scatter and its
+    # gather and copied back: on the chip that is 1.0 ms a step faster than
+    # the store pinned to HBM; my chip runs, PR 56)
+    assert _made(text, 97 * 32 * 128 * 128) == []        # a layer's states
+
+
+@pytest.mark.parametrize("n_pre", [0, 256])
+def test_sala_prefill_chunk_compiles_for_v5e_within_memory(
+        one_chip, chip_precision, on_tpu, n_pre):
+    """A 512-token chunk, a prompt's first and one over 256 pages of prefix
+    (16k tokens): the sparse layers choose per query token and attend
+    through the blocks' mask, the lightning layers run the chunked form
+    from the slot's state, beside the weights, the pages and the states."""
+    from polyrl_tpu.models import decoder
+
+    page, pb = 64, 512
+    cfg, params, pools = _sala_shapes(one_chip, 96, SALA_PAGES, page)
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def chunk(params, paged, state, ids, n, at, pre_pages, pages, slot):
+        return decoder.prefill_suffix_into_pages(
+            params, cfg, ids, n, at, (paged, state), pre_pages, pages, slot)
+
+    compiled = jax.jit(chunk, donate_argnums=(1, 2)).lower(
+        params, pools[0], pools[1], arg((pb,), jnp.int32),
+        arg((), jnp.int32), arg((), jnp.int32), arg((n_pre,), jnp.int32),
+        arg((pb // page,), jnp.int32), arg((), jnp.int32)).compile()
+    m = compiled.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert live < 15.7 * 10**9
+    assert m.temp_size_in_bytes < 1.2 * 10**9

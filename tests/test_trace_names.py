@@ -18,7 +18,7 @@ from polyrl_tpu.rollout.cb_engine import CBEngine
 SCOPES = ("attn_qkv", "attn_core", "attn_out", "mlp", "head")
 # the dense decoder and one preset a family of mixers (``FAMILIES`` below)
 STEP_PRESETS = ("tiny", "moe-tiny", "hybrid-tiny", "mla-moe-tiny", "cca-tiny",
-                "sambay-tiny", "mixed-tiny", "ouro-tiny")
+                "sambay-tiny", "mixed-tiny", "ouro-tiny", "minicpm-sala-tiny")
 ENGINE_PROGRAMS = {
     "step": lambda e: e._get_step(False, 2),
     "spec_step": lambda e: e._get_spec_step(False, 3, 2),
@@ -274,6 +274,9 @@ FAMILIES = {
                            "window_rows_read")),
     "ouro-tiny": ("ouro-2.6b.rollout-short-looped",
                   ("paged_rows_read", "ut_passes", "kv_pass_rows_read")),
+    "minicpm-sala-tiny": ("minicpm-sala.rollout-long-sparse-linear",
+                          ("sparse_pages_read", "sparse_pooled_scored",
+                           "sparse_dense_rows", "lightning_state_rows")),
 }
 
 
