@@ -33,12 +33,19 @@ A decode step writes the token's key and value (``paged_kv_write``), then
 under ``sparse_select``: the pooled key that the token completes (token
 ``stride j + kernel - 1``: the mean of the last ``kernel`` keys, read back
 from the pages as two slabs of ``stride`` rows), the scores of the row's
-queries against its pooled keys (gathered a page at a time through the
-table, as an embedding's rows are), the blocks' scores, and the choice
+queries against its pooled keys, the blocks' scores, and the choice
 WITHOUT a sort (a block's rank is the count of blocks that beat it, a
 [blocks, blocks] comparison: PR 49 found ``lax.top_k`` to be a full sort
 on this chip); the chosen blocks in rising order, the row's part-filled
-own block last, are a page table a (row, K/V head). Then attention over
+own block last, are a page table a (row, K/V head). ``selected_table`` is
+the one function that builds it, in one of two forms of the same
+equations: on a TPU at the published sizes the kernel of
+``ops/sparse_select.py``, a row a program over THAT ROW'S pages of the
+store, which stays in HBM (PR 57; ``in_kernel``; the engine counts
+``sparse_kernel_steps``); elsewhere, and as the kernel's oracle, the jnp
+form, which gathers every row's pooled keys a page at a time through the
+table at its full width, as an embedding's rows are, and ranks the table's
+width of blocks against itself. Then attention over
 that table by the GQA decode kernel (``ops.paged_attention``) as it is:
 the pools seen as ONE K/V head of ``Hkv * N`` pages and each (row, K/V
 head) as a row of G query heads, so its block plan, DMA ring and HBM
@@ -301,11 +308,12 @@ def _complete_pooled(cfg, k_pool, c_pool, ctx):
     ``kernel`` keys, two slabs of ``stride`` rows of ``k_pool``, the
     token's own among them) at row ``(j % r) * Hkv + g`` of page ``j //
     r`` of the row's table; a row that completes none writes the null
-    page. (The chip's compiler keeps the scatter's whole result, 94 MB at
-    the cell's sizes, in VMEM and copies it back, 0.41 ms a step for three
-    layers; pinned to HBM through a kernel that does nothing the copy goes
-    and the step is 1.0 ms SLOWER, the gather then reading HBM: my chip
-    runs, PR 56, three seeds each way. Left to the compiler.)"""
+    page. The scatter of ``S * Hkv`` rows is XLA's. (Beside the jnp form
+    of ``selected_table`` the chip's compiler keeps the scatter's whole
+    result, 94 MB at the cell's sizes, in VMEM for the gather that follows
+    and copies it back, 0.41 ms a step for three layers: my chip runs, PR
+    56. The kernel takes the store as an operand pinned to HBM
+    (``_in_hbm``), so there is no gather to serve and no copy: PR 57.)"""
     stride, kernel, _block, r = geometry(cfg)
     hkv, n_pages, ps, d = k_pool.shape
     n = ctx.attn_lens                                           # [S]
@@ -329,6 +337,20 @@ def _complete_pooled(cfg, k_pool, c_pool, ctx):
     return flat.reshape(c_pool.shape)
 
 
+def in_kernel(cfg, rows: int = 0) -> bool:
+    """Whether a decode step chooses its blocks in the kernel
+    (``ops/sparse_select.py``), from what its program is built on: the
+    backend, the pooled store's shape and dtype (a page's pooled keys of
+    every K/V head one float32 tile; the engine's page is the block, or
+    ``step`` raises), heads of 128."""
+    from polyrl_tpu.ops import sparse_select
+
+    hkv = cfg.num_kv_heads
+    return sparse_select.in_kernel(
+        (0, geometry(cfg)[3] * hkv, cfg.head_dim_), cache_spec.POOLED_DTYPE,
+        cfg.head_dim_, cfg.num_heads // hkv)
+
+
 def selected_table(cfg, q, c_pool, ctx, n_pages: int):
     """The pages each (row, K/V head) attends, as ``paged_attention`` takes
     them from the pools seen as one head of ``Hkv * n_pages`` pages:
@@ -336,24 +358,37 @@ def selected_table(cfg, q, c_pool, ctx, n_pages: int):
     n_pages``, the chosen blocks in rising order and so the row's own,
     part-filled, last; the keys they hold [S * Hkv]; the chosen blocks a
     (row, head) [S, Hkv]). W: the most a row takes (``table_width``) or
-    the row's own table's width."""
-    _stride, _kernel, block, r = geometry(cfg)
+    the row's own table's width. Built by the kernel that walks each row's
+    own pooled pages where ``in_kernel`` says so, else by the jnp form
+    below, at the table's full width: the oracle, and the path off a TPU."""
+    from polyrl_tpu.ops import sparse_select
+
+    stride, kernel, block, r = geometry(cfg)
     s, width = ctx.page_table.shape
     hkv, d = cfg.num_kv_heads, cfg.head_dim_
-    # a page's rows are its pooled keys in order, a K/V head after the
-    # other within each: the gathered pages ARE the row's pooled keys
-    pooled = c_pool[ctx.page_table].reshape(s, width * r, hkv, d)
-    n = jnp.maximum(ctx.attn_lens, 1)[:, None]                  # [S, 1]
-    chosen = choose(cfg, block_scores(cfg, q[:, None], pooled, n), n)[:, :, 0]
-    chosen &= ctx.live[:, None, None]                            # [S, Hkv, M]
-    count = jnp.sum(chosen.astype(jnp.int32), axis=-1)
     w = min(width, table_width(cfg))
-    place = jnp.cumsum(chosen.astype(jnp.int32), axis=-1) - 1
-    hit = chosen[..., None] & (
-        place[..., None] == jnp.arange(w, dtype=jnp.int32))      # [S,Hkv,M,W]
-    pages = (ctx.page_table[:, None, :]
-             + jnp.arange(hkv, dtype=jnp.int32)[None, :, None] * n_pages)
-    table = jnp.sum(jnp.where(hit, pages[..., None], 0), axis=2)
+    if in_kernel(cfg):
+        _scores, table, count = sparse_select.sparse_select_pallas(
+            q, c_pool, ctx.page_table, jnp.where(ctx.live, ctx.attn_lens, 0),
+            stride=stride, kernel=kernel, block=block, topk=cfg.sparse_topk,
+            init_blocks=cfg.sparse_init_blocks,
+            near_blocks=cfg.sparse_window_size // block,
+            dense_len=cfg.sparse_dense_len, width=w, n_pages=n_pages)
+    else:
+        # a page's rows are its pooled keys in order, a K/V head after the
+        # other within each: the gathered pages ARE the row's pooled keys
+        pooled = c_pool[ctx.page_table].reshape(s, width * r, hkv, d)
+        n = jnp.maximum(ctx.attn_lens, 1)[:, None]                # [S, 1]
+        chosen = choose(cfg, block_scores(cfg, q[:, None], pooled, n),
+                        n)[:, :, 0]
+        chosen &= ctx.live[:, None, None]                         # [S,Hkv,M]
+        count = jnp.sum(chosen.astype(jnp.int32), axis=-1)
+        place = jnp.cumsum(chosen.astype(jnp.int32), axis=-1) - 1
+        hit = chosen[..., None] & (
+            place[..., None] == jnp.arange(w, dtype=jnp.int32))   # [S,Hkv,M,W]
+        pages = (ctx.page_table[:, None, :]
+                 + jnp.arange(hkv, dtype=jnp.int32)[None, :, None] * n_pages)
+        table = jnp.sum(jnp.where(hit, pages[..., None], 0), axis=2)
     own = (ctx.attn_lens - 1) // block
     lens = jnp.where(count > 0, (count - 1) * block
                      + (ctx.attn_lens - own * block)[:, None], 0)
@@ -426,4 +461,5 @@ SPARSE = Mixer(
     row_parallel=("wo",), pages_scope="attn_core", pages_by_slabs=True,
     scatter=scatter, slot_scope="sparse_select", read_slot=_no_rows,
     write_slot=_as_they_are, held=held,
-    counts=("sparse_pages_read", "sparse_pooled_scored", "sparse_dense_rows"))
+    counts=("sparse_pages_read", "sparse_pooled_scored", "sparse_dense_rows"),
+    kernel=("sparse_kernel_steps", in_kernel))

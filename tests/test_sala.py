@@ -256,21 +256,53 @@ def test_chunked_prefill_then_decode_agrees_with_the_full_forward(
     np.testing.assert_allclose(mine, tr["pooled"], atol=LOGIT_TOL)
 
 
-@pytest.mark.parametrize("fault, least", [
-    ("", 0.0), ("first_pages", 0.25), ("no_head_offset", 0.5)])
+@pytest.fixture(scope="module")
+def wide(params):
+    """The tiny model at the sizes the selection kernel takes
+    (``ops/sparse_select.py``): 16 query heads of 128 over 2 K/V heads,
+    pooled keys of 4 tokens every 2, so that a page of 8 tokens holds one
+    float32 tile of them; weights drawn like ``params``."""
+    cfg = decoder.get_config(
+        "minicpm-sala-tiny", dtype=jnp.float32, num_heads=16, head_dim=128,
+        sparse_kernel_size=4, sparse_kernel_stride=2)
+    tree = decoder.init_params(jax.random.PRNGKey(1), cfg)
+    norms = {k: v for k, v in params["layers"]["sparse"].items()
+             if k in ("q_norm", "k_norm")}
+    tree["layers"]["sparse"].update({
+        k: jnp.tile(v, (1, 128 // v.shape[1])) for k, v in norms.items()})
+    return cfg, tree
+
+
+@pytest.mark.parametrize("fault, least, kernel", [
+    ("", 0.0, False), ("first_pages", 0.25, False),
+    ("no_head_offset", 0.5, False), ("", 0.0, True),
+    ("first_pages", 0.25, True)])
 def test_the_steps_own_table_is_held_to_the_references_choice(
-        ref, cfg, params, fault, least):
+        ref, cfg, params, wide, monkeypatch, fault, least, kernel):
     """48 decode steps of a row from its first token, compiled once; then
     the table its last step left in the slot, read back through the row's
     pages as the benchmark's plane reads it (``step_choice``), against the
     reference's choice for that token. With a fault planted in the step's
     table (``benchmark/tests/control_sala_on_chip.py::plant``: the first
     pages in place of the chosen ones; a head's offset dropped) the share
-    of the reference's blocks it lacks says so, whatever the logits do."""
+    of the reference's blocks it lacks says so, whatever the logits do.
+    ``kernel``: the step chooses in the selection kernel, interpreted, at
+    the sizes it takes (``wide``); the row beside it has no request, and
+    its slot's table stays as it was."""
+    import functools
     import importlib
+
+    from polyrl_tpu.ops import sparse_select
 
     plane = harness.load_named("planes", "rollout_sala")
     control = importlib.import_module("benchmark.tests.control_sala_on_chip")
+    if kernel:
+        cfg, params = wide
+        monkeypatch.setattr(sparse_select, "in_kernel", sparse_select.accepts)
+        monkeypatch.setattr(
+            sparse_select, "sparse_select_pallas", functools.partial(
+                sparse_select.sparse_select_pallas, interpret=True))
+        assert sparse.in_kernel(cfg)
     n = 48
     ids = np.asarray(_prompts([n], seed=11)[0], np.int32)
     c = file_keys(cfg)
@@ -290,6 +322,7 @@ def test_the_steps_own_table_is_held_to_the_references_choice(
     finally:
         undo()
     picked = hybrid.held_state(cfg, pools[1], 1)[0]
+    assert not hybrid.held_state(cfg, pools[1], 0)[0].any()
     tr = ref.trace(params, c, ids.tolist(), n - 1, 1)
     assert tr["chosen"].sum(1).tolist() == [4, 4]
     diff = plane.set_diff(plane.step_choice(c, picked, pages, n, 24),

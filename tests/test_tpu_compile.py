@@ -1266,13 +1266,15 @@ def test_sala_decode_step_compiles_for_v5e_and_moves_no_pool(
     96 rows, the pages with their pooled stores and the 9 layers' states
     donated, the token drawn inside the untied head. The three sparse
     layers' write and attention kernels take the pools as ONE K/V head of
-    ``2 * N`` pages under 16 query rows (Mosaic's word on it); the nine
-    lightning layers update their states in the one-pass kernel. Weights
-    (7.86 GB), pages (4.80 GB) and states (1.83 GB) and everything the step
-    holds at once fit a 16 GB chip; nothing the optimised program writes
-    is as large as a sparse layer's K pool or pooled store, or a lightning
-    layer's states, but the kernels' own in-place results and a pooled
-    store's scatter, and no K/V pool is taken into VMEM."""
+    ``2 * N`` pages under 16 query rows (Mosaic's word on it), and each
+    chooses its blocks in the kernel that walks a row's own pooled pages;
+    the nine lightning layers update their states in the one-pass kernel.
+    Weights (7.86 GB), pages (4.80 GB) and states (1.83 GB) and everything
+    the step holds at once fit a 16 GB chip; nothing the optimised program
+    writes is as large as a sparse layer's K pool or pooled store, or a
+    lightning layer's states, but the kernels' own in-place results and a
+    pooled store's scatter; no K/V pool and no pooled store is taken into
+    VMEM, and no row's pooled keys are gathered at the table's width."""
     from polyrl_tpu.models import decoder
 
     s, width, page = 96, 448, 64
@@ -1310,17 +1312,50 @@ def test_sala_decode_step_compiles_for_v5e_and_moves_no_pool(
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert 14.3e9 < live < 15.2e9
     text = compiled.as_text()
-    # a write and an attention a sparse layer, a state update a lightning
-    # layer, the head
-    assert text.count("tpu_custom_call") == 2 * 3 + 9 + 1
+    # a write, a choice and an attention a sparse layer, a state update a
+    # lightning layer, the head
+    assert text.count("tpu_custom_call") == 3 * 3 + 9 + 1
     pool = (2, SALA_PAGES, 64, 128)            # a sparse layer's K (or V)
     assert _made(text, math.prod(pool)) == []
     assert _taken_into_vmem(text, pool) == []
     assert _taken_into_vmem(text, (1, 2 * SALA_PAGES, 64, 128)) == []
-    # (a pooled store, 94 MB, IS taken into VMEM around its scatter and its
-    # gather and copied back: on the chip that is 1.0 ms a step faster than
-    # the store pinned to HBM; my chip runs, PR 56)
+    # the pooled store stays where it is around its scatter, and what the
+    # jnp form gathers of it (every row's pages at the table's width) is
+    # not made
+    assert _taken_into_vmem(text, (SALA_PAGES, 8, 128)) == []
+    # nor its view as rows (the scatter's, the kernel's): held in VMEM, or
+    # made by a copy
+    flat = re.compile(rf"f32\[{SALA_PAGES * 8},128\]"
+                      r"(\{[^}]*S\(1\)\}|\S* (copy|copy-done)\()")
+    assert [line[:200] for line in text.splitlines()
+            if flat.search(line)] == []
+    assert _made(text, 97 * width * 8 * 128) == []
     assert _made(text, 97 * 32 * 128 * 128) == []        # a layer's states
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_sparse_select_kernel_compiles_for_v5e(one_chip, dtype):
+    """The kernel that chooses a sparse layer's blocks, alone, at the
+    cell's shapes: 97 rows of 2 K/V heads of 16 queries, a 448-wide table
+    over a pooled store of one float32 tile a page left in HBM; bf16
+    queries (three passes a product) and float32 ones (six). Its strided
+    loads of a tile's sublanes, its transposes and its buffers (two rows'
+    pages, 4 MiB) are Mosaic's to accept."""
+    from polyrl_tpu.ops import sparse_select
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    assert sparse_select.accepts((SALA_PAGES, 8, 128), jnp.float32, 128, 16)
+    compiled = jax.jit(functools.partial(
+        sparse_select.sparse_select_pallas, stride=16, kernel=32, block=64,
+        topk=64, init_blocks=1, near_blocks=32, dense_len=8192, width=128,
+        n_pages=SALA_PAGES)).lower(
+            arg((97, 32, 128), dtype), arg((SALA_PAGES, 8, 128), jnp.float32),
+            arg((97, 448), jnp.int32), arg((97,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert _taken_into_vmem(text, (SALA_PAGES, 8, 128)) == []
 
 
 @pytest.mark.parametrize("n_pre", [0, 256])
