@@ -62,6 +62,12 @@ class EngineOptions:
                "dispatches: the admitted batch is whole soonest and its "
                "streams stall meanwhile (off = one chunk, then one decode "
                "dispatch, in turn)")
+    prefix_pages_floor: int = _opt(
+        1, "smallest bucket of prefix pages a suffix-attending prefill "
+           "(cache hit, chunk extend, chunk final) is built for; the "
+           "buckets double from it. A higher floor builds fewer programs "
+           "(a model of many unrolled layers takes tens of seconds each) "
+           "and attends over padded pages under the floor")
     # Proposals, token history and acceptance stay on the device, so spec
     # dispatches pipeline like normal steps. Wins when outputs are locally
     # repetitive (math/code CoT); costs m x attention reads per verify, so

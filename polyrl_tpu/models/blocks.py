@@ -17,8 +17,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from polyrl_tpu.models import cache_spec
-from polyrl_tpu.models.quant import (QuantWeight, mm, moe_mm, moe_rows,
-                                     unembed)
+from polyrl_tpu.models.quant import (QuantWeight, expert_in, mm, moe_mm,
+                                     moe_rows, unembed)
 from polyrl_tpu.ops import grouped_matmul
 from polyrl_tpu.ops.grouped_matmul import row_tables, row_tile, tiled_layout
 from polyrl_tpu.parallel.mesh import EP, TP
@@ -124,7 +124,7 @@ def _expert_mix(x, experts, layer, token_of, place, choice, top_p, sizes,
     down projection, and each choice reads its row back."""
     n, d = x.shape
     m = token_of.shape[0]
-    e_here = experts["we_gate"].shape[-3]
+    e_here = experts["we_down"].shape[-3]
     mine = jax.lax.dynamic_slice_in_dim(sizes, first, e_here)
     first_row = jnp.sum(jnp.where(jnp.arange(sizes.shape[0]) < first,
                                   sizes, 0))
@@ -133,7 +133,8 @@ def _expert_mix(x, experts, layer, token_of, place, choice, top_p, sizes,
     rows = jnp.where(
         lay.live, _take(token_of, jnp.clip(first_row + lay.src, 0, m - 1)), n)
     xs = _take(jnp.concatenate([x, jnp.zeros((1, d), x.dtype)]), rows)
-    hidden = moe_mm(xs, (experts["we_gate"], experts["we_up"]), lay, layer)
+    ws_in, act = expert_in(experts)
+    hidden = moe_mm(xs, ws_in, lay, layer, act)
     ys = moe_mm(hidden, (experts["we_down"],), lay, layer)
     here = choice - first            # invalid choices carry expert E
     is_here = (here >= 0) & (here < e_here)
@@ -164,7 +165,7 @@ def _expert_mix_sharded(mesh, x, experts, layer, *route):
             q=s, scale=P(*s[:-2], s[-1]))
 
     def local(x, experts, *route):
-        first = jax.lax.axis_index(EP) * experts["we_gate"].shape[-3]
+        first = jax.lax.axis_index(EP) * experts["we_down"].shape[-3]
         return jax.lax.psum(
             _expert_mix(x, experts, layer, *route, first=first), (EP, TP))
 
@@ -325,7 +326,7 @@ def _moe_mlp(cfg, x: jnp.ndarray, lp: dict,
         load = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0),
                           jnp.max(sizes)])
     with jax.named_scope("moe_experts"):
-        experts = {key: lp[key] for key in EXPERT_KEYS}
+        experts = {key: lp[key] for key in EXPERT_KEYS if key in lp}
         token_of = order // k
         if by_table:
             out = _expert_rows(x, experts, layer, token_of, weight, sizes)
@@ -343,6 +344,12 @@ def _moe_mlp(cfg, x: jnp.ndarray, lp: dict,
         with jax.named_scope("moe_shared"):
             gate = jax.nn.silu(mm(x, lp["ws_gate"]).astype(jnp.float32))
             out = out + mm(gate.astype(x.dtype) * mm(x, lp["ws_up"]),
+                           lp["ws_down"]).astype(jnp.float32)
+    elif "ws_up" in lp:
+        # the ungated shared expert: ``relu(x W_up)^2 W_down``
+        with jax.named_scope("moe_shared"):
+            up = jnp.maximum(mm(x, lp["ws_up"]).astype(jnp.float32), 0.0)
+            out = out + mm(jnp.square(up).astype(x.dtype),
                            lp["ws_down"]).astype(jnp.float32)
     with jax.named_scope("glue"):
         return out.astype(x.dtype), load

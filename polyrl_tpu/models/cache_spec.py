@@ -23,7 +23,15 @@ layer (ZAYA1's decoder). A published ``mixer_types`` list
 (``minicpm4`` / ``lightning-attn``: MiniCPM-SALA) makes ``sparse`` (softmax
 attention over the blocks of keys a token's queries choose, a K/V head at
 a time, through pooled keys) and ``lightning`` (linear attention: a float32
-state a head under a constant decay).
+state a head under a constant decay). A published ``hybrid_override_pattern``
+(``nemotron_h``: one character a layer, ``PATTERN_KINDS``) makes layers of
+ONE sublayer: ``M`` is a ``mamba2`` mixer (Mamba-2's SSD: a float32 state
+a head under a scalar decay, B and C shared by groups of heads, the tail of
+one short convolution) and no MLP, ``*`` a ``gqa`` mixer WITHOUT positions
+(``attn_no_rope``) and no MLP, ``E`` a routed MLP and no mixer
+(``LayerPlan.mixer`` or ``.mlp`` is None; ``hybrid.py`` runs such a layer
+with its one norm and one residual, and the tree holds one ``norm`` a
+layer); another character (the family's dense ``-``) is refused by name.
 
 The SambaY family (``mb_per_layer`` > 0: a decoder, a cross-decoder and
 differential attention without positions) has six kinds that follow from
@@ -45,7 +53,10 @@ pages that the engine's allocator hands out: a K/V pair of ``[Hkv, N,
 page, D]`` for ``gqa`` and ``diff``, one latent pool ``[1, N, page, row]``
 for ``mla``, ``row`` being ``rank + rope`` rounded up to whole lanes), or a
 SLOT (fixed-size arrays indexed by the engine's slot, for ``kda``,
-``lightning`` and the scans), or BOTH in one layer (``PagedAndSlot``, for
+``lightning``, the scans and ``mamba2``: its float32 state ``[G, N, (H / G)
+P]``, a group's heads side by side on the lanes under the state size on the
+sublanes, 2 MiB a layer at the published sizes, with the ``[K-1, channels]``
+tail), or BOTH in one layer (``PagedAndSlot``, for
 ``cca`` and ``sparse``: the latter's slot is the table of pages its last
 decode step attended, int32). A ``sparse`` layer's pages carry a
 POOLED-KEY STORE beside the K/V pair (``Paged.pooled``): one float32 row
@@ -112,9 +123,10 @@ import jax.numpy as jnp
 @dataclasses.dataclass(frozen=True)
 class LayerPlan:
     # "gqa" | "kda" | "mla" | "cca" | "ssm" | "swa" | "ssm_mem" | "diff" |
-    # "gmu" | "cross" | "gqa_window" | "sparse" | "lightning"
-    mixer: str
-    mlp: str          # "dense" | "moe"
+    # "gmu" | "cross" | "gqa_window" | "sparse" | "lightning" | "mamba2";
+    # None: the layer has no mixer (``hybrid_override_pattern``)
+    mixer: str | None
+    mlp: str | None   # "dense" | "moe"; None: the layer has no MLP
     published: int    # the layer's index in the published model
 
 
@@ -219,6 +231,8 @@ def layer_plan(cfg) -> tuple[LayerPlan, ...]:
     layer by published layer (``LAYER_TYPES``). ``first_k_dense_replace`` leading published layers keep
     the dense MLP. ``kept_layers`` names the published layers that run
     here (a depth cut), all of them by default."""
+    if cfg.hybrid_override_pattern:
+        return _pattern_plan(cfg)
     kept = cfg.kept_layers or tuple(range(cfg.num_layers))
     if len(kept) != cfg.num_layers:
         raise ValueError(f"kept_layers {kept} names {len(kept)} layers, "
@@ -252,6 +266,38 @@ def layer_plan(cfg) -> tuple[LayerPlan, ...]:
     return tuple(plan)
 
 
+# a character of a published ``hybrid_override_pattern`` -> the layer's ONE
+# sublayer, (mixer, mlp)
+PATTERN_KINDS = {"M": ("mamba2", None), "*": ("gqa", None), "E": (None, "moe")}
+
+
+def _pattern_plan(cfg) -> tuple[LayerPlan, ...]:
+    """The layers a published ``hybrid_override_pattern`` names, one
+    sublayer each (``PATTERN_KINDS``), every one of them kept."""
+    pattern = cfg.hybrid_override_pattern
+    if len(pattern) != cfg.num_layers or (
+            cfg.kept_layers
+            and tuple(cfg.kept_layers) != tuple(range(len(pattern)))):
+        raise ValueError(
+            f"hybrid_override_pattern names {len(pattern)} layers, "
+            f"num_layers is {cfg.num_layers} (no depth cut of a pattern "
+            "without a period)")
+    unknown = sorted(set(pattern) - set(PATTERN_KINDS))
+    if unknown:
+        raise NotImplementedError(
+            f"hybrid_override_pattern characters {unknown}: a layer of "
+            f"that kind is not written here ({sorted(PATTERN_KINDS)} are)")
+    return tuple(LayerPlan(*PATTERN_KINDS[ch], i)
+                 for i, ch in enumerate(pattern))
+
+
+def one_sublayer(cfg) -> bool:
+    """Every layer is ONE norm, one sublayer and one residual (a published
+    ``hybrid_override_pattern``): the tree holds ``norm`` [L, d] in place
+    of ``attn_norm`` and ``mlp_norm``."""
+    return bool(cfg.hybrid_override_pattern)
+
+
 # a published ``layer_types`` entry -> the mixer that runs it
 LAYER_TYPES = {"full_attention": "gqa", "sliding_attention": "gqa_window"}
 # a published ``mixer_types`` entry -> the mixer that runs it
@@ -264,6 +310,7 @@ def is_uniform(cfg) -> bool:
     return (not cfg.layer_group_size and not cfg.kv_lora_rank
             and not cfg.cca_time0 and not cfg.mb_per_layer
             and not cfg.layer_types and not cfg.mixer_types
+            and not cfg.hybrid_override_pattern
             and not (cfg.num_experts and cfg.first_k_dense_replace)
             and cfg.ut_steps == 1 and not cfg.sandwich_norm)
 
@@ -349,10 +396,21 @@ def gqa_heads(cfg, p: LayerPlan) -> int:
     return per_layer[p.published] if per_layer else cfg.num_heads
 
 
+def mamba2_dims(cfg) -> tuple[int, int, int, int, int]:
+    """(heads H, head size P, groups G, state size N, convolution taps K)
+    of a Mamba-2 layer; its inner width is ``H * P`` and its convolution
+    runs over ``H * P + 2 * G * N`` channels (x | B | C)."""
+    return (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.mamba_n_groups,
+            cfg.ssm_state_size, cfg.ssm_conv_kernel)
+
+
 def gqa_rope(cfg, p: LayerPlan):
     """The rope of a ``gqa`` or ``gqa_window`` layer
     (``decoder.RopeParameters``): the published ``rope_parameters`` block
-    of its layer type, the model's own rope without."""
+    of its layer type, the model's own rope without; None for attention
+    WITHOUT positions (``attn_no_rope``)."""
+    if cfg.attn_no_rope:
+        return None
     return cfg.rope_of(cfg.layer_types[p.published] if cfg.layer_types
                        else None)
 
@@ -364,6 +422,8 @@ def layer_cache(cfg, plan: LayerPlan, dtype=None
     from this module's types)."""
     from polyrl_tpu.models.mixers import MIXERS
 
+    if plan.mixer is None:
+        return None
     c = MIXERS[plan.mixer].cache(cfg, plan, dtype or cfg.dtype)
     if passes(cfg) == 1:
         return c
@@ -403,7 +463,8 @@ def without_kernel(cfg, feature: str) -> tuple[str, ...]:
     """The mixers of this model's layers for which the engine's
     ``feature`` has no kernel: empty where it may run."""
     have = FEATURE_KERNELS[feature] if is_uniform(cfg) else ()
-    return tuple(sorted({p.mixer for p in layer_plan(cfg)} - set(have)))
+    return tuple(sorted({p.mixer for p in layer_plan(cfg) if p.mixer}
+                        - set(have)))
 
 
 def paged_bytes_per_token(cfg, dtype=None) -> int:

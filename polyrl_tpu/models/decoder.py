@@ -210,6 +210,22 @@ class ModelConfig:
     scale_emb: float = 1.0
     scale_depth: float = 0.0
     dim_model_base: int = 0
+    # layers of ONE sublayer (``nemotron_h``; ``cache_spec.PATTERN_KINDS``):
+    # ``hybrid_override_pattern`` names each published layer ``M`` (a
+    # Mamba-2 mixer, ``models/mixers/mamba2.py``: ``mamba_num_heads`` heads
+    # of ``mamba_head_dim``, B and C shared by ``mamba_n_groups`` groups of
+    # them, a state of ``ssm_state_size`` a head column, ``ssm_conv_kernel``
+    # taps, prefill in chunks of ``ssd_chunk_size``), ``*`` (a ``gqa``
+    # mixer; ``attn_no_rope``: without positions) or ``E`` (the routed MLP;
+    # ``mlp_hidden_act`` relu2: an expert and the shared expert are two
+    # matrices, ``relu(x W_up)^2 W_down``, no gate)
+    hybrid_override_pattern: str = ""
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_n_groups: int = 1
+    ssd_chunk_size: int = 128
+    attn_no_rope: bool = False
+    mlp_hidden_act: str = "silu"
     dtype: Any = jnp.bfloat16
 
     @property
@@ -618,6 +634,51 @@ PRESETS["minicpm-sala-tiny"] = ModelConfig(
     sparse_topk=4, sparse_init_blocks=1, sparse_window_size=16,
     sparse_dense_len=32, lightning_heads=4, lightning_head_dim=16,
     scale_emb=12.0, scale_depth=1.4, dim_model_base=16,
+)
+
+
+# NVIDIA-Nemotron-3-Nano-30B-A3B (HF config: nvidia/NVIDIA-Nemotron-3-Nano-
+# 30B-A3B-BF16, model_type nemotron_h; ``hf_loader.nemotron_h_config`` of
+# the published keys gives this, tested): 52 layers of ONE sublayer each by
+# the published pattern: 23 Mamba-2 mixers (64 heads of 64, 8 groups, state
+# 128, 4 taps), 6 attention mixers (32 query heads over 2 K/V heads of 128,
+# no positions) and 23 routed MLPs (128 experts of width 1856 at top-6
+# behind DeepSeek-V3's sigmoid router, scaled 2.5, one shared expert of
+# 3712; an expert is two matrices under relu(.)^2); hidden 2688, an untied
+# head over 131,072 rows; 31,577,940,288 parameters
+PRESETS["nemotron-3-nano-30b-a3b"] = ModelConfig(
+    vocab_size=131072, hidden_size=2688, intermediate_size=1856,
+    num_layers=52, num_heads=32, num_kv_heads=2, head_dim=128,
+    rope_theta=10000.0, rms_norm_eps=1e-5, max_position_embeddings=262144,
+    num_experts=128, num_experts_per_tok=6, moe_intermediate_size=1856,
+    scoring_func="sigmoid", n_group=1, topk_group=1,
+    routed_scaling_factor=2.5, moe_shared_expert_intermediate_size=3712,
+    hybrid_override_pattern=(
+        "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"),
+    mamba_num_heads=64, mamba_head_dim=64, mamba_n_groups=8,
+    ssm_state_size=128, ssm_conv_kernel=4, ssd_chunk_size=128,
+    attn_no_rope=True, mlp_hidden_act="relu2",
+)
+# one chip of eight that share EVERY layer: all 52 layers, experts 0-15 of
+# each routed layer, rows 0-16383 of the vocabulary; mixers, shared experts
+# and routers whole (benchmark/configs/nemotron-3-nano-30b-a3b.json)
+PRESETS["nemotron-3-nano-30b-a3b-share8"] = cut_to_share(
+    PRESETS["nemotron-3-nano-30b-a3b"], tuple(range(52)), 8)
+# test-size model of the same family: M E * E M, 4 Mamba-2 heads of 16 in 2
+# groups at state 16, 4 query heads over 2 K/V heads of 16 without
+# positions, 8 experts of width 24 at top-2 of which 4 are held, a shared
+# expert of 48
+PRESETS["nemotron-h-tiny"] = ModelConfig(
+    vocab_size=512, hidden_size=64, intermediate_size=24, num_layers=5,
+    num_heads=4, num_kv_heads=2, head_dim=16, rope_theta=10000.0,
+    rms_norm_eps=1e-5, max_position_embeddings=2048,
+    num_experts=8, num_experts_per_tok=2, moe_intermediate_size=24,
+    scoring_func="sigmoid", n_group=1, topk_group=1,
+    routed_scaling_factor=2.5, moe_shared_expert_intermediate_size=48,
+    experts_held=(0, 4), hybrid_override_pattern="ME*EM",
+    mamba_num_heads=4, mamba_head_dim=16, mamba_n_groups=2,
+    ssm_state_size=16, ssm_conv_kernel=4, ssd_chunk_size=8,
+    attn_no_rope=True, mlp_hidden_act="relu2",
 )
 
 

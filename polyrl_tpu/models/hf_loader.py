@@ -318,17 +318,88 @@ def minicpm_sala_config(hf: dict, dtype=jnp.bfloat16) -> decoder.ModelConfig:
         dim_model_base=int(hf["dim_model_base"]), dtype=dtype)
 
 
+def nemotron_h_config(hf: dict, dtype=jnp.bfloat16) -> decoder.ModelConfig:
+    """A ``ModelConfig`` from a ``nemotron_h`` config.json
+    (nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16): every key the published
+    file has that the decoder reads. ``hybrid_override_pattern`` names each
+    layer's ONE sublayer (``cache_spec.PATTERN_KINDS``); a file that states
+    one chip's share (``experts_held`` [first, count] and ``published``
+    beside ``n_routed_experts`` and ``vocab_size``: the benchmark's) gives
+    the router its published width and the chip its experts. What the
+    family does that is not written here is refused by name. The
+    checkpoint's tensors have no key map yet (``load_hf_params`` says
+    so)."""
+    pattern = hf["hybrid_override_pattern"]
+    if len(pattern) != hf["num_hidden_layers"]:
+        raise ValueError(f"a pattern of {len(pattern)} layers for "
+                         f"{hf['num_hidden_layers']}")
+    unknown = sorted(set(pattern) - set(cache_spec.PATTERN_KINDS))
+    if unknown:
+        raise NotImplementedError(
+            f"hybrid_override_pattern characters {unknown}")
+    for key in ("attention_bias", "mlp_bias", "mamba_proj_bias", "use_bias"):
+        if hf.get(key):
+            raise NotImplementedError(f"nemotron_h with {key}")
+    if hf.get("mlp_hidden_act") != "relu2" \
+            or hf.get("mamba_hidden_act", "silu") != "silu" \
+            or not hf.get("use_conv_bias", True):
+        raise NotImplementedError(
+            "nemotron_h with another MLP activation than relu2, another "
+            "Mamba activation than silu or no convolution bias")
+    if hf.get("n_group", 1) != 1 or hf.get("topk_group", 1) != 1 \
+            or hf.get("n_shared_experts", 1) != 1:
+        raise NotImplementedError(
+            "nemotron_h with a group-limited router or several shared "
+            "experts")
+    if hf.get("time_step_limit") or hf.get("sliding_window"):
+        raise NotImplementedError(
+            "nemotron_h with a clamp on dt or a sliding window")
+    if hf["mamba_num_heads"] % hf["n_groups"]:
+        raise ValueError("Mamba-2 heads that are no whole groups")
+    published = hf.get("published") or {}
+    held = hf.get("experts_held")
+    return decoder.ModelConfig(
+        vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        num_layers=hf["num_hidden_layers"],
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"], head_dim=hf["head_dim"],
+        rope_theta=float(hf["rope_theta"]),
+        rms_norm_eps=float(hf["layer_norm_epsilon"]),
+        tie_word_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        max_position_embeddings=hf["max_position_embeddings"],
+        num_experts=published.get("n_routed_experts", hf["n_routed_experts"]),
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        scoring_func="sigmoid", n_group=1, topk_group=1,
+        routed_scaling_factor=float(hf["routed_scaling_factor"]),
+        moe_shared_expert_intermediate_size=hf[
+            "moe_shared_expert_intermediate_size"],
+        experts_held=tuple(held) if held else None,
+        kept_layers=(tuple(range(len(pattern))) if held else None),
+        hybrid_override_pattern=pattern,
+        mamba_num_heads=hf["mamba_num_heads"],
+        mamba_head_dim=hf["mamba_head_dim"], mamba_n_groups=hf["n_groups"],
+        ssm_state_size=hf["ssm_state_size"],
+        ssm_conv_kernel=hf["conv_kernel"], ssd_chunk_size=hf["chunk_size"],
+        attn_no_rope=True, mlp_hidden_act="relu2", dtype=dtype)
+
+
 def config_from_hf(ckpt_dir: str, dtype=jnp.bfloat16) -> decoder.ModelConfig:
     """Build a ModelConfig from the checkpoint's config.json (llama/qwen2/
     qwen3 architectures; ``zaya``: ``zaya_config``; ``phi4flash``:
     ``phi4flash_config``; ``laguna``: ``laguna_config``; ``ouro``:
-    ``ouro_config``; ``minicpm_sala``: ``minicpm_sala_config``)."""
+    ``ouro_config``; ``minicpm_sala``: ``minicpm_sala_config``;
+    ``nemotron_h``: ``nemotron_h_config``)."""
     with open(os.path.join(ckpt_dir, "config.json")) as f:
         hf = json.load(f)
     if hf.get("model_type") == "ouro":
         return ouro_config(hf, dtype)
     if hf.get("model_type") == "minicpm_sala":
         return minicpm_sala_config(hf, dtype)
+    if hf.get("model_type") == "nemotron_h":
+        return nemotron_h_config(hf, dtype)
     if hf.get("model_type") == "laguna":
         return laguna_config(hf, dtype)
     if hf.get("model_type") == "zaya":
@@ -427,6 +498,10 @@ def load_hf_params(ckpt_dir: str, cfg: decoder.ModelConfig | None = None,
         raise NotImplementedError(
             "no key map for a minicpm_sala checkpoint yet: write it from "
             "the published model.safetensors.index.json (ROADMAP.md Queue 2)")
+    if cfg.hybrid_override_pattern:
+        raise NotImplementedError(
+            "no key map for a nemotron_h checkpoint yet: write it from the "
+            "published model.safetensors.index.json (ROADMAP.md Queue 2)")
     dtype = dtype or cfg.dtype
     np_dtype = jnp.dtype(dtype)
 

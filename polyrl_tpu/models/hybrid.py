@@ -27,13 +27,20 @@ passes are a scan of the program around the layers below,
 block-sparse softmax attention whose pages carry pooled keys beside the
 K/V pair, among linear-attention layers with a float32 state in the slot,
 under muP's scalings of the embedding, of each sublayer's output and of
-the head's input; ``mixers/sparse.py``, ``mixers/lightning.py``).
+the head's input; ``mixers/sparse.py``, ``mixers/lightning.py``); and
+Nemotron-H's (``nemotron_h``: every layer ONE norm, ONE sublayer and ONE
+residual, a Mamba-2 mixer, attention without positions or routed experts
+of two matrices under ``relu(.)^2`` by a published pattern:
+``cache_spec.one_sublayer``; a layer without a mixer or without an MLP
+skips that half here, nothing stands in for it; ``mixers/mamba2.py``).
 
 One block a kind, parameters stacked per kind::
 
     params["exit_gate"] = {w [d, 1], b [1]}            a looped model's
     params["layers"] = {
       "attn_norm", "mlp_norm": [L, d]                  every layer
+      "norm": [L, d]                                   in their place where
+                                                       a layer is ONE sublayer
       "attn_post_norm", "mlp_post_norm": [L, d]        ``sandwich_norm``
       <a mixer's stack>: its module's docstring, with what its family
                          keeps beside the stacks ([L, ..]: residual scales,
@@ -43,6 +50,8 @@ One block a kind, parameters stacked per kind::
                 (the sigmoid router's and the router MLP's),
                 we_gate we_up [Ls, E_held, d, fe], we_down [Ls, E_held, fe, d],
                 ws_gate ws_up [Ls, d, fs], ws_down [Ls, fs, d]}
+               (``mlp_hidden_act`` relu2: no we_gate and no ws_gate, an
+                expert is ``relu(x we_up)^2 we_down``)
                (with ``router_hidden_size`` R: router_down [Ls, d, R],
                 router_gamma [Ls] float32, router_norm [Ls, R],
                 router_w1 router_w2 [Ls, R, R], router [Ls, R, E_all])
@@ -62,7 +71,7 @@ from polyrl_tpu.models.blocks import (EXPERT_KEYS, _gather_slabs_kv, _head,
                                       _scatter_pages_kv, _scatter_slabs,
                                       experts_in_kernel, norm)
 from polyrl_tpu.models.mixers import MIXERS
-from polyrl_tpu.models.mixers.base import Chunk, Load, SlotRows, Step
+from polyrl_tpu.models.mixers.base import Chunk, Kept, Load, SlotRows, Step
 from polyrl_tpu.models.quant import mm
 
 # what a router's latent keeps of the layer before's (init_params)
@@ -75,19 +84,23 @@ ROUTER_GAMMA = 0.5
 def _counts(cfg) -> collections.Counter:
     """Layers a stack of mixer weights and a kind of MLP holds."""
     plan = cache_spec.layer_plan(cfg)
-    return collections.Counter([MIXERS[p.mixer].stack for p in plan]
-                               + [p.mlp for p in plan])
+    return collections.Counter(
+        [MIXERS[p.mixer].stack for p in plan if p.mixer]
+        + [p.mlp for p in plan if p.mlp])
 
 
 def kind_index(cfg) -> list[tuple[int, int]]:
     """For each layer: (its index among the layers of its mixer's stack,
-    its index among the layers of its MLP's kind)."""
+    its index among the layers of its MLP's kind); 0 for a half the
+    layer lacks."""
     seen: dict = {}
     out = []
     for p in cache_spec.layer_plan(cfg):
-        mixer = MIXERS[p.mixer].stack
+        mixer = MIXERS[p.mixer].stack if p.mixer else None
         i, j = seen.get(mixer, 0), seen.get(p.mlp, 0)
-        seen[mixer], seen[p.mlp] = i + 1, j + 1
+        for kind, at in ((mixer, i), (p.mlp, j)):
+            if kind is not None:
+                seen[kind] = at + 1
         out.append((i, j))
     return out
 
@@ -124,7 +137,8 @@ def init_params(rng: jax.Array, cfg) -> dict:
     d, L = cfg.hidden_size, cfg.num_layers
     draw = _Draw(rng, cfg)
     norm, ones = draw.normal, draw.ones
-    layers: dict = {"attn_norm": ones(L, d), "mlp_norm": ones(L, d)}
+    layers: dict = ({"norm": ones(L, d)} if cache_spec.one_sublayer(cfg)
+                    else {"attn_norm": ones(L, d), "mlp_norm": ones(L, d)})
     if cfg.sandwich_norm:
         layers.update(attn_post_norm=ones(L, d), mlp_post_norm=ones(L, d))
     for rec in MIXERS.values():
@@ -155,14 +169,17 @@ def init_params(rng: jax.Array, cfg) -> dict:
         if r or cfg.scoring_func == "sigmoid":
             router["router_bias"] = norm(s, cfg.num_experts,
                                          dtype=jnp.float32)
+        # ``relu2``: an expert is two matrices, no gate
+        gated = cfg.mlp_hidden_act != "relu2"
         layers["moe"] = {
             **router,
-            "we_gate": norm(s, held, d, fe), "we_up": norm(s, held, d, fe),
-            "we_down": norm(s, held, fe, d),
+            **({"we_gate": norm(s, held, d, fe)} if gated else {}),
+            "we_up": norm(s, held, d, fe), "we_down": norm(s, held, fe, d),
         }
         if fs:
-            layers["moe"].update(ws_gate=norm(s, d, fs), ws_up=norm(s, d, fs),
-                                 ws_down=norm(s, fs, d))
+            if gated:
+                layers["moe"]["ws_gate"] = norm(s, d, fs)
+            layers["moe"].update(ws_up=norm(s, d, fs), ws_down=norm(s, fs, d))
     params = {"embed": norm(cfg.vocab_size, d), "final_norm": ones(d),
               "layers": layers}
     if "attn_norm_bias" in layers:
@@ -255,16 +272,24 @@ def _post(cfg, layers: dict, name: str, out, l: int):
 
 def _layer_params(cfg, layers: dict, l: int) -> tuple[dict, dict]:
     """(mixer weights, MLP weights) of layer ``l``: slices of the stacks
-    of its kinds; the routed experts stay whole stacks (``moe_mm`` takes
-    the layer's index among the sparse layers)."""
+    of its kinds, None for a half the layer lacks; the routed experts stay
+    whole stacks (``moe_mm`` takes the layer's index among the sparse
+    layers)."""
     plan = cache_spec.layer_plan(cfg)[l]
     i, j = kind_index(cfg)[l]
-    mixer = jax.tree_util.tree_map(lambda a: a[i],
-                                   layers[MIXERS[plan.mixer].stack])
-    mlp = {k: v if k in EXPERT_KEYS
-           else jax.tree_util.tree_map(lambda a: a[j], v)
-           for k, v in layers[plan.mlp].items()}
+    mixer = plan.mixer and jax.tree_util.tree_map(
+        lambda a: a[i], layers[MIXERS[plan.mixer].stack])
+    mlp = plan.mlp and {k: v if k in EXPERT_KEYS
+                        else jax.tree_util.tree_map(lambda a: a[j], v)
+                        for k, v in layers[plan.mlp].items()}
     return mixer, mlp
+
+
+def _norm_names(cfg) -> tuple[str, str]:
+    """The norms before a layer's mixer and before its MLP: the one
+    ``norm`` of a layer of one sublayer."""
+    return (("norm", "norm") if cache_spec.one_sublayer(cfg)
+            else ("attn_norm", "mlp_norm"))
 
 
 def _res(layers, name: str, l: int):
@@ -288,7 +313,7 @@ def _mlp(cfg, x, layers, l, mlp_lp, valid, carry=None):
     with jax.named_scope("mlp"):
         with jax.named_scope("glue"):
             res = _res(layers, "mlp_res", l)
-            h = norm(layers, "mlp_norm", x, cfg.rms_norm_eps, l)
+            h = norm(layers, _norm_names(cfg)[1], x, cfg.rms_norm_eps, l)
         if plan.mlp == "dense":
             with jax.named_scope("mlp_dense"):
                 gate = jax.nn.silu(
@@ -413,21 +438,26 @@ def run_plan(params, cfg, x, positions, valid, states=None,
         def layer(x, hands, l=l, p=p, at_pages=at_pages, at_slot=at_slot):
             with jax.named_scope("glue"):
                 mixer_lp, mlp_lp = _layer_params(cfg, layers, l)
-                h_in = norm(layers, "attn_norm", x, cfg.rms_norm_eps, l)
-                st = states[at_slot] \
-                    if states is not None and at_slot is not None \
-                    else _zero_state(cfg, p, b, x.dtype)
-            pre = None if prefix is None or at_pages is None \
-                else prefix[at_pages]
-            out, kept = _form(p, "sequence")(
-                cfg, p, mixer_lp, h_in,
-                Chunk(positions, valid, st, pre, hands))
-            with jax.named_scope("glue"):
-                out = _post(cfg, layers, "attn_post_norm", out, l)
-                x = _residual(x, _branch(cfg, out),
-                              _res(layers, "attn_res", l))
-            x, _load, latent = _mlp(cfg, x, layers, l, mlp_lp, valid,
-                                    hands["latent"])
+            kept, latent = Kept(), hands["latent"]
+            if p.mixer is not None:
+                with jax.named_scope("glue"):
+                    h_in = norm(layers, _norm_names(cfg)[0], x,
+                                cfg.rms_norm_eps, l)
+                    st = states[at_slot] \
+                        if states is not None and at_slot is not None \
+                        else _zero_state(cfg, p, b, x.dtype)
+                pre = None if prefix is None or at_pages is None \
+                    else prefix[at_pages]
+                out, kept = _form(p, "sequence")(
+                    cfg, p, mixer_lp, h_in,
+                    Chunk(positions, valid, st, pre, hands))
+                with jax.named_scope("glue"):
+                    out = _post(cfg, layers, "attn_post_norm", out, l)
+                    x = _residual(x, _branch(cfg, out),
+                                  _res(layers, "attn_res", l))
+            if p.mlp is not None:
+                x, _load, latent = _mlp(cfg, x, layers, l, mlp_lp, valid,
+                                        latent)
             return (x, {**hands, **kept.hands, "latent": latent}, kept.pages,
                     kept.slot)
 
@@ -669,7 +699,8 @@ def step_counters(cfg, rows: int, one_chip: bool = True) -> tuple[str, ...]:
     take (``Mixer.kernel``), and the routed MLP's where its experts take
     their rows by table (``blocks.experts_in_kernel``; on a mesh of
     several chips they keep the tiled form)."""
-    kinds = dict.fromkeys(p.mixer for p in cache_spec.layer_plan(cfg))
+    kinds = dict.fromkeys(p.mixer for p in cache_spec.layer_plan(cfg)
+                          if p.mixer)
     routed = (("moe_gather_kernel_steps",)
               if one_chip and experts_in_kernel(cfg, rows) else ())
     return tuple(MIXERS[k].kernel[0] for k in kinds
@@ -717,7 +748,7 @@ def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
     index = cache_spec.pool_index(cfg)
     ctx = Step(positions, seq_lens, live, page_table, ps, write_page,
                write_off, attn_lens, n_live, rows_read, load, per={})
-    for kind in dict.fromkeys(p.mixer for p in plan):
+    for kind in dict.fromkeys(p.mixer for p in plan if p.mixer):
         if MIXERS[kind].per_step is not None:
             ctx.per[kind] = MIXERS[kind].per_step(cfg, ctx)
 
@@ -727,23 +758,28 @@ def paged_decode(params, cfg, tokens, positions, pools, page_table, seq_lens,
             at_pages, at_slot = index[l]
             with jax.named_scope("glue"):
                 mixer_lp, mlp_lp = _layer_params(cfg, layers, l)
-                h_in = norm(layers, "attn_norm", x, cfg.rms_norm_eps, l)
-            own = dataclasses.replace(
-                ctx, pages=None if at_pages is None else paged[at_pages],
-                slot=None if at_slot is None else state[at_slot],
-                stack=layers[MIXERS[p.mixer].stack],
-                index=kind_index(cfg)[l][0], hands=hands)
-            out, kept = _form(p, "step")(cfg, p, mixer_lp, h_in, own)
-            if kept.pages is not None:
-                paged[at_pages] = kept.pages
-            if kept.slot is not None:
-                state[at_slot] = kept.slot
-            with jax.named_scope("glue"):
-                out = _post(cfg, layers, "attn_post_norm", out, l)
-                x = _residual(x, _branch(cfg, out),
-                              _res(layers, "attn_res", l))
-            x, moe, latent = _mlp(cfg, x, layers, l, mlp_lp, active,
-                                  hands["latent"])
+            kept, moe, latent = Kept(), None, hands["latent"]
+            if p.mixer is not None:
+                with jax.named_scope("glue"):
+                    h_in = norm(layers, _norm_names(cfg)[0], x,
+                                cfg.rms_norm_eps, l)
+                own = dataclasses.replace(
+                    ctx, pages=None if at_pages is None else paged[at_pages],
+                    slot=None if at_slot is None else state[at_slot],
+                    stack=layers[MIXERS[p.mixer].stack],
+                    index=kind_index(cfg)[l][0], hands=hands)
+                out, kept = _form(p, "step")(cfg, p, mixer_lp, h_in, own)
+                if kept.pages is not None:
+                    paged[at_pages] = kept.pages
+                if kept.slot is not None:
+                    state[at_slot] = kept.slot
+                with jax.named_scope("glue"):
+                    out = _post(cfg, layers, "attn_post_norm", out, l)
+                    x = _residual(x, _branch(cfg, out),
+                                  _res(layers, "attn_res", l))
+            if p.mlp is not None:
+                x, moe, latent = _mlp(cfg, x, layers, l, mlp_lp, active,
+                                      latent)
             hands = {**hands, **kept.hands, "latent": latent}
             if moe is not None:
                 with jax.named_scope("glue"):

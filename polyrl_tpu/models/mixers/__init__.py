@@ -10,9 +10,11 @@ from polyrl_tpu.models.mixers.diff import CROSS, DIFF, SWA
 from polyrl_tpu.models.mixers.gqa import GQA, GQA_WINDOW
 from polyrl_tpu.models.mixers.kda import KDA
 from polyrl_tpu.models.mixers.lightning import LIGHTNING
+from polyrl_tpu.models.mixers.mamba2 import MAMBA2
 from polyrl_tpu.models.mixers.mla import MLA
 from polyrl_tpu.models.mixers.sparse import SPARSE
 from polyrl_tpu.models.mixers.ssm import GMU, SSM, SSM_MEM
 
 MIXERS = {m.name: m for m in (GQA, KDA, MLA, CCA, SSM, SSM_MEM, DIFF, SWA,
-                              CROSS, GMU, GQA_WINDOW, SPARSE, LIGHTNING)}
+                              CROSS, GMU, GQA_WINDOW, SPARSE, LIGHTNING,
+                              MAMBA2)}

@@ -69,8 +69,11 @@ def _init(kind: str):
 def rope(cfg, p, x, positions):
     """The layer's rope on ``x`` [..., T, H, D] float32 at ``positions``
     [..., T]: the first ``partial_rotary_factor`` of a head's columns
-    turned, the rest as they are (``base.rope_partial``)."""
+    turned, the rest as they are (``base.rope_partial``); ``x`` as it is
+    for attention WITHOUT positions (``attn_no_rope``: Nemotron-H's)."""
     r = cache_spec.gqa_rope(cfg, p)
+    if r is None:
+        return x
     rot = int(x.shape[-1] * r.partial_rotary_factor)
     return rope_partial(x, positions,
                         yarn_inv_freq(r.rope_theta, rot, r.scaling),

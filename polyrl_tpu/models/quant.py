@@ -138,13 +138,14 @@ def mm(x, w):
     return x @ w
 
 
-def moe_mm(x, ws: tuple, lay, layer: int | None = None):
+def moe_mm(x, ws: tuple, lay, layer: int | None = None, act: str = ""):
     """Grouped matmul of the MoE expert projections, the analogue of
     ``mm``: ``x`` [M, in] holds each expert's rows in whole tiles
     (``ops.grouped_matmul.tiled_layout``: ``lay``), and every row is
     multiplied with its own expert's matrix of ``ws[0]`` [E, in, out]
     alone; with two weights, gate and up, the result is SwiGLU's
-    ``silu(x @ gate) * (x @ up)`` (``ops.grouped_matmul.grouped_matmul``:
+    ``silu(x @ gate) * (x @ up)``, with one under ``act`` ``relu2``
+    ``relu(x @ up)^2`` (``ops.grouped_matmul.grouped_matmul``:
     a Pallas kernel on a TPU, ``jax.lax.ragged_dot`` elsewhere). A
     QuantWeight's int8 experts are read as int8 and cast in the kernel
     (exact); the per-expert, per-output-channel ``scale`` [E, out]
@@ -157,7 +158,7 @@ def moe_mm(x, ws: tuple, lay, layer: int | None = None):
     whole, as L*E groups of which only this layer's have rows; a group
     without rows is not read."""
     ws, scales, lay = _layer_groups(ws, lay, layer)
-    return grouped_matmul(x, ws, scales, lay)
+    return grouped_matmul(x, ws, scales, lay, act)
 
 
 def _layer_groups(ws: tuple, lay, layer: int | None):
@@ -180,6 +181,16 @@ def _layer_groups(ws: tuple, lay, layer: int | None):
     return ws, scales, lay
 
 
+def expert_in(experts: dict) -> tuple:
+    """The matrices an expert's hidden row is made from, and the
+    activation that makes it (``ops.grouped_matmul._activate``): SwiGLU's
+    gate and up, or ``we_up`` alone under ``relu2`` where the tree holds no
+    gate (an expert of two matrices)."""
+    if "we_gate" in experts:
+        return (experts["we_gate"], experts["we_up"]), ""
+    return (experts["we_up"],), "relu2"
+
+
 def moe_rows(x, experts: dict, tab, weight, tile: int,
              layer: int | None = None):
     """The routed experts of the tokens ``x`` [N, d] at a decode step's
@@ -187,11 +198,10 @@ def moe_rows(x, experts: dict, tab, weight, tile: int,
     ``we_gate`` and ``we_up`` and ``we_down`` over the rows ``tab`` names
     (``row_tables``), each times its ``weight`` and summed into its token;
     QuantWeights and ``layer`` as ``moe_mm`` takes them."""
-    ws, scales, tab = _layer_groups(
-        (experts["we_gate"], experts["we_up"], experts["we_down"]), tab,
-        layer)
-    return expert_rows(x, ws[:2], ws[2:], scales and scales[:2],
-                       scales and scales[2:], tab, weight, tile)
+    ws_in, act = expert_in(experts)
+    ws, scales, tab = _layer_groups((*ws_in, experts["we_down"]), tab, layer)
+    return expert_rows(x, ws[:-1], ws[-1:], scales and scales[:-1],
+                       scales and scales[-1:], tab, weight, tile, act)
 
 
 def unembed(x, head, eq: str):

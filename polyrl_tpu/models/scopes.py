@@ -34,6 +34,9 @@ LEAF_SCOPES = (
     # token completes, the scores against the pooled keys, the chosen
     # pages' table); linear attention's products and its state's step
     "sparse_select", "lightning_proj", "lightning_core",
+    # Mamba-2 (SSD): the in-projection, convolution, gated grouped norm and
+    # out-projection, and the state's step
+    "ssd_proj", "ssd_core",
     # inside ``mlp``: the router, the routed experts, the shared expert,
     # and the gate, up and down products of a dense MLP
     "moe_route", "moe_experts", "moe_shared", "mlp_dense",

@@ -116,6 +116,10 @@ emission or iteration, never once a token, all on this profiler's clock:
   linear-attention layers (a float32 state read and written each;
   ``mixers/lightning.py``), and the steps whose program updated those
   states in the one-pass kernel (``ops/lightning_state.py``);
+  ``ssd_state_rows`` / ``ssd_kernel_steps`` — live rows times Mamba-2
+  layers (a float32 state of 2 MiB read and written each at the published
+  sizes; ``mixers/mamba2.py``), and the steps whose program updated those
+  states in the one-pass kernel (``ops/ssd_state.py``);
   ``sparse_kernel_steps`` — the steps whose program chose its sparse
   layers' blocks in the kernel that walks a row's own pooled pages
   (``ops/sparse_select.py``);
@@ -212,6 +216,7 @@ CUMULATIVE_KEYS = (
     "kv_pass_rows_read",
     "sparse_pages_read", "sparse_pooled_scored", "sparse_dense_rows",
     "lightning_state_rows", "lightning_kernel_steps", "sparse_kernel_steps",
+    "ssd_state_rows", "ssd_kernel_steps",
     "device_busy_s", "loop_wall_s", "loop_host_s",
     *PHASE_KEYS.values(), "emit_wait_s", "dispatches_emitted",
     "landing_gap_hist", "stalls", "programs_built", "build_s")

@@ -23,7 +23,13 @@ then consecutive tiles of one group reuse the block already in VMEM.
 Larger ones are cut into slabs of about 4 MiB, and a group of several
 tiles then reads its weights once a TILE: decode has one tile a group
 (``row_tile`` is twice the mean); tiles of 256 rows are bound by the MXU
-either way (256 FLOPs a weight byte against the chip's 240). Why slabs
+either way (256 FLOPs a weight byte against the chip's 240). A weight
+whose columns are no whole number of lane tiles (Nemotron-3-Nano's 2688 x
+1856: 14.5) lies on the chip as its transpose, the rows minor (the device's
+own layout for the shape): it is read where it lies, as ``[N, K]`` rows,
+whole, and multiplied from the right (``_as_rows``); as ``[K, N]`` the
+program copies the whole stack before every dispatch (3.5 GB at 23 layers
+of 16 experts). Why slabs
 and not column blocks: a block [K, tn] of a row-major matrix is K strided
 runs, which at dots.vlm1's widths (runs of 512 B) read 86% of 819 GB/s
 where a slab reads 92%, this chip's ceiling (PERF.md section 6, PR 45).
@@ -56,6 +62,17 @@ _SLAB_BYTES = 4 * 2**20
 # a tile's float32 sums, the rows' and the output's blocks take up to 29
 # MiB at the shapes the cells run (dots.vlm1's down in tiles of 256 rows)
 _VMEM_LIMIT_BYTES = 40 * 2**20
+_LANES = 128
+
+
+def _as_rows(ws: tuple) -> tuple[tuple, bool]:
+    """(the weights as the kernels read them, whether as ``[G, N, K]``
+    rows): a stack ``[G, K, N]`` whose ``N`` is no whole number of lane
+    tiles as its transpose, which is where the chip holds it (module
+    docstring), so that the view costs nothing there."""
+    if ws[0].shape[-1] % _LANES == 0:
+        return ws, False
+    return tuple(jnp.swapaxes(w, 1, 2) for w in ws), True
 
 
 def row_tile(n_rows: int, n_groups: int) -> int:
@@ -126,9 +143,10 @@ def _slab_plan(k: int, n: int, itemsize: int, n_w: int) -> int:
     VMEM. A slab is ``tk`` whole rows of each weight, one contiguous run
     in HBM: all ``k`` where the step's weights fit ``_WHOLE_BYTES`` (or k
     has no multiple of 128 to cut at), else the most rows, a multiple of
-    128 that divides k, that fit ``_SLAB_BYTES``."""
+    128 that divides k, that fit ``_SLAB_BYTES``. All ``k`` too where ``n``
+    is no whole number of lane tiles (``_as_rows``)."""
     row_bytes = n * itemsize * n_w
-    if k * row_bytes <= _WHOLE_BYTES or k % 128:
+    if k * row_bytes <= _WHOLE_BYTES or k % 128 or n % _LANES:
         return k
     tk = max(128, _SLAB_BYTES // row_bytes // 128 * 128)
     while k % tk:
@@ -136,25 +154,36 @@ def _slab_plan(k: int, n: int, itemsize: int, n_w: int) -> int:
     return tk
 
 
+def _activate(ys: list, act: str):
+    """What a tile's float32 products ``ys`` (one a weight) become before
+    the ONE rounding: SwiGLU of two weights (``silu(x @ w0) * (x @ w1)``),
+    one weight's product as it is, or with ``act`` ``relu2`` its
+    ``relu(x @ w0)^2`` (an expert of two matrices: no gate)."""
+    if act == "relu2":
+        return jnp.square(jnp.maximum(ys[0], 0.0))
+    if act:
+        raise ValueError(f"activation {act!r} of a grouped matmul")
+    return ys[0] if len(ys) == 1 else jax.nn.silu(ys[0]) * ys[1]
+
+
 def _kernel(tile_group_ref, tiles_used_ref, x_ref, *refs, n_w, scaled,
-            n_slabs):
+            n_slabs, act="", rows=False):
     """One slab of one used tile: the tile's columns of that slab times
     the slab of each weight, summed over the slabs in float32; after the
-    last, the scales, SwiGLU of two weights (``silu(x @ w0) * (x @ w1)``)
-    in float32 and the ONE rounding."""
+    last, the scales, the activation (``_activate``) in float32 and the
+    ONE rounding. ``rows``: a weight's block is ``[N, K]`` (``_as_rows``)."""
     del tile_group_ref, tiles_used_ref
     ws, rest = refs[:n_w], refs[n_w:]
     scales, rest = (rest[:n_w], rest[n_w:]) if scaled else ((), rest)
     o_ref, accs = rest[0], rest[1:]
     x = x_ref[...]
     ys = [jax.lax.dot_general(
-        x, w[...].astype(x.dtype), (((1,), (0,)), ((), ())),
+        x, w[...].astype(x.dtype), (((1,), (1 if rows else 0,)), ((), ())),
         preferred_element_type=jnp.float32) for w in ws]
 
     def finish(ys):
         ys = [y * s[...] for y, s in zip(ys, scales)] or ys
-        y = ys[0] if n_w == 1 else jax.nn.silu(ys[0]) * ys[1]
-        o_ref[...] = y.astype(o_ref.dtype)
+        o_ref[...] = _activate(ys, act).astype(o_ref.dtype)
 
     if n_slabs == 1:
         finish(ys)
@@ -176,15 +205,17 @@ def _kernel(tile_group_ref, tiles_used_ref, x_ref, *refs, n_w, scaled,
         finish([acc[...] for acc in accs])
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret", "slab"))
+@functools.partial(jax.jit, static_argnames=("tile", "interpret", "slab",
+                                             "act"))
 def grouped_matmul_pallas(x, ws, tile_group, tiles_used, scales=None, *,
                           tile: int, interpret: bool = False,
-                          slab: int | None = None):
+                          slab: int | None = None, act: str = ""):
     """``x`` [T*tile, K] in the tiled layout times ``ws[0]`` [G, K, N]
     (any dtype: a slab is cast to x's in VMEM, so int8 weights are read as
     int8), tile j with group ``tile_group[j]``'s matrix; with two weights
     the result is ``silu(x @ ws[0]) * (x @ ws[1])``, one pass over x and
-    half the grid steps a weight byte. ``scales`` (one [G, N] float32 a
+    half the grid steps a weight byte; one weight under ``act`` ``relu2``
+    gives ``relu(x @ ws[0])^2``. ``scales`` (one [G, N] float32 a
     weight, or None) multiply a tile's product by its group's row. The
     grid ends at ``tiles_used``: the tiles past it are not visited, fetch
     nothing, and their rows of the result are undefined. ``slab`` is
@@ -195,17 +226,16 @@ def grouped_matmul_pallas(x, ws, tile_group, tiles_used, scales=None, *,
     n_w = len(ws)
     tk = slab or _slab_plan(k, n, ws[0].dtype.itemsize, n_w)
     n_slabs = k // tk
-    w_spec = pl.BlockSpec((None, tk, n), lambda j, s, tg, used: (tg[j], s, 0))
-    s_spec = pl.BlockSpec((None, 1, n), lambda j, s, tg, used: (tg[j], 0, 0))
-    scales = [s.astype(jnp.float32).reshape(g, 1, n) for s in scales or ()]
+    ws, rows = _as_rows(ws)
+    w_specs, scales = _weight_specs(g, n, tk, n_w, scales, rows)
     return pl.pallas_call(
         functools.partial(_kernel, n_w=n_w, scaled=bool(scales),
-                          n_slabs=n_slabs),
+                          n_slabs=n_slabs, act=act, rows=rows),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(tiles_used[0], n_slabs),
             in_specs=[pl.BlockSpec((tile, tk), lambda j, s, tg, used: (j, s))]
-            + [w_spec] * n_w + [s_spec] * len(scales),
+            + w_specs,
             out_specs=pl.BlockSpec((tile, n), lambda j, s, tg, used: (j, 0)),
             # a tile's float32 sums over its slabs, one a weight
             scratch_shapes=[pltpu.VMEM((tile, n), jnp.float32)]
@@ -217,7 +247,7 @@ def grouped_matmul_pallas(x, ws, tile_group, tiles_used, scales=None, *,
     )(tile_group, tiles_used, x, *ws, *scales)
 
 
-def _ragged(x, ws, scales, lay: TiledLayout):
+def _ragged(x, ws, scales, lay: TiledLayout, act: str = ""):
     """The same result by ``jax.lax.ragged_dot`` over the padded groups
     (pad rows are zero rows of ``x``); rows past the last group are zero."""
     ys = [jax.lax.ragged_dot(x, w.astype(x.dtype), lay.padded_sizes)
@@ -225,33 +255,33 @@ def _ragged(x, ws, scales, lay: TiledLayout):
     if scales is not None:
         rows = jnp.repeat(lay.tile_group, lay.tile)
         ys = [y.astype(jnp.float32) * s[rows] for y, s in zip(ys, scales)]
-    if len(ws) == 2:
-        ys = [jax.nn.silu(ys[0].astype(jnp.float32))
-              * ys[1].astype(jnp.float32)]
+    if len(ws) == 2 or act:
+        ys = [_activate([y.astype(jnp.float32) for y in ys], act)]
     live = jnp.arange(x.shape[0]) < jnp.sum(lay.padded_sizes)
     return jnp.where(live[:, None], ys[0].astype(x.dtype), 0)
 
 
-@jax.custom_vjp
-def grouped_matmul(x, ws, scales, lay: TiledLayout):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def grouped_matmul(x, ws, scales, lay: TiledLayout, act: str = ""):
     """``x`` [T*tile, K] in the layout ``lay`` times the stacked matrices
-    ``ws`` (a tuple of one [G, K, N], or of SwiGLU's gate and up;
-    ``scales`` as ``grouped_matmul_pallas`` takes them): the Pallas kernel
-    on a TPU (it lowers or raises), ``ragged_dot`` elsewhere. Rows of tiles
-    that hold no row are undefined. Differentiable in ``x`` and ``ws``."""
+    ``ws`` (a tuple of one [G, K, N], or of SwiGLU's gate and up, or of one
+    under ``act`` ``relu2``; ``scales`` as ``grouped_matmul_pallas`` takes
+    them): the Pallas kernel on a TPU (it lowers or raises), ``ragged_dot``
+    elsewhere. Rows of tiles that hold no row are undefined.
+    Differentiable in ``x`` and ``ws``."""
     if jax.default_backend() == "tpu":
         return grouped_matmul_pallas(x, ws, lay.tile_group, lay.tiles_used,
-                                     scales, tile=lay.tile)
-    return _ragged(x, ws, scales, lay)
+                                     scales, tile=lay.tile, act=act)
+    return _ragged(x, ws, scales, lay, act)
 
 
-def _fwd(x, ws, scales, lay):
-    return grouped_matmul(x, ws, scales, lay), (x, ws, scales, lay)
+def _fwd(x, ws, scales, lay, act):
+    return grouped_matmul(x, ws, scales, lay, act), (x, ws, scales, lay)
 
 
-def _bwd(res, dy):
+def _bwd(act, res, dy):
     x, ws, scales, lay = res
-    _y, vjp = jax.vjp(lambda x, ws: _ragged(x, ws, scales, lay), x, ws)
+    _y, vjp = jax.vjp(lambda x, ws: _ragged(x, ws, scales, lay, act), x, ws)
     dx, dws = vjp(dy)
     return dx, dws, None, None
 
@@ -336,7 +366,8 @@ def row_tables(sizes: jnp.ndarray, token_of: jnp.ndarray, tile: int,
 
 
 def _gather_kernel(tile_group_ref, tiles_run_ref, start_ref, count_ref,
-                   token_ref, x_ref, *refs, n_w, scaled, n_slabs):
+                   token_ref, x_ref, *refs, n_w, scaled, n_slabs, act="",
+                   rows=False):
     """``_kernel`` on a tile whose rows it takes from the tokens itself:
     before a tile's first slab, row r of the tile is token
     ``token_of[start + r]``'s for r under the tile's count, copied from
@@ -365,11 +396,12 @@ def _gather_kernel(tile_group_ref, tiles_run_ref, start_ref, count_ref,
             rows_ref[i] = rows[:, i * tk:(i + 1) * tk]
 
     _kernel(tile_group_ref, tiles_run_ref, rows_ref.at[s], *refs[:-3],
-            n_w=n_w, scaled=scaled, n_slabs=n_slabs)
+            n_w=n_w, scaled=scaled, n_slabs=n_slabs, act=act, rows=rows)
 
 
 def _scatter_kernel(tile_group_ref, tiles_run_ref, start_ref, count_ref,
-                    token_ref, weight_ref, h_ref, *refs, scaled, n_slabs):
+                    token_ref, weight_ref, h_ref, *refs, scaled, n_slabs,
+                    rows=False):
     """``_kernel`` on a tile of ``hidden`` whose products it hands to
     their tokens itself: after a tile's last slab the products, rounded
     once as the tiled form rounds them, are added row by live row, each
@@ -384,7 +416,8 @@ def _scatter_kernel(tile_group_ref, tiles_run_ref, start_ref, count_ref,
         o_ref[...] = jnp.zeros_like(o_ref)
 
     _kernel(tile_group_ref, tiles_run_ref, h_ref, *refs[:1 + scaled], y_ref,
-            *refs[2 + scaled:-2], n_w=1, scaled=scaled, n_slabs=n_slabs)
+            *refs[2 + scaled:-2], n_w=1, scaled=scaled, n_slabs=n_slabs,
+            rows=rows)
 
     @pl.when(s == n_slabs - 1)
     def _add():
@@ -416,18 +449,23 @@ def _tables_call(kernel, tab: RowTables, prefetch, operands, in_specs,
     )(*prefetch, *operands)
 
 
-def _weight_specs(g, n, tk, n_w, scales):
+def _weight_specs(g, n, tk, n_w, scales, rows=False):
     """The slabs' and the scales' blocks, chosen by a tile's group, and
-    the scales as the kernels take them."""
-    w_spec = pl.BlockSpec((None, tk, n), lambda j, s, tg, *_: (tg[j], s, 0))
+    the scales as the kernels take them. ``rows``: the weights come as
+    ``[G, N, K]`` (``_as_rows``), whole."""
+    w_spec = (pl.BlockSpec((None, n, tk), lambda j, s, tg, *_: (tg[j], 0, s))
+              if rows else
+              pl.BlockSpec((None, tk, n), lambda j, s, tg, *_: (tg[j], s, 0)))
     s_spec = pl.BlockSpec((None, 1, n), lambda j, s, tg, *_: (tg[j], 0, 0))
     scales = [s.astype(jnp.float32).reshape(g, 1, n) for s in scales or ()]
     return [w_spec] * n_w + [s_spec] * len(scales), scales
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret", "slab"))
+@functools.partial(jax.jit, static_argnames=("tile", "interpret", "slab",
+                                             "act"))
 def gather_matmul_pallas(x, ws, tab: RowTables, scales=None, *, tile: int,
-                         interpret: bool = False, slab: int | None = None):
+                         interpret: bool = False, slab: int | None = None,
+                         act: str = ""):
     """``grouped_matmul_pallas`` of the tokens ``x`` [n, K] themselves:
     the result [T*tile, N] in the tiled layout, tile j's rows the tokens
     ``tab`` names (zero rows past its count). ``x`` is one block, fetched
@@ -437,13 +475,14 @@ def gather_matmul_pallas(x, ws, tab: RowTables, scales=None, *, tile: int,
     n_w = len(ws)
     tk = slab or _slab_plan(k, n, ws[0].dtype.itemsize, n_w)
     n_slabs = k // tk
-    w_specs, scales = _weight_specs(g, n, tk, n_w, scales)
+    ws, rows = _as_rows(ws)
+    w_specs, scales = _weight_specs(g, n, tk, n_w, scales, rows)
     prefetch = (tab.tile_group, tab.tiles_run, tab.tile_start,
                 tab.tile_count, tab.token_of)
     f32 = jnp.float32
     return _tables_call(
         functools.partial(_gather_kernel, n_w=n_w, scaled=bool(scales),
-                          n_slabs=n_slabs),
+                          n_slabs=n_slabs, act=act, rows=rows),
         tab, prefetch, (x, *ws, *scales),
         [pl.BlockSpec((n_tok, k), lambda j, s, *_: (0, 0))] + w_specs,
         jax.ShapeDtypeStruct((tab.tile_group.shape[0] * tile, n), x.dtype),
@@ -470,13 +509,14 @@ def matmul_scatter_pallas(hidden, ws, tab: RowTables, weights, scales=None,
     g, _k, n = ws[0].shape
     tk = slab or _slab_plan(k, n, ws[0].dtype.itemsize, 1)
     n_slabs = k // tk
-    w_specs, scales = _weight_specs(g, n, tk, 1, scales)
+    ws, rows = _as_rows(ws)
+    w_specs, scales = _weight_specs(g, n, tk, 1, scales, rows)
     prefetch = (tab.tile_group, tab.tiles_run, tab.tile_start,
                 tab.tile_count, tab.token_of, weights.astype(jnp.float32))
     f32 = jnp.float32
     return _tables_call(
         functools.partial(_scatter_kernel, scaled=bool(scales),
-                          n_slabs=n_slabs),
+                          n_slabs=n_slabs, rows=rows),
         tab, prefetch, (hidden, *ws, *scales),
         [pl.BlockSpec((tile, tk), lambda j, s, *_: (j, s))] + w_specs,
         jax.ShapeDtypeStruct((n_tokens, n), f32),
@@ -488,7 +528,7 @@ def matmul_scatter_pallas(hidden, ws, tab: RowTables, weights, scales=None,
 
 
 def _rows_plain(x, ws_in, ws_out, scales_in, scales_out, tab: RowTables,
-                weights, tile: int):
+                weights, tile: int, act: str = ""):
     """What the two kernels compute, in XLA's operations: the tokens
     gathered into the tiled layout, ``_ragged`` twice, each live row times
     its weight summed into its token. The gradient's form."""
@@ -500,17 +540,19 @@ def _rows_plain(x, ws_in, ws_out, scales_in, scales_out, tab: RowTables,
     lay = TiledLayout(tab.tile_group, tab.tiles_run, tab.padded_sizes, src,
                       live, None)
     xs = jnp.where(live[:, None], x[token], 0)
-    ys = _ragged(_ragged(xs, ws_in, scales_in, lay), ws_out, scales_out, lay)
+    ys = _ragged(_ragged(xs, ws_in, scales_in, lay, act), ws_out,
+                 scales_out, lay)
     w = weights.astype(jnp.float32)[src]
     ys = jnp.where(live[:, None], ys.astype(jnp.float32) * w[:, None], 0.0)
     return jnp.zeros((x.shape[0], ys.shape[1]), jnp.float32).at[token].add(ys)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
 def expert_rows(x, ws_in, ws_out, scales_in, scales_out, tab: RowTables,
-                weights, tile: int):
+                weights, tile: int, act: str = ""):
     """Each token's weighted sum over its choices' experts, [n, N] float32:
-    ``x`` [n, K] through SwiGLU of ``ws_in`` (gate, up: [G, K, F]) and
+    ``x`` [n, K] through SwiGLU of ``ws_in`` (gate, up: [G, K, F]; or
+    (up,) alone under ``act`` ``relu2``: ``relu(x up)^2``) and
     ``ws_out`` ((down,): [G, F, N]), ``scales_*`` as
     ``grouped_matmul_pallas`` takes them, the choices and their tiles in
     ``tab`` (``row_tables``), ``weights`` [M] float32 a sorted row. The two
@@ -520,23 +562,24 @@ def expert_rows(x, ws_in, ws_out, scales_in, scales_out, tab: RowTables,
     cotangents are XLA's over ``_rows_plain``."""
     interpret = jax.default_backend() != "tpu"
     hidden = gather_matmul_pallas(x, ws_in, tab, scales_in, tile=tile,
-                                  interpret=interpret)
+                                  interpret=interpret, act=act)
     return matmul_scatter_pallas(hidden, ws_out, tab, weights, scales_out,
                                  n_tokens=x.shape[0], tile=tile,
                                  interpret=interpret)
 
 
-def _rows_fwd(x, ws_in, ws_out, scales_in, scales_out, tab, weights, tile):
+def _rows_fwd(x, ws_in, ws_out, scales_in, scales_out, tab, weights, tile,
+              act):
     return (expert_rows(x, ws_in, ws_out, scales_in, scales_out, tab, weights,
-                        tile),
+                        tile, act),
             (x, ws_in, ws_out, scales_in, scales_out, tab, weights))
 
 
-def _rows_bwd(tile, res, dy):
+def _rows_bwd(tile, act, res, dy):
     x, ws_in, ws_out, scales_in, scales_out, tab, weights = res
     _y, vjp = jax.vjp(
         lambda x, ws_in, ws_out, weights: _rows_plain(
-            x, ws_in, ws_out, scales_in, scales_out, tab, weights, tile),
+            x, ws_in, ws_out, scales_in, scales_out, tab, weights, tile, act),
         x, ws_in, ws_out, weights)
     dx, dws_in, dws_out, dweights = vjp(dy)
     return dx, dws_in, dws_out, None, None, None, dweights

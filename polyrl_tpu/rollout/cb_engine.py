@@ -442,6 +442,11 @@ class CBEngine:
         self.steps_per_dispatch = max(1, int(o.steps_per_dispatch))
         self.prefill_chunk = int(o.prefill_chunk)
         self.prefill_first = bool(o.prefill_first)
+        self.prefix_pages_floor = int(o.prefix_pages_floor)
+        if not 1 <= self.prefix_pages_floor <= self.pages_per_slot:
+            raise ValueError(
+                f"prefix_pages_floor {self.prefix_pages_floor}: a slot has "
+                f"{self.pages_per_slot} pages")
         self._chunk_jobs: collections.deque = collections.deque()
         # prompt-lookup speculative decoding: each decode dispatch runs
         # spec_rounds fused speculation rounds; every round proposes
@@ -1365,7 +1370,7 @@ class CBEngine:
         page_ids = np.zeros((pb // self.page_size,), np.int32)
         page_ids[:n_sfx_pages] = sfx_pages[:n_sfx_pages]
         if n_pre_b is None:
-            n_pre_b = 1
+            n_pre_b = self.prefix_pages_floor
             while n_pre_b < len(prefix_pages):
                 n_pre_b *= 2
         prefix_ids = np.zeros((n_pre_b,), np.int32)

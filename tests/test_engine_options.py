@@ -21,7 +21,7 @@ NON_DEFAULT = {
     "max_slots": 8, "page_size": 128, "max_seq_len": 4096, "num_pages": 77,
     "prompt_buckets": (256, 512), "steps_per_dispatch": 4,
     "pipeline_depth": 2, "prefill_chunk": 128, "prefill_first": True,
-    "spec_tokens": 2,
+    "prefix_pages_floor": 4, "spec_tokens": 2,
     "spec_rounds": 3, "salvage_partials": False, "admit_wave": 3,
     "admit_reorder_window": 0, "group_share": False,
     "decode_group_share": False, "group_preref_ttl_s": 5.5,

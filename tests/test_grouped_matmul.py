@@ -470,3 +470,144 @@ def test_the_form_follows_the_shapes(call, monkeypatch):
         else:           # hidden's block twice, the result twice, the products
             need += 2 * tile * tk * 2 + 2 * n * d * 4 + tile * d * 6
         assert need <= gm._VMEM_LIMIT_BYTES - 8 * 2**20
+
+
+# --- an expert of two matrices: ``relu(x up)^2 down`` (``act="relu2"``) ---
+
+def _plain_relu2(x, up, down, choice, top_p, first):
+    """The plain float32 form of the ungated expert, token by token."""
+    held = up.shape[0]
+    x, up, down = (np.asarray(a, np.float32) for a in (x, up, down))
+    out = np.zeros((x.shape[0], down.shape[-1]), np.float32)
+    for t, (experts, weights) in enumerate(zip(choice, top_p)):
+        for e, w in zip(experts - first, weights):
+            if 0 <= e < held:
+                out[t] += w * (np.maximum(x[t] @ up[e], 0.0) ** 2 @ down[e])
+    return out
+
+
+@pytest.mark.parametrize("k,n,slab", [(32, 256, None), (512, 256, 128),
+                                      (384, 232, None)],
+                         ids=["one slab", "four slabs",
+                              "an odd width, read as rows"])
+def test_one_weight_under_relu2_is_the_squared_relu(k, n, slab):
+    """Width 232 is 1.8 lane tiles, as 1856 is 14.5."""
+    sizes, lay, rows, x, w = _case([3, 0, 17, 4], 24, 8, k=k, n=n)
+    used = int(lay.tiles_used[0]) * lay.tile
+    got = gm.grouped_matmul_pallas(x, (w,), lay.tile_group, lay.tiles_used,
+                                   tile=lay.tile, interpret=True, slab=slab,
+                                   act="relu2")
+    tol = 1e-5 * k
+    np.testing.assert_allclose(
+        got[:used], gm._ragged(x, (w,), None, lay, "relu2")[:used],
+        rtol=1e-5, atol=tol)
+    at = int(lay.shift[2]) + 3
+    np.testing.assert_allclose(
+        got[at:at + 17], jnp.maximum(rows[3:20] @ w[2], 0.0) ** 2,
+        rtol=1e-5, atol=tol)
+    with pytest.raises(ValueError, match="gelu"):
+        gm._ragged(x, (w,), None, lay, "gelu")
+
+
+RELU2_CASES = {
+    # (tokens, d, f, choices, routed, held, first, slabs of up and of down)
+    "nemotron: 4 of 32 at an odd width": (9, 256, 232, 3, 32, 4, 0,
+                                          (None, None)),
+    "a share that starts at expert 4": (17, 384, 232, 3, 16, 4, 4,
+                                        (None, None)),
+}
+
+
+@pytest.mark.parametrize("case", RELU2_CASES)
+def test_rows_by_table_under_relu2_are_the_plain_float32_form(case):
+    n, d, f, k, routed, held, first, slabs = RELU2_CASES[case]
+    token_of, weight, sizes, choice, top_p = _routed(n, k, routed, held,
+                                                     first)
+    x = jax.random.normal(jax.random.PRNGKey(7), (n, d))
+    _gate, up, down = _weights(d, f, held)
+    tile = gm.row_tile(token_of.shape[0], held)
+    tab = gm.row_tables(sizes[first:first + held], token_of, tile,
+                        int(jnp.sum(sizes[:first])))
+    hidden = gm.gather_matmul_pallas(x, (up,), tab, None, tile=tile,
+                                     interpret=True, slab=slabs[0],
+                                     act="relu2")
+    got = gm.matmul_scatter_pallas(hidden, (down,), tab, weight, None,
+                                   n_tokens=n, tile=tile, interpret=True,
+                                   slab=slabs[1])
+    want = _plain_relu2(x, up, down, choice, top_p, first)
+    # sums of 232 squares reach 20: float32's ulps of that
+    assert np.abs(want).max() > 1e-3
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=5e-5)
+    # the dispatcher's own form off a TPU, and the gradient's
+    both = gm.expert_rows(x, (up,), (down,), None, None, tab, weight, tile,
+                          "relu2")
+    np.testing.assert_allclose(both, want, rtol=1e-5, atol=5e-5)
+    np.testing.assert_allclose(
+        gm._rows_plain(x, (up,), (down,), None, None, tab, weight, tile,
+                       "relu2"), want, rtol=1e-5, atol=5e-5)
+
+
+def test_relu2_gradients_of_both_forms_are_jax_grads_of_the_plain_form():
+    """``jax.grad`` of ``_expert_rows`` (the kernels' custom VJP) and of
+    ``_expert_mix`` (``grouped_matmul``'s) for an expert of two matrices at
+    an odd width, against ``jax.grad`` of the plain form written out."""
+    from polyrl_tpu.models import blocks
+
+    n, d, f, k, held = 9, 64, 29, 2, 4
+    token_of, weight, sizes, choice, top_p = _routed(n, k, held, held)
+    x = jax.random.normal(jax.random.PRNGKey(11), (n, d))
+    _gate, up, down = _weights(d, f, held)
+    experts = {"we_up": up, "we_down": down}
+    flat = jnp.asarray(choice.reshape(-1))
+    order = jnp.argsort(flat, stable=True)
+    place = jnp.argsort(order)
+
+    def rows(x, experts, top_p):
+        return blocks._expert_rows(x, experts, None, token_of,
+                                   top_p.reshape(-1)[order], sizes)
+
+    def tiled(x, experts, top_p):
+        return blocks._expert_mix(x, experts, None, token_of, place, flat,
+                                  top_p, sizes)
+
+    def plain(x, experts, top_p):
+        hidden = jnp.maximum(jnp.einsum(
+            "nd,nkdf->nkf", x, experts["we_up"][jnp.asarray(choice)]),
+            0.0) ** 2
+        return jnp.einsum("nkf,nkfd,nk->nd", hidden,
+                          experts["we_down"][jnp.asarray(choice)], top_p)
+
+    def grads(fn):
+        return jax.grad(lambda *a: jnp.sum(jnp.sin(fn(*a))),
+                        argnums=(0, 1, 2))(x, experts, jnp.asarray(top_p))
+
+    want = grads(plain)
+    for fn in (rows, tiled):
+        np.testing.assert_allclose(fn(x, experts, jnp.asarray(top_p)),
+                                   plain(x, experts, jnp.asarray(top_p)),
+                                   rtol=1e-5, atol=1e-6)
+        for a, b in zip(jax.tree_util.tree_leaves(grads(fn)),
+                        jax.tree_util.tree_leaves(want)):
+            assert np.abs(np.asarray(b)).max() > 0
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+def test_slab_plan_at_nemotrons_widths():
+    """2688 -> 1856: 9.98 MB a matrix passes the one-slab rule, but 1856
+    columns are 14.5 lane tiles: the chip holds the matrix as its transpose
+    and it is read as ``[1856, 2688]`` rows, whole (``_as_rows``); 1856 ->
+    2688: 1856 rows cannot be cut at a lane tile either, so that matrix
+    goes in whole too, two of them in flight under the kernels' VMEM
+    limit."""
+    assert gm._slab_plan(2688, 1856, 2, 1) == 2688
+    assert gm._slab_plan(1856, 2688, 2, 1) == 1856
+    w = jnp.zeros((3, 2688, 1856), jnp.bfloat16)
+    assert gm._as_rows((w,))[1] and gm._as_rows((w,))[0][0].shape == (
+        3, 1856, 2688)
+    assert not gm._as_rows((jnp.swapaxes(w, 1, 2),))[1]
+    tile = gm.row_tile(64 * 6, 16)
+    assert tile == 64
+    whole = 1856 * 2688 * 2
+    need = (2 * whole + tile * 2688 * 4 + 2 * tile * 1856 * 2
+            + 2 * 65 * 2688 * 4 + tile * 2688 * (2 + 4))
+    assert need <= gm._VMEM_LIMIT_BYTES - 8 * 2**20

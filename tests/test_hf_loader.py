@@ -290,3 +290,57 @@ def test_minicpm_sala_config_is_the_published_preset(tmp_path):
     with pytest.raises(NotImplementedError,
                        match="no key map for a minicpm_sala"):
         hf_loader.load_hf_params(str(tmp_path))
+
+
+# -- the nemotron_h family (layers of one sublayer) ---------------------------
+
+
+def test_nemotron_h_config_is_the_published_preset(tmp_path):
+    """``nemotron_h_config`` on the benchmark's file (the catalog's keys
+    with one chip's share of eight: 16 experts held, an eighth of the
+    vocabulary) gives the ``nemotron-3-nano-30b-a3b-share8`` preset, on the
+    published keys alone the whole model; the file holds every number of
+    the catalog's row but the two it lists as ``reduced``; a checkpoint's
+    config.json is read, its tensors are refused by name until a key map
+    exists."""
+    import json
+    import os
+
+    from polyrl_tpu.models import hf_loader
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "nemotron-3-nano-30b-a3b.json")) as f:
+        hf = json.load(f)
+    assert hf["model_type"] == "nemotron_h"
+    assert hf["reduced"] == ["n_routed_experts", "vocab_size"]
+    assert hf_loader.nemotron_h_config(hf) == decoder.get_config(
+        "nemotron-3-nano-30b-a3b-share8")
+    whole = {**hf, **hf["published"]}
+    for key in ("published", "experts_held"):
+        del whole[key]
+    assert hf_loader.nemotron_h_config(whole) == decoder.get_config(
+        "nemotron-3-nano-30b-a3b")
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if os.path.exists(catalog):
+        with open(catalog) as f:
+            row = next(r for r in map(json.loads, f)
+                       if r["name"] == "NVIDIA-Nemotron-3-Nano-30B-A3B-BF16")
+        assert hf["source"] == row["source_url"]
+        assert {k for k, v in row["config"].items() if hf[k] != v} \
+            == set(hf["reduced"])
+        assert {k: row["config"][k] for k in hf["reduced"]} == hf["published"]
+    with pytest.raises(NotImplementedError, match=r"characters \['-'\]"):
+        hf_loader.nemotron_h_config(
+            {**hf, "hybrid_override_pattern": "-" + hf[
+                "hybrid_override_pattern"][1:]})
+    with pytest.raises(NotImplementedError, match="relu2"):
+        hf_loader.nemotron_h_config({**hf, "mlp_hidden_act": "silu"})
+    with pytest.raises(NotImplementedError, match="mamba_proj_bias"):
+        hf_loader.nemotron_h_config({**hf, "mamba_proj_bias": True})
+    (tmp_path / "config.json").write_text(json.dumps(hf))
+    cfg = hf_loader.config_from_hf(str(tmp_path))
+    assert cfg.hybrid_override_pattern and cfg.experts_held == (0, 16)
+    with pytest.raises(NotImplementedError,
+                       match="no key map for a nemotron_h"):
+        hf_loader.load_hf_params(str(tmp_path))

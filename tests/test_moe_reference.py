@@ -322,8 +322,8 @@ def test_rows_of_unvisited_tiles_reach_no_output(monkeypatch):
     real = quant.grouped_matmul
     poisoned = []
 
-    def undefined_past_the_used_tiles(x, ws, scales, lay):
-        y = real(x, ws, scales, lay)
+    def undefined_past_the_used_tiles(x, ws, scales, lay, act=""):
+        y = real(x, ws, scales, lay, act)
         unvisited = jnp.arange(y.shape[0]) >= lay.tiles_used[0] * lay.tile
         poisoned.append(int(jnp.sum(unvisited)))
         return jnp.where(unvisited[:, None], jnp.nan, y)

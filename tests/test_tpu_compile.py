@@ -1386,3 +1386,218 @@ def test_sala_prefill_chunk_compiles_for_v5e_within_memory(
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert live < 15.7 * 10**9
     assert m.temp_size_in_bytes < 1.2 * 10**9
+
+
+# -- Nemotron-3-Nano's cell (benchmark/configs/nemotron-3-nano-30b-a3b.json) ---
+
+
+@pytest.mark.parametrize("slots,s", [(65, 64), (40, 33)])
+def test_ssd_state_kernel_compiles_for_v5e(one_chip, slots, s):
+    """The one-pass Mamba-2 state update at the published sizes (8 groups
+    of 128 x 512 float32, 2 MiB a row): Mosaic takes its transposes of the
+    ``[groups, 128]`` blocks of B and C, the lane broadcasts of their
+    columns and its four 2 MiB buffers inside the VMEM it asks for, and the
+    state stack is the call's input and output: aliased whole, nothing of
+    its size among the temporaries."""
+    from polyrl_tpu.ops import ssd_state
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    assert ssd_state.accepts((slots, 8, 128, 512), jnp.float32)
+    compiled = jax.jit(ssd_state.ssd_state_pallas, donate_argnums=(0,)).lower(
+        arg(slots, 8, 128, 512), arg(s, 8, 512), arg(s, 8, 512),
+        arg(s, 8, 128), arg(s, 8, 128)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == slots * 8 * 128 * 512 * 4
+    assert mem.temp_size_in_bytes < 2**20 + 4 * s * 8 * 512 * 4
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8])
+@pytest.mark.parametrize("rows", [64, 512])
+def test_relu2_expert_kernels_compile_for_v5e(one_chip, chip_precision, rows,
+                                              dtype):
+    """An expert of two matrices at Nemotron-3-Nano's widths, 16 experts
+    held of each of 23 layers: ``[16, 2688, 1856]`` under ``relu2`` (9.98
+    MB a matrix passes the one-slab rule: three slabs of 896 rows of 1856
+    columns, 14.5 lane tiles) and ``[16, 1856, 2688]`` (1856 cannot be cut:
+    one slab whole, two in flight), by table at a decode step's 64 rows
+    and tiled at a prefill chunk's 512, in bf16 and with int8 experts."""
+    from polyrl_tpu.ops import grouped_matmul as gm
+
+    d, f, e, stack, k = 2688, 1856, 16, 23, 6
+    m = rows * k
+    tile = gm.row_tile(m, e)
+    n_tiles = m // tile + e
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def scales(width):
+        return ((arg((stack * e, width), jnp.float32),)
+                if dtype == jnp.int8 else None)
+
+    i32 = jnp.int32
+    up_w, down_w = arg((stack * e, d, f), dtype), arg((stack * e, f, d), dtype)
+    if gm.rows_by_table(rows, d, 2, m, e):
+        assert rows == 64 and tile == 64
+        tab = gm.RowTables(arg((n_tiles,), i32), arg((1,), i32),
+                           arg((n_tiles,), i32), arg((n_tiles,), i32),
+                           arg((stack * e,), i32), arg((m,), i32))
+        up = jax.jit(functools.partial(
+            gm.gather_matmul_pallas, tile=tile, act="relu2")).lower(
+                arg((rows, d), jnp.bfloat16), (up_w,), tab,
+                scales(f)).compile()
+        down = jax.jit(functools.partial(
+            gm.matmul_scatter_pallas, n_tokens=rows, tile=tile)).lower(
+                arg((n_tiles * tile, f), jnp.bfloat16), (down_w,), tab,
+                arg((m,), jnp.float32), scales(d)).compile()
+    else:
+        assert rows == 512 and tile == 256
+        up = jax.jit(functools.partial(
+            gm.grouped_matmul_pallas, tile=tile, act="relu2")).lower(
+                arg((n_tiles * tile, d), jnp.bfloat16), (up_w,),
+                arg((n_tiles,), i32), arg((1,), i32), scales(f)).compile()
+        down = jax.jit(functools.partial(
+            gm.grouped_matmul_pallas, tile=tile)).lower(
+                arg((n_tiles * tile, f), jnp.bfloat16), (down_w,),
+                arg((n_tiles,), i32), arg((1,), i32), scales(d)).compile()
+    assert "tpu_custom_call" in up.as_text()
+    assert "tpu_custom_call" in down.as_text()
+
+
+def _nemotron_shapes(one_chip, s, n_pages, page):
+    from polyrl_tpu.models import decoder
+
+    cfg = decoder.get_config("nemotron-3-nano-30b-a3b-share8")
+
+    def shapes(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = shapes(jax.eval_shape(
+        lambda: decoder.init_params(jax.random.PRNGKey(0), cfg)))
+    pools = shapes(jax.eval_shape(
+        lambda: decoder.make_paged_pools(cfg, n_pages, page, slots=s + 1)))
+    return cfg, params, pools
+
+
+NEMOTRON_PAGES = 3601
+
+
+def test_nemotron_decode_step_compiles_for_v5e_within_memory(
+        one_chip, chip_precision, on_tpu):
+    """The cell's whole decode program: 8 fused steps of all 52 layers at
+    64 rows, the six attention layers' pages and the 23 Mamba-2 layers'
+    states and tails donated, the token drawn inside the untied head. The
+    Mamba-2 layers update their states in the one-pass kernel, the expert
+    layers take their rows by table. Weights (10.52 GB), states (3.19 GB)
+    and pages (1.42 GB) and everything the step holds at once fit a 16 GB
+    chip, and nothing the optimised program makes is as large as a layer's
+    states."""
+    from polyrl_tpu.models import decoder
+
+    s, width, page = 64, 128, 64
+    cfg, params, pools = _nemotron_shapes(one_chip, s, NEMOTRON_PAGES, page)
+    assert len(pools[0]) == 6 and len(pools[1]) == 23
+    assert pools[0][0][0].shape == (2, NEMOTRON_PAGES, page, 128)
+    assert pools[1][0][0].shape == (65, 8, 128, 512)
+    assert pools[1][0][1].shape == (65, 3, 6144)
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(params, paged, state, rng, table, lens, last, active, temps):
+        def body(carry, _):
+            paged, state, rng, lens, last = carry
+            rng, sub = jax.random.split(rng)
+            head = functools.partial(decoder.head_and_sample, rng=sub,
+                                     temps=temps)
+            (tok, logp), (paged, state), load = decoder.forward_paged_decode(
+                params, cfg, last, lens, (paged, state), table, lens,
+                active=active, head_fn=head)
+            return (paged, state, rng, lens + 1, tok), (tok, logp, load)
+        return jax.lax.scan(body, (paged, state, rng, lens, last), None,
+                            length=8)
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, pools[0], pools[1], arg((2,), jnp.uint32),
+        arg((s, width), jnp.int32), arg((s,), jnp.int32),
+        arg((s,), jnp.int32), arg((s,), jnp.bool_),
+        arg((s,), jnp.float32)).compile()
+    m = compiled.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert 15.0e9 < live < 16.0e9
+    text = compiled.as_text()
+    # a state update a Mamba-2 layer, a write and an attention an attention
+    # layer, the two expert kernels an expert layer, the head
+    assert text.count("tpu_custom_call") == 23 + 2 * 6 + 2 * 23 + 1
+    assert _made(text, 65 * 8 * 128 * 512) == []         # a layer's states
+
+
+def test_nemotron_prefill_chunk_compiles_for_v5e_within_memory(
+        one_chip, chip_precision, on_tpu):
+    """A 512-token chunk over 64 pages of prefix (a 4k prompt's last):
+    the Mamba-2 layers run the chunked SSD form from the slot's state, the
+    expert layers the tiled form at 512 rows, beside the weights, the
+    states and the pages."""
+    from polyrl_tpu.models import decoder
+
+    page, pb, n_pre = 64, 512, 64
+    cfg, params, pools = _nemotron_shapes(one_chip, 64, NEMOTRON_PAGES, page)
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def chunk(params, paged, state, ids, n, at, pre_pages, pages, slot):
+        return decoder.prefill_suffix_into_pages(
+            params, cfg, ids, n, at, (paged, state), pre_pages, pages, slot)
+
+    compiled = jax.jit(chunk, donate_argnums=(1, 2)).lower(
+        params, pools[0], pools[1], arg((pb,), jnp.int32),
+        arg((), jnp.int32), arg((), jnp.int32), arg((n_pre,), jnp.int32),
+        arg((pb // page,), jnp.int32), arg((), jnp.int32)).compile()
+    m = compiled.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert live < 16.0 * 10**9
+    assert m.temp_size_in_bytes < 0.8 * 10**9
+
+
+def test_nemotron_reference_walk_compiles_for_v5e_within_memory(one_chip):
+    """What decides the cell's ``correct`` runs on the chip beside the
+    weights: the float32 reference's walk of the longest scored request
+    (5,632 positions once padded) with its taps, the whole model and its
+    first layer alone (the controls'), fits the chip (with ``control=
+    "fp8_weights"``, the control of the log-probabilities' limits, it was
+    compiled the same way once by hand: the rounding fuses into the casts
+    and moves no size; 100 s of this file's time a case). (A reduction over a
+    layer's ``dt`` outside the scan kept every layer's in-projection alive
+    to the program's end, 7.3 GB of temporaries, and failed every run of a
+    call: the heads' forgetting is summed in the scan's carry.)"""
+    from benchmark.lib import harness
+    from polyrl_tpu.models import decoder
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ref = harness.load_named("references", "nemotron_h")
+    config = harness.load_config(os.path.join(
+        root, "benchmark", "configs", "nemotron-3-nano-30b-a3b.json"))
+    cfg = decoder.get_config(config["preset"])
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: decoder.init_params(jax.random.PRNGKey(0),
+                                                   cfg)))
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    with jax.default_matmul_precision("highest"):
+        compiled = ref._score.lower(
+            params, arg((5632,)), arg(()), arg(()),
+            ref._sizes(config["config"]), 384, control="").compile()
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.6e9
+    assert m.temp_size_in_bytes < 5.0e9

@@ -18,7 +18,8 @@ from polyrl_tpu.rollout.cb_engine import CBEngine
 SCOPES = ("attn_qkv", "attn_core", "attn_out", "mlp", "head")
 # the dense decoder and one preset a family of mixers (``FAMILIES`` below)
 STEP_PRESETS = ("tiny", "moe-tiny", "hybrid-tiny", "mla-moe-tiny", "cca-tiny",
-                "sambay-tiny", "mixed-tiny", "ouro-tiny", "minicpm-sala-tiny")
+                "sambay-tiny", "mixed-tiny", "ouro-tiny", "minicpm-sala-tiny",
+                "nemotron-h-tiny")
 ENGINE_PROGRAMS = {
     "step": lambda e: e._get_step(False, 2),
     "spec_step": lambda e: e._get_spec_step(False, 3, 2),
@@ -241,6 +242,14 @@ def test_each_pallas_call_we_own_has_a_name():
         lambda *a: kda_state.kda_state_pallas(*a, interpret=True),
         jnp.zeros((3, 4, d, d), jnp.float32), rows, rows, rows, rows,
         jnp.zeros((s, 4), jnp.float32)) == ["kda_state"]
+    from polyrl_tpu.ops import ssd_state
+
+    # likewise ``ssd_state`` for a Mamba-2 layer's (``ssd_kernel_steps``)
+    cols = jnp.zeros((s, 2, 16), jnp.float32)
+    assert names(
+        lambda *a: ssd_state.ssd_state_pallas(*a, interpret=True),
+        jnp.zeros((3, 2, 16, d), jnp.float32), rows[:, :2], rows[:, :2],
+        cols, cols) == ["ssd_state"]
     from polyrl_tpu.ops import mla_proj
 
     # and ``mla_absorb`` / ``mla_unabsorb`` that a step's MLA layers
@@ -277,6 +286,10 @@ FAMILIES = {
     "minicpm-sala-tiny": ("minicpm-sala.rollout-long-sparse-linear",
                           ("sparse_pages_read", "sparse_pooled_scored",
                            "sparse_dense_rows", "lightning_state_rows")),
+    "nemotron-h-tiny": ("nemotron-3-nano-30b-a3b.rollout-wide-ssd",
+                        _MOE + ("moe_choices", "paged_rows_read",
+                                "kda_state_rows", "mla_rows_read",
+                                "ssd_state_rows")),
 }
 
 

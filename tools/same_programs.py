@@ -49,7 +49,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PRESETS = ("qwen2.5-7b", "qwen3-30b-a3b", "ling-3.0-flash-share4",
            "dots.vlm1-share16", "zaya1-8b-depth12",
            "phi-4-mini-flash-reasoning", "laguna-xs.2-share8", "ouro-2.6b",
-           "minicpm-sala")
+           "minicpm-sala", "nemotron-3-nano-30b-a3b-share8")
 
 
 def readable(text: str) -> str:
