@@ -24,14 +24,22 @@ Two implementations with identical semantics for rows that hold a request:
   and V, meet on the MXU in the pool's dtype (bf16) with float32
   accumulation; the online-softmax statistics stay float32. A block whose
   every key is live takes no mask and ONE wait a pool; a row's part-filled
-  last block fetches the pages the row owns and is multiplied by
-  sub-blocks, the dead ones skipped. The page table, the lengths and the
-  next-live-row table ride as scalar prefetch. (PR 26; before it the TPU
-  path called ``jax.experimental.pallas.ops.tpu.paged_attention``, which
-  for 7 query heads a KV head multiplies in float32 one head a program
-  and ran at a third of the HBM bandwidth. PR 44: the ring; until then
-  two buffers and one block ahead, which a row's short last block did not
-  cover. Measured: PERF.md section 6.)
+  last block fetches the pages the row owns, waits for them by their
+  BYTES (one wait a pool when every page is there, else a wait for each
+  power of two of pages) and is multiplied by sub-blocks, the dead ones
+  skipped. The descriptors carry no bounds checks of Mosaic's: the
+  wrapper clips the page table to the pool, which is what stands in
+  their place. The page table, the lengths and the next-live-row table
+  ride as scalar prefetch. (PR 26; before it the TPU path called
+  ``jax.experimental.pallas.ops.tpu.paged_attention``, which for 7 query
+  heads a KV head multiplies in float32 one head a program and ran at a
+  third of the HBM bandwidth. PR 44: the ring; until then two buffers and
+  one block ahead, which a row's short last block did not cover. PR 59:
+  the scalar work a page; until then a descriptor was 22 bundles, 15 of
+  them two checks, and a last block started the block ahead from a loop
+  and waited a page at a time, which paced a program of 16 KB pages (a
+  sparse layer's, 64 descriptors a block) and not its DMAs. Measured:
+  PERF.md section 6.)
 
 Shared-prefix GROUPED decode (``grouped_paged_attention*``): GRPO's
 G-samples-per-prompt traffic means G slots share one physical prompt-KV
@@ -203,9 +211,14 @@ def _paged_attn_kernel(lens_ref, live_from_ref, table_ref,  # scalar prefetch
     output's write-back and the next program's prologue run under them;
     only the call's first live row waits for cold DMAs. A block whose
     every key is live takes no mask and one wait a pool; the row's
-    part-filled last block fetches the pages the row owns, is taken by
-    sub-blocks and skips the dead ones (the rest of a live sub-block
-    keeps an older block's finite values, masked by length)."""
+    part-filled last block fetches the pages the row owns, waits for
+    their bytes in at most ``log2(b) + 1`` pieces a pool (one when all
+    ``b`` pages are there), is taken by sub-blocks and skips the dead
+    ones (the rest of a live sub-block keeps an older block's finite
+    values, masked by length). Both kinds of iteration start the block
+    ahead unrolled, a page under its predicate. The kernel is compiled
+    without bounds checks: a page's number must lie in the pool, which
+    the wrapper's clip sees to."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -219,42 +232,37 @@ def _paged_attn_kernel(lens_ref, live_from_ref, table_ref,  # scalar prefetch
     n_full = length // bt
     n_blk = (length + bt - 1) // bt
 
-    def page_dma(r, col, j, which, start: bool):
+    def start_page(r, col, j, which):
+        """Two descriptors: page ``col`` of row ``r``'s table into place
+        ``j`` of buffer ``which``, K and V. Mosaic's bounds checks are off
+        (``paged_attention_pallas``): the table is clipped to the pool in
+        the wrapper, and ``j < b`` and ``which < nbuf`` by construction."""
         pg = table_ref[r * p + col]
         at = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
         for pool, dst, sem in ((k_hbm, kbuf, sems.at[0, which]),
                                (v_hbm, vbuf, sems.at[1, which])):
-            cp = pltpu.make_async_copy(pool.at[:, pg], dst.at[which, :, at],
-                                       sem)
-            if start:
-                cp.start()
-            else:
-                cp.wait()
+            pltpu.make_async_copy(pool.at[:, pg], dst.at[which, :, at],
+                                  sem).start()
 
-    def pages_dma(r, blk, which, start: bool, unrolled: bool = False):
-        """Start, or wait for, the copies of block ``blk`` of row ``r``
-        into buffer ``which``, two a live page. In a whole block's
-        iteration the starts are ``unrolled``, each under its predicate
-        (the block ahead may be any row's last). Elsewhere (the cold start
-        and a row's last block) the descriptors are a loop: unrolled
-        everywhere, the sister kernel (``mla_attention``) lowered four
-        times as slowly, which every decode program pays at set-up."""
-        n_pg = (lens_ref[r] + page_size - 1) // page_size - blk * b
-        if unrolled:
-            for j in range(b):
-                pl.when(j < n_pg)(functools.partial(
-                    page_dma, r, blk * b + j, j, which, start))
-        else:
-            jax.lax.fori_loop(
-                0, jnp.clip(n_pg, 0, b),
-                lambda j, _: page_dma(r, blk * b + j, j, which, start), None)
-
-    def wait_whole(which):
-        """Wait for a block of ``b`` live pages: every page's copy signals
-        the buffer's semaphore, so one wait for the buffer's bytes a pool
-        stands for all of them."""
+    def wait_pages(which, n: int):
+        """Wait for ``n`` pages of buffer ``which``: every page's copy
+        signals the buffer's semaphore with its bytes, so one wait for
+        ``n`` pages' bytes a pool stands for all of them."""
         for dst, sem in ((kbuf, sems.at[0, which]), (vbuf, sems.at[1, which])):
-            pltpu.make_async_copy(dst.at[which], dst.at[which], sem).wait()
+            part = dst.at[which, :, pl.ds(0, n * page_size)]
+            pltpu.make_async_copy(part, part, sem).wait()
+
+    def wait_owned(which, n_pg):
+        """Wait for the ``n_pg`` (1 to ``b``) pages a row's last block
+        owns, by their bytes: the whole block's in one wait when all are
+        there, else in power-of-two pieces, at most ``log2(b) + 1`` waits
+        a pool where a wait a page was ``b``."""
+        left = n_pg
+        for n in [b] + [1 << k for k in range(b.bit_length() - 1, -1, -1)
+                        if 1 << k < b]:
+            take = left >= n
+            pl.when(take)(functools.partial(wait_pages, which, n))
+            left = jnp.where(take, left - n, left)
 
     @pl.when(row == 0)
     def _first_program():
@@ -272,10 +280,26 @@ def _paged_attn_kernel(lens_ref, live_from_ref, table_ref,  # scalar prefetch
         nxt = live_from_ref[jnp.minimum(r + 1, n_rows)]
         return jnp.where(last, nxt, r), jnp.where(last, 0, blk + 1)
 
-    def start_block(r, blk, which, unrolled: bool = False):
+    def start_block(r, blk, which, unrolled: bool):
+        """Start the copies of block ``blk`` of row ``r`` (none where
+        ``r`` is past the last row) into buffer ``which``, two a live
+        page. From an iteration of the loop over blocks the starts are
+        ``unrolled``, each under its predicate (the block ahead may be
+        any row's last); the call's cold start, which runs once, keeps
+        them a loop: that is code every decode program lowers, and the
+        sister kernel (``mla_attention``) lowered four times as slowly
+        with every path unrolled."""
         @pl.when(r < n_rows)
         def _():
-            pages_dma(r, blk, which, start=True, unrolled=unrolled)
+            n_pg = (lens_ref[r] + page_size - 1) // page_size - blk * b
+            if unrolled:
+                for j in range(b):
+                    pl.when(j < n_pg)(functools.partial(
+                        start_page, r, blk * b + j, j, which))
+            else:
+                jax.lax.fori_loop(
+                    0, jnp.clip(n_pg, 0, b),
+                    lambda j, _: start_page(r, blk * b + j, j, which), None)
 
     def ring(which, k):
         """The buffer ``k`` places after ``which``."""
@@ -285,7 +309,7 @@ def _paged_attn_kernel(lens_ref, live_from_ref, table_ref,  # scalar prefetch
     def _cold_start():  # the call's first live row: nobody prefetched for it
         r, blk = row, 0
         for k in range(nbuf - 1):
-            start_block(r, blk, ring(buf0, k))
+            start_block(r, blk, ring(buf0, k), unrolled=False)
             r, blk = after(r, blk)
 
     q = q_ref[0]                                        # [Hkv, R, D]
@@ -328,11 +352,11 @@ def _paged_attn_kernel(lens_ref, live_from_ref, table_ref,  # scalar prefetch
         blk = jnp.where(last, 0, i + 1)
         for _ in range(nbuf - 2):
             r, blk = after(r, blk)
-        start_block(r, blk, ring(which, nbuf - 1), unrolled=whole)
+        start_block(r, blk, ring(which, nbuf - 1), unrolled=True)
         if whole:
-            wait_whole(which)
+            wait_pages(which, b)
         else:
-            pages_dma(row, i, which, start=False)
+            wait_owned(which, (length + page_size - 1) // page_size - i * b)
         return which
 
     def whole_block(i, state):
@@ -380,12 +404,15 @@ def paged_attention_pallas(
     in bf16; rows are free on the MXU up to 128); the pages a block holds,
     the last block's sub-blocks and the ring's depth come from
     ``_block_plan`` unless a test or ``tools/bench_paged_attention.py``
-    hands the kernel another ``plan``. A row of length 0 returns zeros."""
+    hands the kernel another ``plan``. A row of length 0 returns zeros.
+    The table is clipped to the pool and the lengths to the table's width:
+    the kernel runs without Mosaic's bounds checks, and these stand in
+    their place."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     s, hq, d = q.shape
-    hkv, _n_pool, page_size, _ = k_pool.shape
+    hkv, n_pool, page_size, _ = k_pool.shape
     p = page_table.shape[1]
     rep = hq // hkv
     scale = scale if scale is not None else d ** -0.5
@@ -398,6 +425,8 @@ def paged_attention_pallas(
     if r_pad != rep:
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, r_pad - rep), (0, 0)))
     lens = jnp.clip(seq_lens.astype(jnp.int32), 0, p * page_size)
+    # the kernel's descriptors carry no bounds checks: this clip is theirs
+    table = jnp.clip(page_table.astype(jnp.int32), 0, n_pool - 1)
     # live_from[r]: the first row at or after r that has a request, else s
     live_from = jax.lax.cummin(
         jnp.where(lens > 0, jnp.arange(s, dtype=jnp.int32), s),
@@ -429,9 +458,15 @@ def paged_attention_pallas(
         interpret=interpret,
         name="paged_attention",
         # rows run in order: each hands the live rows after it their blocks
+        # (no bounds checks: at 1 K/V head a block is 64 descriptors, which
+        # paced the kernel at 21-23 bundles each, 14-16 of them two checks,
+        # and are 5 without; a page's number is clipped to the pool above,
+        # its place in the buffer is a static multiple of the page below
+        # the block, and the buffer and the semaphore are a remainder by
+        # the ring)
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-    )(lens, live_from, page_table.astype(jnp.int32).reshape(-1),
+            dimension_semantics=("arbitrary",), disable_bounds_checks=True),
+    )(lens, live_from, table.reshape(-1),
       qr, _in_hbm(k_pool, interpret), _in_hbm(v_pool, interpret))
     return out[:, :, :rep].reshape(s, hq, d)
 

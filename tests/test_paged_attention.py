@@ -1,5 +1,9 @@
 """Paged decode attention: oracle vs dense attention, Pallas(interpret) vs oracle."""
 
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -80,9 +84,10 @@ _KERNEL_CASES = [
 ]
 
 
-def _check_against_ref(q, kp, vp, table, lens, tol, **kw):
+def _check_against_ref(q, kp, vp, table, lens, tol, interpret=True, **kw):
     ref = paged_attention_ref(q, kp, vp, table, lens)
-    pal = paged_attention_pallas(q, kp, vp, table, lens, interpret=True, **kw)
+    pal = paged_attention_pallas(q, kp, vp, table, lens, interpret=interpret,
+                                 **kw)
     assert pal.dtype == q.dtype and pal.shape == q.shape
     live = np.asarray(lens) > 0
     np.testing.assert_allclose(np.asarray(pal, np.float32)[live],
@@ -196,6 +201,142 @@ def test_ring_runs_across_rows(pattern, plan):
         rng, s=len(lens), hq=6, hkv=2, d=24, lens=lens, max_pages=width,
         n_pool=1 + sum(-(-ln // PAGE) for ln in lens))
     _check_against_ref(q, kp, vp, table, lens, 2e-5, plan=plan)
+
+
+def _sparse_call(rng, parts, width, counts, hkv=2, d=16, rep=16, page=PAGE,
+                 dtype=np.float32):
+    """What a block-sparse layer hands the kernel (``mixers/sparse.py``) in
+    small: a row a (request, K/V head) of ONE head under ``rep`` query
+    rows, the pools seen as one head's, a table of the pages that head
+    chose in no order (head ``g``'s numbers offset by ``g * N``) and the
+    keys they hold: ``count - 1`` whole pages and ``part`` keys of the
+    request's own, last; ``count`` 0 is a request that is not there."""
+    n_pages = 1 + width * len(parts)
+    table = np.zeros((len(parts) * hkv, width), np.int32)
+    lens = np.zeros(len(table), np.int32)
+    for r, (part, count) in enumerate(zip(parts, counts)):
+        for g in range(hkv):
+            table[r * hkv + g, :count] = g * n_pages + rng.permutation(
+                np.arange(1, n_pages))[:count]
+            lens[r * hkv + g] = max(count - 1, 0) * page + (count > 0) * part
+    pools = [jnp.asarray(rng.standard_normal((1, hkv * n_pages, page, d)),
+                         dtype) for _ in range(2)]
+    q = jnp.asarray(rng.standard_normal((len(table), rep, d)), dtype)
+    return q, pools[0], pools[1], table, lens
+
+
+@pytest.mark.parametrize("dtype,tol", [(np.float32, 2e-5),
+                                       (jnp.bfloat16, 1e-2)],
+                         ids=["float32", "bfloat16"])
+def test_sparse_layers_call_in_small(dtype, tol):
+    """The call MiniCPM-SALA's cell makes 582 times a step, in small: 1
+    K/V head under 16 query rows, every row two blocks (4 pages each of a
+    table of 8) of which the second is the row's last with EVERY page
+    there and the newest part-filled: the path that waits for the block's
+    bytes at once. Beside them a request under ``topk`` pages, one whose
+    newest page is full (two whole blocks) and one that is not there."""
+    rng = np.random.default_rng(21)
+    q, kp, vp, table, lens = _sparse_call(
+        rng, parts=(1, 5, PAGE - 1, 3, PAGE, 2), width=8,
+        counts=(8, 8, 8, 3, 8, 0), dtype=dtype)
+    assert pa._block_plan(1, PAGE, 16, 4, 8)[0] == 8   # so a plan is handed
+    _check_against_ref(q, kp, vp, table, lens, tol, plan=(4, 2, 3))
+
+
+# (pages a block, sub-blocks, buffers): a block of a power of two, and
+# Phi's ring of three pages (a whole block, then 2 + 1)
+_LAST_BLOCK_PLANS = [(8, 2, 3), (3, 1, 3)]
+
+
+def _last_block_case(b, counts, seed):
+    """Rows whose last block owns ``n_pg`` pages with ``tail`` keys in the
+    newest, for each (n_pg, tail) of ``counts``: after 0, 1 and 2 whole
+    blocks of ``b`` pages, a row of length 0 between live rows."""
+    lens = ()
+    for n_pg, tail in counts:
+        keys = (n_pg - 1) * PAGE + tail
+        lens += (keys, 0, b * PAGE + keys, 2 * b * PAGE + keys)
+    return _make_case(
+        np.random.default_rng(seed), s=len(lens), hq=6, hkv=2, d=24,
+        lens=lens, max_pages=3 * b + 1,
+        n_pool=1 + sum(-(-ln // PAGE) for ln in lens))[:5]
+
+
+@pytest.mark.parametrize("tail", [1, PAGE], ids=["one_key", "page_full"])
+@pytest.mark.parametrize("plan,n_pg", [
+    (plan, n) for plan in _LAST_BLOCK_PLANS for n in range(1, plan[0] + 1)],
+    ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else f"{v}pg")
+def test_last_block_of_every_page_count(plan, n_pg, tail):
+    """A row's last block waits for the pages it owns by their BYTES, in
+    power-of-two pieces (the block whole when all are there): every count
+    from one page to the block, the newest page holding one key and
+    full (with all ``b`` pages that is a whole block), with a row of
+    length 0 between live rows."""
+    _check_against_ref(*_last_block_case(plan[0], [(n_pg, tail)], 13), 2e-5,
+                       plan=plan)
+
+
+_BALANCE = """
+import sys
+from jax.experimental.pallas import tpu as pltpu
+sys.path.insert(0, {tests!r})
+import test_paged_attention as t
+plan = {plan!r}
+case = t._last_block_case(plan[0], [(n, tail) for n in range(1, plan[0] + 1)
+                                    for tail in (1, t.PAGE)], 17)
+t._check_against_ref(*case, 2e-5, plan=plan,
+                     interpret=pltpu.InterpretParams())
+print("ran to its end")
+"""
+
+
+@pytest.mark.parametrize("plan", _LAST_BLOCK_PLANS,
+                         ids=lambda p: "x".join(map(str, p)))
+def test_waits_balance_the_starts(plan):
+    """The HLO interpreter copies at a start and waits for nothing, so a
+    wait for too few or too many bytes passes there and stalls or races
+    on the chip. Mosaic's own interpreter keeps the semaphores: a copy
+    runs when its bytes are WAITED for (a wait short of the starts reads
+    stale keys and leaves a count it reports at the kernel's end), and a
+    wait beyond them never returns, hence a child with a time limit. Every
+    page count of a last block, after 0, 1 and 2 whole blocks, in one
+    call."""
+    done = subprocess.run(
+        [sys.executable, "-c", _BALANCE.format(
+            tests=os.path.dirname(os.path.abspath(__file__)), plan=plan)],
+        capture_output=True, text=True, timeout=300,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert done.returncode == 0, done.stderr[-2000:]
+    assert "ran to its end" in done.stdout
+    assert "non-zero count" not in done.stdout + done.stderr
+
+
+def test_table_outside_the_pool_is_clipped():
+    """The kernel's descriptors carry no bounds checks (they paced a
+    program of small pages); the wrapper clips the table to the pool in
+    their place. A table holding -1, the pool's size and 2**30 among a
+    row's LIVE pages returns exactly what the clipped table returns."""
+    rng = np.random.default_rng(19)
+    lens = (5 * PAGE + 3, 0, 2 * PAGE, 7 * PAGE)
+    q, kp, vp, table, lens, _, _ = _make_case(
+        rng, s=len(lens), hq=4, hkv=2, d=16, lens=lens, max_pages=7,
+        n_pool=20)
+    n_pool = kp.shape[1]
+    wild = table.copy()
+    wild[0, 1], wild[0, 4], wild[2, 0], wild[3, 6] = -1, n_pool, 2**30, -2**31
+    wild[1, :] = 2**30            # a row without a request: never fetched
+    clipped = np.clip(wild, 0, n_pool - 1)
+    assert (clipped != wild).sum() == 4 + 7
+
+    def run(tab):
+        return np.asarray(paged_attention_pallas(
+            q, kp, vp, tab, lens, interpret=True, plan=(2, 2, 3)))
+
+    got = run(wild)
+    np.testing.assert_array_equal(got, run(clipped))
+    np.testing.assert_allclose(
+        got[0], np.asarray(paged_attention_ref(q, kp, vp, clipped, lens))[0],
+        rtol=2e-5, atol=2e-5)
 
 
 def test_every_row_dead():

@@ -63,6 +63,10 @@ def one_chip(v5e_2x2):
     # phi-4-mini-flash-reasoning's paired heads: the shared pool, a ring
     (129, 40, 10, 128, jnp.bfloat16, 64, 320),
     (129, 40, 10, 128, jnp.bfloat16, 64, 8),
+    # minicpm-sala's sparse layers: a (request, K/V head) a row of ONE head
+    # under 16 query rows, 64 chosen pages of 16 KB in a table of 128:
+    # blocks of 32 pages, 64 descriptors each
+    (194, 16, 1, 128, jnp.bfloat16, 64, 128),
 ])
 def test_decode_kernel_compiles_for_v5e(one_chip, s, hq, hkv, d, dtype, page,
                                         width):
@@ -75,6 +79,30 @@ def test_decode_kernel_compiles_for_v5e(one_chip, s, hq, hkv, d, dtype, page,
             arg((s, hq, d), dtype), pool, pool,
             arg((s, width), jnp.int32), arg((s,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# (rows, hq, hkv, table width, pages a block): minicpm-sala's sparse layers
+# and zaya1-8b's CCA layers
+@pytest.mark.parametrize("s,hq,hkv,width,b", [(194, 16, 1, 128, 32),
+                                              (129, 8, 2, 192, 16)],
+                         ids=["sparse", "zaya"])
+def test_decode_kernel_unrolls_two_blocks_of_starts_and_waits_by_bytes(
+        s, hq, hkv, width, b):
+    """A descriptor that is unrolled code is a start in the kernel's jaxpr,
+    and what a decode program pays at set-up when it lowers. Two a page of
+    the block that a whole block's iteration starts and of the one a last
+    block's iteration starts, and the two loops of the call's cold start;
+    a wait a pool for a whole block, and for a last block the block's bytes
+    or those of each power of two of pages below it: never a wait a page.
+    No chip and no compiler: the count is the trace's."""
+    arg = jax.ShapeDtypeStruct
+    pool = arg((hkv, 1 + 4 * width, 64, 128), jnp.bfloat16)
+    assert pa._block_plan(hkv, 64, 128, 2, width) == (b, 2, 3)
+    text = str(jax.make_jaxpr(pa.paged_attention_pallas)(
+        arg((s, hq, 128), jnp.bfloat16), pool, pool,
+        arg((s, width), jnp.int32), arg((s,), jnp.int32)))
+    assert text.count("dma_start") == 2 * (2 * b + 2)
+    assert text.count("dma_wait") == 2 * (1 + 1 + int(math.log2(b)))
 
 
 def _colours(lowered) -> tuple[dict[int, int], list[int]]:

@@ -8,12 +8,21 @@ call then exits 1 after the other variants.
 
     chiprun -- python tools/bench_paged_attention.py \
         [--cell zaya1-8b.rollout-wide-cca] [--window 512] \
-        [--plans "16,4,3;16,1,2"] [--also-tree .parent] \
-        [--parts whole,merged2,dead]
+        [--into-answer 264,400] [--plans "16,4,3;16,1,2"] \
+        [--also-tree .parent] [--parts whole,merged2,dead] [--sparse]
 
 ``--cell`` takes the heads from the cell's configuration and the rows, the
 table's width and the prompt lengths from its traffic file; ``--window``
 cuts every context to a ring of that many keys (Phi's window layers).
+``--into-answer`` is a LIST of how many tokens every row has generated: the
+tool gives every row the same number, and which rows have just crossed a
+block's edge moves a reading by a third, so a variant is judged at two
+offsets at least. ``--sparse`` lays the call out as a block-sparse layer
+of the cell's configuration hands it over (``mixers/sparse.py``): every
+(request, K/V head) a row of ONE head over the pools seen as one head's,
+its table the pages that head chose (``topk`` of the request's own, drawn
+without order, its newest last) and its length the whole chosen pages and
+the part-filled newest one.
 ``--plans`` times the kernel under other (pages a block, sub-blocks of a
 last block, buffers) than ``paged_attention._block_plan`` returns for the
 shapes. ``--also-tree`` times other checkouts' kernels beside this one
@@ -72,6 +81,7 @@ def cell_shapes(cell: str) -> dict:
     mix = traffic.load_mix(work["traffic"])
     engine = mix["engine"]
     return {"hq": hq, "hkv": hkv // side_by_side, "d": d * side_by_side,
+            "sparse": cfg.get("sparse_config"),
             "page": engine["page_size"],
             "width": engine["max_seq_len"] // engine["page_size"],
             "prompts": traffic.size_set(mix["prompt_tokens"],
@@ -115,15 +125,62 @@ def inputs(lengths: list[int], shapes: dict, width: int, seed: int):
     return q, k_pool, v_pool, jnp.asarray(table), lens
 
 
+def sparse_width(shapes: dict) -> int:
+    """The most pages a (request, K/V head) attends
+    (``mixers.sparse.table_width``)."""
+    cfg = shapes["sparse"]
+    return max(cfg["topk"], -(-cfg["dense_len"] // shapes["page"]))
+
+
+def sparse_inputs(contexts: list[int], shapes: dict, seed: int):
+    """What a block-sparse layer's decode step hands the kernel
+    (``mixers.sparse.selected_table`` and ``step``): q ``[S * Hkv, Hq / Hkv,
+    D]``, the pools as ``[1, Hkv * N, page, D]``, a table ``[S * Hkv, W]``
+    of the pages a (request, head) chose (head ``g``'s numbers offset by
+    ``g * N``; the newest page last, the others drawn from the request's
+    own without order) and the keys they hold. One more request than
+    ``contexts`` (the engine's spare slot, dead)."""
+    cfg, page = shapes["sparse"], shapes["page"]
+    hkv, d = shapes["hkv"], shapes["d"]
+    if cfg["block_size"] != page:
+        raise SystemExit("--sparse: a sparse layer's page is its block")
+    rng = np.random.default_rng(seed)
+    width = sparse_width(shapes)
+    own = [-(-t // page) for t in contexts]
+    n_pages = 1 + int(sum(own) * 1.12)
+    order = rng.permutation(np.arange(1, n_pages))
+    table = np.zeros(((len(contexts) + 1) * hkv, width), np.int32)
+    lens = np.zeros(len(table), np.int32)
+    at = 0
+    for r, (t, n) in enumerate(zip(contexts, own)):
+        mine = order[at:at + n]
+        at += n
+        count = n if t <= cfg["dense_len"] else min(n, cfg["topk"])
+        for g in range(hkv):
+            older = rng.permutation(mine[:-1])[:count - 1]
+            table[r * hkv + g, :count] = g * n_pages + np.append(older,
+                                                                 mine[-1])
+            lens[r * hkv + g] = (count - 1) * page + t - (n - 1) * page
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed % (1 << 31)), 3)
+    k_pool = jax.random.normal(kk, (1, hkv * n_pages, page, d), jnp.bfloat16)
+    v_pool = jax.random.normal(kv, (1, hkv * n_pages, page, d), jnp.bfloat16)
+    q = jax.random.normal(kq, (len(table), shapes["hq"] // hkv, d),
+                          jnp.bfloat16)
+    return q, k_pool, v_pool, jnp.asarray(table), jnp.asarray(lens)
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--cell", default="zaya1-8b.rollout-wide-cca")
-    ap.add_argument("--into-answer", type=int, default=264,
-                    help="tokens generated so far (64 warm + the traced "
-                         "part's middle)")
+    ap.add_argument("--into-answer", default="264,400",
+                    help="tokens generated so far, a list (64 warm + the "
+                         "traced part's middle, and a third of a block on)")
     ap.add_argument("--window", type=int, default=0,
                     help="a ring of this many keys a row (Phi's window "
                          "layers: 512) instead of the whole context")
+    ap.add_argument("--sparse", action="store_true",
+                    help="the call of a block-sparse layer of the cell's "
+                         "configuration: a row a (request, K/V head)")
     ap.add_argument("--plans", default="")
     ap.add_argument("--also-tree", default="")
     ap.add_argument("--parts", default="")
@@ -139,13 +196,16 @@ def main() -> int:
     from polyrl_tpu.ops import paged_attention as here
 
     shapes = cell_shapes(args.cell)
-    real = [t + args.into_answer for t in shapes["prompts"]]
+    if args.sparse and (not shapes["sparse"] or args.parts or args.window):
+        raise SystemExit("--sparse: a cell with a sparse_config, and neither "
+                         "--parts nor --window")
     width = shapes["width"]
     if args.window:
-        real = [min(t, args.window) for t in real]
         width = args.window // shapes["page"]
-    rule = here._block_plan(shapes["hkv"], shapes["page"], shapes["d"], 2,
-                            width)
+    if args.sparse:
+        width = sparse_width(shapes)
+    rule = here._block_plan(1 if args.sparse else shapes["hkv"],
+                            shapes["page"], shapes["d"], 2, width)
     variants = [("change", here, None)]
     variants += [(f"change {p}", here, tuple(int(x) for x in p.split(",")))
                  for p in args.plans.split(";") if p]
@@ -153,61 +213,86 @@ def main() -> int:
                  for tree in args.also_tree.split(",") if tree]
     os.makedirs(args.out, exist_ok=True)
     failed = 0
-    for part in ["real"] + [p for p in args.parts.split(",") if p]:
-        lengths = lay_out(real, part, rule[0] * shapes["page"])
-        wide = max(width, -(-max(lengths) // shapes["page"]))
-        q, k_pool, v_pool, table, lens = inputs(lengths, shapes, wide,
-                                                args.seed)
-        live = sum(t > 0 for t in lengths)
-        check = jnp.asarray([0, len(lengths) // 2, len(lengths) - 1,
-                             len(lengths)])
-        want = here.paged_attention_ref(q[check], k_pool, v_pool,
-                                        table[check], lens[check])
-        want = jnp.where((lens[check] > 0)[:, None, None], want, 0)
-        # the least the chip could take: every live key's K and V row once
-        least_ms = 1e3 * (2 * shapes["hkv"] * shapes["d"] * 2 * sum(lengths)
-                          / HBM_BYTES_S)
-        for k, (name, mod, plan) in enumerate(variants):
-            fn = mod.paged_attention_pallas
-            if plan is not None:
-                fn = functools.partial(fn, plan=plan)
-            try:
-                got = jax.block_until_ready(
-                    fn(q, k_pool, v_pool, table, lens))
-                err = float(jnp.abs(got[check].astype(jnp.float32)
-                                    - want.astype(jnp.float32)).max())
-                if not err <= TOLERANCE:
-                    raise ValueError(f"{err} from the oracle, over "
-                                     f"{TOLERANCE}: not timed")
-            except Exception as e:  # the others still run; the call fails
-                failed += 1
-                print(json.dumps({"variant": name, "layout": part,
-                                  "error": str(e)[:300]}), flush=True)
-                continue
-            trace_dir = os.path.join(args.out, f"trace_{part}_{k}")
-            with jax.profiler.trace(trace_dir):
-                for _ in range(args.calls):
-                    got = fn(q, k_pool, v_pool, table, lens)
-                jax.block_until_ready(got)
-            ms = kernel_ms(trace_dir, KERNEL)
-            med = statistics.median(ms)
-            line = json.dumps({
-                "variant": name, "layout": part,
-                "plan": plan or (list(rule) if mod is here else None),
-                "cell": args.cell, "window": args.window,
-                "device": jax.devices()[0].device_kind,
-                "heads": [shapes["hq"], shapes["hkv"]],
-                "rows": len(lengths) + 1, "live_rows": live,
-                "keys": sum(lengths), "table_width": wide,
-                "kernel_ms_median": med, "kernel_ms_min": min(ms),
-                "kernel_ms_max": max(ms), "events": len(ms),
-                "roofline_share": 100 * least_ms / med,
-                "us_a_row_beyond_bytes": 1e3 * (med - least_ms) / live,
-                "max_abs_err_vs_oracle": err})
-            print(line, flush=True)
-            with open(os.path.join(args.out, "results.jsonl"), "a") as f:
-                f.write(line + "\n")     # the call shows its last lines only
+    for into in (int(t) for t in args.into_answer.split(",")):
+        real = [t + into for t in shapes["prompts"]]
+        if args.window:
+            real = [min(t, args.window) for t in real]
+        parts = ["sparse"] if args.sparse else (
+            ["real"] + [p for p in args.parts.split(",") if p])
+        for part in parts:
+            if args.sparse:
+                q, k_pool, v_pool, table, lens = sparse_inputs(
+                    real, shapes, args.seed)
+            else:
+                lengths = lay_out(real, part, rule[0] * shapes["page"])
+                wide = max(width, -(-max(lengths) // shapes["page"]))
+                q, k_pool, v_pool, table, lens = inputs(lengths, shapes,
+                                                        wide, args.seed)
+            failed += time_variants(args, variants, rule, part, into,
+                                    q, k_pool, v_pool, table, lens)
     return 1 if failed else 0
+
+
+def time_variants(args, variants, rule, part: str, into: int,
+                  q, k_pool, v_pool, table, lens) -> int:
+    """A JSON line a variant at one layout and offset; how many failed."""
+    here = variants[0][1]
+    hkv, _, _, d = k_pool.shape
+    keys, rows = int(lens.sum()), len(lens)
+    alive = np.flatnonzero(np.asarray(lens) > 0)
+    live = len(alive)
+    check = jnp.asarray([alive[0], alive[live // 2], alive[-1], rows - 1])
+    want = here.paged_attention_ref(q[check], k_pool, v_pool, table[check],
+                                    lens[check])
+    want = jnp.where((lens[check] > 0)[:, None, None], want, 0)
+    # the least the chip could take: every live key's K and V row once
+    least_ms = 1e3 * 2 * hkv * d * 2 * keys / HBM_BYTES_S
+    failed, first = 0, None
+    for k, (name, mod, plan) in enumerate(variants):
+        fn = mod.paged_attention_pallas
+        if plan is not None:
+            fn = functools.partial(fn, plan=plan)
+        try:
+            got = jax.block_until_ready(fn(q, k_pool, v_pool, table, lens))
+            err = float(jnp.abs(got[check].astype(jnp.float32)
+                                - want.astype(jnp.float32)).max())
+            if not err <= TOLERANCE:
+                raise ValueError(f"{err} from the oracle, over "
+                                 f"{TOLERANCE}: not timed")
+        except Exception as e:  # the others still run; the call fails
+            failed += 1
+            print(json.dumps({"variant": name, "layout": part,
+                              "into_answer": into, "error": str(e)[:300]}),
+                  flush=True)
+            continue
+        first = got if first is None else first
+        trace_dir = os.path.join(args.out, f"trace_{part}_{into}_{k}")
+        with jax.profiler.trace(trace_dir):
+            for _ in range(args.calls):
+                got = fn(q, k_pool, v_pool, table, lens)
+            jax.block_until_ready(got)
+        ms = kernel_ms(trace_dir, KERNEL)
+        med = statistics.median(ms)
+        line = json.dumps({
+            "variant": name, "layout": part, "into_answer": into,
+            "plan": plan or (list(rule) if mod is here else None),
+            "cell": args.cell, "window": args.window,
+            "device": jax.devices()[0].device_kind,
+            "heads": [q.shape[1], hkv],
+            "rows": rows, "live_rows": live,
+            "keys": keys, "table_width": table.shape[1],
+            "kernel_ms_median": med, "kernel_ms_min": min(ms),
+            "kernel_ms_max": max(ms), "events": len(ms),
+            "roofline_share": 100 * least_ms / med,
+            "us_a_row_beyond_bytes": 1e3 * (med - least_ms) / live,
+            "max_abs_err_vs_oracle": err,
+            # same bytes, same products, same order: a variant under the
+            # first one's plan gives the first one's bits
+            "bits_of_the_first": bool(jnp.array_equal(got, first))})
+        print(line, flush=True)
+        with open(os.path.join(args.out, "results.jsonl"), "a") as f:
+            f.write(line + "\n")     # the call shows its last lines only
+    return failed
 
 
 if __name__ == "__main__":
